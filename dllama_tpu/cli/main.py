@@ -664,6 +664,10 @@ def main(argv=None) -> int:
     from dllama_tpu.obs import trace
 
     trace.configure(args.trace_buffer)
+    if args.mode != "router":  # the router owns no engine: nothing jits
+        from dllama_tpu.obs.compile import place_compile_cache
+
+        place_compile_cache()
     from dllama_tpu.utils import faults
 
     # $DLLAMA_FAULTS first, --faults wins when both are set; a bad spec

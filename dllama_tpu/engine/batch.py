@@ -750,6 +750,25 @@ class BatchEngine:
         # the cache/pool with the exact construction-time parameters
         self.cache_dtype = cache_dtype
         self._shardings = shardings
+        # kernel selection shared with InferenceEngine (engine/kernel_select.py)
+        # — resolved BEFORE the cache exists: the paged kernel route decides
+        # the pool's row width
+        from dllama_tpu.engine.kernel_select import (
+            resolve_kernels,
+            resolve_moe_impl,
+        )
+
+        moe_impl = resolve_moe_impl(moe_impl, shardings)
+        sel = resolve_kernels(cfg, self.seq_len, n_slots, kernels, attn_impl,
+                              shardings, paged=kv_layout == "paged",
+                              page_size=self.page_size,
+                              cache_dtype=cache_dtype)
+        mm, mm_in, attn_fn = sel.mm, sel.mm_in, sel.attn_fn
+        self.backend = sel.backend
+        # which attention path actually runs ('paged_kernel' = the fused
+        # flash-decode kernel, 'paged_gather' = jnp view gather, ...) — the
+        # cost model prices the two paged routes very differently
+        self.attn_route = sel.attn_route
         self.pool: PagePool | None = None
         if kv_layout == "paged":
             if shardings is not None:
@@ -767,8 +786,7 @@ class BatchEngine:
             n_pages = int(kv_pages) or max_blocks * n_slots
             self.pool = PagePool(n_pages, self.page_size, n_slots, max_blocks)
             self.pool.write_horizons = self._write_horizons
-            self.cache = PagedKVCache.create(
-                cfg, n_slots, n_pages, self.page_size, cache_dtype, max_blocks)
+            self.cache = self._new_paged_cache(n_pages, max_blocks)
         else:
             self.cache = KVCache.create(cfg, n_slots, cache_dtype, self.seq_len)
         if radix_cache not in ("auto", "on", "off"):
@@ -877,24 +895,6 @@ class BatchEngine:
             from dllama_tpu.parallel.collectives import make_q80_col_matmul
 
             self._col_fn = make_q80_col_matmul(shardings.mesh)
-
-        # kernel selection shared with InferenceEngine (engine/kernel_select.py)
-        from dllama_tpu.engine.kernel_select import (
-            resolve_kernels,
-            resolve_moe_impl,
-        )
-
-        moe_impl = resolve_moe_impl(moe_impl, shardings)
-        sel = resolve_kernels(cfg, self.seq_len, n_slots, kernels, attn_impl,
-                              shardings, paged=self.pool is not None,
-                              page_size=self.page_size,
-                              cache_dtype=cache_dtype)
-        mm, mm_in, attn_fn = sel.mm, sel.mm_in, sel.attn_fn
-        self.backend = sel.backend
-        # which attention path actually runs ('paged_kernel' = the fused
-        # flash-decode kernel, 'paged_gather' = jnp view gather, ...) — the
-        # cost model prices the two paged routes very differently
-        self.attn_route = sel.attn_route
 
         self._prefill_step = jax.jit(
             partial(self._prefill_impl, cfg, attn_fn, self._col_fn, mm, mm_in, moe_impl),
@@ -1007,17 +1007,29 @@ class BatchEngine:
                 f"{compile_obs.TRANSFER_GUARD_MODES}, got {transfer_guard!r}")
         self.transfer_guard = transfer_guard
         self.contract = compile_obs.ShapeContract()
-        self._bucket_tag = sel.bucket_tag()
+        self.kernel_route = sel.bucket_tag()
         from dllama_tpu.engine.kernel_select import pow2_buckets
 
         # pow2_chunk never emits a chunk wider than the prompt cap, and a
         # prompt is < seq_len — the declared prefill universe honors both
         for c in pow2_buckets(self._prefill_bucket_cap()):
             self.contract.declare("prefill_chunk", f"m{c}",
-                                  note=self._bucket_tag)
-        self.contract.declare("commit", "b1", note=self._bucket_tag)
+                                  note=self.kernel_route)
+        self.contract.declare("commit", "b1", note=self.kernel_route)
         compile_obs.LEDGER.install_contract(self.contract)
         compile_obs.LEDGER.ensure_listener()
+
+    def _new_paged_cache(self, n_pages: int, max_blocks: int) -> PagedKVCache:
+        """The engine's page pool (construction and warm_restart). On the
+        paged_kernel route its rows are whole 128-lane vectors: Mosaic
+        cannot DMA-walk a narrower pool (pool_lanes has the details)."""
+        from dllama_tpu.ops.pallas.paged_attention import pool_lanes
+
+        lanes = (pool_lanes(self.cfg.head_size)
+                 if self.attn_route == "paged_kernel" else 0)
+        return PagedKVCache.create(
+            self.cfg, self.n_slots, n_pages, self.page_size,
+            self.cache_dtype, max_blocks, lanes=lanes)
 
     # ------------------------------------------------------------- jitted fns
 
@@ -1730,7 +1742,8 @@ class BatchEngine:
         cfg = self.cfg
         return ChunkCostModel(
             n_layers=cfg.n_layers, dim=cfg.dim, hidden_dim=cfg.hidden_dim,
-            kv_dim=cfg.kv_dim, head_size=cfg.head_size,
+            # row width as stored (a paged_kernel pool is lane-padded)
+            kv_dim=cfg.kv_dim, head_size=int(self.cache.k.shape[-1]),
             n_kv_heads=cfg.n_kv_heads, vocab_size=cfg.vocab_size,
             seq_len=self.seq_len, weight_bytes=int(params_nbytes(self.params)),
             cache_bytes_per_el=int(cache_el),
@@ -1793,7 +1806,7 @@ class BatchEngine:
         'undeclared' (no contract, no false alarms)."""
         from dllama_tpu.engine.kernel_select import pow2_buckets
 
-        tag = self._bucket_tag
+        tag = self.kernel_route
         chunk = max(1, int(chunk))
         fns = ["decode", "decode_pen"]
         if self.spec_k:
@@ -2035,9 +2048,7 @@ class BatchEngine:
                                  self.n_slots, max_blocks)
             self.pool.audit_on_release = audit_flag
             self.pool.write_horizons = self._write_horizons
-            self.cache = PagedKVCache.create(
-                self.cfg, self.n_slots, self.pool.n_pages, self.page_size,
-                self.cache_dtype, max_blocks)
+            self.cache = self._new_paged_cache(self.pool.n_pages, max_blocks)
             if self.radix is not None:
                 # the radix tree's page ids died with the pool: rebuild it
                 # EMPTY against the fresh allocator (never stale page refs);

@@ -132,6 +132,7 @@ class InferenceEngine:
         sel = resolve_kernels(cfg, self.seq_len, batch, kernels, attn_impl, shardings)
         mm, mm_in, attn_fn = sel.mm, sel.mm_in, sel.attn_fn
         self.backend = sel.backend
+        self.kernel_route = sel.bucket_tag()
         from dllama_tpu.parallel.collectives import resolve_sync
 
         self.sync = sync = resolve_sync(sync, shardings)

@@ -14,8 +14,6 @@ from dataclasses import dataclass
 from functools import partial
 from typing import Callable
 
-import jax
-
 from dllama_tpu.models.config import LlamaConfig
 
 
@@ -52,6 +50,17 @@ class KernelSelection:
     # 'paged_gather' — the single string obs/bench/README quote for "what
     # actually runs", and what chunk_cost_model prices (kernel vs gather
     # paged bytes differ by the whole re-materialized view)
+    interpret: bool = False  # Pallas interpret mode baked into attn_fn (and
+    # what ops.matmul derives for the matmuls): true only off-TPU
+
+    def __post_init__(self):
+        from dllama_tpu.ops.matmul import device_platform
+
+        if self.interpret and device_platform() == "tpu":
+            raise RuntimeError(
+                "kernel selection carries interpret=True on a TPU: the "
+                "serving path must run compiled kernels")
+
     def bucket_tag(self) -> str:
         """'backend/attn_route' — the variant tag the compile ledger's
         shape-bucket contract stamps on each declared bucket, so a
@@ -113,12 +122,12 @@ def resolve_kernels(
       operands per call (VERDICT r2 weak #1 / ADVICE r1).
     * sp meshes keep their ring-attention shard_map (shardings.attn_fn).
     """
-    from dllama_tpu.ops.matmul import engine_matmul
+    from dllama_tpu.ops.matmul import device_platform, engine_matmul
 
     mm = engine_matmul(kernels, shardings)
     backend = mm.keywords["backend"]
     mm_in = None
-    on_tpu = jax.devices()[0].platform == "tpu"
+    on_tpu = device_platform() == "tpu"
 
     sharded_pallas = (
         shardings is not None
@@ -156,12 +165,10 @@ def resolve_kernels(
             (cfg.n_heads, cfg.head_size), page_size,
             kv_dtype=cache_dtype if cache_dtype is not None else jnp.bfloat16,
         ) and (attn_impl == "flash" or on_tpu):
-            interp = not on_tpu
-
             def attn_fn(q, k_pool, v_pool, tables, pos, new_k, new_v, active):
                 return paged_decode_attention(
                     q, k_pool, v_pool, tables, pos, new_k, new_v, active,
-                    interpret=interp)
+                    interpret=not on_tpu)
 
             # models/llama._layer hands the new KV rows to the kernel
             # instead of paying a separate scatter dispatch per layer; the
@@ -174,6 +181,7 @@ def resolve_kernels(
             fused_cap = FUSED_SCATTER_MAX_T
         return KernelSelection(mm=mm, mm_in=mm_in, attn_fn=attn_fn,
                                backend=backend, attn_route=route,
+                               interpret=not on_tpu,
                                fused_scatter_max_t=fused_cap)
 
     attn_fn = shardings.attn_fn(batch) if shardings is not None else None
@@ -190,16 +198,12 @@ def resolve_kernels(
                 attn_fn = partial(
                     flash_gqa_attention, interpret=not on_tpu,
                     # kv grids bucketed by live-context length — decode steps
-                    # and early prefill chunks alike. RECORDED REASON this
-                    # stays opt-in (VERDICT r4 next #8): exactness is tested
-                    # and the lax.switch is AOT-accepted, but the flip
-                    # criterion is a MEASURED shallow-pos win at S=8192 with
-                    # no deep-pos regression (PLAYBOOK "Bucketed flash grid";
-                    # decide.py prints FLIP/keep from the kbench depth sweep
-                    # + the bench 8b_long A/B) — and no TPU window has ever
-                    # produced those timings. CPU-smoke numbers showed 3.4x
-                    # at pos=8 but CPU interpret timings don't transfer.
+                    # and early prefill chunks alike. Opt-in: exactness is
+                    # tested and the lax.switch compiles for v5e, but the
+                    # flip needs a measured shallow-pos win at S=8192 with no
+                    # deep-pos regression, and no chip run has timed it yet.
                     s_buckets=os.environ.get("DLLAMA_FLASH_BUCKETS") == "1")
 
     return KernelSelection(mm=mm, mm_in=mm_in, attn_fn=attn_fn,
-                           backend=backend, attn_route=route)
+                           backend=backend, attn_route=route,
+                           interpret=not on_tpu)

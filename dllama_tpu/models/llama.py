@@ -86,9 +86,13 @@ class PagedKVCache:
 
     @classmethod
     def create(cls, cfg: LlamaConfig, n_slots: int, n_pages: int,
-               page_size: int, dtype=jnp.bfloat16, max_blocks: int = 0):
+               page_size: int, dtype=jnp.bfloat16, max_blocks: int = 0,
+               lanes: int = 0):
+        """``lanes`` widens the row (minor) dim past head_size — the paged
+        Pallas kernel needs whole 128-lane rows
+        (ops/pallas/paged_attention.pool_lanes); 0 = head_size."""
         shape = (cfg.n_layers, n_pages + 1, cfg.n_kv_heads, page_size,
-                 cfg.head_size)
+                 lanes or cfg.head_size)
         tables = jnp.zeros((n_slots, max_blocks or 1), jnp.int32)
         return cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), tables)
 

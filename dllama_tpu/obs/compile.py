@@ -44,6 +44,8 @@ both into gated, scrapeable contracts:
 * **device-memory gauges** — :func:`refresh_device_gauges` publishes live
   buffer count/bytes (``jax.live_arrays``) alongside the existing
   param/KV gauges at scrape time.
+* **the persistent compile cache's place** — :func:`place_compile_cache`,
+  called by every entry point before anything jits.
 
 Module import is stdlib-only (scripts/checks.sh imports this without jax
 or a model); jax is imported lazily inside the functions that need it.
@@ -52,6 +54,7 @@ or a model); jax is imported lazily inside the functions that need it.
 from __future__ import annotations
 
 import logging
+import os
 import threading
 import time
 from collections import deque
@@ -61,6 +64,40 @@ from dllama_tpu.obs import trace
 from dllama_tpu.utils import locks
 
 log = logging.getLogger("dllama_tpu.obs")
+
+#: Where JAX's persistent compilation cache lives when the caller's
+#: environment does not place it: ONE fixed, git-ignored path inside the
+#: checkout. The path is part of the cache key, so it is never derived from
+#: a temp name, pid or time — a directory that moves never hits.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    "experiments", "jax_cache")
+
+
+def place_compile_cache() -> str:
+    """Turn the persistent compile cache on for this process and return
+    its directory. Every entry point calls this before anything jits
+    (cli/main.py, bench.py, chip_smoke.py's children, experiments/*bench.py),
+    so a server's boot, a bench and a smoke share one cache: the compiled
+    shape universe (ROADMAP Speed #5) is paid for once per machine, not
+    once per process.
+
+    Placed from OUTSIDE when ``JAX_COMPILATION_CACHE_DIR`` is set: jax reads
+    that variable into its own config, and this function then sets no
+    directory (and never the variable). Otherwise :data:`COMPILE_CACHE_DIR`.
+    Either way the minimum compile time for an entry drops from jax's 1 s
+    to 0 — the small decode-bucket and boundary programs are most of the
+    universe by count."""
+    import jax
+
+    cache_dir = jax.config.jax_compilation_cache_dir
+    if not cache_dir:
+        cache_dir = COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    log.info("compile cache: %s", cache_dir)
+    return cache_dir
+
 
 #: ledger `fn` labels the engine dispatch sites bill compiles to — the
 #: single definition site for the README "Shape-bucket contract" table
@@ -578,8 +615,10 @@ def h2d_guard(mode: str):
 
 def refresh_device_gauges() -> dict:
     """Publish live device-buffer count/bytes (jax.live_arrays) — called
-    at scrape time like the process self-metrics, never on the hot path.
-    Answers {'buffers': None, 'bytes': None} where jax is unavailable."""
+    at scrape time like the process self-metrics, never on the hot path —
+    plus the first device's high-water mark where the backend keeps one
+    (``memory_stats()`` is None on CPU). Answers {'buffers': None,
+    'bytes': None} where jax is unavailable."""
     try:
         import jax
 
@@ -595,7 +634,9 @@ def refresh_device_gauges() -> dict:
         return {"buffers": None, "bytes": None}
     ins.DEVICE_LIVE_BUFFERS.set(n)
     ins.DEVICE_LIVE_BYTES.set(total)
-    return {"buffers": n, "bytes": total}
+    stats = jax.devices()[0].memory_stats() or {}
+    return {"buffers": n, "bytes": total,
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use")}
 
 
 _UNSET = object()

@@ -216,11 +216,14 @@ ROUTER_FAILOVERS = metrics.counter(
 BUILD_INFO = metrics.gauge(
     "dllama_tpu_build_info",
     "Always 1; the labels carry what is running — package version, jax "
-    "version, jax backend platform, whether the overlapped decode "
-    "pipeline is active (on/off, or n/a on the single-engine tier), and "
-    "the boot warmup mode (auto = the compiled-shape universe was "
-    "precompiled before traffic; off; n/a on the single-engine tier)",
-    ("version", "jax", "backend", "overlap", "warmup"))
+    "version, the device as jax reports it (platform, device_kind, "
+    "count), the resolved kernel route (matmul backend/attention route), "
+    "whether the overlapped decode pipeline is active (on/off, or n/a on "
+    "the single-engine tier), and the boot warmup mode (auto = the "
+    "compiled-shape universe was precompiled before traffic; off; n/a on "
+    "the single-engine tier)",
+    ("version", "jax", "backend", "device_kind", "device_count", "kernels",
+     "overlap", "warmup"))
 QUEUE_DEPTH = metrics.gauge(
     "dllama_queue_depth", "Requests waiting in the admission queue")
 BUSY_SLOTS = metrics.gauge(

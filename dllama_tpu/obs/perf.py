@@ -27,7 +27,8 @@ ROADMAP's SLO-aware scheduling and any honest bench trajectory consume:
   ``batched_step_bytes`` delegates here; one definition site, so the live
   gauges and the offline roofline tables cannot drift). The live side
   prices each consumed decode chunk and divides by its measured device
-  window to export bandwidth attainment against the v5e HBM roofline.
+  window to export bandwidth attainment against the serving device's HBM
+  roofline (:data:`PEAK_HBM_GBS`, keyed by device kind).
 * :class:`SloPolicy` / :class:`PerfAggregator` — configurable TTFT/ITL SLO
   targets (``--slo-ttft-ms`` / ``--slo-itl-ms``), burn counters
   (``dllama_slo_violations_total{kind}``), a windowed attainment gauge,
@@ -49,10 +50,21 @@ from dataclasses import dataclass
 from dllama_tpu.obs import instruments as ins
 from dllama_tpu.utils import locks
 
-#: v5e HBM bandwidth (public spec), the same constant
-#: experiments/hbm_traffic.py prices its offline rooflines against — the
-#: live bandwidth-attainment gauge divides achieved bytes/s by this
-PEAK_HBM_GBS = 819.0
+#: Peak HBM bandwidth in GB/s, keyed by ``jax.devices()[0].device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" (819 GB/s per chip) — the
+#: same figure experiments/hbm_traffic.py prices its offline rooflines
+#: against. The live bandwidth-attainment gauge divides achieved bytes/s by
+#: the serving device's entry; a device that is not listed is UNPRICED (no
+#: attainment is exported for it), never defaulted to another chip's peak.
+PEAK_HBM_GBS = {
+    "TPU v5 lite": 819.0,
+}
+
+
+def peak_hbm_gbs(device_kind: str) -> float | None:
+    """The device's peak HBM GB/s from :data:`PEAK_HBM_GBS`, or None when
+    the table does not know it (e.g. the CPU backend the tests run on)."""
+    return PEAK_HBM_GBS.get(device_kind)
 
 #: the exclusive states of the scheduler worker loop — the label set of
 #: dllama_scheduler_time_seconds_total{state} and the README ledger table
@@ -597,10 +609,11 @@ class PerfAggregator:
     def __init__(self, slo: SloPolicy | None = None,
                  cost_model: ChunkCostModel | None = None,
                  window_s: float = 60.0, slices: int = 6,
-                 peak_gbs: float = PEAK_HBM_GBS, now_fn=time.monotonic):
+                 peak_gbs: float | None = None, now_fn=time.monotonic):
         self.slo = slo or SloPolicy()
         self.cost_model = cost_model
-        self.peak_gbs = float(peak_gbs)
+        # peak_hbm_gbs(device_kind) of the serving device; None = unpriced
+        self.peak_gbs = peak_gbs
         mk = lambda: WindowQuantiles(window_s, slices, now_fn=now_fn)
         self.ttft = mk()   # seconds
         self.itl = mk()    # seconds
@@ -685,27 +698,32 @@ class PerfAggregator:
         span = self.flow.span_s()
         device_s = c.get("device_s", 0.0)
         by = c.get("bytes", 0.0)
-        # unpriced (no cost model) or unmeasured windows answer None, not a
-        # false "0.0 attainment"
-        achieved = (by / device_s) if (device_s > 0 and by > 0) else None
-        att = (achieved / (self.peak_gbs * 1e9)
-               if achieved is not None else None)
+        # priced = a cost model AND a known peak for this device. Unpriced
+        # windows carry no rate at all (peak_gbs / bandwidth_attainment are
+        # ABSENT, not defaulted); a priced but unmeasured window answers
+        # None, not a false "0.0 attainment"
+        priced = self.cost_model is not None and self.peak_gbs is not None
+        achieved = ((by / device_s)
+                    if (priced and device_s > 0 and by > 0) else None)
         thr = f.get("tokens", 0.0) / span
         good = f.get("good_tokens", 0.0) / span
-        return {
-            "priced": self.cost_model is not None,
+        out = {
+            "priced": priced,
             "window_chunks": int(c.get("chunks", 0.0)),
             "chunk_tokens": int(c.get("chunk_tokens", 0.0)),
             "device_s": round(device_s, 6),
             "bytes": int(by),
             "achieved_gbs": (None if achieved is None
                              else round(achieved / 1e9, 3)),
-            "peak_gbs": self.peak_gbs,
-            "bandwidth_attainment": (None if att is None
-                                     else round(att, 6)),
             "throughput_tok_s": round(thr, 3),
             "goodput_tok_s": round(good, 3),
         }
+        if priced:
+            out["peak_gbs"] = self.peak_gbs
+            out["bandwidth_attainment"] = (
+                None if achieved is None
+                else round(achieved / (self.peak_gbs * 1e9), 6))
+        return out
 
     def refresh_gauges(self) -> None:
         """Push the windowed views into the registry gauges — called at
@@ -724,8 +742,9 @@ class PerfAggregator:
         att = slo["attainment"]
         ins.SLO_ATTAINMENT.set(nan if att is None else att)
         roof = self.roofline_snapshot()
-        bw = roof["bandwidth_attainment"]
-        ins.BW_ATTAINMENT.set(nan if bw is None else bw)
+        if roof["priced"]:  # an unpriced device exports no attainment sample
+            bw = roof["bandwidth_attainment"]
+            ins.BW_ATTAINMENT.set(nan if bw is None else bw)
         ins.THROUGHPUT.set(roof["throughput_tok_s"])
         ins.GOODPUT.set(roof["goodput_tok_s"])
 

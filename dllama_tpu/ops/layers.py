@@ -28,10 +28,11 @@ RMS_NORM_IMPL = "jnp"
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     """y = x * w / rms(x) with f32 accumulation (nn-cpu-ops.cpp:108-183)."""
     if RMS_NORM_IMPL == "pallas":
+        from dllama_tpu.ops.matmul import device_platform
         from dllama_tpu.ops.pallas.rms_norm import rms_norm as pallas_rms_norm
 
         return pallas_rms_norm(x, weight, eps,
-                               interpret=jax.devices()[0].platform != "tpu")
+                               interpret=device_platform() != "tpu")
     xf = x.astype(jnp.float32)
     inv = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (xf * inv * weight.astype(jnp.float32)).astype(x.dtype)

@@ -39,18 +39,15 @@ BACKEND = "auto"
 # bench.py overrides via BENCH_XLA_PREFILL_M to A/B it on hardware.
 XLA_PREFILL_MIN_M: int | None = None
 
-# Pallas interpret-mode override: None = auto (interpret off-TPU, the normal
-# rule). experiments/aot_check.py sets False while AOT-compiling for a TPU
-# topology from a CPU host — the platform check would otherwise bake
-# interpret=True into the trace and Mosaic would never see the kernel.
-INTERPRET: bool | None = None
 
-
-def _platform() -> str:
-    try:
-        return jax.devices()[0].platform
-    except RuntimeError:
-        return "cpu"
+def device_platform() -> str:
+    """Platform of the device the kernels will run on — the ONE place the
+    package asks. A backend that fails to initialise raises here instead of
+    reading as "cpu" (which would silently turn kernels=auto into the XLA
+    dequant path). Compile-only rehearsals for a described chip (no device
+    attached: experiments/aot_check.py, tests/test_chip_compile.py) steer
+    every platform-derived choice by replacing this function."""
+    return jax.devices()[0].platform
 
 
 def resolve_backend(backend: str | None = None, sharded: bool = False) -> str:
@@ -60,7 +57,7 @@ def resolve_backend(backend: str | None = None, sharded: bool = False) -> str:
     if b == "auto":
         if sharded:
             return "xla"
-        return "pallas" if _platform() == "tpu" else "xla"
+        return "pallas" if device_platform() == "tpu" else "xla"
     return b
 
 
@@ -112,8 +109,8 @@ def matmul(x: jax.Array, w, layer=None, backend: str | None = None) -> jax.Array
                 from dllama_tpu.ops.pallas.q80_matmul import supported
 
             if supported(x.shape, w) and not _route_xla_prefill(x):
-                interp = INTERPRET if INTERPRET is not None else _platform() != "tpu"
-                return kernel(x, w, layer, interpret=interp)
+                return kernel(x, w, layer,
+                              interpret=device_platform() != "tpu")
         if layer is not None and len(w.shape) == 3:
             w = slice_leaf(w, layer)
         wd = w.dequantize(x.dtype)

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dllama_tpu.ops.pallas.tiling import COMPILER_PARAMS, pick_tile as _pick_tile
+from dllama_tpu.ops.pallas.tiling import pick_tile as _pick_tile
 
 _NEG_INF = -1e30  # large-finite: keeps fully-masked tiles NaN-free
 
@@ -143,7 +143,7 @@ def _flash_folded(q, k, v, pos, *, group: int, hkv: int, interpret: bool,
                           hkv=hkv, group=group, rows_live=rows_live),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bhkv, rows, hd), jnp.float32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -313,7 +313,7 @@ def _flash_paged_folded(q, k_pool, v_pool, pos, tables, *, group: int,
                           ts=ts, hkv=hkv, group=group, rows_live=rows_live),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bhkv, rows, hd), jnp.float32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dllama_tpu.ops.pallas.tiling import COMPILER_PARAMS, pick_tile as _pick_tile
+from dllama_tpu.ops.pallas.tiling import pick_tile as _pick_tile
 
 _NEG_INF = -1e30  # large-finite: keeps fully-masked pages NaN-free
 
@@ -57,6 +57,20 @@ FUSED_SCATTER_MAX_T = 16
 #: live at once). Pages above it route to the gather fallback instead of
 #: risking a Mosaic VMEM overflow at compile time.
 _PAGE_VMEM_BYTES = 4 * 1024 * 1024
+
+_LANES = 128  # TPU vector lane count: the minor-dim tile of every memref
+
+
+def pool_lanes(head_size: int) -> int:
+    """Minor-dim width of a page pool this kernel can DMA-walk: the head
+    size rounded up to whole 128-lane rows. Mosaic slices an HBM memref only
+    at tile granularity, so a [P, Hkv, page, 64] pool (Llama-3.2-1B's
+    head_size) is refused at compile time ("Slice shape along dimension 3
+    must be aligned to tiling (128), but is 64") and XLA would hold it
+    page-minor, a whole-pool relayout away from what a Mosaic call takes.
+    Engines on the kernel route allocate their pool this wide; the pad
+    lanes stay zero (zero q lanes score 0, zero v lanes are sliced off)."""
+    return -(-head_size // _LANES) * _LANES
 
 
 def paged_decode_supported(q_shape: tuple[int, ...], page_size: int,
@@ -75,7 +89,8 @@ def paged_decode_supported(q_shape: tuple[int, ...], page_size: int,
       flash path now hits in the AOT gate, a libtpu-level pre-existing
       condition, so paged matches dense f8 behavior rather than extending
       the breakage);
-    * double-buffering two (k, v) page pairs must fit the VMEM budget.
+    * double-buffering two (k, v) page pairs — at the pool's lane-padded
+      row width (:func:`pool_lanes`) — must fit the VMEM budget.
 
     Ragged tables need no capability: unallocated entries are clamped to
     the last live page by the kernel and masked by position, so any
@@ -88,7 +103,7 @@ def paged_decode_supported(q_shape: tuple[int, ...], page_size: int,
         and page_size % 8 == 0
         and hd >= 8
         and el in (2, 4)
-        and 4 * page_size * hd * el <= _PAGE_VMEM_BYTES
+        and 4 * page_size * pool_lanes(hd) * el <= _PAGE_VMEM_BYTES
     )
 
 
@@ -208,10 +223,10 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
 
 
 @functools.partial(jax.jit, static_argnames=("group", "interpret",
-                                             "rows_live", "fused"))
+                                             "rows_live", "fused", "scale"))
 def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
                   new_v, *, group: int, interpret: bool, rows_live: int,
-                  fused: bool):
+                  fused: bool, scale: float):
     """qf[B, Hkv, rows_pad, hd] x pool[P, Hkv, page, hd] ->
     (out f32 [B, Hkv, rows_pad, hd], k_pool, v_pool).
 
@@ -224,7 +239,7 @@ def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
     t = new_k.shape[2]
     tq = _pick_tile(rows, (128, 64, 32, 16, 8))
     grid = (b, hkv, rows // tq)
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+    any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # pos[B], tables[B, nb], wpages/woffs[B, t]
@@ -252,7 +267,7 @@ def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
         ],
     )
     out, k_pool, v_pool = pl.pallas_call(
-        functools.partial(_kernel, scale=1.0 / math.sqrt(hd), page=page,
+        functools.partial(_kernel, scale=scale, page=page,
                           group=group, t=t, tq=tq, rows_live=rows_live,
                           nb=nb, fused=fused),
         grid_spec=grid_spec,
@@ -264,7 +279,7 @@ def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
         # after the 4 scalar-prefetch args: qf=4, newk=5, newv=6, kpool=7,
         # vpool=8; the pools alias outputs 1 and 2 (in-place update)
         input_output_aliases={7: 1, 8: 2},
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -280,7 +295,7 @@ def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
 
 def paged_decode_attention(
     q: jax.Array,  # [B, T, Hq, hd]
-    k_pool: jax.Array,  # [P, Hkv, page, hd] (one layer's pool slice)
+    k_pool: jax.Array,  # [P, Hkv, page, hd or pool_lanes(hd)] (one layer)
     v_pool: jax.Array,
     tables: jax.Array,  # i32 [B, max_blocks]
     pos_base: jax.Array,  # i32 scalar or [B] per-row positions
@@ -302,12 +317,18 @@ def paged_decode_attention(
     scatter via XLA before the launch instead (identical result; prefill
     chunks should not serialize per-row DMAs)."""
     b, t, hq, hd = q.shape
-    n_pool, hkv, page, _ = k_pool.shape
+    n_pool, hkv, page, lanes = k_pool.shape
     group = hq // hkv
+    if lanes != hd:
+        # lane-padded pool (pool_lanes): zero-pad the head dim of every row
+        # that meets it — scores and outputs are unchanged (exact zeros)
+        pad_hd = lambda x: None if x is None else jnp.pad(
+            x, ((0, 0),) * 3 + ((0, lanes - hd),))
+        q, new_k, new_v = pad_hd(q), pad_hd(new_k), pad_hd(new_v)
     qf = (
-        q.reshape(b, t, hkv, group, hd)
+        q.reshape(b, t, hkv, group, lanes)
         .transpose(0, 2, 1, 3, 4)
-        .reshape(b, hkv, t * group, hd)
+        .reshape(b, hkv, t * group, lanes)
     )
     rows = t * group
     pad = (-rows) % 8
@@ -338,19 +359,18 @@ def paged_decode_attention(
         # the kernel skips the scatter entirely (fused=False)
         wpages = jnp.zeros((b, 1), jnp.int32)
         woffs = jnp.zeros((b, 1), jnp.int32)
-        nk = jnp.zeros((b, hkv, 1, hd), k_pool.dtype)
-        nv = jnp.zeros((b, hkv, 1, hd), v_pool.dtype)
+        nk = jnp.zeros((b, hkv, 1, lanes), k_pool.dtype)
+        nv = jnp.zeros((b, hkv, 1, lanes), v_pool.dtype)
     else:
         nk = new_k.astype(k_pool.dtype)
         nv = new_v.astype(v_pool.dtype)
 
     out, k_pool, v_pool = _paged_folded(
         qf, k_pool, v_pool, pos, tables, wpages, woffs, nk, nv,
-        group=group, interpret=interpret, rows_live=rows, fused=write)
-    if pad:
-        out = out[:, :, :rows]
+        group=group, interpret=interpret, rows_live=rows, fused=write,
+        scale=1.0 / math.sqrt(hd))
     out = (
-        out.reshape(b, hkv, t, group, hd)
+        out[:, :, :rows, :hd].reshape(b, hkv, t, group, hd)
         .transpose(0, 2, 1, 3, 4)
         .reshape(b, t, hq, hd)
         .astype(q.dtype)

@@ -54,7 +54,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dllama_tpu.ops.pallas.tiling import COMPILER_PARAMS, pick_tile as _pick_tile
+from dllama_tpu.ops.pallas.tiling import pick_tile as _pick_tile
 from dllama_tpu.ops.quant import Q_BLOCK, QTensor
 
 # f32 bit pattern of 2^23 = 8388608.0; mantissa ulp there is exactly 1, so
@@ -190,7 +190,7 @@ def _deq_call(layer, x, packed, scales, *, interpret: bool = False):
         functools.partial(_deq_kernel, tk=tk, tn=tn),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -284,7 +284,7 @@ def _loopdot_call(layer, x, packed, scales, *, interpret: bool = False):
         functools.partial(_loopdot_kernel, tk=tk, tn=tn),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -319,7 +319,7 @@ def _maskdot_call(layer, x, packed, scales, *, interpret: bool = False):
         functools.partial(_maskdot_kernel, tk=tk, tn=tn),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -360,7 +360,7 @@ def _blockdot_call(layer, x, packed, scales, *, interpret: bool = False,
         functools.partial(_blockdot_kernel, tk=tk, tn=tn),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

@@ -22,7 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dllama_tpu.ops.pallas.q40_matmul import _scales_f32
-from dllama_tpu.ops.pallas.tiling import COMPILER_PARAMS, pick_tile as _pick_tile
+from dllama_tpu.ops.pallas.tiling import pick_tile as _pick_tile
 from dllama_tpu.ops.quant import Q_BLOCK, Q8Tensor
 
 
@@ -88,7 +88,7 @@ def _deq_call(layer, x, codes, scales, *, interpret: bool = False):
         functools.partial(_deq_kernel, tk=tk, tn=tn),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -126,7 +126,7 @@ def _blockdot_call(layer, x, codes, scales, *, interpret: bool = False):
         functools.partial(_blockdot_kernel, tk=tk, tn=tn),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

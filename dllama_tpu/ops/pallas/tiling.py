@@ -2,14 +2,6 @@
 
 from __future__ import annotations
 
-from jax.experimental.pallas import tpu as pltpu
-
-#: jax-version compat: the TPU compiler-params dataclass is
-#: ``pltpu.CompilerParams`` on newer jax and ``pltpu.TPUCompilerParams`` on
-#: older releases (e.g. 0.4.x). Every kernel constructs it through this
-#: alias so one jax pin change cannot strand the whole Pallas tier.
-COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def pick_tile(dim: int, candidates: tuple[int, ...]) -> int:
     """Largest candidate that divides `dim`, else `dim` itself (one tile)."""

@@ -1,26 +1,2 @@
 """Parallelism tier: mesh/sharding specs, collectives, ring attention,
-pipeline and multi-host glue.
-
-`shard_map` below is the jax-version compat accessor: newer jax exposes it
-as ``jax.shard_map``; 0.4.x only has ``jax.experimental.shard_map``. Every
-call site in this package imports it from here so one jax pin change cannot
-strand the whole mesh tier (same pattern as ops/pallas/tiling.COMPILER_PARAMS).
-"""
-
-import jax
-
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # jax < 0.5
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None, **kw):
-        """Adapt the modern keyword surface to 0.4.x's experimental one:
-        ``axis_names`` (the MANUAL axes) becomes its complement ``auto``,
-        and ``check_vma`` was called ``check_rep``."""
-        if axis_names is not None:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if check_vma is not None:
-            kw["check_rep"] = bool(check_vma)
-        return _exp_shard_map(f, mesh, in_specs, out_specs, **kw)
+pipeline and multi-host glue."""

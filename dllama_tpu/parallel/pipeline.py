@@ -35,8 +35,6 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-
-from dllama_tpu.parallel import shard_map as _shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -76,7 +74,7 @@ def make_pp_forward(cfg: LlamaConfig, mesh: Mesh, n_micro: int = 1, attn_fn=None
         rope = jax.lax.dynamic_slice_in_dim(rope_cache, pos, t, axis=0)
 
         @partial(
-            _shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(
                 jax.tree.map(lambda _: P(), params["embedding"]),

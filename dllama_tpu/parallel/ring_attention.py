@@ -27,16 +27,6 @@ import math
 from functools import partial
 
 import jax
-
-from dllama_tpu.parallel import shard_map as _shard_map
-
-
-def _axis_size(axis_name):
-    """jax-version compat: jax.lax.axis_size is missing on 0.4.x —
-    psum(1) over the axis is the portable spelling of its size."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -94,7 +84,7 @@ def ring_attention(
     b, tl, hq, d = q.shape
     hkv, sl = k.shape[1], k.shape[2]
     g = hq // hkv
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     scale = 1.0 / math.sqrt(d)
 
@@ -180,7 +170,7 @@ def ring_cache_attention(
     b, tl, hq, d = q.shape
     hkv, sl = k_cache.shape[1], k_cache.shape[2]
     g = hq // hkv
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     scale = 1.0 / math.sqrt(d)
 
@@ -224,14 +214,14 @@ def make_sp_attention(mesh, cache_batch_spec=None):
     def attn(q, k_cache, v_cache, pos_base):
         t = q.shape[1]  # static under jit
         if t > 1 and t % sp == 0:
-            return _shard_map(
+            return jax.shard_map(
                 partial(ring_cache_attention, axis_name="sp"),
                 mesh=mesh,
                 in_specs=(P(dp, "sp", "tp", None), P(dp, "tp", "sp", None),
                           P(dp, "tp", "sp", None), P()),
                 out_specs=P(dp, "sp", "tp", None),
             )(q, k_cache, v_cache, pos_base)
-        return _shard_map(
+        return jax.shard_map(
             partial(sp_cache_attention, axis_name="sp"),
             mesh=mesh,
             in_specs=(P(dp, None, "tp", None), P(dp, "tp", "sp", None), P(dp, "tp", "sp", None), P()),

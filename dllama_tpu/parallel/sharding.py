@@ -14,8 +14,6 @@ nn-network.cpp:521-554) becomes a reduce-scatter/all-gather pair on ICI.
 from __future__ import annotations
 
 import jax
-
-from dllama_tpu.parallel import shard_map as _shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -281,12 +279,12 @@ class LlamaShardings:
                     return jax.lax.psum(out, "tp") if reduce_over_tp else out
 
                 if nd == 3:  # layer-stacked weight: the layer index rides along
-                    fn = _shard_map(
+                    fn = jax.shard_map(
                         body, mesh=mesh, in_specs=(x_spec, wspec_t, P()),
                         out_specs=out_spec, check_vma=False,
                     )
                     return fn(x, w, jnp.asarray(layer, jnp.int32))
-                fn = _shard_map(
+                fn = jax.shard_map(
                     lambda x, w: body(x, w), mesh=mesh,
                     in_specs=(x_spec, wspec_t), out_specs=out_spec, check_vma=False,
                 )
@@ -310,7 +308,7 @@ class LlamaShardings:
             pos_vec = jnp.broadcast_to(
                 jnp.atleast_1d(jnp.asarray(pos_base, jnp.int32)), (b,)
             )
-            fn = _shard_map(
+            fn = jax.shard_map(
                 lambda q, k, v, p: flash_gqa_attention(q, k, v, p, interpret=interpret),
                 mesh=mesh,
                 in_specs=(

@@ -285,15 +285,22 @@ class ApiServer:
         self.model_params_bytes, self.kv_cache_bytes = set_memory_gauges(
             eng.params, eng.cache)
         # build-info gauge (value always 1; the labels ARE the payload): what
-        # exactly is serving — package + jax versions, backend platform, and
-        # whether the overlapped pipeline is live. Also embedded in /health
-        # so a probe answers "what is this replica running" without a scrape.
+        # exactly is serving — package + jax versions, the device as JAX
+        # reports it, the resolved kernel route, and whether the overlapped
+        # pipeline is live. Also embedded in /health so a probe answers
+        # "what is this replica running on" without a scrape.
         import jax
 
+        devices = jax.devices()
         self.build_info = {
             "version": __version__,
             "jax": jax.__version__,
-            "backend": jax.default_backend(),
+            "backend": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": str(len(devices)),
+            # KernelSelection.bucket_tag() of the engine that serves:
+            # 'backend/attn_route', e.g. pallas/paged_kernel
+            "kernels": eng.kernel_route,
             "overlap": ("n/a" if scheduler is None
                         else ("on" if scheduler.overlap else "off")),
             # boot precompile state (ISSUE 13): whether this replica warmed

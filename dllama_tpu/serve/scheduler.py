@@ -423,7 +423,8 @@ class Scheduler:
             slo=perf.SloPolicy(
                 None if slo_ttft_ms is None else float(slo_ttft_ms),
                 None if slo_itl_ms is None else float(slo_itl_ms)),
-            cost_model=cost_model)
+            cost_model=cost_model,
+            peak_gbs=perf.peak_hbm_gbs(jax.devices()[0].device_kind))
         # ---- hybrid chunked prefill (ISSUE 12, --prefill-budget): when a
         # request is admitting WHILE others decode, each device chunk is a
         # FUSED hybrid step (engine.hybrid_dispatch) that co-processes up
@@ -499,7 +500,7 @@ class Scheduler:
         self._thread.start()
         # stall watchdog: marks the server unhealthy when the worker goes
         # silent mid-work for longer than the deadline (a hung device chunk,
-        # a wedged collective). Detection only — there is no safe preemption
+        # a hung collective). Detection only — there is no safe preemption
         # of a dispatched XLA computation; the operator (or the pod
         # supervisor watching /health) owns the restart.
         self.stall_deadline_s = float(stall_deadline_s)
@@ -576,7 +577,7 @@ class Scheduler:
             raise SchedulerUnhealthy(
                 f"scheduler worker is dead ({self.crashed!r}); refusing work")
         if self.stalled:
-            # the watchdog says the worker is wedged mid-chunk: queueing more
+            # the watchdog says the worker is hung mid-chunk: queueing more
             # work would strand more clients. The flag clears if heartbeats
             # resume, and 503+Retry-After tells callers to come back then.
             ins.REQUESTS_SHED.labels(reason="unhealthy").inc()
@@ -623,7 +624,7 @@ class Scheduler:
         """Liveness + readiness snapshot for the API tier's /health.
 
         `live`   — the worker thread can still make progress (alive, not
-                   crashed, not known-wedged): false means restart me.
+                   crashed, not known-hung): false means restart me.
         `ready`  — admit new work here: false while draining, saturated, or
                    not live (balancers should route away, not kill).
         The rest is the observability payload: queue depth, busy slots, and
@@ -802,7 +803,8 @@ class Scheduler:
         # in the p95 for the next minute of a bench leg (same policy and
         # cost model; attribute swap is atomic for concurrent scrapes)
         self.perf = perf.PerfAggregator(slo=self.perf.slo,
-                                        cost_model=self.perf.cost_model)
+                                        cost_model=self.perf.cost_model,
+                                        peak_gbs=self.perf.peak_gbs)
 
     def cancel(self, req: Request, reason: str = "cancelled") -> None:
         """Release a request's slot. `reason` becomes the finish_reason when
@@ -814,7 +816,7 @@ class Scheduler:
         req.cancelled.set()
         self._wake.set()
 
-    #: how long shutdown() waits for the worker before declaring it wedged
+    #: how long shutdown() waits for the worker before declaring it hung
     #: (attribute, not constant: fault drills shrink it instead of sleeping)
     join_timeout_s: float = 10.0
 
@@ -827,7 +829,7 @@ class Scheduler:
         self._wake.set()
         self._thread.join(timeout=self.join_timeout_s)
         if self._thread.is_alive():
-            # a worker that won't die is almost certainly wedged inside a
+            # a worker that won't die is almost certainly hung inside a
             # device call; it is daemonic so the process can still exit, but
             # the engine must be considered unusable — say so loudly and let
             # /health report it instead of silently returning

@@ -1,19 +1,24 @@
 """ctypes bindings for the native host-side components (native/dllama_native.cpp).
 
 Loading order: $DLLAMA_NATIVE_LIB, then the in-repo build
-(native/build/libdllama_native.so), auto-building with `make` on first use if
-the source tree and a compiler are present (set DLLAMA_NATIVE=0 to disable
-everything). All callers must keep a pure-Python fallback — `available()`
-gating is the contract, and tests/test_native.py pins C++ == Python semantics.
+(native/build/libdllama_native.so — never committed: built with `make` from
+native/dllama_native.cpp on first use, so the library that runs is the source
+that is checked out; set DLLAMA_NATIVE=0 to disable everything). All callers
+must keep a pure-Python fallback — `available()` gating is the contract, a
+failed build is logged (not swallowed) when it forces that fallback, and
+tests/test_native.py pins C++ == Python semantics.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 
 import numpy as np
+
+log = logging.getLogger("dllama_tpu")
 
 _REPO_NATIVE = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "native")
 _lib = None
@@ -42,7 +47,12 @@ def _load() -> ctypes.CDLL | None:
                 capture_output=True,
                 timeout=120,
             )
-        except Exception:
+        except (OSError, subprocess.SubprocessError) as e:
+            log.warning(
+                "native library build failed (%r%s); the tokenizer and "
+                "quantizer fall back to pure Python", e,
+                ": " + e.stderr.decode(errors="replace")[-300:]
+                if getattr(e, "stderr", None) else "")
             return None
     for c in candidates:
         if os.path.exists(c):

@@ -5,7 +5,7 @@ Runs plain fused greedy decode and prompt-lookup speculative decode
 tokens/forward and agreement. Synthetic weights — output ids are noise, the
 point is the EXACTNESS (identical streams) and the forward-count accounting.
 
-    env PYTHONPATH= JAX_PLATFORMS=cpu python examples/speculative.py
+    JAX_PLATFORMS=cpu python examples/speculative.py
 """
 
 import os

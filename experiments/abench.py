@@ -46,7 +46,10 @@ def main():
     from dllama_tpu.engine.batch import BatchEngine
     from dllama_tpu.models.config import LlamaConfig
     from dllama_tpu.models.llama import random_params_fast
+    from dllama_tpu.obs.compile import place_compile_cache
     from dllama_tpu.serve.scheduler import Scheduler
+
+    place_compile_cache()
 
     if smoke:
         # ONE protocol with bench.bench_admission (bench.ADMISSION_PROTOCOL):

@@ -47,13 +47,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dllama_tpu.models.config import LlamaConfig
 from dllama_tpu.models.llama import KVCache, forward
+from dllama_tpu.obs.perf import PEAK_HBM_GBS
 from dllama_tpu.ops import matmul as mmod
 from dllama_tpu.ops.matmul import matmul
 from dllama_tpu.ops.pallas import q40_matmul as qmod
 from dllama_tpu.ops.pallas.flash_attention import flash_gqa_attention
 from dllama_tpu.ops.quant import Q_BLOCK, QTensor
 
-V5E_HBM_GBS = 819.0  # v5e HBM bandwidth (public spec) for the roofline line
+# v5e HBM bandwidth for the roofline line — the live gauge's table entry
+V5E_HBM_GBS = PEAK_HBM_GBS["TPU v5 lite"]
 
 PRESETS = {
     # bench.py's synthetic presets (llama-3.2-1b / llama-3.1-8b shapes)
@@ -243,7 +245,10 @@ def compile_step(cfg, topo, *, backend: str, style: str | None, on_cpu=False):
     attn = partial(flash_gqa_attention, interpret=on_cpu)
 
     def step(params, cache, tokens, pos, rope):
-        mmod.INTERPRET = on_cpu
+        # the kernels' interpret= follows the platform; the target here is
+        # a described chip (or the CPU smoke), not what jax.devices() says
+        platform, mmod.device_platform = (
+            mmod.device_platform, lambda: "cpu" if on_cpu else "tpu")
         old_style = qmod.STYLE
         if style is not None:
             qmod.STYLE = style
@@ -254,7 +259,7 @@ def compile_step(cfg, topo, *, backend: str, style: str | None, on_cpu=False):
                                     last_only=True)
             return logits[:, -1], cache
         finally:
-            mmod.INTERPRET = None
+            mmod.device_platform = platform
             qmod.STYLE = old_style
 
     return jax.jit(step).trace(*args).lower().compile()

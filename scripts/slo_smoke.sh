@@ -106,8 +106,10 @@ try:
     assert led["seconds"]["prefill"] > 0, "no prefill time attributed"
 
     roof = doc["roofline"]
-    assert roof["priced"] and roof["window_chunks"] > 0, roof
-    assert roof["bandwidth_attainment"] is not None, roof
+    # this smoke boots on CPU, a device obs/perf.PEAK_HBM_GBS does not
+    # list: chunks are counted, but no rate is priced against a peak
+    assert roof["window_chunks"] > 0 and roof["bytes"] > 0, roof
+    assert roof["priced"] is False and "bandwidth_attainment" not in roof, roof
     assert roof["throughput_tok_s"] >= roof["goodput_tok_s"] >= 0, roof
 
     slo = doc["slo"]
@@ -123,7 +125,7 @@ try:
           f"p50={win['p50']}ms, ledger residual {resid:.4%} "
           f"(decode_wait {led['seconds']['decode_wait']:.3f}s of "
           f"{wall:.3f}s wall), roofline chunks={roof['window_chunks']} "
-          f"attainment={roof['bandwidth_attainment']}, "
+          f"(unpriced on CPU), "
           f"slo attainment={slo['attainment']}")
 finally:
     proc.send_signal(signal.SIGTERM)

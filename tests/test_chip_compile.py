@@ -1,0 +1,81 @@
+"""The serving path's programs, compiled for TPU v5e with no chip attached.
+
+libtpu is installed here, and a described topology lets XLA:TPU + Mosaic
+compile for a chip that is not there. Interpret mode — what every other
+kernel test in this suite runs — cannot show a slice that is not aligned to
+the tiling, a VMEM overflow or a program that does not fit HBM; this can
+(it is how PR 21 found that Mosaic refuses the paged kernel at
+Llama-3.2-1B's head size 64 until the pool rows are whole 128-lane vectors).
+
+The cases are `experiments/aot_check.py`'s own, at Llama-3.2-1B width — the
+width `chip_smoke.py` serves on the chip — so the script, MOSAIC_AOT.md and
+these tests cannot drift apart. Nothing runs: a pass says the chip's
+compiler accepts the program, nothing about results or speed.
+"""
+
+import jax
+import pytest
+
+from dllama_tpu.ops import matmul as mmod
+from experiments import aot_check
+
+#: a subset of aot_check.all_cases() by name: the kernels and whole programs
+#: of `serve --slots 8 --max-seq-len 2048 --spec-k 4` on a 1b model
+CASES = (
+    "q40 decode m=8 w1(2048x8192)",
+    "q40 decode m=8 w2(8192x2048)",
+    "q40 decode m=8 wcls(2048x128256)",
+    "q40 prefill m=256 w1(2048x8192)",
+    "q40 prefill m=256 w2(8192x2048)",
+    "q40 prefill m=256 wcls(2048x128256)",
+    "flash decode t=1 S=2048 hd=64",
+    "flash prefill t=256 S=2048 hd=64",
+    "paged decode t=1 p=128 hd=64 fused scatter",
+    "paged spec verify t=5 p=128 hd=64 fused scatter",
+    "paged decode t=1 p=16 hd=64 fused scatter",
+    "paged decode t=1 p=128 hd=128 fused scatter",
+    "tp=4 shard_map mm in-shard+psum (w2)",
+    "serve 1b paged decode chunk n=4",
+    "serve 1b hybrid step p=64 n=4",
+)
+
+
+@pytest.fixture(scope="module")
+def thunks():
+    """name -> compile thunk, built once: the described topology, the
+    platform steer, and the persistent compile cache off (an entry written
+    for a described chip cannot be read back without one, and warns)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(aot_check.TARGET, platform="tpu")
+    except Exception as e:  # no libtpu / no topology support in this install
+        pytest.skip(f"cannot describe {aot_check.TARGET}: {e!r}"[:200])
+    mp = pytest.MonkeyPatch()
+    # kernels=auto / interpret= derive from the platform; the chip is only
+    # described, so steer the one place the package asks (in the test, not
+    # through an option of the program)
+    mp.setattr(mmod, "device_platform", lambda: "tpu")
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # conftest forces true-f32 dots for the numerics tests; the program
+    # the chip runs traces at the default precision (and Mosaic refuses a
+    # bf16 matmul asked for at fp32 contract precision)
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+    try:
+        yield {name: thunk for name, thunk, _ in aot_check.all_cases(topo)}
+    finally:
+        jax.config.update("jax_default_matmul_precision", precision)
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+        mp.undo()
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_compiles_for_v5e(thunks, name):
+    compiled = thunks[name]()
+    # the chip's compiler saw a Pallas kernel, not an interpret-mode trace
+    assert "tpu_custom_call" in compiled.as_text()

@@ -13,6 +13,7 @@ time-budgeted tier-1 window."""
 import math
 import random
 
+import jax
 import numpy as np
 import pytest
 
@@ -284,12 +285,17 @@ def test_perf_aggregator_goodput_vs_throughput():
     assert win["ttft"]["count"] == 3 and win["ttft"]["p50"] == 50.0
 
 
+def _tiny_cost_model():
+    return perf.ChunkCostModel(n_layers=2, dim=64, hidden_dim=128, kv_dim=32,
+                               head_size=16, n_kv_heads=2, vocab_size=96,
+                               seq_len=64, weight_bytes=1_000_000)
+
+
 def test_aggregator_prices_chunks_against_device_window():
     clk = FakeClock()
-    cm = perf.ChunkCostModel(n_layers=2, dim=64, hidden_dim=128, kv_dim=32,
-                             head_size=16, n_kv_heads=2, vocab_size=96,
-                             seq_len=64, weight_bytes=1_000_000)
-    agg = perf.PerfAggregator(cost_model=cm, now_fn=clk)
+    cm = _tiny_cost_model()
+    peak = perf.peak_hbm_gbs("TPU v5 lite")
+    agg = perf.PerfAggregator(cost_model=cm, peak_gbs=peak, now_fn=clk)
     agg.observe_chunk(occupancy=2, live_rows=10.0, steps=4, tokens=8,
                       device_s=0.25)
     roof = agg.roofline_snapshot()
@@ -298,15 +304,48 @@ def test_aggregator_prices_chunks_against_device_window():
     # snapshot values are display-rounded (3 / 6 places)
     assert roof["achieved_gbs"] == pytest.approx(expect / 0.25 / 1e9,
                                                  abs=5e-4)
+    assert roof["peak_gbs"] == peak
     assert roof["bandwidth_attainment"] == pytest.approx(
-        (expect / 0.25) / (perf.PEAK_HBM_GBS * 1e9), abs=5e-7)
+        (expect / 0.25) / (peak * 1e9), abs=5e-7)
     # no cost model -> unpriced but still counted
-    agg2 = perf.PerfAggregator(now_fn=clk)
+    agg2 = perf.PerfAggregator(peak_gbs=peak, now_fn=clk)
     agg2.observe_chunk(occupancy=2, live_rows=10.0, steps=4, tokens=8,
                        device_s=0.25)
     r2 = agg2.roofline_snapshot()
-    assert r2["priced"] is False and r2["bandwidth_attainment"] is None
+    assert r2["priced"] is False and "bandwidth_attainment" not in r2
     assert r2["window_chunks"] == 1
+
+
+def test_peak_table_knows_v5e_with_its_source():
+    """The one chip this repo runs on is priced at Google Cloud's published
+    "TPU v5e" figure, keyed by the device_kind jax reports for it."""
+    assert perf.peak_hbm_gbs("TPU v5 lite") == 819.0
+    assert perf.PEAK_HBM_GBS == {"TPU v5 lite": 819.0}
+
+
+def test_unknown_device_kind_is_unpriced_never_defaulted():
+    """A device the table does not list (the CPU backend here) exports no
+    peak, no attainment field and no gauge sample — another chip's peak is
+    never used as a default."""
+    assert perf.peak_hbm_gbs(jax.devices()[0].device_kind) is None
+    assert perf.peak_hbm_gbs("TPU v9 imaginary") is None
+    clk = FakeClock()
+    cm = _tiny_cost_model()
+    agg = perf.PerfAggregator(cost_model=cm, peak_gbs=None, now_fn=clk)
+    agg.observe_chunk(occupancy=2, live_rows=10.0, steps=4, tokens=8,
+                      device_s=0.25)
+    roof = agg.roofline_snapshot()
+    assert roof["priced"] is False and roof["achieved_gbs"] is None
+    assert "peak_gbs" not in roof and "bandwidth_attainment" not in roof
+    assert roof["bytes"] > 0 and roof["window_chunks"] == 1  # still counted
+    def rendered():
+        out: list[str] = []
+        ins.BW_ATTAINMENT.render(out)
+        return out
+
+    before = rendered()
+    agg.refresh_gauges()
+    assert rendered() == before  # the gauge gained no sample
 
 
 def test_cost_model_single_definition_site():
@@ -330,7 +369,7 @@ def test_cost_model_single_definition_site():
             paged_impl=impl)
         assert hbm.batched_step_bytes(cfg, slots, live_frac=frac, paged=paged,
                                       paged_impl=impl) == expect
-    assert hbm.V5E_HBM_GBS == perf.PEAK_HBM_GBS
+    assert hbm.V5E_HBM_GBS == perf.peak_hbm_gbs("TPU v5 lite")
     # the two paged routes price DIFFERENT traffic by design: the gather
     # fallback pays the re-materialized seq_len-row view (write + read, k+v,
     # per layer) the kernel route exists to remove
@@ -394,10 +433,12 @@ def test_scheduler_ledger_invariant_real_run():
     assert summary["ttft_ms_p95"] >= summary["ttft_ms_p50"]
     assert summary["itl_ms_p50"] is not None
     # roofline window saw priced chunks (cost model built by the engine)
+    # ... but this host's device (CPU) is not in the peak table, so the
+    # window is counted without a rate against another chip's peak
     roof = sched.perf.roofline_snapshot()
-    assert roof["priced"] and roof["window_chunks"] > 0
+    assert roof["window_chunks"] > 0
     assert roof["bytes"] > 0 and roof["device_s"] > 0
-    assert roof["bandwidth_attainment"] is not None
+    assert roof["priced"] is False and "bandwidth_attainment" not in roof
     # with SLO targets this loose, both requests attained
     slo = sched.perf.slo_snapshot()
     assert slo["attainment"] == 1.0
@@ -480,7 +521,7 @@ def test_perfdiff_missing_and_info_fields_never_gate():
 
 
 def test_perfdiff_accepts_real_bench_wrapper(tmp_path):
-    """End-to-end through main(): the committed BENCH_r05.json self-diffs
+    """End-to-end through main(): the committed BENCH_r02.json self-diffs
     to PASS (exit 0) and a synthetically degraded copy FAILS (exit 1) —
     the scripts/perf_gate.sh acceptance, without the subprocess."""
     import json
@@ -488,7 +529,7 @@ def test_perfdiff_accepts_real_bench_wrapper(tmp_path):
 
     pd = _perfdiff()
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    src = os.path.join(repo, "BENCH_r05.json")
+    src = os.path.join(repo, "BENCH_r02.json")
     assert pd.main([src, src]) == 0
     with open(src, encoding="utf-8") as f:
         doc = json.load(f)
@@ -505,7 +546,10 @@ def test_refresh_gauges_drained_window_sets_nan_not_stale():
     NaN (Prometheus 'no data'), never leave the last value standing — an
     idle server does not still carry its old p95."""
     clk = FakeClock()
-    agg = perf.PerfAggregator(slo=perf.SloPolicy(ttft_ms=100.0), now_fn=clk)
+    agg = perf.PerfAggregator(slo=perf.SloPolicy(ttft_ms=100.0),
+                              cost_model=_tiny_cost_model(),
+                              peak_gbs=perf.peak_hbm_gbs("TPU v5 lite"),
+                              now_fn=clk)
     agg.observe_finish(finish_reason="stop", ttft_ms=50.0, itl_ms=5.0,
                        e2e_ms=100.0, tokens=4)
     agg.refresh_gauges()

@@ -87,7 +87,6 @@ _PARTIAL_MANUAL_REASON = None
 def _partial_manual_axis_index_unusable():
     global _PARTIAL_MANUAL_REASON
     if _PARTIAL_MANUAL_REASON is None:
-        from dllama_tpu.parallel import shard_map as _shard_map
         devs = jax.devices()
         if len(devs) < 4:
             _PARTIAL_MANUAL_REASON = "needs 4 virtual devices"
@@ -97,7 +96,7 @@ def _partial_manual_axis_index_unusable():
         from jax.sharding import PartitionSpec as P
 
         @jax.jit
-        @partial(_shard_map, mesh=mesh, in_specs=(P("pp"),),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P("pp"),),
                  out_specs=P("pp"), axis_names=frozenset({"pp"}),
                  check_vma=False)
         def probe(x):

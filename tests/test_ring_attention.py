@@ -10,8 +10,6 @@ import numpy as np
 import pytest
 
 import jax
-
-from dllama_tpu.parallel import shard_map as _shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -49,7 +47,7 @@ def test_ring_attention_matches_full_causal(rng, sp, hq, hkv):
 
     mesh = make_mesh(MeshConfig(sp=sp))
     got = jax.jit(
-        _shard_map(
+        jax.shard_map(
             lambda q, k, v: ring_attention(q, k, v, axis_name="sp"),
             mesh=mesh,
             in_specs=(P(None, "sp", None, None), P(None, None, "sp", None), P(None, None, "sp", None)),
@@ -73,7 +71,7 @@ def test_ring_attention_non_causal(rng):
 
     mesh = make_mesh(MeshConfig(sp=4))
     got = jax.jit(
-        _shard_map(
+        jax.shard_map(
             lambda q, k, v: ring_attention(q, k, v, axis_name="sp", causal=False),
             mesh=mesh,
             in_specs=(P(None, "sp", None, None), P(None, None, "sp", None), P(None, None, "sp", None)),
@@ -95,7 +93,7 @@ def test_sp_cache_attention_matches_gqa(rng, t, pos):
 
     mesh = make_mesh(MeshConfig(sp=4, tp=2))
     got = jax.jit(
-        _shard_map(
+        jax.shard_map(
             lambda q, kc, vc, p: sp_cache_attention(q, kc, vc, p, axis_name="sp"),
             mesh=mesh,
             in_specs=(P(None, None, "tp", None), P(None, "tp", "sp", None), P(None, "tp", "sp", None), P()),
@@ -142,7 +140,7 @@ def test_ring_cache_attention_matches_gqa(rng, t, pos):
 
     mesh = make_mesh(MeshConfig(sp=4, tp=2))
     got = jax.jit(
-        _shard_map(
+        jax.shard_map(
             lambda q, kc, vc, p: ring_cache_attention(q, kc, vc, p, axis_name="sp"),
             mesh=mesh,
             in_specs=(P(None, "sp", "tp", None), P(None, "tp", "sp", None), P(None, "tp", "sp", None), P()),

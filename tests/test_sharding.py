@@ -9,8 +9,6 @@ import numpy as np
 import pytest
 
 import jax
-
-from dllama_tpu.parallel import shard_map as _shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -85,7 +83,7 @@ def test_q80_all_gather_and_reduce():
 
     @jax.jit
     def gather(x):
-        return _shard_map(
+        return jax.shard_map(
             lambda s: collectives.q80_all_gather(s, "tp"),
             mesh=mesh,
             in_specs=P("tp", None),
@@ -99,7 +97,7 @@ def test_q80_all_gather_and_reduce():
 
     @jax.jit
     def reduce(x):
-        return _shard_map(
+        return jax.shard_map(
             lambda s: collectives.q80_all_reduce(s, "tp"),
             mesh=mesh,
             in_specs=P("tp", None),
