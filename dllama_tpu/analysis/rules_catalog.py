@@ -7,7 +7,9 @@ name in ``obs/trace.{SPAN,EVENT}_CATALOG``, every fault point in
 to those catalogs; these rules close the other half of the loop — CODE
 that registers or emits outside the catalog fails at the callsite with a
 real location (the grep gates this replaces could only say "something,
-somewhere").
+somewhere"). A ``hook("dllama.x." + y)`` call (the
+``obs/trace.PROFILER_HOOK`` callable, always bound to a local named
+``hook``) is a span emission too: its literal head is its catalog name.
 """
 
 from __future__ import annotations
@@ -39,8 +41,11 @@ def _is_metric_factory(call: ast.Call) -> bool:
 
 
 def _is_tracer_call(call: ast.Call, src_rel: str) -> str | None:
-    """'span' | 'event' when the call is a tracer emission."""
+    """'span' | 'event' when the call is a tracer emission (a call of the
+    profiler hook is a span on the profiler's clock)."""
     f = call.func
+    if isinstance(f, ast.Name) and f.id == "hook":
+        return "span"
     if not isinstance(f, ast.Attribute):
         return None
     kind = {"span": "span", "span_at": "span", "event": "event"}.get(f.attr)
@@ -54,6 +59,17 @@ def _is_tracer_call(call: ast.Call, src_rel: str) -> str | None:
         return kind
     if base == "self" and src_rel == "dllama_tpu/obs/trace.py":
         return kind  # the tracer's own catalog-named emissions
+    return None
+
+
+def _emitted_name(call: ast.Call) -> str | None:
+    """The literal name a tracer call emits; of a ``"prefix." + x`` (how a
+    profiler hook call names its span) the constant head."""
+    name = call.args[0] if call.args else None
+    if isinstance(name, ast.BinOp):
+        name = name.left
+    if isinstance(name, ast.Constant) and isinstance(name.value, str):
+        return name.value
     return None
 
 
@@ -72,7 +88,7 @@ def check(project) -> list[Diagnostic]:
                     "drift check) is the single registration site"))
             kind = _is_tracer_call(node, src.rel)
             if kind is not None:
-                name = str_arg(node, 0)
+                name = _emitted_name(node)
                 if name is not None:
                     catalog = SPAN_CATALOG if kind == "span" \
                         else EVENT_CATALOG

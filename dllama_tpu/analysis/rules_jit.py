@@ -9,8 +9,8 @@ catches that only when the path runs; this fails CI at the callsite).
 
 What counts as a cached-jit callable (collected over all engine modules):
 
-* ``self.X = jax.jit(...)`` attribute bindings (and ``@jax.jit``-decorated
-  methods — called as ``self.X(...)``);
+* ``self.X = jax.jit(...)`` / ``self.X = named_jit(fn, ...)`` attribute
+  bindings (and ``@jax.jit``-decorated methods — called as ``self.X(...)``);
 * ``self.X[...] = factory(...)`` where `factory` is an engine function
   whose body returns ``jax.jit(...)`` (the spec-decoder table);
 * ``@jax.jit``-decorated module-level functions, including when imported
@@ -40,8 +40,13 @@ def _is_scope_call(call: ast.Call) -> bool:
                               or d.endswith(".LEDGER.scope"))
 
 
+#: calls that build a cached-jit callable: jax.jit itself, and the engine's
+#: wrapper that jits a program under its launch_record.PROGRAMS name
+_JIT_BUILDERS = ("jax.jit", "named_jit", "launch_record.named_jit")
+
+
 def _is_jax_jit(node: ast.AST) -> bool:
-    return isinstance(node, ast.Call) and dotted(node.func) == "jax.jit"
+    return isinstance(node, ast.Call) and dotted(node.func) in _JIT_BUILDERS
 
 
 def _decorated_jit(fn: ast.FunctionDef) -> bool:

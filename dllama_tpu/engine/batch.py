@@ -36,7 +36,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dllama_tpu.engine import launch_record
 from dllama_tpu.engine.engine import pow2_chunk
+from dllama_tpu.engine.launch_record import named_jit
 from dllama_tpu.engine.sampling import sample_logits
 from dllama_tpu.models.config import LlamaConfig
 from dllama_tpu.models.llama import KVCache, PagedKVCache, forward
@@ -658,6 +660,9 @@ class DecodeChunk:
     hybrid_slot: int = -1  # >= 0: this chunk also carried a fused prefill
     # slice for that (inactive) admitting slot (hybrid_dispatch)
     hybrid_tokens: int = 0  # prompt tokens the fused slice covered
+    launch: launch_record.LaunchRecord | None = None  # the launch's record
+    # (kind, rows, slot-steps): counted at dispatch, a spec chunk's at
+    # consumption; its args ride the chunk's decode.device/decode.spec span
 
     def nonfinite(self) -> np.ndarray | None:
         """bool[B] rows whose logits went non-finite during this chunk
@@ -896,13 +901,18 @@ class BatchEngine:
 
             self._col_fn = make_q80_col_matmul(shardings.mesh)
 
-        self._prefill_step = jax.jit(
+        # every program is jitted under its name from launch_record.PROGRAMS
+        # (the word its LEDGER.scope uses), so a profiler trace's device
+        # plane reads jit_dllama_decode, jit_dllama_hybrid, ...
+        self._prefill_step = named_jit(
+            "prefill_chunk",
             partial(self._prefill_impl, cfg, attn_fn, self._col_fn, mm, mm_in, moe_impl),
             donate_argnums=(1,),
         )
         slot_prefill = (self._prefill_slot_paged_impl if self.pool is not None
                         else self._prefill_slot_impl)
-        self._prefill_slot = jax.jit(
+        self._prefill_slot = named_jit(
+            "prefill_chunk",
             partial(slot_prefill, cfg, attn_fn, self._col_fn, mm, mm_in, moe_impl),
             donate_argnums=(1,),
         )
@@ -915,11 +925,13 @@ class BatchEngine:
         # pages by table construction).
         self._use_slot_prefill = (self.pool is not None or shardings is None
                                   or shardings.mesh.shape["dp"] == 1)
-        self._decode = jax.jit(
+        self._decode = named_jit(
+            "decode",
             partial(self._decode_impl, cfg, attn_fn, self._col_fn, mm, mm_in, moe_impl),
             static_argnums=(8,), donate_argnums=(1,),
         )
-        self._decode_pen = jax.jit(
+        self._decode_pen = named_jit(
+            "decode_pen",
             partial(self._decode_penalized_impl, cfg, attn_fn, self._col_fn, mm,
                     mm_in, moe_impl),
             static_argnums=(8,), donate_argnums=(1, 11),
@@ -928,23 +940,28 @@ class BatchEngine:
         # ONE launch. Same single-slot prefill contract as _prefill_slot, so
         # it needs an unsharded batch axis (dp meshes keep the phase-split
         # path — the scheduler checks supports_hybrid).
-        self._hybrid = jax.jit(
+        self._hybrid = named_jit(
+            "hybrid",
             partial(self._hybrid_impl, cfg, attn_fn, self._col_fn, mm, mm_in,
                     moe_impl),
             static_argnums=(11,), donate_argnums=(1,),
         )
-        self._hybrid_pen = jax.jit(
+        self._hybrid_pen = named_jit(
+            "hybrid_pen",
             partial(self._hybrid_pen_impl, cfg, attn_fn, self._col_fn, mm,
                     mm_in, moe_impl),
             static_argnums=(11,), donate_argnums=(1, 14),
         )
-        self._copy_rows = jax.jit(self._copy_rows_impl, donate_argnums=(0,))
-        self._copy_page = jax.jit(self._copy_page_impl, donate_argnums=(0,))
+        self._copy_rows = named_jit("copy_rows", self._copy_rows_impl,
+                                    donate_argnums=(0,))
+        self._copy_page = named_jit("page_copy", self._copy_page_impl,
+                                    donate_argnums=(0,))
         # host-tier restore upload: write one page's (k, v) host payload
         # into a freshly allocated pool page (the h2d counterpart of the
         # spill's d2h slice; boundary-attributed like the COW clone)
-        self._write_page = jax.jit(self._write_page_impl, donate_argnums=(0,))
-        self._read_page = jax.jit(self._read_page_impl)
+        self._write_page = named_jit("page_restore", self._write_page_impl,
+                                     donate_argnums=(0,))
+        self._read_page = named_jit("page_spill", self._read_page_impl)
 
         # batched speculative decoding (see spec_step): per-slot on-device
         # token history feeds the n-gram proposer; one verify forward per
@@ -979,7 +996,8 @@ class BatchEngine:
                     "pre-scatter via XLA per layer", self.spec_k,
                     self.spec_k + 1, cap)
             self.history = jnp.full((n_slots, self.seq_len + 1), -1, jnp.int32)
-            self._spec_step = jax.jit(
+            self._spec_step = named_jit(
+                "spec",
                 partial(self._spec_step_impl, cfg, attn_fn, self._col_fn, mm,
                         mm_in, moe_impl, self.spec_k, spec_ngram),
                 static_argnums=(12,), donate_argnums=(1, 2),
@@ -987,12 +1005,18 @@ class BatchEngine:
             # penalized traffic rides its own jit (counts in the cycle
             # carry) so penalty-free serving pays nothing — same split as
             # _decode vs _decode_pen
-            self._spec_step_pen = jax.jit(
+            self._spec_step_pen = named_jit(
+                "spec_pen",
                 partial(self._spec_step_pen_impl, cfg, attn_fn, self._col_fn,
                         mm, mm_in, moe_impl, self.spec_k, spec_ngram),
                 static_argnums=(15,), donate_argnums=(1, 2, 12),
             )
-            self._hist_write = jax.jit(self._hist_write_impl, donate_argnums=(0,))
+            self._hist_write = named_jit("hist", self._hist_write_impl,
+                                         donate_argnums=(0,))
+            self._hist_write_batch = named_jit("hist_batch",
+                                               self._hist_write_batch_impl)
+            self._hist_copy_prefix = named_jit("hist_copy",
+                                               self._hist_copy_prefix_impl)
 
         # ---- compile observability (ISSUE 13, obs/compile): the ledger's
         # jax.monitoring listener attributes every trace/compile to the
@@ -1451,8 +1475,7 @@ class BatchEngine:
         return jax.lax.dynamic_update_index_in_dim(history, row, slot, axis=0)
 
     @staticmethod
-    @jax.jit
-    def _hist_write_batch(history, toks, pos_vec, active):
+    def _hist_write_batch_impl(history, toks, pos_vec, active):
         """history[i, pos[i]+1 : pos[i]+1+n] = toks[i] for active slots —
         decode() backfills its emitted tokens so later spec_step drafting
         keeps full n-gram coverage."""
@@ -1462,8 +1485,7 @@ class BatchEngine:
         return jnp.where(active[:, None], upd, history)
 
     @staticmethod
-    @jax.jit
-    def _hist_copy_prefix(history, src, dst, rows):
+    def _hist_copy_prefix_impl(history, src, dst, rows):
         """history[dst, :rows] = history[src, :rows] without per-length
         recompiles (masked full-row copy, mirrors _copy_rows_impl)."""
         s = history.shape[1]
@@ -2115,7 +2137,7 @@ class BatchEngine:
             # the shared prefix's token ids come along so the n-gram
             # proposer can draft from it in the new slot too (masked full-row
             # copy: one compile serves every prefix length)
-            with compile_obs.LEDGER.scope("boundary", "hist"):
+            with compile_obs.LEDGER.scope("boundary", "hist_copy"):
                 self.history = self._hist_copy_prefix(
                     self.history, jnp.int32(src_slot), jnp.int32(dst_slot),
                     jnp.int32(rows))
@@ -2164,6 +2186,9 @@ class BatchEngine:
         t0 = time.perf_counter()
         n, off, slot = len(adm.toks), adm.off, adm.slot
         c = pow2_chunk(n - off, self.max_prefill_chunk)
+        # a prefill chunk runs no decode step: its record is its rows
+        rec = launch_record.LaunchRecord("prefill_chunk", prefill_rows=c)
+        t_disp = time.monotonic()
         if self.spec_k:
             # the n-gram proposer drafts from the prompt too — that's the
             # whole point of prompt lookup
@@ -2182,7 +2207,8 @@ class BatchEngine:
             compile_obs.note_transfer("h2d", "prefill", int(ptoks.nbytes))
             with compile_obs.LEDGER.scope(
                     "prefill_chunk", f"m{c}",
-                    sig=lambda: compile_obs.sig_of(ptoks)):
+                    sig=lambda: compile_obs.sig_of(ptoks)), \
+                    rec.annotation():
                 row, self.cache = self._prefill_slot(
                     self.params, self.cache,
                     ptoks,
@@ -2212,7 +2238,8 @@ class BatchEngine:
                 + int(onehot_dev.nbytes))
             with compile_obs.LEDGER.scope(
                     "prefill_chunk", f"m{c}",
-                    sig=lambda: compile_obs.sig_of(chunk_dev)):
+                    sig=lambda: compile_obs.sig_of(chunk_dev)), \
+                    rec.annotation():
                 logits, self.cache = self._prefill_step(
                     self.params, self.cache,
                     chunk_dev,
@@ -2230,6 +2257,13 @@ class BatchEngine:
         # dispatch cost (still the admission stall they inflict on the host).
         ins.PREFILL_CHUNK_SECONDS.observe(time.perf_counter() - t0)
         ins.PREFILL_TOKENS.inc(c)
+        rec.count()
+        tr = trace.TRACER
+        if tr.enabled:
+            # every launch has the one span shape; this one ends when the
+            # call returned (the scheduler's prefill.chunk holds the sync)
+            tr.span_at("decode.device", t_disp, tr.now(), cat="prefill",
+                       track="launches", req_id=adm.req_id, **rec.args())
         return adm.off >= n
 
     def add_commit(self, adm: "Admission", temperature: float = 0.8,
@@ -2404,6 +2438,29 @@ class BatchEngine:
         compile_obs.note_transfer("h2d", "vectors", nbytes)
         self._vec_dirty = False
 
+    def _penalized(self) -> bool:
+        """Whether some active slot carries a repetition penalty: the
+        launch then rides the program's _pen variant (counts in the
+        carry)."""
+        return self._counts is not None and bool(
+            (self.presence[self.active] != 0).any()
+            or (self.frequency[self.active] != 0).any())
+
+    def _pool_dry(self) -> bool:
+        """No free page in the pool, read as page_starved() reads it but
+        without that method's top-up."""
+        return self.pool is not None and self.pool.free_count == 0
+
+    def _launch_record(self, kind: str, n: int, start_pos, active, advance,
+                       *, prefill_rows: int = 0) -> launch_record.LaunchRecord:
+        """The record of the launch being built (engine/launch_record), from
+        host arrays only; its seq is the number the launch's DecodeChunk is
+        about to take. Counted once the call has returned."""
+        return launch_record.build(
+            kind, self.chunk_seq + 1, n, start_pos, active, advance,
+            seq_len=self.seq_len, pool_dry=self._pool_dry(),
+            prefill_rows=prefill_rows)
+
     def decode_dispatch(self, n: int, spec: bool = False) -> DecodeChunk:
         """Dispatch one fused n-step decode chunk WITHOUT waiting for its
         tokens. The jitted scan threads the device-resident carry (cache,
@@ -2467,27 +2524,26 @@ class BatchEngine:
         # host->device upload into an error — every operand below is a
         # device-resident carry, so a clean engine trips neither.
         guard = compile_obs.h2d_guard(self.transfer_guard)
-        if self._counts is not None and (
-            (self.presence[self.active] != 0).any()
-            or (self.frequency[self.active] != 0).any()
-        ):
-            with compile_obs.LEDGER.scope(
-                    "decode_pen", f"n{n}",
-                    sig=lambda: compile_obs.sig_of(*args[2:])), guard:
-                (toks, self.cache, self._keys_dev, self._pos_dev,
-                 self._last_dev, self._counts, bad) = self._decode_pen(
-                    *args, self._counts, self._pres_dev, self._freq_dev)
-        else:
-            with compile_obs.LEDGER.scope(
-                    "decode", f"n{n}",
-                    sig=lambda: compile_obs.sig_of(*args[2:])), guard:
-                (toks, self.cache, self._keys_dev, self._pos_dev,
-                 self._last_dev, bad) = self._decode(*args)
+        pen = self._penalized()
         start_pos = self.pos.copy()
         active = self.active.copy()
         advance = np.where(
             active, np.clip(limit - start_pos, 0, n), 0
         ).astype(np.int32)
+        rec = self._launch_record("decode_pen" if pen else "decode", n,
+                                  start_pos, active, advance)
+        with compile_obs.LEDGER.scope(
+                rec.kind, f"n{n}",
+                sig=lambda: compile_obs.sig_of(*args[2:])), guard, \
+                rec.annotation():
+            if pen:
+                (toks, self.cache, self._keys_dev, self._pos_dev,
+                 self._last_dev, self._counts, bad) = self._decode_pen(
+                    *args, self._counts, self._pres_dev, self._freq_dev)
+            else:
+                (toks, self.cache, self._keys_dev, self._pos_dev,
+                 self._last_dev, bad) = self._decode(*args)
+        rec.count()
         bad_inject = None
         if faults.flag("decode.nan"):
             # drill the NaN guard without needing genuinely poisoned
@@ -2517,7 +2573,8 @@ class BatchEngine:
         self.chunk_seq += 1
         return DecodeChunk(toks=toks, n=n, start_pos=start_pos, active=active,
                            advance=advance, t0=t0, seq=self.chunk_seq,
-                           t_disp=t_disp, bad=bad, bad_inject=bad_inject)
+                           t_disp=t_disp, bad=bad, bad_inject=bad_inject,
+                           launch=rec)
 
     @property
     def supports_hybrid(self) -> bool:
@@ -2599,33 +2656,33 @@ class BatchEngine:
         # the fused launch itself takes only device-resident operands, so
         # the strict transfer guard holds through hybrid serving too
         guard = compile_obs.h2d_guard(self.transfer_guard)
-        if self._counts is not None and (
-            (self.presence[self.active] != 0).any()
-            or (self.frequency[self.active] != 0).any()
-        ):
-            with compile_obs.LEDGER.scope(
-                    "hybrid_pen", f"p{c}.n{n}",
-                    sig=lambda: compile_obs.sig_of(ptoks, *args[5:])), guard:
+        pen = self._penalized()
+        start_pos = self.pos.copy()
+        active = self.active.copy()
+        advance = np.where(
+            active, np.clip(limit - start_pos, 0, n), 0
+        ).astype(np.int32)
+        rec = self._launch_record("hybrid_pen" if pen else "hybrid", n,
+                                  start_pos, active, advance,
+                                  prefill_rows=c)
+        with compile_obs.LEDGER.scope(
+                rec.kind, f"p{c}.n{n}",
+                sig=lambda: compile_obs.sig_of(ptoks, *args[5:])), guard, \
+                rec.annotation():
+            if pen:
                 (plog, toks, self.cache, self._keys_dev, self._pos_dev,
                  self._last_dev, self._counts, bad) = self._hybrid_pen(
                     *args, self._counts, self._pres_dev, self._freq_dev)
-        else:
-            with compile_obs.LEDGER.scope(
-                    "hybrid", f"p{c}.n{n}",
-                    sig=lambda: compile_obs.sig_of(ptoks, *args[5:])), guard:
+            else:
                 (plog, toks, self.cache, self._keys_dev, self._pos_dev,
                  self._last_dev, bad) = self._hybrid(*args)
+        rec.count()
         adm.logits = plog  # [1, V] — materializes with the chunk
         adm.off += c
-        start_pos = self.pos.copy()
-        active = self.active.copy()
         # the admitting slot's host pos advances with its slice (the device
         # pos carry keeps its stale inactive row — add_commit/resume_commit
         # write it surgically at activation, same contract as add_step)
         self.pos[slot] += c
-        advance = np.where(
-            active, np.clip(limit - start_pos, 0, n), 0
-        ).astype(np.int32)
         bad_inject = None
         if faults.flag("decode.nan"):
             bad_inject = np.zeros(self.n_slots, bool)
@@ -2643,7 +2700,7 @@ class BatchEngine:
         return DecodeChunk(toks=toks, n=n, start_pos=start_pos, active=active,
                            advance=advance, t0=t0, seq=self.chunk_seq,
                            t_disp=t_disp, bad=bad, bad_inject=bad_inject,
-                           hybrid_slot=slot, hybrid_tokens=c)
+                           hybrid_slot=slot, hybrid_tokens=c, launch=rec)
 
     def _spec_dispatch(self, n_cycles: int) -> DecodeChunk:
         """Dispatch one fused spec CHUNK (decode_dispatch's spec=True
@@ -2686,26 +2743,29 @@ class BatchEngine:
             self._limit_dev,
         )
         guard = compile_obs.h2d_guard(self.transfer_guard)
-        if self._counts is not None and (
-            (self.presence[self.active] != 0).any()
-            or (self.frequency[self.active] != 0).any()
-        ):
-            with compile_obs.LEDGER.scope(
-                    "spec_pen", f"n{n_cycles}",
-                    sig=lambda: compile_obs.sig_of(*args[3:])), guard:
+        pen = self._penalized()
+        active = self.active.copy()
+        # the chunk's rows are data-dependent: this record names the launch
+        # (its annotation carries seq/n/active) and holds the pool's state
+        # it was dispatched under; decode_consume rebuilds it from the
+        # materialised counts and counts it there, once
+        rec = launch_record.LaunchRecord(
+            "spec_pen" if pen else "spec", seq=self.chunk_seq + 1,
+            n=n_cycles, active=int(active.sum()), pool_dry=self._pool_dry())
+        with compile_obs.LEDGER.scope(
+                rec.kind, f"n{n_cycles}",
+                sig=lambda: compile_obs.sig_of(*args[3:])), guard, \
+                rec.annotation():
+            if pen:
                 (emits, advs, nxt, self.cache, self.history, self._keys_dev,
                  self._pos_dev, drafts, bad, self._counts) = \
                     self._spec_step_pen(*args, self._counts, self._pres_dev,
                                         self._freq_dev, n_cycles)
-        else:
-            with compile_obs.LEDGER.scope(
-                    "spec", f"n{n_cycles}",
-                    sig=lambda: compile_obs.sig_of(*args[3:])), guard:
+            else:
                 (emits, advs, nxt, self.cache, self.history, self._keys_dev,
                  self._pos_dev, drafts, bad) = self._spec_step(*args, n_cycles)
         self._last_dev = nxt
         self._spec_inflight += 1
-        active = self.active.copy()
         bad_inject = None
         if faults.flag("decode.nan"):
             bad_inject = np.zeros(self.n_slots, bool)
@@ -2720,7 +2780,8 @@ class BatchEngine:
                            advance=np.where(active, 1, 0).astype(np.int32),
                            t0=t0, seq=self.chunk_seq, t_disp=t_disp, bad=bad,
                            bad_inject=bad_inject, spec=True, adv_dev=advs,
-                           drafted_dev=drafts, start_dev=start_dev)
+                           drafted_dev=drafts, start_dev=start_dev,
+                           launch=rec)
 
     def decode_consume(self, chunk: DecodeChunk) -> np.ndarray:
         """Block until the chunk's tokens are on host; fold them into the
@@ -2806,21 +2867,29 @@ class BatchEngine:
             for val, cnt in enumerate(np.bincount(acc[msk])):
                 ins.SPEC_ACCEPTED_LENGTH.observe_n(val, int(cnt))
             ins.BATCH_OCCUPANCY.observe(int((total > 0).sum()))
+            # the launch's record, now that its rows are known: a slot
+            # that emitted nothing was frozen for every cycle
+            chunk.launch = rec = launch_record.build(
+                chunk.launch.kind, chunk.seq, m_cycles, chunk.start_pos,
+                chunk.active, total, seq_len=self.seq_len,
+                pool_dry=chunk.launch.pool_dry,
+                frozen=np.where(total == 0, m_cycles, 0)).count()
             if tr.enabled:
                 tr.span_at("decode.spec", chunk.t_disp, tr.now(),
-                           cat="decode", track="device", chunk=chunk.seq,
+                           cat="decode", track="launches", chunk=chunk.seq,
                            cycles=m_cycles,
                            occupancy=int((total > 0).sum()),
-                           emitted=n_emit, accepted=n_acc)
+                           emitted=n_emit, accepted=n_acc, **rec.args())
             return out
         ins.BATCH_OCCUPANCY.observe(int(chunk.active.sum()))
         if tr.enabled:
-            # the chunk's device-side window: dispatch -> tokens on host.
+            # the launch on the host clock: dispatch -> tokens on host.
             # Under the overlapped pipeline this span brackets the NEXT
             # chunk's dispatch span — the overlap, visible in Perfetto.
             tr.span_at("decode.device", chunk.t_disp, tr.now(),
-                       cat="decode", track="device", chunk=chunk.seq,
-                       n=chunk.n, occupancy=int(chunk.active.sum()))
+                       cat="decode", track="launches", chunk=chunk.seq,
+                       occupancy=int(chunk.active.sum()),
+                       **chunk.launch.args())
         self.last_token[chunk.active] = toks[-1, chunk.active]
         return toks
 

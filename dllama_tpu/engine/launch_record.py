@@ -1,0 +1,134 @@
+"""One launch record per engine launch (ISSUE 26).
+
+A *launch* is one call of a step program of `engine/batch.BatchEngine`: a
+fused n-step decode chunk, a hybrid step (decode chunk + prefill slice), a
+speculative chunk, or an admission's prefill chunk. The record is built
+where the launch is built, from the host arrays the dispatch already holds
+(no device read, no sync), and goes to three places through seams that
+exist:
+
+* the counters `dllama_launches_total{kind}`,
+  `dllama_slot_steps_total{state}`, `dllama_launch_kv_rows_total{kind}`,
+  `dllama_launch_prefill_rows_total{kind}` (always on, O(1) a launch);
+* the args of the launch's span in the tracer ring (`decode.device` /
+  `decode.spec`, track `launches`), behind `tr.enabled`;
+* while a jax.profiler capture runs, a `dllama.launch.<kind>` annotation
+  around the jit call (`obs/trace.PROFILER_HOOK`), on the profiler's clock.
+
+:data:`PROGRAMS` is the ONE table the program names come from: the word a
+program's `compile_obs.LEDGER.scope(fn, key)` uses (for the small boundary
+programs, which share the scope word ``boundary``: the scope's key) -> the
+function name its `jax.jit` is built under, so the device plane of a
+profiler trace reads ``jit_dllama_<fn>`` and a record's `kind` is a key of
+the same table.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass
+
+import jax
+import numpy as np
+
+from dllama_tpu.obs import instruments as ins
+from dllama_tpu.obs import trace
+
+#: the step programs: each call is a launch, and a record's `kind`
+LAUNCH_KINDS = ("decode", "decode_pen", "hybrid", "hybrid_pen",
+                "prefill_chunk", "spec", "spec_pen")
+#: the boundary programs, by the key of their ("boundary", key) scope
+BOUNDARY_PROGRAMS = ("copy_rows", "page_copy", "page_spill", "page_restore",
+                     "hist", "hist_batch", "hist_copy")
+#: fn -> the name the program is jitted under
+PROGRAMS = {fn: f"dllama_{fn}" for fn in LAUNCH_KINDS + BOUNDARY_PROGRAMS}
+
+SLOT_STATES = ("advanced", "starved", "empty")
+for _s in SLOT_STATES:  # the series exist from the first scrape on
+    ins.SLOT_STEPS.labels(state=_s)
+
+
+def named_jit(fn: str, impl, **jit_kw):
+    """`jax.jit(impl)` under the program's name from :data:`PROGRAMS` (a
+    `functools.partial` has no `__name__`: jax would call it `_unknown`).
+    An `fn` the table lacks is a KeyError: no program goes unnamed."""
+    prog = functools.partial(impl)
+    prog.__name__ = prog.__qualname__ = PROGRAMS[fn]
+    return jax.jit(prog, **jit_kw)
+
+
+@dataclass(slots=True)
+class LaunchRecord:
+    """What one launch was asked to do, in rows and slot-steps."""
+
+    kind: str  # a LAUNCH_KINDS word
+    seq: int = 0  # DecodeChunk.seq of the launch's chunk (0: a prefill chunk)
+    n: int = 0  # decode steps (spec: verify cycles) of the launch
+    active: int = 0  # slots that held a request at dispatch
+    advanced: int = 0  # slot-steps that wrote a row
+    starved: int = 0  # slot-steps of active slots frozen by a dry page pool
+    empty: int = 0  # slot-steps of slots without a request
+    kv_rows: int = 0  # KV rows the decode steps attended, over slots and steps
+    prefill_rows: int = 0  # prompt rows the launch wrote
+    pool_dry: bool = False  # no free page in the pool when it was dispatched
+
+    def args(self) -> dict:
+        """The span / annotation arguments (`kind` is in the name too)."""
+        return {"kind": self.kind, "seq": self.seq, "n": self.n,
+                "active": self.active, "starved": self.starved,
+                "kv_rows": self.kv_rows, "prefill_rows": self.prefill_rows}
+
+    def count(self) -> "LaunchRecord":
+        """Into the counters, once per launch, after its call returned (a
+        launch that raised was not made)."""
+        ins.LAUNCHES.labels(kind=self.kind).inc()
+        ins.SLOT_STEPS.labels(state="advanced").inc(self.advanced)
+        ins.SLOT_STEPS.labels(state="empty").inc(self.empty)
+        if self.starved:
+            ins.SLOT_STEPS.labels(state="starved").inc(self.starved)
+        if self.kv_rows:
+            ins.LAUNCH_KV_ROWS.labels(kind=self.kind).inc(self.kv_rows)
+        if self.prefill_rows:
+            ins.LAUNCH_PREFILL_ROWS.labels(kind=self.kind).inc(
+                self.prefill_rows)
+        return self
+
+    def annotation(self):
+        """The launch's profiler annotation, to be entered around the jit
+        call: the shared no-op span unless a capture is running."""
+        hook = trace.PROFILER_HOOK
+        if hook is None:
+            return trace.NULL_SPAN
+        a = self.args()
+        return hook("dllama.launch." + a.pop("kind"), **a)
+
+
+def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
+          active: np.ndarray, advance: np.ndarray, *, seq_len: int,
+          pool_dry: bool, prefill_rows: int = 0,
+          frozen: np.ndarray | None = None) -> LaunchRecord:
+    """The record of a launch of `n` steps over slots at `start_pos`, of
+    which the `active` ones advance `advance` rows each.
+
+    A step at position p attends p + 1 rows, so a slot that advances a rows
+    from p attends a*p + a*(a+1)/2. A slot freezes for one of two reasons:
+    it reached `seq_len`, or its next row has no page; the second, with no
+    free page in the pool (`pool_dry`), is `BatchEngine.page_starved`'s
+    condition and counts its frozen steps as starved. `frozen` gives the
+    frozen steps per slot where they are not n - advance (a spec chunk)."""
+    if kind not in LAUNCH_KINDS:
+        raise ValueError(f"unknown launch kind {kind!r} "
+                         f"(catalog: {LAUNCH_KINDS})")
+    pos = start_pos[active].astype(np.int64)
+    adv = advance[active].astype(np.int64)
+    n_active = int(active.sum())
+    starved = 0
+    if pool_dry:
+        idle = (n - adv) if frozen is None else frozen[active]
+        starved = int(idle[pos + adv < seq_len].sum())
+    return LaunchRecord(
+        kind=kind, seq=int(seq), n=int(n), active=n_active,
+        advanced=int(adv.sum()), starved=starved,
+        empty=(active.size - n_active) * int(n),
+        kv_rows=int((adv * pos + adv * (adv + 1) // 2).sum()),
+        prefill_rows=int(prefill_rows), pool_dry=bool(pool_dry))

@@ -298,6 +298,32 @@ BATCH_OCCUPANCY = metrics.histogram(
     "dllama_batch_occupancy",
     "Active slots per fused decode chunk (mean = _sum/_count)",
     buckets=(1, 2, 4, 8, 16, 32, 64, 128))
+# one launch record per engine launch (ISSUE 26, engine/launch_record):
+# counted where the launch is built, from host arrays the dispatch holds
+LAUNCHES = metrics.counter(
+    "dllama_launches_total",
+    "Engine program launches by kind (decode, hybrid, prefill_chunk, spec "
+    "and their _pen variants): the denominator of every per-launch cost",
+    ("kind",))
+SLOT_STEPS = metrics.counter(
+    "dllama_slot_steps_total",
+    "Decode steps x slots of every launch, by what the slot did: advanced "
+    "(wrote a row and emitted a token), starved (active, below seq_len, "
+    "frozen because the page pool had no free page) or empty (no request "
+    "in the slot). starved/(advanced+starved) is the share of paid-for "
+    "steps the pool's size threw away",
+    ("state",))
+LAUNCH_KV_ROWS = metrics.counter(
+    "dllama_launch_kv_rows_total",
+    "KV rows the launches' decode steps attended, by kind: per active "
+    "slot, advance*start_pos + advance*(advance+1)/2. Over advanced "
+    "slot-steps it is the mean context length on the device",
+    ("kind",))
+LAUNCH_PREFILL_ROWS = metrics.counter(
+    "dllama_launch_prefill_rows_total",
+    "Prompt rows written by the launches, by kind (a hybrid launch's "
+    "slice, a prefill chunk)",
+    ("kind",))
 ADMISSION_STALL_SECONDS = metrics.histogram(
     "dllama_admission_stall_seconds",
     "Decode-to-decode gap inserted by admission work between fused chunks "

@@ -214,6 +214,13 @@ class Counter(_Family):
     def value(self) -> float:
         return self.labels().value()
 
+    def series(self) -> dict:
+        """{label values joined by ',': value} of every child: one
+        consistent read of the family (the profiler-capture snapshots)."""
+        with self._lock:
+            return {",".join(values): child._value
+                    for values, child in self._children.items()}
+
     def _render_child(self, out, values, child) -> None:
         # caller holds self._lock (same lock guards child._value)
         out.append(f"{self.name}{self._label_str(values)} "

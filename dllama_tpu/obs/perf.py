@@ -48,6 +48,7 @@ from collections import deque
 from dataclasses import dataclass
 
 from dllama_tpu.obs import instruments as ins
+from dllama_tpu.obs import trace
 from dllama_tpu.utils import locks
 
 #: Peak HBM bandwidth in GB/s, keyed by ``jax.devices()[0].device_kind``.
@@ -280,11 +281,18 @@ class TimeLedger:
     (and the scrape-path ``poke()``, which bills the open span without
     changing state) may run from API threads — all entry points take the
     lock, and billing stays correct because every moment is attributed to
-    whatever state was current when it passed."""
+    whatever state was current when it passed.
+
+    While a jax.profiler capture runs (``obs/trace.PROFILER_HOOK`` set),
+    every state is also one profiler annotation ``dllama.sched.<state>``,
+    closed and opened at each transition on the worker's own thread: the
+    states tile that thread's line of the capture's host plane, on the
+    clock that stamps the device plane too."""
 
     def __init__(self, counter=None, now_fn=time.monotonic,
                  states=LEDGER_STATES):
         self.states = tuple(states)
+        self._ann = None  # the open state's profiler annotation, if any
         self._counter = counter
         self._now = now_fn
         # _bill() increments the scheduler-time counter while holding this
@@ -322,6 +330,29 @@ class TimeLedger:
                              f"(catalog: {self.states})")
         self._state = state
         self._t = now if state is not None else None
+        self._stamp(state)
+
+    def _stamp(self, state: str | None) -> None:
+        """Close the open profiler annotation and, while a capture runs,
+        open `state`'s (caller holds the lock)."""
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        hook = trace.PROFILER_HOOK
+        if hook is not None and state is not None:
+            self._ann = hook("dllama.sched." + state)
+            self._ann.__enter__()
+
+    def restamp(self) -> None:
+        """Close and reopen the current state's annotation, from any thread
+        (the profiler accepts an annotation closed on another thread than
+        it was opened on). The profiler drops an annotation that is open
+        when it stops and never sees one opened before it started, so a
+        capture calls this as it begins and just before it stops: the state
+        it began in and the state still open at its end are then stamped
+        (a commit can hold the worker in one state for a whole launch)."""
+        with self._lock:
+            self._stamp(self._state)
 
     def transition(self, state: str) -> None:
         with self._lock:

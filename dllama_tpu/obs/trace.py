@@ -17,8 +17,8 @@ metrics registry:
 * a **Chrome trace-event exporter** (:meth:`Tracer.export_chrome`): the
   JSON ``GET /debug/trace`` returns loads directly in Perfetto /
   chrome://tracing, with one named track per subsystem ("scheduler",
-  "device", "requests") so the overlapped decode pipeline is *visible* as
-  interleaved dispatch/consume/device spans.
+  "launches", "requests") so the overlapped decode pipeline is *visible* as
+  interleaved dispatch/consume/launch spans.
 
 Disabled mode (:func:`configure` with capacity 0, CLI ``--trace-buffer 0``)
 swaps in a singleton no-op tracer: ``span()`` returns the same null span
@@ -82,8 +82,8 @@ SPAN_CATALOG = {
     "request": "whole request lifetime: submit -> terminal state (track: requests)",
     "decode.dispatch": "host work to dispatch one fused decode chunk (track: scheduler)",
     "decode.consume": "blocking wait for a dispatched chunk's tokens (track: scheduler)",
-    "decode.device": "chunk dispatch -> tokens materialized: the device-side window (track: device)",
-    "decode.spec": "one batched speculative propose/verify cycle (track: device)",
+    "decode.device": "one engine launch on the HOST clock, dispatch -> its tokens on the host (a prefill chunk: dispatch -> the call returned; its sync is prefill.chunk's); NOT a device time. Args carry the launch record: kind/seq/n/active/starved/kv_rows/prefill_rows (track: launches)",
+    "decode.spec": "one fused speculative propose/verify launch, dispatch -> its counts on the host, with the same launch record plus cycles/emitted/accepted (track: launches)",
     "emit.scan": "post-consume token emit + EOS/budget stop scan (track: scheduler)",
     "compile": "one jit trace/lower/compile attributed to a dispatch site (obs/compile ledger); args carry fn/key/classification — visible in Perfetto as compile stealing device time mid-traffic (track: compile)",
     "proxy": "router: one relay leg of a proxied SSE stream — headers to terminal frame or upstream death; args carry replica/verdict (track: router)",
@@ -92,6 +92,10 @@ SPAN_CATALOG = {
     "failover.attempt": "router: one mid-stream failover attempt — the jittered exponential backoff + survivor pick before a resume dispatch; args carry attempt (track: router)",
     "resume": "router: connect + resume request to a survivor replica, journal replay included; args carry replica/tokens (track: router)",
     "journal": "router: a proxied stream's failover-journal hold window, acquire to release; args carry valid (False = ring-capped, unresumable) + tokens journaled + retries (track: router)",
+    # written onto the PROFILER's clock while a capture runs (PROFILER_HOOK
+    # below), not into the ring; named by prefix
+    "dllama.launch.": "profiler annotation dllama.launch.<kind> around one engine launch's jit call, kind one of engine/launch_record.LAUNCH_KINDS; args seq/n/active/starved/kv_rows/prefill_rows (a spec launch's rows are known only when consumed: its annotation carries seq/n/active)",
+    "dllama.sched.": "profiler annotation dllama.sched.<state>: the scheduler worker's exclusive time-ledger state (obs/perf.LEDGER_STATES), opened and closed at each transition (and restamped at a capture's two ends, TimeLedger.restamp), so the states tile the host plane",
 }
 
 #: instant-event names (``ph: "i"`` in the export), same drift contract
@@ -111,6 +115,16 @@ EVENT_CATALOG = {
     "request.resumed": "a preempted request re-entered a slot and its stream continued (track: requests)",
     "affinity.pick": "router: one routing decision; args carry replica/warm (affinity hit) — the warm-routing record a merged trace shows next to the replica's radix lookups (track: router)",
 }
+
+
+#: the profiler's clock (ISSUE 26). None unless a jax.profiler capture is
+#: running; utils/profiling installs ``jax.profiler.TraceAnnotation`` here
+#: for the length of a capture (this package stays stdlib-only). A call
+#: ``PROFILER_HOOK(name, **args)`` gives a context manager that stamps one
+#: event onto the host plane of the capture's .xplane.pb, whose device plane
+#: the same profiler stamps: no clock arithmetic joins them. Hot paths load
+#: the attribute once and test it for None before building any argument.
+PROFILER_HOOK = None
 
 
 def _clean(v):

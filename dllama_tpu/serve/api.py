@@ -1198,6 +1198,12 @@ class RequestRoutes:
         # process-global): compiles/seconds/unexpected + warmup state; the
         # full dump lives at GET /debug/compile
         payload["compile"] = compile_obs.LEDGER.summary()
+        # what the engine launched inside the last finished profiler
+        # capture (ISSUE 26): launches by kind, slot-steps by state, KV and
+        # prompt rows by kind — the rows behind that trace's device plane
+        from dllama_tpu.utils import profiling
+
+        payload["capture"] = profiling.last_capture()
         self._send_json(200, payload)
 
     def _debug_compile(self) -> None:
@@ -1383,9 +1389,11 @@ class RequestRoutes:
             self._send_json(400, {"error": {"message": "invalid JSON body"}})
             return
         try:
+            sched = self.api.scheduler
             info = profiling.start_profile(
                 log_dir=body.get("dir"),
-                duration_s=body.get("duration_s", 2.0))
+                duration_s=body.get("duration_s", 2.0),
+                restamp=sched.ledger.restamp if sched is not None else None)
         except profiling.ProfileBusy as e:
             self._send_json(409, {"error": {"message": str(e)}},
                             {"Retry-After": "2"})

@@ -47,6 +47,27 @@ _prof_lock = locks.make_lock("utils.profiling")
 _prof_state = {"active": False, "dir": None, "started_at": 0.0,
                "duration_s": None}
 
+#: the launch counters (obs/instruments, fed by engine/launch_record) a
+#: capture is bracketed with, by the key /debug/perf's `capture` block uses
+_CAPTURE_COUNTERS = {"launches": ins.LAUNCHES, "slot_steps": ins.SLOT_STEPS,
+                     "kv_rows": ins.LAUNCH_KV_ROWS,
+                     "prefill_rows": ins.LAUNCH_PREFILL_ROWS}
+_capture = {"begin": None, "t_begin": 0.0, "last": None}
+
+
+def _launch_counters() -> dict:
+    return {k: fam.series() for k, fam in _CAPTURE_COUNTERS.items()}
+
+
+def last_capture() -> dict | None:
+    """What the engine launched during the last FINISHED capture, as
+    counter deltas: {"launches": {kind: n}, "slot_steps": {state: n},
+    "kv_rows": {kind: n}, "prefill_rows": {kind: n}, "seconds": s}: the
+    exact rows of the launches a reader of that trace is looking at
+    (`/debug/perf`'s `capture` block). None before the first capture."""
+    with _prof_lock:
+        return _capture["last"]
+
 #: hard cap on an on-demand capture: profiles are heavy (host callbacks +
 #: trace buffers); a forgotten long capture must not degrade serving forever
 MAX_PROFILE_SECONDS = 60.0
@@ -59,9 +80,20 @@ def _profiler_begin(log_dir: str, duration_s: float | None = None) -> None:
                 f"a profiler capture is already running "
                 f"(dir={_prof_state['dir']!r}, started "
                 f"{time.time() - _prof_state['started_at']:.1f}s ago)")
-        jax.profiler.start_trace(log_dir)
+        # the profiler's clock is shared from here to _profiler_end, and
+        # only then: launches and scheduler states become annotations on
+        # the capture's host plane (obs/trace.PROFILER_HOOK). Installed
+        # BEFORE the session starts, so the first state the session can see
+        # is stamped; an annotation opened while it spins up is a no-op.
+        reqtrace.PROFILER_HOOK = jax.profiler.TraceAnnotation
+        try:
+            jax.profiler.start_trace(log_dir)
+        except BaseException:
+            reqtrace.PROFILER_HOOK = None
+            raise
         _prof_state.update(active=True, dir=log_dir, started_at=time.time(),
                            duration_s=duration_s)
+        _capture.update(begin=_launch_counters(), t_begin=time.monotonic())
     reqtrace.TRACER.event("profile.start", cat="profile", track="profiler",
                           dir=log_dir)
 
@@ -70,9 +102,15 @@ def _profiler_end() -> None:
     with _prof_lock:
         if not _prof_state["active"]:
             return
+        end, before = _launch_counters(), _capture["begin"]
+        _capture["last"] = {
+            **{k: {label: v - before[k].get(label, 0.0)
+                   for label, v in end[k].items()} for k in end},
+            "seconds": time.monotonic() - _capture["t_begin"]}
         try:
             jax.profiler.stop_trace()
         finally:
+            reqtrace.PROFILER_HOOK = None
             _prof_state.update(active=False, duration_s=None)
     reqtrace.TRACER.event("profile.stop", cat="profile", track="profiler")
 
@@ -84,17 +122,30 @@ def profile_status() -> dict:
                 "duration_s": _prof_state["duration_s"]}
 
 
-def start_profile(log_dir: str | None = None, duration_s: float = 2.0) -> dict:
+def start_profile(log_dir: str | None = None, duration_s: float = 2.0,
+                  restamp=None) -> dict:
     """Start an on-demand jax.profiler capture and schedule its stop after
     `duration_s` (clamped to [0.05, MAX_PROFILE_SECONDS]) on a timer thread.
     Returns {dir, duration_s}; raises :class:`ProfileBusy` when a capture
     (this one or a CLI ``--trace`` run) is already in flight — the caller
-    never blocks behind someone else's capture."""
+    never blocks behind someone else's capture. `restamp` (the scheduler's
+    ``TimeLedger.restamp``) is called once the capture has begun and again
+    just before it stops, so the states open at its two ends are stamped."""
     duration_s = min(max(float(duration_s), 0.05), MAX_PROFILE_SECONDS)
     if not log_dir:
         log_dir = tempfile.mkdtemp(prefix="dllama_profile_")
     _profiler_begin(str(log_dir), duration_s)
-    t = threading.Timer(duration_s, _profiler_end)
+
+    def stop():
+        try:
+            if restamp is not None:
+                restamp()
+        finally:
+            _profiler_end()
+
+    if restamp is not None:
+        restamp()
+    t = threading.Timer(duration_s, stop)
     t.daemon = True  # a dying process must not hang on the stop timer
     t.start()
     return {"dir": str(log_dir), "duration_s": duration_s}
@@ -104,7 +155,10 @@ def start_profile(log_dir: str | None = None, duration_s: float = 2.0) -> dict:
 def trace(log_dir: str | None):
     """jax.profiler trace over a with-block; no-op when log_dir is falsy.
     Shares the process profiler session with :func:`start_profile`, so it
-    raises :class:`ProfileBusy` instead of corrupting a running capture."""
+    raises :class:`ProfileBusy` instead of corrupting a running capture.
+    Takes no `restamp`: its one caller (``inference --trace``) runs the
+    batch-1 engine, which has no scheduler ledger to tile the host plane;
+    only ``POST /debug/profile`` captures carry the states."""
     if not log_dir:
         yield
         return

@@ -91,7 +91,10 @@ for name in ("dllama_kv_pages_total", "dllama_kv_pages_used",
              "dllama_router_federation_scrape_seconds",
              "dllama_fleet_scrape_age_seconds",
              "dllama_router_ttft_seconds", "dllama_router_itl_seconds",
-             "dllama_router_slo_attainment"):
+             "dllama_router_slo_attainment",
+             "dllama_launches_total", "dllama_slot_steps_total",
+             "dllama_launch_kv_rows_total",
+             "dllama_launch_prefill_rows_total"):
     if name not in metrics.REGISTRY.names():
         missing.append(f"unregistered:{name}")
 for name in sorted(trace.SPAN_CATALOG):

@@ -119,6 +119,14 @@ try:
         "the overlapped pipeline is not visible in the trace "
         f"(dispatch chunks {sorted(disp)}, consume chunks {sorted(cons)})")
 
+    # ---- every engine launch is one span with the launch record as args
+    launches = [e for e in evs if e["name"] == "decode.device"]
+    assert launches, "no launch spans"
+    need = {"kind", "seq", "n", "active", "starved", "kv_rows", "prefill_rows"}
+    assert all(need <= set(e["args"]) for e in launches), launches[0]["args"]
+    assert {e["args"]["kind"] for e in launches} & {"decode", "hybrid"}, (
+        "no decode launch among the launch spans")
+
     print(f"PASS: request {rid}: timings {timings}, "
           f"{len(rec['chunks'])} chunks in flight recorder, "
           f"overlap visible on chunk pairs {sorted(overlapped)[:4]} "

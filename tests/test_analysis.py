@@ -230,6 +230,25 @@ def go():
     assert sorted(rules_of(diags)) == ["catalog-event", "catalog-span"]
 
 
+def test_catalog_span_covers_profiler_hook_calls():
+    """A hook("prefix." + x) call (obs/trace.PROFILER_HOOK) is a span on
+    the profiler's clock: its literal head is its SPAN_CATALOG name."""
+    tmpl = '''
+from dllama_tpu.obs import trace
+
+def go(kind):
+    hook = trace.PROFILER_HOOK
+    if hook is not None:
+        with hook({name}, seq=1):
+            pass
+'''
+    bad = tmpl.format(name='"mystery.launch." + kind')
+    diags = findings({"dllama_tpu/serve/fake.py": bad})
+    assert rules_of(diags) == ["catalog-span"]
+    good = tmpl.format(name='"dllama.launch." + kind')
+    assert findings({"dllama_tpu/serve/fake.py": good}) == []
+
+
 def test_catalog_fault_red():
     bad = ('from dllama_tpu.utils import faults\n'
            'faults.fire("definitely.not.a.point")\n')

@@ -210,6 +210,10 @@ def test_warmup_report_full_coverage_then_zero_compile_request():
     cobs.LEDGER.reset()  # the ledger is process-global and earlier tests
     # deliberately recorded an unexpected compile — health() reports
     # lifetime totals, so this test wants a clean slate
+    jax.clear_caches()  # so are jax's compiled eager ops: a test file run
+    # earlier in this process at the same vocab (tests/test_engine.py) leaves
+    # the commit bucket's sampling ops compiled, the bucket then counts as
+    # cached, is never seen by the reset ledger, and coverage reads incomplete
     eng = BatchEngine(CFG, PARAMS, n_slots=2, cache_dtype=jnp.float32,
                       kv_layout="paged", page_size=PAGE, max_prefill_chunk=4)
     sched = Scheduler(eng, chunk=2, warmup="auto")

@@ -592,6 +592,44 @@ def test_debug_profile_starts_and_conflicts_409(mserver, tmp_path, monkeypatch):
     assert st == 400
 
 
+def test_debug_perf_capture_block_counts_the_captures_launches(
+        mserver, tmp_path, monkeypatch):
+    """ISSUE 26: /debug/perf's `capture` block is what the engine launched
+    between the begin and the end of the last FINISHED profiler capture:
+    counter deltas, by kind and by slot-step state (the profiler itself is
+    stubbed, as above)."""
+    from dllama_tpu.utils import profiling
+
+    monkeypatch.setattr(profiling.jax.profiler, "start_trace",
+                        lambda log_dir: None)
+    monkeypatch.setattr(profiling.jax.profiler, "stop_trace", lambda: None)
+    port, _api, _ = mserver
+    launched = val("dllama_launches_total", {"kind": "decode"}) or 0.0
+    st, _, _ = _post_raw(port, "/debug/profile",
+                         {"duration_s": 30, "dir": str(tmp_path / "p")})
+    assert st == 200
+    try:
+        st, _, _ = _post_raw(
+            port, "/v1/chat/completions",
+            {"messages": [{"role": "user", "content": "capture"}],
+             "max_tokens": 6, "temperature": 0.0})
+        assert st == 200
+    finally:
+        profiling._profiler_end()  # the timer's call, early
+    during = val("dllama_launches_total", {"kind": "decode"}) - launched
+    st, data, _ = _get_raw(port, "/debug/perf")
+    assert st == 200
+    cap = json.loads(data)["capture"]
+    assert cap == profiling.last_capture()
+    assert set(cap) == {"launches", "slot_steps", "kv_rows", "prefill_rows",
+                        "seconds"}
+    assert cap["launches"]["decode"] == during >= 1
+    assert cap["slot_steps"]["advanced"] >= 5  # 6 tokens, the first at commit
+    assert cap["kv_rows"]["decode"] > 0
+    assert sum(cap["prefill_rows"].values()) > 0
+    assert cap["seconds"] > 0
+
+
 def test_debug_perf_joins_windows_ledger_roofline(mserver):
     """GET /debug/perf (ISSUE 7): after at least one served request the
     join must show a populated TTFT window with p50/p95/p99, a ledger whose

@@ -627,9 +627,14 @@ def test_real_mesh_trace_propagation_and_postmortem(real_mesh):
     assert st == 200, data
 
     # cross-hop postmortem: router journal joined with the replica timeline
-    st, data = rget(port, f"/router/requests/{rid}")
-    assert st == 200, data
-    pm = json.loads(data)
+    # (the router notes the outcome just AFTER it has answered the client)
+    for _ in range(100):
+        st, data = rget(port, f"/router/requests/{rid}")
+        assert st == 200, data
+        pm = json.loads(data)
+        if pm["router"]["outcome"] is not None:
+            break
+        time.sleep(0.02)
     tid = pm["trace_id"]
     assert tid and len(tid) == 16 and tid != rid
     assert pm["router"]["outcome"] == "ok"
