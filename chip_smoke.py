@@ -270,11 +270,28 @@ def kernels_phase(seed: int) -> None:
     active = jnp.asarray([True] * (SLOTS - 1) + [False])
     pools = [jnp.pad(bf16(SLOTS * nb + 1, hkv, page, hd),
                      ((0, 0),) * 3 + ((0, lanes - hd),)) for _ in range(2)]
-    for t in (1, SPEC_K + 1):  # plain decode; the K+1-wide spec verify
+    same = lambda a, b: np.array_equal(np.asarray(a, np.float32),
+                                       np.asarray(b, np.float32))
+    # plain decode; the K+1-wide spec verify; a prefill slice long enough
+    # to take the XLA pre-scatter (page by page) instead of the fused one
+    for t in (1, SPEC_K + 1, 64):
+        pos = jnp.minimum(pos, seq - t)
         q, nk, nv = bf16(SLOTS, t, hq, hd), bf16(SLOTS, hkv, t, hd), bf16(SLOTS, hkv, t, hd)
         out, kp, vp = paged_decode_attention(
             q, pools[0], pools[1], tables, pos, nk, nv, active,
             interpret=False)
+        # the call as the decoder's layer scan makes it: the layer-stacked
+        # pool whole, the layer as data. Bit-equal to the call on the
+        # layer's slice, and the other layer is not touched
+        stack = [jnp.stack([p[::-1], p]) for p in pools]
+        out_l, ks, vs = paged_decode_attention(
+            q, stack[0], stack[1], tables, pos, nk, nv, active,
+            layer=jnp.int32(1), interpret=False)
+        if not (same(out_l, out) and same(ks[1], kp) and same(vs[1], vp)
+                and same(ks[0], stack[0][0]) and same(vs[0], stack[1][0])):
+            raise SystemExit(f"kernels: paged_decode_attention t={t}: the "
+                             "layer-indexed call differs from the call on "
+                             "the layer's slice")
         k_ref = _paged_cache_update(pools[0][..., :hd], nk, tables, pos, active)
         v_ref = _paged_cache_update(pools[1][..., :hd], nv, tables, pos, active)
         name = f"paged_decode_attention t={t} page={page} hd={hd}"

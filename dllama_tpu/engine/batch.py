@@ -1538,8 +1538,15 @@ class BatchEngine:
     def _alloc_decode_rows(self, n: int) -> None:
         """Paged: best-effort top-up before a decode/spec dispatch — extend
         each active slot's table to cover n more rows (clamped at seq_len).
-        Slots the pool cannot serve keep their current limit and freeze
-        per-row in the scan; pages freed by later releases un-freeze them.
+        A top-up the free list cannot cover first takes LRU leaves of the
+        radix tree (a decoding slot outranks a cached prefix, as an
+        admission's shortfall already does): without it a pool whose spare
+        pages all sit in the tree freezes decoders at every page boundary
+        until some request ends (PERF.md section 6, PR 27: 39% of slot-steps
+        on the 7B cell once the step was fast enough to get there inside a
+        window). Slots the pool still cannot serve keep their current limit
+        and freeze per-row in the scan; pages freed by later releases
+        un-freeze them.
 
         Also the draft-write COW gate: any SHARED allocated page covering
         the slot's writable rows [pos, pos+n) is copy-on-written first, so
@@ -1552,6 +1559,10 @@ class BatchEngine:
         changed = False
         for s in np.flatnonzero(self.active):
             want = min(self.seq_len, int(self.pos[s]) + n)
+            short = (self.pool.blocks_for(want) - int(self.pool.n_blocks[s])
+                     - self.pool.free_count)
+            if short > 0:
+                self.radix_evict(short)
             changed |= self.pool.grow(int(s), want, best_effort=True)
             changed |= self.pool.cow_writable(int(s), int(self.pos[s]), want,
                                               self._pool_page_copy)

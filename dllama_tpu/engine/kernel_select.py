@@ -165,10 +165,13 @@ def resolve_kernels(
             (cfg.n_heads, cfg.head_size), page_size,
             kv_dtype=cache_dtype if cache_dtype is not None else jnp.bfloat16,
         ) and (attn_impl == "flash" or on_tpu):
-            def attn_fn(q, k_pool, v_pool, tables, pos, new_k, new_v, active):
+            def attn_fn(q, k_pool, v_pool, tables, pos, new_k, new_v, active,
+                        layer):
+                # the pools are the whole layer-stacked arrays: the kernel
+                # indexes `layer` (models/llama.run_layers carries them)
                 return paged_decode_attention(
                     q, k_pool, v_pool, tables, pos, new_k, new_v, active,
-                    interpret=not on_tpu)
+                    layer=layer, interpret=not on_tpu)
 
             # models/llama._layer hands the new KV rows to the kernel
             # instead of paying a separate scatter dispatch per layer; the

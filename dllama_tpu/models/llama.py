@@ -151,6 +151,14 @@ def _layer(cfg: LlamaConfig, x, layers, li, k_cache, v_cache, rope, pos_base, at
     lazily (XLA path). Slicing stacked weights before a pallas_call would make
     XLA materialize a full HBM copy of every weight, every layer, every token.
 
+    `k_cache`/`v_cache` are this layer's slice of the cache ([B, Hkv, S, hd],
+    or [P, Hkv, page, hd] of a page pool) and come back updated — except on
+    the paged kernel route (`attn_fn.fused_kv_scatter`), where they are the
+    whole stacked pools [L, P, Hkv, page, lanes], for the same reason the
+    weights are whole: the kernel reads and writes layer `li`'s pages in
+    place, and a 70 MB slice cut out and put back per layer per step was
+    45% of a 7B decode step's device time (PERF.md section 6, PR 27).
+
     `mm_in` is the matmul for the INPUT-dim-sharded weights (wo/w2 — the
     reference's col slices with merge-add): under sharded-Pallas it psums
     partials inside shard_map; default is plain `mm` (GSPMD inserts the
@@ -186,10 +194,13 @@ def _layer(cfg: LlamaConfig, x, layers, li, k_cache, v_cache, rope, pos_base, at
     elif getattr(attn_fn, "fused_kv_scatter", False):
         # paged flash-decode kernel: the new rows' scatter write is fused
         # into the attention launch (ops/pallas/paged_attention) — no
-        # separate per-layer scatter dispatch, identical pool contents
+        # separate per-layer scatter dispatch, identical pool contents.
+        # k_cache/v_cache are the WHOLE layer-stacked pools here (run_layers
+        # carries them): the kernel indexes layer `li` itself, like the
+        # matmuls index the weight stacks
         att, k_cache, v_cache = attn_fn(
             q, k_cache, v_cache, tables, pos_base,
-            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), active)
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), active, li)
         att = att.reshape(b, t, d)
     else:  # paged layout: scatter at block-table positions, same math
         k_cache = _paged_cache_update(k_cache, k.transpose(0, 2, 1, 3),
@@ -281,9 +292,19 @@ def run_layers(
     """Scan the decoder layers (any contiguous stack — the full model, or one
     pipeline stage's slice). Returns (x, k_cache, v_cache).
 
-    The scan carries only the layer INDEX (plus the per-layer cache slices) —
-    the stacked weights stay closed-over and un-sliced, so the Pallas kernels
-    can DMA-index them with zero copies (ops/pallas/q40_matmul.py docstring).
+    The scan iterates over the layer INDEX — the stacked weights stay
+    closed-over and un-sliced, so the Pallas kernels can DMA-index them with
+    zero copies (ops/pallas/q40_matmul.py docstring). The cache rides in one
+    of two ways, decided by what the call was given:
+
+    * a page pool whose attention function is the fused paged kernel
+      (`tables` and `attn_fn.fused_kv_scatter`): the whole stacked pools are
+      CARRIED beside x, the kernel indexes the layer, and every layer's
+      aliased call updates the one buffer in place — nothing pool-sized or
+      slice-sized is cut out, put back or copied;
+    * every other layout (dense caches, a pipeline stage's slice, the sp
+      ring, the paged gather route): the per-layer slices are the scan's
+      xs and ys, as XLA attention over a layer's slice wants them.
 
     `unroll`: passed to lax.scan — trades compile time for cross-layer
     scheduling freedom."""
@@ -295,6 +316,18 @@ def run_layers(
 
             attn_fn = paged_gqa_attention
     n_layers = k_cache.shape[0]
+    layer_ids = jnp.arange(n_layers, dtype=jnp.int32)
+
+    if tables is not None and getattr(attn_fn, "fused_kv_scatter", False):
+        def pool_scan_fn(carry, li):
+            x, kp, vp = carry
+            return _layer(cfg, x, layer_params, li, kp, vp, rope, pos_base,
+                          attn_fn, active, col_fn, mm, mm_in, moe_impl,
+                          tables), None
+
+        (x, k_new, v_new), _ = jax.lax.scan(
+            pool_scan_fn, (x, k_cache, v_cache), layer_ids, unroll=unroll)
+        return x, k_new, v_new
 
     def scan_fn(carry, xs):
         x = carry
@@ -304,8 +337,7 @@ def run_layers(
         return x, (kc, vc)
 
     x, (k_new, v_new) = jax.lax.scan(
-        scan_fn, x, (jnp.arange(n_layers, dtype=jnp.int32), k_cache, v_cache),
-        unroll=unroll,
+        scan_fn, x, (layer_ids, k_cache, v_cache), unroll=unroll,
     )
     return x, k_new, v_new
 

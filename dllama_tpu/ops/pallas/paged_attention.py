@@ -25,7 +25,14 @@ SNIPPETS.md [1]/[2]'s `pltpu.PrefetchScalarGridSpec` scalar-prefetch idiom):
 * the new token's KV rows are scatter-written into the pool INSIDE the same
   launch (``input_output_aliases`` keeps the pool update in place): the
   separate `_paged_cache_update` dispatch decode used to pay per layer is
-  gone, and the attention sweep reads the row it just wrote.
+  gone, and the attention sweep reads the row it just wrote;
+* the pool the kernel walks is the WHOLE layer-stacked array, viewed as one
+  run of L*P pages, and the layer is data: its first page is added to the
+  prefetched page indices (``paged_decode_attention(layer=)``). The decoder's
+  layer scan carries that one buffer through every layer's aliased call, so
+  no layer's slice is cut out of the stack before its call or written back
+  after it — the same reason the matmuls DMA-index the weight stacks. A
+  per-layer pool (``layer=None``) is the same call with first page 0.
 
 Numerics are the same online-softmax (flash) formulation as
 ``flash_attention._kernel``: f32 accumulation, large-finite mask fill, one
@@ -227,12 +234,16 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
 def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
                   new_v, *, group: int, interpret: bool, rows_live: int,
                   fused: bool, scale: float):
-    """qf[B, Hkv, rows_pad, hd] x pool[P, Hkv, page, hd] ->
+    """qf[B, Hkv, rows_pad, hd] x pool[N, Hkv, page, hd] ->
     (out f32 [B, Hkv, rows_pad, hd], k_pool, v_pool).
 
     The pools ride in HBM (ANY memory space) and alias their outputs, so the
     fused scatter is an in-place update at the XLA level; the kernel DMA-
-    walks them through the prefetched block tables."""
+    walks them through the prefetched block tables. N is one layer's P
+    pages, or all L*P of the layer-merged stack with ``tables``/``wpages``
+    already offset to the layer (the kernel cannot tell, and need not). The
+    name and the 4-D pool in the result are what the benchmark's trace
+    reader finds this call by (benchmark/costs/paged_attention.py)."""
     b, hkv, rows, hd = qf.shape
     npool, _, page, _ = k_pool.shape
     nb = tables.shape[1]
@@ -293,9 +304,53 @@ def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
     return out, k_pool, v_pool
 
 
+def _scatter_rows_by_page(pool, new, nb, pos, wpages, woffs, trash):
+    """``pool.at[wpages, :, woffs, :].set(rows)`` for a chunk of T
+    consecutive rows per slot, done page by page so that the pool itself is
+    only ever updated by whole pages at its leading dim.
+
+    The direct form scatters at dims 0 and 2 of [N, Hkv, page, lanes], and
+    XLA:TPU serves it by re-laying-out the WHOLE operand before and after
+    (three copies of whatever it is handed: a layer's 70 MB slice when the
+    pool was sliced per layer, the 2.1 GB stack once it is not). A chunk
+    touches at most ceil(T / page) + 1 pages a slot, so: gather those
+    pages (a few MB), scatter the rows into that small buffer with the same
+    (page, offset) addressing, and put each page back with a
+    dynamic_update_slice at the leading dim, which is in place. Pages of
+    the buffer that received no row (the chunk ended before them) go back
+    to the trash page, never over a page another entry writes.
+
+    pool [N, Hkv, page, lanes]; new [B, Hkv, T, lanes]; wpages/woffs i32
+    [B, T] from ``paged_write_targets`` (same block clipping, inactive slots
+    already routed to ``trash``)."""
+    b, _, t, _ = new.shape
+    page = pool.shape[2]
+    n_local = min(-(-t // page) + 1, nb)  # pages one slot's chunk can touch
+    rows = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+    blk = jnp.clip(rows // page, 0, nb - 1)  # as paged_write_targets clips
+    local = blk - blk[:, :1]  # [B, T] which of the slot's pages a row hits
+    ahead = blk[:, :1] + jnp.arange(n_local, dtype=jnp.int32)[None]
+    # page id of local page j = the target of the first row that hits it
+    first_row = jnp.clip(ahead * page - pos[:, None], 0, t - 1)
+    ids = jnp.where(ahead <= blk[:, -1:],
+                    jnp.take_along_axis(wpages, first_row, axis=1),
+                    trash).reshape(-1)
+    buf = jnp.take(pool, ids, axis=0, mode="clip")  # [B*n_local, Hkv, page, lanes]
+    slot_base = jnp.arange(b, dtype=jnp.int32)[:, None] * n_local
+    buf = buf.at[slot_base + local, :, woffs, :].set(
+        new.transpose(0, 2, 1, 3).astype(pool.dtype))
+
+    def put(i, pool):
+        pg = jax.lax.dynamic_slice_in_dim(buf, i, 1, axis=0)
+        return jax.lax.dynamic_update_slice(pool, pg, (ids[i], 0, 0, 0))
+
+    return jax.lax.fori_loop(0, b * n_local, put, pool)
+
+
 def paged_decode_attention(
     q: jax.Array,  # [B, T, Hq, hd]
-    k_pool: jax.Array,  # [P, Hkv, page, hd or pool_lanes(hd)] (one layer)
+    k_pool: jax.Array,  # [P, Hkv, page, hd or pool_lanes(hd)] (one layer),
+    # or with `layer` the stored stack [L, P, Hkv, page, lanes]
     v_pool: jax.Array,
     tables: jax.Array,  # i32 [B, max_blocks]
     pos_base: jax.Array,  # i32 scalar or [B] per-row positions
@@ -303,6 +358,7 @@ def paged_decode_attention(
     new_v: jax.Array | None = None,
     active: jax.Array | None = None,  # [B] bool: inactive rows -> trash page
     *,
+    layer: jax.Array | None = None,  # i32 scalar: the pools are the stack
     interpret: bool = False,
 ) -> jax.Array | tuple[jax.Array, jax.Array, jax.Array]:
     """Block-table paged attention over the HBM page pool, any page size.
@@ -315,9 +371,24 @@ def paged_decode_attention(
     ``(out, k_pool, v_pool)`` with the pools updated in place
     (input/output aliased). Chunks longer than ``FUSED_SCATTER_MAX_T``
     scatter via XLA before the launch instead (identical result; prefill
-    chunks should not serialize per-row DMAs)."""
+    chunks should not serialize per-row DMAs).
+
+    With ``layer`` the pools are the whole layer-stacked arrays as
+    ``PagedKVCache`` stores them and the call reads and writes that layer's
+    pages IN the stack: the stack is viewed as one pool of L*P pages (a
+    merge of the two leading dims — a bitcast, no bytes move) and the
+    layer's first page, ``layer * P``, is added to every page index that
+    rides in as scalar prefetch (block tables, write targets; the layer's
+    trash page is its own last page). The kernel body, the rows written
+    and their order are the per-layer call's; what goes is the layer's
+    slice being cut out of the stack before the call and put back after
+    it. Returns the pools at the stacked shape."""
     b, t, hq, hd = q.shape
-    n_pool, hkv, page, lanes = k_pool.shape
+    stack_shape = k_pool.shape
+    if layer is not None:
+        k_pool = k_pool.reshape(-1, *stack_shape[2:])
+        v_pool = v_pool.reshape(-1, *stack_shape[2:])
+    n_pool, hkv, page, lanes = stack_shape[-4:]
     group = hq // hkv
     if lanes != hd:
         # lane-padded pool (pool_lanes): zero-pad the head dim of every row
@@ -337,6 +408,9 @@ def paged_decode_attention(
     pos = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(pos_base, jnp.int32)),
                            (b,))
     tables = jnp.asarray(tables, jnp.int32)
+    # page indices of the layer-merged view: the layer's own first page on
+    # top of what the per-layer call computes
+    first_page = 0 if layer is None else jnp.asarray(layer, jnp.int32) * n_pool
 
     write = new_k is not None
     if write:
@@ -347,12 +421,13 @@ def paged_decode_attention(
 
         wpages, woffs = paged_write_targets(tables, pos, t, page, n_pool,
                                             active)
+        wpages = wpages + first_page
         if t > FUSED_SCATTER_MAX_T:
-            # prefill-sized chunk: one XLA scatter, then a read-only sweep
-            k_pool = k_pool.at[wpages, :, woffs, :].set(
-                new_k.transpose(0, 2, 1, 3).astype(k_pool.dtype))
-            v_pool = v_pool.at[wpages, :, woffs, :].set(
-                new_v.transpose(0, 2, 1, 3).astype(v_pool.dtype))
+            # prefill-sized chunk: scattered by XLA, then a read-only sweep
+            k_pool, v_pool = (
+                _scatter_rows_by_page(pool, new, tables.shape[1], pos,
+                                      wpages, woffs, first_page + n_pool - 1)
+                for pool, new in ((k_pool, new_k), (v_pool, new_v)))
             write = False
     if not write:
         # dummy single-row write of what the trash page already gets —
@@ -366,7 +441,7 @@ def paged_decode_attention(
         nv = new_v.astype(v_pool.dtype)
 
     out, k_pool, v_pool = _paged_folded(
-        qf, k_pool, v_pool, pos, tables, wpages, woffs, nk, nv,
+        qf, k_pool, v_pool, pos, tables + first_page, wpages, woffs, nk, nv,
         group=group, interpret=interpret, rows_live=rows, fused=write,
         scale=1.0 / math.sqrt(hd))
     out = (
@@ -377,4 +452,4 @@ def paged_decode_attention(
     )
     if new_k is None:
         return out
-    return out, k_pool, v_pool
+    return out, k_pool.reshape(stack_shape), v_pool.reshape(stack_shape)

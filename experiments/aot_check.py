@@ -173,19 +173,26 @@ def cases(full: bool):
     # wide (Mosaic refuses to DMA-walk a 64-lane pool). Production at the
     # shipped default page size AND at the small/odd sizes the capability
     # check admits; t=K+1 is the batched spec-verify shape, t=256 exercises
-    # the XLA pre-scatter prefill path of the same wrapper.
+    # the XLA pre-scatter prefill path of the same wrapper; "layer-indexed
+    # stack" is the call as the decoder's layer scan makes it (the whole
+    # [L, P, ...] pool and the layer as data).
     from dllama_tpu.ops.pallas.paged_attention import paged_decode_attention, pool_lanes
 
-    def paged(name, page, t=1, b=SLOTS, hd=HD, read_only=False):
+    def paged(name, page, t=1, b=SLOTS, hd=HD, read_only=False, stacked=False):
         nb = SEQ // page
-        pools = S((b * nb + 1, HKV, page, pool_lanes(hd)), jnp.bfloat16)
+        pools = S(((2,) * stacked) + (b * nb + 1, HKV, page, pool_lanes(hd)),
+                  jnp.bfloat16)
         args = [S((b, t, HQ, hd), jnp.bfloat16), pools, pools,
                 S((b, nb), jnp.int32), S((b,), jnp.int32)]
         if not read_only:
             args += [S((b, HKV, t, hd), jnp.bfloat16),
                      S((b, HKV, t, hd), jnp.bfloat16), S((b,), jnp.bool_)]
-        out.append((name, lambda *a: paged_decode_attention(*a, interpret=False),
-                    tuple(args), True))
+        fn = lambda *a: paged_decode_attention(*a, interpret=False)
+        if stacked:  # the layer-stacked pool, the layer as data (PR 27)
+            args.append(S((), jnp.int32))
+            fn = lambda *a: paged_decode_attention(*a[:-1], layer=a[-1],
+                                                   interpret=False)
+        out.append((name, fn, tuple(args), True))
 
     paged(f"paged decode t=1 p=128 hd={HD} fused scatter", 128)
     paged(f"paged spec verify t={SPEC_K + 1} p=128 hd={HD} fused scatter", 128, t=SPEC_K + 1)
@@ -195,6 +202,9 @@ def cases(full: bool):
     paged(f"paged prefill t=256 p=128 hd={HD} (XLA pre-scatter)", 128, t=256, b=1)
     paged(f"paged decode t=1 p=128 hd={HD} read-only sweep", 128, read_only=True)
     paged("paged decode t=1 p=128 hd=128 fused scatter", 128, hd=HD_8B)
+    paged(f"paged decode t=1 p=128 hd={HD} layer-indexed stack", 128, stacked=True)
+    paged(f"paged prefill t=256 p=128 hd={HD} layer-indexed stack (XLA pre-scatter)",
+          128, t=256, b=1, stacked=True)
     paged("paged spec verify t=9 p=128 hd=128 fused scatter", 128, t=9, hd=HD_8B)
 
     from dllama_tpu.ops.pallas.rms_norm import rms_norm as prms
@@ -378,20 +388,18 @@ def sharded_cases(topo):
     return out
 
 
-def serving_cases(topo):
-    """The whole BatchEngine programs `serve --slots 8 --max-seq-len 2048
-    --spec-k 4` dispatches at 1b width, through the engine's own jits
-    (static args, donation, pool aliasing inside the layer scan): paged
-    decode chunk, one hybrid prefill-slice + decode step, prefill chunks,
-    the K+1-wide spec-verify chunk, the penalized scan — plus a decode
-    chunk of an OLMoE-width sparse-expert block (`moe_ffn`'s ragged_dot
-    inside the scan; not on the 1b path). Returns (name, thunk, production):
-    the engine is built here on the CPU over shapes, and each thunk lowers
-    one of its programs for the described chip."""
+def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
+                    hybrid_p=(64,)):
+    """(name, thunk, production) for the step programs of a paged
+    BatchEngine (`serve --slots N --max-seq-len 2048 [--spec-k K]`): the
+    engine is built here on the CPU over shapes, and each thunk lowers one
+    of its programs for the described chip and returns the compiled
+    executable. `kv_pages` 0 = full coverage; `hybrid_p` = the prefill
+    slices to offer the hybrid step at. serving_cases() and
+    experiments/pool_copies.py (the 7B cell's sizes) both build on this."""
     from jax.sharding import SingleDeviceSharding
 
     from dllama_tpu.engine.batch import BatchEngine
-    from dllama_tpu.models.config import LlamaConfig
     from dllama_tpu.models.llama import PagedKVCache
     from dllama_tpu.ops.layers import build_rope_cache
 
@@ -400,45 +408,61 @@ def serving_cases(topo):
     place = lambda tree: jax.tree.map(lambda a: A(a.shape, a.dtype), tree)
     i32 = lambda *shape: A(shape, jnp.int32)
     f32 = lambda *shape: A(shape, jnp.float32)
+    page = 128
+    # a 2-page pool keeps construction cheap; the programs are lowered
+    # against the pool the server allocates
+    be = BatchEngine(cfg, params, n_slots=slots, max_seq_len=SEQ,
+                     kv_layout="paged", page_size=page, kv_pages=2,
+                     spec=spec)
+    assert be.kernel_route == "pallas/paged_kernel", be.kernel_route
+    nb = SEQ // page
+    pool = A((cfg.n_layers, (kv_pages or slots * nb) + 1, cfg.n_kv_heads,
+              page, be.cache.k.shape[-1]), jnp.bfloat16)
+    cache = PagedKVCache(pool, pool, i32(slots, nb))
+    rope = place(jax.eval_shape(lambda: build_rope_cache(cfg, SEQ)))
+    vecs = (i32(slots), A((slots,), jnp.bool_), A((slots, 2), jnp.uint32),
+            f32(slots), f32(slots))  # pos, active, keys, temps, topp
+    dec = lambda n: (params, cache, i32(slots, 1), *vecs, n, rope, i32(slots))
+    out = [(f"{tag} paged decode chunk n=4",
+            lambda: be._decode.lower(*dec(4)).compile(), True)]
+    if spec:
+        out += [(f"{tag} hybrid step p={p} n=4", lambda p=p: be._hybrid.lower(
+            params, cache, i32(1, p), i32(), i32(), i32(slots, 1),
+            *vecs, 4, rope, i32(slots)).compile(), True) for p in hybrid_p]
+        out += [
+            (f"{tag} paged prefill chunk m=256", lambda: be._prefill_slot.lower(
+                params, cache, i32(1, 256), i32(), i32(), rope).compile(), True),
+            (f"{tag} paged prefill chunk m=1", lambda: be._prefill_slot.lower(
+                params, cache, i32(1, 1), i32(), i32(), rope).compile(), True),
+            (f"{tag} spec-verify chunk K={spec} m=4", lambda: be._spec_step.lower(
+                params, cache, i32(slots, SEQ + 1), i32(slots), vecs[0],
+                vecs[1], i32(slots), *vecs[2:], rope, i32(slots), 4).compile(), True),
+            (f"{tag} paged penalized decode chunk n=4", lambda: be._decode_pen.lower(
+                *dec(4), i32(slots, cfg.vocab_size), f32(slots),
+                f32(slots)).compile(), True),
+        ]
+    return out
 
-    def programs(tag, cfg, params, slots, spec, kv_pages=0):
-        page = 128
-        # a 2-page pool keeps construction cheap; the programs are lowered
-        # against the full-coverage pool the server allocates
-        be = BatchEngine(cfg, params, n_slots=slots, max_seq_len=SEQ,
-                         kv_layout="paged", page_size=page, kv_pages=2,
-                         spec=spec)
-        assert be.kernel_route == "pallas/paged_kernel", be.kernel_route
-        nb = SEQ // page
-        pool = A((cfg.n_layers, (kv_pages or slots * nb) + 1, cfg.n_kv_heads,
-                  page, be.cache.k.shape[-1]), jnp.bfloat16)
-        cache = PagedKVCache(pool, pool, i32(slots, nb))
-        rope = place(jax.eval_shape(lambda: build_rope_cache(cfg, SEQ)))
-        vecs = (i32(slots), A((slots,), jnp.bool_), A((slots, 2), jnp.uint32),
-                f32(slots), f32(slots))  # pos, active, keys, temps, topp
-        dec = lambda n: (params, cache, i32(slots, 1), *vecs, n, rope, i32(slots))
-        out = [(f"{tag} paged decode chunk n=4",
-                lambda: be._decode.lower(*dec(4)).compile(), True)]
-        if spec:
-            out += [
-                (f"{tag} hybrid step p=64 n=4", lambda: be._hybrid.lower(
-                    params, cache, i32(1, 64), i32(), i32(), i32(slots, 1),
-                    *vecs, 4, rope, i32(slots)).compile(), True),
-                (f"{tag} paged prefill chunk m=256", lambda: be._prefill_slot.lower(
-                    params, cache, i32(1, 256), i32(), i32(), rope).compile(), True),
-                (f"{tag} paged prefill chunk m=1", lambda: be._prefill_slot.lower(
-                    params, cache, i32(1, 1), i32(), i32(), rope).compile(), True),
-                (f"{tag} spec-verify chunk K={spec} m=4", lambda: be._spec_step.lower(
-                    params, cache, i32(slots, SEQ + 1), i32(slots), vecs[0],
-                    vecs[1], i32(slots), *vecs[2:], rope, i32(slots), 4).compile(), True),
-                (f"{tag} paged penalized decode chunk n=4", lambda: be._decode_pen.lower(
-                    *dec(4), i32(slots, cfg.vocab_size), f32(slots),
-                    f32(slots)).compile(), True),
-            ]
-        return out
+
+def serving_cases(topo):
+    """The whole BatchEngine programs `serve --slots 8 --max-seq-len 2048
+    --spec-k 4` dispatches at 1b width, through the engine's own jits
+    (static args, donation, pool aliasing inside the layer scan): paged
+    decode chunk, one hybrid prefill-slice + decode step, prefill chunks,
+    the K+1-wide spec-verify chunk, the penalized scan — plus a decode
+    chunk of an OLMoE-width sparse-expert block (`moe_ffn`'s ragged_dot
+    inside the scan; not on the 1b path). Returns (name, thunk, production)
+    as engine_programs() builds them."""
+    from jax.sharding import SingleDeviceSharding
+
+    from dllama_tpu.models.config import LlamaConfig
+
+    one = SingleDeviceSharding(topo.devices[0])
+    A = lambda shape, dt: S(shape, dt, sharding=one)
+    f32 = lambda *shape: A(shape, jnp.float32)
 
     cfg = _cfg_1b()
-    out = programs("serve 1b", cfg, _abstract_params(
+    out = engine_programs(topo, "serve 1b", cfg, _abstract_params(
         cfg, lambda t: jax.tree.map(lambda _: one, t)), SLOTS, SPEC_K)
 
     # OLMoE-1B-7B width (ROADMAP Reach #1): 64 experts top-8, expert hidden
@@ -468,8 +492,8 @@ def serving_cases(topo):
         },
     }
     # not a shipped default yet: the configuration lands with Reach #1
-    out += [(n, t, False) for n, t, _ in programs(
-        "serve olmoe-width 64-slot", mcfg, mparams, 64, 0, kv_pages=256)]
+    out += [(n, t, False) for n, t, _ in engine_programs(
+        topo, "serve olmoe-width 64-slot", mcfg, mparams, 64, 0, kv_pages=256)]
     return out
 
 

@@ -34,6 +34,8 @@ CASES = (
     "paged spec verify t=5 p=128 hd=64 fused scatter",
     "paged decode t=1 p=16 hd=64 fused scatter",
     "paged decode t=1 p=128 hd=128 fused scatter",
+    "paged decode t=1 p=128 hd=64 layer-indexed stack",
+    "paged prefill t=256 p=128 hd=64 layer-indexed stack (XLA pre-scatter)",
     "tp=4 shard_map mm in-shard+psum (w2)",
     "serve 1b paged decode chunk n=4",
     "serve 1b hybrid step p=64 n=4",
@@ -79,3 +81,27 @@ def test_compiles_for_v5e(thunks, name):
     compiled = thunks[name]()
     # the chip's compiler saw a Pallas kernel, not an interpret-mode trace
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", [
+    "serve 1b paged decode chunk n=4",
+    "serve 1b hybrid step p=64 n=4",
+    "serve 1b paged prefill chunk m=256",
+])
+def test_step_program_moves_no_layer_of_the_pool(thunks, name):
+    """The KV page pool rides the layer scan and the step scan as a carry
+    and the paged kernel indexes the layer (PR 27): in the compiled program
+    no loop body cuts a layer's slice out of the stacked pool, puts one
+    back or copies the pool, and the program's temp is not a second pool.
+    (At 7B this was 45% of a decode step's device time, PERF.md section 6;
+    `experiments/pool_copies.py` is the same reading at that width.)"""
+    from experiments import pool_copies
+
+    compiled = thunks[name]()
+    # serving_cases' 1b pool: [16 layers, 8 slots x 16 blocks + 1, 8, 128, 128] bf16
+    layer_bytes = (aot_check.SLOTS * (aot_check.SEQ // 128) + 1) * aot_check.HKV * 128 * 128 * 2
+    moved = [m for m in pool_copies.big_movers(compiled.as_text(), layer_bytes)
+             if m[1]]
+    assert not moved, moved
+    pool_bytes = aot_check.N_LAYERS * layer_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2

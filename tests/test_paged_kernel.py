@@ -5,7 +5,10 @@ partial last pages; GQA group > 1; the fused KV scatter landing rows exactly
 where ``PagedKVCache``/`_paged_cache_update` expects (bitwise, incl. the
 trash-page routing of inactive rows); and the engine-level contract — the
 fused kernel's token streams are BIT-IDENTICAL to the gather path's through
-the real decode scan.
+the real decode scan. Every op-level case also runs LAYER-INDEXED (PR 27):
+the same call on a layer-stacked pool with `layer=li` must equal the
+per-layer call on that layer's slice bit for bit, and leave every other
+layer's pages as they were.
 
 Numerics note: the attention OUTPUT is online-softmax (flash), so op-level
 parity vs the materialized-softmax gather is allclose at f32 tolerance (the
@@ -38,6 +41,41 @@ def _setup(rng, page, nb, b=2, t=1, hq=4, hkv=2, hd=64, dtype=jnp.float32):
     return q, kp, vp, tables
 
 
+N_LAYERS = 3
+
+
+def _stack(rng, kp, vp):
+    """Layer-stacked pools [L, P, Hkv, page, hd] with distinct contents per
+    layer, the way PagedKVCache stores them; layer 0 is (kp, vp) itself."""
+    more = lambda p: jnp.asarray(
+        rng.standard_normal((N_LAYERS - 1, *p.shape)), p.dtype)
+    return (jnp.concatenate([kp[None], more(kp)]),
+            jnp.concatenate([vp[None], more(vp)]))
+
+
+def _assert_layer_indexed_equals_sliced(rng, q, kp, vp, tables, pos,
+                                        nk=None, nv=None, active=None):
+    """For every layer of a stacked pool: the layer-indexed call == the
+    per-layer call on the slice, BITWISE (output and pools), and the other
+    layers' pages (their trash pages too) are untouched."""
+    ks, vs = _stack(rng, kp, vp)
+    for li in range(N_LAYERS):
+        want = paged_decode_attention(q, ks[li], vs[li], tables, pos, nk, nv,
+                                      active, interpret=True)
+        got = paged_decode_attention(q, ks, vs, tables, pos, nk, nv, active,
+                                     layer=jnp.int32(li), interpret=True)
+        if nk is None:
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+            continue
+        for (g, w, old) in ((got[1], want[1], ks), (got[2], want[2], vs)):
+            assert g.shape == old.shape  # comes back at the stored shape
+            np.testing.assert_array_equal(np.asarray(g[li]), np.asarray(w))
+            others = [l for l in range(N_LAYERS) if l != li]
+            np.testing.assert_array_equal(np.asarray(g)[others],
+                                          np.asarray(old)[others])
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+
+
 def _reference(q, kp, vp, tables, pos, nk=None, nv=None, active=None):
     """Scatter via the model's own `_paged_cache_update`, then the jnp
     gather attention — the exact pair of dispatches the fused kernel
@@ -48,35 +86,46 @@ def _reference(q, kp, vp, tables, pos, nk=None, nv=None, active=None):
     return paged_gqa_attention(q, kp, vp, tables, pos), kp, vp
 
 
+LAYOUTS = pytest.mark.parametrize("stacked", [False, True],
+                                  ids=["per-layer", "layer-indexed"])
+
+
+@LAYOUTS
 @pytest.mark.parametrize("page,nb,pos", [
     (8, 8, [19, 1]),      # small page the old gate rejected
     (16, 4, [35, 0]),     # pow-2, one slot empty
     (24, 3, [51, 17]),    # non-power-of-2, partial last page both slots
     (64, 2, [63, 127]),   # legacy-tileable size, page-boundary edges
 ])
-def test_read_parity_any_page_size(rng, page, nb, pos):
+def test_read_parity_any_page_size(rng, page, nb, pos, stacked):
     """Read-only sweep matches the gather reference for every (page_size,
     horizon) combo — incl. pages the old `% 64` gate rejected."""
     q, kp, vp, tables = _setup(rng, page, nb)
     pos = jnp.asarray(pos, jnp.int32)
+    if stacked:
+        return _assert_layer_indexed_equals_sliced(rng, q, kp, vp, tables, pos)
     want, _, _ = _reference(q, kp, vp, tables, pos)
     got = paged_decode_attention(q, kp, vp, tables, pos, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
 
+@LAYOUTS
 @pytest.mark.parametrize("page,nb,t,pos", [
     (8, 8, 1, [19, 1]),    # decode step
     (8, 8, 5, [9, 2]),     # spec-verify chunk crossing a page boundary
     (24, 3, 1, [23, 47]),  # write at the exact last row of a page
 ])
-def test_fused_scatter_parity(rng, page, nb, t, pos):
+def test_fused_scatter_parity(rng, page, nb, t, pos, stacked):
     """Fused path: pools match `_paged_cache_update` BITWISE (the row lands
     where PagedKVCache expects) and the output reads the just-written rows."""
     q, kp, vp, tables = _setup(rng, page, nb, t=t)
     pos = jnp.asarray(pos, jnp.int32)
     nk = jnp.asarray(rng.standard_normal((2, 2, t, 64)), jnp.float32)
     nv = jnp.asarray(rng.standard_normal((2, 2, t, 64)), jnp.float32)
+    if stacked:
+        return _assert_layer_indexed_equals_sliced(rng, q, kp, vp, tables,
+                                                   pos, nk, nv)
     want, kp_ref, vp_ref = _reference(q, kp, vp, tables, pos, nk, nv)
     got, kp2, vp2 = paged_decode_attention(q, kp, vp, tables, pos, nk, nv,
                                            interpret=True)
@@ -86,14 +135,27 @@ def test_fused_scatter_parity(rng, page, nb, t, pos):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_fused_scatter_inactive_rows_hit_trash_page(rng):
+@LAYOUTS
+def test_fused_scatter_inactive_rows_hit_trash_page(rng, stacked):
     """active=False rows scatter to the trash page (pool page P-1) exactly
-    like `_paged_cache_update`'s masked write — live pages untouched."""
+    like `_paged_cache_update`'s masked write — live pages untouched.
+    Layer-indexed: to THAT layer's trash page, no other layer's."""
     q, kp, vp, tables = _setup(rng, 16, 4)
     pos = jnp.asarray([35, 1], jnp.int32)
     active = jnp.asarray([True, False])
     nk = jnp.asarray(rng.standard_normal((2, 2, 1, 64)), jnp.float32)
     nv = jnp.asarray(rng.standard_normal((2, 2, 1, 64)), jnp.float32)
+    if stacked:
+        _assert_layer_indexed_equals_sliced(rng, q, kp, vp, tables, pos,
+                                            nk, nv, active)
+        # and the inactive slot's row really is in layer 1's trash page
+        ks, vs = _stack(rng, kp, vp)
+        _, ks2, _ = paged_decode_attention(
+            q, ks, vs, tables, pos, nk, nv, active, layer=jnp.int32(1),
+            interpret=True)
+        np.testing.assert_array_equal(np.asarray(ks2[1, -1, :, 1 % 16]),
+                                      np.asarray(nk[1, :, 0]))
+        return
     _, kp_ref, vp_ref = _reference(q, kp, vp, tables, pos, nk, nv, active)
     _, kp2, vp2 = paged_decode_attention(q, kp, vp, tables, pos, nk, nv,
                                          active, interpret=True)
@@ -116,19 +178,36 @@ def test_gqa_group_gt_one(rng):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_prefill_chunk_pre_scatter_path(rng):
+@LAYOUTS
+@pytest.mark.parametrize("page,nb,t,pos,active", [
+    (8, 8, FUSED_SCATTER_MAX_T * 2, [0, 3], None),  # 4-5 pages a slot
+    (8, 8, FUSED_SCATTER_MAX_T + 1, [21, 40], None),  # starts mid-page
+    (24, 3, FUSED_SCATTER_MAX_T + 4, [0, 29], None),  # ends before the
+    # last page a chunk of this length could reach: that page is not written
+    (16, 4, FUSED_SCATTER_MAX_T * 2, [5, 20], [True, False]),  # trash-routed
+])
+def test_prefill_chunk_pre_scatter_path(rng, page, nb, t, pos, active,
+                                        stacked):
     """t > FUSED_SCATTER_MAX_T takes the XLA pre-scatter branch of the same
-    wrapper: identical pools and output as the fused contract."""
-    t = FUSED_SCATTER_MAX_T * 2
-    q, kp, vp, tables = _setup(rng, 8, 8, t=t)
-    pos = jnp.asarray([0, 3], jnp.int32)
+    wrapper (page by page, `_scatter_rows_by_page`): identical pools and
+    output as the fused contract."""
+    q, kp, vp, tables = _setup(rng, page, nb, t=t)
+    pos = jnp.asarray(pos, jnp.int32)
+    active = None if active is None else jnp.asarray(active)
     nk = jnp.asarray(rng.standard_normal((2, 2, t, 64)), jnp.float32)
     nv = jnp.asarray(rng.standard_normal((2, 2, t, 64)), jnp.float32)
-    want, kp_ref, vp_ref = _reference(q, kp, vp, tables, pos, nk, nv)
+    if stacked:
+        return _assert_layer_indexed_equals_sliced(rng, q, kp, vp, tables,
+                                                   pos, nk, nv, active)
+    want, kp_ref, vp_ref = _reference(q, kp, vp, tables, pos, nk, nv, active)
     got, kp2, vp2 = paged_decode_attention(q, kp, vp, tables, pos, nk, nv,
-                                           interpret=True)
-    np.testing.assert_array_equal(np.asarray(kp2), np.asarray(kp_ref))
-    np.testing.assert_array_equal(np.asarray(vp2), np.asarray(vp_ref))
+                                           active, interpret=True)
+    # every allocatable page; the trash page too unless a chunk longer than
+    # a page was routed there (rows collide on it, and which one stays is
+    # nobody's contract)
+    live = slice(None) if active is None else slice(0, -1)
+    np.testing.assert_array_equal(np.asarray(kp2[live]), np.asarray(kp_ref[live]))
+    np.testing.assert_array_equal(np.asarray(vp2[live]), np.asarray(vp_ref[live]))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
@@ -182,3 +261,57 @@ def test_engine_streams_bit_exact_kernel_vs_gather():
     assert (rg, rk) == ("paged_gather", "paged_kernel")
     np.testing.assert_array_equal(ag, ak)
     np.testing.assert_array_equal(eg, ek)
+
+
+@pytest.mark.parametrize("route", ["paged_kernel", "paged_gather", "dense"])
+def test_layer_scan_carries_the_pool_only_on_the_kernel_route(route):
+    """What rides the layer scan of `forward`, read off its jaxpr (PR 27).
+    Kernel route: the whole stacked K and V pools are CARRIED (the kernel
+    indexes the layer) and nothing of the pool's shape is an xs or a ys —
+    so no layer's slice is cut out of the stack or put back. The gather
+    route and the dense layout scan the per-layer slices as xs/ys, as they
+    always have: only x is carried."""
+    from dllama_tpu.engine.kernel_select import resolve_kernels
+    from dllama_tpu.models.config import LlamaConfig
+    from dllama_tpu.models.llama import (KVCache, PagedKVCache, forward,
+                                         random_params)
+    from dllama_tpu.ops.layers import build_rope_cache
+    from dllama_tpu.ops.pallas.paged_attention import pool_lanes
+
+    cfg = LlamaConfig(dim=64, hidden_dim=128, n_layers=3, n_heads=4,
+                      n_kv_heads=2, vocab_size=96, seq_len=64)
+    params = random_params(cfg, seed=0, dtype=jnp.float32, quantize=False)
+    if route == "dense":
+        attn_fn = None
+        cache = KVCache.create(cfg, 2, jnp.float32)
+    else:
+        sel = resolve_kernels(
+            cfg, cfg.seq_len, 2, paged=True, page_size=8,
+            attn_impl="flash" if route == "paged_kernel" else "jnp")
+        assert sel.attn_route == route
+        attn_fn = sel.attn_fn
+        cache = PagedKVCache.create(
+            cfg, 2, 16, 8, jnp.float32, max_blocks=8,
+            lanes=pool_lanes(cfg.head_size) if route == "paged_kernel" else 0)
+    jaxpr = jax.make_jaxpr(
+        lambda p, c, tok, pos: forward(cfg, p, tok, pos, c,
+                                       build_rope_cache(cfg, cfg.seq_len),
+                                       attn_fn))(
+        params, cache, jnp.zeros((2, 1), jnp.int32), jnp.zeros(2, jnp.int32))
+    scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"
+             and e.params["length"] == cfg.n_layers]
+    assert len(scans) == 1
+    scan = scans[0]
+    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    shapes = lambda vs: [v.aval.shape for v in vs]
+    carry = shapes(scan.invars[n_consts:n_consts + n_carry])
+    xs = shapes(scan.invars[n_consts + n_carry:])
+    ys = shapes(scan.outvars[n_carry:])
+    stored = cache.k.shape
+    if route == "paged_kernel":
+        assert carry.count(stored) == 2 and n_carry == 3  # x, K pool, V pool
+        assert stored not in xs and stored not in ys
+        assert xs == [(cfg.n_layers,)] and ys == []  # the layer index alone
+    else:
+        assert n_carry == 1 and stored not in carry  # x alone
+        assert xs.count(stored) == 2 and ys == [stored, stored]

@@ -337,6 +337,29 @@ def test_eviction_under_pressure_admits_deferred_request():
         sched.shutdown()
 
 
+@pytest.mark.parametrize("n", [8, 3])  # to past the boundary / just short of it
+def test_decoder_at_a_page_boundary_takes_tree_pages(n):
+    """A pool whose spare pages all sit in the radix tree must not freeze a
+    decoding slot at its page boundary: the decode top-up evicts LRU leaves
+    for it (a decoder outranks a cached prefix, as an admission already
+    does). On the 7B cell this was 39% of slot-steps once the step got fast
+    enough to dry the pool inside a window (PERF.md section 6, PR 27)."""
+    eng = _engine("on", n_slots=2, kv_pages=6)
+    done = list(range(1, 18))  # a finished request: 2 full pages to the tree
+    eng.add(0, done, temperature=0.0, seed=0)
+    assert eng.radix_insert(0, done) == 2
+    eng.release(0)
+    eng.add(0, list(range(30, 39)), temperature=0.0, seed=0)
+    eng.add(1, list(range(50, 59)), temperature=0.0, seed=1)
+    assert eng.pool.free_count == 0 and eng.radix_stats()["pages"] == 2
+    eng.decode(n)  # rows 9.. : both slots cross row 16 when n == 8
+    assert eng.pos.tolist() == [9 + n, 9 + n]  # nobody froze
+    assert not eng.page_starved().any()
+    # the tree gave exactly what the decoders were short of, no more
+    assert eng.radix_stats()["evicted_pages"] == (2 if n == 8 else 0)
+    assert eng.pool.audit()["ok"]
+
+
 def test_warm_restart_drops_tree_resumes_bitexact():
     """A worker crash rebuilds pool + tree from scratch (never stale page
     refs); the tree re-fills from post-restart traffic and the interrupted
