@@ -1,28 +1,42 @@
-"""The benchmark's own writer (and reader) of the files the program serves.
+"""The benchmark's own writer (and reader) of the files the program serves:
+the formats and the discipline, and no model.
 
-A `.m` model file of random Q40 weights and a byte-level `.t` tokenizer, both
+A `.m` model file of random weights and a byte-level `.t` tokenizer, both
 made from the seed with numpy alone. Nothing here imports the program: the
-file formats are the interface (magic, key/value header, tensors in the order
-below), so the plain reference (`reference/`) reads exactly the bytes this
-module wrote and a refactor of the program's loader cannot change what the
-benchmark compares against. Copied from `chip_smoke.write_model` /
-`write_tokenizer` (PR 21) and made faster: random bytes are drawn as 64-bit
-words, not one call per byte.
+file formats are the interface, so the plain reference (`reference/`) reads
+exactly the bytes this module wrote and a refactor of the program's loader
+cannot change what the benchmark compares against.
 
-`.m` layout: i32 magic 0x0A00ABCD, i32 header bytes, (key, value) i32 pairs,
-then tensors: embedding f32 [vocab, dim]; per layer wq [dim, dim], wk
-[kv_dim, dim], wv [kv_dim, dim], wo [dim, dim], w1 [hidden, dim], w2
-[dim, hidden], w3 [hidden, dim] (Q40, stored [out, in] row-major), rms_att
-f32 [dim], rms_ffn f32 [dim]; final_norm f32 [dim]; wcls Q40 [vocab, dim].
-A Q40 block is 32 weights along `in`: an f16 scale, then 16 bytes whose low
-nibbles are weights 0..15 and high nibbles weights 16..31; weight =
-scale * (nibble - 8).
+WHICH tensors a model file holds, in what order, under which header keys
+and with which gains is a LAYOUT: a module the configuration file names
+under `layout` (`benchmark.layouts.llama`), found by that name like the
+reference. A layout gives
+
+    shapes_of(config) -> dict        the file-level sizes of a configuration
+    header(shapes) -> [(key, value)] the header's i32 pairs, its arch id
+                                     among them
+    tensor_plan(shapes, weights={}) -> [Entry]   in on-disk order; `weights`
+                                     is the configuration's block of gains
+    read_header(path) -> (shapes, header bytes)
+    tensor_views(path) -> (shapes, {name: (uint8 view, file shape, kind)})
+
+and this module writes whatever plan it is handed: it names no tensor, no
+header key and no architecture.
+
+`.m` format: i32 magic 0x0A00ABCD, i32 header bytes, (key, value) i32
+pairs, then the plan's tensors back to back. An `f32` tensor is its values;
+a `q40` tensor is stored [out, in] row-major in blocks of 32 weights along
+`in`: an f16 scale, then 16 bytes whose low nibbles are weights 0..15 and
+high nibbles weights 16..31; weight = scale * (nibble - 8).
 """
 
 from __future__ import annotations
 
+import dataclasses
+import importlib
 import os
 import struct
+from typing import Callable
 
 import numpy as np
 
@@ -32,85 +46,111 @@ Q_BLOCK = 32
 Q40_BLOCK_BYTES = 2 + Q_BLOCK // 2
 #: variance of (nibble - 8) once 0 is folded onto 8: (2 * 140 + 0) / 16
 NIBBLE_VARIANCE = 17.5
-#: what a configuration's `weights` block may set, and the default:
-#: `attention_sharpness`, the standard deviation of the attention scores (wq
-#: is that much larger than unit gain)
-WEIGHT_DEFAULTS = {"attention_sharpness": 1.0}
-
-# header keys of the `.m` format (the program's models/config.HeaderKey)
-_K = {"version": 0, "arch": 1, "dim": 2, "hidden_dim": 3, "n_layers": 4,
-      "n_heads": 5, "n_kv_heads": 6, "n_experts": 7, "n_active_experts": 8,
-      "vocab_size": 9, "seq_len": 10, "hidden_act": 11, "rope_theta": 12,
-      "weight_type": 13, "norm_epsilon_x1e12": 100}
-ARCH_LLAMA, ACT_SILU, FT_Q40 = 0xABCD00, 1, 2
 
 
-def shapes_of(config: dict) -> dict:
-    """The file-level sizes of a configuration file (HF key names in, the
-    `.m` header's names out)."""
-    dim = int(config["hidden_size"])
-    heads = int(config["num_attention_heads"])
-    kv_heads = int(config["num_key_value_heads"])
-    return {"dim": dim, "hidden_dim": int(config["intermediate_size"]),
-            "n_layers": int(config["num_hidden_layers"]), "n_heads": heads,
-            "n_kv_heads": kv_heads, "vocab_size": int(config["vocab_size"]),
-            "seq_len": int(config["max_position_embeddings"]),
-            "rope_theta": float(config["rope_theta"]),
-            "norm_epsilon": float(config["rms_norm_eps"]),
-            "head_size": dim // heads, "kv_dim": dim * kv_heads // heads}
+@dataclasses.dataclass(frozen=True)
+class Entry:
+    """One tensor of a plan: all the writer needs to know of it.
+
+    `kind` "q40": random blocks whose scales are sized `1 / sqrt(17.5 * in)`
+    (a matmul keeps its input's magnitude) times `gain`: one number, or
+    ((rows, gain), ...) by block of output rows, top to bottom, for a
+    matrix whose outputs are several projections side by side. With
+    `derived_from` it is instead the Q40 quantisation of that f32 entry (a
+    tied head) and draws nothing.
+    `kind` "f32": `init(rng, n) -> float32[n]`, given the entry's own
+    generator (`ones` and `uniform` below; a layout may bring its own)."""
+
+    name: str
+    shape: tuple
+    kind: str
+    gain: float | tuple = 1.0
+    init: Callable | None = None
+    derived_from: str | None = None
+
+    @property
+    def nbytes(self) -> int:
+        n = int(np.prod(self.shape))
+        return 4 * n if self.kind == "f32" else n // Q_BLOCK * Q40_BLOCK_BYTES
 
 
-def tensor_plan(s: dict) -> list[tuple[str, tuple, str]]:
-    """(name, file shape, 'f32' | 'q40') in on-disk order."""
-    plan = [("embedding", (s["vocab_size"], s["dim"]), "f32")]
-    for li in range(s["n_layers"]):
-        plan += [(f"layers.{li}.wq", (s["dim"], s["dim"]), "q40"),
-                 (f"layers.{li}.wk", (s["kv_dim"], s["dim"]), "q40"),
-                 (f"layers.{li}.wv", (s["kv_dim"], s["dim"]), "q40"),
-                 (f"layers.{li}.wo", (s["dim"], s["dim"]), "q40"),
-                 (f"layers.{li}.w1", (s["hidden_dim"], s["dim"]), "q40"),
-                 (f"layers.{li}.w2", (s["dim"], s["hidden_dim"]), "q40"),
-                 (f"layers.{li}.w3", (s["hidden_dim"], s["dim"]), "q40"),
-                 (f"layers.{li}.rms_att", (s["dim"],), "f32"),
-                 (f"layers.{li}.rms_ffn", (s["dim"],), "f32")]
-    plan += [("final_norm", (s["dim"],), "f32"),
-             ("wcls", (s["vocab_size"], s["dim"]), "q40")]
-    return plan
+def ones(rng: np.random.Generator, n: int) -> np.ndarray:
+    return np.ones(n, np.float32)
 
 
-def tensor_nbytes(shape: tuple, kind: str) -> int:
-    n = int(np.prod(shape))
-    return 4 * n if kind == "f32" else n // Q_BLOCK * Q40_BLOCK_BYTES
+def uniform(half_width: float) -> Callable:
+    """An initialiser: uniform in +-half_width."""
+    def init(rng: np.random.Generator, n: int) -> np.ndarray:
+        return (rng.random(n, np.float32) - 0.5) * np.float32(2 * half_width)
+    return init
 
 
-def _header(s: dict) -> bytes:
-    kv = [(_K["version"], 0), (_K["arch"], ARCH_LLAMA), (_K["dim"], s["dim"]),
-          (_K["hidden_dim"], s["hidden_dim"]), (_K["n_layers"], s["n_layers"]),
-          (_K["n_heads"], s["n_heads"]), (_K["n_kv_heads"], s["n_kv_heads"]),
-          (_K["n_experts"], 0), (_K["n_active_experts"], 0),
-          (_K["vocab_size"], s["vocab_size"]), (_K["seq_len"], s["seq_len"]),
-          (_K["hidden_act"], ACT_SILU),
-          (_K["rope_theta"], int(s["rope_theta"])),
-          (_K["weight_type"], FT_Q40)]
-    if abs(s["norm_epsilon"] - 1e-5) > 1e-12:
-        kv.append((_K["norm_epsilon_x1e12"],
-                   int(round(s["norm_epsilon"] * 1e12))))
-    body = b"".join(struct.pack("<ii", k, v) for k, v in kv)
+def layout_of(config: dict):
+    """The layout module a configuration names. No default architecture: a
+    configuration without the key fails by name, as a missing file does."""
+    if "layout" not in config:
+        raise SystemExit(f"benchmark: the configuration {config.get('name')!r} "
+                         "names no `layout` (a module such as "
+                         "benchmark.layouts.llama)")
+    try:
+        return importlib.import_module(config["layout"])
+    except ModuleNotFoundError as e:
+        raise SystemExit(f"benchmark: the configuration {config.get('name')!r} "
+                         f"names the layout {config['layout']!r}: {e}") from e
+
+
+# ------------------------------------------------------------ the header
+
+
+def pack_header(pairs: list) -> bytes:
+    body = b"".join(struct.pack("<ii", k, v) for k, v in pairs)
     return struct.pack("<ii", MODEL_MAGIC, 8 + len(body)) + body
 
 
-def _tensor_bytes(seq: np.random.SeedSequence, name: str, shape: tuple,
-                  kind: str, weights: dict) -> np.ndarray:
-    """One tensor's bytes as they lie on disk, from its own seed sequence."""
-    rng = np.random.Generator(np.random.PCG64(seq))
-    n = int(np.prod(shape))
-    if kind == "f32":
-        if name == "embedding":
-            x = (rng.random(n, np.float32) - 0.5) * np.float32(0.04)
-        else:  # rms norm gains
-            x = np.ones(n, np.float32)
-        return x.view(np.uint8)
-    blocks = n // Q_BLOCK
+def parse_header(path: str) -> tuple[dict, int]:
+    """({key: value} as the file has them, header bytes) of a `.m` file."""
+    with open(path, "rb") as f:
+        magic, size = struct.unpack("<ii", f.read(8))
+        if magic != MODEL_MAGIC:
+            raise ValueError(f"{path}: not a .m file (magic {magic:#x})")
+        body = f.read(size - 8)
+    return dict(struct.unpack_from("<ii", body, i)
+                for i in range(0, len(body), 8)), size
+
+
+def views(path: str, offset: int, plan: list) -> dict:
+    """{name: (uint8 memmap view, file shape, kind)} of the plan's tensors
+    from `offset` on; the file must end where the plan does."""
+    data = np.memmap(path, dtype=np.uint8, mode="r")
+    out = {}
+    for e in plan:
+        out[e.name] = (data[offset:offset + e.nbytes], e.shape, e.kind)
+        offset += e.nbytes
+    if offset != data.shape[0]:
+        raise ValueError(f"{path}: {data.shape[0]} bytes on disk, the header "
+                         f"accounts for {offset}")
+    return out
+
+
+# ----------------------------------------------------------- the tensors
+
+
+def _block_gains(entry: Entry) -> np.ndarray | np.float32:
+    """The entry's gain per Q40 block: a scalar, or one value a block where
+    the gain goes by block of output rows."""
+    if not isinstance(entry.gain, tuple):
+        return np.float32(entry.gain)
+    rows = [r for r, _ in entry.gain]
+    if sum(rows) != entry.shape[0]:
+        raise ValueError(f"{entry.name}: gains cover {sum(rows)} rows of "
+                         f"{entry.shape[0]}")
+    per_row = entry.shape[-1] // Q_BLOCK
+    return np.repeat(np.asarray([g for _, g in entry.gain], np.float32),
+                     [r * per_row for r in rows])
+
+
+def _random_q40(rng: np.random.Generator, entry: Entry) -> np.ndarray:
+    blocks = int(np.prod(entry.shape)) // Q_BLOCK
     # random bytes for the whole record, then the two scale bytes of each
     # block written over them: one pass, no 16-of-18 strided copy
     words = rng.bit_generator.random_raw((blocks * Q40_BLOCK_BYTES + 7) // 8)
@@ -127,36 +167,66 @@ def _tensor_bytes(seq: np.random.SeedSequence, name: str, shape: tuple,
     rec = words.view(np.uint16)[:blocks * (Q40_BLOCK_BYTES // 2)].reshape(
         blocks, Q40_BLOCK_BYTES // 2)
     # scales sized so a matmul keeps its input's magnitude (the weights'
-    # variance is 17.5 scale^2); wq times the configuration's sharpness
-    scale = np.float32(1.0 / np.sqrt(NIBBLE_VARIANCE * shape[-1]))  # [out, in]
-    if name.endswith(".wq"):
-        scale *= np.float32(weights["attention_sharpness"])
+    # variance is 17.5 scale^2), times the entry's gain
+    scale = np.float32(1.0 / np.sqrt(NIBBLE_VARIANCE * entry.shape[-1]))  # [out, in]
+    scale = scale * _block_gains(entry)
     scales = scale * (np.float32(0.5) + rng.random(blocks, np.float32))
     rec[:, 0] = scales.astype(np.float16).view(np.uint16)
     return rec.reshape(-1).view(np.uint8)
 
 
+def quantise_q40(x: np.ndarray) -> np.ndarray:
+    """float32 values (a multiple of 32 of them) -> their Q40 record bytes:
+    per block the scale is the entry of largest magnitude over -8, so that
+    entry lands on nibble 0 exactly, and every weight rounds to the nearest
+    nibble (the reference converter's rule)."""
+    blocks = np.asarray(x, np.float32).reshape(-1, Q_BLOCK)
+    peak = blocks[np.arange(len(blocks)), np.abs(blocks).argmax(axis=1)]
+    scale = (peak / np.float32(-8.0)).astype(np.float16)
+    d = scale.astype(np.float32)
+    inv = np.divide(np.float32(1.0), d, out=np.zeros_like(d), where=d != 0)
+    q = np.clip(blocks * inv[:, None] + np.float32(8.5), 0, 15).astype(np.uint8)
+    rec = np.empty((len(blocks), Q40_BLOCK_BYTES), np.uint8)
+    rec[:, :2] = scale.view(np.uint8).reshape(-1, 2)
+    rec[:, 2:] = q[:, :Q_BLOCK // 2] | (q[:, Q_BLOCK // 2:] << 4)
+    return rec.reshape(-1)
+
+
+def _tensor_bytes(i: int, plan: list, seqs: list) -> np.ndarray:
+    """The i-th tensor's bytes as they lie on disk, from its own seed
+    sequence (a derived tensor: from its source's)."""
+    entry = plan[i]
+    if entry.derived_from is not None:
+        src = next(j for j, e in enumerate(plan) if e.name == entry.derived_from)
+        if plan[src].kind != "f32" or plan[src].shape != entry.shape:
+            raise ValueError(f"{entry.name}: derived from {entry.derived_from}, "
+                             "which is not an f32 tensor of its shape")
+        return quantise_q40(_tensor_bytes(src, plan, seqs).view(np.float32))
+    rng = np.random.Generator(np.random.PCG64(seqs[i]))
+    if entry.kind == "f32":
+        return entry.init(rng, int(np.prod(entry.shape))).view(np.uint8)
+    return _random_q40(rng, entry)
+
+
 def write_model(path: str, config: dict, seed: int, workers: int = 4) -> int:
     """A whole `.m` of random weights, the same bytes for the same seed.
     Q40 tensors are written as they lie on disk (f16 scale + 16 packed
-    bytes a block), so no float copy of the model is made. Weights are
-    symmetric about zero, scales sized so that every matmul keeps its
-    input's magnitude (see _tensor_bytes); norm gains are 1; the embedding
-    is uniform in +-0.02. Each tensor has its own stream spawned from the seed, so a
-    few threads make them side by side and the file does not depend on how
+    bytes a block), so no float copy of the model is made. Each plan entry
+    has its own stream spawned from the seed, in plan order, so a few
+    threads make them side by side and the file does not depend on how
     many. Returns the bytes written."""
     from concurrent.futures import ThreadPoolExecutor
 
-    s = shapes_of(config)
-    plan = tensor_plan(s)
-    weights = {**WEIGHT_DEFAULTS, **config.get("weights", {})}
+    layout = layout_of(config)
+    s = layout.shapes_of(config)
+    plan = layout.tensor_plan(s, config.get("weights", {}))
     seqs = np.random.SeedSequence(int(seed)).spawn(len(plan))
     tmp = path + ".tmp"
     with open(tmp, "wb") as f, ThreadPoolExecutor(workers) as pool:
-        f.write(_header(s))
+        f.write(pack_header(layout.header(s)))
         pending = []
-        for i, (seq, (name, shape, kind)) in enumerate(zip(seqs, plan)):
-            pending.append(pool.submit(_tensor_bytes, seq, name, shape, kind, weights))
+        for i in range(len(plan)):
+            pending.append(pool.submit(_tensor_bytes, i, plan, seqs))
             if len(pending) > 2 * workers:  # bounded: a layer or so in flight
                 f.write(pending.pop(0).result().data)
         for fut in pending:
@@ -166,40 +236,7 @@ def write_model(path: str, config: dict, seed: int, workers: int = 4) -> int:
     return size
 
 
-def read_header(path: str) -> tuple[dict, int]:
-    """(sizes as `shapes_of` names them, header bytes) of a `.m` file."""
-    with open(path, "rb") as f:
-        magic, size = struct.unpack("<ii", f.read(8))
-        if magic != MODEL_MAGIC:
-            raise ValueError(f"{path}: not a .m file (magic {magic:#x})")
-        body = f.read(size - 8)
-    names = {v: k for k, v in _K.items()}
-    kv = {names[k]: v for k, v in
-          (struct.unpack_from("<ii", body, i) for i in range(0, len(body), 8))}
-    if kv["arch"] != ARCH_LLAMA or kv["weight_type"] != FT_Q40:
-        raise ValueError(f"{path}: the reference reads Q40 LLAMA files only")
-    s = {k: kv[k] for k in ("dim", "hidden_dim", "n_layers", "n_heads",
-                            "n_kv_heads", "vocab_size", "seq_len")}
-    s["rope_theta"] = float(kv["rope_theta"])
-    s["norm_epsilon"] = kv.get("norm_epsilon_x1e12", 10_000_000) / 1e12
-    s["head_size"] = s["dim"] // s["n_heads"]
-    s["kv_dim"] = s["dim"] * s["n_kv_heads"] // s["n_heads"]
-    return s, size
-
-
-def tensor_views(path: str) -> tuple[dict, dict]:
-    """(sizes, {name: (uint8 memmap view, file shape, kind)}) of a `.m`."""
-    s, offset = read_header(path)
-    data = np.memmap(path, dtype=np.uint8, mode="r")
-    views = {}
-    for name, shape, kind in tensor_plan(s):
-        n = tensor_nbytes(shape, kind)
-        views[name] = (data[offset:offset + n], shape, kind)
-        offset += n
-    if offset != data.shape[0]:
-        raise ValueError(f"{path}: {data.shape[0]} bytes on disk, the header "
-                         f"accounts for {offset}")
-    return s, views
+# --------------------------------------------------------- the tokenizer
 
 
 def write_tokenizer(path: str, vocab_size: int) -> None:

@@ -5,7 +5,8 @@ RMSNorm -> q/k/v -> RoPE on interleaved pairs (the `.m` layout of q and k)
 -> SwiGLU (silu(w1 x) * w3 x) -> w2, residual; final RMSNorm; head. All in
 float32 under `jax.default_matmul_precision("highest")`, no kernels, no
 cache, no batching, and no import from the program: the weights are the
-bytes `benchmark/files.py` wrote, dequantised here (f16 scale x (nibble -
+bytes `benchmark/files.py` wrote, found through the layout
+(`benchmark/layouts/llama.py`) and dequantised here (f16 scale x (nibble -
 8)). One layer's float32 weights are live at a time (0.8-0.9 GB at 7B), and
 the head is computed only at the positions asked for.
 
@@ -23,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark import files
+from benchmark.layouts import llama as layout
 
 PRECISION = "highest"
 
@@ -98,7 +100,7 @@ def logits_at(model_path: str, sequences: list, positions: list) -> list:
     """For each token sequence (1-d int array), the float32 logits
     [len(positions[i]), vocab] at the positions asked for, from one full
     causal forward pass over the whole sequence."""
-    s, views = files.tensor_views(model_path)
+    s, views = layout.tensor_views(model_path)
     emb = np.asarray(views["embedding"][0]).view(np.float32).reshape(
         views["embedding"][1])
     with jax.default_matmul_precision(PRECISION):

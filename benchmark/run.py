@@ -3,10 +3,12 @@
     python3 benchmark/run.py --workload NAME --seed N --seconds S --trace 0|1
 
 Everything that belongs to one cell is found by the names in BENCHMARK.json:
-the configuration's file, `traffic/<mix>.json` (which names its generator
-under generators/), and `metrics/<name>.json` for every metric the cell
-reports (each names its reducer under reducers/). There is no branch on a
-cell, a configuration or a metric here; a missing file fails by name.
+the configuration's file (which names its model-file layout under layouts/
+and its reference under reference/), `traffic/<mix>.json` (which names its
+generator under generators/), and `metrics/<name>.json` for every metric the
+cell reports (each names its reducer under reducers/). There is no branch
+on a cell, a configuration, an architecture or a metric here; a missing
+file fails by name.
 
 This process never imports JAX (a chip belongs to one process): it writes
 the model files from the seed, starts `serve_child.py` (which loads once,
@@ -71,6 +73,7 @@ def resolve(workload: str, trace: bool, manifest_path: str) -> dict:
                          f"configuration {cell['config']!r}, which "
                          "BENCHMARK.json does not list")
     config = load_json(os.path.join(ROOT, entry["file"]), "configuration")
+    files.layout_of(config)  # a missing layout fails here, by name
     traffic = load_json(os.path.join(HERE, "traffic", cell["traffic"] + ".json"),
                         "traffic")
     wanted = manifest["per_layer"] if trace else manifest["end_to_end"]
@@ -348,7 +351,7 @@ def main(argv=None) -> int:
                 window_s=run["trace"]["window_s"], busy_s=run["trace"]["busy_s"],
                 modules={k: [len(v), sum(v)]
                          for k, v in run["trace"]["modules"].items()},
-                ops=[[o["name"], o["count"], o["seconds"]]
+                ops=[[o["module"], o["name"], o["count"], o["seconds"]]
                      for o in run["trace"]["ops"][:30]])
         struct = structural(run, kv)
         say(phase="window", seconds=window["t1"] - window["t0"], **struct,
@@ -373,6 +376,15 @@ def main(argv=None) -> int:
             result["breakdown"] = {"device_ops": run["trace"]["device_ops"],
                                    "idle_gaps": run["trace"]["idle_gaps"]}
         print(json.dumps(result), flush=True)
+        # each number compared beside its limit, as the last lines of stderr
+        # too: where a run is not correct, that is what the driver keeps
+        for k, limit in chk["limits"].items():
+            if k in chk:
+                sys.stderr.write(f"check {k} = {chk[k]!r} (limit {limit!r})\n")
+        sys.stderr.write(f"check route = {chk['route']} (expected "
+                         f"{chk['expected_route']}); structure ok = "
+                         f"{struct['ok']}; server exit = {rc}; correct = "
+                         f"{result['correct']}\n")
         return 0
     finally:
         if child.proc.poll() is None:

@@ -45,6 +45,7 @@ def run_cli(args, **kw):
 
 def test_model_file_is_deterministic_and_read_by_the_program(tmp_path, tiny_files):
     from benchmark import files
+    from benchmark.layouts import llama as layout
     from dllama_tpu.models.formats import read_header
     from dllama_tpu.tokenizer.tokenizer import Tokenizer
 
@@ -58,7 +59,7 @@ def test_model_file_is_deterministic_and_read_by_the_program(tmp_path, tiny_file
         assert first == b.read()
         assert first != c.read()
     prog, header = read_header(tiny_files[0])
-    mine, header2 = files.read_header(tiny_files[0])
+    mine, header2 = layout.read_header(tiny_files[0])
     assert header == header2
     assert (prog.dim, prog.hidden_dim, prog.n_layers, prog.n_kv_heads) == (
         mine["dim"], mine["hidden_dim"], mine["n_layers"], mine["n_kv_heads"])
@@ -113,6 +114,7 @@ def test_check_fails_when_one_layers_weights_differ(tiny_files, tmp_path):
     """The reference reads the file; the engine is handed another model
     (one layer's w1 redrawn): rel L2 and the token margins both show it."""
     from benchmark import check, files
+    from benchmark.layouts import llama as layout
     from dllama_tpu.engine.loader import load_model
 
     cfg = tiny_config()
@@ -122,9 +124,9 @@ def test_check_fails_when_one_layers_weights_differ(tiny_files, tmp_path):
     other = str(tmp_path / "perturbed.m")
     with open(tiny_files[0], "rb") as f:
         data = bytearray(f.read())
-    _, views = files.tensor_views(tiny_files[0])
+    _, views = layout.tensor_views(tiny_files[0])
     raw = views["layers.1.w1"][0]
-    start = raw.ctypes.data - views["embedding"][0].ctypes.data + files.read_header(tiny_files[0])[1]
+    start = raw.ctypes.data - views["embedding"][0].ctypes.data + layout.read_header(tiny_files[0])[1]
     rng = np.random.default_rng(9)
     blocks = np.frombuffer(data, np.uint8, len(raw), start).reshape(-1, files.Q40_BLOCK_BYTES)
     blocks[:, 2:] = rng.integers(0, 256, blocks[:, 2:].shape, np.uint8)
@@ -296,7 +298,7 @@ def test_whole_command_end_to_end_on_cpu(workload, trace):
     names = {x["name"] for x in (m["per_layer"] if trace else m["end_to_end"])
              if workload in x.get("workloads", [workload])}
     if trace:
-        names -= {"chunk_ms_p50", "q40_matmul_roofline"}  # no device plane on a CPU
+        names -= {"q40_matmul_roofline"}  # no device plane on a CPU
         assert "breakdown" in last and "busy_s" in last["device"]
     assert set(last["metrics"]) == names
     assert all(v["value"] == v["value"] for v in last["metrics"].values())
@@ -319,6 +321,13 @@ def test_trace_reduction_of_the_recorded_trace():
     assert {k: len(v) for k, v in got["modules"].items()} == want["module_counts"]
     assert got["device_ops"][0][0] == want["top_op"]
     assert 0 < got["busy_s"] <= got["window_s"]
+    # device_ops are sums by group over the (module, name) pairs: the same
+    # totals as when ops were keyed by name alone (the five largest, as
+    # read with PR 27's trace_reduce.py)
+    assert [k for k, _ in got["device_ops"][:5]] == list(want["device_ops_top5"])
+    for k, v in got["device_ops"][:5]:
+        assert v == pytest.approx(want["device_ops_top5"][k], rel=1e-9)
+    assert all(o["module"] for o in got["ops"])  # every op lies in a module
     calls = [o for o in got["ops"] if o["group"] == "_blockdot_call"]
     assert sum(o["count"] for o in calls) == want["blockdot_calls"]
     assert sum(o["seconds"] for o in calls) == pytest.approx(want["blockdot_seconds"], rel=1e-9)
@@ -356,3 +365,48 @@ def test_trace_reduction_arithmetic_on_a_made_up_trace():
     gaps = dict((k, v) for k, v in out["idle_gaps"])
     assert gaps["before jit_step"] == pytest.approx(200e-9)
     assert gaps["trace start"] == gaps["trace end"] == 0.0
+
+
+@pytest.mark.parametrize("first", ["jit_dllama_decode", "jit_dllama_hybrid"])
+def test_one_op_name_in_two_modules_is_priced_at_each_modules_shape(first):
+    """Instruction names are numbered per XLA module: `_blockdot_call.71` is
+    a layer's matmul in the decode program and the 102,400-wide head in a
+    hybrid program. Each is priced at its own shape, whichever the capture
+    holds first (keyed by name alone, the first text seen priced both)."""
+    from benchmark import trace_reduce
+    from benchmark.costs import q40_matmul
+    from benchmark.reducers import trace_call_roofline
+
+    layer = ("%_blockdot_call.71 = f32[16,4096]{1,0} custom-call(bf16[16,4096]{1,0} %x, "
+             "u8[30,2048,4096]{2,1,0} %packed, f16[30,128,4096]{2,1,0} %scales)")
+    head = ("%_blockdot_call.71 = f32[16,102400]{1,0} custom-call(bf16[16,4096]{1,0} %x, "
+            "u8[2048,102400]{1,0} %packed, f16[128,102400]{1,0} %scales)")
+    ev = lambda n, s, d: (n, s, d, {})
+    progs = {"jit_dllama_decode": (layer, 30, 40_000), "jit_dllama_hybrid": (head, 1, 900_000)}
+    mods, ops, t = [], [], 1_000
+    for name in (first, *(n for n in progs if n != first)):
+        hlo, count, dur = progs[name]
+        mods.append(ev(f"{name}(7)", t, count * dur + 10))
+        ops += [ev(hlo, t + i * dur, dur) for i in range(count)]
+        t += count * dur + 5_000
+    out = trace_reduce.reduce_planes([{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Modules", "events": mods}, {"name": "XLA Ops", "events": ops}]}])
+    got = {o["module"]: o for o in out["ops"]}
+    assert set(got) == set(progs)
+    assert got["jit_dllama_decode"]["hlo"] == layer and got["jit_dllama_hybrid"]["hlo"] == head
+    assert (got["jit_dllama_decode"]["count"], got["jit_dllama_hybrid"]["count"]) == (30, 1)
+    assert out["device_ops"] == [["_blockdot_call", pytest.approx((30 * 40_000 + 900_000) * 1e-9)]]
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    share = trace_call_roofline.reduce({"match": "^_blockdot_call$", "cost": "q40_matmul"},
+                                       {"trace": out, "config": {}, "peaks": lambda: peaks})
+    least = (30 * q40_matmul.cost(16, 4096, 4096)[1] + q40_matmul.cost(16, 4096, 102400)[1]) / 819e9
+    assert share == pytest.approx(100 * least / ((30 * 40_000 + 900_000) * 1e-9))
+    assert share < 100
+    # an op that starts outside every module's execution keeps "" and is not
+    # merged into a module's entry
+    stray = trace_reduce.reduce_planes([{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Modules", "events": [ev("jit_a(1)", 100, 50)]},
+        {"name": "XLA Ops", "events": [ev("%k.1 = f32[8] fusion()", 110, 10),
+                                       ev("%k.1 = f32[9] fusion()", 400, 10)]}]}])
+    assert sorted((o["module"], o["hlo"][-15:]) for o in stray["ops"]) == [
+        ("", "f32[9] fusion()"), ("jit_a", "f32[8] fusion()")]

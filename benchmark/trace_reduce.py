@@ -9,13 +9,17 @@
       "busy_s":    union of the intervals in which an XLA op ran on a device
                    plane, averaged over the device planes,
       "modules":   {XLA module name: [device seconds of each execution]},
-      "ops":       [{"name", "group", "count", "seconds", "hlo"}] device ops by
-                   call site: `name` is the HLO instruction's name
-                   (`_blockdot_call.75`), `group` that name without its
-                   number (`_blockdot_call`: every Q40 matmul call), `seconds`
-                   the op's SELF time (a `while` holds its body's ops on the
-                   same line; what they cover is taken out of it), `hlo` the
-                   instruction's text with its shapes,
+      "ops":       [{"module", "name", "group", "count", "seconds", "hlo"}]
+                   device ops by call site: `module` is the XLA module whose
+                   execution holds the op's start (instruction names are
+                   numbered per module: `_blockdot_call.71` is a layer's
+                   matmul in one program and the head in another), `name`
+                   the HLO instruction's name (`_blockdot_call.75`), `group`
+                   that name without its number (`_blockdot_call`: every Q40
+                   matmul call), `seconds` the op's SELF time (a `while`
+                   holds its body's ops on the same line; what they cover is
+                   taken out of it), `hlo` the instruction's text with its
+                   shapes, one per (module, name),
       "device_ops": [[group, self seconds]] the ten groups that took most,
       "idle_gaps":  [[label, seconds]] the ten labels with most idle time; a
                    gap is labelled by the module the device ran next (what
@@ -30,6 +34,7 @@ no backend. `python benchmark/trace_reduce.py FILE` prints the reduction;
 
 from __future__ import annotations
 
+import bisect
 import gzip
 import json
 import re
@@ -42,17 +47,24 @@ _NUMBER = re.compile(r"(\.\d+)?(\.(rem|clone)[.\w]*)?$")
 
 
 def _self_times(events: list) -> list:
-    """(name, self ns) per event of one line, where an event that lies
-    inside another (a loop's body inside the loop) is taken out of it."""
+    """[name, start ns, self ns] per event of one line, where an event that
+    lies inside another (a loop's body inside the loop) is taken out of it."""
     out, stack = [], []  # stack of [end, index into out]
     for n, s, d, _ in sorted(events, key=lambda e: (e[1], -e[2])):
         while stack and s >= stack[-1][0]:
             stack.pop()
         if stack:
-            out[stack[-1][1]][1] -= d
-        out.append([n, d])
+            out[stack[-1][1]][2] -= d
+        out.append([n, s, d])
         stack.append([s + d, len(out) - 1])
     return out
+
+
+def _module_at(mods: list, starts: list, t: int) -> str:
+    """The module (of `mods`, sorted (start, end, name)) whose execution
+    holds the instant t; "" where none does."""
+    i = bisect.bisect_right(starts, t) - 1
+    return mods[i][2] if i >= 0 and t < mods[i][1] else ""
 
 
 def _union(intervals: list) -> float:
@@ -101,13 +113,16 @@ def reduce_planes(planes: list) -> dict:
                       for n, s, d, _ in lines.get(MODULE_LINE, ()))
         for s, e, n in mods:
             modules.setdefault(n, []).append((e - s) / 1e9)
+        starts = [s for s, _, _ in mods]
         op_iv = [(s, s + d) for _, s, d, _ in lines.get(OP_LINE, ())]
-        for hlo, self_ns in _self_times(lines.get(OP_LINE, ())):
+        for hlo, start, self_ns in _self_times(lines.get(OP_LINE, ())):
             name = hlo.split(" = ", 1)[0].lstrip("%")
-            o = ops.setdefault(name, {"name": name,
-                                      "group": _NUMBER.sub("", name),
-                                      "count": 0, "seconds": 0.0,
-                                      "hlo": hlo[:1200]})
+            module = _module_at(mods, starts, start)
+            o = ops.setdefault((module, name),
+                               {"module": module, "name": name,
+                                "group": _NUMBER.sub("", name),
+                                "count": 0, "seconds": 0.0,
+                                "hlo": hlo[:1200]})
             o["count"] += 1
             o["seconds"] += max(self_ns, 0) / 1e9
         iv = op_iv or [(s, e) for s, e, _ in mods]
