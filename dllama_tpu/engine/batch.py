@@ -29,6 +29,7 @@ import os
 import threading
 import time
 from collections import OrderedDict
+import dataclasses
 from dataclasses import dataclass
 from functools import partial
 
@@ -55,6 +56,12 @@ class AdmissionAborted(RuntimeError):
     """A cooperative abort fired between prefill chunks of add() — the slot
     is released-equivalent (pos unspecified); callers must not reuse its
     cached rows."""
+
+
+class StateNotResumable(ValueError):
+    """add_begin(start_pos=r) on a model with recurrent state, with r > 0,
+    where the slot's state does not stand at row r: the state cannot be
+    rewound or re-entered, so the caller recomputes the prompt from row 0."""
 
 
 class PageExhausted(RuntimeError):
@@ -607,6 +614,7 @@ class Admission:
     off: int = 0
     logits: jax.Array | None = None  # [1, V] slot row from the LAST chunk
     req_id: str = ""  # serving-tier request id, for engine-level log/trace lines
+    sampled: tuple | None = None  # (token [1] on the device, its key): add_sample
 
 
 @dataclass
@@ -731,11 +739,37 @@ class BatchEngine:
         # implicit per-chunk upload into an error instead of a silently
         # serialized pipeline. Boundary uploads (vector refresh, prefill
         # chunks) happen outside the guarded window and stay legal.
+        state_dtype=jnp.float32,  # element type of the recurrent state S
+        # (models/llama.RecurrentState) where the model has state-space
+        # layers: a running sum over the whole context, float32 as served
     ):
         from dllama_tpu.ops.layers import build_rope_cache
 
         self.cfg = cfg
         self.params = params
+        self.state_dtype = state_dtype
+        if cfg.recurrent:
+            # per-slot recurrent state: fixed-size, not pageable, and it
+            # cannot be rewound or re-entered at an arbitrary row. What
+            # assumes it can is refused or resolved off HERE, by mechanism
+            if shardings is not None:
+                raise ValueError(
+                    "a model with per-slot recurrent state serves on one "
+                    "device: the state has no sharding under a mesh yet")
+            if spec:
+                raise ValueError(
+                    "speculative decoding rewinds rejected draft rows; "
+                    "recurrent state cannot be rewound (--spec-k must be 0)")
+            if radix_cache == "on" or kv_host_pages > 0:
+                raise ValueError(
+                    "the radix prefix cache and its host spill tier re-enter "
+                    "a prefix at any page boundary; recurrent state stands "
+                    "at one row only (--radix-cache off, --kv-host-pages 0)")
+            if radix_cache == "auto":
+                log.info("radix prefix cache off: the model's recurrent "
+                         "state cannot be re-entered at a page boundary "
+                         "(prefix rows are recomputed)")
+                radix_cache = "off"
         if fuse_weights:
             if shardings is not None:
                 raise ValueError("fuse_weights requires an unsharded engine "
@@ -767,13 +801,18 @@ class BatchEngine:
         sel = resolve_kernels(cfg, self.seq_len, n_slots, kernels, attn_impl,
                               shardings, paged=kv_layout == "paged",
                               page_size=self.page_size,
-                              cache_dtype=cache_dtype)
+                              cache_dtype=cache_dtype,
+                              state_dtype=state_dtype)
         mm, mm_in, attn_fn = sel.mm, sel.mm_in, sel.attn_fn
         self.backend = sel.backend
         # which attention path actually runs ('paged_kernel' = the fused
         # flash-decode kernel, 'paged_gather' = jnp view gather, ...) — the
-        # cost model prices the two paged routes very differently
-        self.attn_route = sel.attn_route
+        # cost model prices the two paged routes very differently — and,
+        # behind a '+', what the recurrent state's decode step runs on and
+        # the state's element type ('paged_kernel+ssm_step.float32')
+        self.attn_route = sel.route
+        self._paged_route = sel.attn_route
+        self._state_step = sel.state_step
         self.pool: PagePool | None = None
         if kv_layout == "paged":
             if shardings is not None:
@@ -793,7 +832,17 @@ class BatchEngine:
             self.pool.write_horizons = self._write_horizons
             self.cache = self._new_paged_cache(n_pages, max_blocks)
         else:
-            self.cache = KVCache.create(cfg, n_slots, cache_dtype, self.seq_len)
+            self.cache = KVCache.create(cfg, n_slots, cache_dtype, self.seq_len,
+                                        state_dtype=state_dtype,
+                                        conv_dtype=params["embedding"].dtype,
+                                        state_step=sel.state_step)
+        # recurrent models: the row each slot's state STANDS at, for an
+        # admission to continue from (-1: unknown or in use). Set by
+        # release(keep_rows=) when the rows kept end where the state stands,
+        # consumed by add_begin(start_pos=)
+        self._state_at = np.full(n_slots, -1, np.int64)
+        if self.cache.state is not None:
+            ins.RECURRENT_STATE_BYTES.set(self.cache.state.nbytes)
         if radix_cache not in ("auto", "on", "off"):
             raise ValueError(
                 f"radix_cache must be auto|on|off, got {radix_cache!r}")
@@ -1050,10 +1099,32 @@ class BatchEngine:
         from dllama_tpu.ops.pallas.paged_attention import pool_lanes
 
         lanes = (pool_lanes(self.cfg.head_size)
-                 if self.attn_route == "paged_kernel" else 0)
+                 if self._paged_route == "paged_kernel" else 0)
         return PagedKVCache.create(
             self.cfg, self.n_slots, n_pages, self.page_size,
-            self.cache_dtype, max_blocks, lanes=lanes)
+            self.cache_dtype, max_blocks, lanes=lanes,
+            state_dtype=self.state_dtype,
+            conv_dtype=self.params["embedding"].dtype,
+            state_step=self._state_step)
+
+    @property
+    def rows_reenterable(self) -> bool:
+        """Can a sequence be re-entered at ANY row its KV cache holds
+        (prefix sharing across slots and requests, the radix tree, the host
+        spill tier, preempt-to-pages, speculative rewind)? True for KV-only
+        models. False where the model carries recurrent state: that stands
+        at one row a slot (`resumable_rows`), everything else recomputes."""
+        return not self.cfg.recurrent
+
+    def resumable_rows(self, slot: int, rows: int, donor: int | None = None) -> int:
+        """How many of `rows` reusable prefix rows an admission into `slot`
+        can really start after: all of them for a KV-only model; for a
+        recurrent one, `rows` iff they are the slot's own and its state
+        stands exactly there (the same sequence continuing), else 0."""
+        if self.rows_reenterable:
+            return rows
+        own = donor is None or donor == slot
+        return rows if own and rows > 0 and self._state_at[slot] == rows else 0
 
     # ------------------------------------------------------------- jitted fns
 
@@ -1079,17 +1150,11 @@ class BatchEngine:
         blocking per SURVEY.md §7.4.6); this keeps admission O(prompt) while
         the other slots' decode state waits untouched.
         """
-        sub = KVCache(
-            jax.lax.dynamic_slice_in_dim(cache.k, slot, 1, axis=1),
-            jax.lax.dynamic_slice_in_dim(cache.v, slot, 1, axis=1),
-        )
+        sub = cache.slot_view(slot)
         logits, sub = forward(cfg, params, tokens, pos, sub, rope, attn_fn,
                               col_fn=col_fn, mm=mm, mm_in=mm_in,
                               moe_impl=moe_impl, last_only=True)
-        return logits[:, -1], KVCache(
-            jax.lax.dynamic_update_slice_in_dim(cache.k, sub.k, slot, axis=1),
-            jax.lax.dynamic_update_slice_in_dim(cache.v, sub.v, slot, axis=1),
-        )
+        return logits[:, -1], cache.merge_slot(sub, slot)
 
     @staticmethod
     def _prefill_slot_paged_impl(cfg, attn_fn, col_fn, mm, mm_in, moe_impl,
@@ -1098,12 +1163,11 @@ class BatchEngine:
         one slot's block-table row. No batch-axis slice/unslice — the writes
         land in the slot's own pages by table construction, so other slots'
         pages are untouched exactly like the dense slot slice."""
-        row = jax.lax.dynamic_slice_in_dim(cache.tables, slot, 1, axis=0)
-        sub = PagedKVCache(cache.k, cache.v, row)
+        sub = cache.slot_view(slot)
         logits, sub = forward(cfg, params, tokens, pos, sub, rope, attn_fn,
                               col_fn=col_fn, mm=mm, mm_in=mm_in,
                               moe_impl=moe_impl, last_only=True)
-        return logits[:, -1], PagedKVCache(sub.k, sub.v, cache.tables)
+        return logits[:, -1], cache.merge_slot(sub, slot)
 
     @staticmethod
     def _copy_page_impl(cache, src, dst):
@@ -1116,7 +1180,7 @@ class BatchEngine:
             pg = jax.lax.dynamic_index_in_dim(buf, src, axis=1, keepdims=False)
             return jax.lax.dynamic_update_index_in_dim(buf, pg, dst, axis=1)
 
-        return PagedKVCache(one(cache.k), one(cache.v), cache.tables)
+        return dataclasses.replace(cache, k=one(cache.k), v=one(cache.v))
 
     @staticmethod
     def _write_page_impl(cache, kpg, vpg, dst):
@@ -1127,8 +1191,7 @@ class BatchEngine:
         def one(buf, pg):  # [L, P, H, page, hd] <- [L, H, page, hd]
             return jax.lax.dynamic_update_index_in_dim(buf, pg, dst, axis=1)
 
-        return PagedKVCache(one(cache.k, kpg), one(cache.v, vpg),
-                            cache.tables)
+        return dataclasses.replace(cache, k=one(cache.k, kpg), v=one(cache.v, vpg))
 
     @staticmethod
     def _read_page_impl(cache, src):
@@ -1238,24 +1301,11 @@ class BatchEngine:
         bitwise independent of this write — which is what makes hybrid-on
         token streams bit-exact vs the phase-split path. Returns
         (last-token logits [1, V], updated cache)."""
-        if isinstance(cache, PagedKVCache):
-            row = jax.lax.dynamic_slice_in_dim(cache.tables, slot, 1, axis=0)
-            sub = PagedKVCache(cache.k, cache.v, row)
-            plog, sub = forward(cfg, params, ptoks, ppos, sub, rope, attn_fn,
-                                col_fn=col_fn, mm=mm, mm_in=mm_in,
-                                moe_impl=moe_impl, last_only=True)
-            return plog[:, -1], PagedKVCache(sub.k, sub.v, cache.tables)
-        sub = KVCache(
-            jax.lax.dynamic_slice_in_dim(cache.k, slot, 1, axis=1),
-            jax.lax.dynamic_slice_in_dim(cache.v, slot, 1, axis=1),
-        )
+        sub = cache.slot_view(slot)
         plog, sub = forward(cfg, params, ptoks, ppos, sub, rope, attn_fn,
                             col_fn=col_fn, mm=mm, mm_in=mm_in,
                             moe_impl=moe_impl, last_only=True)
-        return plog[:, -1], KVCache(
-            jax.lax.dynamic_update_slice_in_dim(cache.k, sub.k, slot, axis=1),
-            jax.lax.dynamic_update_slice_in_dim(cache.v, sub.v, slot, axis=1),
-        )
+        return plog[:, -1], cache.merge_slot(sub, slot)
 
     @classmethod
     def _hybrid_impl(cls, cfg, attn_fn, col_fn, mm, mm_in, moe_impl, params,
@@ -1510,13 +1560,14 @@ class BatchEngine:
                 buf, jnp.where(mask, src_rows, dst_rows), dst, axis=1
             )
 
-        return KVCache(one(cache.k), one(cache.v))
+        return dataclasses.replace(cache, k=one(cache.k), v=one(cache.v))
 
     @property
     def supports_cross_slot_copy(self) -> bool:
         """False on dp meshes: the batch axis is sharded, so a slot-to-slot
-        row copy would gather across shards."""
-        return self._use_slot_prefill
+        row copy would gather across shards. False with recurrent state: a
+        donor's KV rows come without the state that stood at them."""
+        return self._use_slot_prefill and self.rows_reenterable
 
     # ------------------------------------------------------- paged-layout api
 
@@ -1784,7 +1835,7 @@ class BatchEngine:
             # the routed attention path decides the paged pricing: the
             # gather fallback re-materializes the whole block-table view
             # through XLA every step, the kernel streams live pages only
-            paged_impl=("gather" if self.attn_route == "paged_gather"
+            paged_impl=("gather" if self._paged_route == "paged_gather"
                         else "kernel"))
 
     # ------------------------------ compile contract & warmup (ISSUE 13)
@@ -2099,10 +2150,14 @@ class BatchEngine:
                 self.radix.spill = self._host_spill
         else:
             self.cache = KVCache.create(self.cfg, self.n_slots,
-                                        self.cache_dtype, self.seq_len)
+                                        self.cache_dtype, self.seq_len,
+                                        state_dtype=self.state_dtype,
+                                        conv_dtype=self.params["embedding"].dtype,
+                                        state_step=self._state_step)
         if self._shardings is not None:
             self.cache = self._shardings.put_cache(self.cache)
         self.pos[:] = 0
+        self._state_at[:] = -1
         self.active[:] = False
         self.last_token[:] = 0
         self.temperature[:] = 0.0
@@ -2177,6 +2232,17 @@ class BatchEngine:
             raise ValueError("prompt must be non-empty")
         if start_pos + n >= self.seq_len:
             raise ValueError(f"prompt ({start_pos}+{n}) exceeds seq_len {self.seq_len}")
+        if self.cfg.recurrent:
+            if start_pos and self._state_at[slot] != start_pos:
+                raise StateNotResumable(
+                    f"slot {slot}: start_pos={start_pos} but the recurrent "
+                    f"state stands at {self._state_at[slot]} (-1: unknown); "
+                    "recompute the prompt from row 0")
+            if not start_pos:
+                # the forward zeroes the state of a row at position 0 on the
+                # device (models/llama._ssm_mixer): nothing to launch here
+                ins.STATE_RESETS.inc()
+            self._state_at[slot] = -1  # moving with the sequence from here on
         if self.pool is not None:
             # paged: drop the dead tail past the reused prefix, copy-on-write
             # a shared boundary page, and back every prompt row with a page.
@@ -2277,30 +2343,44 @@ class BatchEngine:
                        track="launches", req_id=adm.req_id, **rec.args())
         return adm.off >= n
 
-    def add_commit(self, adm: "Admission", temperature: float = 0.8,
-                   topp: float = 0.9, seed: int | None = None,
-                   presence: float = 0.0, frequency: float = 0.0,
-                   spec_k: int | None = None) -> int:
-        """Sample the first token from the finished admission and activate
-        the slot. Must follow add_step returning True. `spec_k` is the
-        slot's PER-REQUEST draft length for batched speculation (clamped to
-        the engine's compile-time K; None keeps the engine default — the
-        pre-ISSUE-11 engine-global behavior; 0 opts this slot out)."""
+    def add_sample(self, adm: "Admission", temperature: float = 0.8,
+                   topp: float = 0.9, seed: int | None = None) -> None:
+        """Dispatch the sampling of a finished admission's first token and
+        read nothing back: the token stays on the device in `adm.sampled`
+        until add_commit. Called right after the launch that carried the
+        admission's last prompt rows, the sampling queues behind that
+        launch and AHEAD of its successor, so add_commit's one host read is
+        ready when the launch ends and the pipeline never drains for it."""
         assert adm.off >= len(adm.toks) and adm.logits is not None, "admission not pumped"
-        slot = adm.slot
         if seed is not None:
             key = jax.random.PRNGKey(seed)
         else:
             key = jax.random.fold_in(self._base_key, self._admissions)
         self._admissions += 1
         key, sub = jax.random.split(key)
-        self.keys[slot] = np.array(key)  # np.array copies (np.asarray of a jax
-        # array is a read-only view; this row is mutated on every add)
         with compile_obs.LEDGER.scope(
                 "commit", "b1",
                 sig=lambda: compile_obs.sig_of(adm.logits)):
             tok = sample_logits(adm.logits, sub, jnp.float32(temperature),
                                 jnp.float32(topp))
+        adm.sampled = (tok, key)
+
+    def add_commit(self, adm: "Admission", temperature: float = 0.8,
+                   topp: float = 0.9, seed: int | None = None,
+                   presence: float = 0.0, frequency: float = 0.0,
+                   spec_k: int | None = None) -> int:
+        """Sample the first token from the finished admission (unless
+        add_sample already dispatched that) and activate the slot. Must
+        follow add_step returning True. `spec_k` is the
+        slot's PER-REQUEST draft length for batched speculation (clamped to
+        the engine's compile-time K; None keeps the engine default — the
+        pre-ISSUE-11 engine-global behavior; 0 opts this slot out)."""
+        slot = adm.slot
+        if adm.sampled is None:
+            self.add_sample(adm, temperature, topp, seed)
+        tok, key = adm.sampled
+        self.keys[slot] = np.array(key)  # np.array copies (np.asarray of a jax
+        # array is a read-only view; this row is mutated on every add)
         first = int(np.asarray(tok)[0])
         compile_obs.note_transfer("d2h", "commit", int(tok.nbytes))
         self.active[slot] = True
@@ -2441,7 +2521,7 @@ class BatchEngine:
             # the mirrors; .copy() for the same aliasing reason as above)
             tables = jnp.asarray(self.pool.tables.copy(), jnp.int32)
             nbytes += int(tables.nbytes)
-            self.cache = PagedKVCache(self.cache.k, self.cache.v, tables)
+            self.cache = dataclasses.replace(self.cache, tables=tables)
         # boundary upload accounting (ISSUE 13): this fan is the ONLY
         # legitimate steady-path upload site, and it fires at boundaries
         # only — a per-chunk rate here is the device-resident-state
@@ -2989,6 +3069,11 @@ class BatchEngine:
         self.active[slot] = False
         self.presence[slot] = self.frequency[slot] = 0.0
         self.spec_k_slot[slot] = 0
+        # recurrent state: resumable only where the rows kept end exactly
+        # where it stands; a stop inside a chunk (keep_rows below the rows
+        # the device advanced) leaves it unknown
+        self._state_at[slot] = (keep_rows if keep_rows is not None
+                                and keep_rows == self.pos[slot] else -1)
         if keep_rows is not None:
             self.pos[slot] = keep_rows
             if self.pool is not None:

@@ -13,6 +13,7 @@ nBatches=32 and pads the final chunk; we never compute padded positions).
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from dataclasses import dataclass
 from functools import partial
@@ -97,6 +98,10 @@ class InferenceEngine:
     ):
         self.cfg = cfg
         self.params = params
+        if cfg.recurrent and shardings is not None:
+            raise ValueError(
+                "a model with per-sequence recurrent state runs on one "
+                "device: the state has no sharding under a mesh yet")
         if fuse_weights:
             if shardings is not None:
                 raise ValueError("fuse_weights requires an unsharded engine "
@@ -112,7 +117,8 @@ class InferenceEngine:
         self.max_prefill_chunk = max_prefill_chunk
         self.shardings = shardings
         self.rope_cache = build_rope_cache(cfg, self.seq_len)
-        self.cache = KVCache.create(cfg, batch, cache_dtype, self.seq_len)
+        self.cache = KVCache.create(cfg, batch, cache_dtype, self.seq_len,
+                                    conv_dtype=params["embedding"].dtype)
         self.pos = 0
 
         if shardings is not None:
@@ -133,6 +139,11 @@ class InferenceEngine:
         mm, mm_in, attn_fn = sel.mm, sel.mm_in, sel.attn_fn
         self.backend = sel.backend
         self.kernel_route = sel.bucket_tag()
+        if self.cache.state is not None:
+            # the state carries the decode step the selection resolved
+            self.cache = dataclasses.replace(
+                self.cache, state=dataclasses.replace(
+                    self.cache.state, step=sel.state_step))
         from dllama_tpu.parallel.collectives import resolve_sync
 
         self.sync = sync = resolve_sync(sync, shardings)
@@ -282,8 +293,19 @@ class InferenceEngine:
         self.pos += t
         return logits
 
+    def can_resume_at(self, pos: int) -> bool:
+        """Can the next tokens be fed at row `pos`? Any row the KV cache
+        holds, for a KV-only model; with recurrent state (which follows
+        `self.pos` and cannot be rewound) only row 0 — the forward zeroes
+        the state there — or where it stands."""
+        return not self.cfg.recurrent or pos in (0, self.pos)
+
     def reset(self, pos: int = 0) -> None:
         """Rewind to `pos` (prefix-cache reuse keeps cache contents ≤ pos valid)."""
+        if not self.can_resume_at(pos):
+            raise ValueError(
+                f"cannot rewind to row {pos}: the recurrent state stands at "
+                f"row {self.pos}; reset(0) and recompute")
         self.pos = pos
 
     def measured_collective_report(self) -> dict:
@@ -346,6 +368,12 @@ class InferenceEngine:
         v = np.asarray(self.cache.v)
         # npz cannot represent ml_dtypes elements (an f8 cache loads back as
         # raw void): persist the BYTES plus the dtype name and re-view on load
+        extra = {}
+        if self.cache.state is not None:  # the recurrent state stands at pos
+            conv = np.asarray(self.cache.state.conv)
+            extra = dict(state_s=np.asarray(self.cache.state.s, np.float32),
+                         state_conv=conv.view(np.uint8),
+                         state_conv_dtype=str(conv.dtype))
         np.savez_compressed(
             path,
             fingerprint=self._session_fingerprint(),
@@ -353,6 +381,7 @@ class InferenceEngine:
             pos=self.pos,
             k=k.view(np.uint8),
             v=v.view(np.uint8),
+            **extra,
         )
 
     def load_session(self, path: str) -> None:
@@ -381,7 +410,17 @@ class InferenceEngine:
                     dt = self.cache.k.dtype
                     k = k.view(np.uint8).view(dt).reshape(self.cache.k.shape)
                     v = v.view(np.uint8).view(dt).reshape(self.cache.v.shape)
-            cache = KVCache(jnp.asarray(k), jnp.asarray(v))
+            state = self.cache.state
+            if state is not None:
+                from dllama_tpu.models.llama import RecurrentState
+
+                conv = data["state_conv"].view(
+                    jnp.dtype(str(data["state_conv_dtype"])))
+                state = RecurrentState(
+                    jnp.asarray(data["state_s"], state.s.dtype),
+                    jnp.asarray(conv.reshape(state.conv.shape)),
+                    step=state.step)
+            cache = KVCache(jnp.asarray(k), jnp.asarray(v), state)
             if self.shardings is not None:
                 cache = self.shardings.put_cache(cache)
             self.cache = cache
@@ -550,6 +589,11 @@ class InferenceEngine:
         runs ignore it.
         """
         assert self.batch == 1, "generate() drives a single sequence; use step() for batches"
+        if self.cfg.recurrent:
+            # a chunk decoded past a stop is undone by rewinding the rows;
+            # recurrent state cannot be rewound, so nothing is decoded that
+            # might have to be: one token a launch, no draft window
+            chunk, spec = 1, 0
         # penalized greedy is argmax of MODIFIED logits: speculative drafting
         # verifies against raw argmax, so penalties force the plain scan
         use_spec = spec > 0 and sampler.temperature == 0.0 and not sampler.has_penalties

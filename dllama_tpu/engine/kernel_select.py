@@ -61,19 +61,57 @@ class KernelSelection:
                 "kernel selection carries interpret=True on a TPU: the "
                 "serving path must run compiled kernels")
 
+    @property
+    def route(self) -> str:
+        """The mixers' routes: `attn_route`, and behind a '+' the recurrent
+        state's decode step and element type where the model has one
+        ('paged_kernel+ssm_step.float32')."""
+        return self.attn_route + (f"+{self.state_route}"
+                                  if self.state_route else "")
+
     def bucket_tag(self) -> str:
-        """'backend/attn_route' — the variant tag the compile ledger's
+        """'backend/route' — the variant tag the compile ledger's
         shape-bucket contract stamps on each declared bucket, so a
         coverage dump says WHICH compiled universe (dense vs paged, jnp vs
-        flash) the buckets belong to."""
-        return f"{self.backend}/{self.attn_route}"
+        flash, which state step) the buckets belong to."""
+        return f"{self.backend}/{self.route}"
 
+    state_step: Callable | None = None  # recurrent models: the whole-batch
+    # decode step on the layer-stacked state (models/llama.RecurrentState
+    # carries it to the mixer); None = the jnp step on a layer's slice
+    state_route: str = ""  # '' (no recurrent state) | 'ssm_step.<dtype>' (the
+    # in-place Pallas kernel, ops/pallas/ssm_step) | 'ssm_jnp.<dtype>': what
+    # the state-space layers' decode step runs on and the state's element
+    # type — a fallback or a narrowed state shows in the tag, never silently
     fused_scatter_max_t: int | None = None  # paged_kernel route only: the
     # widest chunk (query rows per slot) whose new-KV scatter stays fused
     # inside the kernel launch. A speculative verify forward is spec_k+1
     # rows wide, so engines log when their K rides the per-layer
     # pre-scatter path instead (still correct — one XLA scatter per layer
     # per cycle — just not the zero-extra-dispatch fused write)
+
+
+def resolve_state_step(cfg: LlamaConfig, batch: int, backend: str,
+                       state_dtype=None) -> tuple[Callable | None, str]:
+    """(step, route) of the state-space layers' decode step for a model
+    with recurrent state, (None, '') for any other. The in-place Pallas
+    kernel serves where the quantized matmuls run on Pallas and the kernel
+    takes the state's shape and element type (32-bit); everything else is
+    the jnp step, and the route says so."""
+    if not cfg.recurrent:
+        return None, ""
+    import jax.numpy as jnp
+
+    from dllama_tpu.ops.matmul import device_platform, resolve_backend
+    from dllama_tpu.ops.pallas.ssm_step import ssm_step, supported
+
+    dtype = jnp.dtype(jnp.float32 if state_dtype is None else state_dtype)
+    shape = (cfg.n_ssm_layers, batch, cfg.ssm_heads, cfg.ssm_head_dim,
+             cfg.ssm_state)
+    if resolve_backend(backend) == "pallas" and supported(shape, dtype):
+        return (partial(ssm_step, interpret=device_platform() != "tpu"),
+                f"ssm_step.{dtype.name}")
+    return None, f"ssm_jnp.{dtype.name}"
 
 
 def resolve_moe_impl(moe_impl: str, shardings=None) -> str:
@@ -109,6 +147,7 @@ def resolve_kernels(
     page_size: int = 0,
     cache_dtype=None,  # KV pool element type (paged capability check);
     # None = bf16, the serving default
+    state_dtype=None,  # recurrent state's element type; None = float32
 ) -> KernelSelection:
     """Resolution rules:
 
@@ -137,6 +176,9 @@ def resolve_kernels(
     if sharded_pallas:
         mm, mm_in = shardings.pallas_mms(batch)
         backend = "pallas"
+
+    state_step, state_route = resolve_state_step(cfg, batch, backend,
+                                                 state_dtype)
 
     if paged and shardings is None:
         # paged KV cache (BatchEngine --kv-layout paged; unsharded only — the
@@ -185,6 +227,7 @@ def resolve_kernels(
         return KernelSelection(mm=mm, mm_in=mm_in, attn_fn=attn_fn,
                                backend=backend, attn_route=route,
                                interpret=not on_tpu,
+                               state_step=state_step, state_route=state_route,
                                fused_scatter_max_t=fused_cap)
 
     attn_fn = shardings.attn_fn(batch) if shardings is not None else None
@@ -209,4 +252,5 @@ def resolve_kernels(
 
     return KernelSelection(mm=mm, mm_in=mm_in, attn_fn=attn_fn,
                            backend=backend, attn_route=route,
-                           interpret=not on_tpu)
+                           interpret=not on_tpu, state_step=state_step,
+                           state_route=state_route)

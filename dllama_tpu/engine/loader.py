@@ -37,6 +37,14 @@ class LoadedModel:
 def build_shardings(cfg: LlamaConfig, mesh_spec: str | None) -> LlamaShardings | None:
     """mesh_spec: 'tp=4,dp=2'-style string, 'auto', or None (single device)."""
     n_dev = len(jax.devices())
+    if cfg.recurrent:
+        # per-sequence recurrent state has no sharding under a mesh yet:
+        # 'auto' resolves to one device, an explicit mesh is refused
+        if mesh_spec not in (None, "auto"):
+            raise ValueError(
+                f"--mesh {mesh_spec}: a model with recurrent (state-space) "
+                "state serves on one device; its state is not sharded yet")
+        return None
     if mesh_spec is None or (mesh_spec == "auto" and n_dev == 1):
         return None
     if mesh_spec == "auto":

@@ -18,6 +18,16 @@ MODEL_MAGIC = 0x0A00ABCD  # llm.cpp:46-48 (magic 0xA00ABCD)
 
 class ArchType(IntEnum):
     LLAMA = 0xABCD00
+    # decoder whose layers are of two kinds under one residual/MLP skeleton:
+    # softmax attention or a Mamba-2 state-space mixer, by the header's
+    # per-layer kind keys (a GraniteMoeHybrid-style stack; source:
+    # huggingface.co/ibm-granite/granite-4.0-h-micro config.json)
+    HYBRID_SSM = 0xABCD10
+
+
+class LayerKind(IntEnum):
+    ATTENTION = 0
+    SSM = 1
 
 
 class HiddenAct(IntEnum):
@@ -29,6 +39,7 @@ class RopeType(IntEnum):
     LLAMA = 0
     FALCON = 1  # present in the reference enum order (nn-core.hpp), unused
     LLAMA3_1 = 2
+    NONE = 3  # no positional rotation at all: q and k are used as projected
 
 
 class HeaderKey(IntEnum):
@@ -57,6 +68,25 @@ class HeaderKey(IntEnum):
     # normEpsilon=1e-5, llm.cpp:33): written only when eps != 1e-5, value is
     # eps * 1e12 as an int. Reference binaries reject files carrying it.
     NORM_EPSILON_X1E12 = 100
+    # ---- dllama-tpu extensions for ArchType.HYBRID_SSM (floats int-coded
+    # like NORM_EPSILON_X1E12; a key that is absent keeps the LLAMA meaning)
+    HEAD_SIZE = 101  # explicit attention head size (n_heads * it == dim)
+    ATTN_SCALE_X1E6 = 102  # score scale; absent = 1/sqrt(head_size)
+    EMBEDDING_MULT_X1E6 = 103  # h0 = mult * E[token]
+    RESIDUAL_MULT_X1E6 = 104  # h += mult * block(norm(h)), both adds
+    LOGITS_DIV_X1E6 = 105  # logits = head(h) / div
+    TIED_HEAD = 106  # 1 = wcls on disk is the Q40 of the embedding
+    SSM_HEADS = 110
+    SSM_HEAD_DIM = 111
+    SSM_STATE = 112
+    SSM_GROUPS = 113
+    SSM_CONV = 114  # conv taps
+    SSM_CHUNK = 115  # rows a block of the chunked scan holds
+
+
+#: the kind of layer i is header key LAYER_KIND_BASE + i (one key a layer,
+#: not a period and not a name), value a LayerKind
+LAYER_KIND_BASE = 1000
 
 
 @dataclasses.dataclass
@@ -82,18 +112,80 @@ class LlamaConfig:
     norm_epsilon: float = 1e-5
     weight_type: FloatType = FloatType.Q40
     orig_seq_len: int = 0  # pre-clamp seq len from the file
+    # ---- HYBRID_SSM (defaults are the LLAMA meaning)
+    head_dim: int = 0  # explicit head size; 0 = dim // n_heads
+    attn_scale: float = 0.0  # 0 = 1/sqrt(head_size)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    tied_head: bool = False
+    layer_kinds: tuple = ()  # LayerKind per layer; () = all attention
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
 
     def __post_init__(self):
         if self.orig_seq_len == 0:
             self.orig_seq_len = self.seq_len
+        self.layer_kinds = tuple(int(k) for k in self.layer_kinds)
+        if self.layer_kinds and len(self.layer_kinds) != self.n_layers:
+            raise ValueError(
+                f"{len(self.layer_kinds)} layer kinds for {self.n_layers} layers")
+        if self.head_dim and self.head_dim * self.n_heads != self.dim:
+            raise ValueError(
+                f"head size {self.head_dim} x {self.n_heads} heads != dim "
+                f"{self.dim}: an attention width other than the model's is "
+                "not supported")
+        if self.n_ssm_layers and self.ssm_groups != 1:
+            raise ValueError("state-space layers with more than one B/C group "
+                             "are not supported")
 
     @property
     def head_size(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_dim or self.dim // self.n_heads
 
     @property
     def kv_dim(self) -> int:
-        return (self.dim * self.n_kv_heads) // self.n_heads
+        return self.n_kv_heads * self.head_size
+
+    @property
+    def n_ssm_layers(self) -> int:
+        return sum(1 for k in self.layer_kinds if k == LayerKind.SSM)
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that hold KV rows: what a cache's layer axis is sized by."""
+        return self.n_layers - self.n_ssm_layers
+
+    @property
+    def recurrent(self) -> bool:
+        """The model carries per-sequence state that cannot be rewound."""
+        return self.n_ssm_layers > 0
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the causal conv runs over: x | B | C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def ssm_in_proj(self) -> int:
+        """Output columns of in_proj as published: z | x | B | C | dt."""
+        return self.ssm_inner + self.ssm_conv_dim + self.ssm_heads
+
+    @property
+    def softmax_scale_ratio(self) -> float:
+        """What q is multiplied by before kernels that bake 1/sqrt(hd) in,
+        so the scores come out at the configured scale."""
+        if not self.attn_scale:
+            return 1.0
+        return self.attn_scale * self.head_size ** 0.5
 
     @property
     def q_per_kv(self) -> int:
@@ -109,6 +201,9 @@ class LlamaConfig:
             f"act={self.hidden_act.name} rope={self.rope_type.name} "
             f"weights={self.weight_type.name}"
             + (f" experts={self.n_experts}/{self.n_active_experts}" if self.n_experts else "")
+            + (f" ssm_layers={self.n_ssm_layers}/{self.n_layers} "
+               f"ssm={self.ssm_heads}x{self.ssm_head_dim}x{self.ssm_state}"
+               if self.recurrent else "")
         )
 
     def clamp_seq_len(self, max_seq_len: int | None) -> "LlamaConfig":
@@ -144,14 +239,27 @@ class LlamaConfig:
                 (HeaderKey.ROPE_SCALING_ORIG_MAX_SEQ_LEN, self.rope_scaling_orig_max_seq_len),
                 (HeaderKey.ROPE_TYPE, int(self.rope_type)),
             ]
+        elif self.rope_type == RopeType.NONE:
+            kv.append((HeaderKey.ROPE_TYPE, int(self.rope_type)))
         if abs(self.norm_epsilon - 1e-5) > 1e-12:
             kv.append((HeaderKey.NORM_EPSILON_X1E12, int(round(self.norm_epsilon * 1e12))))
+        if self.arch == ArchType.HYBRID_SSM:
+            kv.append((HeaderKey.HEAD_SIZE, self.head_size))
+            kv += [(key, int(round(getattr(self, name) * 1e6)))
+                   for key, name in _X1E6_KEYS.items()]
+            kv.append((HeaderKey.TIED_HEAD, int(self.tied_head)))
+            kv += [(key, getattr(self, name)) for key, name in _SSM_INT_KEYS.items()]
+            kv += [(LAYER_KIND_BASE + i, k) for i, k in enumerate(self.layer_kinds)]
         return [(int(k), int(v)) for k, v in kv]
 
     @classmethod
     def from_header_kv(cls, kv: list[tuple[int, int]]) -> "LlamaConfig":
         vals: dict = {}
+        kinds: dict = {}
         for key, value in kv:
+            if key >= LAYER_KIND_BASE:
+                kinds[key - LAYER_KIND_BASE] = LayerKind(value)
+                continue
             key = HeaderKey(key)
             if key == HeaderKey.VERSION:
                 vals["version"] = value
@@ -193,4 +301,30 @@ class LlamaConfig:
                 vals["rope_type"] = RopeType(value)
             elif key == HeaderKey.NORM_EPSILON_X1E12:
                 vals["norm_epsilon"] = value / 1e12
+            elif key == HeaderKey.HEAD_SIZE:
+                vals["head_dim"] = value
+            elif key in _X1E6_KEYS:
+                vals[_X1E6_KEYS[key]] = value / 1e6
+            elif key == HeaderKey.TIED_HEAD:
+                vals["tied_head"] = bool(value)
+            elif key in _SSM_INT_KEYS:
+                vals[_SSM_INT_KEYS[key]] = value
+        if kinds:
+            n = vals.get("n_layers", 0)
+            if sorted(kinds) != list(range(n)):
+                raise ValueError(
+                    f"the header names the kind of {len(kinds)} layers of {n}")
+            vals["layer_kinds"] = tuple(kinds[i] for i in range(n))
         return cls(**vals)
+
+
+_X1E6_KEYS = {HeaderKey.ATTN_SCALE_X1E6: "attn_scale",
+              HeaderKey.EMBEDDING_MULT_X1E6: "embedding_multiplier",
+              HeaderKey.RESIDUAL_MULT_X1E6: "residual_multiplier",
+              HeaderKey.LOGITS_DIV_X1E6: "logits_scaling"}
+_SSM_INT_KEYS = {HeaderKey.SSM_HEADS: "ssm_heads",
+                 HeaderKey.SSM_HEAD_DIM: "ssm_head_dim",
+                 HeaderKey.SSM_STATE: "ssm_state",
+                 HeaderKey.SSM_GROUPS: "ssm_groups",
+                 HeaderKey.SSM_CONV: "ssm_conv",
+                 HeaderKey.SSM_CHUNK: "ssm_chunk"}

@@ -8,6 +8,10 @@ order (llm.cpp:453-468):
   per layer: q [dim,dim] k [kv_dim,dim] v [kv_dim,dim] wo [dim,dim]
              w1 [hidden,dim] w2 [dim,hidden] w3 [hidden,dim]   (weight_type)
              rms_norm_0 f32 [dim], rms_norm_1 f32 [dim]
+             (a state-space layer of an ArchType.HYBRID_SSM file holds, in
+             place of q/k/v/wo: in_proj [2*inner + 2*state + heads, dim],
+             conv_w f32 [inner + 2*state, taps], conv_b, dt_bias [heads],
+             a_log [heads], d [heads], ssm_norm [inner], out_proj [dim, inner])
   final_rms_norm f32 [dim]
   wcls [vocab, dim]                                            (weight_type)
 
@@ -27,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dllama_tpu.models.config import MODEL_MAGIC, LlamaConfig
+from dllama_tpu.models.config import MODEL_MAGIC, LayerKind, LlamaConfig
 from dllama_tpu.ops.quant import (
     FloatType,
     Q_BLOCK,
@@ -95,13 +99,29 @@ def tensor_plan(config: LlamaConfig) -> list[tuple[str, tuple[int, int] | tuple[
     """(name, file_shape, float_type) in on-disk order (llm.cpp:453-468)."""
     wt = config.weight_type
     plan: list = [("embedding", (config.vocab_size, config.dim), FloatType.F32)]
-    for layer in range(config.n_layers):
-        plan += [
-            (f"layers.{layer}.wq", (config.dim, config.dim), wt),
-            (f"layers.{layer}.wk", (config.kv_dim, config.dim), wt),
-            (f"layers.{layer}.wv", (config.kv_dim, config.dim), wt),
-            (f"layers.{layer}.wo", (config.dim, config.dim), wt),
-        ]
+    kinds = config.layer_kinds or (LayerKind.ATTENTION,) * config.n_layers
+    for layer, kind in enumerate(kinds):
+        if kind == LayerKind.SSM:
+            # the state-space mixer (ops/ssm.py): in_proj's output rows are
+            # z | x | B | C | dt side by side, at the published width
+            f32 = FloatType.F32
+            plan += [
+                (f"layers.{layer}.in_proj", (config.ssm_in_proj, config.dim), wt),
+                (f"layers.{layer}.conv_w", (config.ssm_conv_dim, config.ssm_conv), f32),
+                (f"layers.{layer}.conv_b", (config.ssm_conv_dim,), f32),
+                (f"layers.{layer}.dt_bias", (config.ssm_heads,), f32),
+                (f"layers.{layer}.a_log", (config.ssm_heads,), f32),
+                (f"layers.{layer}.d", (config.ssm_heads,), f32),
+                (f"layers.{layer}.ssm_norm", (config.ssm_inner,), f32),
+                (f"layers.{layer}.out_proj", (config.dim, config.ssm_inner), wt),
+            ]
+        else:
+            plan += [
+                (f"layers.{layer}.wq", (config.dim, config.dim), wt),
+                (f"layers.{layer}.wk", (config.kv_dim, config.dim), wt),
+                (f"layers.{layer}.wv", (config.kv_dim, config.dim), wt),
+                (f"layers.{layer}.wo", (config.dim, config.dim), wt),
+            ]
         if config.n_experts:
             # MoE extension: the reference header carries N_EXPERTS
             # (llm.hpp:17-18) and its HF converter emits expert tensors
@@ -345,6 +365,28 @@ def _load_matmul(raw: np.ndarray, shape: tuple[int, int], ft: FloatType, dtype, 
     return decode_dense(raw, shape, ft).T.astype(dtype, order="C")
 
 
+#: a layer's small float32 tensors, loaded as they lie: the norms and the
+#: state-space mixer's conv, step and skip parameters
+_F32_LEAVES = ("rms_att", "rms_ffn", "conv_w", "conv_b", "dt_bias", "a_log",
+               "d", "ssm_norm")
+
+
+def _pad_columns(w, multiple: int):
+    """Zero output columns up to a whole `multiple` (host-side leaf)."""
+    n = w.shape[-1]
+    pad = (-n) % multiple
+    if not pad:
+        return w
+    if isinstance(w, QTensor):
+        # nibble 8 is weight 0; the scale is 0 as well
+        return QTensor(np.pad(w.packed, ((0, 0), (0, pad)), constant_values=0x88),
+                       np.pad(w.scales, ((0, 0), (0, pad))))
+    if isinstance(w, Q8Tensor):
+        return Q8Tensor(np.pad(w.codes, ((0, 0), (0, pad))),
+                        np.pad(w.scales, ((0, 0), (0, pad))))
+    return np.pad(w, ((0, 0), (0, pad)))
+
+
 def _load_expert_matmul(raw: np.ndarray, shape: tuple[int, int, int], ft: FloatType, dtype, dequantize: bool):
     """File [E, out, in] blob -> expert-stacked host x@W operand [E, in, out]."""
     e, n_out, k_in = shape
@@ -398,8 +440,15 @@ def load_params(
                                                     lazy=True, q80_packed=q80_packed))
         else:
             _, _, short = name.split(".")
-            if short in ("rms_att", "rms_ffn"):
+            if short in _F32_LEAVES:
                 leaf = decode_dense(raw, shape, ft)
+            elif short == "in_proj":
+                # 2*inner + 2*state + heads columns are not whole 128-lane
+                # tiles (8,512 at the published widths): zero columns are
+                # added after the dt block HERE, on the way to the device;
+                # the file keeps the published width
+                leaf = _pad_columns(_load_matmul(raw, shape, ft, dtype, dequantize,
+                                                 q80_packed=q80_packed), 128)
             elif short == "moe_gate":
                 # router stays f32; file [E, dim] -> h@gate operand [dim, E]
                 leaf = decode_dense(raw, shape, ft).T.astype(np.float32, order="C")

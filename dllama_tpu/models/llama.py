@@ -12,13 +12,97 @@ inserted by XLA from the weight/cache shardings (parallel/sharding.py).
 from __future__ import annotations
 
 import dataclasses
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 
-from dllama_tpu.models.config import LlamaConfig
+from dllama_tpu.models.config import LayerKind, LlamaConfig, RopeType
+from dllama_tpu.ops import ssm
 from dllama_tpu.ops.layers import activation, apply_rope, gqa_attention, moe_ffn, rms_norm
 from dllama_tpu.ops.matmul import matmul
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class RecurrentState:
+    """The state-space layers' per-sequence state, beside the KV cache:
+
+      s    [Ls, B, H, P, N]   the recurrence's running sum, float32 unless
+                              constructed otherwise (it sums over the whole
+                              context; a narrower `dtype` is the control the
+                              tests hold against the tolerance)
+      conv [Ls, B, K-1, C]    the causal conv's window: the last K-1 rows
+                              of x|B|C before activation, in the
+                              activations' own type (bf16 as served: the
+                              rows ARE bf16 activations, nothing is lost)
+
+    Ls = state-space layers, B = batch rows or serving slots. Fixed-size a
+    slot, not pageable, and it CANNOT be rewound: it stands at one row of
+    its sequence, and the engines keep which (engine/batch.BatchEngine).
+    Carried whole through the layer scan and the step scan and updated in
+    place, as the page pool is. `slot` (a traced scalar) narrows every read
+    and write to that one slot at B = 1 — an admission's prefill slice cuts
+    2 MB a layer out of the stack and puts it back, never the stack.
+
+    `step` (static, not a leaf) is the whole-batch decode step on the
+    layer-stacked `s` that the engine's kernel selection resolved
+    (engine/kernel_select.resolve_state_step, named in the route tag); None
+    = the jnp step on a layer's slice (ops/ssm.ssm_step_ref). The model
+    asks nothing else about kernels."""
+
+    s: jax.Array
+    conv: jax.Array
+    slot: jax.Array | None = None
+    step: "Callable | None" = None
+
+    def tree_flatten(self):
+        return (self.s, self.conv, self.slot), self.step
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, step=aux)
+
+    @classmethod
+    def create(cls, cfg: LlamaConfig, batch: int, dtype=jnp.float32,
+               conv_dtype=jnp.bfloat16, step=None):
+        ls = cfg.n_ssm_layers
+        return cls(
+            jnp.zeros((ls, batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), dtype),
+            jnp.zeros((ls, batch, cfg.ssm_conv - 1, cfg.ssm_conv_dim), conv_dtype),
+            step=step)
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.s.nbytes + self.conv.nbytes)
+
+    def at_slot(self, slot) -> "RecurrentState":
+        return RecurrentState(self.s, self.conv, slot, self.step)
+
+    def _cut(self, buf, si):
+        if self.slot is None:
+            return jax.lax.dynamic_index_in_dim(buf, si, axis=0, keepdims=False)
+        start = (si, self.slot) + (0,) * (buf.ndim - 2)
+        return jax.lax.dynamic_slice(buf, start, (1, 1) + buf.shape[2:])[0]
+
+    def _put(self, buf, si, new):
+        start = (si, 0 if self.slot is None else self.slot) + (0,) * (buf.ndim - 2)
+        return jax.lax.dynamic_update_slice(buf, new[None].astype(buf.dtype), start)
+
+    def window(self, si) -> jax.Array:
+        """Layer si's conv window [B, K-1, C] (B = 1 under `slot`)."""
+        return self._cut(self.conv, si)
+
+    def layer_state(self, si) -> jax.Array:
+        """Layer si's S [B, H, P, N] (B = 1 under `slot`)."""
+        return self._cut(self.s, si)
+
+    def replace_layer(self, si, window, s_new=None, s_stack=None) -> "RecurrentState":
+        """Layer si's window put back, and its S (`s_new`, [B, H, P, N]) —
+        or the whole stack where a kernel already updated it in place."""
+        s = s_stack if s_stack is not None else self._put(self.s, si, s_new)
+        return RecurrentState(s, self._put(self.conv, si, window), self.slot,
+                              self.step)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -34,22 +118,42 @@ class KVCache:
 
     k: jax.Array
     v: jax.Array
+    state: RecurrentState | None = None  # where the model has state-space layers
 
     def tree_flatten(self):
-        return (self.k, self.v), None
+        return (self.k, self.v, self.state), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children)
 
     @classmethod
-    def create(cls, cfg: LlamaConfig, batch: int, dtype=jnp.bfloat16, seq_len: int | None = None):
-        shape = (cfg.n_layers, batch, cfg.n_kv_heads, seq_len or cfg.seq_len, cfg.head_size)
-        return cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+    def create(cls, cfg: LlamaConfig, batch: int, dtype=jnp.bfloat16, seq_len: int | None = None,
+               state_dtype=jnp.float32, conv_dtype=jnp.bfloat16,
+               state_step=None):
+        """The layer axis counts the layers that hold KV rows
+        (`cfg.n_attn_layers`: all of them, but for a hybrid stack)."""
+        shape = (cfg.n_attn_layers, batch, cfg.n_kv_heads, seq_len or cfg.seq_len, cfg.head_size)
+        state = (RecurrentState.create(cfg, batch, state_dtype, conv_dtype,
+                                       state_step)
+                 if cfg.recurrent else None)
+        return cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), state)
 
     @property
     def seq_len(self) -> int:
         return self.k.shape[3]
+
+    def slot_view(self, slot) -> "KVCache":
+        """ONE batch row's cache at B = 1: its rows cut out of the batch
+        axis, its own recurrent state."""
+        cut = lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1)
+        state = None if self.state is None else self.state.at_slot(slot)
+        return KVCache(cut(self.k), cut(self.v), state)
+
+    def merge_slot(self, sub: "KVCache", slot) -> "KVCache":
+        put = lambda c, n: jax.lax.dynamic_update_slice_in_dim(c, n, slot, axis=1)
+        state = None if sub.state is None else sub.state.at_slot(None)
+        return KVCache(put(self.k, sub.k), put(self.v, sub.v), state)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -76,9 +180,10 @@ class PagedKVCache:
     k: jax.Array
     v: jax.Array
     tables: jax.Array  # i32 [n_slots, max_blocks]
+    state: RecurrentState | None = None  # per SLOT, beside the pool
 
     def tree_flatten(self):
-        return (self.k, self.v, self.tables), None
+        return (self.k, self.v, self.tables, self.state), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -87,14 +192,32 @@ class PagedKVCache:
     @classmethod
     def create(cls, cfg: LlamaConfig, n_slots: int, n_pages: int,
                page_size: int, dtype=jnp.bfloat16, max_blocks: int = 0,
-               lanes: int = 0):
+               lanes: int = 0, state_dtype=jnp.float32,
+               conv_dtype=jnp.bfloat16, state_step=None):
         """``lanes`` widens the row (minor) dim past head_size — the paged
         Pallas kernel needs whole 128-lane rows
-        (ops/pallas/paged_attention.pool_lanes); 0 = head_size."""
-        shape = (cfg.n_layers, n_pages + 1, cfg.n_kv_heads, page_size,
+        (ops/pallas/paged_attention.pool_lanes); 0 = head_size. The pool's
+        layer axis counts the attention layers (`cfg.n_attn_layers`)."""
+        shape = (cfg.n_attn_layers, n_pages + 1, cfg.n_kv_heads, page_size,
                  lanes or cfg.head_size)
         tables = jnp.zeros((n_slots, max_blocks or 1), jnp.int32)
-        return cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), tables)
+        state = (RecurrentState.create(cfg, n_slots, state_dtype, conv_dtype,
+                                       state_step)
+                 if cfg.recurrent else None)
+        return cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), tables, state)
+
+    def slot_view(self, slot) -> "PagedKVCache":
+        """The cache as ONE slot's B = 1 forward sees it: its block-table
+        row over the global pool, its own recurrent state."""
+        row = jax.lax.dynamic_slice_in_dim(self.tables, slot, 1, axis=0)
+        state = None if self.state is None else self.state.at_slot(slot)
+        return PagedKVCache(self.k, self.v, row, state)
+
+    def merge_slot(self, sub: "PagedKVCache", slot=None) -> "PagedKVCache":
+        """Back from `slot_view`: the pools and state `sub` wrote, under
+        every slot's tables."""
+        state = None if sub.state is None else sub.state.at_slot(None)
+        return PagedKVCache(sub.k, sub.v, self.tables, state)
 
     @property
     def page_size(self) -> int:
@@ -142,22 +265,148 @@ def _paged_cache_update(pool, new, tables, pos_base, active):
 from dllama_tpu.ops.quant import slice_leaf as _slice_layer
 
 
-def _layer(cfg: LlamaConfig, x, layers, li, k_cache, v_cache, rope, pos_base, attn_fn,
-           active=None, col_fn=None, mm=None, mm_in=None, moe_impl="auto",
-           tables=None):
-    """One decoder layer. `layers` is the full stacked params dict and `li`
-    the traced layer index — quantized weights are NOT sliced here: the matmul
-    dispatcher either DMA-indexes the stack (Pallas scalar prefetch) or slices
-    lazily (XLA path). Slicing stacked weights before a pallas_call would make
-    XLA materialize a full HBM copy of every weight, every layer, every token.
+def _attention_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, rope,
+                     pos_base, attn_fn, active, mm, colmm, tables):
+    """Softmax attention over the cache. `ai` indexes the ATTENTION layers'
+    weight stacks and the cache's layer axis (a hybrid model has fewer of
+    both than it has layers). Returns (out [B, T, D], k_cache, v_cache)."""
+    b, t, d = h.shape
+    kvd = cfg.kv_dim
+    if "wqkv" in layers:  # fused launch (fuse_layer_weights)
+        qkv = mm(h, layers["wqkv"], ai)
+        q, k, v = qkv[..., :d], qkv[..., d : d + kvd], qkv[..., d + kvd :]
+    else:
+        q = mm(h, layers["wq"], ai)
+        k = mm(h, layers["wk"], ai)
+        v = mm(h, layers["wv"], ai)
+    q = q.reshape(b, t, cfg.n_heads, cfg.head_size)
+    k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_size)
+    v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_size)
+    if rope is not None:  # RopeType.NONE: q and k as projected
+        q = apply_rope(q, rope)
+        k = apply_rope(k, rope)
+    if cfg.softmax_scale_ratio != 1.0:
+        # a configured score scale: every attention path bakes 1/sqrt(hd)
+        # in, so q carries the ratio (a power of two where the scale is one:
+        # exact in bf16)
+        q = q * jnp.asarray(cfg.softmax_scale_ratio, q.dtype)
+    if tables is None:
+        k_cache = _cache_update(k_cache, k.transpose(0, 2, 1, 3), pos_base, active)
+        v_cache = _cache_update(v_cache, v.transpose(0, 2, 1, 3), pos_base, active)
+        att = attn_fn(q, k_cache, v_cache, pos_base).reshape(b, t, d)
+    elif getattr(attn_fn, "fused_kv_scatter", False):
+        # paged flash-decode kernel: the new rows' scatter write is fused
+        # into the attention launch (ops/pallas/paged_attention) — no
+        # separate per-layer scatter dispatch, identical pool contents.
+        # k_cache/v_cache are the WHOLE layer-stacked pools here (run_layers
+        # carries them): the kernel indexes layer `ai` itself, like the
+        # matmuls index the weight stacks
+        att, k_cache, v_cache = attn_fn(
+            q, k_cache, v_cache, tables, pos_base,
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), active, ai)
+        att = att.reshape(b, t, d)
+    else:  # paged layout: scatter at block-table positions, same math
+        k_cache = _paged_cache_update(k_cache, k.transpose(0, 2, 1, 3),
+                                      tables, pos_base, active)
+        v_cache = _paged_cache_update(v_cache, v.transpose(0, 2, 1, 3),
+                                      tables, pos_base, active)
+        att = attn_fn(q, k_cache, v_cache, tables, pos_base).reshape(b, t, d)
+    return colmm(att, layers["wo"], ai), k_cache, v_cache
 
-    `k_cache`/`v_cache` are this layer's slice of the cache ([B, Hkv, S, hd],
-    or [P, Hkv, page, hd] of a page pool) and come back updated — except on
-    the paged kernel route (`attn_fn.fused_kv_scatter`), where they are the
-    whole stacked pools [L, P, Hkv, page, lanes], for the same reason the
-    weights are whole: the kernel reads and writes layer `li`'s pages in
-    place, and a 70 MB slice cut out and put back per layer per step was
-    45% of a 7B decode step's device time (PERF.md section 6, PR 27).
+
+def _ssm_mixer(cfg: LlamaConfig, h, layers, si, state: RecurrentState,
+               pos_base, active, mm, colmm):
+    """The Mamba-2 state-space mixer (ops/ssm.py has the equations). `si`
+    indexes the state-space layers' weight stacks and the state's layer
+    axis. A row at position 0 starts from ZERO state and a zero conv window,
+    whatever its slot held: a sequence's start has no history, so a slot
+    re-used by a new request cannot read the last one's state. Rows with
+    active==False leave both bit-equal. Returns (out [B, T, D], state)."""
+    b, t, _ = h.shape
+    inner, n = cfg.ssm_inner, cfg.ssm_state
+    heads, p, cd = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_conv_dim
+    proj = mm(h, layers["in_proj"], si)  # z | x B C | dt (| zero pad)
+    z, xbc = proj[..., :inner], proj[..., inner : inner + cd]
+    dt_raw = proj[..., inner + cd : inner + cd + heads]
+    fresh = jnp.broadcast_to(jnp.asarray(pos_base, jnp.int32) == 0, (b,))
+    window = state.window(si)
+    xbc, new_window = ssm.causal_conv(xbc, window, layers["conv_w"][si],
+                                      layers["conv_b"][si], fresh)
+    x = xbc[..., :inner].reshape(b, t, heads, p)
+    bmat, cmat = xbc[..., inner : inner + n], xbc[..., inner + n :]
+    dt, log_a = ssm.decay_terms(dt_raw, layers["dt_bias"][si], layers["a_log"][si])
+    act = jnp.ones((b,), bool) if active is None else active
+    new_window = jnp.where(act[:, None, None], new_window, window)
+    if t == 1 and state.slot is None and state.step is not None:
+        # the decode step the engine resolved, on the layer-stacked state,
+        # in place
+        mode = jnp.where(act, jnp.where(fresh, 2, 1), 0)
+        y, s_stack = state.step(state.s, si, x[:, 0], dt[:, 0],
+                                jnp.exp(log_a[:, 0]), bmat[:, 0], cmat[:, 0],
+                                mode)
+        y = y[:, None]
+        state = state.replace_layer(si, new_window, s_stack=s_stack)
+    else:
+        s_old = state.layer_state(si)
+        s_in = jnp.where(fresh[:, None, None, None], 0.0,
+                         s_old.astype(jnp.float32))
+        if t == 1:
+            y, s_new = ssm.ssm_step_ref(s_in, x[:, 0], dt[:, 0], log_a[:, 0],
+                                        bmat[:, 0], cmat[:, 0])
+            y = y[:, None]
+        else:
+            y, s_new = ssm.ssm_chunk_scan(s_in, x, dt, log_a, bmat, cmat,
+                                          cfg.ssm_chunk)
+        s_new = jnp.where(act[:, None, None, None], s_new.astype(s_old.dtype), s_old)
+        state = state.replace_layer(si, new_window, s_new=s_new)
+    y = y + layers["d"][si][:, None] * x
+    y = ssm.gated_rms_norm(y.reshape(b, t, inner), z, layers["ssm_norm"][si],
+                           cfg.norm_epsilon).astype(h.dtype)
+    return colmm(y, layers["out_proj"], si), state
+
+
+def _mlp(cfg: LlamaConfig, h, layers, li, mm, colmm, moe_impl):
+    """The feed-forward block (reference "ff" segment, llm.cpp:314-385);
+    sparse-MoE variant when the header carries N_EXPERTS (llm.hpp:17-18 —
+    a key the reference parses but never executes)."""
+    if "moe_gate" in layers:
+        return moe_ffn(
+            cfg, h, layers["moe_gate"][li],
+            _slice_layer(layers["moe_w1"], li),
+            _slice_layer(layers["moe_w2"], li),
+            _slice_layer(layers["moe_w3"], li),
+            impl=moe_impl,
+        )
+    if "w13" in layers:  # fused launch (fuse_layer_weights)
+        gu = mm(h, layers["w13"], li)
+        f = cfg.hidden_dim
+        gate = activation(gu[..., :f].astype(jnp.float32), cfg.hidden_act).astype(h.dtype)
+        return colmm(gate * gu[..., f:], layers["w2"], li)
+    gate = activation(mm(h, layers["w1"], li).astype(jnp.float32), cfg.hidden_act).astype(h.dtype)
+    up = mm(h, layers["w3"], li)
+    return colmm(gate * up, layers["w2"], li)
+
+
+def _layer(cfg: LlamaConfig, x, layers, li, mix, col_fn=None, mm=None,
+           mm_in=None, moe_impl="auto"):
+    """One decoder layer: the ONE skeleton every architecture runs,
+
+        x += r * mix(norm(x));  x += r * mlp(norm(x))
+
+    with `mix(h, mm, colmm) -> (out, aux)` the layer's mixer (attention or
+    state-space, chosen by the caller from the layer's kind; `aux` is
+    whatever cache or state it updated) and r the residual multiplier (1
+    unless the header says otherwise). `layers` is the full stacked params
+    dict and `li` the traced layer index — quantized weights are NOT sliced
+    here: the matmul dispatcher either DMA-indexes the stack (Pallas scalar
+    prefetch) or slices lazily (XLA path). Slicing stacked weights before a
+    pallas_call would make XLA materialize a full HBM copy of every weight,
+    every layer, every token. Caches and states follow the same rule where
+    a kernel takes them: the paged kernel route (`attn_fn.fused_kv_scatter`)
+    and the state-space decode step are handed the whole stacked arrays and
+    read and write their layer in place — a 70 MB slice cut out and put
+    back per layer per step was 45% of a 7B decode step's device time
+    (PERF.md section 6, PR 27).
 
     `mm_in` is the matmul for the INPUT-dim-sharded weights (wo/w2 — the
     reference's col slices with merge-add): under sharded-Pallas it psums
@@ -171,66 +420,14 @@ def _layer(cfg: LlamaConfig, x, layers, li, k_cache, v_cache, rope, pos_base, at
     else:
         def colmm(h, w, layer=None):
             return col_fn(h, _slice_layer(w, layer) if layer is not None else w)
-    b, t, d = x.shape
-    kvd = cfg.kv_dim
-    # --- attention block (reference "att" segment, llm.cpp:198-312)
+    r = cfg.residual_multiplier
+    scaled = (lambda y: y) if r == 1.0 else (lambda y: y * jnp.asarray(r, y.dtype))
+    # --- mixer block (reference "att" segment, llm.cpp:198-312)
     h = rms_norm(x, layers["rms_att"][li], cfg.norm_epsilon)
-    if "wqkv" in layers:  # fused launch (fuse_layer_weights)
-        qkv = mm(h, layers["wqkv"], li)
-        q, k, v = qkv[..., :d], qkv[..., d : d + kvd], qkv[..., d + kvd :]
-    else:
-        q = mm(h, layers["wq"], li)
-        k = mm(h, layers["wk"], li)
-        v = mm(h, layers["wv"], li)
-    q = q.reshape(b, t, cfg.n_heads, cfg.head_size)
-    k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_size)
-    v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_size)
-    q = apply_rope(q, rope)
-    k = apply_rope(k, rope)
-    if tables is None:
-        k_cache = _cache_update(k_cache, k.transpose(0, 2, 1, 3), pos_base, active)
-        v_cache = _cache_update(v_cache, v.transpose(0, 2, 1, 3), pos_base, active)
-        att = attn_fn(q, k_cache, v_cache, pos_base).reshape(b, t, d)
-    elif getattr(attn_fn, "fused_kv_scatter", False):
-        # paged flash-decode kernel: the new rows' scatter write is fused
-        # into the attention launch (ops/pallas/paged_attention) — no
-        # separate per-layer scatter dispatch, identical pool contents.
-        # k_cache/v_cache are the WHOLE layer-stacked pools here (run_layers
-        # carries them): the kernel indexes layer `li` itself, like the
-        # matmuls index the weight stacks
-        att, k_cache, v_cache = attn_fn(
-            q, k_cache, v_cache, tables, pos_base,
-            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), active, li)
-        att = att.reshape(b, t, d)
-    else:  # paged layout: scatter at block-table positions, same math
-        k_cache = _paged_cache_update(k_cache, k.transpose(0, 2, 1, 3),
-                                      tables, pos_base, active)
-        v_cache = _paged_cache_update(v_cache, v.transpose(0, 2, 1, 3),
-                                      tables, pos_base, active)
-        att = attn_fn(q, k_cache, v_cache, tables, pos_base).reshape(b, t, d)
-    x = x + colmm(att, layers["wo"], li)
-    # --- feed-forward block (reference "ff" segment, llm.cpp:314-385);
-    # sparse-MoE variant when the header carries N_EXPERTS (llm.hpp:17-18 —
-    # a key the reference parses but never executes)
+    out, aux = mix(h, mm, colmm)
+    x = x + scaled(out)
     h = rms_norm(x, layers["rms_ffn"][li], cfg.norm_epsilon)
-    if "moe_gate" in layers:
-        x = x + moe_ffn(
-            cfg, h, layers["moe_gate"][li],
-            _slice_layer(layers["moe_w1"], li),
-            _slice_layer(layers["moe_w2"], li),
-            _slice_layer(layers["moe_w3"], li),
-            impl=moe_impl,
-        )
-    elif "w13" in layers:  # fused launch (fuse_layer_weights)
-        gu = mm(h, layers["w13"], li)
-        f = cfg.hidden_dim
-        gate = activation(gu[..., :f].astype(jnp.float32), cfg.hidden_act).astype(x.dtype)
-        x = x + colmm(gate * gu[..., f:], layers["w2"], li)
-    else:
-        gate = activation(mm(h, layers["w1"], li).astype(jnp.float32), cfg.hidden_act).astype(x.dtype)
-        up = mm(h, layers["w3"], li)
-        x = x + colmm(gate * up, layers["w2"], li)
-    return x, k_cache, v_cache
+    return x + scaled(_mlp(cfg, h, layers, li, mm, colmm, moe_impl)), aux
 
 
 def fuse_layer_weights(layers: dict) -> dict:
@@ -271,14 +468,34 @@ def fuse_layer_weights(layers: dict) -> dict:
     return out
 
 
+def layer_schedule(kinds: tuple) -> tuple[int, list[tuple[int, int, int]]]:
+    """(period, runs) of a layer pattern: the shortest period the pattern
+    repeats with, and within one period the runs of equal kinds as
+    (kind, first offset in the period, length). A homogeneous stack is
+    period 1 with one run of one layer; `m m m m m a m m m m` x 4 is period
+    10 with runs (ssm, 0, 5), (attention, 5, 1), (ssm, 6, 4)."""
+    n = len(kinds)
+    period = next(p for p in range(1, n + 1)
+                  if n % p == 0 and all(kinds[i] == kinds[i % p] for i in range(n)))
+    runs: list = []
+    for off in range(period):
+        if runs and runs[-1][0] == kinds[off]:
+            runs[-1][2] += 1
+        else:
+            runs.append([kinds[off], off, 1])
+    return period, [tuple(r) for r in runs]
+
+
 def run_layers(
     cfg: LlamaConfig,
-    layer_params: dict,  # stacked [L, ...] leaves
+    layer_params: dict,  # stacked [L, ...] leaves (per kind: a mixer's
+    # weights are stacked over the layers of ITS kind)
     x: jax.Array,  # [B, T, D]
     pos_base: jax.Array,  # scalar, or [B] per-row positions
-    k_cache: jax.Array,  # [L, B, Hkv, S, hd]
+    k_cache: jax.Array,  # [La, B, Hkv, S, hd], La = attention layers
     v_cache: jax.Array,
-    rope: jax.Array,  # [T, head_size/2, 2] rope rows (or [B, T, ...] per-row)
+    rope: jax.Array | None,  # [T, head_size/2, 2] rope rows (or [B, T, ...]
+    # per-row); None = no rotation
     attn_fn=None,
     active: jax.Array | None = None,  # [B] bool: rows allowed to write cache
     unroll: int | bool = 1,
@@ -288,14 +505,22 @@ def run_layers(
     moe_impl: str = "auto",  # MoE compute scheme (ops.layers.moe_ffn)
     tables: jax.Array | None = None,  # i32 [B, max_blocks] block tables —
     # presence selects the paged cache layout (k/v are then page pools)
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+    state: "RecurrentState | None" = None,  # the state-space layers' state
+) -> tuple[jax.Array, jax.Array, jax.Array, "RecurrentState | None"]:
     """Scan the decoder layers (any contiguous stack — the full model, or one
-    pipeline stage's slice). Returns (x, k_cache, v_cache).
+    pipeline stage's slice). Returns (x, k_cache, v_cache, state).
 
-    The scan iterates over the layer INDEX — the stacked weights stay
-    closed-over and un-sliced, so the Pallas kernels can DMA-index them with
-    zero copies (ops/pallas/q40_matmul.py docstring). The cache rides in one
-    of two ways, decided by what the call was given:
+    The layers are scanned BY PERIOD of the layer pattern
+    (`layer_schedule`): the scan's body holds each run of equal layers once
+    (a run longer than one layer is an inner scan), so a 40-layer stack of
+    period 10 compiles two state-space bodies and one attention body, and a
+    homogeneous stack is the plain scan over layers it always was. The scan
+    iterates over INDICES — the stacked weights stay closed-over and
+    un-sliced, so the Pallas kernels can DMA-index them with zero copies
+    (ops/pallas/q40_matmul.py docstring); each kind counts its own layers
+    (`ai` into the attention stacks and the cache, `si` into the state-space
+    stacks and the state). The caches ride in one of two ways, decided by
+    what the call was given:
 
     * a page pool whose attention function is the fused paged kernel
       (`tables` and `attn_fn.fused_kv_scatter`): the whole stacked pools are
@@ -306,6 +531,8 @@ def run_layers(
       ring, the paged gather route): the per-layer slices are the scan's
       xs and ys, as XLA attention over a layer's slice wants them.
 
+    The recurrent state is always carried whole and updated in place.
+
     `unroll`: passed to lax.scan — trades compile time for cross-layer
     scheduling freedom."""
     if attn_fn is None:
@@ -315,31 +542,101 @@ def run_layers(
             from dllama_tpu.ops.layers import paged_gqa_attention
 
             attn_fn = paged_gqa_attention
-    n_layers = k_cache.shape[0]
-    layer_ids = jnp.arange(n_layers, dtype=jnp.int32)
+    kinds = cfg.layer_kinds or (int(LayerKind.ATTENTION),) * k_cache.shape[0]
+    period, runs = layer_schedule(kinds)
+    n_periods = len(kinds) // period
+    a_pp = sum(n for kind, _, n in runs if kind == LayerKind.ATTENTION)
+    s_pp = period - a_pp
+    fused = tables is not None and getattr(attn_fn, "fused_kv_scatter", False)
 
-    if tables is not None and getattr(attn_fn, "fused_kv_scatter", False):
-        def pool_scan_fn(carry, li):
-            x, kp, vp = carry
-            return _layer(cfg, x, layer_params, li, kp, vp, rope, pos_base,
-                          attn_fn, active, col_fn, mm, mm_in, moe_impl,
-                          tables), None
+    def one_layer(x, kc, vc, st, li, ai, si, kind):
+        """Layer `li` of kind `kind`; kc/vc are the whole pools (fused) or
+        this layer's slice."""
+        if kind == LayerKind.SSM:
+            def mix(h, mm_, colmm):
+                return _ssm_mixer(cfg, h, layer_params, si, st, pos_base,
+                                  active, mm_, colmm)
 
-        (x, k_new, v_new), _ = jax.lax.scan(
-            pool_scan_fn, (x, k_cache, v_cache), layer_ids, unroll=unroll)
-        return x, k_new, v_new
+            x, st = _layer(cfg, x, layer_params, li, mix, col_fn, mm, mm_in,
+                           moe_impl)
+            return x, kc, vc, st
 
-    def scan_fn(carry, xs):
-        x = carry
-        li, kc, vc = xs
-        x, kc, vc = _layer(cfg, x, layer_params, li, kc, vc, rope, pos_base, attn_fn,
-                           active, col_fn, mm, mm_in, moe_impl, tables)
-        return x, (kc, vc)
+        def mix(h, mm_, colmm):
+            out, k2, v2 = _attention_mixer(cfg, h, layer_params, ai, kc, vc,
+                                           rope, pos_base, attn_fn, active,
+                                           mm_, colmm, tables)
+            return out, (k2, v2)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        scan_fn, x, (layer_ids, k_cache, v_cache), unroll=unroll,
-    )
-    return x, k_new, v_new
+        x, (kc, vc) = _layer(cfg, x, layer_params, li, mix, col_fn, mm, mm_in,
+                             moe_impl)
+        return x, kc, vc, st
+
+    def period_fn(carry, xs):
+        """One period: each run of equal layers once. Fused: the pools are
+        in the carry. Else: `kx`/`vx` are this period's [a_pp, ...] slices
+        (the scan's xs), rebuilt into its ys."""
+        x, kp, vp, st = carry
+        pi, kx, vx = xs
+        if not fused and a_pp == 1:  # the xs ARE the layer's slices
+            kx, vx = kx[None], vx[None]
+        k_out, v_out = [], []
+        a_off = s_off = 0
+        for kind, off, n in runs:
+            is_attn = kind == LayerKind.ATTENTION
+            li0 = pi * period + off
+            ai0, si0 = pi * a_pp + a_off, pi * s_pp + s_off
+
+            def run_fn(c, j_kv, kind=kind, li0=li0, ai0=ai0, si0=si0,
+                       is_attn=is_attn):
+                x, kp, vp, st = c
+                j, kc, vc = j_kv
+                if fused or not is_attn:
+                    x, kp, vp, st = one_layer(x, kp, vp, st, li0 + j, ai0 + j,
+                                              si0 + j, kind)
+                    return (x, kp, vp, st), None
+                x, kc, vc, st = one_layer(x, kc, vc, st, li0 + j, ai0 + j,
+                                          si0 + j, kind)
+                return (x, kp, vp, st), (kc, vc)
+
+            sliced = is_attn and not fused
+            kr = kx[a_off : a_off + n] if sliced else None
+            vr = vx[a_off : a_off + n] if sliced else None
+            if n == 1:
+                c, ys = run_fn((x, kp, vp, st), (
+                    0, kr[0] if sliced else None, vr[0] if sliced else None))
+                if sliced:
+                    ys = (ys[0][None], ys[1][None])
+            else:
+                c, ys = jax.lax.scan(
+                    run_fn, (x, kp, vp, st),
+                    (jnp.arange(n, dtype=jnp.int32), kr, vr))
+            x, kp, vp, st = c
+            if sliced:
+                k_out.append(ys[0])
+                v_out.append(ys[1])
+            if is_attn:
+                a_off += n
+            else:
+                s_off += n
+        ys = None
+        if not fused and a_pp:
+            ys = (jnp.concatenate(k_out) if len(k_out) > 1 else k_out[0],
+                  jnp.concatenate(v_out) if len(v_out) > 1 else v_out[0])
+            if a_pp == 1:
+                ys = (ys[0][0], ys[1][0])
+        return (x, kp, vp, st), ys
+
+    period_ids = jnp.arange(n_periods, dtype=jnp.int32)
+    if fused:
+        (x, k_new, v_new, state), _ = jax.lax.scan(
+            period_fn, (x, k_cache, v_cache, state), (period_ids, None, None),
+            unroll=unroll)
+        return x, k_new, v_new, state
+    by_period = lambda c: c if a_pp == 1 else c.reshape(n_periods, a_pp, *c.shape[1:])
+    (x, _, _, state), (k_new, v_new) = jax.lax.scan(
+        period_fn, (x, None, None, state),
+        (period_ids, by_period(k_cache), by_period(v_cache)), unroll=unroll)
+    return x, k_new.reshape(k_cache.shape), v_new.reshape(v_cache.shape), state
 
 
 def forward(
@@ -374,28 +671,36 @@ def forward(
 
     `cache` may be a dense KVCache or a PagedKVCache — the paged layout
     threads its block tables through the layer scan (scatter writes at
-    table positions, gather/block-indexed attention; identical math)."""
+    table positions, gather/block-indexed attention; identical math). Either
+    carries the state-space layers' RecurrentState where the model has any.
+
+    The header's scalars apply here and in `_layer` (all 1 for a LLAMA
+    file): h0 = embedding_multiplier * E[token]; logits / logits_scaling."""
     x = params["embedding"][tokens]  # [B, T, D]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     t = tokens.shape[1]
     pos_base = jnp.asarray(pos_base, jnp.int32)
-    if pos_base.ndim == 1:
+    if cfg.rope_type == RopeType.NONE:
+        rope = None
+    elif pos_base.ndim == 1:
         idx = pos_base[:, None] + jnp.arange(t, dtype=jnp.int32)[None]  # [B, T]
         rope = rope_cache[jnp.clip(idx, 0, rope_cache.shape[0] - 1)]
     else:
         rope = jax.lax.dynamic_slice_in_dim(rope_cache, pos_base, t, axis=0)
     paged = isinstance(cache, PagedKVCache)
-    x, k_new, v_new = run_layers(
+    x, k_new, v_new, state = run_layers(
         cfg, params["layers"], x, pos_base, cache.k, cache.v, rope, attn_fn, active,
         unroll=unroll, col_fn=col_fn, mm=mm, mm_in=mm_in, moe_impl=moe_impl,
-        tables=cache.tables if paged else None,
+        tables=cache.tables if paged else None, state=cache.state,
     )
     if last_only:
         x = x[:, -1:]
     x = rms_norm(x, params["final_norm"], cfg.norm_epsilon)
     logits = (mm or matmul)(x, params["wcls"]).astype(jnp.float32)
-    if paged:
-        return logits, PagedKVCache(k_new, v_new, cache.tables)
-    return logits, KVCache(k_new, v_new)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits, dataclasses.replace(cache, k=k_new, v=v_new, state=state)
 
 
 def random_params_fast(cfg: LlamaConfig, seed: int = 0, dtype=jnp.bfloat16):

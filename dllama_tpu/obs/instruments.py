@@ -324,6 +324,23 @@ LAUNCH_PREFILL_ROWS = metrics.counter(
     "Prompt rows written by the launches, by kind (a hybrid launch's "
     "slice, a prefill chunk)",
     ("kind",))
+# recurrent (state-space) models: the per-slot state beside the page pool
+RECURRENT_STATE_BYTES = metrics.gauge(
+    "dllama_recurrent_state_bytes",
+    "Bytes of per-slot recurrent state (state-space layers' S and conv "
+    "window) resident in HBM beside the KV cache; 0 for a KV-only model")
+STATE_RESETS = metrics.counter(
+    "dllama_state_resets_total",
+    "Admissions that started a slot's recurrent state from zero "
+    "(add_begin at row 0: the forward zeroes the state on the device)")
+PREFIX_ROWS_RECOMPUTED = metrics.counter(
+    "dllama_prefix_rows_recomputed_total",
+    "Rows of a reusable prefix (a radix hit or the slot's own history) that "
+    "an admission prefilled again because the recurrent state could not be "
+    "re-entered there, by reason: state_elsewhere (the slot's state stands "
+    "at another row or is unknown) or cross_slot (the rows are another "
+    "slot's)",
+    ("reason",))
 ADMISSION_STALL_SECONDS = metrics.histogram(
     "dllama_admission_stall_seconds",
     "Decode-to-decode gap inserted by admission work between fused chunks "

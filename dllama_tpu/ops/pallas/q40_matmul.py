@@ -171,7 +171,11 @@ def _deq_call(layer, x, packed, scales, *, interpret: bool = False):
     """x[m, k] @ dequant(packed[layer], scales[layer]) -> f32[m, n]."""
     m, k = x.shape
     n = packed.shape[-1]
-    tm = _pick_tile(m, (512, 256, 128, 64, 32, 16, 8))
+    # every m tile streams and dequantises the WHOLE weight again, so a batch
+    # up to 512 rows is one tile whatever it divides by: m = 48 (48 serving
+    # slots) split as 3 x 16 cost three passes, 124 us a 2048 x 8192 call
+    # where m = 64 cost 59 (my chip run, PR 29, experiments/q40_decode_bench.py)
+    tm = m if m <= 512 else _pick_tile(m, (512, 256, 128, 64, 32, 16, 8))
     tn = _pick_tile(n, (512, 256, 128))
     tk = _pick_tile(k, (512, 256, 128, 64, 32))
     grid = (m // tm, n // tn, k // tk)

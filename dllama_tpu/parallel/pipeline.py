@@ -51,7 +51,7 @@ def _shift_right(x: jax.Array, pp: int) -> jax.Array:
 
 
 def _stage_body(cfg: LlamaConfig, attn_fn, mm, layers, x, pos, k, v, rope):
-    x, k, v = run_layers(cfg, layers, x, pos, k, v, rope, attn_fn, mm=mm)
+    x, k, v, _ = run_layers(cfg, layers, x, pos, k, v, rope, attn_fn, mm=mm)
     return x, k, v
 
 

@@ -279,11 +279,12 @@ class ApiServer:
         # startup HBM gauges (model_params_bytes / kv_cache_bytes): account
         # the engine that actually serves — the BatchEngine owns the slot
         # cache on the continuous tier, loaded.engine on the single tier
-        from dllama_tpu.utils.profiling import set_memory_gauges
+        from dllama_tpu.utils.profiling import set_memory_gauges, state_nbytes
 
         eng = scheduler.engine if scheduler is not None else self.engine
         self.model_params_bytes, self.kv_cache_bytes = set_memory_gauges(
             eng.params, eng.cache)
+        self.recurrent_state_bytes = state_nbytes(eng.cache)
         # build-info gauge (value always 1; the labels ARE the payload): what
         # exactly is serving — package + jax versions, the device as JAX
         # reports it, the resolved kernel route, and whether the overlapped
@@ -348,6 +349,7 @@ class ApiServer:
         # capacity questions don't need a restart with --report
         h["model_params_bytes"] = self.model_params_bytes
         h["kv_cache_bytes"] = self.kv_cache_bytes
+        h["recurrent_state_bytes"] = self.recurrent_state_bytes
         h["build"] = self.build_info
         # process self-metrics ride every probe (and /metrics as gauges):
         # uptime answers "did it just restart", RSS + threads answer "is it
@@ -433,9 +435,7 @@ class ApiServer:
         self._trace_single_submit(req_id, t_submit)
         with self.lock:
             t_admit = time.monotonic()
-            delta, start_pos, add_bos = self.cache.resolve(messages)
-            if start_pos == 0:
-                self.cache.clear()
+            delta, start_pos, add_bos = self._resolve_prefix(messages)
             self.engine.reset(start_pos)
             generated = self.template.generate(
                 [ChatItem(r, c) for r, c in delta], append_generation_prompt=True
@@ -517,6 +517,20 @@ class ApiServer:
                            for m in messages)):
             raise ApiError(400, "messages must be a non-empty array of "
                                 "{role, content} objects")
+
+    def _resolve_prefix(self, messages):
+        """(delta messages, start_pos, add_bos) of a conversation against
+        the prefix cache, clipped to where the engine can resume: a model
+        with recurrent state continues only where that state stands, else
+        the whole conversation is fed again from row 0."""
+        delta, start_pos, add_bos = self.cache.resolve(messages)
+        if start_pos and not self.engine.can_resume_at(start_pos):
+            ins.PREFIX_ROWS_RECOMPUTED.labels(
+                reason="state_elsewhere").inc(start_pos)
+            delta, start_pos, add_bos = messages, 0, True
+        if start_pos == 0:
+            self.cache.clear()
+        return delta, start_pos, add_bos
 
     def _budget_and_sampler(self, prompt_len, max_tokens, temperature, topp,
                             seed, presence, frequency):

@@ -441,6 +441,15 @@ class Scheduler:
         self.prefill_budget = prefill_budget  # "auto" | int (0 = legacy)
         self._hybrid_on = (prefill_budget != 0
                            and getattr(engine, "supports_hybrid", False))
+        # pipelined commit: an admission's first token is sampled behind
+        # the chunk that carried its last prompt rows and read after that
+        # chunk is consumed, with the successor already on the device, so
+        # a commit costs the device nothing (the joiner decodes one chunk
+        # later). Not with speculation: a slot must not be activated under
+        # a spec chunk in flight (_commit_ready_inflight).
+        self._pipelined_commit = (self._hybrid_on and self.overlap
+                                  and hasattr(engine, "add_sample")
+                                  and not getattr(engine, "spec_k", 0))
         self._budget_ctl = None
         if not self._hybrid_on:
             self._budget_now = 0
@@ -466,6 +475,17 @@ class Scheduler:
         if preempt not in ("auto", "on", "off"):
             raise ValueError(f"preempt must be auto|on|off, got {preempt!r}")
         self._preempt_on = preempt != "off"
+        if self._preempt_on and not getattr(engine, "rows_reenterable", True):
+            # a suspended request resumes by re-entering its kept rows in
+            # whatever slot is free: recurrent state stands in ONE slot at
+            # ONE row, so every resume would re-prefill the whole stream
+            if preempt == "on":
+                raise ValueError(
+                    "--preempt on needs rows that can be re-entered; this "
+                    "model's recurrent state cannot (use --preempt off)")
+            log.info("preempt-to-pages off: the model's recurrent state "
+                     "cannot be re-entered at a suspended request's rows")
+            self._preempt_on = False
         self.preempt_count = 0  # lifetime totals (latency_summary/health)
         self.resume_count = 0
         # ---- compile observability (ISSUE 13, obs/compile): declare THIS
@@ -947,10 +967,25 @@ class Scheduler:
         best_any = max(donors, key=lcp.__getitem__)
         if lcp[best_any] > lcp[best_idle]:
             dst = min(idle, key=lambda s: len(self.slot_tokens.get(s, [])))
-            return dst, lcp[best_any], best_any
+            return self._resumable(dst, lcp[best_any], best_any)
         if lcp[best_idle] > 0:
-            return best_idle, lcp[best_idle], None
+            return self._resumable(best_idle, lcp[best_idle], None)
         return min(idle, key=lambda s: len(self.slot_tokens.get(s, []))), 0, None
+
+    def _resumable(self, slot: int, reuse: int,
+                   donor: int | None) -> tuple[int, int, int | None]:
+        """Clip a matched prefix to what the engine can start after
+        (`BatchEngine.resumable_rows`: everything for a KV-only model; for
+        recurrent state only the slot's own rows, and only where the state
+        stands). Rows clipped away are prefilled again and counted."""
+        resume = getattr(self.engine, "resumable_rows", None)
+        ok = reuse if resume is None else resume(slot, reuse, donor)
+        if ok == reuse:
+            return slot, reuse, donor
+        cross = donor is not None and donor != slot
+        ins.PREFIX_ROWS_RECOMPUTED.labels(
+            reason="cross_slot" if cross else "state_elsewhere").inc(reuse - ok)
+        return slot, ok, None
 
     def _lcp_lengths(self, prompt: list[int], donors: list[int]) -> dict[int, int]:
         """Longest-common-prefix length of `prompt` against every donor
@@ -1505,14 +1540,17 @@ class Scheduler:
                                req_id=req.req_id,
                                tokens=req.produced)
 
-    def _commit_ready_inflight(self) -> None:
+    def _commit_ready_inflight(self, sampled_only: bool = False) -> None:
         """Opportunistic early commit (overlapped loop): while the chunk in
         flight is a plain/hybrid (non-spec) chunk, a fully-pumped head
         admission can commit NOW — blocking only on its own logits (which
         materialize with that chunk) instead of draining the pipeline for a
         whole boundary. Spec chunks are excluded: their data-dependent
         position advance must settle before any host-side slot activation
-        touches shared state."""
+        touches shared state. `sampled_only` (the pipelined commit) leaves
+        a fresh admission whose first token is not being sampled yet: its
+        logits come with the chunk just dispatched, and reading them now
+        would hold the host for that whole chunk."""
         while self._inflight:
             req, adm, reuse = self._inflight[0]
             now = time.monotonic()
@@ -1520,6 +1558,9 @@ class Scheduler:
                     or (req.deadline_at is not None
                         and now >= req.deadline_at)):
                 return  # mid-pump or needs abort handling at a boundary
+            if (sampled_only and adm.sampled is None
+                    and req.resume_tokens is None):
+                return
             try:
                 self._commit_admission(req, adm, reuse)
             except Exception as e:
@@ -1530,6 +1571,21 @@ class Scheduler:
                 if self._inflight and self._inflight[0][1] is adm:
                     self._inflight.pop(0)
                 self._abort_admission(req, adm, e)
+
+    def _sample_ready_inflight(self) -> None:
+        """Pipelined commit, first half: dispatch the first-token sampling
+        of every fully-pumped fresh admission, reading nothing. It queues
+        behind the chunk in flight (which carried the admission's last
+        prompt rows) and ahead of the successor about to be dispatched, so
+        the commit that follows that chunk's consumption finds its token
+        ready: the device never waits for a commit."""
+        for req, adm, _ in self._inflight:
+            if adm.off < len(adm.toks):
+                return  # admissions pump head first
+            if (adm.sampled is None and req.resume_tokens is None
+                    and not req.cancelled.is_set()):
+                self.engine.add_sample(adm, req.temperature, req.topp,
+                                       seed=req.seed)
 
     def _hybrid_now(self) -> bool:
         """Whether in-flight admissions ride fused hybrid chunks right now:
@@ -1881,7 +1937,11 @@ class Scheduler:
                 return True
             req, adm, _ = self._inflight[0]
             now0 = time.monotonic()
-            if (adm.off >= len(adm.toks) or req.cancelled.is_set()
+            # the pipelined commit takes a pumped head after the chunk in
+            # flight is consumed: no boundary for it
+            pumped = adm.off >= len(adm.toks) and not (
+                self._pipelined_commit and inflight_chunk is not None)
+            if (pumped or req.cancelled.is_set()
                     or (req.deadline_at is not None
                         and now0 >= req.deadline_at)
                     or (self.admit_ttft_deadline_ms is not None
@@ -1976,13 +2036,17 @@ class Scheduler:
         # prefill launch ever stalls the decode cadence. Hybrid chunks are
         # plain (non-spec) chunks; an in-flight spec chunk drains through
         # the same mode-switch bail as spec<->plain.
-        hyb_adm = None
+        hyb_adm = hyb_req = None
         if self._hybrid_now() and self._inflight:
-            _req, _adm, _ = self._inflight[0]
+            # the head, or under the pipelined commit the first admission
+            # behind heads that are pumped and wait for their commit
+            _req, _adm, _ = next(
+                (e for e in self._inflight if e[1].off < len(e[1].toks)
+                 or not self._pipelined_commit), self._inflight[0])
             if (_adm.off < len(_adm.toks) and not _req.cancelled.is_set()
                     and (_req.deadline_at is None
                          or time.monotonic() < _req.deadline_at)):
-                hyb_adm = _adm
+                hyb_adm, hyb_req = _adm, _req
         self.ledger.transition("hybrid" if hyb_adm is not None
                                else "decode_dispatch")
         use_spec = False
@@ -2045,8 +2109,9 @@ class Scheduler:
                 # launch is indistinguishable from a decode failure — the
                 # jit donates the cache — and stays engine-fatal, handled
                 # by warm restart.)
-                req, adm, _reuse = self._inflight.pop(0)
-                self._abort_admission(req, adm, e)
+                self._inflight[:] = [e_ for e_ in self._inflight
+                                     if e_[1] is not hyb_adm]
+                self._abort_admission(hyb_req, hyb_adm, e)
                 return self.engine.decode_dispatch(n_disp, spec=False)
 
         tr = trace.TRACER
@@ -2067,10 +2132,9 @@ class Scheduler:
                 # the flight recorder's prefill story stays complete under
                 # hybrid: each fused slice is a prefill.chunk span for the
                 # ADMITTING request, bracketing the dispatch
-                _req = self._inflight[0][0] if self._inflight else None
                 tr.span_at("prefill.chunk", t0, tr.now(), cat="prefill",
                            track="scheduler",
-                           req_id=_req.req_id if _req else "",
+                           req_id=hyb_req.req_id if hyb_req else "",
                            slot=chunk.hybrid_slot, off=int(hyb_adm.off),
                            total=len(hyb_adm.toks), hybrid=True)
             return chunk, dict(self.slots)
@@ -2176,7 +2240,10 @@ class Scheduler:
                     # release-side boundary work still wait for settled
                     # state.
                     if self._inflight:
-                        self._commit_ready_inflight()
+                        if self._pipelined_commit:
+                            self._sample_ready_inflight()
+                        else:
+                            self._commit_ready_inflight()
                     if self._backlog or not self.pending.empty():
                         self.ledger.transition("admission")
                         self._admit_starts(boundary=False)
@@ -2184,6 +2251,11 @@ class Scheduler:
                        else self._dispatch_chunk(pipeline_empty=False,
                                                  inflight=pending[0]))
                 self._consume_chunk(*pending)
+                if self._pipelined_commit and self._inflight:
+                    # second half: the consumed chunk's logits are there and
+                    # the sampling queued behind it has run; the successor
+                    # (dispatched with the joiner inactive) is on the device
+                    self._commit_ready_inflight(sampled_only=True)
                 pending = nxt
                 continue
             t_boundary = time.monotonic()

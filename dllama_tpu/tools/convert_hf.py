@@ -5,6 +5,12 @@ Analog of the reference converter (converter/convert-hf.py): reads
 a time), applies the Q/K rope permutation, and streams tensors to disk in the
 fixed `.m` plan order (llm.cpp:453-468).
 
+Checkpoints: `LlamaForCausalLM` / Mistral / Mixtral (the `.m` LLAMA arch)
+and `GraniteMoeHybridForCausalLM` (Mamba-2 mixers `mamba.in_proj` / `conv1d`
+/ `dt_bias` / `A_log` / `D` / `norm` / `out_proj` beside attention layers,
+`shared_mlp.input_linear` / `output_linear`, the four scalars and
+`layer_types` into the header: `converter_core.HYBRID_NAME_MAP`).
+
 Usage:
     python -m dllama_tpu.tools.convert_hf <model_dir> <weight_type> [--output out.m] [--max-seq-len N]
 """

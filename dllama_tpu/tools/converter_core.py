@@ -13,7 +13,13 @@ from typing import Mapping
 
 import numpy as np
 
-from dllama_tpu.models.config import ArchType, HiddenAct, LlamaConfig, RopeType
+from dllama_tpu.models.config import (
+    ArchType,
+    HiddenAct,
+    LayerKind,
+    LlamaConfig,
+    RopeType,
+)
 from dllama_tpu.ops.quant import FloatType, parse_float_type
 
 
@@ -34,6 +40,8 @@ def permute_rope(w: np.ndarray, n_heads: int) -> np.ndarray:
 
 def hf_config_to_llama(config: Mapping, weight_type: FloatType) -> LlamaConfig:
     """HF config.json -> LlamaConfig (mirrors convert-hf.py:152-195)."""
+    if config["model_type"] == "granitemoehybrid":
+        return _hybrid_ssm_config(config, weight_type)
     arch = {
         "llama": ArchType.LLAMA,
         "mistral": ArchType.LLAMA,
@@ -77,6 +85,49 @@ def hf_config_to_llama(config: Mapping, weight_type: FloatType) -> LlamaConfig:
     return LlamaConfig(**kwargs)
 
 
+def _hybrid_ssm_config(config: Mapping, weight_type: FloatType) -> LlamaConfig:
+    """A `GraniteMoeHybridForCausalLM` config.json (Mamba-2 mixers beside
+    attention layers, one shared SwiGLU MLP after each; source of the key
+    names: huggingface.co/ibm-granite/granite-4.0-h-micro config.json) ->
+    an ArchType.HYBRID_SSM header. What the program does not run is refused
+    by mechanism: routed experts, rotated positions, projection biases, an
+    untied head."""
+    refused = {
+        "routed experts (num_local_experts > 0)": config.get("num_local_experts"),
+        "rotary positions (position_embedding_type != nope)":
+            config.get("position_embedding_type") != "nope",
+        "projection or attention biases":
+            config.get("mamba_proj_bias") or config.get("attention_bias"),
+        "an untied output head": not config.get("tie_word_embeddings"),
+        "a conv without bias": not config.get("mamba_conv_bias", True),
+    }
+    for what, present in refused.items():
+        if present:
+            raise ValueError(f"unsupported in a hybrid state-space model: {what}")
+    if config["hidden_act"] != "silu":
+        raise ValueError(f"unsupported hidden act: {config['hidden_act']}")
+    kinds = {"mamba": LayerKind.SSM, "attention": LayerKind.ATTENTION}
+    dim, heads = config["hidden_size"], config["num_attention_heads"]
+    return LlamaConfig(
+        arch=ArchType.HYBRID_SSM, hidden_act=HiddenAct.SILU, dim=dim,
+        hidden_dim=config["shared_intermediate_size"],
+        n_layers=config["num_hidden_layers"], n_heads=heads,
+        n_kv_heads=config["num_key_value_heads"], weight_type=weight_type,
+        seq_len=config["max_position_embeddings"],
+        vocab_size=config["vocab_size"],
+        norm_epsilon=float(config.get("rms_norm_eps", 1e-5)),
+        rope_type=RopeType.NONE, head_dim=dim // heads,
+        attn_scale=float(config["attention_multiplier"]),
+        embedding_multiplier=float(config["embedding_multiplier"]),
+        residual_multiplier=float(config["residual_multiplier"]),
+        logits_scaling=float(config["logits_scaling"]), tied_head=True,
+        layer_kinds=tuple(kinds[k] for k in config["layer_types"]),
+        ssm_heads=config["mamba_n_heads"], ssm_head_dim=config["mamba_d_head"],
+        ssm_state=config["mamba_d_state"], ssm_groups=config["mamba_n_groups"],
+        ssm_conv=config["mamba_d_conv"], ssm_chunk=config["mamba_chunk_size"],
+    )
+
+
 # `.m` plan name -> HF tensor name template (convert-hf.py:51-89 order)
 HF_NAME_MAP = {
     "embedding": "model.embed_tokens.weight",
@@ -99,6 +150,22 @@ HF_NAME_MAP = {
     "moe_w3": "model.layers.{l}.block_sparse_moe.experts.{e}.w3.weight",
 }
 
+# ArchType.HYBRID_SSM (GraniteMoeHybridForCausalLM): the Mamba-2 mixer's
+# tensors, and the shared MLP whose input_linear holds gate | up stacked
+HYBRID_NAME_MAP = {
+    "in_proj": "model.layers.{l}.mamba.in_proj.weight",
+    "conv_w": "model.layers.{l}.mamba.conv1d.weight",  # [C, 1, K] -> [C, K]
+    "conv_b": "model.layers.{l}.mamba.conv1d.bias",
+    "dt_bias": "model.layers.{l}.mamba.dt_bias",
+    "a_log": "model.layers.{l}.mamba.A_log",
+    "d": "model.layers.{l}.mamba.D",
+    "ssm_norm": "model.layers.{l}.mamba.norm.weight",
+    "out_proj": "model.layers.{l}.mamba.out_proj.weight",
+    "w1": "model.layers.{l}.shared_mlp.input_linear.weight",  # rows [:hidden]
+    "w3": "model.layers.{l}.shared_mlp.input_linear.weight",  # rows [hidden:]
+    "w2": "model.layers.{l}.shared_mlp.output_linear.weight",
+}
+
 
 def hf_tensor_for(name: str, cfg: LlamaConfig, get) -> np.ndarray:
     """Fetch + transform the HF tensor for a `.m` plan entry.
@@ -117,6 +184,17 @@ def hf_tensor_for(name: str, cfg: LlamaConfig, get) -> np.ndarray:
                 ],
                 axis=0,
             )
+        if cfg.arch == ArchType.HYBRID_SSM:
+            if short in HYBRID_NAME_MAP:
+                x = get(HYBRID_NAME_MAP[short].format(l=layer))
+                if short == "conv_w":
+                    return x.reshape(x.shape[0], x.shape[-1])
+                if short in ("w1", "w3"):
+                    h = cfg.hidden_dim
+                    return x[:h] if short == "w1" else x[h:]
+                return x
+            # attention without rotation: q and k rows stay as published
+            return get(HF_NAME_MAP[short].format(l=layer))
         hf_name = HF_NAME_MAP[short].format(l=layer)
         x = get(hf_name)
         if short == "wq":

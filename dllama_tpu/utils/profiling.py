@@ -312,6 +312,12 @@ def cache_nbytes(cache) -> int:
             + cache.v.size * cache.v.dtype.itemsize)
 
 
+def state_nbytes(cache) -> int:
+    """Bytes of the recurrent state beside the KV cache (0: none)."""
+    state = getattr(cache, "state", None)
+    return 0 if state is None else state.nbytes
+
+
 def set_memory_gauges(params, cache) -> tuple[int, int]:
     """Publish the HBM accounting as startup gauges (model_params_bytes /
     kv_cache_bytes) so it is queryable at /metrics and in the /health ready

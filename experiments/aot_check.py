@@ -414,21 +414,23 @@ def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
     be = BatchEngine(cfg, params, n_slots=slots, max_seq_len=SEQ,
                      kv_layout="paged", page_size=page, kv_pages=2,
                      spec=spec)
-    assert be.kernel_route == "pallas/paged_kernel", be.kernel_route
+    assert be.kernel_route == ("pallas/paged_kernel" + (
+        "+ssm_step.float32" if cfg.recurrent else "")), be.kernel_route
     nb = SEQ // page
-    pool = A((cfg.n_layers, (kv_pages or slots * nb) + 1, cfg.n_kv_heads,
+    pool = A((cfg.n_attn_layers, (kv_pages or slots * nb) + 1, cfg.n_kv_heads,
               page, be.cache.k.shape[-1]), jnp.bfloat16)
-    cache = PagedKVCache(pool, pool, i32(slots, nb))
+    cache = PagedKVCache(pool, pool, i32(slots, nb), place(be.cache.state))
     rope = place(jax.eval_shape(lambda: build_rope_cache(cfg, SEQ)))
     vecs = (i32(slots), A((slots,), jnp.bool_), A((slots, 2), jnp.uint32),
             f32(slots), f32(slots))  # pos, active, keys, temps, topp
     dec = lambda n: (params, cache, i32(slots, 1), *vecs, n, rope, i32(slots))
     out = [(f"{tag} paged decode chunk n=4",
             lambda: be._decode.lower(*dec(4)).compile(), True)]
-    if spec:
+    if spec or cfg.recurrent:
         out += [(f"{tag} hybrid step p={p} n=4", lambda p=p: be._hybrid.lower(
             params, cache, i32(1, p), i32(), i32(), i32(slots, 1),
             *vecs, 4, rope, i32(slots)).compile(), True) for p in hybrid_p]
+    if spec:
         out += [
             (f"{tag} paged prefill chunk m=256", lambda: be._prefill_slot.lower(
                 params, cache, i32(1, 256), i32(), i32(), rope).compile(), True),
@@ -497,6 +499,68 @@ def serving_cases(topo):
     return out
 
 
+#: the hybrid state-space / attention stack at the published widths of the
+#: benchmark's configuration (benchmark/configs/granite-4.0-h-micro.json):
+#: 40 layers of period `m m m m m a m m m m`, Mamba-2 64 x 64 x 128, GQA 32/8
+#: heads of 64, MLP 8192, a 100,352-row head; 48 slots over 456 pages
+HYBRID_SLOTS, HYBRID_PAGES = 48, 456
+
+
+def hybrid_cfg(n_layers: int = 40):
+    from dllama_tpu.models.config import ArchType, LlamaConfig, RopeType
+
+    period = (1, 1, 1, 1, 1, 0, 1, 1, 1, 1)
+    return LlamaConfig(
+        dim=2048, hidden_dim=8192, n_layers=n_layers, n_heads=32, n_kv_heads=8,
+        vocab_size=100352, seq_len=SEQ, arch=ArchType.HYBRID_SSM,
+        rope_type=RopeType.NONE, attn_scale=0.015625, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=8.0, tied_head=True,
+        layer_kinds=period * (n_layers // 10), ssm_heads=64, ssm_head_dim=64,
+        ssm_state=128, ssm_conv=4, ssm_chunk=256)
+
+
+def hybrid_params(cfg, A):
+    """Abstract params of a HYBRID_SSM model as models/formats.load_params
+    stacks them: per kind, in_proj padded to whole 128-lane tiles."""
+    def qw(lead, k, n):
+        return QTensor(A((*lead, k // 2, n), jnp.uint8),
+                       A((*lead, k // Q_BLOCK, n), jnp.float16))
+
+    f32 = lambda *shape: A(shape, jnp.float32)
+    L, La, Ls = cfg.n_layers, cfg.n_attn_layers, cfg.n_ssm_layers
+    d, h, inner, cd = cfg.dim, cfg.hidden_dim, cfg.ssm_inner, cfg.ssm_conv_dim
+    return {
+        "embedding": A((cfg.vocab_size, d), jnp.bfloat16),
+        "final_norm": f32(d), "wcls": qw((), d, cfg.vocab_size),
+        "layers": {
+            "wq": qw((La,), d, d), "wk": qw((La,), d, cfg.kv_dim),
+            "wv": qw((La,), d, cfg.kv_dim), "wo": qw((La,), d, d),
+            "in_proj": qw((Ls,), d, -(-cfg.ssm_in_proj // 128) * 128),
+            "conv_w": f32(Ls, cd, cfg.ssm_conv), "conv_b": f32(Ls, cd),
+            "dt_bias": f32(Ls, cfg.ssm_heads), "a_log": f32(Ls, cfg.ssm_heads),
+            "d": f32(Ls, cfg.ssm_heads), "ssm_norm": f32(Ls, inner),
+            "out_proj": qw((Ls,), inner, d),
+            "w1": qw((L,), d, h), "w2": qw((L,), h, d), "w3": qw((L,), d, h),
+            "rms_att": f32(L, d), "rms_ffn": f32(L, d),
+        },
+    }
+
+
+def hybrid_cases(topo, slots: int = HYBRID_SLOTS, pages: int = HYBRID_PAGES):
+    """The step programs of `serve --slots 48 --kv-pages 456` on the hybrid
+    state-space model at its published widths: decode chunk and hybrid
+    step (tests/test_chip_compile.py holds them, at fewer slots, to no
+    state-stack-sized copy). Kept out of all_cases(): building the engine
+    allocates the slots' real state on the host (3.7 GB at 48)."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = hybrid_cfg()
+    params = hybrid_params(cfg, lambda shape, dt: S(shape, dt, sharding=one))
+    return engine_programs(topo, f"serve hybrid-ssm {slots}-slot", cfg, params,
+                           slots, 0, kv_pages=pages)
+
+
 def all_cases(topo, full: bool = False):
     """Every case as (name, thunk, production): thunk() compiles for the
     described chip and raises what the chip's compiler would raise."""
@@ -532,7 +596,8 @@ def main():
 
     mmod.device_platform = lambda: "tpu"  # see module docstring
     rows, prod_reject = [], []
-    for cname, thunk, production in all_cases(topology(), full):
+    topo = topology()
+    for cname, thunk, production in all_cases(topo, full) + hybrid_cases(topo):
         t0 = time.time()
         try:
             thunk()

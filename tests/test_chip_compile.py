@@ -105,3 +105,40 @@ def test_step_program_moves_no_layer_of_the_pool(thunks, name):
     assert not moved, moved
     pool_bytes = aot_check.N_LAYERS * layer_bytes
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2
+
+
+# ------------------------------------------- the hybrid state-space model
+
+
+@pytest.fixture(scope="module")
+def hybrid_thunks(thunks):
+    """The hybrid state-space / attention model's step programs at its
+    published widths, 40 layers (aot_check.hybrid_cases), at 8 slots so the
+    engine built on the host holds 0.6 GB of state and not 3.7. Depends on
+    `thunks` for the platform steer and the cache settings."""
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(aot_check.TARGET, platform="tpu")
+    return {name.split("-slot ")[1]: thunk
+            for name, thunk, _ in aot_check.hybrid_cases(topo, slots=8, pages=80)}
+
+
+@pytest.mark.parametrize("name", ["paged decode chunk n=4",
+                                  "hybrid step p=64 n=4"])
+def test_hybrid_ssm_step_program_moves_no_layer_of_the_state(hybrid_thunks, name):
+    """The recurrent state [36 layers, slots, 64, 64, 128] f32 rides the
+    period scan and the step scan as a carry and `_ssm_step` indexes the
+    layer in the stack (input/output aliased): the compiled decode and
+    hybrid programs hold no instruction that writes a buffer the size of
+    one layer's state over the slots (16.8 MB at 8 slots) or more, in a
+    loop body or out of one, other than the kernel's in-place update; a
+    prefill slice cuts its ONE slot's 2 MB a layer. The temp is not a
+    second state."""
+    from experiments import pool_copies
+
+    compiled = hybrid_thunks[name]()
+    layer_state = 8 * 64 * 64 * 128 * 4
+    moved = pool_copies.big_movers(compiled.as_text(), layer_state)
+    assert not moved, moved
+    assert "_ssm_step" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 36 * layer_state // 2

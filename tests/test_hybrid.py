@@ -466,6 +466,49 @@ def test_hybrid_ledger_state_and_summary():
         sched.shutdown()
 
 
+def test_commit_is_pipelined_behind_the_successor():
+    """A joiner's first token is sampled behind the chunk that carried its
+    last prompt rows, a successor is dispatched, and only then does the
+    commit read the token: the one host read of an admission never finds
+    the pipeline empty (the drain that put a second mode into the launch
+    intervals, PERF.md section 6, PR 29)."""
+    sched = _sched("paged")
+    eng, events = sched.engine, []
+
+    def spy(name, key=lambda a: None):
+        real = getattr(eng, name)
+
+        def wrapped(*a, **kw):
+            events.append((name, key(a)))
+            return real(*a, **kw)
+        setattr(eng, name, wrapped)
+
+    names = ("add_sample", "add_commit", "hybrid_dispatch", "decode_dispatch")
+    try:
+        spy("add_sample", lambda a: a[0].slot)
+        spy("add_commit", lambda a: a[0].slot)
+        spy("hybrid_dispatch")
+        spy("decode_dispatch")
+        assert sched._pipelined_commit
+        _mixed_workload(sched)
+    finally:
+        for name in names:
+            delattr(eng, name)  # the class's own methods again
+        sched.shutdown()
+    commits = [i for i, (n, _) in enumerate(events) if n == "add_commit"]
+    assert len(commits) == 3
+    pipelined = 0
+    for i in commits:
+        slot = events[i][1]
+        if events[i + 1:i + 2] == [("add_sample", slot)]:
+            continue  # no decoders to protect: the boundary path samples
+            # inside its commit
+        j = max(k for k in range(i) if events[k] == ("add_sample", slot))
+        assert any(n.endswith("_dispatch") for n, _ in events[j + 1:i]), events
+        pipelined += 1
+    assert pipelined >= 1, events
+
+
 def test_api_priority_tenant_parsing():
     """Body-field validation: ints 0..2 and low/normal/high names for
     `priority`, bounded strings for `tenant`; malformed values are clean

@@ -386,6 +386,17 @@ def test_deferred_request_survives_restart():
             assert _t.monotonic() < deadline, "admission never deferred"
             _t.sleep(0.002)
         faults.install("scheduler.loop", "raise", times=1)
+        # the plan is process-wide: another scheduler alive in this worker
+        # (a fixture server's idle loop from an earlier file) can consume
+        # the activation (faults.pending's docstring); re-arm until OUR
+        # worker has restarted
+        while sched.health()["restarts"] == 0:
+            assert _t.monotonic() < deadline, "the worker never crashed"
+            if not faults.pending("scheduler.loop"):
+                _t.sleep(0.05)  # ours may be mid-restart, not yet counted
+                if sched.health()["restarts"] == 0:
+                    faults.install("scheduler.loop", "raise", times=1)
+            _t.sleep(0.002)
         out1 = list(it1)
         out2 = list(r2.tokens())
         assert r1.finish_reason == "length" and len(out1) + 1 == 8
