@@ -189,7 +189,9 @@ def resolve_kernels(
         # fallback (ops.layers.paged_gqa_attention), valid everywhere but
         # re-materializing the whole paged view through XLA each step; the
         # general flash-decode kernel (scalar-prefetched block tables,
-        # double-buffered page DMA, fused KV scatter) routes on an explicit
+        # pages DMA'd as whole blocks of kv heads through a ring that does
+        # not drain between grid steps, the new KV rows blended into the
+        # sweep's own copy of their page) routes on an explicit
         # CAPABILITY check — dtype/head-dim/page-geometry, ANY page size —
         # not the old whole-64-row-tile gate.
         from dllama_tpu.ops.pallas.paged_attention import (

@@ -249,7 +249,7 @@ def supported(q_shape: tuple[int, ...], cache_seq_len: int) -> bool:
 # 64-row kv tiles (`paged_supported`), which is why the serving tier no
 # longer routes it — engine/kernel_select resolves the paged layout to the
 # GENERAL any-page-size kernel in ops/pallas/paged_attention.py (manual
-# double-buffered page DMA + fused KV scatter). Kept as the pipelined
+# ring of head-block page DMAs + fused KV scatter). Kept as the pipelined
 # reference/A/B variant for tileable pages; tests/test_paged_kv.py still
 # pins it against the jnp gather.
 

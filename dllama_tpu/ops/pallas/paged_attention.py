@@ -14,18 +14,44 @@ SNIPPETS.md [1]/[2]'s `pltpu.PrefetchScalarGridSpec` scalar-prefetch idiom):
 * the page pools stay in HBM (``memory_space=ANY``) — the kernel, not the
   BlockSpec machinery, owns their movement;
 * ``(pos, tables)`` ride as scalar-prefetch arguments, so the kernel walks
-  each slot's block table and issues double-buffered ``make_async_copy``
-  DMAs of one PAGE at a time into VMEM (page i+1's copy is in flight while
-  page i is in the MXU) — any page size, the partial last page masked by
-  the same absolute-position causal mask the dense kernel uses;
-* the grid is one step per (slot, kv_head, q_tile); the page run is a
-  dynamic ``fori_loop`` bounded by the slot's LIVE page count (``pos``-
-  derived), so decode cost scales with the live context exactly like the
-  dense kernel's tile pruning;
+  each slot's block table and issues ``make_async_copy`` DMAs of one PAGE of
+  a BLOCK of kv heads at a time into a ring of VMEM landing buffers — any
+  page size, the partial last page masked by the same absolute-position
+  causal mask the dense kernel uses. The pool's layout is ``[N, Hkv, page,
+  lanes]``, so a page's ``hb`` consecutive heads are ONE contiguous run
+  (``pool.at[pg, pl.ds(h0, hb)]``: 1 MB at 32 heads x 128 rows x 128 lanes
+  of bf16), not ``hb`` separate 32 KB copies that each pay a round trip;
+* the grid is one step per (slot, block of ``hb`` kv heads, q tile); the
+  page run is a dynamic ``fori_loop`` bounded by the slot's LIVE page count
+  (``pos``-derived), so decode cost scales with the live context exactly
+  like the dense kernel's tile pruning. ``hb`` and the ring's depth come
+  from :func:`_plan`: the largest divisor of ``Hkv`` whose ring,
+  accumulator and per-page temporaries fit a VMEM budget — a function of
+  the call's shapes and dtype, no flag; ``hb = 1`` at depth 2 is the same
+  code and what `paged_decode_supported` admits. The dots are batched over
+  the head block; each head's online softmax runs page by page;
+* the ring does not drain between grid steps: while a step's last pages are
+  in the MXU the copies of the NEXT step's first pages (next q tile, head
+  block or slot: its ``pos`` and table are scalar prefetch too) are already
+  in flight, and the ring slot of a step's first page is carried in SMEM —
+  the jax-ml kernel's ``buffer_index`` idea. Every grid axis is therefore
+  ``arbitrary`` (one TensorCore on v5e: nothing is lost);
 * the new token's KV rows are scatter-written into the pool INSIDE the same
-  launch (``input_output_aliases`` keeps the pool update in place): the
-  separate `_paged_cache_update` dispatch decode used to pay per layer is
-  gone, and the attention sweep reads the row it just wrote;
+  launch (``input_output_aliases`` keeps the pool update in place) and read
+  nothing back: a row's target page is one of the sweep's own last pages,
+  so when that page's head block has landed the rows are blended into the
+  landed copy (an f32 ``where`` over the sublane tile that holds the row),
+  the sweep reads the blended copy, and ONE DMA writes the block back under
+  that page's dots. An inactive slot's rows, routed to the trash page no
+  table holds, find that page riding as one more page behind the slot's
+  sweep (masked out of the softmax): the same blend, the same one write a
+  head block. The separate `_paged_cache_update` dispatch decode used to
+  pay per layer is gone;
+* q and K enter the q.k product as the bfloat16 they are stored as where
+  both are (a product of two bfloat16 values is exact in float32, so it is
+  the same sum in one MXU pass); the scale, mask, exp, p, l, the
+  accumulator and the p.v product stay float32, as do both operands
+  whenever q or the pool is float32;
 * the pool the kernel walks is the WHOLE layer-stacked array, viewed as one
   run of L*P pages, and the layer is data: its first page is added to the
   prefetched page indices (``paged_decode_attention(layer=)``). The decoder's
@@ -36,7 +62,7 @@ SNIPPETS.md [1]/[2]'s `pltpu.PrefetchScalarGridSpec` scalar-prefetch idiom):
 
 Numerics are the same online-softmax (flash) formulation as
 ``flash_attention._kernel``: f32 accumulation, large-finite mask fill, one
-running (m, l, acc) state per q tile.
+running (m, l, acc) state per head and q tile.
 """
 
 from __future__ import annotations
@@ -54,16 +80,33 @@ from dllama_tpu.ops.pallas.tiling import pick_tile as _pick_tile
 _NEG_INF = -1e30  # large-finite: keeps fully-masked pages NaN-free
 
 #: Chunks longer than this scatter their KV rows with a single XLA scatter
-#: before the kernel launches instead of fusing per-row DMAs into it — a
-#: 256-token prefill chunk would otherwise serialize 2*T row copies per
-#: (slot, head) program. Decode (t=1) and batched spec verify (t=k+1) sit
-#: far below it and always fuse.
+#: before the kernel launches instead of blending them into the sweep's
+#: landed pages: a 256-token prefill chunk would otherwise unroll 2*T row
+#: blends into every (slot, head block) program. Decode (t=1) and batched
+#: spec verify (t=k+1) sit far below it and always fuse.
 FUSED_SCATTER_MAX_T = 16
 
-#: VMEM budget for the double-buffered page landing zones (2 pages x (k, v)
-#: live at once). Pages above it route to the gather fallback instead of
-#: risking a Mosaic VMEM overflow at compile time.
+#: The capability floor: two (k, v) pairs of ONE head's page — the ring at
+#: its shallowest (depth 2) and narrowest (one kv head a grid step) — must
+#: fit here. Pages above it route to the gather fallback instead of risking
+#: a Mosaic VMEM overflow at compile time.
 _PAGE_VMEM_BYTES = 4 * 1024 * 1024
+
+#: What :func:`_plan` sizes the head block and the ring's depth into: the
+#: landing ring, the f32 accumulator with m/l, and the sweep's per-page
+#: temporaries (scores, probabilities, the widened V). Read at call time,
+#: so a test can shrink it to force ``hb = 1``.
+_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+#: Mosaic's scoped-VMEM ceiling for this kernel (v5e: 128 MiB physical, 16
+#: MiB by default): the budget above plus the auto-pipelined q / new-row /
+#: out blocks and the compiler's own temporaries.
+_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+#: The ring is as deep as the budget allows up to this many (k, v) pairs:
+#: depth - 1 pairs are in flight while one is in the MXU, and past a few
+#: hundred KB in flight the HBM is covered; every slot of the ring costs an
+#: unrolled conditional DMA start in the prologue.
+_MAX_DEPTH = 4
+_Q_TILE_MAX = 128  # folded q rows one grid step takes (and one MXU pass)
 
 _LANES = 128  # TPU vector lane count: the minor-dim tile of every memref
 
@@ -96,8 +139,10 @@ def paged_decode_supported(q_shape: tuple[int, ...], page_size: int,
       flash path now hits in the AOT gate, a libtpu-level pre-existing
       condition, so paged matches dense f8 behavior rather than extending
       the breakage);
-    * double-buffering two (k, v) page pairs — at the pool's lane-padded
-      row width (:func:`pool_lanes`) — must fit the VMEM budget.
+    * a ring of two (k, v) pairs of one head's page — at the pool's
+      lane-padded row width (:func:`pool_lanes`) — must fit the floor
+      (:func:`_plan` falls back to exactly that: one head a grid step,
+      depth 2).
 
     Ragged tables need no capability: unallocated entries are clamped to
     the last live page by the kernel and masked by position, so any
@@ -114,126 +159,245 @@ def paged_decode_supported(q_shape: tuple[int, ...], page_size: int,
     )
 
 
+def _q_tile(rows: int) -> int:
+    """Folded q rows one grid step takes: all of them up to one MXU pass
+    (a spec-verify chunk of 9 tokens x group 4 is ONE sweep of the pages,
+    not five), else the largest power-of-two tile that divides them."""
+    return rows if rows <= _Q_TILE_MAX else _pick_tile(rows, (128, 64, 32, 16, 8))
+
+
+def _fuses(t: int, rows: int) -> bool:
+    """Whether a chunk of ``t`` new rows (``rows`` folded q rows) is
+    scattered inside the kernel: the blend needs the slot's ONE sweep of its
+    pages, so the folded rows must be one q tile, and ``t`` small enough to
+    loop over (longer chunks scatter through XLA first, identical result)."""
+    return t <= FUSED_SCATTER_MAX_T and _q_tile(rows) == rows
+
+
+def _plan(hkv: int, page: int, lanes: int, itemsize: int, tq: int, t: int,
+          budget: int) -> tuple[int, int, int]:
+    """(hb, depth, bytes): the kv heads one grid step serves, the landing
+    ring's depth in (k, v) pairs, and the VMEM that plan needs — functions
+    of the call's shapes and dtype alone.
+
+    A head costs ``depth`` (k, v) page pairs of ring, its f32 accumulator
+    row block with m and l, the f32 copies of its ``t`` new rows, and the
+    sweep's per-page temporaries. ``hb`` is the largest divisor of ``hkv``
+    whose ring of three pairs fits the budget (two in flight behind the one
+    in the MXU); the depth then grows into what is left, up to
+    ``_MAX_DEPTH``. A page too large for any of it gets ``hb = 1`` at depth
+    2, which is what `paged_decode_supported` admits."""
+    pair = 2 * page * lanes * itemsize
+    fixed = (tq * (lanes + 2 * _LANES) + 2 * t * lanes  # acc, m, l, new rows
+             + 2 * tq * page + 2 * page * lanes) * 4  # s, p, widened k / v
+    need = lambda hb, depth: hb * (depth * pair + fixed)
+    hb = max((d for d in range(1, hkv + 1)
+              if hkv % d == 0 and need(d, 3) <= budget),
+             default=1)
+    depth = max((d for d in range(2, _MAX_DEPTH + 1) if need(hb, d) <= budget),
+                default=2)
+    return hb, depth, need(hb, depth)
+
+
 def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
-            q_ref, newk_ref, newv_ref,  # VMEM blocks
+            q_ref, newk_ref, newv_ref,  # VMEM blocks [hb, tq | t, lanes]
             kpool_in, vpool_in,  # HBM (ANY) — aliased to outputs
             out_ref, kpool_ref, vpool_ref,  # out block + aliased pools
-            kbuf, vbuf, acc_ref, m_ref, l_ref, copy_sems, write_sem,
-            *, scale, page, group, t, tq, rows_live, nb, fused):
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    iq = pl.program_id(2)
+            kbuf, vbuf, newk32, newv32, acc_ref, m_ref, l_ref, base_ref,
+            copy_sems, write_sems,
+            *, scale, page, group, t, tq, rows_live, nb, fused, hb, depth,
+            mxu_dtype):
+    b, hblk, iq = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nbatch, nhb, nq = pl.num_programs(0), pl.num_programs(1), pl.num_programs(2)
+    lanes = kbuf.shape[-1]
 
-    # ---- fused KV scatter: the new token rows land in the pool before this
-    # (slot, head)'s sweep starts. Mosaic cannot DMA a dynamically-offset
-    # single sublane row, so each write is a whole-page read-modify-write:
-    # DMA the target page into the (not-yet-used) double buffer, blend the
-    # row at its offset (f32 blend — sub-32-bit sublane broadcasts don't
-    # lower; bf16<->f32 round-trips exactly), DMA the page back. One page
-    # round-trip per row per pool — trivial against the decode sweep, and
-    # t is capped at FUSED_SCATTER_MAX_T (prefill pre-scatters via XLA).
-    # Only the first q tile of each (slot, head) writes; rows are blended
-    # in order, so a duplicate (page, offset) target — only possible for
-    # trash-page collisions when t > page_size — resolves last-row-wins.
-    if fused:
-        @pl.when(iq == 0)
-        def _():
-            for tt in range(t):  # static unroll: t is a trace-time int
-                pg = wpages_ref[b, tt]
-                off = woffs_ref[b, tt]
-                sel = jax.lax.broadcasted_iota(
-                    jnp.int32, (page, newk_ref.shape[-1]), 0) == off
-                for src, pool, buf in ((newk_ref, kpool_ref, kbuf),
-                                       (newv_ref, vpool_ref, vbuf)):
-                    cp = pltpu.make_async_copy(
-                        pool.at[pg, h], buf.at[0], write_sem)
-                    cp.start()
-                    cp.wait()
-                    row = src[tt].astype(jnp.float32)[None, :]
-                    buf[0] = jnp.where(
-                        sel, jnp.broadcast_to(row, sel.shape),
-                        buf[0].astype(jnp.float32)).astype(buf.dtype)
-                    cp = pltpu.make_async_copy(
-                        buf.at[0], pool.at[pg, h], write_sem)
-                    cp.start()
-                    cp.wait()
+    def sweep_pages(bb, qq):
+        # live-page horizon of q tile qq of slot bb (mirrors
+        # flash_attention's kv-tile clamp: pad rows must not widen it),
+        # clamped to the table: the logical view is exactly nb*page rows
+        last_row = jnp.minimum(qq * tq + tq - 1, rows_live - 1)
+        return jnp.minimum((pos_ref[bb] + last_row // group) // page + 1, nb)
 
-    # ---- live-page horizon for this q tile (mirrors flash_attention's
-    # kv-tile clamp: pad rows must not widen it)
+    # ---- fused KV scatter, addressing. A live slot's rows pos .. pos+t-1
+    # land in table pages blk(0) .. blk(t-1), the LAST pages of its sweep
+    # (the sweep covers pos+t-1; a block past the table is clipped to the
+    # last entry, which the sweep ends on too): they are blended into the
+    # sweep's own landed copy of the page and read nothing back. An
+    # inactive slot's rows were routed to the trash page, which no table
+    # holds: that page rides as ONE more page behind the slot's sweep,
+    # receives the rows the same way, and is masked out of the softmax.
     pos_b = pos_ref[b]
-    last_row = jnp.minimum(iq * tq + tq - 1, rows_live - 1)
-    qpos_max = pos_b + last_row // group
-    # clamp to the table capacity: the logical view is exactly nb*page rows
-    # (a horizon past it reads nothing, same as the gather reference's view)
-    npages = jnp.minimum(qpos_max // page + 1, nb)
+    blk = lambda tt: jnp.minimum((pos_b + tt) // page, nb - 1)
+    n_sweep = sweep_pages(b, iq)
+    if fused:
+        live = wpages_ref[b, 0] == tables_ref[b, blk(0)]
+        n = n_sweep + jnp.where(live, 0, 1)
+        # the block a row lands in, as an index of this step's page run
+        target = lambda tt: jnp.where(live, blk(tt), n_sweep)
+        # Mosaic cannot DMA a dynamically-offset single sublane row, so a
+        # row is blended in VMEM: an f32 `where` (sub-32-bit sublane
+        # broadcasts don't lower; bf16<->f32 round-trips exactly) over the
+        # one sublane tile that holds the row, or over the whole page where
+        # a page is not whole tiles of its dtype
+        win = 32 // jnp.dtype(kbuf.dtype).itemsize
+        win = page if page % win else win
+    else:
+        n = n_sweep
 
-    q = q_ref[...].astype(jnp.float32)  # [tq, hd]
+    # ---- the page run this step consumes, and the successor's: the ring
+    # does not drain between grid steps. Run index v < n is this step's
+    # page v; n <= v < n + n2 is sweep page v - n of the NEXT grid step
+    # (next q tile, head block or slot: its pos and block table are scalar
+    # prefetch, so they are known; never its trash page, which this step
+    # may still be writing). Run page v lands in ring slot
+    # (base + v) % depth; `base` is carried across steps in SMEM.
+    first = (b == 0) & (hblk == 0) & (iq == 0)
+
+    @pl.when(first)
+    def _():
+        base_ref[0] = 0
+
+    base = base_ref[0]
+    wrap_q = iq == nq - 1
+    wrap_h = wrap_q & (hblk == nhb - 1)
+    iq2 = jnp.where(wrap_q, 0, iq + 1)
+    hblk2 = jnp.where(wrap_h, 0, jnp.where(wrap_q, hblk + 1, hblk))
+    b2 = jnp.minimum(jnp.where(wrap_h, b + 1, b), nbatch - 1)
+    n2 = jnp.where(wrap_h & (b == nbatch - 1), 0, sweep_pages(b2, iq2))
+
+    def page_id(bb, i, own):
+        # defensive clamp like _paged_cache_update: a horizon past the
+        # allocated table reads the last entry (its rows are masked anyway)
+        pg = tables_ref[bb, jnp.minimum(i, nb - 1)]
+        if fused:  # only this step's own run reaches past its sweep
+            pg = jnp.where(own & (i >= n_sweep), wpages_ref[b, 0], pg)
+        return pg
+
+    def copies(pg, hh, slot, back=False):
+        heads = pl.ds(hh * hb, hb)  # [hb, page, lanes]: contiguous in HBM
+        pairs = ((kpool_ref, kbuf), (vpool_ref, vbuf))
+        if back:
+            return [pltpu.make_async_copy(buf.at[slot], pool.at[pg, heads],
+                                          write_sems.at[j])
+                    for j, (pool, buf) in enumerate(pairs)]
+        return [pltpu.make_async_copy(pool.at[pg, heads], buf.at[slot],
+                                      copy_sems.at[slot, j])
+                for j, (pool, buf) in enumerate(pairs)]
+
+    def start(v):
+        mine = v < n
+
+        @pl.when(v < n + n2)
+        def _():
+            pg = page_id(jnp.where(mine, b, b2), jnp.where(mine, v, v - n), mine)
+            for cp in copies(pg, jnp.where(mine, hblk, hblk2),
+                             jax.lax.rem(base + v, depth)):
+                cp.start()
+
+    def prologue(v, _):  # what no predecessor started for this step
+        @pl.when(first | (v >= n_sweep))
+        def _():
+            start(v)
+        return 0
+
+    jax.lax.fori_loop(0, depth - 1, prologue, 0)
+
+    mxu = lambda x: x if x.dtype == mxu_dtype else x.astype(mxu_dtype)
+    q = mxu(q_ref[...])  # [hb, tq, lanes]
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
-
-    def start_copy(i, slot):
-        # defensive clamp like _paged_cache_update: a horizon past the
-        # allocated table reads the last entry (its rows are masked anyway)
-        pg = tables_ref[b, jnp.minimum(i, nb - 1)]
-        ck = pltpu.make_async_copy(
-            kpool_ref.at[pg, h], kbuf.at[slot], copy_sems.at[slot, 0])
-        cv = pltpu.make_async_copy(
-            vpool_ref.at[pg, h], vbuf.at[slot], copy_sems.at[slot, 1])
-        return ck, cv
-
-    ck0, cv0 = start_copy(0, 0)
-    ck0.start()
-    cv0.start()
+    if fused:
+        # f32 copies of the new rows: the blend below takes one row at a
+        # dynamic sublane offset, which Mosaic serves for 32-bit rows only
+        newk32[...] = newk_ref[...].astype(jnp.float32)
+        newv32[...] = newv_ref[...].astype(jnp.float32)
+    # causal mask against absolute cache positions: query row r of tile iq
+    # holds token offset (iq*tq + r) // group (t-major GQA fold)
+    qpos = pos_b + (iq * tq + jax.lax.broadcasted_iota(
+        jnp.int32, (tq, page), 0)) // group
+    col = jax.lax.broadcasted_iota(jnp.int32, (tq, page), 1)
 
     def body(i, _):
-        slot = jax.lax.rem(i, 2)
+        slot = jax.lax.rem(base + i, depth)
+        start(i + depth - 1)  # into the slot page i-1 just left
+        here = page_id(b, i, True)
+        for cp in copies(here, hblk, slot):
+            cp.wait()
 
-        @pl.when(i + 1 < npages)
-        def _():
-            ck, cv = start_copy(i + 1, jax.lax.rem(i + 1, 2))
-            ck.start()
-            cv.start()
+        if fused:
+            # rows are blended in order, so a duplicate (page, offset)
+            # target — a clipped table, or trash collisions when
+            # t > page_size — resolves last-row-wins
+            wrote = i >= target(0)
+            backs = copies(here, hblk, slot, back=True)
 
-        ck, cv = start_copy(i, slot)
-        ck.wait()
-        cv.wait()
-        k = kbuf[slot].astype(jnp.float32)  # [page, hd]
-        v = vbuf[slot].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+            def blend(tt, _):
+                @pl.when(target(tt) == i)
+                def _():
+                    off = woffs_ref[b, tt]
+                    r0 = pl.multiple_of(off // win * win, win)
+                    window = (slot, slice(None), pl.ds(r0, win), slice(None))
+                    sel = jax.lax.broadcasted_iota(
+                        jnp.int32, (win, lanes), 0) == off - r0
+                    for rows, buf in ((newk32, kbuf), (newv32, vbuf)):
+                        buf[window] = jnp.where(
+                            sel[None], rows[:, pl.ds(tt, 1), :],
+                            buf[window].astype(jnp.float32)).astype(buf.dtype)
+                return 0
+
+            @pl.when(wrote)
+            def _():
+                jax.lax.fori_loop(0, t, blend, 0)
+                for wr in backs:  # ONE write a pool, under this page's dots
+                    wr.start()
+
+        k = kbuf[slot]  # [hb, page, lanes]
+        v = vbuf[slot]
+        # q and K enter the MXU as the bfloat16 they are stored as where
+        # both are (a product of two bfloat16 values is exact in float32);
+        # a float32 q or pool keeps float32 operands
+        s = jax.lax.dot_general(q, mxu(k), (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32)
-        s = s * scale  # [tq, page]
-
-        # causal mask against absolute cache positions: query row r of tile
-        # iq holds token offset (iq*tq + r) // group (t-major GQA fold)
-        row = jax.lax.broadcasted_iota(jnp.int32, (tq, page), 0)
-        qpos = pos_b + (iq * tq + row) // group
-        span = i * page + jax.lax.broadcasted_iota(jnp.int32, (tq, page), 1)
-        mask = span <= qpos
+        s = s * scale  # [hb, tq, page]
+        mask = i * page + col <= qpos
+        if fused:  # the trash page behind an inactive slot's sweep
+            mask = mask & (i < n_sweep)
+        mask = mask[None]
         s = jnp.where(mask, s, _NEG_INF)
 
-        m_prev = m_ref[...][:, :1]
-        l_prev = l_ref[...][:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_prev = m_ref[...][:, :, :1]
+        l_prev = l_ref[...][:, :, :1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
         p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
-        l_cur = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
+        l_cur = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_cur, l_ref.shape)
+
+        if fused:
+            @pl.when(wrote)  # before the ring hands this slot out again
+            def _():
+                for wr in backs:
+                    wr.wait()
         return 0
 
-    jax.lax.fori_loop(0, npages, body, 0)
-    l = l_ref[...][:, :1]
+    jax.lax.fori_loop(0, n, body, 0)
+    l = l_ref[...][:, :, :1]
     out_ref[...] = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+    base_ref[0] = jax.lax.rem(base + n, depth)
 
 
 @functools.partial(jax.jit, static_argnames=("group", "interpret",
-                                             "rows_live", "fused", "scale"))
+                                             "rows_live", "fused", "scale",
+                                             "vmem_budget"))
 def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
                   new_v, *, group: int, interpret: bool, rows_live: int,
-                  fused: bool, scale: float):
+                  fused: bool, scale: float,
+                  vmem_budget: int = _VMEM_BUDGET_BYTES):
     """qf[B, Hkv, rows_pad, hd] x pool[N, Hkv, page, hd] ->
     (out f32 [B, Hkv, rows_pad, hd], k_pool, v_pool).
 
@@ -248,39 +412,51 @@ def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
     npool, _, page, _ = k_pool.shape
     nb = tables.shape[1]
     t = new_k.shape[2]
-    tq = _pick_tile(rows, (128, 64, 32, 16, 8))
-    grid = (b, hkv, rows // tq)
+    tq = _q_tile(rows)
+    assert not fused or _fuses(t, rows), (t, group, rows)
+    hb, depth, _ = _plan(hkv, page, hd, k_pool.dtype.itemsize, tq, t,
+                         vmem_budget)
+    grid = (b, hkv // hb, rows // tq)
+    bf16 = jnp.dtype(jnp.bfloat16)
+    mxu_dtype = bf16 if qf.dtype == bf16 == k_pool.dtype else jnp.dtype(
+        jnp.float32)
     any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    by_q = lambda b, h, iq, *_: (b, h, iq, 0)
+    by_head = lambda b, h, iq, *_: (b, h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # pos[B], tables[B, nb], wpages/woffs[B, t]
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, None, tq, hd), lambda b, h, iq, *_: (b, h, iq, 0)),
-            pl.BlockSpec((None, None, t, hd), lambda b, h, iq, *_: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, t, hd), lambda b, h, iq, *_: (b, h, 0, 0)),
+            pl.BlockSpec((None, hb, tq, hd), by_q),
+            pl.BlockSpec((None, hb, t, hd), by_head),
+            pl.BlockSpec((None, hb, t, hd), by_head),
             any_spec,  # k pool (HBM)
             any_spec,  # v pool (HBM)
         ],
         out_specs=[
-            pl.BlockSpec((None, None, tq, hd), lambda b, h, iq, *_: (b, h, iq, 0)),
+            pl.BlockSpec((None, hb, tq, hd), by_q),
             any_spec,
             any_spec,
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, page, hd), k_pool.dtype),  # double-buffered k pages
-            pltpu.VMEM((2, page, hd), v_pool.dtype),
-            pltpu.VMEM((tq, hd), jnp.float32),  # acc
-            pltpu.VMEM((tq, 128), jnp.float32),  # m
-            pltpu.VMEM((tq, 128), jnp.float32),  # l
-            pltpu.SemaphoreType.DMA((2, 2)),  # (buffer slot, k/v) copies
-            pltpu.SemaphoreType.DMA(()),  # scatter writes
+            pltpu.VMEM((depth, hb, page, hd), k_pool.dtype),  # k landing ring
+            pltpu.VMEM((depth, hb, page, hd), v_pool.dtype),
+            pltpu.VMEM((hb, t, hd), jnp.float32),  # the new k rows, widened
+            pltpu.VMEM((hb, t, hd), jnp.float32),
+            pltpu.VMEM((hb, tq, hd), jnp.float32),  # acc
+            pltpu.VMEM((hb, tq, _LANES), jnp.float32),  # m
+            pltpu.VMEM((hb, tq, _LANES), jnp.float32),  # l
+            pltpu.SMEM((1,), jnp.int32),  # ring slot of this step's page 0
+            pltpu.SemaphoreType.DMA((depth, 2)),  # (ring slot, k/v) copies
+            pltpu.SemaphoreType.DMA((2,)),  # k/v scatter write-backs
         ],
     )
     out, k_pool, v_pool = pl.pallas_call(
         functools.partial(_kernel, scale=scale, page=page,
                           group=group, t=t, tq=tq, rows_live=rows_live,
-                          nb=nb, fused=fused),
+                          nb=nb, fused=fused, hb=hb, depth=depth,
+                          mxu_dtype=mxu_dtype),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, rows, hd), jnp.float32),
@@ -291,7 +467,10 @@ def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
         # vpool=8; the pools alias outputs 1 and 2 (in-place update)
         input_output_aliases={7: 1, 8: 2},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # every axis carries the ring into the next step: one
+            # TensorCore (v5e) walks them in order, nothing is lost
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES,
         ),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * hkv * rows * nb * page * hd,
@@ -371,7 +550,7 @@ def paged_decode_attention(
     ``(out, k_pool, v_pool)`` with the pools updated in place
     (input/output aliased). Chunks longer than ``FUSED_SCATTER_MAX_T``
     scatter via XLA before the launch instead (identical result; prefill
-    chunks should not serialize per-row DMAs).
+    chunks should not unroll a blend per row into the kernel).
 
     With ``layer`` the pools are the whole layer-stacked arrays as
     ``PagedKVCache`` stores them and the call reads and writes that layer's
@@ -422,8 +601,10 @@ def paged_decode_attention(
         wpages, woffs = paged_write_targets(tables, pos, t, page, n_pool,
                                             active)
         wpages = wpages + first_page
-        if t > FUSED_SCATTER_MAX_T:
-            # prefill-sized chunk: scattered by XLA, then a read-only sweep
+        if not _fuses(t, qf.shape[2]):
+            # prefill-sized chunk (or one whose folded rows take several q
+            # tiles, so several sweeps): scattered by XLA, then a
+            # read-only sweep
             k_pool, v_pool = (
                 _scatter_rows_by_page(pool, new, tables.shape[1], pos,
                                       wpages, woffs, first_page + n_pool - 1)
@@ -443,7 +624,7 @@ def paged_decode_attention(
     out, k_pool, v_pool = _paged_folded(
         qf, k_pool, v_pool, pos, tables + first_page, wpages, woffs, nk, nv,
         group=group, interpret=interpret, rows_live=rows, fused=write,
-        scale=1.0 / math.sqrt(hd))
+        scale=1.0 / math.sqrt(hd), vmem_budget=_VMEM_BUDGET_BYTES)
     out = (
         out[:, :, :rows, :hd].reshape(b, hkv, t, group, hd)
         .transpose(0, 2, 1, 3, 4)

@@ -52,6 +52,8 @@ S = jax.ShapeDtypeStruct
 
 
 TARGET = "v5e:2x2"
+#: paged-attention case name -> (hb, depth, VMEM bytes) its shapes were given
+PAGED_PLANS: dict[str, tuple[int, int, int]] = {}
 
 
 def topology():
@@ -167,31 +169,42 @@ def cases(full: bool):
     flash("flash decode bucketed S=8192 hd=128", 1, 8192, HD_8B, s_buckets=True)
 
     # general paged flash-decode kernel (ops/pallas/paged_attention): the
-    # paged-by-default serving route — scalar-prefetched block tables,
-    # double-buffered page DMA, fused KV-row scatter (whole-page RMW). The
+    # paged-by-default serving route — scalar-prefetched block tables, one
+    # grid step per (slot, block of kv heads, q tile), pages DMA'd as whole
+    # head blocks through a ring that does not drain between grid steps, the
+    # new KV rows blended into the sweep's landed copy of their page. The
     # pool is as the engine allocates it on this route: rows pool_lanes(hd)
     # wide (Mosaic refuses to DMA-walk a 64-lane pool). Production at the
     # shipped default page size AND at the small/odd sizes the capability
     # check admits; t=K+1 is the batched spec-verify shape, t=256 exercises
     # the XLA pre-scatter prefill path of the same wrapper; "layer-indexed
     # stack" is the call as the decoder's layer scan makes it (the whole
-    # [L, P, ...] pool and the layer as data).
+    # [L, P, ...] pool and the layer as data). The last two are the decode
+    # calls of the benchmark's two cells at their own shapes (PERF.md
+    # section 4). PAGED_PLANS keeps what `_plan` gave each case.
+    from dllama_tpu.ops.pallas import paged_attention as pa
     from dllama_tpu.ops.pallas.paged_attention import paged_decode_attention, pool_lanes
 
-    def paged(name, page, t=1, b=SLOTS, hd=HD, read_only=False, stacked=False):
+    def paged(name, page, t=1, b=SLOTS, hd=HD, read_only=False, stacked=False,
+              hq=HQ, hkv=HKV, pages=None):
         nb = SEQ // page
-        pools = S(((2,) * stacked) + (b * nb + 1, HKV, page, pool_lanes(hd)),
-                  jnp.bfloat16)
-        args = [S((b, t, HQ, hd), jnp.bfloat16), pools, pools,
+        pools = S(((2,) * stacked) + ((pages or b * nb) + 1, hkv, page,
+                                      pool_lanes(hd)), jnp.bfloat16)
+        args = [S((b, t, hq, hd), jnp.bfloat16), pools, pools,
                 S((b, nb), jnp.int32), S((b,), jnp.int32)]
         if not read_only:
-            args += [S((b, HKV, t, hd), jnp.bfloat16),
-                     S((b, HKV, t, hd), jnp.bfloat16), S((b,), jnp.bool_)]
+            args += [S((b, hkv, t, hd), jnp.bfloat16),
+                     S((b, hkv, t, hd), jnp.bfloat16), S((b,), jnp.bool_)]
         fn = lambda *a: paged_decode_attention(*a, interpret=False)
         if stacked:  # the layer-stacked pool, the layer as data (PR 27)
             args.append(S((), jnp.int32))
             fn = lambda *a: paged_decode_attention(*a[:-1], layer=a[-1],
                                                    interpret=False)
+        rows = -(-t * (hq // hkv) // 8) * 8
+        fused = not read_only and pa._fuses(t, rows)  # else the kernel sees no row
+        PAGED_PLANS[name] = pa._plan(hkv, page, pool_lanes(hd), 2,
+                                     pa._q_tile(rows), t if fused else 1,
+                                     pa._VMEM_BUDGET_BYTES)
         out.append((name, fn, tuple(args), True))
 
     paged(f"paged decode t=1 p=128 hd={HD} fused scatter", 128)
@@ -206,6 +219,12 @@ def cases(full: bool):
     paged(f"paged prefill t=256 p=128 hd={HD} layer-indexed stack (XLA pre-scatter)",
           128, t=256, b=1, stacked=True)
     paged("paged spec verify t=9 p=128 hd=128 fused scatter", 128, t=9, hd=HD_8B)
+    paged("paged decode t=1 p=128 b=12 Hkv=32 hd=128 layer-indexed stack "
+          "(deepseek7b.decode_closed)", 128, b=12, hq=32, hkv=32, hd=128,
+          pages=66, stacked=True)
+    paged("paged decode t=1 p=128 b=48 Hkv=8 hd=64 layer-indexed stack "
+          "(granite4h.reason_closed)", 128, b=48, hq=32, hkv=8, hd=64,
+          pages=456, stacked=True)
 
     from dllama_tpu.ops.pallas.rms_norm import rms_norm as prms
 
@@ -627,6 +646,16 @@ def main():
         for cname, production, verdict in rows:
             f.write(f"| {cname} | {'yes' if production else ''} | "
                     f"{verdict.split(chr(10))[0][:120]} |\n")
+        f.write("\n## The paged kernel's plan for each case\n\n"
+                "`ops/pallas/paged_attention._plan`: kv heads a grid step "
+                "serves, (k, v) pairs in the landing ring, and the VMEM the "
+                "plan counts (ring + f32 accumulator, m, l + per-page "
+                "temporaries) — functions of the shapes and dtype alone.\n\n"
+                "| case | hb | depth | VMEM bytes |\n|---|---|---|---|\n")
+        for cname, (hb, depth, nbytes) in PAGED_PLANS.items():
+            f.write(f"| {cname} | {hb} | {depth} | {nbytes:,} |\n")
+            print(f"paged plan | {cname}: hb={hb} depth={depth} "
+                  f"vmem={nbytes:,} B")
     print(f"wrote {md_path}")
     print("AOT CHECK " + ("FAIL: production kernels rejected: " + str(prod_reject)
                           if prod_reject else "ALL PRODUCTION KERNELS ACCEPT"))

@@ -1,11 +1,15 @@
 """Microbench harness for Q40 matmul kernel variants on the real TPU.
 
 Usage: python experiments/kbench.py suite
+       python experiments/kbench.py paged
        python experiments/kbench.py M SHAPE [variant ...]
 'suite' benches the decode variants (m=8 on w1/wcls), the prefill tier
 comparison (m=256/512: in-kernel deq vs XLA dequant-dot), and a blockdot
 (tk, tn) tile autotune, all in one process.
-'suite --smoke' runs the same code path on CPU (interpret-mode Pallas, tiny
+'paged' times the paged flash-decode kernel alone, as a decode step of each
+benchmark cell calls it (300 calls on the layer-stacked pool, pools threaded),
+against what the HBM would take for the rows it needs.
+'suite --smoke' (and 'paged --smoke') runs the same code path on CPU (interpret-mode Pallas, tiny
 shapes, 2 iters) so CI proves the harness cannot crash on the chip; smoke
 numbers are meaningless, only completion matters.
   variants: A  production dispatch (q40_matmul auto: blockdot for m<=16, deq above)
@@ -247,6 +251,14 @@ def enable_smoke():
     ]
     SWEEP_TK = (32, 64)
     SWEEP_TN = (128,)
+    global PAGED_CELLS, PAGED_CALLS
+    PAGED_CALLS = 2
+    PAGED_CELLS = {
+        "tiny mha": dict(slots=2, hq=4, hkv=4, hd=64, page=16, layers=2,
+                         kv_pages=8, rows=(10, 40)),
+        "tiny gqa": dict(slots=3, hq=8, hkv=2, hd=64, page=8, layers=2,
+                         kv_pages=16, rows=(3, 30)),
+    }
 
 
 def sweep_blockdot_tiles(m=8, label="w1"):
@@ -368,8 +380,111 @@ def bench_flash_decode():
         sys.stdout.flush()
 
 
+#: The decode-shaped paged-attention call of each benchmark cell (PERF.md
+#: section 4): slots, heads, the pool as the engine allocates it, and the
+#: span of rows the slots stand at in a window.
+PAGED_CELLS = {
+    "deepseek7b.decode_closed": dict(
+        slots=12, hq=32, hkv=32, hd=128, page=128, layers=30, kv_pages=66,
+        rows=(150, 420)),
+    "granite4h.reason_closed": dict(
+        slots=48, hq=32, hkv=8, hd=64, page=128, layers=4, kv_pages=456,
+        rows=(100, 1000)),
+}
+PAGED_CALLS = 300
+HBM_GBS = 819.0  # TPU v5e (benchmark/peaks.json)
+
+
+def bench_paged_decode(cells=None, calls=None, rows=None):
+    """The paged flash-decode kernel alone on the chip, as a decode step
+    calls it: `paged_decode_attention` with t = 1 on the layer-stacked pool,
+    the layer cycling, the pools threaded through PAGED_CALLS calls of one
+    jitted scan. Prints ms a call beside what the HBM would take for the
+    rows the call NEEDS (`benchmark/costs/paged_attention.py`'s bytes) and
+    for the whole pages it touches, with the new row's scatter fused into
+    the kernel and without it (the same kernel over pools nobody writes)."""
+    from dllama_tpu.ops.pallas import paged_attention as pa
+
+    calls = calls or PAGED_CALLS
+    for name, c in (cells or PAGED_CELLS).items():
+        b, hq, hkv, hd, page = c["slots"], c["hq"], c["hkv"], c["hd"], c["page"]
+        lo, hi = rows or c["rows"]
+        lanes = pa.pool_lanes(hd)
+        nb = -(-(hi + 1) // page)
+        n_pool = max(c["kv_pages"], b * nb) + 1  # + the trash page
+        rng = np.random.default_rng(0)
+        pool = lambda: jnp.asarray(
+            rng.standard_normal((1, n_pool, hkv, page, lanes), np.float32),
+            jnp.bfloat16) * jnp.ones((c["layers"], 1, 1, 1, 1), jnp.bfloat16)
+        kp, vp = pool(), pool()
+        # distinct pages a slot, shuffled: the physical order must not help
+        tables = jnp.asarray(
+            rng.permutation(n_pool - 1)[: b * nb].reshape(b, nb), jnp.int32)
+        pos = jnp.asarray(np.linspace(lo, hi, b).astype(np.int32))
+        q = jnp.asarray(rng.standard_normal((b, 1, hq, hd)), jnp.bfloat16)
+        nk = jnp.asarray(rng.standard_normal((b, hkv, 1, hd)), jnp.bfloat16)
+        nv = jnp.asarray(rng.standard_normal((b, hkv, 1, hd)), jnp.bfloat16)
+        group = hq // hkv
+
+        def fused(q, kp, vp, li):
+            return pa.paged_decode_attention(
+                q, kp, vp, tables, pos, nk, nv, None, layer=li,
+                interpret=INTERPRET)
+
+        def read_only(q, kp, vp, li):
+            # the wrapper's fold, then the kernel with fused=False: the
+            # public read-only call drops the aliased pools (PERF.md
+            # section 7), so it cannot be threaded through a loop
+            pad = lambda x: jnp.pad(x, ((0, 0),) * 3 + ((0, lanes - hd),))
+            qf = pad(q).reshape(b, 1, hkv, group, lanes).transpose(
+                0, 2, 1, 3, 4).reshape(b, hkv, group, lanes)
+            qf = jnp.pad(qf, ((0, 0), (0, 0), (0, (-group) % 8), (0, 0)))
+            zero = jnp.zeros((b, 1), jnp.int32)
+            row = jnp.zeros((b, hkv, 1, lanes), kp.dtype)
+            out, k2, v2 = pa._paged_folded(
+                qf, kp.reshape(-1, *kp.shape[2:]), vp.reshape(-1, *vp.shape[2:]),
+                pos, tables + li * n_pool, zero, zero, row, row, group=group,
+                interpret=INTERPRET, rows_live=group, fused=False,
+                scale=hd ** -0.5)
+            return out, k2.reshape(kp.shape), v2.reshape(vp.shape)
+
+        seen = np.asarray(pos) + 1
+        needed = float(seen.sum()) * 2 * hkv * hd * 2 + b * hq * hd * (2 + 4)
+        touched = float((-(-seen // page)).sum()) * 2 * hkv * page * lanes * 2
+        for label, call in (("fused scatter", fused), ("read-only", read_only)):
+            @jax.jit
+            def loop(q, kp, vp):
+                def step(carry, i):
+                    kp, vp, acc = carry
+                    out, kp, vp = call(q, kp, vp, i % c["layers"])
+                    return (kp, vp, acc + out.astype(jnp.float32).sum()), None
+                return jax.lax.scan(
+                    step, (kp, vp, jnp.float32(0)),
+                    jnp.arange(calls, dtype=jnp.int32))[0]
+
+            try:
+                kp, vp, acc = loop(q, kp, vp)  # compiles; pools stay threaded
+                jax.block_until_ready(acc)
+                t0 = time.perf_counter()
+                kp, vp, acc = loop(q, kp, vp)
+                jax.block_until_ready(acc)
+                ms = (time.perf_counter() - t0) / calls * 1e3
+                print(f"paged decode {name} {label}: {ms:.4f} ms a call over "
+                      f"{calls} calls; slots {b} x {hkv} kv heads x {hd}, rows "
+                      f"{lo}-{hi}; needed {needed / 1e6:.1f} MB = "
+                      f"{needed / HBM_GBS / 1e3:.1f} us at {HBM_GBS:.0f} GB/s "
+                      f"({needed / HBM_GBS / 1e4 / ms:.1f}% of the call); pages "
+                      f"touched {touched / 1e6:.1f} MB = "
+                      f"{touched / HBM_GBS / 1e3:.1f} us "
+                      f"({touched / HBM_GBS / 1e4 / ms:.1f}%)")
+            except Exception as e:
+                print(f"paged decode {name} {label}: FAILED {e!r}"[:300])
+            sys.stdout.flush()
+
+
 def main():
     # argv: 'suite [--smoke] [--no-flash]' | 'flash [--smoke]' |
+    # 'paged [--smoke]' (the paged decode call of each benchmark cell) |
     # M SHAPE [variant ...] — suite runs the whole decode + prefill matrix in
     # ONE process (one device init, not six). --no-flash skips the flash
     # section; the q40 rows and the tile sweep still land.
@@ -384,6 +499,10 @@ def main():
         enable_smoke()
     if sys.argv[1:2] == ["flash"]:
         bench_flash_decode()
+        print("KBENCH DONE")
+        return
+    if sys.argv[1:2] == ["paged"]:
+        bench_paged_decode()
         print("KBENCH DONE")
         return
     if sys.argv[1:2] == ["suite"]:

@@ -36,6 +36,11 @@ CASES = (
     "paged decode t=1 p=128 hd=128 fused scatter",
     "paged decode t=1 p=128 hd=64 layer-indexed stack",
     "paged prefill t=256 p=128 hd=64 layer-indexed stack (XLA pre-scatter)",
+    # the benchmark's two cells at their own shapes: head blocks of 32 and 8
+    "paged decode t=1 p=128 b=12 Hkv=32 hd=128 layer-indexed stack "
+    "(deepseek7b.decode_closed)",
+    "paged decode t=1 p=128 b=48 Hkv=8 hd=64 layer-indexed stack "
+    "(granite4h.reason_closed)",
     "tp=4 shard_map mm in-shard+psum (w2)",
     "serve 1b paged decode chunk n=4",
     "serve 1b hybrid step p=64 n=4",

@@ -36,6 +36,15 @@ def test_kbench_suite_smoke():
     assert "tile tk=" in p.stdout, p.stdout
 
 
+def test_kbench_paged_smoke():
+    """The paged-decode loop of the benchmark's cells (the pricing of every
+    paged-kernel PR) at a tiny size: fused and read-only, pools threaded."""
+    p = _run(["experiments/kbench.py", "paged", "--smoke"])
+    assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
+    assert "KBENCH DONE" in p.stdout and "FAILED" not in p.stdout, p.stdout
+    assert p.stdout.count("fused scatter:") == p.stdout.count("read-only:") == 2
+
+
 def test_ebench_smoke():
     p = _run(["experiments/ebench.py", "4"], {"EBENCH_TINY": "1"})
     assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"

@@ -8,7 +8,12 @@ fused kernel's token streams are BIT-IDENTICAL to the gather path's through
 the real decode scan. Every op-level case also runs LAYER-INDEXED (PR 27):
 the same call on a layer-stacked pool with `layer=li` must equal the
 per-layer call on that layer's slice bit for bit, and leave every other
-layer's pages as they were.
+layer's pages as they were. Since PR 30 one grid step serves a BLOCK of kv
+heads whose size and ring depth come from a VMEM budget: the cases below
+run blocks of 1, 3 and 8 heads, force `hb = 1` and `hb = Hkv` through the
+budget (same pools bit for bit), land a verify chunk's rows in two pages of
+one sweep, mix live and trash-routed slots in one call, and share a prefix
+page between tables.
 
 Numerics note: the attention OUTPUT is online-softmax (flash), so op-level
 parity vs the materialized-softmax gather is allclose at f32 tolerance (the
@@ -23,6 +28,7 @@ import pytest
 
 from dllama_tpu.models.llama import _paged_cache_update
 from dllama_tpu.ops.layers import paged_gqa_attention
+from dllama_tpu.ops.pallas import paged_attention as pa
 from dllama_tpu.ops.pallas.paged_attention import (
     FUSED_SCATTER_MAX_T,
     paged_decode_attention,
@@ -210,6 +216,191 @@ def test_prefill_chunk_pre_scatter_path(rng, page, nb, t, pos, active,
     np.testing.assert_array_equal(np.asarray(vp2[live]), np.asarray(vp_ref[live]))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+def _fused_case(rng, page, nb, t, pos, hq, hkv, active=None,
+                dtype=jnp.float32, hd=64):
+    """Operands of one fused call: (q, kp, vp, tables, pos, nk, nv, active)."""
+    b = len(pos)
+    q, kp, vp, tables = _setup(rng, page, nb, b=b, t=t, hq=hq, hkv=hkv,
+                               hd=hd, dtype=dtype)
+    nk = jnp.asarray(rng.standard_normal((b, hkv, t, hd)), dtype)
+    nv = jnp.asarray(rng.standard_normal((b, hkv, t, hd)), dtype)
+    return (q, kp, vp, tables, jnp.asarray(pos, jnp.int32), nk, nv,
+            None if active is None else jnp.asarray(active))
+
+
+def _assert_fused_matches_reference(args, atol=2e-5):
+    """Pools BITWISE what `_paged_cache_update` writes (trash page too),
+    output to the gather reference's tolerance on the active slots."""
+    want, kp_ref, vp_ref = _reference(*args)
+    got, kp2, vp2 = paged_decode_attention(*args, interpret=True)
+    np.testing.assert_array_equal(np.asarray(kp2), np.asarray(kp_ref))
+    np.testing.assert_array_equal(np.asarray(vp2), np.asarray(vp_ref))
+    live = slice(None) if args[7] is None else np.asarray(args[7])
+    np.testing.assert_allclose(np.asarray(got, np.float32)[live],
+                               np.asarray(want, np.float32)[live],
+                               atol=atol, rtol=atol)
+    return got, kp2, vp2
+
+
+def _plan_of(args):
+    """(hb, depth) the call's shapes get at the module's budget."""
+    q, kp = args[0], args[1]
+    rows = -(-(q.shape[1] * (q.shape[2] // kp.shape[1])) // 8) * 8
+    return pa._plan(kp.shape[1], kp.shape[2], kp.shape[3], kp.dtype.itemsize,
+                    pa._q_tile(rows), q.shape[1], pa._VMEM_BUDGET_BYTES)[:2]
+
+
+@LAYOUTS
+@pytest.mark.parametrize("hq,hkv", [(2, 1), (3, 3), (6, 3), (8, 8)])
+def test_head_blocks_of_one_three_and_whole_hkv(rng, hq, hkv, stacked):
+    """One grid step serves a BLOCK of kv heads (PR 30): a block of one
+    head, a non-power-of-two block and a whole-Hkv block of 8 all read and
+    write what the per-head reference does — a spec-verify chunk whose rows
+    land in two pages of the sweep, slots at 1, 2 and 3 live pages."""
+    args = _fused_case(rng, 8, 4, 3, [6, 15, 17], hq, hkv)
+    assert _plan_of(args)[0] == hkv  # tiny pages: the block is all heads
+    if stacked:
+        return _assert_layer_indexed_equals_sliced(rng, *args)
+    _assert_fused_matches_reference(args)
+
+
+@pytest.mark.parametrize("t,pos,active", [
+    (1, [19, 0, 44, 7], None),  # decode; slots of 3, 1, 6 and 1 live pages
+    (5, [6, 13, 27, 61], None),  # verify chunks over two pages; one clipped
+    # at the table's end (rows 64, 65 land in the last page, as the XLA scatter clips)
+    (5, [6, 13, 27, 40], [False, True, False, True]),  # trash first and mid
+    (16, [3, 0, 21, 40], None),  # t > page: three pages receive rows
+])
+def test_budget_forcing_one_head_equals_whole_block(rng, monkeypatch, t, pos,
+                                                    active):
+    """`hb` and the ring's depth are functions of the shapes and a VMEM
+    budget: a budget nothing fits gives hb = 1 at depth 2 (what
+    `paged_decode_supported` admits), a middling one a proper divisor, the
+    default all of Hkv at full depth. The three are the SAME result: pools
+    bit for bit, outputs to 2e-5 — and each matches the reference."""
+    args = _fused_case(rng, 8, 8, t, pos, 8, 4, active)
+    results, default = [], pa._VMEM_BUDGET_BYTES
+    for hb in (1, 2, 4):
+        for budget in (1, *range(10_000, 1_000_000, 10_000)):
+            monkeypatch.setattr(pa, "_VMEM_BUDGET_BYTES",
+                                default if hb == 4 else budget)
+            if _plan_of(args)[0] == hb:
+                break
+        assert _plan_of(args)[0] == hb and (budget == 1) == (hb != 2)
+        results.append(_assert_fused_matches_reference(args))
+    assert _plan_of(args) == (4, pa._MAX_DEPTH)
+    for got, kp2, vp2 in results[1:]:
+        np.testing.assert_array_equal(np.asarray(kp2), np.asarray(results[0][1]))
+        np.testing.assert_array_equal(np.asarray(vp2), np.asarray(results[0][2]))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(results[0][0]),
+                                   atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("budget", [1, pa._VMEM_BUDGET_BYTES],
+                         ids=["one-head", "whole-block"])
+def test_ring_carries_over_q_tiles_and_slots(rng, monkeypatch, budget):
+    """The landing ring does not drain between grid steps: a prefill chunk
+    of several q tiles over slots of 1 to 8 live pages, read-only, walks
+    (slot, head block, q tile) steps whose first pages the step BEFORE
+    started — also across steps shorter than the ring is deep."""
+    monkeypatch.setattr(pa, "_VMEM_BUDGET_BYTES", budget)
+    q, kp, vp, tables = _setup(rng, 8, 8, b=3, t=40, hq=16, hkv=4)
+    assert q.shape[1] * 4 > pa._Q_TILE_MAX  # 160 folded rows: 5 q tiles of 32
+    pos = jnp.asarray([0, 23, 3], jnp.int32)
+    want, _, _ = _reference(q, kp, vp, tables, pos)
+    got = paged_decode_attention(q, kp, vp, tables, pos, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+@LAYOUTS
+def test_active_and_inactive_slots_in_one_head_blocked_call(rng, stacked):
+    """An inactive slot's rows go to the trash page through ONE
+    read-modify-write a head block, beside live slots whose rows are
+    blended into the sweep: every page, the trash page too, bitwise as
+    `_paged_cache_update` leaves it (4 rows at distinct offsets)."""
+    args = _fused_case(rng, 16, 4, 4, [35, 1, 14, 60], 8, 4,
+                       [True, False, True, True])
+    assert _plan_of(args)[0] == 4
+    if stacked:
+        return _assert_layer_indexed_equals_sliced(rng, *args)
+    _, kp2, _ = _assert_fused_matches_reference(args)
+    np.testing.assert_array_equal(np.asarray(kp2[-1, :, 1:5]),
+                                  np.asarray(args[5][1]))  # rows 1..4 of trash
+    for pg in np.asarray(args[3][1]):  # the slot's own pages: untouched
+        np.testing.assert_array_equal(np.asarray(kp2[pg]),
+                                      np.asarray(args[1][pg]))
+
+
+@LAYOUTS
+def test_two_slots_share_a_read_only_prefix_page(rng, stacked):
+    """Prefix sharing: two tables hold the same first page. Both sweeps
+    read it (one of them through the copy the step before started), each
+    slot writes only its own tail page."""
+    q, kp, vp, tables, pos, nk, nv, _ = _fused_case(
+        rng, 8, 4, 2, [11, 14, 9], 8, 4)
+    tables = tables.at[1, 0].set(tables[0, 0]).at[2, 0].set(tables[0, 0])
+    args = (q, kp, vp, tables, pos, nk, nv, None)
+    if stacked:
+        return _assert_layer_indexed_equals_sliced(rng, *args)
+    _, kp2, vp2 = _assert_fused_matches_reference(args)
+    shared = int(tables[0, 0])
+    np.testing.assert_array_equal(np.asarray(kp2[shared]), np.asarray(kp[shared]))
+    np.testing.assert_array_equal(np.asarray(vp2[shared]), np.asarray(vp[shared]))
+
+
+@pytest.mark.parametrize("t,group", [(9, 4), (16, 16)])
+def test_folded_rows_one_q_tile_or_pre_scatter(rng, t, group):
+    """A verify chunk's folded rows are ONE q tile up to 128 (9 tokens x
+    group 4 = 36 rows: one sweep of the pages, not five); past that the
+    rows take several tiles, so several sweeps, and the wrapper scatters
+    them through XLA first — the fused blend needs the slot's one sweep."""
+    args = _fused_case(rng, 8, 8, t, [5, 30], 2 * group, 2)
+    rows = -(-t * group // 8) * 8
+    assert (pa._q_tile(rows) == rows) == (rows <= pa._Q_TILE_MAX)
+    _assert_fused_matches_reference(args)
+
+
+def test_bfloat16_pool_takes_bfloat16_qk_operands(rng):
+    """Where q and the pool are BOTH bfloat16 the q.k product takes them as
+    they are stored (a product of two bfloat16 values is exact in float32,
+    so only the order of the float32 sum can differ); p, the accumulator
+    and p.v stay float32. Pools bitwise; the output against the reference
+    run in float32 on the same bfloat16 values."""
+    args = _fused_case(rng, 16, 4, 2, [35, 14, 60], 8, 4,
+                       dtype=jnp.bfloat16, hd=128)
+    q, kp, vp, tables, pos, nk, nv, _ = args
+    _, kp_ref, vp_ref = _reference(*args)
+    got, kp2, vp2 = paged_decode_attention(*args, interpret=True)
+    np.testing.assert_array_equal(np.asarray(kp2, np.float32),
+                                  np.asarray(kp_ref, np.float32))
+    np.testing.assert_array_equal(np.asarray(vp2, np.float32),
+                                  np.asarray(vp_ref, np.float32))
+    f32 = lambda x: x.astype(jnp.float32)
+    want = paged_gqa_attention(f32(q), f32(kp_ref), f32(vp_ref), tables, pos)
+    assert got.dtype == jnp.bfloat16  # the wrapper hands back q's dtype
+    np.testing.assert_allclose(np.asarray(f32(got)), np.asarray(want),
+                               atol=1e-2, rtol=1e-2)  # its one rounding
+
+
+@pytest.mark.parametrize("name,hkv,page,itemsize,tq,hb_range", [
+    ("deepseek-llm-7b decode", 32, 128, 2, 8, (8, 32)),
+    ("granite-4.0-h-micro decode", 8, 128, 2, 8, (8, 8)),
+    ("deepseek-llm-7b prefill chunk", 32, 128, 2, 128, (4, 32)),
+    ("a page only one head of fits", 8, 4096, 2, 8, (1, 1)),
+])
+def test_plan_is_a_function_of_shapes(name, hkv, page, itemsize, tq, hb_range):
+    """The two cells' decode calls get head blocks of 8-32 heads (256 KB to
+    1 MB a copy); a prefill chunk's accumulator takes its share; a page too
+    large for anything else gets hb = 1 at depth 2."""
+    hb, depth, nbytes = pa._plan(hkv, page, 128, itemsize, tq, 1,
+                                 pa._VMEM_BUDGET_BYTES)
+    assert hkv % hb == 0 and hb_range[0] <= hb <= hb_range[1], (name, hb)
+    assert 2 <= depth <= pa._MAX_DEPTH
+    assert nbytes <= pa._VMEM_BUDGET_BYTES or (hb, depth) == (1, 2)
+    assert paged_decode_supported((hkv, 128), page)
 
 
 def test_capability_check():
