@@ -9,6 +9,17 @@ AND depth of Llama-3.2-1B (Q40 weights random, made from --seed), and checks
 what comes out by the repo's own means. It claims no speed: the figures it
 prints are smoke figures of one cold run.
 
+WARNING, a named debt (ROADMAP, Design): `write_model` below still writes
+the BLIND weights. Its nibbles are uniform in [-8, 7], so every block has a
+mean of -0.5 and the residual stream collapses onto one direction; the
+serving path then agrees with the reference to a rel-L2 of 0.0009-0.002
+with or without an f8 KV cache (PERF.md section 4), which means the parity
+this smoke prints on the serve path cannot tell a sound cache from a lossy
+one. It proves that the path starts, compiles and drains, and no more. The
+cure is symmetric nibbles with `wq` 1.5 times larger, as
+`benchmark/layouts/llama.py` writes them, or retiring this file in favour
+of `benchmark/drill.py`; neither is done here.
+
 This process NEVER initialises a JAX backend (a chip belongs to one process
 at a time): it writes the files with numpy and talks HTTP. Each phase is a
 child process, one at a time, each the only holder of the chip:

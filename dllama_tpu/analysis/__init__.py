@@ -26,8 +26,8 @@ drift-checked against :data:`RULE_CATALOG` both directions):
   acquired under the metrics/tracer leaf locks. The runtime half is the
   ``DLLAMA_LOCK_AUDIT=1`` sanitizer in ``utils/locks``.
 * **gate** — the repo contracts scripts/checks.sh used to grep for
-  (paged-route README table, bench records, perfdiff rules, the AOT
-  inventory), now with real ``file:line`` diagnostics.
+  (paged-route README table, the AOT inventory), now with real
+  ``file:line`` diagnostics.
 * **doc** — the README rule-catalog and lock-rank tables match the code's
   definition sites exactly, both directions.
 """

@@ -40,9 +40,6 @@ RULE_CATALOG = {
                      "(or a rank no lock uses)",
     "gate-routes": "engine/kernel_select.PAGED_ROUTES drifted from the "
                    "README paged-routing table",
-    "gate-bench": "bench.py lost a gated record (bench_hybrid / "
-                  "bench_compile / bench_router)",
-    "gate-perfdiff": "experiments/perfdiff.py lost a gated regression rule",
     "gate-aot": "experiments/aot_check.py lost the paged-kernel AOT "
                 "inventory",
     "gate-scripts": "a gated smoke script is missing or not executable",
@@ -171,8 +168,7 @@ class Project:
 
     #: non-package files some rules read (gates/docs); missing entries are
     #: each rule's problem to report
-    EXTRA_FILES = ("README.md", "bench.py", "experiments/perfdiff.py",
-                   "experiments/aot_check.py")
+    EXTRA_FILES = ("README.md", "experiments/aot_check.py")
 
     def __init__(self, files: dict[str, str], root: str | None = None):
         self.root = root

@@ -10,13 +10,10 @@ everything textual moved here:
   "Paged KV cache" routing table must match both directions (a route the
   docs don't name, or a doc row for a route kernel_select cannot
   resolve, is the operator-facing contract lying).
-* ``gate-bench`` / ``gate-perfdiff`` / ``gate-aot`` — the hybrid/compile
-  bench records, the perfdiff regression rules (stall/TTFT ratios, the
-  zero-recompile/zero-upload ceilings), and the paged-kernel AOT
-  inventory must keep existing: deleting any of them un-gates a shipped
-  invariant silently.
-* ``gate-scripts`` — the smoke entry points those gates cite stay
-  present and executable.
+* ``gate-aot`` — the paged-kernel AOT inventory of
+  ``experiments/aot_check.py`` must keep existing: deleting it un-gates a
+  shipped invariant silently.
+* ``gate-scripts`` — the smoke entry points stay present and executable.
 * ``doc-rules`` / ``doc-ranks`` — the README rule-catalog table matches
   :data:`~dllama_tpu.analysis.core.RULE_CATALOG` and the README lock-rank
   table matches ``utils/locks.LOCK_RANKS``, both directions — the same
@@ -38,31 +35,9 @@ _KSEL = "dllama_tpu/engine/kernel_select.py"
 #: README must still fail, not pass as "consistent")
 REQUIRED_ROUTES = ("paged_kernel", "paged_gather")
 
-#: perfdiff regression-rule keys whose deletion un-gates a shipped
-#: invariant (ISSUE 12/13 acceptance surfaces)
-PERFDIFF_KEYS = ("hybrid.stall_reduction_x", "hybrid.ttft_overhead_x",
-                 "compile.steady.unexpected_compiles",
-                 "compile.steady.upload_bytes",
-                 "compile.warmup_ttft_ratio",
-                 # ISSUE 15: the router's affinity warm-TTFT win and the
-                 # 2-vs-1-replica scaling ratio stay gated
-                 "router.affinity.warm_ttft_ratio_on_off",
-                 "router.scale.agg_tok_s_ratio_2_1",
-                 # ISSUE 17: the observability plane stays ~free on the
-                 # proxy path and every merged replica stays clock-aligned
-                 "fleet_obs.tok_s_ratio_on_off",
-                 "fleet_obs.trace.unaligned_replicas",
-                 # ISSUE 19: the acceptance pin — proxy overhead with the
-                 # plane on vs off, ceiling 1.03x
-                 "fleet_obs.proxy_overhead_x")
-
 #: aot_check.py markers: the paged flash-decode op inventory + its fused-
 #: scatter cases (ISSUE 8)
 AOT_MARKERS = ("paged_decode_attention", "fused scatter")
-
-#: bench records the perf gate rules read
-BENCH_DEFS = ("bench_hybrid", "bench_compile", "bench_router",
-              "bench_fleet_obs")
 
 #: smoke scripts the gates cite (path, must-be-executable)
 GATED_SCRIPTS = ("scripts/hybrid_smoke.sh", "scripts/compile_smoke.sh",
@@ -147,32 +122,7 @@ def _check_routes(project, diags):
                 "kernel_select.PAGED_ROUTES cannot resolve"))
 
 
-def _check_texts(project, diags):
-    bench = project.source("bench.py")
-    if bench is None:
-        diags.append(Diagnostic("bench.py", 1, "gate-bench",
-                                "bench.py missing from the tree"))
-    elif bench.parse_error() is not None:
-        pass  # reported once as parse-error
-    else:
-        defs = {n.name for n in ast.walk(bench.tree)
-                if isinstance(n, ast.FunctionDef)}
-        for name in BENCH_DEFS:
-            if name not in defs:
-                diags.append(Diagnostic(
-                    "bench.py", 1, "gate-bench",
-                    f"bench.py lost its gated record (def {name})"))
-    pd = project.source("experiments/perfdiff.py")
-    if pd is None:
-        diags.append(Diagnostic("experiments/perfdiff.py", 1,
-                                "gate-perfdiff", "perfdiff.py missing"))
-    else:
-        for key in PERFDIFF_KEYS:
-            if key not in pd.text:
-                diags.append(Diagnostic(
-                    "experiments/perfdiff.py", 1, "gate-perfdiff",
-                    f"perfdiff rules lost {key!r} — that regression "
-                    "surface is no longer gated"))
+def _check_aot(project, diags):
     aot = project.source("experiments/aot_check.py")
     if aot is None:
         diags.append(Diagnostic("experiments/aot_check.py", 1, "gate-aot",
@@ -239,7 +189,7 @@ def _check_docs(project, diags):
 def check(project) -> list[Diagnostic]:
     diags: list[Diagnostic] = []
     _check_routes(project, diags)
-    _check_texts(project, diags)
+    _check_aot(project, diags)
     _check_scripts(project, diags)
     _check_docs(project, diags)
     return diags

@@ -644,10 +644,6 @@ class DecodeChunk:
     bad: jax.Array | None = None  # bool[B] rows whose logits went
     # non-finite inside the scan (the decode NaN guard's device-side half)
     bad_inject: np.ndarray | None = None  # decode.nan fault overlay
-    device_s: float = 0.0  # exclusive device window, stamped at consumption
-    # (same clock as DECODE_CHUNK_SECONDS: starts at the later of this
-    # chunk's dispatch and the previous chunk's consumption) — what the
-    # roofline-attainment gauge divides priced HBM bytes by
     spec: bool = False  # this chunk is a fused spec chunk of `n` verify
     # cycles: `toks` is the stacked per-cycle emit tensor [n, B, K+1]
     # (decode_consume flattens each slot's accepted runs into the plain
@@ -1809,35 +1805,6 @@ class BatchEngine:
         ins.KV_SPILL.labels(direction="in").inc()
         return page
 
-    def chunk_cost_model(self):
-        """Frozen obs/perf.ChunkCostModel pricing THIS engine's decode
-        steps (the scheduler's roofline-attainment feed): the same per-op
-        byte formula as experiments/hbm_traffic.py's offline tables, with
-        `weight_bytes` = the REAL resident parameter bytes — an unquantized
-        test model is priced as what it actually streams per step, not as a
-        hypothetical Q40."""
-        from dllama_tpu.obs.perf import ChunkCostModel
-        from dllama_tpu.utils.profiling import params_nbytes
-
-        try:
-            cache_el = np.dtype(self.cache_dtype).itemsize
-        except TypeError:  # ml_dtypes classes resolve via a jnp scalar
-            cache_el = jnp.zeros((), self.cache_dtype).dtype.itemsize
-        cfg = self.cfg
-        return ChunkCostModel(
-            n_layers=cfg.n_layers, dim=cfg.dim, hidden_dim=cfg.hidden_dim,
-            # row width as stored (a paged_kernel pool is lane-padded)
-            kv_dim=cfg.kv_dim, head_size=int(self.cache.k.shape[-1]),
-            n_kv_heads=cfg.n_kv_heads, vocab_size=cfg.vocab_size,
-            seq_len=self.seq_len, weight_bytes=int(params_nbytes(self.params)),
-            cache_bytes_per_el=int(cache_el),
-            paged=self.kv_layout == "paged", page_size=self.page_size,
-            # the routed attention path decides the paged pricing: the
-            # gather fallback re-materializes the whole block-table view
-            # through XLA every step, the kernel streams live pages only
-            paged_impl=("gather" if self._paged_route == "paged_gather"
-                        else "kernel"))
-
     # ------------------------------ compile contract & warmup (ISSUE 13)
 
     def _prefill_bucket_cap(self) -> int:
@@ -2899,7 +2866,6 @@ class BatchEngine:
         start = (chunk.t0 if self._t_last_consume is None
                  else max(chunk.t0, self._t_last_consume))
         ins.DECODE_CHUNK_SECONDS.observe(now - start)
-        chunk.device_s = now - start  # the roofline gauge's denominator
         self._t_last_consume = now
         tr = trace.TRACER
         if chunk.spec:

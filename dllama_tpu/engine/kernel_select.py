@@ -47,9 +47,8 @@ class KernelSelection:
     backend: str  # 'pallas' | 'xla' (what the quantized matmuls run on)
     attn_route: str = "jnp"  # which attention path attn_fn resolves to:
     # 'jnp' | 'flash' | 'sharded_flash' | 'ring' | 'paged_kernel' |
-    # 'paged_gather' — the single string obs/bench/README quote for "what
-    # actually runs", and what chunk_cost_model prices (kernel vs gather
-    # paged bytes differ by the whole re-materialized view)
+    # 'paged_gather' — the single string obs/README/the benchmark quote
+    # for "what actually runs"
     interpret: bool = False  # Pallas interpret mode baked into attn_fn (and
     # what ops.matmul derives for the matmuls): true only off-TPU
 

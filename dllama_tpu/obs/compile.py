@@ -77,8 +77,8 @@ COMPILE_CACHE_DIR = os.path.join(
 def place_compile_cache() -> str:
     """Turn the persistent compile cache on for this process and return
     its directory. Every entry point calls this before anything jits
-    (cli/main.py, bench.py, chip_smoke.py's children, experiments/*bench.py),
-    so a server's boot, a bench and a smoke share one cache: the compiled
+    (cli/main.py, chip_smoke.py's children, experiments/kbench.py),
+    so a server's boot, a kernel sweep and a smoke share one cache: the compiled
     shape universe (ROADMAP Speed #5) is paid for once per machine, not
     once per process.
 

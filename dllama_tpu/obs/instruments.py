@@ -375,11 +375,6 @@ LATENCY_WINDOW = metrics.gauge(
     "metric=ttft|itl|e2e at quantile=p50|p95|p99 — the live-tail view the "
     "per-request histograms cannot give without a quantile-capable backend",
     ("metric", "quantile"))
-BW_ATTAINMENT = metrics.gauge(
-    "dllama_decode_bandwidth_attainment",
-    "Windowed decode HBM-bandwidth attainment: priced chunk bytes "
-    "(experiments/hbm_traffic.py's cost model, one definition site in "
-    "obs/perf.decode_step_bytes) / measured device seconds / peak HBM GB/s")
 THROUGHPUT = metrics.gauge(
     "dllama_throughput_tok_s",
     "Windowed completion-token rate over finished requests (scrape-time "

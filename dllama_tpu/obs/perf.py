@@ -1,9 +1,11 @@
 """SLO & saturation observability core (ISSUE 7): sliding-window latency
-quantiles, the scheduler time ledger, and roofline/goodput attribution.
+quantiles, the scheduler time ledger, and goodput-vs-throughput.
 
 Everything here is host-side aggregation over marks the serving stack
 already produces (PR 2's metrics registry, PR 4's spans) — the layer the
-ROADMAP's SLO-aware scheduling and any honest bench trajectory consume:
+ROADMAP's SLO-aware scheduling consumes. It holds what host clocks are
+right for; a kernel's share of its roofline is read from a trace by
+``benchmark/`` (its ``costs/`` and ``peaks.json``), never priced here:
 
 * :class:`WindowQuantiles` — a dependency-free sliding-window quantile
   estimator in the streaming-sketch family the ISSUE cites (P²/t-digest,
@@ -22,13 +24,6 @@ ROADMAP's SLO-aware scheduling and any honest bench trajectory consume:
   totals partition wall time by construction — their sum equals loop wall
   time to the clock's precision, which is the invariant
   tests/test_perf.py drives a real scheduler run through.
-* :class:`ChunkCostModel` / :func:`decode_step_bytes` — the per-step HBM
-  byte pricing shared with ``experiments/hbm_traffic.py`` (that script's
-  ``batched_step_bytes`` delegates here; one definition site, so the live
-  gauges and the offline roofline tables cannot drift). The live side
-  prices each consumed decode chunk and divides by its measured device
-  window to export bandwidth attainment against the serving device's HBM
-  roofline (:data:`PEAK_HBM_GBS`, keyed by device kind).
 * :class:`SloPolicy` / :class:`PerfAggregator` — configurable TTFT/ITL SLO
   targets (``--slo-ttft-ms`` / ``--slo-itl-ms``), burn counters
   (``dllama_slo_violations_total{kind}``), a windowed attainment gauge,
@@ -50,22 +45,6 @@ from dataclasses import dataclass
 from dllama_tpu.obs import instruments as ins
 from dllama_tpu.obs import trace
 from dllama_tpu.utils import locks
-
-#: Peak HBM bandwidth in GB/s, keyed by ``jax.devices()[0].device_kind``.
-#: Source: Google Cloud documentation, "TPU v5e" (819 GB/s per chip) — the
-#: same figure experiments/hbm_traffic.py prices its offline rooflines
-#: against. The live bandwidth-attainment gauge divides achieved bytes/s by
-#: the serving device's entry; a device that is not listed is UNPRICED (no
-#: attainment is exported for it), never defaulted to another chip's peak.
-PEAK_HBM_GBS = {
-    "TPU v5 lite": 819.0,
-}
-
-
-def peak_hbm_gbs(device_kind: str) -> float | None:
-    """The device's peak HBM GB/s from :data:`PEAK_HBM_GBS`, or None when
-    the table does not know it (e.g. the CPU backend the tests run on)."""
-    return PEAK_HBM_GBS.get(device_kind)
 
 #: the exclusive states of the scheduler worker loop — the label set of
 #: dllama_scheduler_time_seconds_total{state} and the README ledger table
@@ -175,7 +154,7 @@ class WindowQuantiles:
 
 class WindowSums:
     """Time-sliced sliding-window sums (the rate companion of
-    :class:`WindowQuantiles`): ``add(tokens=3, bytes=1e6)`` accumulates into
+    :class:`WindowQuantiles`): ``add(tokens=3, finished=1)`` accumulates into
     the current slice, ``totals()`` merges live slices, ``span_s()`` is the
     window the totals cover (for rate = total / span)."""
 
@@ -417,102 +396,6 @@ class TimeLedger:
         }
 
 
-# ---------------------------------------------------------- chunk pricing
-
-
-def decode_step_bytes(*, n_layers: int, dim: int, hidden_dim: int,
-                      kv_dim: int, head_size: int, n_kv_heads: int,
-                      vocab_size: int, seq_len: int, weight_bytes: int,
-                      slots: int, live_rows: float,
-                      cache_bytes_per_el: int = 2, paged: bool = False,
-                      page_size: int = 128,
-                      paged_impl: str = "kernel") -> int:
-    """Per-STEP HBM bytes of a ``slots``-wide batched decode — THE cost
-    model (moved here from ``experiments/hbm_traffic.py``, which now
-    delegates, so the offline roofline tables and the live attainment gauge
-    price identically). The weight stream is read once per step and serves
-    every slot; the KV stream scales with slots; activations scale with
-    slots but stay negligible. ``live_rows`` is the per-slot live KV
-    horizon in rows (the offline script passes ``live_frac * seq_len``; the
-    live path passes the chunk's mean position).
-
-    paged=True prices by the routed attention path (``paged_impl``, set
-    from ``KernelSelection.attn_route``):
-
-    * ``kernel`` — the Pallas flash-decode kernel: PER-PAGE KV reads (live
-      rows round up to whole pages — the page DMA quantum) plus the i32
-      block tables, scalar-prefetched ONCE per fused launch per layer (the
-      fused scatter rides the same launch, so there is no second table
-      read and no separate scatter dispatch).
-    * ``gather`` — the jnp fallback: on top of the per-page pool reads,
-      XLA MATERIALIZES the full ``max_blocks*page = seq_len``-row
-      contiguous view for k and v (one write + one read of the whole view,
-      per layer, every step) and reads the tables once per gather (k + v).
-      This is the traffic blowup the kernel exists to remove — the two
-      routes' bytes differ by design, not by drift."""
-    L, d, h = n_layers, dim, hidden_dim
-    m = max(8, slots)  # one fused step: all slots are rows of one matmul
-
-    def mm_act(k, n):
-        return m * k * 2 + m * n * 4
-
-    acts = (mm_act(d, d) * 2 + mm_act(d, kv_dim) * 2
-            + mm_act(d, h) * 2 + mm_act(h, d)) * L + mm_act(d, vocab_size)
-    rows = float(live_rows)
-    view_rows = 0.0
-    if paged:
-        # page-granular pruning horizon: live rows round up to whole pages
-        rows = -(-int(rows) // page_size) * page_size
-        if paged_impl == "gather":
-            # full contiguous view, written then read, k and v, per layer
-            view_rows = 2.0 * seq_len
-    kv_stream = int(2 * slots * n_kv_heads * (rows + view_rows) * head_size
-                    * cache_bytes_per_el) * L
-    kv_write = 2 * slots * kv_dim * cache_bytes_per_el * L
-    if not paged:
-        table_read = 0
-    elif paged_impl == "gather":
-        table_read = 4 * slots * (seq_len // page_size) * 2 * L  # k + v gathers
-    else:
-        table_read = 4 * slots * (seq_len // page_size) * L  # one fused launch
-    return int(weight_bytes + acts + kv_stream + kv_write + table_read
-               + slots * d * 2)
-
-
-@dataclass(frozen=True)
-class ChunkCostModel:
-    """Frozen per-engine pricing inputs for :func:`decode_step_bytes`
-    (built once at scheduler construction by
-    ``BatchEngine.chunk_cost_model()`` — ``weight_bytes`` is the engine's
-    REAL resident parameter bytes, so an unquantized test model is priced
-    as what it actually streams, not as a hypothetical Q40)."""
-
-    n_layers: int
-    dim: int
-    hidden_dim: int
-    kv_dim: int
-    head_size: int
-    n_kv_heads: int
-    vocab_size: int
-    seq_len: int
-    weight_bytes: int
-    cache_bytes_per_el: int = 2
-    paged: bool = False
-    page_size: int = 128
-    paged_impl: str = "kernel"  # 'kernel' | 'gather' (KernelSelection route)
-
-    def step_bytes(self, slots: int, live_rows: float) -> int:
-        return decode_step_bytes(
-            n_layers=self.n_layers, dim=self.dim, hidden_dim=self.hidden_dim,
-            kv_dim=self.kv_dim, head_size=self.head_size,
-            n_kv_heads=self.n_kv_heads, vocab_size=self.vocab_size,
-            seq_len=self.seq_len, weight_bytes=self.weight_bytes,
-            slots=slots, live_rows=live_rows,
-            cache_bytes_per_el=self.cache_bytes_per_el,
-            paged=self.paged, page_size=self.page_size,
-            paged_impl=self.paged_impl)
-
-
 # -------------------------------------------------------------- SLO policy
 
 
@@ -630,21 +513,17 @@ class PrefillBudgetController:
 
 
 class PerfAggregator:
-    """The per-scheduler join of the three views: latency windows + SLO
-    accounting (request finishes), and roofline pricing (decode chunks).
+    """The per-scheduler join of the latency windows, the SLO accounting
+    and goodput-vs-throughput, all fed by request finishes.
     Gauges live in the process registry (last scheduler wins, like every
     other serving gauge); ``refresh_gauges()`` runs at scrape time so the
     windowed values are current without putting quantile merges on the
     serving hot path."""
 
     def __init__(self, slo: SloPolicy | None = None,
-                 cost_model: ChunkCostModel | None = None,
                  window_s: float = 60.0, slices: int = 6,
-                 peak_gbs: float | None = None, now_fn=time.monotonic):
+                 now_fn=time.monotonic):
         self.slo = slo or SloPolicy()
-        self.cost_model = cost_model
-        # peak_hbm_gbs(device_kind) of the serving device; None = unpriced
-        self.peak_gbs = peak_gbs
         mk = lambda: WindowQuantiles(window_s, slices, now_fn=now_fn)
         self.ttft = mk()   # seconds
         self.itl = mk()    # seconds
@@ -653,8 +532,6 @@ class PerfAggregator:
         # throughput share this basis — both rate over FINISHED requests,
         # so goodput/throughput is a like-for-like fraction)
         self.flow = WindowSums(window_s, slices, now_fn=now_fn)
-        # decode-chunk window: priced bytes vs measured device seconds
-        self.chunks = WindowSums(window_s, slices, now_fn=now_fn)
 
     # ------------------------------------------------------------ feeding
 
@@ -678,19 +555,6 @@ class PerfAggregator:
         good = finish_reason in ("stop", "length") and v["ok"]
         self.flow.add(finished=1, ok=1 if v["ok"] else 0,
                       tokens=tokens, good_tokens=tokens if good else 0)
-
-    def observe_chunk(self, *, occupancy: int, live_rows: float, steps: int,
-                      tokens: int, device_s: float) -> None:
-        """One consumed decode chunk: price its HBM traffic with the cost
-        model (``steps`` fused steps at this occupancy and live-KV horizon)
-        against its measured exclusive device window. Chunks with no
-        measurable window (clock noise) still count their tokens."""
-        fields = {"chunks": 1, "chunk_tokens": tokens,
-                  "device_s": max(device_s, 0.0)}
-        if self.cost_model is not None and occupancy > 0:
-            fields["bytes"] = (self.cost_model.step_bytes(occupancy, live_rows)
-                               * max(steps, 0))
-        self.chunks.add(**fields)
 
     # ------------------------------------------------------------- reading
 
@@ -724,37 +588,14 @@ class PerfAggregator:
         }
 
     def roofline_snapshot(self) -> dict:
-        c = self.chunks.totals()
+        """Windowed token rates over finished requests. The name is the
+        `/debug/perf` key the router's fleet view federates."""
         f = self.flow.totals()
         span = self.flow.span_s()
-        device_s = c.get("device_s", 0.0)
-        by = c.get("bytes", 0.0)
-        # priced = a cost model AND a known peak for this device. Unpriced
-        # windows carry no rate at all (peak_gbs / bandwidth_attainment are
-        # ABSENT, not defaulted); a priced but unmeasured window answers
-        # None, not a false "0.0 attainment"
-        priced = self.cost_model is not None and self.peak_gbs is not None
-        achieved = ((by / device_s)
-                    if (priced and device_s > 0 and by > 0) else None)
-        thr = f.get("tokens", 0.0) / span
-        good = f.get("good_tokens", 0.0) / span
-        out = {
-            "priced": priced,
-            "window_chunks": int(c.get("chunks", 0.0)),
-            "chunk_tokens": int(c.get("chunk_tokens", 0.0)),
-            "device_s": round(device_s, 6),
-            "bytes": int(by),
-            "achieved_gbs": (None if achieved is None
-                             else round(achieved / 1e9, 3)),
-            "throughput_tok_s": round(thr, 3),
-            "goodput_tok_s": round(good, 3),
+        return {
+            "throughput_tok_s": round(f.get("tokens", 0.0) / span, 3),
+            "goodput_tok_s": round(f.get("good_tokens", 0.0) / span, 3),
         }
-        if priced:
-            out["peak_gbs"] = self.peak_gbs
-            out["bandwidth_attainment"] = (
-                None if achieved is None
-                else round(achieved / (self.peak_gbs * 1e9), 6))
-        return out
 
     def refresh_gauges(self) -> None:
         """Push the windowed views into the registry gauges — called at
@@ -773,15 +614,12 @@ class PerfAggregator:
         att = slo["attainment"]
         ins.SLO_ATTAINMENT.set(nan if att is None else att)
         roof = self.roofline_snapshot()
-        if roof["priced"]:  # an unpriced device exports no attainment sample
-            bw = roof["bandwidth_attainment"]
-            ins.BW_ATTAINMENT.set(nan if bw is None else bw)
         ins.THROUGHPUT.set(roof["throughput_tok_s"])
         ins.GOODPUT.set(roof["goodput_tok_s"])
 
     def snapshot(self, ledger: TimeLedger | None = None) -> dict:
         """The `/debug/perf` join: windowed quantiles, SLO accounting,
-        ledger attribution, roofline/goodput — one JSON document."""
+        ledger attribution, goodput/throughput — one JSON document."""
         out = {
             "window": self.window_snapshot(),
             "slo": self.slo_snapshot(),

@@ -20,8 +20,8 @@ from dllama_tpu.models.config import HiddenAct, LlamaConfig, RopeType
 # 'jnp' lets XLA fuse the norm into neighbors (the right default); 'pallas'
 # routes through ops/pallas/rms_norm — the single-pass fused kernel for the
 # case where the norm feeds a Pallas matmul (an opaque call XLA won't fuse
-# across). Measured via the ebench 'pallas-norm' row (VERDICT r3 weak #8);
-# flip only with a recorded win.
+# across). Not measured on the chip; flip only with a recorded win (a
+# ledger line).
 RMS_NORM_IMPL = "jnp"
 
 

@@ -35,8 +35,8 @@ BACKEND = "auto"
 # nn-cpu-ops.cpp:1003-1019): at or above this flattened batch*seq, a Pallas-
 # backed matmul routes to the XLA dequant-dot instead — prefill is FLOPs-bound
 # and the plain MXU GEMM beats in-kernel unpacking once the packed-bytes
-# saving stops mattering. None = always fused (the pre-measurement default);
-# bench.py overrides via BENCH_XLA_PREFILL_M to A/B it on hardware.
+# saving stops mattering. None = always fused: the route at prefill sizes
+# is not measured on the chip (ROADMAP Speed #5, experiments/kbench.py).
 XLA_PREFILL_MIN_M: int | None = None
 
 

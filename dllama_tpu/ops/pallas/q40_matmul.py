@@ -421,7 +421,7 @@ def q40_matmul(
     elif style in ("blockdot", "maskdot", "loopdot") and mp > 16:
         # forced decode-shaped styles apply only to decode-shaped calls; a
         # forced style is a DECODE-kernel selector, prefill always uses deq
-        # (callers labeling results must report per-m paths, see bench.py)
+        # (callers labeling results must report per-m paths)
         style = "deq"
     if style == "blockdot":
         tk_o = BLOCKDOT_TK if (

@@ -311,7 +311,7 @@ class Scheduler:
         # joining prompt BETWEEN decode chunks instead of running the whole
         # chunked prefill synchronously — a 2 Ki-token admission no longer
         # stalls every decoding slot for its full prefill. False = legacy
-        # synchronous admission (the A/B baseline, experiments/abench.py).
+        # synchronous admission.
         self.admit_interleave = admit_interleave
         # pacing (VERDICT r4 weak #3: fixed 1-chunk pacing cost joiners 5-6x
         # TTFT on slow chunks): each admission visit keeps pumping prefill
@@ -414,17 +414,12 @@ class Scheduler:
         # per-state totals partition loop wall time by construction), and
         # the aggregator joins sliding-window TTFT/ITL/e2e quantiles, SLO
         # burn/attainment accounting (--slo-ttft-ms / --slo-itl-ms), and
-        # roofline/goodput attribution of consumed decode chunks priced by
-        # the engine's cost model. Both feed GET /debug/perf and /metrics.
+        # goodput vs throughput. Both feed GET /debug/perf and /metrics.
         self.ledger = perf.TimeLedger(counter=ins.SCHEDULER_TIME)
-        cost_model = (engine.chunk_cost_model()
-                      if hasattr(engine, "chunk_cost_model") else None)
         self.perf = perf.PerfAggregator(
             slo=perf.SloPolicy(
                 None if slo_ttft_ms is None else float(slo_ttft_ms),
-                None if slo_itl_ms is None else float(slo_itl_ms)),
-            cost_model=cost_model,
-            peak_gbs=perf.peak_hbm_gbs(jax.devices()[0].device_kind))
+                None if slo_itl_ms is None else float(slo_itl_ms)))
         # ---- hybrid chunked prefill (ISSUE 12, --prefill-budget): when a
         # request is admitting WHILE others decode, each device chunk is a
         # FUSED hybrid step (engine.hybrid_dispatch) that co-processes up
@@ -820,11 +815,9 @@ class Scheduler:
         self._t_dec_end = None
         self._t_consumed = None
         # fresh sliding windows too: warmup-compile latencies must not sit
-        # in the p95 for the next minute of a bench leg (same policy and
-        # cost model; attribute swap is atomic for concurrent scrapes)
-        self.perf = perf.PerfAggregator(slo=self.perf.slo,
-                                        cost_model=self.perf.cost_model,
-                                        peak_gbs=self.perf.peak_gbs)
+        # in the p95 for the next minute of a measured window (same policy;
+        # attribute swap is atomic for concurrent scrapes)
+        self.perf = perf.PerfAggregator(slo=self.perf.slo)
 
     def cancel(self, req: Request, reason: str = "cancelled") -> None:
         """Release a request's slot. `reason` becomes the finish_reason when
@@ -2154,21 +2147,6 @@ class Scheduler:
         toks = self.engine.decode_consume(chunk)  # records decode.device
         self._t_dec_end = self._t_consumed = time.monotonic()
         self.ledger.transition("emit")
-        if chunk.active.any():
-            # roofline/goodput feed: price this chunk's HBM traffic at its
-            # dispatch-time occupancy and mean live-KV horizon against the
-            # exclusive device window decode_consume just measured. For a
-            # spec chunk `n` is the number of verify cycles — each one
-            # weight/KV sweep like a decode step — however many tokens the
-            # cycles emitted (that gap IS the speculation win the goodput
-            # series shows).
-            self.perf.observe_chunk(
-                occupancy=int(chunk.active.sum()),
-                live_rows=float(chunk.start_pos[chunk.active].mean())
-                + (chunk.n + 1) / 2.0,
-                steps=chunk.n,
-                tokens=int(chunk.advance.sum()),
-                device_s=chunk.device_s)
         if tr.enabled:
             tr.span_at("decode.consume", t0, tr.now(), cat="decode",
                        track="scheduler", chunk=chunk.seq, n=chunk.n)

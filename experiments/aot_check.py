@@ -70,7 +70,7 @@ def topology():
             "compile check cannot run; do not treat this as a pass")
 
 
-#: Llama-3.2-1B (bench.py preset `1b`): dim 2048, hidden 8192, 16 layers,
+#: Llama-3.2-1B: dim 2048, hidden 8192, 16 layers,
 #: 32/8 heads — head_size 64, NOT 128 — vocab 128256. The width chip_smoke.py
 #: serves, so the width every case below is sized to unless it says 8b.
 DIM, HIDDEN, N_LAYERS, HQ, HKV, HD, VOCAB = 2048, 8192, 16, 32, 8, 64, 128256

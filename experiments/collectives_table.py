@@ -17,8 +17,7 @@ Two columns, two meanings:
   per chip for ring implementations, the reference's counter semantics.
 
 Usage:  python experiments/collectives_table.py [--smoke] [--out COLLECTIVES.md]
-Writes the markdown table + experiments/collectives.json (consumed by
-bench.py to fill kb_per_token_per_chip when a mesh is active).
+Writes the markdown table + experiments/collectives.json.
 """
 
 import json
@@ -37,13 +36,22 @@ import jax.numpy as jnp
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench import PRESETS
 from dllama_tpu.engine.engine import InferenceEngine
 from dllama_tpu.models.config import LlamaConfig
 from dllama_tpu.models.llama import random_params_fast
 from dllama_tpu.parallel.mesh import MeshConfig, make_mesh
 from dllama_tpu.parallel.sharding import LlamaShardings
 from dllama_tpu.utils.profiling import collective_bytes_per_token
+
+# dims follow the HF configs of the reference's model zoo (launch.py)
+PRESETS = {
+    "tiny": dict(dim=512, hidden_dim=1536, n_layers=4, n_heads=8, n_kv_heads=4,
+                 vocab_size=2048, seq_len=512),
+    "1b": dict(dim=2048, hidden_dim=8192, n_layers=16, n_heads=32, n_kv_heads=8,
+               vocab_size=128256, seq_len=1024),
+    "8b": dict(dim=4096, hidden_dim=14336, n_layers=32, n_heads=32, n_kv_heads=8,
+               vocab_size=128256, seq_len=1024),
+}
 
 
 def measure(cfg: LlamaConfig, mesh_kw: dict, sync: str) -> dict:

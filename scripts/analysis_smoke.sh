@@ -16,12 +16,13 @@ trap 'rm -rf "$tmp"' EXIT
 cp -r dllama_tpu "$tmp/dllama_tpu"
 rm -rf "$tmp"/dllama_tpu/__pycache__ "$tmp"/dllama_tpu/*/__pycache__ \
        "$tmp"/dllama_tpu/*/*/__pycache__ 2>/dev/null || true
-cp README.md bench.py "$tmp/"
+cp README.md "$tmp/"
 mkdir -p "$tmp/experiments" "$tmp/scripts"
-cp experiments/perfdiff.py experiments/aot_check.py "$tmp/experiments/"
+cp experiments/aot_check.py "$tmp/experiments/"
 cp scripts/hybrid_smoke.sh scripts/compile_smoke.sh \
    scripts/analysis_smoke.sh scripts/router_smoke.sh \
-   scripts/failover_smoke.sh scripts/chaos_soak.sh "$tmp/scripts/"
+   scripts/failover_smoke.sh scripts/chaos_soak.sh scripts/fleet_smoke.sh \
+   "$tmp/scripts/"
 
 echo "analysis_smoke: pristine copy must pass"
 python -m dllama_tpu.analysis --root "$tmp"

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # checks.sh — static hygiene gate for CI and pre-commit:
 #
-#   1. `python -m compileall` over the package, tests, and bench — syntax
+#   1. `python -m compileall` over the package, tests and scripts — syntax
 #      errors fail here in milliseconds instead of mid-suite;
 #   2. observability catalog drift check — every metric registered in
 #      dllama_tpu/obs/instruments.py, every span/event name in
@@ -15,9 +15,8 @@
 #      `python -m dllama_tpu.analysis` — jit-dispatch discipline,
 #      device-state writes, single-site catalogs, the steady-state
 #      transfer lint, the static lock-order graph, and the textual
-#      contracts this script used to grep for (paged routes, bench
-#      records, perfdiff rules, the AOT inventory), all with file:line
-#      diagnostics. scripts/analysis_smoke.sh drills that the gate can
+#      contracts this script used to grep for (paged routes, the AOT
+#      inventory), all with file:line diagnostics. scripts/analysis_smoke.sh drills that the gate can
 #      actually fail.
 #
 # Pure host: imports only dllama_tpu.obs/analysis (stdlib-only — no jax,
@@ -25,7 +24,7 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-python -m compileall -q dllama_tpu tests scripts bench.py
+python -m compileall -q dllama_tpu tests scripts
 echo "checks: compileall OK"
 
 python - <<'PY'
@@ -42,32 +41,11 @@ missing = []
 for name in metrics.REGISTRY.names():
     if name not in readme:
         missing.append(f"metric:{name}")
-# the paged-KV pool gauges are load-bearing for capacity operations (ISSUE 5
-# acceptance reads dllama_kv_pages_shared), and the radix prefix-cache
-# series are what scripts/radix_smoke.sh and the bench radix record assert
-# on (ISSUE 9): their REMOVAL from the registry must fail here too, not
-# just their absence from the README
-# ...the speculative-decoding acceptance series are what
-# scripts/spec_smoke.sh and the bench spec_batch record assert on
-# (ISSUE 11): removal from the registry must fail here too
-# ...and the hybrid/preemption series are what scripts/hybrid_smoke.sh and
-# the bench hybrid record assert on (ISSUE 12): removal must fail here too
-# ...and the compile-ledger / transfer series are what
-# scripts/compile_smoke.sh, the bench compile record, and the perfdiff
-# zero-ceilings assert on (ISSUE 13): removal must fail here too
-# ...and the router / aio-front-end series are what
-# scripts/router_smoke.sh, the bench router record, and the test_aio
-# bounded-thread drill assert on (ISSUE 15): removal must fail here too
-# ...and the failover / host-spill-tier series are what
-# scripts/failover_smoke.sh, the chaos mesh, and the test_paged_kv host
-# drills assert on (ISSUE 16): removal must fail here too
-# ...and the clock-offset / federation-scrape series are what
-# scripts/fleet_smoke.sh, the bench fleet_obs record, and the
-# test_fleet_obs merge/federation drills assert on (ISSUE 17): removal
-# must fail here too
-# ...and the scrape-staleness / client-seat SLO series are what the
-# federated /metrics staleness contract and GET /router/fleet
-# reconciliation stand on (ISSUE 19): removal must fail here too
+# series that a smoke script, a drill or the benchmark asserts on (the
+# paged-KV pool gauges, the radix / speculative / hybrid / compile-ledger /
+# router / failover / fleet series, and the launch counters the `capture`
+# block of /debug/perf reads): their REMOVAL from the registry must fail
+# here too, not just their absence from the README
 for name in ("dllama_kv_pages_total", "dllama_kv_pages_used",
              "dllama_kv_pages_shared",
              "dllama_radix_lookups_total", "dllama_radix_hit_tokens_total",
@@ -178,8 +156,7 @@ print(f"checks: catalog drift OK ({len(metrics.REGISTRY.names())} metrics, "
 PY
 
 # everything textual that used to be grep'd here — the paged-route README
-# table (ISSUE 8), the hybrid/compile bench records and perfdiff rules
-# (ISSUES 12/13), the AOT inventory — plus the new invariant rules
-# (ISSUE 14) run as ONE analyzer pass with real file:line diagnostics
+# table (ISSUE 8), the AOT inventory — plus the invariant rules (ISSUE 14)
+# run as ONE analyzer pass with real file:line diagnostics
 python -m dllama_tpu.analysis
 echo "checks: invariant analyzer OK (jit/device-state/catalog/transfer/lock rules + repo gates)"

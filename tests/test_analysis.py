@@ -36,9 +36,9 @@ from dllama_tpu.utils import locks
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(dllama_tpu.__file__)))
 
 #: context-file rules that fire on minimal in-memory projects simply
-#: because bench.py/README/perfdiff aren't part of the fixture
-_CONTEXT_RULES = {"gate-routes", "gate-bench", "gate-perfdiff", "gate-aot",
-                  "gate-scripts", "doc-rules", "doc-ranks", "lock-unranked"}
+#: because README/aot_check.py aren't part of the fixture
+_CONTEXT_RULES = {"gate-routes", "gate-aot", "gate-scripts", "doc-rules",
+                  "doc-ranks", "lock-unranked"}
 
 
 def findings(files: dict, keep_context: bool = False) -> list[Diagnostic]:
@@ -457,12 +457,12 @@ def test_gate_routes_drift_red():
     assert "paged_stale" in msgs      # readme-only: no such route
 
 
-def test_gate_bench_red():
-    diags = [d for d in run(Project({"bench.py": "def bench_other():\n"
-                                     "    pass\n"}))
-             if d.rule == "gate-bench"]
+def test_gate_aot_red():
+    diags = [d for d in run(Project({"experiments/aot_check.py":
+                                     "OPS = ['paged_decode_attention']\n"}))
+             if d.rule == "gate-aot"]
     msgs = " ".join(d.message for d in diags)
-    assert "bench_hybrid" in msgs and "bench_compile" in msgs
+    assert "fused scatter" in msgs and "paged_decode_attention" not in msgs
 
 
 def test_doc_rules_drift_red():

@@ -1,0 +1,296 @@
+"""The surfaces `benchmark/` takes from the program (PERF.md section 3: "a
+refactor keeps them or brings a `benchmark` issue"), held on the CPU: a PR
+that breaks one learns it here, not as a refused run on the chip.
+
+The harness's own code is the reader wherever it can be: its command line
+(`serve_child.serve_argv`), its client (`loadlib.stream_completion`), its
+scraper (`loadlib.prometheus`), its engine drive (`check.engine_side`) and
+its cost file for the `capture` block. One tiny model (the harness's
+rehearsal size, its own writer) and one server serve every case.
+"""
+
+import functools
+import glob
+import json
+import os
+import re
+import threading
+
+import pytest
+
+from benchmark import check, files, loadlib, serve_child
+from benchmark.costs import paged_attention
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+CELL_CONFIGS = sorted(glob.glob(os.path.join(BENCH, "configs", "*.json")))
+#: where the harness names a Prometheus family it reads
+METRIC_READERS = ("metrics", "reducers", "costs", "run.py")
+
+
+def read_json(path):
+    with open(path) as f:
+        return json.load(f)
+
+
+def scanned_metric_families() -> list:
+    """Every `dllama_*` family the harness names, by scanning it (a new
+    metric file adds its own case). A program name on the device plane
+    (`jit_dllama_decode`) and the package's name are not families."""
+    names = set()
+    for entry in METRIC_READERS:
+        path = os.path.join(BENCH, entry)
+        paths = ([path] if os.path.isfile(path) else
+                 glob.glob(os.path.join(path, "*.json"))
+                 + glob.glob(os.path.join(path, "*.py")))
+        for p in paths:
+            with open(p) as f:
+                names.update(re.findall(r"(?<![a-z_])dllama_[a-z_]+", f.read()))
+    return sorted(names - {"dllama_tpu"})
+
+
+# ------------------------------------------------------ the serve flags
+
+
+#: flag -> (argparse dest, the value the harness passes for a configuration)
+SERVE_FLAGS = {
+    "--slots": ("slots", lambda c: int(c["serve"]["slots"])),
+    "--max-seq-len": ("max_seq_len", lambda c: int(c["max_position_embeddings"])),
+    "--page-size": ("page_size", lambda c: int(c["serve"]["page_size"])),
+    "--kv-pages": ("kv_pages", lambda c: int(c["serve"]["kv_pages"])),
+    "--warmup": ("warmup", lambda c: "auto"),
+    "--cache-dtype": ("cache_dtype", lambda c: "f8"),  # the check's control
+    "--port": ("port", lambda c: 9471),
+}
+
+
+@pytest.mark.parametrize("flag", sorted(SERVE_FLAGS))
+def test_serve_flag_parses_as_the_harness_passes_it(flag):
+    from dllama_tpu.cli.main import build_parser
+
+    dest, expected = SERVE_FLAGS[flag]
+    assert CELL_CONFIGS
+    for path in CELL_CONFIGS:
+        config = read_json(path)
+        argv = serve_child.serve_argv(config, "m.m", "t.t", 9471,
+                                      ["--cache-dtype", "f8"])
+        assert flag in argv, (flag, path)
+        args = build_parser().parse_args(argv)
+        assert args.mode == "serve"
+        assert getattr(args, dest) == expected(config), (flag, path)
+
+
+# ------------------------------------------- one tiny model, one server
+
+
+@pytest.fixture(scope="module")
+def tiny(tmp_path_factory):
+    """The harness's rehearsal configuration, written by its own writer and
+    loaded as the CLI loads it for `serve_argv`'s flags."""
+    import jax.numpy as jnp
+
+    from dllama_tpu.cli.main import build_parser
+    from dllama_tpu.engine.loader import load_model
+
+    config = read_json(os.path.join(BENCH, "tests", "tiny-llama.json"))
+    out = tmp_path_factory.mktemp("surface")
+    model, tok, _ = files.write_files(config, 3, str(out))
+    args = build_parser().parse_args(
+        serve_child.serve_argv(config, model, tok, 0, []))
+    loaded = load_model(model, tok, max_seq_len=args.max_seq_len, mesh=None,
+                        cache_dtype=jnp.bfloat16)
+    return {"config": config, "args": args, "loaded": loaded, "dir": str(out)}
+
+
+@pytest.fixture(scope="module")
+def served(tiny):
+    """The server `serve_argv`'s flags build (warm-up left off: it is
+    minutes of CPU compiles and no surface), one streamed completion by the
+    harness's client inside a profiler capture, then every document the
+    harness fetches. The profiler is stubbed: the capture block is counter
+    deltas between its begin and end, whatever the profiler wrote."""
+    from dllama_tpu.serve.api import make_server
+    from dllama_tpu.utils import profiling
+
+    args = tiny["args"]
+    httpd, api = make_server(tiny["loaded"], host="127.0.0.1", port=0,
+                             n_slots=args.slots, kv_layout=args.kv_layout,
+                             page_size=args.page_size, kv_pages=args.kv_pages)
+    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    port = httpd.server_address[1]
+    get = lambda path: loadlib.http_json("127.0.0.1", port, "GET", path)
+    rec = loadlib.Record(
+        shape=loadlib.Shape(prompt="abcdefgh", prompt_tokens=9, max_tokens=6),
+        t_due=0.0)
+    try:
+        docs = {"/health/ready": get("/health/ready")}
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(profiling.jax.profiler, "start_trace",
+                       lambda log_dir: None)
+            mp.setattr(profiling.jax.profiler, "stop_trace", lambda: None)
+            profile = loadlib.http_json(
+                "127.0.0.1", port, "POST", "/debug/profile",
+                {"dir": os.path.join(tiny["dir"], "trace"),
+                 "duration_s": 30.0})
+            try:
+                loadlib.stream_completion("127.0.0.1", port, rec,
+                                          threading.Event())
+            finally:
+                profiling._profiler_end()  # the capture's timer, early
+        docs.update((path, get(path)) for path in
+                    ("/health", "/metrics", "/debug/perf", "/debug/compile",
+                     "/debug/kv"))
+        yield {"docs": docs, "profile": profile, "rec": rec,
+               "metrics": loadlib.prometheus(docs["/metrics"][1])}
+    finally:
+        api.scheduler.shutdown()
+        httpd.shutdown()
+
+
+def _health(s, tiny):
+    st, doc = s["docs"]["/health"]
+    assert st == 200
+    # what run.py's `ready` line quotes
+    assert "kernels" in doc["build"]
+    assert doc["model_params_bytes"] > 0 and doc["kv_cache_bytes"] > 0
+
+
+def _ready(s, tiny):
+    assert s["docs"]["/health/ready"][0] == 200
+
+
+def _metrics(s, tiny):
+    st, text = s["docs"]["/metrics"]
+    assert st == 200 and isinstance(text, str)
+    assert s["metrics"]["dllama_requests_finished_total"] >= 1
+
+
+def _perf(s, tiny):
+    st, doc = s["docs"]["/debug/perf"]
+    assert st == 200
+    cap = doc["capture"]
+    assert set(cap) == {"launches", "slot_steps", "kv_rows", "prefill_rows",
+                        "seconds"}
+    assert cap["seconds"] > 0 and cap["slot_steps"]["advanced"] >= 5
+    # the reader of the block: rows a decode step swept, for its cost file
+    assert paged_attention.rows_per_step(cap, tiny["args"].slots) > 0
+    # what host clocks are right for stays beside it, under its names (the
+    # router federates `roofline`, `slo` and `window`)
+    assert set(doc["roofline"]) == {"throughput_tok_s", "goodput_tok_s"}
+    assert doc["roofline"]["goodput_tok_s"] > 0
+    assert {"window", "slo", "ledger"} <= set(doc)
+
+
+def _compile(s, tiny):
+    st, doc = s["docs"]["/debug/compile"]
+    assert st == 200
+    assert "warmup" in doc
+    assert any(t["compiles"] > 0 for t in doc["totals"].values())
+
+
+def _kv(s, tiny):
+    st, doc = s["docs"]["/debug/kv"]
+    assert st == 200 and doc["audit"]["ok"] is True
+
+
+def _profile(s, tiny):
+    st, doc = s["profile"]
+    assert st == 200
+    assert doc["profiling"]["dir"] == os.path.join(tiny["dir"], "trace")
+
+
+def _completion(s, tiny):
+    rec = s["rec"]
+    assert rec.status == 200 and rec.error is None and rec.done
+    assert rec.finish == "length"
+    # frames carry their token ids (`include_token_ids`): exact counts
+    assert 1 <= sum(k for _, k in rec.events) <= rec.shape.max_tokens
+    assert rec.timings["decode_tokens"] == rec.shape.max_tokens
+    assert rec.timings["queue_wait_ms"] >= 0
+
+
+ENDPOINTS = {"/health": _health, "/health/ready": _ready,
+             "/metrics": _metrics, "/debug/perf": _perf,
+             "/debug/compile": _compile, "/debug/kv": _kv,
+             "POST /debug/profile": _profile, "/v1/completions": _completion}
+
+
+@pytest.mark.parametrize("endpoint", sorted(ENDPOINTS))
+def test_endpoint_answers_what_the_harness_reads(endpoint, served, tiny):
+    ENDPOINTS[endpoint](served, tiny)
+
+
+@pytest.mark.parametrize("family", scanned_metric_families())
+def test_metric_family_the_harness_names_is_exported(family, served):
+    """After one request `/metrics` declares the family and carries it as
+    the harness's own parser reads it (a histogram by its `_sum` / `_count`).
+    Only a labelled counter none of whose series has moved may have no
+    sample: `run.py` reads that absence as 0 (restarts, audit failures)."""
+    declared = re.sub(r"_(sum|count)$", "", family)
+    kind = re.search(rf"^# TYPE {declared} (\w+)$",
+                     served["docs"]["/metrics"][1], re.M)
+    assert kind, f"{declared} is not declared by /metrics"
+    assert family in served["metrics"] or kind.group(1) == "counter"
+
+
+# -------------------------------------- the loaded model and the engine
+
+
+@pytest.mark.parametrize("path", ["config", "engine.params",
+                                  "engine.cache.k.dtype", "engine.seq_len"])
+def test_loaded_model_has_what_the_check_reads(path, tiny):
+    import jax
+
+    value = functools.reduce(getattr, path.split("."), tiny["loaded"])
+    if path == "config":
+        assert value.vocab_size == tiny["config"]["vocab_size"]
+    elif path == "engine.params":
+        assert jax.tree_util.tree_leaves(value)
+    elif path == "engine.cache.k.dtype":
+        assert value == "bfloat16"  # the program's default, no --cache-dtype
+    else:
+        assert value == tiny["args"].max_seq_len
+
+
+@pytest.fixture(scope="module")
+def batch_engine(tiny):
+    """Built as `check.engine_side` builds it."""
+    from dllama_tpu.engine.batch import BatchEngine
+
+    loaded = tiny["loaded"]
+    return BatchEngine(loaded.config, loaded.engine.params,
+                       cache_dtype=loaded.engine.cache.k.dtype,
+                       max_seq_len=loaded.engine.seq_len,
+                       **serve_child.engine_kwargs(tiny["config"]))
+
+
+@pytest.mark.parametrize("name", ["add_begin", "add_step", "add_commit",
+                                  "decode", "release"])
+def test_batch_engine_method_the_check_calls(name, batch_engine):
+    assert callable(getattr(batch_engine, name))
+
+
+def test_batch_engine_names_its_backend(batch_engine):
+    assert batch_engine.backend in ("pallas", "xla")
+
+
+def test_batch_engine_names_its_attention_route(batch_engine, tiny):
+    from dllama_tpu.engine.kernel_select import PAGED_ROUTES
+
+    assert batch_engine.attn_route in PAGED_ROUTES
+    route = f"{batch_engine.backend}/{batch_engine.attn_route}"
+    assert route == tiny["config"]["expect"]["route"]
+
+
+def test_check_drives_the_engine_end_to_end(tiny):
+    """`check.engine_side` itself, short: prefill, commit, decode, release
+    keeping rows, a tail chunk behind them, `Admission.logits` on the way."""
+    config = tiny["config"]
+    prompts, tails = check.sample_prompts(3, config["vocab_size"], [9, 40], 7)
+    got = check.engine_side(tiny["loaded"], serve_child.engine_kwargs(config),
+                            prompts, tails, decode_steps=8)
+    assert got["route"] == config["expect"]["route"]
+    assert got["decoded"].shape == (8, 2)
+    assert [len(s) for s in got["sequences"]] == [9 + 1 + 8 + 7, 40 + 1 + 8 + 7]
+    assert all(r.shape == (config["vocab_size"],)
+               for r in got["prefill_rows"] + got["tail_rows"])

@@ -633,8 +633,8 @@ def test_debug_perf_capture_block_counts_the_captures_launches(
 def test_debug_perf_joins_windows_ledger_roofline(mserver):
     """GET /debug/perf (ISSUE 7): after at least one served request the
     join must show a populated TTFT window with p50/p95/p99, a ledger whose
-    per-state seconds partition loop wall time (within 2%), a priced
-    roofline view for the decode path, SLO accounting against the armed
+    per-state seconds partition loop wall time (within 2%), the
+    throughput/goodput rates, SLO accounting against the armed
     targets, and the process self-metrics — one JSON document, no tracer
     dependency."""
     port, _api, _ = mserver
@@ -660,10 +660,8 @@ def test_debug_perf_joins_windows_ledger_roofline(mserver):
     assert set(led["fractions"]) == set(_perf.LEDGER_STATES)
     assert led["seconds"]["decode_wait"] > 0  # decode actually ran
     roof = doc["roofline"]
-    # CPU is not in obs/perf.PEAK_HBM_GBS: counted, never priced
-    assert roof["window_chunks"] > 0 and roof["bytes"] > 0
-    assert roof["priced"] is False and "bandwidth_attainment" not in roof
-    assert roof["throughput_tok_s"] >= roof["goodput_tok_s"] >= 0
+    # the request met its 2-minute targets: all of its tokens are goodput
+    assert roof["goodput_tok_s"] == roof["throughput_tok_s"] > 0
     slo = doc["slo"]
     assert slo["enabled"] and slo["targets"]["ttft_ms"] == 120_000.0
     assert slo["attainment"] == 1.0  # targets are 2 minutes on purpose
