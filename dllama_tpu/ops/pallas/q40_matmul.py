@@ -24,23 +24,31 @@ Two TPU-specific design points beyond the reference's scheme:
    * ``deq`` (m > 16): classic in-kernel dequant — unpack nibbles, one
      fused multiply per weight, bf16 dot. Dequant cost amortizes over the m
      rows, so prefill is MXU-bound.
-   * ``blockdot`` (m <= 16): decode is HBM/VPU-bound and per-element dequant
-     arithmetic is the bottleneck, so this kernel never builds the dequantized
-     matrix. Nibbles become *exact* signed codes ``q - 8`` via an
-     exponent-trick bitcast (OR into the mantissa of 2^23 where the float ulp
-     is 1, subtract 2^23 + 8 — exact by Sterbenz), the codes are lossless in
-     bf16 (|q-8| <= 8), the MXU computes per-block partial dots
-     y[kb] = x_kb @ codes_kb, and the f32 block scales touch only the tiny
-     [k/32, m, n-tile] partials:  out = sum_kb s[kb] * y[kb].
-     Per-weight VPU work drops to the ~2-op unpack; the scale math is
-     O(m/32) per weight element and the per-element dequant multiply is gone.
+   * ``blockdot`` (m <= 16, bf16 activations): decode streams every weight
+     once for a handful of rows, so what counts is bytes a grid step and
+     vector ops a weight. The kernel never builds the dequantized matrix and
+     never widens a byte: a tile of packed rows is read as 32-bit words
+     (``pltpu.bitcast``), and shift / and / or on whole words leave two bf16
+     codes a word, ``16 + q`` exactly (``_unpack_words``: 1.5 vector ops a
+     vreg of weights, where the byte-wise unpack cost 4.5). The MXU takes
+     128 rows at a time against x masked to each Q40 block's lanes, four
+     blocks stacked along the rows (``_group_dot``), which gives the
+     per-block partial dots y[b] = x_b @ codes_b; the f32 block scales meet
+     only those partials, out = sum_b s[b] * y[b], and the codes' constant
+     offset leaves as 24 * sum_b s[b] * xsum[b], a small exact dot a grid
+     step. x stays in VMEM for the whole call; the kernel lays it out in its
+     row order itself, at its first grid step (``_layout_constants``: no XLA
+     op prepares anything); tiles come from the weight's shape by a cost of
+     bytes, grid steps and loop passes (``_blockdot_tiles``): a grid step
+     under 256 KB of packed bytes costs more than it moves (PR 32 measured
+     64 KB steps at 22% of the byte roofline).
 
 Layout (see ops/quant.QTensor): ``packed: u8[(L,) k/2, n]`` where packed row
 ``16*b + j`` holds codes for input dims ``32*b + j`` (low nibble) and
 ``32*b + j + 16`` (high nibble); ``scales: f16[(L,) k/32, n]`` (streamed as
 raw u16 bits, widened in-register by ``_scales_f32``).
 
-Grid is (m_tiles, n_tiles, k_tiles) with k innermost: the f32 accumulator
+Grid is ((m_tiles,) n_tiles, k_tiles) with k innermost: the f32 accumulator
 block stays VMEM-resident across the k sweep and is written back once per
 (m, n) tile. Inputs are double-buffered by the Pallas pipeline automatically.
 """
@@ -65,22 +73,11 @@ from dllama_tpu.ops.quant import Q_BLOCK, QTensor
 _EXP_BITS = 0x4B000000
 _V_OFFSET = 8388608.0 + 8.0
 
-# kernel-style override for benchmarks:
-# 'auto' | 'deq' | 'blockdot' | 'maskdot' | 'loopdot'
-# ('maskdot' = blockdot's math with the per-block partial dots expressed as
-# ONE plain dot on a block-masked activation matrix — a fallback in case
-# Mosaic rejects the batched dot_general; MXU does nb x redundant zero MACs,
-# irrelevant while decode is HBM/VPU-bound. 'loopdot' = the same math as a
-# STATICALLY UNROLLED sequence of plain [m,32]x[32,tn] dots — no batched
-# dot_general, no masking, no redundant MACs; the most lowering-conservative
-# fallback, at the cost of nb tiny MXU launches per grid step.)
+# kernel-style override for the chip benches (experiments/kbench.py,
+# q40_decode_bench.py): 'auto' | 'deq' | 'blockdot'. 'auto' is what serves:
+# blockdot for m <= 16, deq above; a forced 'blockdot' still applies only to
+# decode-shaped calls.
 STYLE = "auto"
-
-# decode-kernel tile overrides for on-hardware autotuning (experiments/
-# kbench.py sweeps these): None = the pick_tile defaults. tk/tn must divide
-# the op's k/n; out-of-range overrides fall back to the default pick.
-BLOCKDOT_TK: int | None = None
-BLOCKDOT_TN: int | None = None
 
 
 def _unpack_codes(packed_block, tk: int, tn: int):
@@ -94,6 +91,25 @@ def _unpack_codes(packed_block, tk: int, tn: int):
         [lo.reshape(nb, half, tn), hi.reshape(nb, half, tn)], axis=1
     )
     return jax.lax.bitcast_convert_type(codes, jnp.float32) - _V_OFFSET
+
+
+# bf16 16.0 in both halves of a 32-bit word; the float ulp at [16, 32) is 1/8,
+# so a nibble q placed at mantissa bits 3..6 reads 16 + q exactly
+_W_EXP = 0x41804180
+_W_MASK = 0x00780078
+_W_OFFSET = 24.0  # (16 + q) - 24 = q - 8
+
+
+def _unpack_words(w):
+    """u32[r, tn] words of four packed bytes -> four bf16[2r, tn] arrays of
+    exact codes 16 + q, whole words at a time (shift, and, or: 12 vector ops
+    a word of eight weights, no widening of a byte). Word row s holds packed
+    rows 4s..4s+3 (`pltpu.bitcast` of the u8 tile); array i takes from it the
+    low (i even) or high (i odd) nibble of bytes i // 2 and i // 2 + 2, which
+    land in bf16 rows 2s and 2s + 1: `_position` is that map."""
+    mask, exp = jnp.uint32(_W_MASK), jnp.uint32(_W_EXP)
+    words = (w << 3, w >> 1, w >> 5, w >> 9)
+    return [pltpu.bitcast((v & mask) | exp, jnp.bfloat16) for v in words]
 
 
 # 2^112: shifts an f16 exponent (bias 15) into the f32 field (bias 127) after
@@ -140,30 +156,156 @@ def _deq_kernel(layer_ref, x_ref, packed_ref, scales_ref, out_ref, acc_ref, *, t
         out_ref[:] = acc_ref[:]
 
 
+#: what the kernel walks k by: eight Q40 blocks, one f32 tile of their scales
+_SUB_K = 256
+#: rows the MXU takes a pass: four Q40 blocks, whose codes `_unpack_words`
+#: leaves in the order `_position` gives
+_GROUP = 128
+#: groups whose block sums share one 128-lane row (a block a lane): 4096 k rows
+_CHUNK = 32
+
+
+def _position(src):
+    """Where input dim `src` of a 128-row group lands among the rows
+    `_unpack_words` unpacks it to: arrays i = 2*i1 + nib of 32 rows each,
+    in them block b, packed row pair jh, half h (a permutation of the bits of
+    the index: src = 32*b + 16*nib + 4*jh + 2*h + i1)."""
+    i1, h, jh, nib, b = src & 1, (src >> 1) & 1, (src >> 2) & 3, (src >> 4) & 1, src >> 5
+    return 64 * i1 + 32 * nib + 8 * b + 2 * jh + h
+
+
+def _layout_constants(groups: int):
+    """The two 0/1 matrices that lay x out for the kernel, through the MXU
+    (exact: one 1 a column): `place[src, 128*b + p]` moves dim src of a
+    group to position p, on the copy kept for block b alone (x masked to a
+    block's lanes, the four blocks side by side); `sums[g, src, 4*g + b]`
+    adds dim src of group g into lane 4*g + b of its chunk's row of block
+    sums."""
+    src = jax.lax.broadcasted_iota(jnp.int32, (_GROUP, 4 * _GROUP), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (_GROUP, 4 * _GROUP), 1)
+    place = ((col & 127) == _position(src)) & ((col >> 7) == (src >> 5))
+    g = jax.lax.broadcasted_iota(jnp.int32, (groups, _GROUP, _GROUP), 0)
+    src = jax.lax.broadcasted_iota(jnp.int32, (groups, _GROUP, _GROUP), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (groups, _GROUP, _GROUP), 2)
+    sums = lane == 4 * g + (src >> 5)
+    # (selected as f32, then narrowed: a mask has the 32-bit layout)
+    as_bf16 = lambda mask: jnp.where(mask, 1.0, 0.0).astype(jnp.bfloat16)
+    return as_bf16(place), as_bf16(sums)
+
+
+def _group_dot(xa, codes):
+    """[g, 4m, 128] x [g, 128, lanes] -> f32[g, 4m, lanes], a 128-row group
+    a batch: rows b*m.. of a group are its block b's partial dot, since `xa`
+    holds x on block b's lanes there and 0 elsewhere."""
+    return jax.lax.dot_general(xa, codes, (((2,), (1,)), ((0,), (0,))),
+                               preferred_element_type=jnp.float32)
+
+
+def _scaled(y, sb):
+    """sum_b y[b] * sb[b] over the blocks of a pass: the f32 scales meet the
+    partial dots (y [blocks, m, lanes], sb [blocks, lanes])."""
+    return (y * sb[:, None, :]).sum(axis=0)
+
+
+def _bf16_parts(v, n: int):
+    """f32 -> n f32 arrays that each fit bf16 (the top 16 bits of what is
+    left, cut by a mask: a rounding convert and its way back is a pair the
+    compiler may drop) and sum to v exactly once n x 8 bits cover it: 3 for
+    any f32, 2 for an f16's 11 bits."""
+    parts = []
+    for _ in range(n - 1):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32) & jnp.uint32(0xFFFF0000)
+        parts.append(jax.lax.bitcast_convert_type(bits, jnp.float32))
+        v = v - parts[-1]
+    return parts + [v]
+
+
 def _blockdot_kernel(
-    layer_ref, xb_ref, packed_ref, scales_ref, out_ref, acc_ref, *, tk, tn
+    layer_ref, x_ref, packed_ref, scales_ref, out_ref, xa_ref, xs_ref, s_ref,
+    *, tk, tn, lanes, rows
 ):
     del layer_ref
-    kb = pl.program_id(1)
+    j, kb = pl.program_id(0), pl.program_id(1)
+    m = out_ref.shape[0]
+    nb = tk // Q_BLOCK
+    per_chunk = min(_CHUNK, xa_ref.shape[0])  # groups a chunk
+
+    @pl.when((j == 0) & (kb == 0))
+    def _():
+        # Once a call, for every grid step to read: x in the kernel's row
+        # order and masked to each Q40 block's lanes, the four blocks stacked
+        # along rows (one 128-deep pass then gives the four blocks' partial
+        # dots), and x's block sums as three bf16 parts (and a zero one, to
+        # whole tiles) for the codes' offset. Both through the MXU, 4096 k
+        # rows a pass: no XLA op prepares anything for this call.
+        place, sums = _layout_constants(per_chunk)
+
+        def chunk(c, carry):
+            base = pl.multiple_of(c * (per_chunk * _GROUP), per_chunk * _GROUP)
+            xc = jnp.concatenate(
+                [x_ref[:, pl.ds(base + g * _GROUP, _GROUP)] for g in range(per_chunk)],
+                axis=0)  # [groups * m, 128], a group's m rows together
+            z = jnp.dot(xc, place, preferred_element_type=jnp.float32)
+            xa = jnp.concatenate(
+                [z[:, _GROUP * b:_GROUP * (b + 1)].reshape(per_chunk, m, _GROUP)
+                 for b in range(4)], axis=1)  # [groups, 4m, 128]
+            xa_ref[pl.ds(c * per_chunk, per_chunk)] = xa.astype(xa_ref.dtype)
+            xsum = _group_dot(xc.reshape(per_chunk, m, _GROUP), sums).sum(axis=0)
+            parts = _bf16_parts(xsum, 3) + [jnp.zeros_like(xsum)]
+            xs_ref[c] = jnp.concatenate(parts, axis=0).astype(xs_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, xs_ref.shape[0], chunk, 0)
 
     @pl.when(kb == 0)
     def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        out_ref[:] = jnp.zeros_like(out_ref)
 
-    # codes q-8 are EXACT in the activation dtype (|q-8| <= 8, integral —
-    # lossless even in bf16), so the MXU block-dot on raw codes is exact; the
-    # f32 scales touch only the [nb, m, tn] partials — per-weight VPU work is
-    # just the unpack, no per-element dequant multiply.
-    c = _unpack_codes(packed_ref[:], tk, tn).astype(xb_ref.dtype)  # [nb, 32, tn]
-    y = jax.lax.dot_general(
-        xb_ref[:], c, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )  # [nb, m, tn]
-    s = _scales_f32(scales_ref[:])[:, None, :]  # [nb, 1, tn]
-    acc_ref[:] += jnp.sum(y * s, axis=0)
+    if s_ref.shape[0] != nb:  # rows past the tile's blocks meet zero sums
+        s_ref[:] = jnp.zeros_like(s_ref)
+    s_ref[0:nb, :] = _scales_f32(scales_ref[:])
 
-    @pl.when(kb == pl.num_programs(1) - 1)
-    def _():
-        out_ref[:] = acc_ref[:]
+    def lane_step(c, carry):
+        l0 = pl.multiple_of(c * lanes, lanes)
+
+        def sweep(at, rows, acc):
+            """`rows` k rows from row `at(1)` of the tile, as whole-array
+            ops: a loop pass waits for its MXU results once (0.15 us), so it
+            is made as long as `_inner` allows and nothing is unrolled by
+            hand. `at(d)` is that row over d: the offsets of the packed rows
+            (2), the scales (32) and the groups (128), whole tiles each."""
+            groups, blocks = rows // _GROUP, rows // Q_BLOCK
+            w = pltpu.bitcast(packed_ref[pl.ds(at(2), rows // 2), pl.ds(l0, lanes)],
+                              jnp.uint32)  # [rows / 8, lanes]
+            # the pass's 128-row groups, one batch each of the dot
+            codes = jnp.concatenate(
+                [t.reshape(groups, 32, lanes) for t in _unpack_words(w)], axis=1)
+            xa = xa_ref[pl.ds(kb * (tk // _GROUP) + at(_GROUP), groups)]
+            sb = s_ref[pl.ds(at(Q_BLOCK), blocks), pl.ds(l0, lanes)]
+            y = _group_dot(xa, codes)
+            return acc + _scaled(y.reshape(blocks, m, lanes), sb)
+
+        acc = jax.lax.fori_loop(
+            0, tk // rows,
+            lambda i, acc: sweep(lambda d: pl.multiple_of(i * (rows // d), _SUB_K // d),
+                                 rows, acc),
+            jnp.zeros((m, lanes), jnp.float32))
+        if tk % rows:  # what the whole passes leave of a k like 43 x 256
+            acc = sweep(lambda d: tk // rows * rows // d, tk % rows, acc)
+        # the codes read 16 + q: take 24 * sum_b xsum[b] * s[b] off, exactly
+        # (an f16 scale is two bf16 parts, a block sum three; f32 sums), a
+        # chunk of 128 blocks a dot
+        chunks = s_ref.shape[0] // _GROUP
+        off = sum(jnp.dot(xs_ref[kb * chunks + c],
+                          part[c * _GROUP:(c + 1) * _GROUP].astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+                  for part in _bf16_parts(s_ref[:, pl.ds(l0, lanes)], 2)
+                  for c in range(chunks))
+        off = off[0:m] + off[m:2 * m] + off[2 * m:3 * m]
+        out_ref[:, pl.ds(l0, lanes)] += acc - _W_OFFSET * off
+        return carry
+
+    jax.lax.fori_loop(0, tn // lanes, lane_step, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -209,171 +351,115 @@ def _deq_call(layer, x, packed, scales, *, interpret: bool = False):
     )(layer, x, packed, scales)
 
 
-def _maskdot_kernel(
-    layer_ref, x_ref, packed_ref, scales_ref, out_ref, acc_ref, *, tk, tn
-):
-    del layer_ref
-    kb = pl.program_id(1)
-
-    @pl.when(kb == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    m = x_ref.shape[0]
-    nb = tk // Q_BLOCK
-    w = _unpack_codes(packed_ref[:], tk, tn).astype(x_ref.dtype).reshape(tk, tn)
-    # x replicated per block row, masked to that block's 32 lanes: one big dot
-    # then computes every per-block partial y[b] = x_b @ codes_b at once
-    lane = jax.lax.broadcasted_iota(jnp.int32, (nb, m, tk), 2)
-    blk = jax.lax.broadcasted_iota(jnp.int32, (nb, m, tk), 0)
-    xaug = jnp.where(lane // Q_BLOCK == blk, x_ref[:][None], 0).reshape(nb * m, tk)
-    y = jnp.dot(xaug, w, preferred_element_type=jnp.float32).reshape(nb, m, tn)
-    acc_ref[:] += jnp.sum(y * _scales_f32(scales_ref[:])[:, None, :], axis=0)
-
-    @pl.when(kb == pl.num_programs(1) - 1)
-    def _():
-        out_ref[:] = acc_ref[:]
+#: What the chip charges beyond a call's bytes, in KB of HBM time at the 600
+#: GB/s a weight stream sustains (fitted to `experiments/kbench.py q40`'s
+#: tile sweep, my chip runs, PR 32): a grid step 0.17 us, a pass of the
+#: kernel's inner loop (whose MXU results it waits for) 0.15 us, and the
+#: first tile's copy, which hides behind nothing.
+_STEP_KB, _PASS_KB, _FIRST_TILE = 100, 90, 0.65
+#: weights one pass of the inner loop covers (what hides its latency)
+_PASS_WEIGHTS = 1 << 21
+#: packed bytes a grid step moves at least where the weight allows it, and
+#: what a step's buffers may take of VMEM
+_STEP_FLOOR, _VMEM_BUDGET = 256 * 1024, 24 * 1024 * 1024
 
 
-def _loopdot_kernel(
-    layer_ref, xb_ref, packed_ref, scales_ref, out_ref, acc_ref, *, tk, tn
-):
-    del layer_ref
-    kb = pl.program_id(1)
-
-    @pl.when(kb == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    # blockdot's exact math (codes q-8 lossless in the activation dtype, f32
-    # scales applied to the per-block partials) with the nb-batched dot
-    # unrolled into nb PLAIN dots at static indices — nothing here that a
-    # Mosaic build supporting jnp.dot can reject
-    c = _unpack_codes(packed_ref[:], tk, tn).astype(xb_ref.dtype)  # [nb, 32, tn]
-    s = _scales_f32(scales_ref[:])  # [nb, tn]
-    acc = acc_ref[:]
-    for b in range(tk // Q_BLOCK):  # static unroll
-        y = jnp.dot(xb_ref[b], c[b], preferred_element_type=jnp.float32)
-        acc = acc + y * s[b][None, :]
-    acc_ref[:] = acc
-
-    @pl.when(kb == pl.num_programs(1) - 1)
-    def _():
-        out_ref[:] = acc_ref[:]
+def _inner(tk: int, tn: int) -> tuple[int, int]:
+    """(lanes, rows) of a pass of the kernel's inner loops for a tile: the
+    widest of 512 / 256 / 128 lanes dividing tn, and the k rows (whole loop
+    steps of 256) that make the pass cover `_PASS_WEIGHTS`."""
+    lanes = next(w for w in (512, 256, 128) if tn % w == 0)
+    return lanes, min(tk, _PASS_WEIGHTS // lanes // _SUB_K * _SUB_K)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _loopdot_call(layer, x, packed, scales, *, interpret: bool = False):
-    """blockdot fallback #2: same math, statically-unrolled plain dots. Small
-    tk keeps the unroll count (tk/32 dots per grid step) bounded."""
+@functools.lru_cache(maxsize=None)
+def _blockdot_tiles(k: int, n: int) -> tuple[int, int]:
+    """(tk, tn) for the m <= 16 kernel from the weight's shape alone: the
+    tile that costs least by the figures above, among tn a multiple of 128
+    dividing n and tk the whole of k or whole chunks of 4096 dividing it (a
+    tile's block sums are then whole rows, its scales whole u16 tiles), within
+    the VMEM budget, no tile under `_STEP_FLOOR` and no weight of two floors
+    or more in one step, where its divisors leave another choice."""
+    best = None
+    for tn in (t for t in range(128, n + 1, 128) if n % t == 0):
+        for tk in [k] + [t for t in range(_CHUNK * _GROUP, k, _CHUNK * _GROUP) if k % t == 0]:
+            nb = tk // Q_BLOCK
+            # two buffers of packed rows and u16 scales, the scales as f32
+            vmem = 2 * (tk * tn // 2 + nb * tn * 2) + -(-nb // _GROUP) * _GROUP * tn * 4
+            if vmem > _VMEM_BUDGET:
+                continue
+            steps = (k // tk) * (n // tn)
+            # kept for a weight whose divisors leave nothing else: a tile
+            # under the floor, one step that overlaps no copy with any work
+            odd = (tk * tn // 2 < min(_STEP_FLOOR, k * n // 4)
+                   or steps == 1 and k * n // 2 >= 2 * _STEP_FLOOR)
+            lanes, rows = _inner(tk, tn)
+            passes = steps * (tn // lanes) * -(-tk // rows)
+            cost = (steps * _STEP_KB + passes * _PASS_KB
+                    + _FIRST_TILE * tk * tn / 2048)
+            if best is None or (odd, cost, -tk) < best[0]:
+                best = ((odd, cost, -tk), tk, tn)
+    return best[1], best[2]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "tk", "tn", "lanes", "rows"))
+def _blockdot_call(layer, x, packed, scales, *, interpret: bool = False,
+                   tk: int | None = None, tn: int | None = None,
+                   lanes: int | None = None, rows: int | None = None):
+    """Decode-shaped path: bf16 x[m<=16, k] against stacked Q40 weights ->
+    f32[m, n]. tk / tn / lanes / rows are the chip sweep's overrides
+    (`experiments/kbench.py q40`); serving passes none and runs
+    `_blockdot_tiles`."""
     m, k = x.shape
     n = packed.shape[-1]
-    nb = k // Q_BLOCK
-    tn = _pick_tile(n, (512, 256, 128))
-    tk = _pick_tile(k, (256, 128, 64, 32))
-    grid = (n // tn, k // tk)
-    xb = x.reshape(m, nb, Q_BLOCK).transpose(1, 0, 2)
+    dtk, dtn = _blockdot_tiles(k, n)
+    tk, tn = tk or dtk, tn or dtn
+    assert m % 16 == 0 and k % _SUB_K == 0 and (tk == k or tk % (_CHUNK * _GROUP) == 0)
+    nb = tk // Q_BLOCK
+    nbp = -(-nb // _GROUP) * _GROUP  # the tile's blocks, in whole chunks
+    dlanes, drows = _inner(tk, tn)
+    lanes, rows = lanes or dlanes, rows or drows
+    # x goes in as it is (padded to whole chunks of 4096 dims, where k has a
+    # part chunk): the kernel lays it out itself at its first grid step
+    groups = k // _GROUP
+    per_chunk = min(_CHUNK, groups)
+    chunks = -(-groups // per_chunk)
+    kp = chunks * per_chunk * _GROUP
+    if kp != k:
+        x = jnp.pad(x, ((0, 0), (0, kp - k)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
+        grid=(n // tn, k // tk),
         in_specs=[
-            pl.BlockSpec((tk // Q_BLOCK, m, Q_BLOCK), lambda j, kb, L: (kb, 0, 0)),
+            pl.BlockSpec((m, kp), lambda j, kb, L: (0, 0)),
             pl.BlockSpec((None, tk // 2, tn), lambda j, kb, L: (L[0], kb, j)),
-            pl.BlockSpec((None, tk // Q_BLOCK, tn), lambda j, kb, L: (L[0], kb, j)),
+            pl.BlockSpec((None, nb, tn), lambda j, kb, L: (L[0], kb, j)),
         ],
         out_specs=pl.BlockSpec((m, tn), lambda j, kb, L: (0, j)),
-        scratch_shapes=[pltpu.VMEM((m, tn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((kp // _GROUP, 4 * m, _GROUP), x.dtype),
+                        pltpu.VMEM((chunks, 4 * m, _GROUP), x.dtype),
+                        pltpu.VMEM((nbp, tn), jnp.float32)],
     )
     return pl.pallas_call(
-        functools.partial(_loopdot_kernel, tk=tk, tn=tn),
+        functools.partial(_blockdot_kernel, tk=tk, tn=tn, lanes=lanes, rows=rows),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # the first grid step builds what every later one reads
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # all but a quarter of VMEM is this call's to claim, so XLA's
+            # memory-space assignment cannot park a whole stacked scales
+            # array there (it copied 115 MB a decode step in slices, waits
+            # included: `slice-done`, PERF.md section 6, PR 32)
+            vmem_limit_bytes=96 * 1024 * 1024,
         ),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * n * k,
-            bytes_accessed=m * k * 4 + k * n // 2 + (k // Q_BLOCK) * n * scales.dtype.itemsize + m * n * 4,
-            transcendentals=0,
-        ),
-        interpret=interpret,
-    )(layer, xb, packed, scales)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _maskdot_call(layer, x, packed, scales, *, interpret: bool = False):
-    """blockdot fallback: same math, plain-dot-only lowering (m <= 16)."""
-    m, k = x.shape
-    n = packed.shape[-1]
-    tn = _pick_tile(n, (512, 256, 128))
-    tk = _pick_tile(k, (512, 256, 128, 64, 32))
-    grid = (n // tn, k // tk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((m, tk), lambda j, kb, L: (0, kb)),
-            pl.BlockSpec((None, tk // 2, tn), lambda j, kb, L: (L[0], kb, j)),
-            pl.BlockSpec((None, tk // Q_BLOCK, tn), lambda j, kb, L: (L[0], kb, j)),
-        ],
-        out_specs=pl.BlockSpec((m, tn), lambda j, kb, L: (0, j)),
-        scratch_shapes=[pltpu.VMEM((m, tn), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_maskdot_kernel, tk=tk, tn=tn),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * m * n * k * (tk // Q_BLOCK),  # nb-masked redundant MACs
-            bytes_accessed=m * k * x.dtype.itemsize + k * n // 2 + (k // Q_BLOCK) * n * scales.dtype.itemsize + m * n * 4,
+            bytes_accessed=m * k * 2 + k * n // 2 + (k // Q_BLOCK) * n * scales.dtype.itemsize + m * n * 4,
             transcendentals=0,
         ),
         interpret=interpret,
     )(layer, x, packed, scales)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "tk", "tn"))
-def _blockdot_call(layer, x, packed, scales, *, interpret: bool = False,
-                   tk: int | None = None, tn: int | None = None):
-    """Decode-shaped path: x[m<=16, k] against stacked Q40 weights.
-    tk/tn are static tile overrides (from the module knobs, validated by the
-    dispatcher) — part of the jit key so an autotune sweep actually recompiles."""
-    m, k = x.shape
-    n = packed.shape[-1]
-    nb = k // Q_BLOCK
-    tn = tn or _pick_tile(n, (512, 256, 128))
-    tk = tk or _pick_tile(k, (2048, 1024, 512, 256, 128, 64, 32))
-    grid = (n // tn, k // tk)
-    # pre-shaped outside the kernel: Mosaic can't split the lane dim in-kernel
-    xb = x.reshape(m, nb, Q_BLOCK).transpose(1, 0, 2)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tk // Q_BLOCK, m, Q_BLOCK), lambda j, kb, L: (kb, 0, 0)),
-            pl.BlockSpec((None, tk // 2, tn), lambda j, kb, L: (L[0], kb, j)),
-            pl.BlockSpec((None, tk // Q_BLOCK, tn), lambda j, kb, L: (L[0], kb, j)),
-        ],
-        out_specs=pl.BlockSpec((m, tn), lambda j, kb, L: (0, j)),
-        scratch_shapes=[pltpu.VMEM((m, tn), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_blockdot_kernel, tk=tk, tn=tn),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * m * n * k,
-            bytes_accessed=m * k * 4 + k * n // 2 + (k // Q_BLOCK) * n * scales.dtype.itemsize + m * n * 4,
-            transcendentals=0,
-        ),
-        interpret=interpret,
-    )(layer, xb, packed, scales)
 
 
 def supported(x_shape: tuple[int, ...], w: QTensor) -> bool:
@@ -406,34 +492,21 @@ def q40_matmul(
         assert layer is not None, "stacked QTensor needs a layer index"
     n = packed.shape[-1]
     if scales.dtype == jnp.float16:
-        # kernels take raw u16 bits (see _scales_f32); the bitcast is free
+        # kernels take raw u16 bits (see _scales_f32; Mosaic takes no f16
+        # argument); the bitcast is free
         scales = jax.lax.bitcast_convert_type(scales, jnp.uint16)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     x2 = x.reshape(m, k)
-    # pad rows up to the f32 sublane (8) so tiny decode batches still tile
-    pad = (-m) % 8
+    # the block-dot kernel carries its codes in bf16, walks k by eight blocks
+    # and takes 16 rows (a whole bf16 tile): other activations, depths and
+    # batches take the dequantising tier, padded to the f32 sublane (8)
+    blockdot = (STYLE != "deq" and m <= 16 and k % _SUB_K == 0
+                and x2.dtype == jnp.bfloat16)
+    pad = (-m) % (16 if blockdot else 8)
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-    mp = m + pad
-    style = STYLE
-    if style == "auto":
-        style = "blockdot" if mp <= 16 else "deq"
-    elif style in ("blockdot", "maskdot", "loopdot") and mp > 16:
-        # forced decode-shaped styles apply only to decode-shaped calls; a
-        # forced style is a DECODE-kernel selector, prefill always uses deq
-        # (callers labeling results must report per-m paths)
-        style = "deq"
-    if style == "blockdot":
-        tk_o = BLOCKDOT_TK if (
-            BLOCKDOT_TK and k % BLOCKDOT_TK == 0 and BLOCKDOT_TK % Q_BLOCK == 0
-        ) else None
-        tn_o = BLOCKDOT_TN if (BLOCKDOT_TN and n % BLOCKDOT_TN == 0) else None
-        out = _blockdot_call(layer_arr, x2, packed, scales, interpret=interpret,
-                             tk=tk_o, tn=tn_o)
-    elif style == "maskdot":
-        out = _maskdot_call(layer_arr, x2, packed, scales, interpret=interpret)
-    elif style == "loopdot":
-        out = _loopdot_call(layer_arr, x2, packed, scales, interpret=interpret)
+    if blockdot:
+        out = _blockdot_call(layer_arr, x2, packed, scales, interpret=interpret)
     else:
         out = _deq_call(layer_arr, x2, packed, scales, interpret=interpret)
     if pad:

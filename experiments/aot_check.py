@@ -3,7 +3,8 @@
 libtpu is installed locally, and a PJRT topology description lets XLA:TPU
 compile a lowered module for a chip that is described, not attached
 (`jax.experimental.topologies.get_topology_desc`). This script offers every
-Pallas kernel, every decode style, the shard_map'd tensor-parallel paths,
+Pallas kernel (the Q40 block-dot kernel at the benchmark cells' own decode
+shapes too), the shard_map'd tensor-parallel paths,
 the whole InferenceEngine step and the whole BatchEngine serving programs
 (paged decode chunk, hybrid step, prefill chunk, spec-verify chunk) to the
 v5e compiler at Llama-3.2-1B width and records ACCEPT or REJECT per case.
@@ -20,8 +21,8 @@ main() points `ops.matmul.device_platform` at "tpu" for the whole run;
 tests/test_chip_compile.py compiles a subset of `all_cases()` the same way.
 
 Usage: python experiments/aot_check.py [--full] [--md MOSAIC_AOT.md]
-Exit 0 when every production-default case accepts (fallback styles may
-reject — they are insurance, flagged but not fatal).
+Exit 0 when every production-default case accepts (the others — reserve
+kernels, `--full`'s tile overrides — are flagged but not fatal).
 """
 
 import os
@@ -83,23 +84,21 @@ def cases(full: bool):
     widths above; `production` marks kernels whose rejection fails the
     check (the shipped defaults), vs fallback insurance."""
     L = 2
-    sh_w = lambda k, n: (
-        S((L, k // 2, n), jnp.uint8),
-        S((L, k // Q_BLOCK, n), jnp.uint16),
-    )
     layer = S((1,), jnp.int32)
     out = []
 
-    def style_case(name, style, m, k, n, production, tk=None, tn=None):
-        packed, scales = sh_w(k, n)
+    def style_case(name, style, m, k, n, production, layers=L, **tiles):
+        packed, scales = (S((layers, k // 2, n), jnp.uint8),
+                          S((layers, k // Q_BLOCK, n), jnp.uint16))
 
-        def fn(l, x, p, s, style=style, tk=tk, tn=tn):
-            qmod.STYLE, qmod.BLOCKDOT_TK, qmod.BLOCKDOT_TN = style, tk, tn
+        def fn(l, x, p, s, style=style):
+            if tiles:  # the chip sweep's overrides, on the jitted call itself
+                return qmod._blockdot_call(l, x, p, s, **tiles)
+            qmod.STYLE = style
             try:
                 return qmod.q40_matmul(x, QTensor(p, s), l)
             finally:
                 qmod.STYLE = "auto"
-                qmod.BLOCKDOT_TK = qmod.BLOCKDOT_TN = None
 
         out.append((name, fn, (layer, S((m, k), jnp.bfloat16), packed, scales), production))
 
@@ -122,13 +121,18 @@ def cases(full: bool):
                SLOTS * (SPEC_K + 1), DIM, HIDDEN, True)
     flat_case("q40 decode m=8 wcls8b(4096x128256)", 8, 4096, 128256)
     flat_case("q40 prefill m=256 wcls8b(4096x128256)", 256, 4096, 128256)
-    style_case("maskdot m=8 w1", "maskdot", 8, DIM, HIDDEN, False)
-    style_case("loopdot m=8 w1", "loopdot", 8, DIM, HIDDEN, False)
+    # the m <= 16 kernel at the benchmark cells' own decode shapes (PERF.md
+    # section 4): each takes another tile from `_blockdot_tiles`
+    for tag, m, k, n, layers in (
+            ("deepseek wq", 16, 4096, 4096, 30), ("deepseek w1", 16, 4096, 11008, 30),
+            ("deepseek w2", 16, 11008, 4096, 30), ("deepseek head", 16, 4096, 102400, 1),
+            ("granite head", 8, 2048, 100352, 1), ("granite in_proj", 8, 2048, 8576, 40)):
+        style_case(f"q40 decode m={m} {tag}({k}x{n})", "auto", m, k, n, True,
+                   layers=layers)
     if full:
-        for tk in (512, 1024, 2048):
-            for tn in (128, 256, 512):
-                style_case(f"blockdot tiles tk={tk} tn={tn}", "blockdot",
-                           8, DIM, HIDDEN, False, tk=tk, tn=tn)
+        for tn in (128, 256, 512, 1024, 2048):
+            style_case(f"blockdot tiles tk={DIM} tn={tn}", "blockdot",
+                       16, DIM, HIDDEN, False, tk=DIM, tn=tn)
 
     # q80 fused matmuls (packed int8 weights, the Q80-file fast path): the
     # same decode/prefill split as q40, production on unsharded engines

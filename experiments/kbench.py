@@ -2,26 +2,32 @@
 
 Usage: python experiments/kbench.py suite
        python experiments/kbench.py paged
+       python experiments/kbench.py q40 [--no-tiles]
        python experiments/kbench.py M SHAPE [variant ...]
-'suite' benches the decode variants (m=8 on w1/wcls), the prefill tier
-comparison (m=256/512: in-kernel deq vs XLA dequant-dot), and a blockdot
-(tk, tn) tile autotune, all in one process.
+'suite' benches the decode variants (m=8 on w1/wcls) and the prefill tier
+comparison (m=256/512: in-kernel deq vs XLA dequant-dot) in one process.
 'paged' times the paged flash-decode kernel alone, as a decode step of each
 benchmark cell calls it (300 calls on the layer-stacked pool, pools threaded),
 against what the HBM would take for the rows it needs.
-'suite --smoke' (and 'paged --smoke') runs the same code path on CPU (interpret-mode Pallas, tiny
+'q40' times the block-dot Q40 kernel (m <= 16) alone at each cell's real
+shapes at its 16 rows, 300 calls in one scan with the layer cycling: parity,
+the kernel as it is and with each part taken out, the (tk, tn) tile sweep
+(--no-tiles leaves it out) and the inner loops' (lanes, rows a pass) sweep.
+'suite --smoke' (and 'paged --smoke', 'q40 --smoke') runs the same code path on CPU (interpret-mode Pallas, tiny
 shapes, 2 iters) so CI proves the harness cannot crash on the chip; smoke
 numbers are meaningless, only completion matters.
   variants: A  production dispatch (q40_matmul auto: blockdot for m<=16, deq above)
             DQ forced deq-style kernel      BD forced blockdot kernel
-            MD forced maskdot fallback      LD forced loopdot fallback
             B  legacy fma-f32 kernel        D  bf16-weights roofline reference
             E  XLA dequantize-then-dot
 Measures achieved HBM GB/s (packed+scales bytes) on 1B-preset shapes.
 """
 import functools
+import os
 import sys
 import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
@@ -145,17 +151,16 @@ def make_inputs(m, label):
     return w, x, qbytes
 
 
-def dispatch_closure(w, style, tk=None, tn=None):
-    """Production-dispatch closure with forced style (+ optional blockdot tile
-    overrides); a FRESH closure per combo so each re-traces its static args."""
+def dispatch_closure(w, style):
+    """Production-dispatch closure with a forced style; a FRESH closure per
+    row so each re-traces under its own style."""
 
-    def prod(x, w=w, style=style, tk=tk, tn=tn):
-        qmod.STYLE, qmod.BLOCKDOT_TK, qmod.BLOCKDOT_TN = style, tk, tn
+    def prod(x, w=w, style=style):
+        qmod.STYLE = style
         try:
             return qmod.q40_matmul(x, w, interpret=INTERPRET)
         finally:
             qmod.STYLE = "auto"
-            qmod.BLOCKDOT_TK = qmod.BLOCKDOT_TN = None
 
     return prod
 
@@ -165,15 +170,13 @@ def run_one(m, label, variants):
     w, x, qbytes = make_inputs(m, label)
     rows = []
     for v in variants:
-        # per-variant isolation: one Mosaic rejection (MD exists because the
-        # batched dot_general might not lower) must not eat the row's other
-        # timings in a one-shot TPU window
+        # per-variant isolation: one Mosaic rejection must not eat the
+        # row's other timings in a one-shot TPU window
         try:
-            if v in ("A", "DQ", "BD", "MD", "LD"):
-                # NOTE: forced decode styles (BD/MD/LD) apply only when m <= 16;
-                # larger m silently uses deq (the dispatcher's prefill rule)
-                style = {"A": "auto", "DQ": "deq", "BD": "blockdot",
-                         "MD": "maskdot", "LD": "loopdot"}[v]
+            if v in ("A", "DQ", "BD"):
+                # NOTE: a forced blockdot applies only when m <= 16; larger m
+                # silently uses deq (the dispatcher's prefill rule)
+                style = {"A": "auto", "DQ": "deq", "BD": "blockdot"}[v]
                 t = bench(dispatch_closure(w, style), (x,))
                 rows.append((f"{v} {style}", t, qbytes))
             elif v == "B":
@@ -221,21 +224,18 @@ def run_one(m, label, variants):
 SUITE = [
     # decode shapes: the production dispatch + each forced style + rooflines
     # (+ Q8: the fused Q80-weight path at the same shape)
-    (8, "w1", ["A", "BD", "MD", "LD", "DQ", "D", "E", "Q8"]),
+    (8, "w1", ["A", "BD", "DQ", "D", "E", "Q8"]),
     (8, "wcls", ["A", "D", "E"]),  # the lm head is ~18% of 1B weight bytes
     # prefill shapes: in-kernel deq vs the XLA dequant-dot the MXU loves
     (256, "w1", ["DQ", "D", "E", "Q8"]),
     (512, "w1", ["DQ", "D", "E"]),
 ]
 
-SWEEP_TK = (512, 1024, 2048)
-SWEEP_TN = (128, 256, 512)
-
 
 def enable_smoke():
-    """Same code path, CPU-sized: every SUITE row and the tile sweep run in
+    """Same code path, CPU-sized: every SUITE row and every mode run in
     interpret mode on shapes small enough for CI (seconds, not windows)."""
-    global INTERPRET, ITERS, SHAPES, SUITE, SWEEP_TK, SWEEP_TN
+    global INTERPRET, ITERS, SHAPES, SUITE
     INTERPRET = True
     ITERS = 2
     SHAPES = {
@@ -245,13 +245,16 @@ def enable_smoke():
         "wcls": (128, 512),
     }
     SUITE = [
-        (8, "w1", ["A", "BD", "MD", "LD", "DQ", "B", "D", "E", "Q8"]),
+        (8, "w1", ["A", "BD", "DQ", "B", "D", "E", "Q8"]),
         (8, "wcls", ["A", "D", "E"]),
         (32, "w1", ["DQ", "D", "E", "Q8"]),
     ]
-    SWEEP_TK = (32, 64)
-    SWEEP_TN = (128,)
-    global PAGED_CELLS, PAGED_CALLS
+    global PAGED_CELLS, PAGED_CALLS, Q40_CELLS, Q40_CALLS
+    global Q40_SWEEP_TK, Q40_SWEEP_TN, Q40_SWEEP_LANES, Q40_SWEEP_ROWS
+    Q40_CALLS = 2
+    Q40_CELLS = {"tiny": {"stacked": (8192, 256, 2), "head": (256, 384, 1)}}
+    Q40_SWEEP_TK, Q40_SWEEP_TN, Q40_SWEEP_LANES = (4096, None), (128, -1), (128,)
+    Q40_SWEEP_ROWS = (256, None)
     PAGED_CALLS = 2
     PAGED_CELLS = {
         "tiny mha": dict(slots=2, hq=4, hkv=4, hd=64, page=16, layers=2,
@@ -259,32 +262,6 @@ def enable_smoke():
         "tiny gqa": dict(slots=3, hq=8, hkv=2, hd=64, page=8, layers=2,
                          kv_pages=16, rows=(3, 30)),
     }
-
-
-def sweep_blockdot_tiles(m=8, label="w1"):
-    """Autotune the decode kernel's (tk, tn) on hardware. Each combo prints
-    (flushed) as soon as it's measured — a session timeout mid-sweep keeps
-    everything already benchmarked — and a sorted summary lands at the end."""
-    k, n = SHAPES[label]
-    w, x, qbytes = make_inputs(m, label)
-    rows = []
-    for tk in SWEEP_TK:
-        for tn in SWEEP_TN:
-            if k % tk or n % tn:
-                continue
-            try:
-                t = bench(dispatch_closure(w, "blockdot", tk, tn), (x,))
-                rows.append((tk, tn, t))
-                print(f"  tile tk={tk} tn={tn}: {t*1e6:.0f}us ({qbytes/t/1e9:.0f}GB/s)")
-            except Exception as e:
-                print(f"  tile tk={tk} tn={tn}: FAILED {e!r}"[:200])
-            sys.stdout.flush()
-    rows.sort(key=lambda r: r[2])
-    out = f"tile sweep m={m} {label} best-first: "
-    for tk, tn, t in rows:
-        out += f"tk{tk}/tn{tn}={t*1e6:.0f}us({qbytes/t/1e9:.0f}GB/s) "
-    print(out)
-    sys.stdout.flush()
 
 
 def bench_flash_decode():
@@ -482,12 +459,175 @@ def bench_paged_decode(cells=None, calls=None, rows=None):
             sys.stdout.flush()
 
 
+
+# ------------------------------------------------------------------ q40 mode
+#: The Q40 matmul shapes of each benchmark cell at m <= 16 (PERF.md section
+#: 4): name -> (k, n, layers of the stacked array; 1 = the unstacked head).
+Q40_CELLS = {
+    "deepseek7b.decode_closed": {
+        "wq..wo": (4096, 4096, 30), "w1/w3": (4096, 11008, 30),
+        "w2": (11008, 4096, 30), "head": (4096, 102400, 1)},
+    "granite4h.reason_closed": {"head": (2048, 100352, 1)},
+}
+Q40_CALLS = 300
+Q40_M = 16  # the kernel takes 16 rows whatever the batch
+
+
+def q40_inputs(m, k, n, layers, seed=0):
+    """Random packed nibbles and f16 scales made ON the device (a stacked
+    4096 x 11008 x 30 array is 0.76 GB), bf16 activations."""
+    kp, ks, kx = jax.random.split(jax.random.PRNGKey(seed), 3)
+    packed = jax.random.bits(kp, (layers, k // 2, n), jnp.uint8)
+    scales = jax.lax.bitcast_convert_type(
+        jax.random.uniform(ks, (layers, k // Q_BLOCK, n), jnp.float32, 1e-3, 2e-2
+                           ).astype(jnp.float16), jnp.uint16)
+    x = jax.random.normal(kx, (m, k), jnp.float32).astype(jnp.bfloat16)
+    return x, packed, scales
+
+
+def q40_loop_us(call, x, packed, scales, calls=None):
+    """us a call of `call(layer[1], x, packed, scales) -> f32[m, n]` over
+    `calls` calls in ONE jitted scan, the layer cycling as the layer scan
+    does; the best of two timed runs."""
+    calls = calls or Q40_CALLS
+    layers = packed.shape[0]
+
+    @jax.jit
+    def loop(x, packed, scales):
+        def step(acc, i):
+            # one layer (a head) would make the call loop-invariant: let x
+            # hang on the carry (an add the size of x, under a microsecond)
+            xi = x if layers > 1 else x + (acc * 1e-30).astype(x.dtype)
+            out = call((i % layers).reshape(1), xi, packed, scales)
+            return acc + out[0, 0], None
+        return jax.lax.scan(step, jnp.float32(0),
+                            jnp.arange(calls, dtype=jnp.int32))[0]
+
+    jax.block_until_ready(loop(x, packed, scales))
+    best = float("inf")
+    for _ in range(2):
+        t0 = time.perf_counter()
+        jax.block_until_ready(loop(x, packed, scales))
+        best = min(best, time.perf_counter() - t0)
+    return best / calls * 1e6
+
+
+def q40_floor_us(m, k, n):
+    """What the HBM takes for the bytes `q40_matmul_roofline` prices: 18 B
+    per 32 weights + the bf16 activations in and out."""
+    from benchmark.costs.q40_matmul import cost
+
+    return cost(m, k, n)[1] / HBM_GBS / 1e3
+
+
+def _q40_ablations():
+    """The kernel with one part taken out, by swapping the helper that does
+    it (`q40_matmul._unpack_words`, `_group_dot`, `_scaled`): what is left
+    says what the part costs where the others cover it."""
+    feed = lambda w: [pltpu.bitcast(w ^ jnp.uint32(c), jnp.bfloat16)
+                      for c in (0, 0x10001, 0x20002, 0x30003)]
+    # [g, 4m, 128] x [g, 128, lanes]: rows of the codes stand for the dot
+    rows = lambda xa, codes: codes[:, : xa.shape[1]].astype(jnp.float32)
+    plain = lambda y, sb: y.sum(axis=0)
+    return {
+        "as it is": {},
+        "no dot (unpack + scale + DMA)": {"_group_dot": rows},
+        "no unpack (MXU + scale + DMA)": {"_unpack_words": feed},
+        "no scale multiply": {"_scaled": plain},
+        "DMA only": {
+            "_unpack_words": lambda w: [pltpu.bitcast(w, jnp.bfloat16)] * 4,
+            "_group_dot": lambda xa, codes: jnp.zeros(
+                xa.shape[:2] + codes.shape[2:], jnp.float32),
+            "_scaled": plain},
+    }
+
+
+def _q40_row(tag, m, k, n, data, patch=None, **tiles):
+    """One timed row; `patch` swaps kernel helpers for the row's trace."""
+    saved = {name: getattr(qmod, name) for name in patch or {}}
+    call = lambda layer, x, p, s: qmod._blockdot_call(
+        layer, x, p, s, interpret=INTERPRET, **tiles)
+    try:
+        for name, fn in (patch or {}).items():
+            setattr(qmod, name, fn)
+        qmod._blockdot_call.clear_cache()
+        us = q40_loop_us(call, *data)
+        floor = q40_floor_us(m, k, n)
+        print(f"q40 {tag}: {us:.2f} us a call, {100 * floor / us:.1f}% of the "
+              f"byte roofline ({floor:.2f} us)")
+    except Exception as e:
+        print(f"q40 {tag}: FAILED {e!r}"[:300])
+    finally:
+        for name, fn in saved.items():
+            setattr(qmod, name, fn)
+        qmod._blockdot_call.clear_cache()
+    sys.stdout.flush()
+
+
+def bench_q40(cells=None, tiles=True):
+    """The block-dot Q40 kernel alone on the chip at a cell's real shapes
+    (16 rows: smaller batches ride padded): parity against the XLA
+    dequantise-then-dot, the kernel as it is and with each part taken out,
+    then the (tk, tn) sweep and the inner loop's (lanes, rows a pass) sweep;
+    300 calls in one jitted scan over the layer-stacked arrays, the layer
+    cycling. It is what prices a change to the kernel before any cell runs
+    (PERF.md section 6, PR 32)."""
+    from dllama_tpu.ops.quant import QTensor
+
+    for cell, shapes in (cells or Q40_CELLS).items():
+        for name, (k, n, layers) in shapes.items():
+            m = Q40_M
+            data = x, packed, scales = q40_inputs(m, k, n, layers)
+            li = layers // 2
+            w = QTensor(packed[li], jax.lax.bitcast_convert_type(
+                scales[li], jnp.float16)).dequantize(jnp.float32)
+            want = jnp.dot(x.astype(jnp.float32), w, precision="highest")
+            got = qmod._blockdot_call(jnp.full((1,), li, jnp.int32), x, packed,
+                                      scales, interpret=INTERPRET)
+            err = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+            del w, want, got
+            tk0, tn0 = qmod._blockdot_tiles(k, n)
+            print(f"q40 {cell} {name} {k}x{n} x{layers}: tiles tk={tk0} "
+                  f"tn={tn0}, parity {err:.2e} of the largest value")
+            for label, patch in _q40_ablations().items():
+                _q40_row(f"{name} {k}x{n} m={m} {label}", m, k, n, data, patch)
+            seen = {(tk0, tn0)}
+            for tk in Q40_SWEEP_TK if tiles else ():
+                for tn in Q40_SWEEP_TN:
+                    tk_, tn_ = tk or k, n // -tn if tn < 0 else tn
+                    if (k % tk_ or n % tn_ or tn_ % 128
+                            or (tk_ != k and tk_ % (qmod._CHUNK * qmod._GROUP))
+                            or (tk_, tn_) in seen
+                            or tk_ * tn_ // 2 > Q40_SWEEP_BYTES):
+                        continue
+                    seen.add((tk_, tn_))
+                    _q40_row(f"{name} {k}x{n} m={m} sweep tk={tk_} tn={tn_}",
+                             m, k, n, data, tk=tk_, tn=tn_)
+            seen = set()
+            for lanes in Q40_SWEEP_LANES:
+                for rows in Q40_SWEEP_ROWS:
+                    rows = min(rows or tk0, tk0)
+                    if tn0 % lanes == 0 and (lanes, rows) not in seen:
+                        seen.add((lanes, rows))
+                        _q40_row(f"{name} {k}x{n} m={m} sweep lanes={lanes} "
+                                 f"rows={rows}", m, k, n, data, lanes=lanes, rows=rows)
+            del x, packed, scales, data
+
+
+Q40_SWEEP_TK = (4096, 8192, None)  # None = the whole of k
+Q40_SWEEP_TN = (256, 512, 1024, 2048, -2, -1)  # -d = n / d
+Q40_SWEEP_LANES = (256, 512)
+#: k rows a pass of the kernel's inner loop covers (None = the tile's)
+Q40_SWEEP_ROWS = (1024, 2048, 4096, 8192, None)
+Q40_SWEEP_BYTES = 6 * 1024 * 1024
+
 def main():
     # argv: 'suite [--smoke] [--no-flash]' | 'flash [--smoke]' |
     # 'paged [--smoke]' (the paged decode call of each benchmark cell) |
+    # 'q40 [--smoke] [--no-tiles]' (the block-dot kernel at the cells' shapes) |
     # M SHAPE [variant ...] — suite runs the whole decode + prefill matrix in
     # ONE process (one device init, not six). --no-flash skips the flash
-    # section; the q40 rows and the tile sweep still land.
+    # section; the q40 rows still land.
     from dllama_tpu.obs.compile import place_compile_cache
 
     place_compile_cache()
@@ -505,6 +645,10 @@ def main():
         bench_paged_decode()
         print("KBENCH DONE")
         return
+    if sys.argv[1:2] == ["q40"]:
+        bench_q40(tiles="--no-tiles" not in sys.argv)
+        print("KBENCH DONE")
+        return
     if sys.argv[1:2] == ["suite"]:
         for m, label, variants in SUITE:
             try:
@@ -512,11 +656,6 @@ def main():
             except Exception as e:
                 print(f"m={m} {label}: FAILED {e!r}"[:300])
                 sys.stdout.flush()
-        try:
-            sweep_blockdot_tiles()
-        except Exception as e:
-            print(f"tile sweep: FAILED {e!r}"[:300])
-            sys.stdout.flush()
         if no_flash:
             print("flash bench SKIPPED (--no-flash)")
         else:

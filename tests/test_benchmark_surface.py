@@ -294,3 +294,30 @@ def test_check_drives_the_engine_end_to_end(tiny):
     assert [len(s) for s in got["sequences"]] == [9 + 1 + 8 + 7, 40 + 1 + 8 + 7]
     assert all(r.shape == (config["vocab_size"],)
                for r in got["prefill_rows"] + got["tail_rows"])
+
+
+# ------------------------------------------- the Q40 kernel's traced call
+
+
+@pytest.mark.parametrize("m,k,n,layers", [(16, 512, 256, 3), (16, 256, 384, 1)])
+def test_blockdot_call_parses_as_the_cost_file_reads_it(m, k, n, layers):
+    """`q40_matmul_roofline` prices every traced `_blockdot_call` from the
+    call's HLO text: a result f32[m, n] and the FIRST u8 operand, the packed
+    array u8[layers, k/2, n] with the same n (`benchmark/costs/q40_matmul.py`;
+    one call that does not parse turns the metric to null). Lowered for the
+    TPU here, no chip and no compile: the custom call's own line."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.costs import q40_matmul as cost
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    S = jax.ShapeDtypeStruct
+    args = (S((1,), jnp.int32), S((m, k), jnp.bfloat16),
+            S((layers, k // 2, n), jnp.uint8), S((layers, k // 32, n), jnp.uint16))
+    assert qmod._blockdot_call.__name__ == "_blockdot_call"  # the op's group
+    hlo = jax.jit(lambda *a: qmod._blockdot_call(*a)).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(dialect="hlo")
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1, hlo
+    assert cost.calls({}, {"hlo": calls[0]}) == cost.cost(m, k, n)

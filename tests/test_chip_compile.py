@@ -25,6 +25,13 @@ CASES = (
     "q40 decode m=8 w1(2048x8192)",
     "q40 decode m=8 w2(8192x2048)",
     "q40 decode m=8 wcls(2048x128256)",
+    # the block-dot kernel at the benchmark cells' decode shapes
+    "q40 decode m=16 deepseek wq(4096x4096)",
+    "q40 decode m=16 deepseek w1(4096x11008)",
+    "q40 decode m=16 deepseek w2(11008x4096)",
+    "q40 decode m=16 deepseek head(4096x102400)",
+    "q40 decode m=8 granite head(2048x100352)",
+    "q40 decode m=8 granite in_proj(2048x8576)",
     "q40 prefill m=256 w1(2048x8192)",
     "q40 prefill m=256 w2(8192x2048)",
     "q40 prefill m=256 wcls(2048x128256)",
@@ -86,6 +93,25 @@ def test_compiles_for_v5e(thunks, name):
     compiled = thunks[name]()
     # the chip's compiler saw a Pallas kernel, not an interpret-mode trace
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name,m,k,n", [
+    ("q40 decode m=16 deepseek w2(11008x4096)", 16, 11008, 4096),
+    ("q40 decode m=8 granite head(2048x100352)", 16, 2048, 100352),  # 8 rows ride as 16
+])
+def test_blockdot_call_is_named_and_shaped_as_the_benchmark_reads_it(thunks, name, m, k, n):
+    """`q40_matmul_roofline` finds the kernel by the device op's group
+    `_blockdot_call` (the compiled instruction's name without its number)
+    and prices it from that instruction's text."""
+    import re
+
+    from benchmark.costs import q40_matmul as cost
+
+    calls = [line for line in thunks[name]().as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert re.search(r"%(_blockdot_call)(\.\d+)? = ", calls[0]), calls[0][:200]
+    assert cost.calls({}, {"hlo": calls[0]}) == cost.cost(m, k, n)
 
 
 @pytest.mark.parametrize("name", [

@@ -32,8 +32,9 @@ def test_kbench_suite_smoke():
     assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
     assert "KBENCH DONE" in p.stdout
     assert "FAILED" not in p.stdout, p.stdout
-    # the tile sweep measured at least one (tk, tn) combo
-    assert "tile tk=" in p.stdout, p.stdout
+    # the production dispatch and both forced tiers were timed at m = 8
+    for row in ("A auto=", "BD blockdot=", "DQ deq="):
+        assert row in p.stdout, p.stdout
 
 
 def test_kbench_paged_smoke():
@@ -45,6 +46,19 @@ def test_kbench_paged_smoke():
     assert p.stdout.count("fused scatter:") == p.stdout.count("read-only:") == 2
 
 
+def test_kbench_q40_smoke():
+    """The block-dot kernel's own bench (the pricing of every change to it)
+    at a tiny size: parity, the kernel with each part taken out, the tile
+    sweep and the inner-loop sweep, stacked and unstacked."""
+    p = _run(["experiments/kbench.py", "q40", "--smoke"])
+    assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
+    assert "KBENCH DONE" in p.stdout and "FAILED" not in p.stdout, p.stdout
+    for row in ("as it is", "no dot", "no unpack", "no scale", "DMA only",
+                "sweep tk=", "sweep lanes="):
+        assert p.stdout.count(row) >= 2, (row, p.stdout)  # both weights
+    assert p.stdout.count("parity") == 2
+
+
 def test_collectives_table_smoke():
     p = _run(["experiments/collectives_table.py", "--smoke"])
     assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
@@ -53,13 +67,12 @@ def test_collectives_table_smoke():
 
 
 def test_kbench_no_flash():
-    """--no-flash skips the flash section but still delivers the q40 rows
-    and the tile sweep."""
+    """--no-flash skips the flash section but still delivers the q40 rows."""
     p = _run(["experiments/kbench.py", "suite", "--smoke", "--no-flash"])
     assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
     assert "flash bench SKIPPED" in p.stdout
     assert "flash decode" not in p.stdout
-    assert "tile tk=" in p.stdout and "KBENCH DONE" in p.stdout
+    assert "A auto=" in p.stdout and "KBENCH DONE" in p.stdout
 
 
 def test_aot_mosaic_acceptance():

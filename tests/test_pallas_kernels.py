@@ -167,27 +167,159 @@ def test_rms_norm_pallas_matches_jnp(rng, shape, dtype):
     )
 
 
-@pytest.mark.parametrize("style", ["blockdot", "maskdot", "loopdot", "deq"])
+@pytest.mark.parametrize("style", ["blockdot", "deq"])
 def test_q40_styles_agree(rng, style):
-    """Every decode-kernel style computes the same product (maskdot and
-    loopdot are the plain-dot fallbacks for blockdot's batched dot_general)."""
+    """Both tiers compute the same product at a decode shape (bf16 in, the
+    block-dot kernel's only activation type)."""
     from dllama_tpu.ops.pallas import q40_matmul as qmod
 
-    x = jnp.asarray(rng.standard_normal((3, 512)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((3, 512)), jnp.bfloat16)
     w = QTensor.quantize(rng.standard_normal((512, 384)).astype(np.float32) * 0.1)
-    want = jnp.dot(x, w.dequantize(jnp.float32))
+    want = jnp.dot(x.astype(jnp.float32), w.dequantize(jnp.float32))
     old = qmod.STYLE
     try:
         qmod.STYLE = style
         got = q40_matmul(x, w, interpret=True)
     finally:
         qmod.STYLE = old
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-2, rtol=2e-2)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               atol=8e-2, rtol=8e-2)
+
+
+# The cells' decode shapes scaled down but keeping what their tiling hangs
+# on: k = 43 x 256 (DeepSeek's w2, taken whole), n = 43 x 128 (its w1/w3:
+# no wide tile divides it), a head whose n is a multiple of 512 and of
+# nothing wider, and a square stacked weight over two k tiles.
+_CELL_SHAPES = {
+    "w2: k = 43 x 256": (11008, 256, 2),
+    "w1: n = 43 x 128": (512, 5504, 2),
+    "head: n = 3 x 512": (256, 1536, 1),
+    "wq: two k tiles": (8192, 128, 3),
+}
+
+
+@pytest.fixture(scope="module")
+def cell_weights():
+    """name -> (stacked QTensor with f16 scales down to subnormals, its
+    float32 dequantisation a layer)."""
+    rng = np.random.default_rng(7)
+    out = {}
+    for name, (k, n, layers) in _CELL_SHAPES.items():
+        packed = rng.integers(0, 256, (layers, k // 2, n), dtype=np.uint8)
+        scales = (rng.random((layers, k // 32, n), np.float32) * 0.02 + 1e-3).astype(np.float16)
+        scales[:, ::3, ::5] = np.float16(3e-6)  # subnormal f16 (< 6.1e-5)
+        scales[:, 1::7, 1::3] *= np.float16(-1)
+        w = QTensor(jnp.asarray(packed), jnp.asarray(scales))
+        out[name] = (w, [QTensor(w.packed[i], w.scales[i]).dequantize(jnp.float32)
+                         for i in range(layers)])
+    return out
+
+
+@pytest.mark.parametrize("m", [1, 8, 12, 16])
+@pytest.mark.parametrize("name", list(_CELL_SHAPES))
+def test_blockdot_matches_dequant_dot_at_cell_shapes(cell_weights, name, m):
+    """The m <= 16 kernel against the XLA dequantise-then-dot in float32,
+    the layer a TRACED index into the stacked arrays. The kernel carries its
+    codes as 16 + q and takes 24 x the block sums off again: that cancels in
+    float32 (1e-5 of the largest value), it is not bit for bit."""
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    w, dense = cell_weights[name]
+    k, _, layers = _CELL_SHAPES[name]
+    x = jnp.asarray(np.random.default_rng(m).standard_normal((m, k)), jnp.bfloat16)
+    pad = jnp.pad(x, ((0, 16 - m), (0, 0)))  # the kernel takes 16 rows
+    scales = jax.lax.bitcast_convert_type(w.scales, jnp.uint16)
+    call = jax.jit(lambda layer: qmod._blockdot_call(
+        layer.reshape(1), pad, w.packed, scales, interpret=True))
+    # a subnormal f16 scale (every third block of every fifth column) may
+    # read as 0 where the backend flushes float32 subnormals (`_scales_f32`);
+    # its weights are under 8 x 3e-6 each
+    flushed = np.zeros(w.shape[-1], bool)
+    flushed[::5] = True
+    slack = 8 * 3e-6 * np.abs(np.asarray(x, np.float32)).sum(axis=1, keepdims=True)
+    for li in {0, layers - 1}:
+        got = np.asarray(call(jnp.int32(li)))[:m]
+        want = np.asarray(jnp.dot(x.astype(jnp.float32), dense[li],
+                                  precision="highest"))
+        err, top = np.abs(got - want), np.abs(want).max()
+        assert err[:, ~flushed].max() <= 1e-5 * top
+        assert (err[:, flushed] <= 1e-5 * top + slack).all()
+        # and through the public entry, in the activation dtype
+        out = q40_matmul(x, w, jnp.int32(li), interpret=True)
+        assert out.dtype == jnp.bfloat16 and out.shape == want.shape
+        np.testing.assert_allclose(np.asarray(out, np.float32), want,
+                                   atol=1e-2 * top, rtol=0)
+
+
+def test_position_is_the_order_the_words_unpack_to(rng):
+    """`_position` says where an input dim of a 128-row group lands among the
+    rows `_unpack_words` leaves (the kernel moves x there through the MXU):
+    the unpacked codes, read back through it, must be the plain codes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    k, n = 256, 128
+    packed = jnp.asarray(rng.integers(0, 256, (k // 2, n), dtype=np.uint8))
+
+    def kern(p_ref, o_ref):
+        parts = qmod._unpack_words(pltpu.bitcast(p_ref[:], jnp.uint32))  # 4 x [64, n]
+        o_ref[:] = jnp.concatenate(
+            [t[32 * g:32 * g + 32] for g in range(2) for t in parts],
+            axis=0).astype(jnp.float32)
+
+    codes = np.asarray(pl.pallas_call(
+        kern, out_shape=jax.ShapeDtypeStruct((k, n), jnp.float32),
+        interpret=True)(packed)) - 24.0
+    dense = np.asarray(QTensor(packed, jnp.ones((k // 32, n), jnp.float16)
+                               ).dequantize(jnp.float32))
+    src = np.arange(k)
+    where = 128 * (src // 128) + np.asarray(qmod._position(src % 128))
+    assert sorted(where) == list(src)  # a permutation within each group
+    np.testing.assert_array_equal(codes[where], dense)
+
+
+# every Q40 (k, n) the two cells serve at m <= 16 (PERF.md section 4)
+_SERVED = {
+    "deepseek wq..wo": (4096, 4096), "deepseek w1/w3": (4096, 11008),
+    "deepseek w2": (11008, 4096), "deepseek head": (4096, 102400),
+    "granite in_proj": (2048, 8576), "granite out_proj": (4096, 2048),
+    "granite w1/w3": (2048, 8192), "granite w2": (8192, 2048),
+    "granite wq/wo": (2048, 2048), "granite wk/wv": (2048, 512),
+    "granite head": (2048, 100352),
+}
+
+
+@pytest.mark.parametrize("name", list(_SERVED))
+def test_blockdot_tiles_keep_their_floor(name):
+    """Every served shape's tile divides its weight, walks k by whole loop
+    steps, takes its scales as whole 16-row u16 tiles and fits the VMEM
+    budget with its second buffer; and wherever the weight's divisors allow
+    it at all, a grid step moves 256 KB of packed bytes or more and the
+    call is more than one step (one step overlaps no copy with any work)."""
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    k, n = _SERVED[name]
+    tk, tn = qmod._blockdot_tiles(k, n)
+    assert k % tk == 0 and n % tn == 0 and tn % 128 == 0 and tk % qmod._SUB_K == 0
+    assert (tk // 32) % 16 == 0 or tk == k
+    assert tk == k or tk % 4096 == 0  # whole chunks of block sums
+    assert 2 * (tk * tn // 2 + tk // 32 * tn * 2) <= qmod._VMEM_BUDGET
+    lanes, rows = qmod._inner(tk, tn)
+    assert tn % lanes == 0 and rows % qmod._SUB_K == 0 and 0 < rows <= tk
+    sound = lambda tk, tn: (tk * tn // 2 >= qmod._STEP_FLOOR
+                            and (k // tk) * (n // tn) >= 2)
+    could = any(sound(a, b) for a in [k] + list(range(4096, k, 4096))
+                for b in range(128, n + 1, 128) if k % a == 0 and n % b == 0)
+    assert sound(tk, tn) or not could
+    if name.startswith("deepseek") or name == "granite head":
+        assert sound(tk, tn)  # the shapes the cells' decode steps run
 
 
 class TestDispatchKnobs:
     """Contracts for the measurement-session knobs: prefill GEMM routing
-    (ops.matmul.XLA_PREFILL_MIN_M) and blockdot tile overrides."""
+    (ops.matmul.XLA_PREFILL_MIN_M), the block-dot tile overrides, and what
+    the block-dot kernel leaves to the dequantising tier."""
 
     def test_xla_prefill_routing_threshold(self, monkeypatch):
         """Pins that the threshold actually ROUTES (not merely that both
@@ -219,30 +351,35 @@ class TestDispatchKnobs:
             with pytest.raises(AssertionError, match="fused kernel"):
                 mm.matmul(xd, w, backend="pallas")
 
-    def test_blockdot_tile_override_matches_default(self, monkeypatch):
+    @pytest.mark.parametrize("tiles", [dict(tk=4096, tn=128), dict(tn=256),
+                                       dict(lanes=128), dict(rows=256)])
+    def test_blockdot_tile_override_matches_default(self, tiles):
+        """The chip sweep's overrides are static arguments of the jitted call
+        (no module knob): another tiling is the same product."""
         from dllama_tpu.ops.pallas import q40_matmul as qm
 
-        w = QTensor.quantize((np.random.default_rng(3).standard_normal((256, 256)) * 0.05).astype(np.float32))
-        x = jnp.asarray(np.random.default_rng(4).standard_normal((8, 256)), jnp.bfloat16)
-        monkeypatch.setattr(qm, "STYLE", "blockdot")
-        want = np.asarray(qm.q40_matmul(x, w, interpret=True), np.float32)
-        monkeypatch.setattr(qm, "BLOCKDOT_TK", 128)
-        monkeypatch.setattr(qm, "BLOCKDOT_TN", 128)
-        got = np.asarray(qm.q40_matmul(x, w, interpret=True), np.float32)
-        np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+        w = QTensor.quantize((np.random.default_rng(3).standard_normal((8192, 256)) * 0.05).astype(np.float32))
+        x = jnp.asarray(np.random.default_rng(4).standard_normal((16, 8192)), jnp.bfloat16)
+        args = (jnp.zeros((1,), jnp.int32), x, w.packed[None],
+                jax.lax.bitcast_convert_type(w.scales, jnp.uint16)[None])
+        want = np.asarray(qm._blockdot_call(*args, interpret=True, tk=8192, tn=128))
+        got = np.asarray(qm._blockdot_call(*args, interpret=True, **tiles))
+        np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)  # f32 sums, reordered
 
-    def test_invalid_tile_override_falls_back(self, monkeypatch):
+    @pytest.mark.parametrize("x_dtype,k", [(jnp.float32, 512), (jnp.bfloat16, 384)])
+    def test_what_the_blockdot_kernel_cannot_take_goes_to_deq(self, monkeypatch, x_dtype, k):
+        """float32 activations and a k that is not whole loop steps of 256
+        rows take the dequantising tier, whatever m is."""
         from dllama_tpu.ops.pallas import q40_matmul as qm
 
-        w = QTensor.quantize((np.random.default_rng(5).standard_normal((256, 256)) * 0.05).astype(np.float32))
-        x = jnp.asarray(np.random.default_rng(6).standard_normal((8, 256)), jnp.bfloat16)
-        monkeypatch.setattr(qm, "STYLE", "blockdot")
-        monkeypatch.setattr(qm, "BLOCKDOT_TK", 16)   # divides k=256 but NOT
-        # Q_BLOCK-aligned (16 % 32 != 0): the alignment clause must reject it
-        monkeypatch.setattr(qm, "BLOCKDOT_TN", 100)  # does not divide n: ignored
+        def boom(*a, **kw):
+            raise AssertionError("block-dot kernel must not run")
+
+        monkeypatch.setattr(qm, "_blockdot_call", boom)
+        w = QTensor.quantize((np.random.default_rng(5).standard_normal((k, 256)) * 0.05).astype(np.float32))
+        x = jnp.asarray(np.random.default_rng(6).standard_normal((8, k)), x_dtype)
         got = np.asarray(qm.q40_matmul(x, w, interpret=True), np.float32)
-        ref = np.asarray(w.dequantize(jnp.float32), np.float32)
-        want = np.asarray(x, np.float32) @ ref
+        want = np.asarray(x, np.float32) @ np.asarray(w.dequantize(jnp.float32))
         np.testing.assert_allclose(got, want, atol=3e-2, rtol=3e-2)
 
 
