@@ -94,7 +94,7 @@ class PagePool:
     mutation — the pool is the single owner of those series."""
 
     def __init__(self, n_pages: int, page_size: int, n_slots: int,
-                 max_blocks: int):
+                 max_blocks: int, name: str = "global"):
         if n_pages < 2:
             raise ValueError(
                 f"kv_pages={n_pages}: the pool needs at least a prompt page "
@@ -103,7 +103,19 @@ class PagePool:
         self.page_size = page_size
         self.max_blocks = max_blocks
         self.refcount = np.zeros(n_pages, np.int32)
-        self.tables = np.zeros((n_slots, max_blocks), np.int32)
+        # A model with windowed attention layers has a pool a kind
+        # (`BatchEngine.wpool` beside `.pool`): each is the other's `peer`,
+        # the summed gauges and the global pool's audit() cover both. The
+        # "window" pool's tables stay positional (block = row // page) with
+        # a released head: blocks [0, head) of a slot were handed back once
+        # no query could see them (`free_head`), and every entry nothing
+        # backs points at `hole`, the pool's trash page, which the clipped
+        # sweep never reads (0 in the global pool, as ever: masked).
+        self.name = name
+        self.peer: "PagePool | None" = None
+        self.hole = n_pages if name == "window" else 0
+        self.tables = np.full((n_slots, max_blocks), self.hole, np.int32)
+        self.head = np.zeros(n_slots, np.int32)
         self.n_blocks = np.zeros(n_slots, np.int32)
         self._free: list[int] = list(range(n_pages - 1, -1, -1))
         # reentrant: the scheduler worker is the only mutator, but audit()
@@ -180,7 +192,13 @@ class PagePool:
             problems: list[str] = []
             refs = np.zeros(self.n_pages, np.int64)
             for s in range(self.tables.shape[0]):
-                for b in range(int(self.n_blocks[s])):
+                for b in range(int(self.head[s])):
+                    if int(self.tables[s, b]) != self.hole:
+                        problems.append(
+                            f"slot {s} block {b} was released at the head "
+                            f"but its entry holds page "
+                            f"{int(self.tables[s, b])}, not the trash page")
+                for b in range(int(self.head[s]), int(self.n_blocks[s])):
                     p = int(self.tables[s, b])
                     if 0 <= p < self.n_pages:
                         refs[p] += 1
@@ -272,6 +290,12 @@ class PagePool:
                       "radix_pages": radix_pages}
             if self.host is not None:
                 report["host"] = self.host.stats()
+            if self.peer is not None and self.name == "global":
+                # the windowed layers' pool answers with this one
+                sub = self.peer.audit(raise_on_fail=False)
+                problems.extend(f"window pool: {p}" for p in sub["problems"])
+                report["window"] = sub
+                report["ok"] = not problems
         if problems:
             ins.KV_AUDIT_FAILURES.inc()
             if raise_on_fail:
@@ -282,9 +306,19 @@ class PagePool:
     def _publish(self) -> None:
         self._published_used = self.n_pages - self.free_count
         self._published_shared = self.shared_count
-        ins.KV_PAGES_TOTAL.set(self.n_pages)
-        ins.KV_PAGES_USED.set(self._published_used)
-        ins.KV_PAGES_SHARED.set(self._published_shared)
+        peer = self.peer
+        if peer is None:
+            ins.KV_PAGES_TOTAL.set(self.n_pages)
+            ins.KV_PAGES_USED.set(self._published_used)
+            ins.KV_PAGES_SHARED.set(self._published_shared)
+            return
+        # a pool a kind: the unlabelled series keep their meaning (pages of
+        # the cache, both pools summed), a labelled pair says which pool
+        ins.KV_PAGES_TOTAL.set(self.n_pages + peer.n_pages)
+        ins.KV_PAGES_USED.set(self._published_used + peer._published_used)
+        ins.KV_PAGES_SHARED.set(self._published_shared + peer._published_shared)
+        ins.KV_POOL_PAGES_TOTAL.labels(pool=self.name).set(self.n_pages)
+        ins.KV_POOL_PAGES_USED.labels(pool=self.name).set(self._published_used)
 
     # ------------------------------------------------------------ primitives
 
@@ -345,16 +379,43 @@ class PagePool:
         with self._mu:
             keep = min(self.blocks_for(keep_rows), int(self.n_blocks[slot]))
             freed = 0
-            for b in range(keep, int(self.n_blocks[slot])):
+            for b in range(max(keep, int(self.head[slot])),
+                           int(self.n_blocks[slot])):
                 p = int(self.tables[slot, b])
                 before = self.free_count
                 self._decref(p)
                 freed += self.free_count - before
-                self.tables[slot, b] = 0
+            self.tables[slot, keep:int(self.n_blocks[slot])] = self.hole
+            self.head[slot] = min(int(self.head[slot]), keep)
             if self.n_blocks[slot] != keep:
                 self.n_blocks[slot] = keep
                 self._publish()
             return freed
+
+    def free_head(self, slot: int, first_row: int) -> int:
+        """Hand back `slot`'s blocks that lie wholly before `first_row` (the
+        oldest row a windowed layer's next query still sees): the table
+        entry then points at the trash page and the slot's `head` moves up.
+        The mirror of `free_tail`. Returns the pages returned to the free
+        list."""
+        with self._mu:
+            upto = min(max(int(first_row), 0) // self.page_size,
+                       int(self.n_blocks[slot]))
+            freed = 0
+            for b in range(int(self.head[slot]), upto):
+                before = self.free_count
+                self._decref(int(self.tables[slot, b]))
+                freed += self.free_count - before
+                self.tables[slot, b] = self.hole
+            if upto > self.head[slot]:
+                self.head[slot] = upto
+                ins.KV_WINDOW_PAGES_RELEASED.inc(freed)
+                self._publish()
+            return freed
+
+    def held(self, slot: int) -> int:
+        """Pages `slot`'s table references now."""
+        return int(self.n_blocks[slot]) - int(self.head[slot])
 
     def ensure_writable(self, slot: int, row: int, copy_fn) -> None:
         """Copy-on-write: make the page holding `row` exclusively owned by
@@ -440,7 +501,10 @@ class PagePool:
             self.free_tail(slot, start)
             if start % self.page_size:
                 self.ensure_writable(slot, start, copy_fn)
-            self.grow(slot, end)
+            if self.name != "window":  # the window pool grows a slice at a
+                # time (BatchEngine._window_advance): a long prompt never
+                # holds more of it than a window and a slice
+                self.grow(slot, end)
 
     def admission_deficit(self, slot: int, reuse: int, total_rows: int,
                           cross: bool) -> int:
@@ -665,6 +729,8 @@ class DecodeChunk:
     # slice for that (inactive) admitting slot (hybrid_dispatch)
     hybrid_tokens: int = 0  # prompt tokens the fused slice covered
     launch: launch_record.LaunchRecord | None = None  # the launch's record
+    moe: "jax.Array | None" = None  # the expert counters as they stood after
+    # this launch (KVCache.moe_stats, a copy the next launch cannot donate)
     # (kind, rows, slot-steps): counted at dispatch, a spec chunk's at
     # consumption; its args ride the chunk's decode.device/decode.spec span
 
@@ -766,6 +832,25 @@ class BatchEngine:
                          "state cannot be re-entered at a page boundary "
                          "(prefix rows are recomputed)")
                 radix_cache = "off"
+        self.windowed = cfg.n_window_layers > 0
+        self.window = cfg.window if self.windowed else 0  # rows a windowed
+        # layer's query sees; 0 = the model has none
+        if self.windowed and kv_layout == "paged":
+            # windowed layers keep their rows in a page pool of their own
+            # and hand back the pages that fell behind the window. What
+            # follows ONE page list a slot is refused or resolved off HERE,
+            # by mechanism, as recurrent state does above
+            if radix_cache == "on" or kv_host_pages > 0:
+                raise ValueError(
+                    "the radix prefix cache and its host spill tier hold one "
+                    "page list a prefix; a model with windowed attention "
+                    "layers has a pool a kind (--radix-cache off, "
+                    "--kv-host-pages 0)")
+            if radix_cache == "auto":
+                log.info("radix prefix cache off: windowed attention layers "
+                         "keep a page pool of their own, which the prefix "
+                         "tree cannot follow yet (prefix rows are recomputed)")
+                radix_cache = "off"
         if fuse_weights:
             if shardings is not None:
                 raise ValueError("fuse_weights requires an unsharded engine "
@@ -793,12 +878,13 @@ class BatchEngine:
             resolve_moe_impl,
         )
 
-        moe_impl = resolve_moe_impl(moe_impl, shardings)
+        moe_impl = resolve_moe_impl(moe_impl, shardings, cfg, self.params,
+                                    kernels)
         sel = resolve_kernels(cfg, self.seq_len, n_slots, kernels, attn_impl,
                               shardings, paged=kv_layout == "paged",
                               page_size=self.page_size,
                               cache_dtype=cache_dtype,
-                              state_dtype=state_dtype)
+                              state_dtype=state_dtype, moe_impl=moe_impl)
         mm, mm_in, attn_fn = sel.mm, sel.mm_in, sel.attn_fn
         self.backend = sel.backend
         # which attention path actually runs ('paged_kernel' = the fused
@@ -810,6 +896,7 @@ class BatchEngine:
         self._paged_route = sel.attn_route
         self._state_step = sel.state_step
         self.pool: PagePool | None = None
+        self.wpool: PagePool | None = None  # the windowed layers' pool
         if kv_layout == "paged":
             if shardings is not None:
                 raise ValueError(
@@ -824,8 +911,7 @@ class BatchEngine:
                     "is what makes it bit-exact)")
             max_blocks = self.seq_len // self.page_size
             n_pages = int(kv_pages) or max_blocks * n_slots
-            self.pool = PagePool(n_pages, self.page_size, n_slots, max_blocks)
-            self.pool.write_horizons = self._write_horizons
+            self._build_pools(n_pages, max_blocks)
             self.cache = self._new_paged_cache(n_pages, max_blocks)
         else:
             self.cache = KVCache.create(cfg, n_slots, cache_dtype, self.seq_len,
@@ -837,6 +923,7 @@ class BatchEngine:
         # release(keep_rows=) when the rows kept end where the state stands,
         # consumed by add_begin(start_pos=)
         self._state_at = np.full(n_slots, -1, np.int64)
+        self._moe_seen = np.zeros(4, np.uint32)  # see _moe_count
         if self.cache.state is not None:
             ins.RECURRENT_STATE_BYTES.set(self.cache.state.nbytes)
         if radix_cache not in ("auto", "on", "off"):
@@ -1076,6 +1163,9 @@ class BatchEngine:
                 f"{compile_obs.TRANSFER_GUARD_MODES}, got {transfer_guard!r}")
         self.transfer_guard = transfer_guard
         self.contract = compile_obs.ShapeContract()
+        # (fn, key) of the programs warmup() has dispatched on this engine:
+        # a second warm-up lowers and compiles none of them ahead again
+        self._warmed: set = set()
         self.kernel_route = sel.bucket_tag()
         from dllama_tpu.engine.kernel_select import pow2_buckets
 
@@ -1088,6 +1178,31 @@ class BatchEngine:
         compile_obs.LEDGER.install_contract(self.contract)
         compile_obs.LEDGER.ensure_listener()
 
+    def _build_pools(self, n_pages: int, max_blocks: int) -> None:
+        """The host allocators (construction and warm_restart): `pool`, and
+        for a model with windowed layers `wpool` beside it. `--kv-pages`
+        sizes the global pool; the window pool is sized here, at what the
+        slots can hold of it at once: a slot's table never references more
+        than the window and the rows one launch writes (a prefill slice at
+        most), so slots x that many pages never run dry and a slice's pages
+        are there whatever the other slots hold."""
+        self.pool = PagePool(n_pages, self.page_size, self.n_slots, max_blocks)
+        self.pool.write_horizons = self._write_horizons
+        self.wpool = None
+        if not self.windowed:
+            return
+        per_slot = min(-(-(self.window + self.max_prefill_chunk)
+                         // self.page_size) + 1, max_blocks)
+        wn = self.n_slots * per_slot
+        self.wpool = PagePool(wn, self.page_size, self.n_slots, max_blocks,
+                              name="window")
+        self.pool.peer, self.wpool.peer = self.wpool, self.pool
+        self.wpool._mu = self.pool._mu  # one reentrant lock: the global
+        # pool's audit reads the window pool under it, as it reads the host
+        # tier
+        self.wpool._publish()
+        self.pool._publish()
+
     def _new_paged_cache(self, n_pages: int, max_blocks: int) -> PagedKVCache:
         """The engine's page pool (construction and warm_restart). On the
         paged_kernel route its rows are whole 128-lane vectors: Mosaic
@@ -1095,13 +1210,14 @@ class BatchEngine:
         from dllama_tpu.ops.pallas.paged_attention import pool_lanes
 
         lanes = (pool_lanes(self.cfg.head_size)
-                 if self._paged_route == "paged_kernel" else 0)
+                 if self._paged_route.startswith("paged_kernel") else 0)
         return PagedKVCache.create(
             self.cfg, self.n_slots, n_pages, self.page_size,
             self.cache_dtype, max_blocks, lanes=lanes,
             state_dtype=self.state_dtype,
             conv_dtype=self.params["embedding"].dtype,
-            state_step=self._state_step)
+            state_step=self._state_step,
+            window_pages=self.wpool.n_pages if self.wpool is not None else 0)
 
     @property
     def rows_reenterable(self) -> bool:
@@ -1110,7 +1226,7 @@ class BatchEngine:
         spill tier, preempt-to-pages, speculative rewind)? True for KV-only
         models. False where the model carries recurrent state: that stands
         at one row a slot (`resumable_rows`), everything else recomputes."""
-        return not self.cfg.recurrent
+        return not self.cfg.recurrent and self.wpool is None
 
     def resumable_rows(self, slot: int, rows: int, donor: int | None = None) -> int:
         """How many of `rows` reusable prefix rows an admission into `slot`
@@ -1120,7 +1236,17 @@ class BatchEngine:
         if self.rows_reenterable:
             return rows
         own = donor is None or donor == slot
+        if not self.cfg.recurrent:
+            # windowed layers: the slot's own rows, while the window pool
+            # still holds what a query at `rows` sees
+            return rows if own and self._window_holds(slot, rows) else 0
         return rows if own and rows > 0 and self._state_at[slot] == rows else 0
+
+    def _window_holds(self, slot: int, rows: int) -> bool:
+        """Whether the window pool still backs every row a windowed layer's
+        query at row `rows` of `slot` sees."""
+        first = max(0, int(rows) - self.window + 1)
+        return int(self.wpool.head[slot]) * self.page_size <= first
 
     # ------------------------------------------------------------- jitted fns
 
@@ -1578,9 +1704,10 @@ class BatchEngine:
         dense; min(seq_len, allocated pages) on paged."""
         if self.pool is None:
             return np.full(self.n_slots, self.seq_len, np.int32)
-        return np.minimum(
-            self.seq_len, self.pool.n_blocks.astype(np.int64) * self.page_size
-        ).astype(np.int32)
+        blocks = self.pool.n_blocks.astype(np.int64)
+        if self.wpool is not None:
+            blocks = np.minimum(blocks, self.wpool.n_blocks)
+        return np.minimum(self.seq_len, blocks * self.page_size).astype(np.int32)
 
     def _alloc_decode_rows(self, n: int) -> None:
         """Paged: best-effort top-up before a decode/spec dispatch — extend
@@ -1613,8 +1740,22 @@ class BatchEngine:
             changed |= self.pool.grow(int(s), want, best_effort=True)
             changed |= self.pool.cow_writable(int(s), int(self.pos[s]), want,
                                               self._pool_page_copy)
+            if self.wpool is not None:
+                changed |= self._window_advance(int(s), want,
+                                                best_effort=True)
         if changed:
             self._vec_dirty = True
+
+    def _window_advance(self, slot: int, want: int,
+                        best_effort: bool = False) -> bool:
+        """The window pool's half of backing `slot` up to row `want`: hand
+        back the blocks that fell wholly behind the window of the slot's
+        next query (the host's position never runs ahead of the device's),
+        then grow. True when the slot's table changed."""
+        freed = self.wpool.free_head(
+            slot, int(self.pos[slot]) - self.window + 1)
+        return bool(freed) | self.wpool.grow(slot, want,
+                                             best_effort=best_effort)
 
     def _write_horizons(self) -> list[tuple[int, int]]:
         """PagePool.audit() provider: (slot, first_writable_row) for every
@@ -1632,7 +1773,7 @@ class BatchEngine:
         self._alloc_decode_rows(1)
         limit = self._row_limit()
         return (self.active & (self.pos >= limit) & (self.pos < self.seq_len)
-                & (self.pool.free_count == 0))
+                & self._pool_dry())
 
     def admission_deficit(self, slot: int, reuse: int, prompt_len: int,
                           cross: bool) -> int:
@@ -1641,6 +1782,8 @@ class BatchEngine:
         capacity-aware admission check."""
         if self.pool is None:
             return 0
+        # the window pool is never short: it holds every slot's most
+        # (_build_pools)
         return self.pool.admission_deficit(slot, reuse, prompt_len, cross)
 
     def min_pages_for(self, prompt_len: int) -> int:
@@ -1656,10 +1799,17 @@ class BatchEngine:
         assert not self.active[slot], f"slot {slot} is busy"
         if self.pool is None:
             return 0
-        freed = self.pool.free_tail(slot, 0)
+        freed = self._free_tail(slot, 0)
         self.pos[slot] = 0
         self._pos_dev = self._pos_dev.at[slot].set(0)
         self._vec_dirty = True
+        return freed
+
+    def _free_tail(self, slot: int, keep_rows: int) -> int:
+        """`PagePool.free_tail` in every pool the engine has."""
+        freed = self.pool.free_tail(slot, keep_rows)
+        if self.wpool is not None:
+            freed += self.wpool.free_tail(slot, keep_rows)
         return freed
 
     def kv_page_stats(self) -> dict | None:
@@ -1671,6 +1821,14 @@ class BatchEngine:
         st = self.pool.stats()
         if self.pool.host is not None:
             st["host"] = self.pool.host.stats()
+        if self.wpool is not None:
+            # the shape stays (total / free / used of the cache's pages,
+            # both pools summed); "pools" says which pool holds what
+            w = self.wpool.stats()
+            st["pools"] = {"global": {k: st[k] for k in ("total", "free", "used")},
+                           "window": {k: w[k] for k in ("total", "free", "used")}}
+            for k in ("total", "free", "used"):
+                st[k] += w[k]
         return st
 
     # ------------------------------------------------------ radix prefix api
@@ -1889,7 +2047,9 @@ class BatchEngine:
         write zeros into idle slot 0's rows, which nothing reads before
         a real admission overwrites them — so XLA compiles the exact
         serving shapes while the engine state stays semantically
-        untouched."""
+        untouched. `thunk(lower=True)` dispatches nothing and returns the
+        program lowered for the same operands (None for eager ops):
+        `_precompile` compiles those side by side first."""
         from dllama_tpu.engine.kernel_select import pow2_buckets
 
         work: list = []
@@ -1897,13 +2057,15 @@ class BatchEngine:
         carry: dict = {}
 
         def prefill_thunk(c):
-            def run():
+            def run(lower=False):
                 self._sync_vectors()
                 # warmup is unsharded-only, where _use_slot_prefill is
                 # always True — the B=1 slot prefill IS the serving shape
-                row, self.cache = self._prefill_slot(
-                    self.params, self.cache, jnp.zeros((1, c), jnp.int32),
-                    jnp.int32(0), jnp.int32(0), self.rope_cache)
+                args = (self.params, self.cache, jnp.zeros((1, c), jnp.int32),
+                        jnp.int32(0), jnp.int32(0), self.rope_cache)
+                if lower:
+                    return self._prefill_slot.lower(*args)
+                row, self.cache = self._prefill_slot(*args)
                 carry["logits"] = row
                 if self.spec_k:
                     self.history = self._hist_write(
@@ -1914,9 +2076,9 @@ class BatchEngine:
         for c in pow2_buckets(self._prefill_bucket_cap()):
             work.append(("prefill_chunk", f"m{c}", prefill_thunk(c)))
 
-        def commit_thunk():
+        def commit_thunk(lower=False):
             row = carry.get("logits")
-            if row is None:  # pragma: no cover - prefill thunks run first
+            if lower or row is None:  # eager ops: nothing to lower ahead
                 return
             _key, sub = jax.random.split(self._base_key)
             sample_logits(row, sub, jnp.float32(0.8), jnp.float32(0.9))
@@ -1924,7 +2086,7 @@ class BatchEngine:
         work.append(("commit", "b1", commit_thunk))
 
         def decode_thunk(n, pen):
-            def run():
+            def run(lower=False):
                 self._sync_vectors()
                 args = (self.params, self.cache, self._last_dev[:, None],
                         self._pos_dev, self._active_dev, self._keys_dev,
@@ -1932,9 +2094,12 @@ class BatchEngine:
                         self._limit_dev)
                 if pen:
                     self._ensure_counts()
+                    args += (self._counts, self._pres_dev, self._freq_dev)
+                if lower:
+                    return (self._decode_pen if pen else self._decode).lower(*args)
+                if pen:
                     (toks, self.cache, self._keys_dev, self._pos_dev,
-                     self._last_dev, self._counts, _bad) = self._decode_pen(
-                        *args, self._counts, self._pres_dev, self._freq_dev)
+                     self._last_dev, self._counts, _bad) = self._decode_pen(*args)
                 else:
                     (toks, self.cache, self._keys_dev, self._pos_dev,
                      self._last_dev, _bad) = self._decode(*args)
@@ -1952,7 +2117,7 @@ class BatchEngine:
 
         if self.spec_k:
             def spec_thunk(n, pen):
-                def run():
+                def run(lower=False):
                     self._sync_vectors()
                     args = (self.params, self.cache, self.history,
                             self._last_dev, self._pos_dev, self._active_dev,
@@ -1960,11 +2125,14 @@ class BatchEngine:
                             self._topp_dev, self.rope_cache, self._limit_dev)
                     if pen:
                         self._ensure_counts()
+                        args += (self._counts, self._pres_dev, self._freq_dev)
+                    if lower:
+                        return (self._spec_step_pen if pen
+                                else self._spec_step).lower(*args, n)
+                    if pen:
                         (emits, advs, nxt, self.cache, self.history,
                          self._keys_dev, self._pos_dev, drafts, _bad,
-                         self._counts) = self._spec_step_pen(
-                            *args, self._counts, self._pres_dev,
-                            self._freq_dev, n)
+                         self._counts) = self._spec_step_pen(*args, n)
                     else:
                         (emits, advs, nxt, self.cache, self.history,
                          self._keys_dev, self._pos_dev, drafts, _bad) = \
@@ -1980,7 +2148,7 @@ class BatchEngine:
             cap = min(int(hybrid_budget_hi), self._prefill_bucket_cap())
 
             def hybrid_thunk(p, n, pen):
-                def run():
+                def run(lower=False):
                     self._sync_vectors()
                     args = (self.params, self.cache,
                             jnp.zeros((1, p), jnp.int32), jnp.int32(0),
@@ -1990,11 +2158,14 @@ class BatchEngine:
                             self.rope_cache, self._limit_dev)
                     if pen:
                         self._ensure_counts()
+                        args += (self._counts, self._pres_dev, self._freq_dev)
+                    if lower:
+                        return (self._hybrid_pen if pen
+                                else self._hybrid).lower(*args)
+                    if pen:
                         (plog, toks, self.cache, self._keys_dev,
                          self._pos_dev, self._last_dev, self._counts,
-                         _bad) = self._hybrid_pen(
-                            *args, self._counts, self._pres_dev,
-                            self._freq_dev)
+                         _bad) = self._hybrid_pen(*args)
                     else:
                         (plog, toks, self.cache, self._keys_dev,
                          self._pos_dev, self._last_dev, _bad) = \
@@ -2024,6 +2195,45 @@ class BatchEngine:
         jnp.full((1,), 0, jnp.int32)
         if self._counts is not None:
             self._counts.at[0].set(0)
+        self._moe_snapshot()
+
+    def _precompile(self, work: list) -> list:
+        """Compile the worklist's programs side by side before warmup()
+        dispatches them one after another: each is traced and lowered here
+        (Python, one at a time), then the lowered programs are compiled on a
+        pool of threads (XLA drops the GIL; a compile is one core's work, and
+        34 programs of 11 s each kept the other cores of a serving host idle
+        for minutes of a cold start). jax keeps a jitted function's lowering
+        and executable by its arguments' types, so the dispatch that follows
+        finds both and compiles nothing; from a warm compile cache the pool
+        reads the executables in side by side instead. -> a meter a work item
+        (what its lowering and compile took, for the item's ledger entry),
+        None where nothing was lowered: a program this engine has warmed
+        already, or eager ops."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        ledger = compile_obs.LEDGER
+        jobs: list = []
+        meters: list = []
+        for fn, key, thunk in work:
+            meter = ledger.meter()
+            with meter:
+                lowered = (None if (fn, key) in self._warmed
+                           else thunk(lower=True))
+            meters.append(None if lowered is None else meter)
+            if lowered is not None:
+                jobs.append((lowered, meter))
+
+        def compile_one(job):
+            lowered, meter = job
+            with meter:  # the pool thread's own scope stack
+                lowered.compile()
+
+        if jobs:
+            workers = max(1, min(len(jobs), (os.cpu_count() or 2) - 1, 8))
+            with ThreadPoolExecutor(workers, "warm-compile") as pool:
+                list(pool.map(compile_one, jobs))
+        return meters
 
     def warmup(self, chunk: int = 4, hybrid_budget_hi: int = 0) -> dict:
         """``--warmup auto`` precompile pass: declare + dispatch every
@@ -2046,9 +2256,13 @@ class BatchEngine:
         had_counts = self._counts is not None
         work = self._warm_worklist(max(1, int(chunk)), hybrid_budget_hi)
         with ledger.warmup_phase():
-            for fn, key, thunk in work:
+            meters = self._precompile(work)
+            for (fn, key, thunk), meter in zip(work, meters):
                 with ledger.scope(fn, key) as sc:
+                    if meter is not None:
+                        sc.absorb(meter)
                     thunk()
+                self._warmed.add((fn, key))
                 if sc.trace_s or sc.lower_s or sc.compile_s:
                     compiled += 1
                     per_fn[fn] = per_fn.get(fn, 0) + 1
@@ -2095,10 +2309,8 @@ class BatchEngine:
         if self.pool is not None:
             max_blocks = self.seq_len // self.page_size
             audit_flag = self.pool.audit_on_release
-            self.pool = PagePool(self.pool.n_pages, self.page_size,
-                                 self.n_slots, max_blocks)
+            self._build_pools(self.pool.n_pages, max_blocks)
             self.pool.audit_on_release = audit_flag
-            self.pool.write_horizons = self._write_horizons
             self.cache = self._new_paged_cache(self.pool.n_pages, max_blocks)
             if self.radix is not None:
                 # the radix tree's page ids died with the pool: rebuild it
@@ -2125,6 +2337,7 @@ class BatchEngine:
             self.cache = self._shardings.put_cache(self.cache)
         self.pos[:] = 0
         self._state_at[:] = -1
+        self._moe_seen[:] = 0  # the fresh cache's counters start over
         self.active[:] = False
         self.last_token[:] = 0
         self.temperature[:] = 0.0
@@ -2217,6 +2430,14 @@ class BatchEngine:
             # scheduler pre-checks admission_deficit() so it never gets here.
             self.pool.prepare_admission(slot, start_pos, start_pos + n,
                                         self._pool_page_copy)
+            if self.wpool is not None:
+                if start_pos and not self._window_holds(slot, start_pos):
+                    raise StateNotResumable(
+                        f"slot {slot}: start_pos={start_pos} but the window "
+                        "pool handed back rows its window still needs; "
+                        "recompute the prompt from row 0")
+                self.wpool.prepare_admission(slot, start_pos, start_pos + n,
+                                             self._pool_page_copy)
         self.pos[slot] = start_pos
         self._pos_dev = self._pos_dev.at[slot].set(int(start_pos))
         self._vec_dirty = True
@@ -2244,6 +2465,9 @@ class BatchEngine:
                 )
         if self._use_slot_prefill:
             if self.pool is not None:
+                if self.wpool is not None and self._window_advance(
+                        slot, int(self.pos[slot]) + c):
+                    self._vec_dirty = True
                 # the slot's block table changed at add_begin (page alloc /
                 # COW): refresh the device copy before the chunk reads it
                 self._sync_vectors()
@@ -2489,6 +2713,10 @@ class BatchEngine:
             tables = jnp.asarray(self.pool.tables.copy(), jnp.int32)
             nbytes += int(tables.nbytes)
             self.cache = dataclasses.replace(self.cache, tables=tables)
+            if self.wpool is not None:
+                wtables = jnp.asarray(self.wpool.tables.copy(), jnp.int32)
+                nbytes += int(wtables.nbytes)
+                self.cache = dataclasses.replace(self.cache, wtables=wtables)
         # boundary upload accounting (ISSUE 13): this fan is the ONLY
         # legitimate steady-path upload site, and it fires at boundaries
         # only — a per-chunk rate here is the device-resident-state
@@ -2507,7 +2735,9 @@ class BatchEngine:
     def _pool_dry(self) -> bool:
         """No free page in the pool, read as page_starved() reads it but
         without that method's top-up."""
-        return self.pool is not None and self.pool.free_count == 0
+        return self.pool is not None and (
+            self.pool.free_count == 0
+            or (self.wpool is not None and self.wpool.free_count == 0))
 
     def _launch_record(self, kind: str, n: int, start_pos, active, advance,
                        *, prefill_rows: int = 0) -> launch_record.LaunchRecord:
@@ -2517,7 +2747,8 @@ class BatchEngine:
         return launch_record.build(
             kind, self.chunk_seq + 1, n, start_pos, active, advance,
             seq_len=self.seq_len, pool_dry=self._pool_dry(),
-            prefill_rows=prefill_rows)
+            prefill_rows=prefill_rows,
+            window=self.window)
 
     def decode_dispatch(self, n: int, spec: bool = False) -> DecodeChunk:
         """Dispatch one fused n-step decode chunk WITHOUT waiting for its
@@ -2602,6 +2833,7 @@ class BatchEngine:
                 (toks, self.cache, self._keys_dev, self._pos_dev,
                  self._last_dev, bad) = self._decode(*args)
         rec.count()
+        moe = self._moe_snapshot()
         bad_inject = None
         if faults.flag("decode.nan"):
             # drill the NaN guard without needing genuinely poisoned
@@ -2632,7 +2864,26 @@ class BatchEngine:
         return DecodeChunk(toks=toks, n=n, start_pos=start_pos, active=active,
                            advance=advance, t0=t0, seq=self.chunk_seq,
                            t_disp=t_disp, bad=bad, bad_inject=bad_inject,
-                           launch=rec)
+                           launch=rec, moe=moe)
+
+    def _moe_snapshot(self):
+        """The expert counters as the launch just dispatched leaves them: a
+        device-side copy (the cache's own leaf is donated to the next
+        launch), read in decode_consume with the launch's tokens. None for
+        a model without experts."""
+        stats = self.cache.moe_stats
+        return None if stats is None else jnp.copy(stats)
+
+    def _moe_count(self, stats: np.ndarray) -> None:
+        """Fold a launch's cumulative device counters (u32, wrapping) into
+        the dllama_moe_* series: every forward since the last fold, prefill
+        chunks included."""
+        now = stats.astype(np.uint32)
+        delta = (now - self._moe_seen).astype(np.uint32)  # mod 2**32
+        self._moe_seen = now
+        for fam, d in zip((ins.MOE_ASSIGNMENTS, ins.MOE_EXPERTS_TOUCHED,
+                           ins.MOE_LAYER_STEPS, ins.MOE_GROUP_ROWS_MAX), delta):
+            fam.inc(int(d))
 
     @property
     def supports_hybrid(self) -> bool:
@@ -2680,6 +2931,8 @@ class BatchEngine:
                              "(seq_len, or an exhausted page pool); "
                              "release first")
         ppos = int(self.pos[slot])
+        if self.wpool is not None and self._window_advance(slot, ppos + c):
+            self._vec_dirty = True
         if self.spec_k:
             # prompt tokens feed the n-gram proposer exactly like add_step
             compile_obs.note_transfer("h2d", "history", c * 4)
@@ -2735,6 +2988,7 @@ class BatchEngine:
                 (plog, toks, self.cache, self._keys_dev, self._pos_dev,
                  self._last_dev, bad) = self._hybrid(*args)
         rec.count()
+        moe = self._moe_snapshot()
         adm.logits = plog  # [1, V] — materializes with the chunk
         adm.off += c
         # the admitting slot's host pos advances with its slice (the device
@@ -2758,7 +3012,8 @@ class BatchEngine:
         return DecodeChunk(toks=toks, n=n, start_pos=start_pos, active=active,
                            advance=advance, t0=t0, seq=self.chunk_seq,
                            t_disp=t_disp, bad=bad, bad_inject=bad_inject,
-                           hybrid_slot=slot, hybrid_tokens=c, launch=rec)
+                           hybrid_slot=slot, hybrid_tokens=c, launch=rec,
+                           moe=moe)
 
     def _spec_dispatch(self, n_cycles: int) -> DecodeChunk:
         """Dispatch one fused spec CHUNK (decode_dispatch's spec=True
@@ -2839,7 +3094,7 @@ class BatchEngine:
                            t0=t0, seq=self.chunk_seq, t_disp=t_disp, bad=bad,
                            bad_inject=bad_inject, spec=True, adv_dev=advs,
                            drafted_dev=drafts, start_dev=start_dev,
-                           launch=rec)
+                           launch=rec, moe=self._moe_snapshot())
 
     def decode_consume(self, chunk: DecodeChunk) -> np.ndarray:
         """Block until the chunk's tokens are on host; fold them into the
@@ -2856,6 +3111,9 @@ class BatchEngine:
         (dllama_spec_* series) is recorded."""
         toks = np.asarray(chunk.toks)
         compile_obs.note_transfer("d2h", "decode_tokens", int(toks.nbytes))
+        if chunk.moe is not None:
+            # four scalars that were ready with the tokens
+            self._moe_count(np.asarray(chunk.moe))
         # the transfer above is the device sync: observing here (not at
         # dispatch) keeps DECODE_CHUNK_SECONDS device-real under overlapped
         # consumption. The clock starts at the later of the chunk's dispatch
@@ -2930,7 +3188,8 @@ class BatchEngine:
                 chunk.launch.kind, chunk.seq, m_cycles, chunk.start_pos,
                 chunk.active, total, seq_len=self.seq_len,
                 pool_dry=chunk.launch.pool_dry,
-                frozen=np.where(total == 0, m_cycles, 0)).count()
+                frozen=np.where(total == 0, m_cycles, 0),
+                window=self.window).count()
             if tr.enabled:
                 tr.span_at("decode.spec", chunk.t_disp, tr.now(),
                            cat="decode", track="launches", chunk=chunk.seq,
@@ -3043,9 +3302,9 @@ class BatchEngine:
         if keep_rows is not None:
             self.pos[slot] = keep_rows
             if self.pool is not None:
-                self.pool.free_tail(slot, keep_rows)
+                self._free_tail(slot, keep_rows)
         elif self.pool is not None:
-            self.pool.free_tail(slot, 0)
+            self._free_tail(slot, 0)
             self.pos[slot] = 0
         self._pos_dev = self._pos_dev.at[slot].set(int(self.pos[slot]))
         if self.pool is not None and self.pool.audit_on_release:

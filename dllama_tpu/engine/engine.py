@@ -134,8 +134,10 @@ class InferenceEngine:
             resolve_moe_impl,
         )
 
-        moe_impl = resolve_moe_impl(moe_impl, shardings)
-        sel = resolve_kernels(cfg, self.seq_len, batch, kernels, attn_impl, shardings)
+        moe_impl = resolve_moe_impl(moe_impl, shardings, cfg, self.params,
+                                    kernels)
+        sel = resolve_kernels(cfg, self.seq_len, batch, kernels, attn_impl,
+                              shardings, moe_impl=moe_impl)
         mm, mm_in, attn_fn = sel.mm, sel.mm_in, sel.attn_fn
         self.backend = sel.backend
         self.kernel_route = sel.bucket_tag()
@@ -420,7 +422,8 @@ class InferenceEngine:
                     jnp.asarray(data["state_s"], state.s.dtype),
                     jnp.asarray(conv.reshape(state.conv.shape)),
                     step=state.step)
-            cache = KVCache(jnp.asarray(k), jnp.asarray(v), state)
+            cache = KVCache(jnp.asarray(k), jnp.asarray(v), state,
+                            self.cache.moe_stats)
             if self.shardings is not None:
                 cache = self.shardings.put_cache(cache)
             self.cache = cache

@@ -62,11 +62,15 @@ class KernelSelection:
 
     @property
     def route(self) -> str:
-        """The mixers' routes: `attn_route`, and behind a '+' the recurrent
-        state's decode step and element type where the model has one
-        ('paged_kernel+ssm_step.float32')."""
-        return self.attn_route + (f"+{self.state_route}"
-                                  if self.state_route else "")
+        """The mixers' routes: `attn_route` (with '.window' where the model
+        has windowed layers and that route clips their walk:
+        'paged_kernel.window'), and behind a '+' each the recurrent state's
+        decode step and element type where the model has one
+        ('paged_kernel+ssm_step.float32') and the expert layers' route
+        where it has experts ('paged_kernel.window+moe_grouped')."""
+        return (self.attn_route
+                + (f"+{self.state_route}" if self.state_route else "")
+                + (f"+moe_{self.moe_route}" if self.moe_route else ""))
 
     def bucket_tag(self) -> str:
         """'backend/route' — the variant tag the compile ledger's
@@ -82,6 +86,10 @@ class KernelSelection:
     # in-place Pallas kernel, ops/pallas/ssm_step) | 'ssm_jnp.<dtype>': what
     # the state-space layers' decode step runs on and the state's element
     # type — a fallback or a narrowed state shows in the tag, never silently
+    moe_impl: str = "auto"  # the expert layers' scheme as resolved
+    # (ops.layers.moe_ffn): 'grouped' = the Q40 kernel over the expert axis
+    moe_route: str = ""  # '' (no experts) | 'grouped' | 'jnp': what the
+    # route tag says of it — a fallback shows there, never silently
     fused_scatter_max_t: int | None = None  # paged_kernel route only: the
     # widest chunk (query rows per slot) whose new-KV scatter stays fused
     # inside the kernel launch. A speculative verify forward is spec_k+1
@@ -113,8 +121,14 @@ def resolve_state_step(cfg: LlamaConfig, batch: int, backend: str,
     return None, f"ssm_jnp.{dtype.name}"
 
 
-def resolve_moe_impl(moe_impl: str, shardings=None) -> str:
-    """MoE compute-scheme resolution shared by both engines. On an
+def resolve_moe_impl(moe_impl: str, shardings, cfg: LlamaConfig, params,
+                     kernels: str) -> str:
+    """MoE compute-scheme resolution shared by both engines. 'auto' resolves
+    to 'grouped', the Q40 kernel over the expert axis
+    (ops/pallas/q40_matmul.q40_expert_matmul), where the quantized matmuls
+    run on Pallas (the engine's `kernels` choice), the engine is unsharded
+    and the kernel takes the expert stacks' shapes and the activations' type; an
+    explicit 'grouped' that cannot run is refused. On an
     expert-parallel mesh (ep > 1) the 'sort' scheme is OFF the table:
     jax.lax.ragged_dot has no correct GSPMD partitioning over a sharded
     group (expert) axis on this backend — the partitioned lowering drifts
@@ -132,6 +146,21 @@ def resolve_moe_impl(moe_impl: str, shardings=None) -> str:
                 "'dense' (exact) or 'dispatch'")
         if moe_impl == "auto":
             return "dense"
+    if cfg.n_experts and moe_impl in ("auto", "grouped"):
+        from dllama_tpu.ops.matmul import engine_matmul
+        from dllama_tpu.ops.pallas.q40_matmul import expert_supported
+
+        layers = params["layers"]
+        backend = engine_matmul(kernels, shardings).keywords["backend"]
+        ok = (shardings is None and backend == "pallas" and all(
+            expert_supported(layers[w], params["embedding"].dtype)
+            for w in ("moe_w1", "moe_w2", "moe_w3")))
+        if moe_impl == "grouped" and not ok:
+            raise ValueError(
+                "moe_impl='grouped' needs unsharded Pallas kernels, Q40 "
+                "expert weights of k % 256 == 0 and n % 128 == 0, and "
+                "bfloat16 activations")
+        return "grouped" if ok else "auto"
     return moe_impl
 
 
@@ -147,6 +176,7 @@ def resolve_kernels(
     cache_dtype=None,  # KV pool element type (paged capability check);
     # None = bf16, the serving default
     state_dtype=None,  # recurrent state's element type; None = float32
+    moe_impl: str = "auto",  # as resolve_moe_impl left it
 ) -> KernelSelection:
     """Resolution rules:
 
@@ -178,6 +208,10 @@ def resolve_kernels(
 
     state_step, state_route = resolve_state_step(cfg, batch, backend,
                                                  state_dtype)
+    moe = dict(moe_impl=moe_impl, moe_route=(
+        "" if not cfg.n_experts else
+        "grouped" if moe_impl == "grouped" else "jnp"))
+    windowed = cfg.n_window_layers > 0
 
     if paged and shardings is None:
         # paged KV cache (BatchEngine --kv-layout paged; unsharded only — the
@@ -209,12 +243,13 @@ def resolve_kernels(
             kv_dtype=cache_dtype if cache_dtype is not None else jnp.bfloat16,
         ) and (attn_impl == "flash" or on_tpu):
             def attn_fn(q, k_pool, v_pool, tables, pos, new_k, new_v, active,
-                        layer):
+                        layer, **window):
                 # the pools are the whole layer-stacked arrays: the kernel
-                # indexes `layer` (models/llama.run_layers carries them)
+                # indexes `layer` (models/llama.run_layers carries them); a
+                # windowed layer brings window=W and the sweep clips its walk
                 return paged_decode_attention(
                     q, k_pool, v_pool, tables, pos, new_k, new_v, active,
-                    layer=layer, interpret=not on_tpu)
+                    layer=layer, interpret=not on_tpu, **window)
 
             # models/llama._layer hands the new KV rows to the kernel
             # instead of paying a separate scatter dispatch per layer; the
@@ -225,15 +260,25 @@ def resolve_kernels(
             attn_fn.fused_kv_scatter = True
             route = "paged_kernel"
             fused_cap = FUSED_SCATTER_MAX_T
+        if windowed:
+            route += ".window"  # both paged routes take the window
         return KernelSelection(mm=mm, mm_in=mm_in, attn_fn=attn_fn,
                                backend=backend, attn_route=route,
                                interpret=not on_tpu,
                                state_step=state_step, state_route=state_route,
-                               fused_scatter_max_t=fused_cap)
+                               fused_scatter_max_t=fused_cap, **moe)
 
+    if windowed and shardings is not None:
+        raise ValueError("a model with windowed attention layers serves on "
+                         "one device: no sharded attention route takes a "
+                         "window yet")
     attn_fn = shardings.attn_fn(batch) if shardings is not None else None
     route = "ring" if attn_fn is not None else "jnp"
-    if attn_fn is None and attn_impl != "jnp":
+    if windowed:
+        # the dense layouts' flash kernel takes no window yet: the jnp
+        # attention masks it (the serving path is the paged kernel above)
+        route = "jnp.window"
+    elif attn_fn is None and attn_impl != "jnp":
         from dllama_tpu.ops.pallas.flash_attention import flash_gqa_attention, supported
 
         if supported((cfg.n_heads, cfg.head_size), seq_len):
@@ -254,4 +299,4 @@ def resolve_kernels(
     return KernelSelection(mm=mm, mm_in=mm_in, attn_fn=attn_fn,
                            backend=backend, attn_route=route,
                            interpret=not on_tpu, state_step=state_step,
-                           state_route=state_route)
+                           state_route=state_route, **moe)

@@ -71,12 +71,18 @@ class LaunchRecord:
     kv_rows: int = 0  # KV rows the decode steps attended, over slots and steps
     prefill_rows: int = 0  # prompt rows the launch wrote
     pool_dry: bool = False  # no free page in the pool when it was dispatched
+    kv_rows_window: int | None = None  # a model with windowed layers: the
+    # rows a windowed layer's decode steps READ, min(position + 1, window) a
+    # slot-step (kv_rows is what a layer that sees everything reads)
 
     def args(self) -> dict:
         """The span / annotation arguments (`kind` is in the name too)."""
-        return {"kind": self.kind, "seq": self.seq, "n": self.n,
-                "active": self.active, "starved": self.starved,
-                "kv_rows": self.kv_rows, "prefill_rows": self.prefill_rows}
+        a = {"kind": self.kind, "seq": self.seq, "n": self.n,
+             "active": self.active, "starved": self.starved,
+             "kv_rows": self.kv_rows, "prefill_rows": self.prefill_rows}
+        if self.kv_rows_window is not None:
+            a["kv_rows_window"] = self.kv_rows_window
+        return a
 
     def count(self) -> "LaunchRecord":
         """Into the counters, once per launch, after its call returned (a
@@ -91,6 +97,10 @@ class LaunchRecord:
         if self.prefill_rows:
             ins.LAUNCH_PREFILL_ROWS.labels(kind=self.kind).inc(
                 self.prefill_rows)
+        if self.kv_rows_window is not None and self.kv_rows:
+            read = ins.LAUNCH_KV_ROWS_READ
+            read.labels(kind=self.kind, pool="global").inc(self.kv_rows)
+            read.labels(kind=self.kind, pool="window").inc(self.kv_rows_window)
         return self
 
     def annotation(self):
@@ -106,7 +116,7 @@ class LaunchRecord:
 def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
           active: np.ndarray, advance: np.ndarray, *, seq_len: int,
           pool_dry: bool, prefill_rows: int = 0,
-          frozen: np.ndarray | None = None) -> LaunchRecord:
+          frozen: np.ndarray | None = None, window: int = 0) -> LaunchRecord:
     """The record of a launch of `n` steps over slots at `start_pos`, of
     which the `active` ones advance `advance` rows each.
 
@@ -115,7 +125,9 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
     it reached `seq_len`, or its next row has no page; the second, with no
     free page in the pool (`pool_dry`), is `BatchEngine.page_starved`'s
     condition and counts its frozen steps as starved. `frozen` gives the
-    frozen steps per slot where they are not n - advance (a spec chunk)."""
+    frozen steps per slot where they are not n - advance (a spec chunk).
+    `window` > 0 (a model with windowed layers): also the rows such a layer
+    reads, min(p + 1, window) a step."""
     if kind not in LAUNCH_KINDS:
         raise ValueError(f"unknown launch kind {kind!r} "
                          f"(catalog: {LAUNCH_KINDS})")
@@ -126,9 +138,15 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
     if pool_dry:
         idle = (n - adv) if frozen is None else frozen[active]
         starved = int(idle[pos + adv < seq_len].sum())
+    kv_rows_window = None
+    if window:
+        step = np.arange(1, int(adv.max(initial=0)) + 1, dtype=np.int64)[None]
+        seen = np.minimum(pos[:, None] + step, window)
+        kv_rows_window = int(seen[step <= adv[:, None]].sum())
     return LaunchRecord(
         kind=kind, seq=int(seq), n=int(n), active=n_active,
         advanced=int(adv.sum()), starved=starved,
         empty=(active.size - n_active) * int(n),
         kv_rows=int((adv * pos + adv * (adv + 1) // 2).sum()),
-        prefill_rows=int(prefill_rows), pool_dry=bool(pool_dry))
+        prefill_rows=int(prefill_rows), pool_dry=bool(pool_dry),
+        kv_rows_window=kv_rows_window)

@@ -33,6 +33,7 @@ class LayerKind(IntEnum):
 class HiddenAct(IntEnum):
     GELU = 0
     SILU = 1
+    RELU = 2  # relu(w1 x) * w3 x: the "sparse ReGLU" expert
 
 
 class RopeType(IntEnum):
@@ -82,11 +83,29 @@ class HeaderKey(IntEnum):
     SSM_GROUPS = 113
     SSM_CONV = 114  # conv taps
     SSM_CHUNK = 115  # rows a block of the chunked scan holds
+    # ---- dllama-tpu extensions for attention layers of more than one kind
+    # and for where the expert router reads (absent = today's meaning; with
+    # HEAD_SIZE present in a LLAMA file, n_heads * head size need not be dim)
+    WINDOW_SIZE = 120  # rows a windowed layer's query sees, itself included
+    ROUTER_INPUT = 121  # 1 = the router reads the ATTENTION norm's output
 
 
 #: the kind of layer i is header key LAYER_KIND_BASE + i (one key a layer,
 #: not a period and not a name), value a LayerKind
 LAYER_KIND_BASE = 1000
+#: layer i is windowed iff key LAYER_WINDOW_BASE + i is 1, and rotates q and k
+#: iff key LAYER_ROPE_BASE + i is 1 (absent lists: no layer is windowed, every
+#: layer rotates as ROPE_TYPE says)
+LAYER_WINDOW_BASE = 2000
+LAYER_ROPE_BASE = 3000
+_LAYER_LISTS_END = 4000
+
+#: `LlamaConfig.schedule_kinds` entries: a LayerKind in the low two bits, and
+#: beside it whether the layer is windowed and whether it leaves q and k
+#: unrotated where the model rotates
+SCHEDULE_KIND_MASK = 3
+SCHEDULE_WINDOWED = 4
+SCHEDULE_UNROTATED = 8
 
 
 @dataclasses.dataclass
@@ -126,6 +145,12 @@ class LlamaConfig:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # ---- attention layers of two kinds (defaults are the LLAMA meaning)
+    window: int = 0  # rows a windowed layer's query sees (itself included)
+    layer_windows: tuple = ()  # 0/1 per layer; () = no layer is windowed
+    layer_ropes: tuple = ()  # 0/1 per layer; () = every layer rotates
+    router_pre_attention: bool = False  # the router reads the attention
+    # block's normed input, not the feed-forward block's
 
     def __post_init__(self):
         if self.orig_seq_len == 0:
@@ -134,11 +159,18 @@ class LlamaConfig:
         if self.layer_kinds and len(self.layer_kinds) != self.n_layers:
             raise ValueError(
                 f"{len(self.layer_kinds)} layer kinds for {self.n_layers} layers")
-        if self.head_dim and self.head_dim * self.n_heads != self.dim:
-            raise ValueError(
-                f"head size {self.head_dim} x {self.n_heads} heads != dim "
-                f"{self.dim}: an attention width other than the model's is "
-                "not supported")
+        self.layer_windows = tuple(int(bool(w)) for w in self.layer_windows)
+        self.layer_ropes = tuple(int(bool(r)) for r in self.layer_ropes)
+        for name, flags in (("window", self.layer_windows),
+                            ("rope", self.layer_ropes)):
+            if flags and len(flags) != self.n_layers:
+                raise ValueError(
+                    f"{len(flags)} {name} flags for {self.n_layers} layers")
+        if any(self.layer_windows) and self.window <= 0:
+            raise ValueError("windowed layers need a window size")
+        if any(w and k == LayerKind.SSM for w, k in
+               zip(self.layer_windows, self.layer_kinds)):
+            raise ValueError("a state-space layer cannot be windowed")
         if self.n_ssm_layers and self.ssm_groups != 1:
             raise ValueError("state-space layers with more than one B/C group "
                              "are not supported")
@@ -150,6 +182,39 @@ class LlamaConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_size
+
+    @property
+    def attn_dim(self) -> int:
+        """Columns of wq and rows of wo: heads x head size (the model's dim
+        unless the header gives a head size of its own)."""
+        return self.n_heads * self.head_size
+
+    @property
+    def n_window_layers(self) -> int:
+        return sum(self.layer_windows)
+
+    def layer_window(self, layer: int) -> int:
+        """Rows layer `layer`'s queries see, 0 = the whole context."""
+        return self.window if self.layer_windows and self.layer_windows[layer] else 0
+
+    def layer_rotates(self, layer: int) -> bool:
+        if self.rope_type == RopeType.NONE:
+            return False
+        return bool(self.layer_ropes[layer]) if self.layer_ropes else True
+
+    @property
+    def schedule_kinds(self) -> tuple:
+        """What `models/llama.layer_schedule` groups by: a layer's kind, and
+        beside it SCHEDULE_WINDOWED and SCHEDULE_UNROTATED. () for a
+        homogeneous stack."""
+        if not (self.layer_kinds or self.layer_windows or self.layer_ropes):
+            return ()
+        kinds = self.layer_kinds or (int(LayerKind.ATTENTION),) * self.n_layers
+        return tuple(
+            int(k) + (SCHEDULE_WINDOWED if self.layer_window(i) else 0)
+            + (SCHEDULE_UNROTATED if k == LayerKind.ATTENTION
+               and self.layer_ropes and not self.layer_ropes[i] else 0)
+            for i, k in enumerate(kinds))
 
     @property
     def n_ssm_layers(self) -> int:
@@ -201,6 +266,7 @@ class LlamaConfig:
             f"act={self.hidden_act.name} rope={self.rope_type.name} "
             f"weights={self.weight_type.name}"
             + (f" experts={self.n_experts}/{self.n_active_experts}" if self.n_experts else "")
+            + (f" window={self.window}x{self.n_window_layers}" if self.n_window_layers else "")
             + (f" ssm_layers={self.n_ssm_layers}/{self.n_layers} "
                f"ssm={self.ssm_heads}x{self.ssm_head_dim}x{self.ssm_state}"
                if self.recurrent else "")
@@ -250,13 +316,31 @@ class LlamaConfig:
             kv.append((HeaderKey.TIED_HEAD, int(self.tied_head)))
             kv += [(key, getattr(self, name)) for key, name in _SSM_INT_KEYS.items()]
             kv += [(LAYER_KIND_BASE + i, k) for i, k in enumerate(self.layer_kinds)]
+        elif self.head_dim:
+            kv.append((HeaderKey.HEAD_SIZE, self.head_size))
+        if self.router_pre_attention:
+            kv.append((HeaderKey.ROUTER_INPUT, 1))
+        if self.window:
+            kv.append((HeaderKey.WINDOW_SIZE, self.window))
+        kv += [(LAYER_WINDOW_BASE + i, w) for i, w in enumerate(self.layer_windows)]
+        kv += [(LAYER_ROPE_BASE + i, r) for i, r in enumerate(self.layer_ropes)]
         return [(int(k), int(v)) for k, v in kv]
 
     @classmethod
     def from_header_kv(cls, kv: list[tuple[int, int]]) -> "LlamaConfig":
         vals: dict = {}
         kinds: dict = {}
+        windows: dict = {}
+        ropes: dict = {}
         for key, value in kv:
+            if key >= _LAYER_LISTS_END:
+                raise ValueError(f"unknown header key {key}")
+            if key >= LAYER_ROPE_BASE:
+                ropes[key - LAYER_ROPE_BASE] = value
+                continue
+            if key >= LAYER_WINDOW_BASE:
+                windows[key - LAYER_WINDOW_BASE] = value
+                continue
             if key >= LAYER_KIND_BASE:
                 kinds[key - LAYER_KIND_BASE] = LayerKind(value)
                 continue
@@ -309,6 +393,17 @@ class LlamaConfig:
                 vals["tied_head"] = bool(value)
             elif key in _SSM_INT_KEYS:
                 vals[_SSM_INT_KEYS[key]] = value
+            elif key == HeaderKey.WINDOW_SIZE:
+                vals["window"] = value
+            elif key == HeaderKey.ROUTER_INPUT:
+                vals["router_pre_attention"] = bool(value)
+        for name, flags in (("layer_windows", windows), ("layer_ropes", ropes)):
+            if flags:
+                n = vals.get("n_layers", 0)
+                if sorted(flags) != list(range(n)):
+                    raise ValueError(
+                        f"the header gives {name} for {len(flags)} layers of {n}")
+                vals[name] = tuple(flags[i] for i in range(n))
         if kinds:
             n = vals.get("n_layers", 0)
             if sorted(kinds) != list(range(n)):

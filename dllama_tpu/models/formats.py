@@ -6,6 +6,8 @@ order (llm.cpp:453-468):
 
   embedding f32 [vocab, dim]
   per layer: q [dim,dim] k [kv_dim,dim] v [kv_dim,dim] wo [dim,dim]
+             (q [heads*head_size, dim] and wo [dim, heads*head_size] where
+             the header gives a head size of its own)
              w1 [hidden,dim] w2 [dim,hidden] w3 [hidden,dim]   (weight_type)
              rms_norm_0 f32 [dim], rms_norm_1 f32 [dim]
              (a state-space layer of an ArchType.HYBRID_SSM file holds, in
@@ -117,10 +119,10 @@ def tensor_plan(config: LlamaConfig) -> list[tuple[str, tuple[int, int] | tuple[
             ]
         else:
             plan += [
-                (f"layers.{layer}.wq", (config.dim, config.dim), wt),
+                (f"layers.{layer}.wq", (config.attn_dim, config.dim), wt),
                 (f"layers.{layer}.wk", (config.kv_dim, config.dim), wt),
                 (f"layers.{layer}.wv", (config.kv_dim, config.dim), wt),
-                (f"layers.{layer}.wo", (config.dim, config.dim), wt),
+                (f"layers.{layer}.wo", (config.dim, config.attn_dim), wt),
             ]
         if config.n_experts:
             # MoE extension: the reference header carries N_EXPERTS

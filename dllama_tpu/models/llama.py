@@ -17,9 +17,23 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from dllama_tpu.models.config import LayerKind, LlamaConfig, RopeType
+from dllama_tpu.models.config import (
+    SCHEDULE_KIND_MASK,
+    SCHEDULE_UNROTATED,
+    SCHEDULE_WINDOWED,
+    LayerKind,
+    LlamaConfig,
+    RopeType,
+)
 from dllama_tpu.ops import ssm
-from dllama_tpu.ops.layers import activation, apply_rope, gqa_attention, moe_ffn, rms_norm
+from dllama_tpu.ops.layers import (
+    activation,
+    apply_rope,
+    gqa_attention,
+    moe_ffn,
+    rms_norm,
+    router_logits,
+)
 from dllama_tpu.ops.matmul import matmul
 
 
@@ -105,6 +119,10 @@ class RecurrentState:
                               self.step)
 
 
+def _moe_stats0(cfg: LlamaConfig):
+    return jnp.zeros((4,), jnp.uint32) if cfg.n_experts else None
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class KVCache:
@@ -119,9 +137,12 @@ class KVCache:
     k: jax.Array
     v: jax.Array
     state: RecurrentState | None = None  # where the model has state-space layers
+    moe_stats: jax.Array | None = None  # u32[4] where the model has routed
+    # experts: running sums of (token-expert rows, experts with a row,
+    # layer-steps, longest group), added to by every forward (ops/layers.moe_ffn)
 
     def tree_flatten(self):
-        return (self.k, self.v, self.state), None
+        return (self.k, self.v, self.state, self.moe_stats), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -137,7 +158,8 @@ class KVCache:
         state = (RecurrentState.create(cfg, batch, state_dtype, conv_dtype,
                                        state_step)
                  if cfg.recurrent else None)
-        return cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), state)
+        return cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), state,
+                   _moe_stats0(cfg))
 
     @property
     def seq_len(self) -> int:
@@ -148,12 +170,13 @@ class KVCache:
         axis, its own recurrent state."""
         cut = lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1)
         state = None if self.state is None else self.state.at_slot(slot)
-        return KVCache(cut(self.k), cut(self.v), state)
+        return KVCache(cut(self.k), cut(self.v), state, self.moe_stats)
 
     def merge_slot(self, sub: "KVCache", slot) -> "KVCache":
         put = lambda c, n: jax.lax.dynamic_update_slice_in_dim(c, n, slot, axis=1)
         state = None if sub.state is None else sub.state.at_slot(None)
-        return KVCache(put(self.k, sub.k), put(self.v, sub.v), state)
+        return KVCache(put(self.k, sub.k), put(self.v, sub.v), state,
+                       sub.moe_stats)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -175,15 +198,29 @@ class PagedKVCache:
     Unallocated table entries point at page 0: reads through them surface
     whatever that page holds, which the causal mask zeroes exactly (stale
     pool values are finite, and softmax assigns masked positions
-    probability 0.0 — so paged attention is bit-exact vs dense)."""
+    probability 0.0 — so paged attention is bit-exact vs dense).
+
+    A model with WINDOWED attention layers holds a pool a kind: `k`/`v`/
+    `tables` for the layers that see the whole context ([Lg, Pg+1, ...]) and
+    `kw`/`vw`/`wtables` for the windowed ones ([Lw, Pw+1, ...]), each with
+    its own allocator on the host (engine/batch.PagePool). Both tables are
+    positional (block = row // page); a window block no query can see any
+    more is handed back and its entry points at the window pool's trash
+    page, which the clipped sweep never reads. No window layer: the three
+    are None and everything is as above."""
 
     k: jax.Array
     v: jax.Array
     tables: jax.Array  # i32 [n_slots, max_blocks]
     state: RecurrentState | None = None  # per SLOT, beside the pool
+    kw: jax.Array | None = None
+    vw: jax.Array | None = None
+    wtables: jax.Array | None = None
+    moe_stats: jax.Array | None = None  # as KVCache.moe_stats
 
     def tree_flatten(self):
-        return (self.k, self.v, self.tables, self.state), None
+        return (self.k, self.v, self.tables, self.state, self.kw, self.vw,
+                self.wtables, self.moe_stats), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -193,31 +230,46 @@ class PagedKVCache:
     def create(cls, cfg: LlamaConfig, n_slots: int, n_pages: int,
                page_size: int, dtype=jnp.bfloat16, max_blocks: int = 0,
                lanes: int = 0, state_dtype=jnp.float32,
-               conv_dtype=jnp.bfloat16, state_step=None):
+               conv_dtype=jnp.bfloat16, state_step=None,
+               window_pages: int = 0):
         """``lanes`` widens the row (minor) dim past head_size — the paged
         Pallas kernel needs whole 128-lane rows
         (ops/pallas/paged_attention.pool_lanes); 0 = head_size. The pool's
-        layer axis counts the attention layers (`cfg.n_attn_layers`)."""
-        shape = (cfg.n_attn_layers, n_pages + 1, cfg.n_kv_heads, page_size,
-                 lanes or cfg.head_size)
+        layer axis counts the attention layers (`cfg.n_attn_layers`); with
+        windowed layers, those that are not, and ``window_pages`` sizes the
+        windowed layers' own pool."""
+        lw = cfg.n_window_layers
+        row = (cfg.n_kv_heads, page_size, lanes or cfg.head_size)
+        shape = (cfg.n_attn_layers - lw, n_pages + 1, *row)
         tables = jnp.zeros((n_slots, max_blocks or 1), jnp.int32)
         state = (RecurrentState.create(cfg, n_slots, state_dtype, conv_dtype,
                                        state_step)
                  if cfg.recurrent else None)
-        return cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), tables, state)
+        kw = vw = wtables = None
+        if lw:
+            kw = jnp.zeros((lw, window_pages + 1, *row), dtype)
+            vw = jnp.zeros((lw, window_pages + 1, *row), dtype)
+            # an entry nothing backs points at the window pool's trash page
+            wtables = jnp.full((n_slots, max_blocks or 1), window_pages,
+                               jnp.int32)
+        return cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), tables,
+                   state, kw, vw, wtables, _moe_stats0(cfg))
 
     def slot_view(self, slot) -> "PagedKVCache":
         """The cache as ONE slot's B = 1 forward sees it: its block-table
         row over the global pool, its own recurrent state."""
-        row = jax.lax.dynamic_slice_in_dim(self.tables, slot, 1, axis=0)
+        cut = lambda t: None if t is None else jax.lax.dynamic_slice_in_dim(
+            t, slot, 1, axis=0)
         state = None if self.state is None else self.state.at_slot(slot)
-        return PagedKVCache(self.k, self.v, row, state)
+        return PagedKVCache(self.k, self.v, cut(self.tables), state, self.kw,
+                            self.vw, cut(self.wtables), self.moe_stats)
 
     def merge_slot(self, sub: "PagedKVCache", slot=None) -> "PagedKVCache":
         """Back from `slot_view`: the pools and state `sub` wrote, under
         every slot's tables."""
         state = None if sub.state is None else sub.state.at_slot(None)
-        return PagedKVCache(sub.k, sub.v, self.tables, state)
+        return PagedKVCache(sub.k, sub.v, self.tables, state, sub.kw, sub.vw,
+                            self.wtables, sub.moe_stats)
 
     @property
     def page_size(self) -> int:
@@ -266,12 +318,19 @@ from dllama_tpu.ops.quant import slice_leaf as _slice_layer
 
 
 def _attention_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, rope,
-                     pos_base, attn_fn, active, mm, colmm, tables):
+                     pos_base, attn_fn, active, mm, colmm, tables, ci=None,
+                     window: int = 0):
     """Softmax attention over the cache. `ai` indexes the ATTENTION layers'
-    weight stacks and the cache's layer axis (a hybrid model has fewer of
-    both than it has layers). Returns (out [B, T, D], k_cache, v_cache)."""
-    b, t, d = h.shape
+    weight stacks and `ci` the cache's layer axis (= `ai` unless the cache
+    is a pool a kind; a hybrid model has fewer of both than it has layers).
+    `window` > 0: the layer's queries see that many rows. Returns
+    (out [B, T, D], k_cache, v_cache)."""
+    b, t, _ = h.shape
+    d = cfg.attn_dim  # heads x head size: the model's dim unless the header
+    # gives the head size
     kvd = cfg.kv_dim
+    ci = ai if ci is None else ci
+    win = {"window": window} if window else {}
     if "wqkv" in layers:  # fused launch (fuse_layer_weights)
         qkv = mm(h, layers["wqkv"], ai)
         q, k, v = qkv[..., :d], qkv[..., d : d + kvd], qkv[..., d + kvd :]
@@ -293,7 +352,7 @@ def _attention_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, rope,
     if tables is None:
         k_cache = _cache_update(k_cache, k.transpose(0, 2, 1, 3), pos_base, active)
         v_cache = _cache_update(v_cache, v.transpose(0, 2, 1, 3), pos_base, active)
-        att = attn_fn(q, k_cache, v_cache, pos_base).reshape(b, t, d)
+        att = attn_fn(q, k_cache, v_cache, pos_base, **win).reshape(b, t, d)
     elif getattr(attn_fn, "fused_kv_scatter", False):
         # paged flash-decode kernel: the new rows' scatter write is fused
         # into the attention launch (ops/pallas/paged_attention) — no
@@ -303,14 +362,15 @@ def _attention_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, rope,
         # matmuls index the weight stacks
         att, k_cache, v_cache = attn_fn(
             q, k_cache, v_cache, tables, pos_base,
-            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), active, ai)
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), active, ci,
+            **win)
         att = att.reshape(b, t, d)
     else:  # paged layout: scatter at block-table positions, same math
         k_cache = _paged_cache_update(k_cache, k.transpose(0, 2, 1, 3),
                                       tables, pos_base, active)
         v_cache = _paged_cache_update(v_cache, v.transpose(0, 2, 1, 3),
                                       tables, pos_base, active)
-        att = attn_fn(q, k_cache, v_cache, tables, pos_base).reshape(b, t, d)
+        att = attn_fn(q, k_cache, v_cache, tables, pos_base, **win).reshape(b, t, d)
     return colmm(att, layers["wo"], ai), k_cache, v_cache
 
 
@@ -365,18 +425,18 @@ def _ssm_mixer(cfg: LlamaConfig, h, layers, si, state: RecurrentState,
     return colmm(y, layers["out_proj"], si), state
 
 
-def _mlp(cfg: LlamaConfig, h, layers, li, mm, colmm, moe_impl):
+def _mlp(cfg: LlamaConfig, h, layers, li, mm, colmm, moe_impl, logits=None,
+         stats=None):
     """The feed-forward block (reference "ff" segment, llm.cpp:314-385);
     sparse-MoE variant when the header carries N_EXPERTS (llm.hpp:17-18 —
-    a key the reference parses but never executes)."""
+    a key the reference parses but never executes): `logits` are the
+    router's, computed by `_layer` where the header says the router reads.
+    The expert stacks go in whole with the layer index, as the matmuls'
+    weights do. Returns the block's output, and with `stats` (out, stats')."""
     if "moe_gate" in layers:
         return moe_ffn(
-            cfg, h, layers["moe_gate"][li],
-            _slice_layer(layers["moe_w1"], li),
-            _slice_layer(layers["moe_w2"], li),
-            _slice_layer(layers["moe_w3"], li),
-            impl=moe_impl,
-        )
+            cfg, h, None, layers["moe_w1"], layers["moe_w2"], layers["moe_w3"],
+            impl=moe_impl, logits=logits, layer=li, stats=stats)
     if "w13" in layers:  # fused launch (fuse_layer_weights)
         gu = mm(h, layers["w13"], li)
         f = cfg.hidden_dim
@@ -388,7 +448,7 @@ def _mlp(cfg: LlamaConfig, h, layers, li, mm, colmm, moe_impl):
 
 
 def _layer(cfg: LlamaConfig, x, layers, li, mix, col_fn=None, mm=None,
-           mm_in=None, moe_impl="auto"):
+           mm_in=None, moe_impl="auto", moe_stats=None):
     """One decoder layer: the ONE skeleton every architecture runs,
 
         x += r * mix(norm(x));  x += r * mlp(norm(x))
@@ -424,10 +484,21 @@ def _layer(cfg: LlamaConfig, x, layers, li, mix, col_fn=None, mm=None,
     scaled = (lambda y: y) if r == 1.0 else (lambda y: y * jnp.asarray(r, y.dtype))
     # --- mixer block (reference "att" segment, llm.cpp:198-312)
     h = rms_norm(x, layers["rms_att"][li], cfg.norm_epsilon)
+    experts = "moe_gate" in layers
+    logits = None
+    if experts and cfg.router_pre_attention:
+        # the router reads the attention block's normed input
+        logits = router_logits(h, layers["moe_gate"][li])
     out, aux = mix(h, mm, colmm)
     x = x + scaled(out)
     h = rms_norm(x, layers["rms_ffn"][li], cfg.norm_epsilon)
-    return x + scaled(_mlp(cfg, h, layers, li, mm, colmm, moe_impl)), aux
+    if experts and logits is None:
+        logits = router_logits(h, layers["moe_gate"][li])
+    y = _mlp(cfg, h, layers, li, mm, colmm, moe_impl, logits, moe_stats)
+    if moe_stats is not None:
+        y, moe_stats = y
+        return x + scaled(y), (aux, moe_stats)
+    return x + scaled(y), aux
 
 
 def fuse_layer_weights(layers: dict) -> dict:
@@ -506,9 +577,20 @@ def run_layers(
     tables: jax.Array | None = None,  # i32 [B, max_blocks] block tables —
     # presence selects the paged cache layout (k/v are then page pools)
     state: "RecurrentState | None" = None,  # the state-space layers' state
-) -> tuple[jax.Array, jax.Array, jax.Array, "RecurrentState | None"]:
+    wpool: tuple | None = None,  # (kw, vw, wtables): the windowed layers'
+    # own page pool and block tables (PagedKVCache); k/v/tables are then the
+    # other attention layers'
+    moe_stats: jax.Array | None = None,  # u32[4] expert counters to add to
+) -> tuple:
     """Scan the decoder layers (any contiguous stack — the full model, or one
-    pipeline stage's slice). Returns (x, k_cache, v_cache, state).
+    pipeline stage's slice). Returns (x, k_cache, v_cache, state), and with
+    `wpool` or `moe_stats` two more: the window pools (kw, vw) and the
+    counters.
+
+    A layer's kind is what `cfg.schedule_kinds` gives: attention or
+    state-space, windowed or not, rotated or not. A windowed layer is handed
+    `window=cfg.window` (its attention masks, and the paged sweep clips its
+    walk); a layer the header leaves unrotated gets no rope.
 
     The layers are scanned BY PERIOD of the layer pattern
     (`layer_schedule`): the scan's body holds each run of equal layers once
@@ -542,80 +624,121 @@ def run_layers(
             from dllama_tpu.ops.layers import paged_gqa_attention
 
             attn_fn = paged_gqa_attention
-    kinds = cfg.layer_kinds or (int(LayerKind.ATTENTION),) * k_cache.shape[0]
+    kinds = cfg.schedule_kinds or (int(LayerKind.ATTENTION),) * k_cache.shape[0]
     period, runs = layer_schedule(kinds)
     n_periods = len(kinds) // period
-    a_pp = sum(n for kind, _, n in runs if kind == LayerKind.ATTENTION)
+    is_attn_kind = lambda kind: (kind & SCHEDULE_KIND_MASK) == LayerKind.ATTENTION
+    a_pp = sum(n for kind, _, n in runs if is_attn_kind(kind))
     s_pp = period - a_pp
-    fused = tables is not None and getattr(attn_fn, "fused_kv_scatter", False)
+    two_pools = wpool is not None  # a pool a kind: (kw, vw, wtables)
+    w_pp = (sum(n for kind, _, n in runs if kind & SCHEDULE_WINDOWED)
+            if two_pools else 0)
+    kernel = tables is not None and getattr(attn_fn, "fused_kv_scatter", False)
+    # the pools ride in the carry where the kernel indexes the layer itself,
+    # and wherever there are two of them (a layer of the other routes then
+    # cuts its slice out of the carried stack and puts it back: the CPU route)
+    fused = kernel or two_pools
+    kwp, vwp, wtables = wpool if two_pools else (None, None, None)
 
-    def one_layer(x, kc, vc, st, li, ai, si, kind):
+    def one_layer(x, kc, vc, st, kw, vw, ms, li, ai, ci, si, kind):
         """Layer `li` of kind `kind`; kc/vc are the whole pools (fused) or
-        this layer's slice."""
-        if kind == LayerKind.SSM:
+        this layer's slice; `ci` is the layer's index into ITS pool."""
+        if not is_attn_kind(kind):
             def mix(h, mm_, colmm):
                 return _ssm_mixer(cfg, h, layer_params, si, st, pos_base,
                                   active, mm_, colmm)
 
             x, st = _layer(cfg, x, layer_params, li, mix, col_fn, mm, mm_in,
-                           moe_impl)
-            return x, kc, vc, st
+                           moe_impl, ms)
+            if ms is not None:
+                st, ms = st
+            return x, kc, vc, st, kw, vw, ms
+
+        windowed = bool(kind & SCHEDULE_WINDOWED)
+        in_wpool = windowed and two_pools
+        pk, pv = (kw, vw) if in_wpool else (kc, vc)
+        tbl = wtables if in_wpool else tables
+        lrope = None if kind & SCHEDULE_UNROTATED else rope
 
         def mix(h, mm_, colmm):
-            out, k2, v2 = _attention_mixer(cfg, h, layer_params, ai, kc, vc,
-                                           rope, pos_base, attn_fn, active,
-                                           mm_, colmm, tables)
+            # the kernel indexes the layer in the carried stack; any other
+            # route is handed the layer's slice (cut here where the stack is
+            # carried, the scan's xs elsewhere)
+            cut = fused and not kernel
+            ks, vs = (pk[ci], pv[ci]) if cut else (pk, pv)
+            out, k2, v2 = _attention_mixer(
+                cfg, h, layer_params, ai, ks, vs, lrope, pos_base, attn_fn,
+                active, mm_, colmm, tbl, ci, cfg.window if windowed else 0)
+            if cut:
+                k2 = jax.lax.dynamic_update_index_in_dim(pk, k2, ci, 0)
+                v2 = jax.lax.dynamic_update_index_in_dim(pv, v2, ci, 0)
             return out, (k2, v2)
 
-        x, (kc, vc) = _layer(cfg, x, layer_params, li, mix, col_fn, mm, mm_in,
-                             moe_impl)
-        return x, kc, vc, st
+        x, aux = _layer(cfg, x, layer_params, li, mix, col_fn, mm, mm_in,
+                        moe_impl, ms)
+        if ms is not None:
+            aux, ms = aux
+        if in_wpool:
+            kw, vw = aux
+        else:
+            kc, vc = aux
+        return x, kc, vc, st, kw, vw, ms
 
     def period_fn(carry, xs):
         """One period: each run of equal layers once. Fused: the pools are
         in the carry. Else: `kx`/`vx` are this period's [a_pp, ...] slices
         (the scan's xs), rebuilt into its ys."""
-        x, kp, vp, st = carry
+        x, kp, vp, st, kw, vw, ms = carry
         pi, kx, vx = xs
         if not fused and a_pp == 1:  # the xs ARE the layer's slices
             kx, vx = kx[None], vx[None]
         k_out, v_out = [], []
-        a_off = s_off = 0
+        a_off = s_off = w_off = 0
         for kind, off, n in runs:
-            is_attn = kind == LayerKind.ATTENTION
+            is_attn = is_attn_kind(kind)
             li0 = pi * period + off
             ai0, si0 = pi * a_pp + a_off, pi * s_pp + s_off
+            # the layer's index into its own pool: window layers count
+            # among themselves where they have a pool of their own
+            if two_pools and kind & SCHEDULE_WINDOWED:
+                ci0 = pi * w_pp + w_off
+            elif two_pools:
+                ci0 = pi * (a_pp - w_pp) + (a_off - w_off)
+            else:
+                ci0 = None  # one pool: the attention layers' own count
 
             def run_fn(c, j_kv, kind=kind, li0=li0, ai0=ai0, si0=si0,
-                       is_attn=is_attn):
-                x, kp, vp, st = c
+                       ci0=ci0, is_attn=is_attn):
+                x, kp, vp, st, kw, vw, ms = c
                 j, kc, vc = j_kv
+                li, ai = li0 + j, ai0 + j
+                ci = ai if ci0 is None else ci0 + j
                 if fused or not is_attn:
-                    x, kp, vp, st = one_layer(x, kp, vp, st, li0 + j, ai0 + j,
-                                              si0 + j, kind)
-                    return (x, kp, vp, st), None
-                x, kc, vc, st = one_layer(x, kc, vc, st, li0 + j, ai0 + j,
-                                          si0 + j, kind)
-                return (x, kp, vp, st), (kc, vc)
+                    return one_layer(x, kp, vp, st, kw, vw, ms, li, ai, ci,
+                                     si0 + j, kind), None
+                x, kc, vc, st, kw, vw, ms = one_layer(
+                    x, kc, vc, st, kw, vw, ms, li, ai, ci, si0 + j, kind)
+                return (x, kp, vp, st, kw, vw, ms), (kc, vc)
 
             sliced = is_attn and not fused
             kr = kx[a_off : a_off + n] if sliced else None
             vr = vx[a_off : a_off + n] if sliced else None
+            c = (x, kp, vp, st, kw, vw, ms)
             if n == 1:
-                c, ys = run_fn((x, kp, vp, st), (
+                c, ys = run_fn(c, (
                     0, kr[0] if sliced else None, vr[0] if sliced else None))
                 if sliced:
                     ys = (ys[0][None], ys[1][None])
             else:
                 c, ys = jax.lax.scan(
-                    run_fn, (x, kp, vp, st),
-                    (jnp.arange(n, dtype=jnp.int32), kr, vr))
-            x, kp, vp, st = c
+                    run_fn, c, (jnp.arange(n, dtype=jnp.int32), kr, vr))
+            x, kp, vp, st, kw, vw, ms = c
             if sliced:
                 k_out.append(ys[0])
                 v_out.append(ys[1])
             if is_attn:
                 a_off += n
+                w_off += n if kind & SCHEDULE_WINDOWED else 0
             else:
                 s_off += n
         ys = None
@@ -624,19 +747,22 @@ def run_layers(
                   jnp.concatenate(v_out) if len(v_out) > 1 else v_out[0])
             if a_pp == 1:
                 ys = (ys[0][0], ys[1][0])
-        return (x, kp, vp, st), ys
+        return (x, kp, vp, st, kw, vw, ms), ys
 
     period_ids = jnp.arange(n_periods, dtype=jnp.int32)
+    extra = lambda out: out + (((kwp, vwp) if two_pools else None, moe_stats)
+                               if two_pools or moe_stats is not None else ())
     if fused:
-        (x, k_new, v_new, state), _ = jax.lax.scan(
-            period_fn, (x, k_cache, v_cache, state), (period_ids, None, None),
-            unroll=unroll)
-        return x, k_new, v_new, state
+        (x, k_new, v_new, state, kwp, vwp, moe_stats), _ = jax.lax.scan(
+            period_fn, (x, k_cache, v_cache, state, kwp, vwp, moe_stats),
+            (period_ids, None, None), unroll=unroll)
+        return extra((x, k_new, v_new, state))
     by_period = lambda c: c if a_pp == 1 else c.reshape(n_periods, a_pp, *c.shape[1:])
-    (x, _, _, state), (k_new, v_new) = jax.lax.scan(
-        period_fn, (x, None, None, state),
+    (x, _, _, state, _, _, moe_stats), (k_new, v_new) = jax.lax.scan(
+        period_fn, (x, None, None, state, None, None, moe_stats),
         (period_ids, by_period(k_cache), by_period(v_cache)), unroll=unroll)
-    return x, k_new.reshape(k_cache.shape), v_new.reshape(v_cache.shape), state
+    return extra((x, k_new.reshape(k_cache.shape), v_new.reshape(v_cache.shape),
+                  state))
 
 
 def forward(
@@ -689,18 +815,28 @@ def forward(
     else:
         rope = jax.lax.dynamic_slice_in_dim(rope_cache, pos_base, t, axis=0)
     paged = isinstance(cache, PagedKVCache)
-    x, k_new, v_new, state = run_layers(
+    wpool = ((cache.kw, cache.vw, cache.wtables)
+             if paged and cache.kw is not None else None)
+    out = run_layers(
         cfg, params["layers"], x, pos_base, cache.k, cache.v, rope, attn_fn, active,
         unroll=unroll, col_fn=col_fn, mm=mm, mm_in=mm_in, moe_impl=moe_impl,
         tables=cache.tables if paged else None, state=cache.state,
+        wpool=wpool, moe_stats=cache.moe_stats,
     )
+    x, k_new, v_new, state = out[:4]
+    more = {}
+    if len(out) > 4:
+        more["moe_stats"] = out[5]
+        if wpool is not None:
+            more.update(kw=out[4][0], vw=out[4][1])
     if last_only:
         x = x[:, -1:]
     x = rms_norm(x, params["final_norm"], cfg.norm_epsilon)
     logits = (mm or matmul)(x, params["wcls"]).astype(jnp.float32)
     if cfg.logits_scaling != 1.0:
         logits = logits / cfg.logits_scaling
-    return logits, dataclasses.replace(cache, k=k_new, v=v_new, state=state)
+    return logits, dataclasses.replace(cache, k=k_new, v=v_new, state=state,
+                                       **more)
 
 
 def random_params_fast(cfg: LlamaConfig, seed: int = 0, dtype=jnp.bfloat16):
@@ -723,10 +859,10 @@ def random_params_fast(cfg: LlamaConfig, seed: int = 0, dtype=jnp.bfloat16):
 
     L = cfg.n_layers
     layers: dict = {
-        "wq": qw((L,), cfg.dim, cfg.dim),
+        "wq": qw((L,), cfg.dim, cfg.attn_dim),
         "wk": qw((L,), cfg.dim, cfg.kv_dim),
         "wv": qw((L,), cfg.dim, cfg.kv_dim),
-        "wo": qw((L,), cfg.dim, cfg.dim),
+        "wo": qw((L,), cfg.attn_dim, cfg.dim),
         "w1": qw((L,), cfg.dim, cfg.hidden_dim),
         "w2": qw((L,), cfg.hidden_dim, cfg.dim),
         "w3": qw((L,), cfg.dim, cfg.hidden_dim),
@@ -760,10 +896,10 @@ def random_params(cfg: LlamaConfig, seed: int = 0, dtype=jnp.bfloat16, quantize:
         return jax.tree.map(lambda *xs: jnp.stack(xs, 0), *leaves)
 
     layers: dict = {
-        "wq": stack(lambda: w(cfg.dim, cfg.dim)),
+        "wq": stack(lambda: w(cfg.dim, cfg.attn_dim)),
         "wk": stack(lambda: w(cfg.dim, cfg.kv_dim)),
         "wv": stack(lambda: w(cfg.dim, cfg.kv_dim)),
-        "wo": stack(lambda: w(cfg.dim, cfg.dim)),
+        "wo": stack(lambda: w(cfg.attn_dim, cfg.dim)),
         "rms_att": stack(lambda: jnp.ones((cfg.dim,), jnp.float32)),
         "rms_ffn": stack(lambda: jnp.ones((cfg.dim,), jnp.float32)),
     }

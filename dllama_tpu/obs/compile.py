@@ -284,16 +284,25 @@ class _Scope:
     scope's (fn, key). Cheap when nothing compiles: one threadlocal push/
     pop and a zero-check."""
 
-    __slots__ = ("ledger", "fn", "key", "sig", "t0",
+    __slots__ = ("ledger", "fn", "key", "sig", "t0", "record",
                  "trace_s", "lower_s", "compile_s", "n_backend")
 
-    def __init__(self, ledger, fn, key, sig):
+    def __init__(self, ledger, fn, key, sig, record=True):
         self.ledger = ledger
         self.fn = fn
         self.key = str(key)
         self.sig = sig
+        self.record = record  # False: a meter (CompileLedger.meter)
         self.trace_s = self.lower_s = self.compile_s = 0.0
         self.n_backend = 0
+
+    def absorb(self, meter: "_Scope") -> None:
+        """Count what `meter` measured ahead of this dispatch (the warm-up's
+        lowering and pooled compile of the same program) as this scope's."""
+        self.trace_s += meter.trace_s
+        self.lower_s += meter.lower_s
+        self.compile_s += meter.compile_s
+        self.n_backend += meter.n_backend
 
     def __enter__(self):
         self.t0 = time.monotonic()
@@ -302,7 +311,7 @@ class _Scope:
 
     def __exit__(self, *exc):
         self.ledger._pop(self)
-        if self.trace_s or self.lower_s or self.compile_s:
+        if self.record and (self.trace_s or self.lower_s or self.compile_s):
             self.ledger._record(self, time.monotonic())
         return False
 
@@ -379,6 +388,14 @@ class CompileLedger:
         thunk — evaluated only when a compile actually happened."""
         self.ensure_listener()
         return _Scope(self, fn, key, sig)
+
+    def meter(self) -> _Scope:
+        """A scope that only measures: compile events inside it add to its
+        seconds and no entry is recorded. The warm-up lowers a program on
+        one thread and compiles it on another under one meter, and the
+        program's dispatch scope then `absorb`s it: one entry a program."""
+        self.ensure_listener()
+        return _Scope(self, "", "", None, record=False)
 
     def _stack(self) -> list:
         st = getattr(self._tls, "stack", None)

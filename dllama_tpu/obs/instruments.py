@@ -244,6 +244,20 @@ KV_PAGES_SHARED = metrics.gauge(
     "dllama_kv_pages_shared",
     "Paged KV cache: pages with more than one referent — several slots, "
     "or a slot plus the radix prefix tree (copy-on-write prefix sharing)")
+KV_POOL_PAGES_TOTAL = metrics.gauge(
+    "dllama_kv_pool_pages_total",
+    "Paged KV cache of a model with windowed attention layers: usable pages "
+    "by pool (global = the layers that see the whole context, window = the "
+    "windowed layers'); dllama_kv_pages_total is their sum",
+    ("pool",))
+KV_POOL_PAGES_USED = metrics.gauge(
+    "dllama_kv_pool_pages_used",
+    "Pages referenced by a slot, by pool (see dllama_kv_pool_pages_total)",
+    ("pool",))
+KV_WINDOW_PAGES_RELEASED = metrics.counter(
+    "dllama_kv_window_pages_released_total",
+    "Window-pool pages handed back while their request ran: blocks that "
+    "fell wholly behind a slot's window (PagePool.free_head)")
 KV_HOST_PAGES_TOTAL = metrics.gauge(
     "dllama_kv_host_pages_total",
     "Host-RAM KV spill tier (--kv-host-pages): page slots in the pinned "
@@ -324,6 +338,30 @@ LAUNCH_PREFILL_ROWS = metrics.counter(
     "Prompt rows written by the launches, by kind (a hybrid launch's "
     "slice, a prefill chunk)",
     ("kind",))
+LAUNCH_KV_ROWS_READ = metrics.counter(
+    "dllama_launch_kv_rows_read_total",
+    "KV rows a launch's decode steps READ per attention layer of a pool, by "
+    "kind and pool: in the global pool the rows attended (as "
+    "dllama_launch_kv_rows_total), in the window pool min(position + 1, "
+    "window) a slot-step; only for a model with windowed layers",
+    ("kind", "pool"))
+# routed experts (ops/layers.moe_ffn): summed on the device over layers and
+# steps, fetched with a launch's tokens (BatchEngine.decode_consume)
+MOE_ASSIGNMENTS = metrics.counter(
+    "dllama_moe_assignments_total",
+    "Token-expert rows the expert layers computed (rows x active experts, "
+    "over layers and steps)")
+MOE_EXPERTS_TOUCHED = metrics.counter(
+    "dllama_moe_experts_touched_total",
+    "Experts that received at least one row, summed over layer-steps: the "
+    "expert weights a step had to read")
+MOE_LAYER_STEPS = metrics.counter(
+    "dllama_moe_layer_steps_total",
+    "Expert-layer forward calls the two counters above are summed over")
+MOE_GROUP_ROWS_MAX = metrics.counter(
+    "dllama_moe_group_rows_max_total",
+    "The longest expert group of each layer-step, summed: over layer-steps "
+    "and against assignments / experts it is the load skew")
 # recurrent (state-space) models: the per-slot state beside the page pool
 RECURRENT_STATE_BYTES = metrics.gauge(
     "dllama_recurrent_state_bytes",

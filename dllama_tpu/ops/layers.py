@@ -8,6 +8,7 @@ attention accumulate in f32 regardless of activation dtype.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -41,6 +42,8 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
 def activation(x: jax.Array, act: HiddenAct) -> jax.Array:
     if act == HiddenAct.SILU:
         return jax.nn.silu(x)
+    if act == HiddenAct.RELU:
+        return jax.nn.relu(x)
     return jax.nn.gelu(x, approximate=False)
 
 
@@ -111,52 +114,160 @@ def _dense_w(w, dtype):
     return w.dequantize(dtype) if isinstance(w, QTensor) else w.astype(dtype)
 
 
+def router_logits(h: jax.Array, gate: jax.Array) -> jax.Array:
+    """The router's [B, T, E] logits in float32, whatever h is stored as:
+    float32 operands at the highest matmul precision (the TPU's default
+    would round the router's weights to bfloat16 inside the MXU; E columns
+    cost nothing), because a logit decides WHICH experts a row meets."""
+    return jnp.einsum("btd,de->bte", h.astype(jnp.float32),
+                      gate.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def expert_tile_rows(rows: int, e: int) -> int:
+    """Rows a tile of the grouped expert kernel holds: a whole bf16 tile (16)
+    while an expert sees a few rows (a decode step), 32 once the mean group
+    passes a tile (a prefill slice), so the MXU's passes are not mostly pad."""
+    return 16 if rows <= 16 * e else 32
+
+
+def expert_groups(topi: jax.Array, e: int, tm: int):
+    """The layout the grouped expert kernel reads, from the router's choices
+    (argsort, cumulative sums and gathers; no scatter). `topi` [N, k] expert
+    ids. The N*k (token, choice) rows are put in expert order and each
+    expert's group is padded to whole tiles of `tm` rows; T = min(E, N*k) +
+    N*k // tm tiles always hold them. Returns
+
+      src   i32[T*tm]  the token whose row stands at each padded position
+                       (pad rows repeat a real token: finite, never read back)
+      pos   i32[N, k]  where (token, choice) stands in the padded order
+      tile_expert i32[T], tile_src i32[T]  for the kernel's index maps: the
+                       expert a tile reads and the tile's own index, both
+                       frozen at the last live tile for the dead ones behind
+                       it, so a dead tile moves no bytes
+      n_live i32       tiles that hold a row
+      sizes i32[E]     rows an expert received"""
+    n, k = topi.shape
+    r = n * k
+    t = min(e, r) + r // tm
+    assign = topi.reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(assign)  # stable: a group stays in token order
+    sizes = jnp.bincount(assign, length=e).astype(jnp.int32)
+    tiles = (sizes + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles)
+    tile_start = tile_end - tiles
+    group_start = jnp.cumsum(sizes) - sizes
+    n_live = tile_end[-1]
+    last = jnp.maximum(n_live - 1, 0)
+    tile_ids = jnp.minimum(jnp.arange(t, dtype=jnp.int32), last)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, tile_ids, side="right"), e - 1).astype(jnp.int32)
+    # padded position p = tile * tm + lane holds row `rank` of its expert
+    p_tile = jnp.repeat(tile_ids, tm)
+    p_exp = jnp.repeat(tile_expert, tm)
+    lane = jnp.tile(jnp.arange(tm, dtype=jnp.int32), t)
+    rank = (p_tile - tile_start[p_exp]) * tm + lane
+    sorted_ix = jnp.where(rank < sizes[p_exp], group_start[p_exp] + rank, 0)
+    src = order[jnp.clip(sorted_ix, 0, r - 1)] // k
+    # (token, choice) a -> sorted index inv[a] -> padded position
+    rank_in_group = jnp.argsort(order) - group_start[assign]
+    pos = (tile_start[assign] * tm + rank_in_group).reshape(n, k)
+    return (src.astype(jnp.int32), pos.astype(jnp.int32), tile_expert,
+            tile_ids, n_live.astype(jnp.int32), sizes)
+
+
 def moe_ffn(
     cfg: LlamaConfig,
     h: jax.Array,  # [B, T, D] (already rms-normed)
-    gate: jax.Array,  # router [D, E] f32
-    w1, w2, w3,  # expert stacks: [E, D, F], [E, F, D], [E, D, F] (QTensor or dense)
-    impl: str = "auto",  # 'auto' | 'dispatch' | 'sort' | 'dense'
+    gate: jax.Array | None,  # router [D, E] f32 (None where `logits` is given)
+    w1, w2, w3,  # expert stacks: [E, D, F], [E, F, D], [E, D, F] (QTensor or
+    # dense); with `layer` the layer-stacked [L, E, ...] arrays themselves
+    impl: str = "auto",  # 'auto' | 'grouped' | 'dispatch' | 'sort' | 'dense'
     capacity_factor: float = 2.0,
-) -> jax.Array:
-    """Mixtral-style sparse MoE FFN: top-k router (softmax over the top-k
-    logits), SwiGLU experts, probability-weighted combine.
+    logits: jax.Array | None = None,  # [B, T, E] f32 router logits computed
+    # by the caller (which knows WHERE the router reads)
+    layer=None,  # traced layer index into layer-stacked expert weights
+    stats: jax.Array | None = None,  # u32[4] running counters, see below
+):
+    """Sparse MoE FFN: softmax over the top-k router logits (= softmax over
+    all, top k, renormalised), gated experts act(w1 x) * w3 x -> w2 with the
+    header's activation, probability-weighted combine in float32.
 
     The reference *parses* N_EXPERTS from the header and its converter emits
     expert tensors, but the runtime has no MoE graph (SURVEY.md §2.4 — EP row);
     this is the capability it never shipped.
 
-    Three compute schemes:
-    * ``sort`` (default for T*B >= E): MegaBlocks-style grouped GEMM — sort
-      the N*k (token, choice) rows by expert id (argsort + gathers, no
-      scatters) and run ragged segment matmuls (``lax.ragged_dot``). Exact
-      like dense (no capacity drops), O(k/E) FLOPs like dispatch, and none
-      of dispatch's scatter risk on TPU.
+    Compute schemes:
+    * ``grouped`` (what `auto` resolves to where the Q40 Pallas kernels
+      serve, engine/kernel_select.resolve_moe_impl): the rows are put in
+      expert order (`expert_groups`) and ONE grouped Q40 kernel a projection
+      (ops/pallas/q40_matmul.q40_expert_matmul) reads each touched expert's
+      packed tile and scales from the stacked [L, E, ...] weights by
+      scalar-prefetched (layer, expert, tile); experts with no row move no
+      bytes and no dequantised copy of an expert is ever written to HBM.
+    * ``sort`` (the jnp default for T*B >= E): MegaBlocks-style grouped GEMM
+      — sort the N*k (token, choice) rows by expert id (argsort + gathers, no
+      scatters) and run ragged segment matmuls (``lax.ragged_dot``) over the
+      DEQUANTISED expert stack. Exact like dense (no capacity drops).
     * ``dispatch``: GShard-style capacity-bucketed dispatch — each expert
-      processes a fixed buffer of C = ~cf*k*N/E token rows (static shapes),
-      so FLOPs are O(k/E) of dense. Tokens over an expert's capacity lose
-      that expert's contribution (standard switch-transformer semantics;
-      cf=2 makes drops rare), and the ``.at[].add`` combine may serialize
-      on TPU (VERDICT r3 weak #6) — kept for the window A/B.
+      processes a fixed buffer of C = ~cf*k*N/E token rows (static shapes).
+      Tokens over an expert's capacity lose that expert's contribution
+      (standard switch-transformer semantics; cf=2 makes drops rare), and
+      the ``.at[].add`` combine may serialize on TPU; never timed on a chip.
     * ``dense``: every expert runs on every token, combine weights zero the
       unrouted ones. Exact (no capacity drops) and gather-free — the
       correctness reference, and the cheaper choice for tiny batches where
       capacity C would equal N anyway.
-    """
+
+    The jnp schemes dequantise the whole expert stack (`_dense_w`): they are
+    the CPU route and the parity reference, not what a chip serves.
+
+    With ``stats`` (u32[4]) the call returns ``(out, stats')``: the counters
+    plus this call's token-expert rows, experts with a row, 1 (a layer-step)
+    and the longest group — summed on the device, read by the engine with a
+    launch's tokens (obs/instruments MOE_*)."""
     e, k = cfg.n_experts, cfg.n_active_experts
     b, t, d = h.shape
     n = b * t
     if impl == "auto":
-        # sort over dispatch: exact (no capacity drops), scatter-free (the
-        # .at[].add scatters VERDICT r3 weak #6 suspects serialize on TPU),
-        # 2.3x faster on CPU, and AOT-accepted for v5e/v6e (MOSAIC_AOT.md);
-        # bench_moe's window A/B re-decides this with hardware numbers
         impl = "sort" if n >= e else "dense"
-    logits = jnp.einsum(
-        "btd,de->bte", h.astype(jnp.float32), gate.astype(jnp.float32)
-    )
-    topv, topi = jax.lax.top_k(logits, k)
+    if logits is None:
+        logits = router_logits(h, gate)
+    topv, topi = jax.lax.top_k(logits.astype(jnp.float32), k)
     probs = jax.nn.softmax(topv, axis=-1)  # [B, T, k]
+    if layer is not None and impl != "grouped":
+        from dllama_tpu.ops.quant import slice_leaf
+
+        w1, w2, w3 = (slice_leaf(w, layer) for w in (w1, w2, w3))
+
+    def done(out, sizes=None):
+        out = out.reshape(b, t, d).astype(h.dtype)
+        if stats is None:
+            return out
+        if sizes is None:
+            sizes = jnp.bincount(topi.reshape(-1), length=e)
+        return out, stats + jnp.stack(
+            [jnp.asarray(n * k), jnp.count_nonzero(sizes), jnp.asarray(1),
+             sizes.max()]).astype(stats.dtype)
+
+    if impl == "grouped":
+        from dllama_tpu.ops.matmul import device_platform
+        from dllama_tpu.ops.pallas.q40_matmul import q40_expert_matmul
+
+        tm = expert_tile_rows(n * k, e)
+        src, pos, tile_expert, tile_src, n_live, sizes = expert_groups(
+            topi.reshape(n, k), e, tm)
+        xs = h.reshape(n, d)[src]  # [T*tm, D] rows in padded expert order
+        mm = functools.partial(
+            q40_expert_matmul, layer=layer, tile_expert=tile_expert,
+            tile_src=tile_src, n_live=n_live, tm=tm,
+            interpret=device_platform() != "tpu")
+        g = mm(xs, w1)
+        up = mm(xs, w3)
+        act = (activation(g, cfg.hidden_act) * up).astype(h.dtype)
+        y = mm(act, w2)  # f32 [T*tm, D]
+        out = jnp.sum(y[pos] * probs.reshape(n, k)[..., None], axis=1)
+        return done(out, sizes)
 
     if impl == "sort":
         hf = h.reshape(n, d)
@@ -177,11 +288,9 @@ def moe_ffn(
         # then the k choices of each token sit contiguous: weighted-sum them
         y = y[inv].reshape(n, k, d)
         out = jnp.sum(y * probs.reshape(n, k)[..., None], axis=1)
-        return out.reshape(b, t, d).astype(h.dtype)
+        return done(out)
 
     if impl == "dispatch":
-        import math
-
         c = min(n, max(1, math.ceil(capacity_factor * k * n / e)))
         if c > 8:
             c = min(n, -(-c // 8) * 8)  # round up to the f32 sublane
@@ -205,7 +314,7 @@ def moe_ffn(
         y_tok = y[ei, ri].astype(jnp.float32)  # [N*k, D]
         wgt = probs.reshape(-1) * keep  # dropped choices contribute nothing
         out = jnp.zeros((n, d), jnp.float32).at[tok].add(y_tok * wgt[:, None])
-        return out.reshape(b, t, d).astype(h.dtype)
+        return done(out)
 
     weights = jnp.sum(
         jax.nn.one_hot(topi, e, dtype=probs.dtype) * probs[..., None], axis=-2
@@ -215,7 +324,7 @@ def moe_ffn(
     act = activation(g.astype(jnp.float32), cfg.hidden_act).astype(h.dtype)
     y = jnp.einsum("btef,efd->bted", act * up, _dense_w(w2, h.dtype))
     out = jnp.einsum("bted,bte->btd", y.astype(jnp.float32), weights)
-    return out.astype(h.dtype)
+    return done(out)
 
 
 def gqa_attention(
@@ -223,8 +332,11 @@ def gqa_attention(
     k_cache: jax.Array,  # [B, Hkv, S, hd]
     v_cache: jax.Array,  # [B, Hkv, S, hd]
     pos_base: jax.Array,  # i32 scalar, or [B] per-sequence positions
+    window: int | None = None,  # rows a query sees, itself included
 ) -> jax.Array:
     """Causal GQA over the full KV cache (nn-cpu-ops.cpp:752-787 equivalent).
+    With `window`, key j is visible to the query at row i iff
+    i - window < j <= i.
 
     Query t attends to cache slots s <= pos_base + t; unwritten future slots
     are masked out, so the cache can stay a fixed [S]-sized ring without
@@ -243,10 +355,16 @@ def gqa_attention(
     qoff = jax.lax.broadcasted_iota(jnp.int32, (t, s), 0)
     pos_base = jnp.asarray(pos_base, jnp.int32)
     if pos_base.ndim == 1:
-        mask = spans[None] <= pos_base[:, None, None] + qoff[None]  # [B, t, s]
+        row = pos_base[:, None, None] + qoff[None]  # [B, t, s]
+        mask = spans[None] <= row
+        if window:
+            mask = mask & (spans[None] > row - window)
         mask = mask[:, None, None]
     else:
-        mask = (spans <= pos_base + qoff)[None, None, None]
+        mask = spans <= pos_base + qoff
+        if window:
+            mask = mask & (spans > pos_base + qoff - window)
+        mask = mask[None, None, None]
     scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgts,bhsd->bthgd", probs, vf)
@@ -294,6 +412,7 @@ def paged_gqa_attention(
     v_pool: jax.Array,
     tables: jax.Array,  # i32 [B, max_blocks]
     pos_base: jax.Array,  # i32 scalar, or [B] per-sequence positions
+    window: int | None = None,
 ) -> jax.Array:
     """Causal GQA over the paged KV cache: the jnp reference/fallback path —
     gather the block-table view, then run the dense attention math unchanged.
@@ -304,4 +423,4 @@ def paged_gqa_attention(
     bit-for-bit correctness reference and serves attn_impl='jnp', f8 pools,
     and non-sublane-aligned page sizes."""
     return gqa_attention(q, paged_view(k_pool, tables),
-                         paged_view(v_pool, tables), pos_base)
+                         paged_view(v_pool, tables), pos_base, window)

@@ -206,7 +206,7 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
             kbuf, vbuf, newk32, newv32, acc_ref, m_ref, l_ref, base_ref,
             copy_sems, write_sems,
             *, scale, page, group, t, tq, rows_live, nb, fused, hb, depth,
-            mxu_dtype):
+            mxu_dtype, window=None):
     b, hblk, iq = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nbatch, nhb, nq = pl.num_programs(0), pl.num_programs(1), pl.num_programs(2)
     lanes = kbuf.shape[-1]
@@ -217,6 +217,13 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
         # clamped to the table: the logical view is exactly nb*page rows
         last_row = jnp.minimum(qq * tq + tq - 1, rows_live - 1)
         return jnp.minimum((pos_ref[bb] + last_row // group) // page + 1, nb)
+
+    def sweep_first(bb, qq, hi):
+        # a windowed layer's walk starts at the block that holds the oldest
+        # row the tile's FIRST query still sees (pos + first_offset - W + 1);
+        # blocks before it may have been handed back to the pool already
+        lo = jnp.maximum(pos_ref[bb] + (qq * tq) // group - window + 1, 0) // page
+        return jnp.minimum(lo, hi - 1)
 
     # ---- fused KV scatter, addressing. A live slot's rows pos .. pos+t-1
     # land in table pages blk(0) .. blk(t-1), the LAST pages of its sweep
@@ -229,11 +236,17 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
     pos_b = pos_ref[b]
     blk = lambda tt: jnp.minimum((pos_b + tt) // page, nb - 1)
     n_sweep = sweep_pages(b, iq)
+    if window is None:
+        lo = 0
+    else:
+        lo = sweep_first(b, iq, n_sweep)
+        n_sweep = n_sweep - lo  # pages of the run; run page i is block lo + i
     if fused:
         live = wpages_ref[b, 0] == tables_ref[b, blk(0)]
         n = n_sweep + jnp.where(live, 0, 1)
         # the block a row lands in, as an index of this step's page run
-        target = lambda tt: jnp.where(live, blk(tt), n_sweep)
+        run_of = (lambda blk_: blk_) if window is None else (lambda blk_: blk_ - lo)
+        target = lambda tt: jnp.where(live, run_of(blk(tt)), n_sweep)
         # Mosaic cannot DMA a dynamically-offset single sublane row, so a
         # row is blended in VMEM: an f32 `where` (sub-32-bit sublane
         # broadcasts don't lower; bf16<->f32 round-trips exactly) over the
@@ -263,12 +276,20 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
     iq2 = jnp.where(wrap_q, 0, iq + 1)
     hblk2 = jnp.where(wrap_h, 0, jnp.where(wrap_q, hblk + 1, hblk))
     b2 = jnp.minimum(jnp.where(wrap_h, b + 1, b), nbatch - 1)
-    n2 = jnp.where(wrap_h & (b == nbatch - 1), 0, sweep_pages(b2, iq2))
+    last_step = wrap_h & (b == nbatch - 1)  # no successor to fetch for
+    n2 = sweep_pages(b2, iq2)
+    if window is None:
+        lo2 = 0
+    else:
+        lo2 = sweep_first(b2, iq2, n2)
+        n2 = n2 - lo2
+    n2 = jnp.where(last_step, 0, n2)
 
     def page_id(bb, i, own):
         # defensive clamp like _paged_cache_update: a horizon past the
         # allocated table reads the last entry (its rows are masked anyway)
-        pg = tables_ref[bb, jnp.minimum(i, nb - 1)]
+        blk_i = i if window is None else i + jnp.where(own, lo, lo2)
+        pg = tables_ref[bb, jnp.minimum(blk_i, nb - 1)]
         if fused:  # only this step's own run reaches past its sweep
             pg = jnp.where(own & (i >= n_sweep), wpages_ref[b, 0], pg)
         return pg
@@ -360,7 +381,11 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
         s = jax.lax.dot_general(q, mxu(k), (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32)
         s = s * scale  # [hb, tq, page]
-        mask = i * page + col <= qpos
+        if window is None:
+            mask = i * page + col <= qpos
+        else:
+            key = (lo + i) * page + col
+            mask = (key <= qpos) & (key > qpos - window)
         if fused:  # the trash page behind an inactive slot's sweep
             mask = mask & (i < n_sweep)
         mask = mask[None]
@@ -391,13 +416,11 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
     base_ref[0] = jax.lax.rem(base + n, depth)
 
 
-@functools.partial(jax.jit, static_argnames=("group", "interpret",
-                                             "rows_live", "fused", "scale",
-                                             "vmem_budget"))
-def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
-                  new_v, *, group: int, interpret: bool, rows_live: int,
-                  fused: bool, scale: float,
-                  vmem_budget: int = _VMEM_BUDGET_BYTES):
+def _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
+                new_v, *, group: int, interpret: bool, rows_live: int,
+                fused: bool, scale: float,
+                vmem_budget: int = _VMEM_BUDGET_BYTES,
+                window: int | None = None):
     """qf[B, Hkv, rows_pad, hd] x pool[N, Hkv, page, hd] ->
     (out f32 [B, Hkv, rows_pad, hd], k_pool, v_pool).
 
@@ -456,7 +479,7 @@ def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
         functools.partial(_kernel, scale=scale, page=page,
                           group=group, t=t, tq=tq, rows_live=rows_live,
                           nb=nb, fused=fused, hb=hb, depth=depth,
-                          mxu_dtype=mxu_dtype),
+                          mxu_dtype=mxu_dtype, window=window),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, rows, hd), jnp.float32),
@@ -481,6 +504,34 @@ def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
         interpret=interpret,
     )(pos, tables, wpages, woffs, qf, new_k, new_v, k_pool, v_pool)
     return out, k_pool, v_pool
+
+
+_STATIC = ("group", "interpret", "rows_live", "fused", "scale", "vmem_budget")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
+                  new_v, *, group: int, interpret: bool, rows_live: int,
+                  fused: bool, scale: float,
+                  vmem_budget: int = _VMEM_BUDGET_BYTES):
+    """`_paged_call` for a layer whose queries see the whole context, under
+    the name the benchmark's trace reader finds it by (benchmark/costs/
+    paged_attention.py)."""
+    return _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
+                       new_v, group=group, interpret=interpret,
+                       rows_live=rows_live, fused=fused, scale=scale,
+                       vmem_budget=vmem_budget)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC + ("window",))
+def _paged_window(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
+                  new_v, *, window: int, **kw):
+    """The same sweep for a layer whose queries see `window` rows: a call of
+    its own name on the device plane, so a trace tells the clipped walk of
+    the window pool from the global one (benchmark/costs/
+    paged_attention_window.py)."""
+    return _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
+                       new_v, window=window, **kw)
 
 
 def _scatter_rows_by_page(pool, new, nb, pos, wpages, woffs, trash):
@@ -539,6 +590,8 @@ def paged_decode_attention(
     *,
     layer: jax.Array | None = None,  # i32 scalar: the pools are the stack
     interpret: bool = False,
+    window: int | None = None,  # rows a query sees, itself included; None
+    # = the whole context (and today's program, to the instruction)
 ) -> jax.Array | tuple[jax.Array, jax.Array, jax.Array]:
     """Block-table paged attention over the HBM page pool, any page size.
 
@@ -621,7 +674,9 @@ def paged_decode_attention(
         nk = new_k.astype(k_pool.dtype)
         nv = new_v.astype(v_pool.dtype)
 
-    out, k_pool, v_pool = _paged_folded(
+    call = (_paged_folded if window is None
+            else functools.partial(_paged_window, window=int(window)))
+    out, k_pool, v_pool = call(
         qf, k_pool, v_pool, pos, tables + first_page, wpages, woffs, nk, nv,
         group=group, interpret=interpret, rows_live=rows, fused=write,
         scale=1.0 / math.sqrt(hd), vmem_budget=_VMEM_BUDGET_BYTES)

@@ -225,7 +225,36 @@ def _blockdot_kernel(
     *, tk, tn, lanes, rows
 ):
     del layer_ref
-    j, kb = pl.program_id(0), pl.program_id(1)
+    _blockdot_body(pl.program_id(0), pl.program_id(1), x_ref, packed_ref,
+                   scales_ref, out_ref, xa_ref, xs_ref, s_ref,
+                   tk=tk, tn=tn, lanes=lanes, rows=rows)
+
+
+def _expert_kernel(
+    layer_ref, expert_ref, src_ref, live_ref, x_ref, packed_ref, scales_ref,
+    out_ref, xa_ref, xs_ref, s_ref, *, tk, tn, lanes, rows
+):
+    """The block-dot tier with an expert index beside the layer index: grid
+    step (t, j, kb) is tile t of the rows in expert order against tile
+    (kb, j) of that tile's expert. The inner loop is `_blockdot_body`'s; x
+    is laid out again at each tile's first step (its rows are another
+    expert's). Tiles behind the last live one do nothing and, their block
+    indices frozen by the index maps, move nothing."""
+    del layer_ref, expert_ref, src_ref
+    t, j, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(t < live_ref[0])
+    def _():
+        _blockdot_body(j, kb, x_ref, packed_ref,
+                       scales_ref, out_ref, xa_ref, xs_ref, s_ref,
+                       tk=tk, tn=tn, lanes=lanes, rows=rows)
+
+
+def _blockdot_body(j, kb, x_ref, packed_ref, scales_ref, out_ref, xa_ref,
+                   xs_ref, s_ref, *, tk, tn, lanes, rows):
+    """Grid step (j, kb) of x[m, k] against one Q40 weight: what
+    `_blockdot_kernel` (one weight a call) and `_expert_kernel` (one weight a
+    tile of rows) both run."""
     m = out_ref.shape[0]
     nb = tk // Q_BLOCK
     per_chunk = min(_CHUNK, xa_ref.shape[0])  # groups a chunk
@@ -402,6 +431,26 @@ def _blockdot_tiles(k: int, n: int) -> tuple[int, int]:
     return best[1], best[2]
 
 
+def _x_and_scratch(x, tk: int, tn: int, m: int | None = None):
+    """What `_blockdot_body` works on besides the weight, for rows of `m`
+    (default: all of x's): x as it goes in (padded to whole chunks of 4096
+    dims where k has a part chunk: the kernel lays it out itself at a
+    tile's first grid step) and the three VMEM scratches: x by group and
+    block, x's block sums, the tile's scales as f32 in whole chunks."""
+    m = m or x.shape[0]
+    k = x.shape[1]
+    groups = k // _GROUP
+    per_chunk = min(_CHUNK, groups)
+    chunks = -(-groups // per_chunk)
+    kp = chunks * per_chunk * _GROUP
+    if kp != k:
+        x = jnp.pad(x, ((0, 0), (0, kp - k)))
+    nbp = -(-(tk // Q_BLOCK) // _GROUP) * _GROUP
+    return x, [pltpu.VMEM((kp // _GROUP, 4 * m, _GROUP), x.dtype),
+               pltpu.VMEM((chunks, 4 * m, _GROUP), x.dtype),
+               pltpu.VMEM((nbp, tn), jnp.float32)]
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "tk", "tn", "lanes", "rows"))
 def _blockdot_call(layer, x, packed, scales, *, interpret: bool = False,
                    tk: int | None = None, tn: int | None = None,
@@ -416,17 +465,10 @@ def _blockdot_call(layer, x, packed, scales, *, interpret: bool = False,
     tk, tn = tk or dtk, tn or dtn
     assert m % 16 == 0 and k % _SUB_K == 0 and (tk == k or tk % (_CHUNK * _GROUP) == 0)
     nb = tk // Q_BLOCK
-    nbp = -(-nb // _GROUP) * _GROUP  # the tile's blocks, in whole chunks
     dlanes, drows = _inner(tk, tn)
     lanes, rows = lanes or dlanes, rows or drows
-    # x goes in as it is (padded to whole chunks of 4096 dims, where k has a
-    # part chunk): the kernel lays it out itself at its first grid step
-    groups = k // _GROUP
-    per_chunk = min(_CHUNK, groups)
-    chunks = -(-groups // per_chunk)
-    kp = chunks * per_chunk * _GROUP
-    if kp != k:
-        x = jnp.pad(x, ((0, 0), (0, kp - k)))
+    x, scratch = _x_and_scratch(x, tk, tn)
+    kp = x.shape[1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n // tn, k // tk),
@@ -436,9 +478,7 @@ def _blockdot_call(layer, x, packed, scales, *, interpret: bool = False,
             pl.BlockSpec((None, nb, tn), lambda j, kb, L: (L[0], kb, j)),
         ],
         out_specs=pl.BlockSpec((m, tn), lambda j, kb, L: (0, j)),
-        scratch_shapes=[pltpu.VMEM((kp // _GROUP, 4 * m, _GROUP), x.dtype),
-                        pltpu.VMEM((chunks, 4 * m, _GROUP), x.dtype),
-                        pltpu.VMEM((nbp, tn), jnp.float32)],
+        scratch_shapes=scratch,
     )
     return pl.pallas_call(
         functools.partial(_blockdot_kernel, tk=tk, tn=tn, lanes=lanes, rows=rows),
@@ -460,6 +500,91 @@ def _blockdot_call(layer, x, packed, scales, *, interpret: bool = False,
         ),
         interpret=interpret,
     )(layer, x, packed, scales)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def _expert_call(layer, tile_expert, tile_src, n_live, x, packed, scales, *,
+                 tm: int, interpret: bool = False):
+    """bf16 x[T*tm, k], rows in expert order and padded to whole tiles of tm,
+    against the stacked experts packed u8[L, E, k/2, n] -> f32[T*tm, n]: tile t
+    meets expert tile_expert[t] of layer `layer`. The name and the 4-D packed
+    operand are what the benchmark's trace reader finds this call by
+    (benchmark/costs/moe_experts.py)."""
+    rows_total, k = x.shape
+    n = packed.shape[-1]
+    tiles = rows_total // tm
+    tk, tn = _blockdot_tiles(k, n)
+    assert tm % 16 == 0 and k % _SUB_K == 0 and (tk == k or tk % (_CHUNK * _GROUP) == 0)
+    nb = tk // Q_BLOCK
+    lanes, rows = _inner(tk, tn)
+    x, scratch = _x_and_scratch(x, tk, tn, m=tm)
+    kp = x.shape[1]
+    nj, nkb = n // tn, k // tk
+
+    def weight_map(t, j, kb, L, E, S, N):
+        # a dead tile keeps the last live step's block: nothing is copied
+        dead = t >= N[0]
+        return (L[0], E[t], jnp.where(dead, nkb - 1, kb),
+                jnp.where(dead, nj - 1, j))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,  # layer[1], tile_expert[T], tile_src[T], n_live[1]
+        grid=(tiles, nj, nkb),
+        in_specs=[
+            pl.BlockSpec((tm, kp), lambda t, j, kb, L, E, S, N: (S[t], 0)),
+            pl.BlockSpec((None, None, tk // 2, tn), weight_map),
+            pl.BlockSpec((None, None, nb, tn), weight_map),
+        ],
+        out_specs=pl.BlockSpec(
+            (tm, tn), lambda t, j, kb, L, E, S, N: (
+                S[t], jnp.where(t >= N[0], nj - 1, j))),
+        scratch_shapes=scratch,
+    )
+    return pl.pallas_call(
+        functools.partial(_expert_kernel, tk=tk, tn=tn, lanes=lanes, rows=rows),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows_total, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=96 * 1024 * 1024,
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * rows_total * n * k,
+            # at most one expert a tile; the trace's reader counts the
+            # experts really touched (benchmark/costs/moe_experts.py)
+            bytes_accessed=rows_total * k * 2 + rows_total * n * 4
+            + min(tiles, packed.shape[1]) * (k * n // 2 + (k // Q_BLOCK) * n * 2),
+            transcendentals=0,
+        ),
+        interpret=interpret,
+    )(layer, tile_expert, tile_src, n_live, x, packed, scales)
+
+
+def expert_supported(w, dtype) -> bool:
+    """Whether `q40_expert_matmul` takes an expert stack (QTensor [.., E, k,
+    n]) with activations of `dtype`: the block-dot tier's own terms."""
+    k, n = w.shape[-2], w.shape[-1]
+    return (isinstance(w, QTensor) and jnp.dtype(dtype) == jnp.bfloat16
+            and k % _SUB_K == 0 and n % 128 == 0)
+
+
+def q40_expert_matmul(x: jax.Array, w: QTensor, *, layer, tile_expert,
+                      tile_src, n_live, tm: int,
+                      interpret: bool = False) -> jax.Array:
+    """Rows in expert order (ops/layers.expert_groups) x the experts' Q40
+    weights -> f32[T*tm, n]. `w` is the layer-stacked [L, E, k, n] expert
+    weight with `layer` a traced index, or one layer's [E, k, n]; either is
+    indexed by the DMA engine, never sliced or dequantised by XLA."""
+    assert expert_supported(w, x.dtype), (w.shape, x.dtype)
+    packed, scales = w.packed, w.scales
+    if packed.ndim == 3:
+        packed, scales, layer = packed[None], scales[None], 0
+    if scales.dtype == jnp.float16:
+        scales = jax.lax.bitcast_convert_type(scales, jnp.uint16)
+    return _expert_call(
+        jnp.asarray(layer, jnp.int32).reshape(1), tile_expert, tile_src,
+        jnp.asarray(n_live, jnp.int32).reshape(1), x, packed, scales, tm=tm,
+        interpret=interpret)
 
 
 def supported(x_shape: tuple[int, ...], w: QTensor) -> bool:

@@ -345,6 +345,7 @@ class _AioContext(api_mod.RequestRoutes):
         self._body = body
         self.api = server.api
         self.detached = False  # True once an SSE machine owns the connection
+        self.ticket = None  # the loop's api.ArrivalOrder ticket, completions only
 
     # ------------------------------------------------- transport primitives
 
@@ -748,6 +749,11 @@ class AioHttpServer:
             conn.closing = True
         conn.busy = True
         ctx = self._ctx_factory(self, conn, command, path, headers, body)
+        # a completion's place in the queue is where its body completed, not
+        # where its worker finished tokenizing (api.ArrivalOrder)
+        order = getattr(self.api, "arrivals", None)
+        ctx.ticket = (order.arrive() if order is not None and command == "POST"
+                      and path.endswith("/completions") else None)
         control = command == "GET" and path.startswith(
             ("/health", "/metrics", "/router/"))
         (self._ctrl if control else self._pool).submit(self._run_ctx, ctx)
@@ -756,6 +762,9 @@ class AioHttpServer:
         try:
             if ctx.command == "GET":
                 ctx.do_GET()
+            elif ctx.command == "POST" and ctx.ticket is not None:
+                with self.api.arrivals.bound(ctx.ticket):
+                    ctx.do_POST()
             elif ctx.command == "POST":
                 ctx.do_POST()
             else:

@@ -28,6 +28,7 @@ one engine owns the KV cache — the reference is equally single-request
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import select
@@ -236,6 +237,66 @@ class TokenAssembler:
         return "".join(self.parts)
 
 
+class ArrivalOrder:
+    """Completions reach the scheduler's queue in the order their bodies
+    reached the front end. The event loop hands each request to a pool of
+    workers that parse and tokenize side by side, so prompts sent together
+    reached `Scheduler.submit` in whatever order their workers finished;
+    admission is serial, so with long prompts that order decided who waited
+    two seconds and who forty. The loop takes a ticket as a completion's body
+    completes (`arrive`), the worker runs the handler as its holder (`bound`)
+    and `turn()`, around the submit, waits until no earlier ticket is open.
+    The pool is FIFO, so the lowest open ticket is always on a worker and
+    never waits, and a ticket is left on every exit of its handler.
+    `patience_s` bounds a wait all the same: order is a courtesy, never
+    something a request hangs on. A thread that holds no ticket (the threads
+    tier, the tests) passes straight through."""
+
+    def __init__(self, patience_s: float = 2.0):
+        self.patience_s = float(patience_s)
+        self._cv = threading.Condition()
+        self._issued = 0
+        self._open: set[int] = set()
+        self._mine = threading.local()
+
+    def arrive(self) -> int:
+        with self._cv:
+            self._issued += 1
+            self._open.add(self._issued)
+            return self._issued
+
+    def leave(self, ticket: int | None) -> None:
+        with self._cv:
+            if ticket in self._open:
+                self._open.discard(ticket)
+                self._cv.notify_all()
+
+    @contextlib.contextmanager
+    def bound(self, ticket: int | None):
+        self._mine.ticket = ticket
+        try:
+            yield
+        finally:
+            self._mine.ticket = None
+            self.leave(ticket)
+
+    @contextlib.contextmanager
+    def turn(self):
+        ticket = getattr(self._mine, "ticket", None)
+        if ticket is not None:
+            deadline = time.monotonic() + self.patience_s
+            with self._cv:
+                while min(self._open, default=ticket) < ticket:
+                    left = deadline - time.monotonic()
+                    if left <= 0:
+                        break
+                    self._cv.wait(left)
+        try:
+            yield
+        finally:
+            self.leave(ticket)
+
+
 class ApiServer:
     def __init__(self, loaded, default_temperature=0.8, default_topp=0.9, default_seed=None,
                  scheduler=None, spec: int = 0,
@@ -272,6 +333,8 @@ class ApiServer:
         # continuous-batching tier: a serve/scheduler.Scheduler over a
         # BatchEngine — concurrent requests share the device, no global lock
         self.scheduler = scheduler
+        # the order completions arrived in is the order they are submitted in
+        self.arrivals = ArrivalOrder()
         # flipped by the SIGTERM drain sequence: new requests get 503 while
         # in-flight ones finish (single-engine tier included — the scheduler
         # has its own draining flag for its admission queue)
@@ -350,6 +413,14 @@ class ApiServer:
         h["model_params_bytes"] = self.model_params_bytes
         h["kv_cache_bytes"] = self.kv_cache_bytes
         h["recurrent_state_bytes"] = self.recurrent_state_bytes
+        eng = self.scheduler.engine if self.scheduler is not None else None
+        if getattr(eng, "wpool", None) is not None:
+            # windowed layers keep a page pool of their own: what follows one
+            # page list a slot resolved off at start-up (engine/batch.py)
+            h["window_pool"] = {
+                "window": eng.cfg.window, "pages": eng.wpool.n_pages,
+                "resolved_off": ["radix_cache", "kv_host_pages",
+                                 "cross_slot_prefix_copy", "preempt_to_pages"]}
         h["build"] = self.build_info
         # process self-metrics ride every probe (and /metrics as gauges):
         # uptime answers "did it just restart", RSS + threads answer "is it
@@ -756,22 +827,23 @@ class ApiServer:
         if p["max_tokens"] > 0:
             budget = min(budget, p["max_tokens"])
         seed = p["seed"]
-        return self.scheduler.submit(
-            prompt_tokens, p["temperature"], p["topp"], budget,
-            self.tokenizer.eos_ids,
-            presence=p["presence"], frequency=p["frequency"],
-            seed=int(seed) if seed is not None else None,
-            req_id=req_id, timeout_s=p["timeout_s"],
-            # None = the --spec-k serving default (the engine's compiled K);
-            # the scheduler clamps explicit values to that capacity
-            spec_k=p["spec_k"],
-            # scheduling class + fair-queue tenant (ISSUE 12): the
-            # scheduler's policy pick and preemption read these
-            priority=p["priority"], tenant=p["tenant"],
-            # cross-replica failover (ISSUE 16): the journaled emitted
-            # prefix to re-prefill before the stream continues
-            resume_tokens=p.get("resume_tokens"),
-        )
+        with self.arrivals.turn():
+            return self.scheduler.submit(
+                prompt_tokens, p["temperature"], p["topp"], budget,
+                self.tokenizer.eos_ids,
+                presence=p["presence"], frequency=p["frequency"],
+                seed=int(seed) if seed is not None else None,
+                req_id=req_id, timeout_s=p["timeout_s"],
+                # None = the --spec-k serving default (the engine's compiled K);
+                # the scheduler clamps explicit values to that capacity
+                spec_k=p["spec_k"],
+                # scheduling class + fair-queue tenant (ISSUE 12): the
+                # scheduler's policy pick and preemption read these
+                priority=p["priority"], tenant=p["tenant"],
+                # cross-replica failover (ISSUE 16): the journaled emitted
+                # prefix to re-prefill before the stream continues
+                resume_tokens=p.get("resume_tokens"),
+            )
 
     def finish_batched(self, req, ended_on_eos: bool,
                        n_generated: int) -> tuple[str, dict]:
@@ -1720,6 +1792,10 @@ def make_server(loaded, host="127.0.0.1", port=0, n_slots: int = 0, **defaults):
             n_slots=n_slots,
             cache_dtype=loaded.engine.cache.k.dtype,
             max_seq_len=loaded.engine.seq_len,
+            # --max-prefill-chunk caps the serving engine's prompt slices
+            # too (a hybrid launch's and an admission chunk's), as it caps
+            # the batch-1 engine's
+            max_prefill_chunk=getattr(loaded.engine, "max_prefill_chunk", 256),
             shardings=loaded.shardings,  # multi-chip serving keeps the mesh placement
             sync=getattr(loaded, "sync", "bf16"),
             spec=spec_n,

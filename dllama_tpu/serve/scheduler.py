@@ -476,10 +476,12 @@ class Scheduler:
             # ONE row, so every resume would re-prefill the whole stream
             if preempt == "on":
                 raise ValueError(
-                    "--preempt on needs rows that can be re-entered; this "
-                    "model's recurrent state cannot (use --preempt off)")
-            log.info("preempt-to-pages off: the model's recurrent state "
-                     "cannot be re-entered at a suspended request's rows")
+                    "--preempt on needs rows that can be re-entered in any "
+                    "slot; this model's recurrent state, or its windowed "
+                    "layers' own page pool, cannot (use --preempt off)")
+            log.info("preempt-to-pages off: a suspended request's rows "
+                     "cannot be re-entered (recurrent state, or windowed "
+                     "layers with a page pool of their own)")
             self._preempt_on = False
         self.preempt_count = 0  # lifetime totals (latency_summary/health)
         self.resume_count = 0

@@ -42,6 +42,8 @@ def hf_config_to_llama(config: Mapping, weight_type: FloatType) -> LlamaConfig:
     """HF config.json -> LlamaConfig (mirrors convert-hf.py:152-195)."""
     if config["model_type"] == "granitemoehybrid":
         return _hybrid_ssm_config(config, weight_type)
+    if "sliding_window_layout" in config:
+        return _window_moe_config(config, weight_type)
     arch = {
         "llama": ArchType.LLAMA,
         "mistral": ArchType.LLAMA,
@@ -83,6 +85,46 @@ def hf_config_to_llama(config: Mapping, weight_type: FloatType) -> LlamaConfig:
             rope_scaling_orig_max_seq_len=int(scaling["original_max_position_embeddings"]),
         )
     return LlamaConfig(**kwargs)
+
+
+def _window_moe_config(config: Mapping, weight_type: FloatType) -> LlamaConfig:
+    """A config.json that lists, layer by layer, which attention layers are
+    windowed (`sliding_window_layout`) and which rotate (`rope_layout`), with
+    routed ReLU-gated experts in every layer and a router that reads the
+    attention block's normed input (source of the key names:
+    huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct config.json) -> a
+    LLAMA header with the per-layer lists. Refused by mechanism: a router
+    without softmax or without renormalised top-k, scaled rope."""
+    if not (config.get("moe_primary_router_apply_softmax")
+            and config.get("norm_topk_prob")):
+        raise ValueError("only a softmax router with renormalised top-k runs")
+    if config.get("rope_scaling") is not None:
+        raise ValueError(f"unsupported rope scaling: {config['rope_scaling']}")
+    n = config["num_hidden_layers"]
+    return LlamaConfig(
+        arch=ArchType.LLAMA, hidden_act=HiddenAct.RELU,
+        dim=config["hidden_size"], hidden_dim=config["moe_ffn_hidden_size"],
+        n_layers=n, n_heads=config["num_attention_heads"],
+        n_kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
+        weight_type=weight_type, seq_len=config["max_position_embeddings"],
+        vocab_size=config["vocab_size"],
+        n_experts=config["moe_num_primary_experts"],
+        n_active_experts=config["moe_num_active_primary_experts"],
+        norm_epsilon=float(config["rms_norm_eps"]),
+        rope_theta=float(config["rope_theta"]),
+        window=int(config["sliding_window_size"]),
+        layer_windows=tuple(config["sliding_window_layout"][:n]),
+        layer_ropes=tuple(config["rope_layout"][:n]),
+        router_pre_attention=True)
+
+
+#: the expert tensors of a `_window_moe_config` checkpoint
+WINDOW_MOE_NAME_MAP = {
+    "moe_gate": "model.layers.{l}.block_sparse_moe.primary_router.weight",
+    "moe_w1": "model.layers.{l}.block_sparse_moe.experts.{e}.gate.weight",
+    "moe_w2": "model.layers.{l}.block_sparse_moe.experts.{e}.down.weight",
+    "moe_w3": "model.layers.{l}.block_sparse_moe.experts.{e}.up.weight",
+}
 
 
 def _hybrid_ssm_config(config: Mapping, weight_type: FloatType) -> LlamaConfig:
@@ -176,14 +218,19 @@ def hf_tensor_for(name: str, cfg: LlamaConfig, get) -> np.ndarray:
     parts = name.split(".")
     if len(parts) == 3:
         _, layer, short = parts
-        if short.startswith("moe_") and short != "moe_gate":
-            return np.stack(
-                [
-                    get(HF_NAME_MAP[short].format(l=layer, e=e))
-                    for e in range(cfg.n_experts)
-                ],
-                axis=0,
-            )
+        if short.startswith("moe_"):
+            # by what the checkpoint holds: Mixtral's names, else the
+            # window-and-global family's
+            def expert_tensor(**at):
+                try:
+                    return get(HF_NAME_MAP[short].format(l=layer, **at))
+                except KeyError:
+                    return get(WINDOW_MOE_NAME_MAP[short].format(l=layer, **at))
+
+            if short == "moe_gate":
+                return expert_tensor()
+            return np.stack([expert_tensor(e=e) for e in range(cfg.n_experts)],
+                            axis=0)
         if cfg.arch == ArchType.HYBRID_SSM:
             if short in HYBRID_NAME_MAP:
                 x = get(HYBRID_NAME_MAP[short].format(l=layer))

@@ -51,7 +51,13 @@ _prof_state = {"active": False, "dir": None, "started_at": 0.0,
 #: capture is bracketed with, by the key /debug/perf's `capture` block uses
 _CAPTURE_COUNTERS = {"launches": ins.LAUNCHES, "slot_steps": ins.SLOT_STEPS,
                      "kv_rows": ins.LAUNCH_KV_ROWS,
-                     "prefill_rows": ins.LAUNCH_PREFILL_ROWS}
+                     "prefill_rows": ins.LAUNCH_PREFILL_ROWS,
+                     "kv_rows_read": ins.LAUNCH_KV_ROWS_READ,
+                     "moe_assignments": ins.MOE_ASSIGNMENTS,
+                     "moe_experts_touched": ins.MOE_EXPERTS_TOUCHED,
+                     "moe_layer_steps": ins.MOE_LAYER_STEPS,
+                     "moe_group_rows_max": ins.MOE_GROUP_ROWS_MAX,
+                     "window_pages_released": ins.KV_WINDOW_PAGES_RELEASED}
 _capture = {"begin": None, "t_begin": 0.0, "last": None}
 
 
@@ -308,8 +314,10 @@ def params_nbytes(params) -> int:
 
 
 def cache_nbytes(cache) -> int:
-    return (cache.k.size * cache.k.dtype.itemsize
-            + cache.v.size * cache.v.dtype.itemsize)
+    """KV bytes: the pool (both pools where windowed layers have their own)."""
+    pools = (cache.k, cache.v, getattr(cache, "kw", None),
+             getattr(cache, "vw", None))
+    return sum(p.size * p.dtype.itemsize for p in pools if p is not None)
 
 
 def state_nbytes(cache) -> int:

@@ -412,7 +412,7 @@ def sharded_cases(topo):
 
 
 def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
-                    hybrid_p=(64,)):
+                    hybrid_p=(64,), seq: int = SEQ, prefill_chunk: int = 256):
     """(name, thunk, production) for the step programs of a paged
     BatchEngine (`serve --slots N --max-seq-len 2048 [--spec-k K]`): the
     engine is built here on the CPU over shapes, and each thunk lowers one
@@ -434,22 +434,34 @@ def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
     page = 128
     # a 2-page pool keeps construction cheap; the programs are lowered
     # against the pool the server allocates
-    be = BatchEngine(cfg, params, n_slots=slots, max_seq_len=SEQ,
+    be = BatchEngine(cfg, params, n_slots=slots, max_seq_len=seq,
                      kv_layout="paged", page_size=page, kv_pages=2,
-                     spec=spec)
-    assert be.kernel_route == ("pallas/paged_kernel" + (
-        "+ssm_step.float32" if cfg.recurrent else "")), be.kernel_route
-    nb = SEQ // page
-    pool = A((cfg.n_attn_layers, (kv_pages or slots * nb) + 1, cfg.n_kv_heads,
-              page, be.cache.k.shape[-1]), jnp.bfloat16)
-    cache = PagedKVCache(pool, pool, i32(slots, nb), place(be.cache.state))
-    rope = place(jax.eval_shape(lambda: build_rope_cache(cfg, SEQ)))
+                     spec=spec, max_prefill_chunk=prefill_chunk)
+    assert be.kernel_route == (
+        "pallas/paged_kernel" + (".window" if cfg.n_window_layers else "")
+        + ("+ssm_step.float32" if cfg.recurrent else "")
+        + ("+moe_grouped" if cfg.n_experts else "")), be.kernel_route
+    nb = seq // page
+    n_pages = kv_pages or slots * nb
+    row = (cfg.n_kv_heads, page, be.cache.k.shape[-1])
+    pool = A((cfg.n_attn_layers - cfg.n_window_layers, n_pages + 1, *row),
+             jnp.bfloat16)
+    wpool = wtables = None
+    if cfg.n_window_layers:
+        # the window pool as the engine sizes it beside `kv_pages`
+        be._build_pools(n_pages, nb)
+        wpool = A((cfg.n_window_layers, be.wpool.n_pages + 1, *row), jnp.bfloat16)
+        wtables = i32(slots, nb)
+    cache = PagedKVCache(pool, pool, i32(slots, nb), place(be.cache.state),
+                         wpool, wpool, wtables,
+                         A((4,), jnp.uint32) if cfg.n_experts else None)
+    rope = place(jax.eval_shape(lambda: build_rope_cache(cfg, seq)))
     vecs = (i32(slots), A((slots,), jnp.bool_), A((slots, 2), jnp.uint32),
             f32(slots), f32(slots))  # pos, active, keys, temps, topp
     dec = lambda n: (params, cache, i32(slots, 1), *vecs, n, rope, i32(slots))
     out = [(f"{tag} paged decode chunk n=4",
             lambda: be._decode.lower(*dec(4)).compile(), True)]
-    if spec or cfg.recurrent:
+    if spec or cfg.recurrent or cfg.n_window_layers:
         out += [(f"{tag} hybrid step p={p} n=4", lambda p=p: be._hybrid.lower(
             params, cache, i32(1, p), i32(), i32(), i32(slots, 1),
             *vecs, 4, rope, i32(slots)).compile(), True) for p in hybrid_p]
@@ -460,7 +472,7 @@ def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
             (f"{tag} paged prefill chunk m=1", lambda: be._prefill_slot.lower(
                 params, cache, i32(1, 1), i32(), i32(), rope).compile(), True),
             (f"{tag} spec-verify chunk K={spec} m=4", lambda: be._spec_step.lower(
-                params, cache, i32(slots, SEQ + 1), i32(slots), vecs[0],
+                params, cache, i32(slots, seq + 1), i32(slots), vecs[0],
                 vecs[1], i32(slots), *vecs[2:], rope, i32(slots), 4).compile(), True),
             (f"{tag} paged penalized decode chunk n=4", lambda: be._decode_pen.lower(
                 *dec(4), i32(slots, cfg.vocab_size), f32(slots),
@@ -584,6 +596,63 @@ def hybrid_cases(topo, slots: int = HYBRID_SLOTS, pages: int = HYBRID_PAGES):
                            slots, 0, kv_pages=pages)
 
 
+#: the window-and-global, routed-expert stack at the published widths of the
+#: benchmark's configuration (benchmark/configs/smallthinker-21b-a3b.json),
+#: cut to 24 layers as there: period `g w w w`, 28/4 heads of 128 over a
+#: 2,560 stream, 64 experts of width 768 with 6 active, a 151,936-row head;
+#: 16 slots over 1,232 global pages and the window pool the engine sizes
+WINDOW_MOE_SLOTS, WINDOW_MOE_PAGES, WINDOW_MOE_SEQ = 16, 1232, 16384
+
+
+def window_moe_cfg(n_layers: int = 24):
+    from dllama_tpu.models.config import HiddenAct, LlamaConfig
+
+    return LlamaConfig(
+        dim=2560, hidden_dim=768, n_layers=n_layers, n_heads=28, n_kv_heads=4,
+        head_dim=128, vocab_size=151936, seq_len=WINDOW_MOE_SEQ, n_experts=64,
+        n_active_experts=6, hidden_act=HiddenAct.RELU, rope_theta=1.5e6,
+        norm_epsilon=1e-6, window=4096, layer_windows=(0, 1, 1, 1) * (n_layers // 4),
+        layer_ropes=(0, 1, 1, 1) * (n_layers // 4), router_pre_attention=True)
+
+
+def window_moe_params(cfg, A):
+    """Abstract params as models/formats.load_params stacks them."""
+    def qw(lead, k, n):
+        return QTensor(A((*lead, k // 2, n), jnp.uint8),
+                       A((*lead, k // Q_BLOCK, n), jnp.float16))
+
+    f32 = lambda *shape: A(shape, jnp.float32)
+    L, E, d, w = cfg.n_layers, cfg.n_experts, cfg.dim, cfg.hidden_dim
+    return {
+        "embedding": A((cfg.vocab_size, d), jnp.bfloat16),
+        "final_norm": f32(d), "wcls": qw((), d, cfg.vocab_size),
+        "layers": {
+            "wq": qw((L,), d, cfg.attn_dim), "wk": qw((L,), d, cfg.kv_dim),
+            "wv": qw((L,), d, cfg.kv_dim), "wo": qw((L,), cfg.attn_dim, d),
+            "moe_gate": f32(L, d, E), "moe_w1": qw((L, E), d, w),
+            "moe_w2": qw((L, E), w, d), "moe_w3": qw((L, E), d, w),
+            "rms_att": f32(L, d), "rms_ffn": f32(L, d),
+        },
+    }
+
+
+def window_moe_cases(topo, slots: int = WINDOW_MOE_SLOTS,
+                     pages: int = WINDOW_MOE_PAGES, n_layers: int = 24):
+    """The step programs of `serve --slots 16 --kv-pages 1232` on the
+    window-and-global routed-expert model at its published widths, with the
+    512-row slices its benchmark cell asks for (`--max-prefill-chunk 512`):
+    decode chunk and the hybrid step (the grouped expert kernel at 1-3 and
+    at 48 rows an expert, the clipped paged sweep)."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = window_moe_cfg(n_layers)
+    params = window_moe_params(cfg, lambda shape, dt: S(shape, dt, sharding=one))
+    return engine_programs(topo, f"serve window-moe {slots}-slot", cfg, params,
+                           slots, 0, kv_pages=pages, hybrid_p=(512,),
+                           seq=WINDOW_MOE_SEQ, prefill_chunk=512)
+
+
 def all_cases(topo, full: bool = False):
     """Every case as (name, thunk, production): thunk() compiles for the
     described chip and raises what the chip's compiler would raise."""
@@ -620,7 +689,8 @@ def main():
     mmod.device_platform = lambda: "tpu"  # see module docstring
     rows, prod_reject = [], []
     topo = topology()
-    for cname, thunk, production in all_cases(topo, full) + hybrid_cases(topo):
+    for cname, thunk, production in (all_cases(topo, full) + hybrid_cases(topo)
+                                     + window_moe_cases(topo)):
         t0 = time.time()
         try:
             thunk()

@@ -279,3 +279,100 @@ def test_x_replica_id_and_timings_replica(tiny_loaded):
         api.scheduler.shutdown()
         httpd.shutdown()
         httpd.server_close()
+
+
+# ---------------------------------------------------------------------------
+# arrival order (PR 36): completions are submitted in the order their bodies
+# reached the loop, whatever order the pool's workers finished tokenizing in
+
+
+def test_arrival_order_turns_follow_tickets():
+    from dllama_tpu.serve.api import ArrivalOrder
+
+    order = ArrivalOrder(patience_s=10.0)
+    tickets = [order.arrive() for _ in range(12)]
+    passed: list = []
+
+    def worker(t, delay):
+        with order.bound(t):
+            time.sleep(delay)  # the later the ticket, the sooner it is ready
+            if t == 5:
+                return  # a handler that ends before any submit (a 400)
+            with order.turn():
+                passed.append(t)
+
+    threads = [threading.Thread(target=worker, args=(t, 0.012 * (12 - i)))
+               for i, t in enumerate(tickets)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=30)
+    assert passed == [t for t in tickets if t != 5]
+    assert not order._open  # every ticket was left
+
+
+def test_arrival_order_patience_and_no_ticket():
+    from dllama_tpu.serve.api import ArrivalOrder
+
+    order = ArrivalOrder(patience_s=0.05)
+    first, second = order.arrive(), order.arrive()
+    t0 = time.monotonic()
+    with order.bound(second), order.turn():  # `first` never leaves
+        waited = time.monotonic() - t0
+    assert 0.04 <= waited < 2.0
+    with order.turn():  # a thread that holds no ticket passes straight through
+        pass
+    with pytest.raises(RuntimeError), order.bound(first), order.turn():
+        raise RuntimeError("a submit that raised")
+    assert not order._open
+
+
+def test_aio_submits_in_arrival_order(tiny_loaded):
+    """Eight streamed completions whose bodies arrive one after another while
+    the EARLIER ones take longer to tokenize: the scheduler still receives
+    them in arrival order (it received them fastest-first before)."""
+    mpath, tpath = tiny_loaded
+    httpd, api = _boot(mpath, tpath, n_slots=2, frontend="aio")
+    n = 8
+    try:
+        port = httpd.server_address[1]
+        encode, submit = api.tokenizer.encode, api.scheduler.submit
+        seen: list = []
+
+        def slow_encode(text, *a, **kw):
+            k = len(text) // 2  # "hi" * k
+            if 1 <= k <= n:
+                time.sleep(0.03 * (n - k))
+            return encode(text, *a, **kw)
+
+        def recording_submit(prompt, *a, **kw):
+            seen.append(len(prompt))
+            return submit(prompt, *a, **kw)
+
+        api.tokenizer.encode = slow_encode
+        api.scheduler.submit = recording_submit
+        socks = []
+        for k in range(1, n + 1):
+            body = json.dumps({"prompt": "hi" * k, "max_tokens": 2,
+                               "temperature": 0.0, "stream": True}).encode()
+            s = socket.create_connection(("127.0.0.1", port), timeout=60)
+            s.sendall(b"POST /v1/completions HTTP/1.1\r\n"
+                      b"Host: 127.0.0.1:%d\r\n"
+                      b"Content-Type: application/json\r\n"
+                      b"Content-Length: %d\r\n\r\n" % (port, len(body)) + body)
+            socks.append(s)
+            time.sleep(0.005)  # bodies complete in this order
+        for s in socks:
+            buf = b""
+            while b"[DONE]" not in buf:
+                chunk = s.recv(65536)
+                assert chunk, buf[-200:]
+                buf += chunk
+            s.close()
+        assert seen == sorted(seen) and len(seen) == n, seen
+        assert not api.arrivals._open
+    finally:
+        api.tokenizer.encode, api.scheduler.submit = encode, submit
+        api.scheduler.shutdown()
+        httpd.shutdown()
+        httpd.server_close()

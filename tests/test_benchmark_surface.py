@@ -169,8 +169,11 @@ def _perf(s, tiny):
     st, doc = s["docs"]["/debug/perf"]
     assert st == 200
     cap = doc["capture"]
-    assert set(cap) == {"launches", "slot_steps", "kv_rows", "prefill_rows",
-                        "seconds"}
+    # what the accepted cells' cost files read, and since PR 36 beside it
+    # what the windowed attention's and the expert layer's read
+    assert {"launches", "slot_steps", "kv_rows", "prefill_rows", "seconds",
+            "kv_rows_read", "moe_experts_touched", "moe_layer_steps",
+            "moe_assignments"} <= set(cap)
     assert cap["seconds"] > 0 and cap["slot_steps"]["advanced"] >= 5
     # the reader of the block: rows a decode step swept, for its cost file
     assert paged_attention.rows_per_step(cap, tiny["args"].slots) > 0
@@ -321,3 +324,154 @@ def test_blockdot_call_parses_as_the_cost_file_reads_it(m, k, n, layers):
     calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
     assert len(calls) == 1, hlo
     assert cost.calls({}, {"hlo": calls[0]}) == cost.cost(m, k, n)
+
+
+# ------------------------- the grouped expert kernel and the windowed sweep
+
+
+@pytest.mark.parametrize("tm,tiles,k,n,layers,experts", [
+    (16, 10, 256, 384, 2, 8), (32, 12, 512, 256, 1, 8)])
+def test_expert_call_parses_as_the_cost_file_reads_it(tm, tiles, k, n, layers,
+                                                      experts):
+    """`moe_expert_roofline` finds the grouped expert kernel by the device
+    op's group `_expert_call` and reads (experts, k, n) from the call's HLO
+    text: the result f32[rows, n] and the 4-D packed operand u8[layers,
+    experts, k/2, n] (`benchmark/costs/moe_experts.py`); the bytes come from
+    the capture's device-side counts, never from all the experts."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.costs import moe_experts as cost
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    S = jax.ShapeDtypeStruct
+    i32 = lambda n_: S((n_,), jnp.int32)
+    args = (i32(1), i32(tiles), i32(tiles), i32(1), S((tiles * tm, k), jnp.bfloat16),
+            S((layers, experts, k // 2, n), jnp.uint8),
+            S((layers, experts, k // 32, n), jnp.uint16))
+    assert qmod._expert_call.__name__ == "_expert_call"  # the op's group
+    hlo = jax.jit(lambda *a: qmod._expert_call(*a, tm=tm)).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(dialect="hlo")
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1, hlo
+    assert cost.shape({"hlo": calls[0]}) == (experts, k, n)
+    config = {"moe_num_primary_experts": experts}
+    capture = {"moe_layer_steps": {"": 4.0}, "moe_experts_touched": {"": 20.0},
+               "moe_assignments": {"": 48.0}}
+    assert cost.calls(config, {"hlo": calls[0]}, capture) == cost.cost(5.0, 12.0, k, n)
+    # never all of them on a guess: no counts, or impossible ones, price nothing
+    assert cost.calls(config, {"hlo": calls[0]}, {}) is None
+    assert cost.calls(config, {"hlo": calls[0]},
+                      dict(capture, moe_experts_touched={"": 40.0})) is None
+
+
+@pytest.mark.parametrize("window,group", [(None, "_paged_folded"),
+                                          (16, "_paged_window")])
+def test_paged_calls_of_both_pools_parse_as_the_cost_file_reads_them(window, group):
+    """`swa_attn_roofline` tells a windowed layer's sweep from a global
+    one's by the device op's group and prices each from its own pool's rows
+    (`benchmark/costs/paged_attention_window.py`, capture keys "kind,pool")."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.costs import paged_attention_window as cost
+    from dllama_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    slots, hkv, hq, hd, page, pages = 4, 2, 4, 128, 8, 9
+    S = jax.ShapeDtypeStruct
+    pool = S((3, pages, hkv, page, hd), jnp.bfloat16)
+    new = S((slots, hkv, 1, hd), jnp.bfloat16)
+
+    def call(q, k, v, tables, pos, nk, nv):
+        return paged_decode_attention(q, k, v, tables, pos, nk, nv, None,
+                                      layer=jnp.int32(1), window=window)
+
+    hlo = jax.jit(call).trace(
+        S((slots, 1, hq, hd), jnp.bfloat16), pool, pool,
+        S((slots, 6), jnp.int32), S((slots,), jnp.int32), new, new).lower(
+        lowering_platforms=("tpu",)).as_text(dialect="hlo")
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    # the compiled instruction takes the jit's name (tests/test_chip_compile.py
+    # holds both on the compiled programs)
+    from dllama_tpu.ops.pallas import paged_attention as pmod
+
+    assert getattr(pmod, group).__name__ == group
+    config = {"serve": {"slots": slots}, "num_key_value_heads": hkv,
+              "num_attention_heads": hq, "head_dim": hd}
+    capture = {"launches": {"decode": 2.0}, "slot_steps": {"advanced": 32.0},
+               "kv_rows_read": {"decode,global": 800.0, "decode,window": 512.0}}
+    op = {"hlo": calls[0], "group": group}
+    rows = {"_paged_folded": 100.0, "_paged_window": 64.0}[group]
+    assert cost.calls(config, op, capture) == cost.base.cost(
+        rows, slots, hq, hkv, hd, 2)
+    assert cost.calls(config, op, {"launches": {"decode": 2.0},
+                                   "slot_steps": {"advanced": 32.0}}) is None
+
+
+_DECODE_OPS = [  # device-plane texts of the compiled decode program (v5e, 16 slots)
+    ("_expert_call", "%_expert_call.73 = f32[1120,768]{1,0} custom-call(s32[1]{0} %a, s32[70]{0} %b, "
+     "s32[70]{0} %c, s32[1]{0} %d, bf16[1120,2560]{1,0} %fusion.374, u8[24,64,1280,768]{3,2,1,0} %w, "
+     "u16[24,64,80,768]{3,2,1,0} %s)", True),
+    ("multiply_convert_fusion", "%multiply_convert_fusion.5 = bf16[1120,768]{1,0} fusion(f32[1120,768]{1,0} "
+     "%_expert_call.72, f32[1120,768]{1,0} %_expert_call.73)", True),
+    ("fusion", "%fusion.309 = f32[16,1,64]{0,2,1} fusion(bf16[16,2560]{1,0} %fusion.307, "
+     "f32[24,2560,64]{2,1,0} %gate, s32[] %layer)", True),
+    ("sort", "%sort.140 = (f32[16,1,64]{0,2,1}, s32[16,1,64]{0,2,1}) sort(f32[16,1,64]{0,2,1} %fusion.350, "
+     "s32[16,1,64]{0,2,1} %iota.419)", True),
+    ("divide_bitcast_fusion", "%divide_bitcast_fusion.5 = f32[16,6]{0,1} fusion(f32[16,1,64]{0,2,1} %g, "
+     "f32[16]{0} %f, f32[16]{0} %r)", True),
+    ("subtract_add_fusion", "%subtract_add_fusion.5 = s32[96]{0} fusion(s32[96]{0} %sort.144, s32[96]{0} %f1, "
+     "s32[96]{0} %f2)", True),
+    ("broadcast_minimum_fusion", "%broadcast_minimum_fusion.5 = s32[70]{0} fusion(s32[70]{0} %while.133)", True),
+    ("multiply_reduce_fusion", "%multiply_reduce_fusion.5 = bf16[16,2560]{1,0} fusion(f32[16,6,2560]{2,1,0} "
+     "%reshape.3335, f32[16,6]{1,0} %copy.323)", True),
+    # rope tables at a head size of 128 are [rows, 64] too: not the router's
+    ("subtract_convert_fusion", "%subtract_convert_fusion.4 = (bf16[16,1,28,64,1]{0,4,3,2,1}, "
+     "bf16[16,1,28,64,1]{0,4,3,2,1}) fusion(f32[16,1,28,64,2]{0,4,3,2,1} %bitcast.1537, f32[16,64]{0,1} %cos, "
+     "f32[16,64]{0,1} %sin)", False),
+    ("while", "%while.318 = (s32[], bf16[16,1,2560]{2,0,1}, f32[24,2560,64]{2,1,0}, s32[96]{0}) "
+     "while((s32[], bf16[16,1,2560]{2,0,1}, f32[24,2560,64]{2,1,0}, s32[96]{0}) %tuple.4)", False),
+    ("_blockdot_call", "%_blockdot_call.12 = f32[16,3584]{1,0} custom-call(bf16[16,2560]{1,0} %x, "
+     "u8[24,1280,3584]{2,1,0} %w)", False),
+    ("_paged_window", "%_paged_window.3 = bf16[16,28,128]{2,1,0} custom-call(bf16[16,28,128]{2,1,0} %q, "
+     "bf16[18,593,4,128,128]{4,3,2,1,0} %k)", False),
+]
+
+
+@pytest.mark.parametrize("tm,slice_rows", [(16, None), (32, 512), (32, 200)])
+def test_expert_layer_ops_are_found_by_sizes_read_from_the_capture(tm, slice_rows):
+    """`moe_busy_share` names no row count: the padded orders and tile maps
+    come from the `_expert_call` instructions the capture holds, so a slice
+    of another length (a prompt's last one) or another tile height is found
+    by itself; rope's [rows, 64] tables are not the router's."""
+    from benchmark.reducers import trace_kernel_layer_share as share
+
+    metric = read_json(os.path.join(BENCH, "metrics", "moe_busy_share.json"))
+    config = read_json(os.path.join(BENCH, "configs", "smallthinker-21b-a3b.json"))
+    ops = [{"group": g, "hlo": h, "seconds": 1.0, "want": w} for g, h, w in _DECODE_OPS]
+    if slice_rows:
+        padded = slice_rows * 6 + 64 * tm  # every group up to a whole tile
+        tiles = padded // tm
+        ops += [{"group": "_expert_call", "seconds": 1.0, "want": True,
+                 "hlo": f"%_expert_call.9 = f32[{padded},768]{{1,0}} custom-call(s32[1]{{0}} %a, "
+                        f"s32[{tiles}]{{0}} %b, bf16[{padded},2560]{{1,0}} %x, u8[24,64,1280,768]{{3,2,1,0}} %w)"},
+                {"group": "fusion", "seconds": 1.0, "want": True,
+                 "hlo": f"%fusion.77 = bf16[{padded},2560]{{1,0}} fusion(bf16[{slice_rows},2560]{{1,0}} %b, "
+                        f"s32[{padded}]{{0}} %order)"},
+                {"group": "sort", "seconds": 1.0, "want": True,
+                 "hlo": f"%sort.9 = (s32[{slice_rows * 6}]{{0}}, s32[{slice_rows * 6}]{{0}}) "
+                        f"sort(s32[{slice_rows * 6}]{{0}} %ids, s32[{slice_rows * 6}]{{0}} %iota)"},
+                {"group": "copy", "seconds": 1.0, "want": True,
+                 "hlo": f"%copy.5 = s32[1,{slice_rows},6]{{2,1,0}} copy(s32[1,{slice_rows},6]{{1,2,0}} %topk)"},
+                {"group": "fusion", "seconds": 1.0, "want": False,
+                 "hlo": f"%fusion.78 = bf16[{slice_rows},3584]{{1,0}} fusion(f32[{slice_rows},3584]{{1,0}} %q)"}]
+    picked = share.layer_ops(metric["params"], config, ops)
+    assert [o["hlo"] for o in ops if o["want"]] == [o["hlo"] for o in ops if o in picked]
+    run = {"config": config, "trace": {"busy_s": 2.0 * len(ops), "ops": ops}}
+    assert share.reduce(metric["params"], run) == pytest.approx(
+        100.0 * sum(o["want"] for o in ops) / (2.0 * len(ops)))
+    # a program without the kernel reports nothing, and so does no trace
+    rest = [o for o in ops if o["group"] != "_expert_call"]
+    assert share.reduce(metric["params"], {"config": config, "trace": {"busy_s": 1.0, "ops": rest}}) is None
+    assert share.reduce(metric["params"], {"config": config, "trace": None}) is None

@@ -173,3 +173,54 @@ def test_hybrid_ssm_step_program_moves_no_layer_of_the_state(hybrid_thunks, name
     assert not moved, moved
     assert "_ssm_step" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 36 * layer_state // 2
+
+
+# --------------------- windowed attention layers over routed experts
+
+
+@pytest.fixture(scope="module")
+def window_moe_thunks(thunks):
+    """The window-and-global, routed-expert model's step programs at its
+    published widths (aot_check.window_moe_cases: 2,560 stream, 28/4 heads
+    of 128, 64 experts of 768 with 6 active, a 151,936-row head), one
+    period of four layers and 4 slots over 320 + the window pool's pages so
+    the engine built on the host stays small. Depends on `thunks` for the
+    platform steer and the cache settings."""
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(aot_check.TARGET, platform="tpu")
+    return {name.split("-slot ")[1]: thunk for name, thunk, _ in
+            aot_check.window_moe_cases(topo, slots=4, pages=320, n_layers=4)}
+
+
+@pytest.mark.parametrize("name", ["paged decode chunk n=4",
+                                  "hybrid step p=512 n=4"])
+def test_window_moe_step_program_compiles_with_its_kernels_named(window_moe_thunks, name):
+    """The decode and hybrid programs compile for v5e; the device plane will
+    read the grouped expert kernel (`_expert_call`, beside the attention
+    matmuls' `_blockdot_call`) and the paged kernel's two names
+    (`_paged_folded` for the global layers, `_paged_window` for the windowed
+    ones), each custom call's line parses as its cost file reads it, and no
+    instruction writes a layer's expert stack (dequantised or not) or a
+    pool's layer."""
+    import re
+
+    from benchmark.costs import moe_experts, paged_attention
+    from experiments import pool_copies
+
+    compiled = window_moe_thunks[name]()
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    groups = {m.group(1) for l in calls
+              for m in [re.search(r"%(_[a-z_]+?)(?:\.\d+)? = ", l)] if m}
+    assert {"_expert_call", "_blockdot_call", "_paged_folded",
+            "_paged_window"} <= groups, groups
+    for line in calls:
+        if "%_expert_call" in line:
+            assert moe_experts.shape({"hlo": line}) in ((64, 2560, 768),
+                                                        (64, 768, 2560))
+        if "%_paged_" in line:
+            assert paged_attention.shape({"hlo": line})[1:] == (4, "bf16")
+    one_expert_layer = 64 * 2560 * 768 // 2  # a projection's packed stack
+    assert not pool_copies.big_movers(text, one_expert_layer)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

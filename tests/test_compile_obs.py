@@ -245,6 +245,83 @@ def test_warmup_report_full_coverage_then_zero_compile_request():
         sched2.shutdown()
 
 
+def test_meter_measures_without_an_entry_and_is_absorbed():
+    """`LEDGER.meter()` counts compile events into itself and records
+    nothing; the dispatch scope that absorbs it makes ONE entry that
+    carries the meter's seconds beside its own."""
+    old = _fresh_contract()
+    try:
+        led = cobs.LEDGER
+        f = jax.jit(lambda x: jnp.cos(x) * 3.25 + 1)
+        x = jnp.ones((5, 3))  # its own eager compile, before the count
+        n0, c0 = len(led.entries), led.total_compiles()
+        meter = led.meter()
+        with meter:
+            lowered = f.lower(x)
+
+        def compile_there():  # another thread, the same meter
+            with meter:
+                lowered.compile()
+
+        th = threading.Thread(target=compile_there)
+        th.start()
+        th.join()
+        assert meter.compile_s > 0 and meter.lower_s + meter.trace_s > 0
+        assert meter.n_backend == 1
+        assert len(led.entries) == n0 and led.total_compiles() == c0
+        with led.scope("metered", "k") as sc:
+            sc.absorb(meter)
+            f(x)  # finds the lowering and the executable: no second compile
+        assert sc.n_backend == 1 and sc.compile_s == meter.compile_s
+        assert len(led.entries) == n0 + 1
+        assert led.entries[-1]["fn"] == "metered"
+        assert led.entries[-1]["compile_s"] > 0
+    finally:
+        cobs.LEDGER.install_contract(old)
+
+
+def test_warmup_compiles_ahead_one_entry_a_program():
+    """warmup() lowers every program, compiles them on a pool of threads,
+    and its dispatches then find the executables: each program has exactly
+    one ledger entry with one backend compile's seconds in it, none is
+    compiled a second time at dispatch, and the engine's state is as a
+    serial warm-up leaves it."""
+    cobs.LEDGER.reset()
+    eng = BatchEngine(CFG, PARAMS, n_slots=2, cache_dtype=jnp.float32,
+                      kv_layout="paged", page_size=PAGE, max_prefill_chunk=4)
+    seen: list = []
+    real = eng._precompile
+
+    def spy(work):
+        meters = real(work)
+        seen.extend(meters)
+        return meters
+
+    eng._precompile = spy
+    rep = eng.warmup(chunk=2, hybrid_budget_hi=4)
+    named = [(fn, key) for fn, key, _ in eng._warm_worklist(2, 4)
+             if fn != "commit"]
+    metered = [m for m in seen if m is not None]
+    assert len(metered) == len(named)  # every jitted program, not `commit`
+    assert all(m.n_backend == 1 and m.compile_s > 0 for m in metered)
+    entries = [e for e in cobs.LEDGER.snapshot(entries=256)["entries"]
+               if e["fn"] != "commit"]
+    assert sorted((e["fn"], e["key"]) for e in entries) == sorted(named)
+    assert all(e["warmup"] and e["compile_s"] > 0 for e in entries)
+    # the dispatch pass added no backend compile to a program's entry
+    totals = cobs.LEDGER.snapshot()["totals"]
+    assert sum(t["compiles"] for fn, t in totals.items()
+               if fn not in ("commit", "untracked")) == len(named)
+    # (`commit` is eager ops an earlier test may have left compiled)
+    assert rep["compiled"] >= len(named) and eng._counts is None
+    assert eng._warmed >= set(named)
+    # a second warm-up lowers nothing ahead and finds everything cached
+    seen.clear()
+    rep2 = eng.warmup(chunk=2, hybrid_budget_hi=4)
+    assert seen and all(m is None for m in seen)
+    assert rep2["compiled"] == 0 and rep2["cached"] == rep2["buckets"]
+
+
 def test_warmup_rejects_busy_engine():
     eng = _engine("dense")
     if not eng.active.any():

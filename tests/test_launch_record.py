@@ -517,6 +517,9 @@ def test_capture_block_is_the_counter_deltas(monkeypatch, tmp_path):
     assert pick(cap["prefill_rows"]) == {"hybrid": 16, "prefill_chunk": 8}
     assert cap["seconds"] >= 0.0
     assert set(cap) == {"launches", "slot_steps", "kv_rows", "prefill_rows",
+                        "kv_rows_read", "moe_assignments",
+                        "moe_experts_touched", "moe_layer_steps",
+                        "moe_group_rows_max", "window_pages_released",
                         "seconds"}
 
 

@@ -622,6 +622,9 @@ def test_debug_perf_capture_block_counts_the_captures_launches(
     cap = json.loads(data)["capture"]
     assert cap == profiling.last_capture()
     assert set(cap) == {"launches", "slot_steps", "kv_rows", "prefill_rows",
+                        "kv_rows_read", "moe_assignments",
+                        "moe_experts_touched", "moe_layer_steps",
+                        "moe_group_rows_max", "window_pages_released",
                         "seconds"}
     assert cap["launches"]["decode"] == during >= 1
     assert cap["slot_steps"]["advanced"] >= 5  # 6 tokens, the first at commit
