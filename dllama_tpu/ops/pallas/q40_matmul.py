@@ -21,9 +21,18 @@ Two TPU-specific design points beyond the reference's scheme:
 2. **Two dequant schemes, split by batch size** (the reference's decode
    GEMV / prefill sgemm split, nn-cpu-ops.cpp:1003-1019):
 
-   * ``deq`` (m > 16): classic in-kernel dequant — unpack nibbles, one
-     fused multiply per weight, bf16 dot. Dequant cost amortizes over the m
-     rows, so prefill is MXU-bound.
+   * ``deq`` (m > 16): dequantise a weight, then one plain dot of m rows a
+     pass. What bound the byte-wise form of this tier was its grid (512 x
+     512 tiles: 32-128 KB a step, the copies alone 70% of a call; PR 37),
+     then vector work a weight. A grid step now moves 0.5-2 MB, the result's
+     whole width where a pass holds it (``_deq_tiles``); inside it the packed
+     rows are read as 32-bit words and every nibble becomes the f32 product
+     (q - 8) * s in four vector ops (``_dequant_words``: xor, shift, mask, an
+     int32 convert that reads (q - 8) * 2^28, one multiply by the scale
+     times 2^-28), rounded once to the activation dtype as before. x stays
+     in VMEM for the call, laid out in the planes' row order by the kernel
+     itself at an m tile's first step (``_deq_position``, through the MXU).
+     Dequantising costs the same for any m, so prefill is MXU-bound.
    * ``blockdot`` (m <= 16, bf16 activations): decode streams every weight
      once for a handful of rows, so what counts is bytes a grid step and
      vector ops a weight. The kernel never builds the dequantized matrix and
@@ -48,7 +57,7 @@ Layout (see ops/quant.QTensor): ``packed: u8[(L,) k/2, n]`` where packed row
 ``32*b + j + 16`` (high nibble); ``scales: f16[(L,) k/32, n]`` (streamed as
 raw u16 bits, widened in-register by ``_scales_f32``).
 
-Grid is ((m_tiles,) n_tiles, k_tiles) with k innermost: the f32 accumulator
+Grid is ((m_tiles,) n_tiles, k_tiles) with k innermost: the f32 result
 block stays VMEM-resident across the k sweep and is written back once per
 (m, n) tile. Inputs are double-buffered by the Pallas pipeline automatically.
 """
@@ -62,36 +71,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dllama_tpu.ops.pallas.tiling import pick_tile as _pick_tile
 from dllama_tpu.ops.quant import Q_BLOCK, QTensor
-
-# f32 bit pattern of 2^23 = 8388608.0; mantissa ulp there is exactly 1, so
-# OR-ing a nibble q into the low bits gives the exact float 2^23 + q, and
-# subtracting (2^23 + 8) yields the exact signed code q - 8 (the subtraction
-# of nearby floats is exact by Sterbenz' lemma) — int->float conversion and
-# the -8 offset in two cheap VPU ops, no convert instruction.
-_EXP_BITS = 0x4B000000
-_V_OFFSET = 8388608.0 + 8.0
-
-# kernel-style override for the chip benches (experiments/kbench.py,
-# q40_decode_bench.py): 'auto' | 'deq' | 'blockdot'. 'auto' is what serves:
-# blockdot for m <= 16, deq above; a forced 'blockdot' still applies only to
-# decode-shaped calls.
-STYLE = "auto"
-
-
-def _unpack_codes(packed_block, tk: int, tn: int):
-    """u8[tk/2, tn] nibbles -> f32[tk/32, 32, tn] of exact codes q - 8."""
-    p = packed_block.astype(jnp.int32)
-    lo = (p & 0x0F) | _EXP_BITS
-    hi = (p >> 4) | _EXP_BITS
-    nb = tk // Q_BLOCK
-    half = Q_BLOCK // 2
-    codes = jnp.concatenate(
-        [lo.reshape(nb, half, tn), hi.reshape(nb, half, tn)], axis=1
-    )
-    return jax.lax.bitcast_convert_type(codes, jnp.float32) - _V_OFFSET
-
 
 # bf16 16.0 in both halves of a 32-bit word; the float ulp at [16, 32) is 1/8,
 # so a nibble q placed at mantissa bits 3..6 reads 16 + q exactly
@@ -138,22 +118,126 @@ def _scales_f32(s):
     return s.astype(jnp.float32)
 
 
-def _deq_kernel(layer_ref, x_ref, packed_ref, scales_ref, out_ref, acc_ref, *, tk, tn):
+#: k rows whose eight nibble planes are one bf16 tile (16 word rows) each:
+#: what the dequantising tier lays x out by, and what its k must be whole in
+_DEQ_GROUP = 128
+#: groups of x laid out a pass of the first grid step's loop
+_DEQ_LAY = 8
+#: (q - 8) * 2^28 is what a nibble reads as at the top of an int32 word
+_TOP_NIBBLE = 2.0 ** -28
+
+
+def _deq_position(src):
+    """Where input dim `src` of a 128-dim group lands among the rows
+    `_dequant_words` leaves: plane e = 2 * byte + nibble of the group's 16
+    word rows, in it block b's packed row quad j4 (src = 32*b + 16*nibble +
+    4*j4 + byte: word row 4*b + j4 holds packed rows 16*b + 4*j4 + byte)."""
+    byte, j4, nib, b = src & 3, (src >> 2) & 3, (src >> 4) & 1, src >> 5
+    return 16 * (2 * byte + nib) + 4 * b + j4
+
+
+def _scale_rows(s):
+    """f32[nb, tn] block scales -> f32[4 nb, tn], a block's row under each
+    of its four word rows: whole tiles of two blocks, chosen by sublane."""
+    nb, tn = s.shape
+    pair = jnp.broadcast_to(s[:, None, :], (nb, 8, tn)).reshape(nb // 2, 2, 8, tn)
+    first = jax.lax.broadcasted_iota(jnp.int32, (nb // 2, 8, tn), 1) < 4
+    return jnp.where(first, pair[:, 0], pair[:, 1]).reshape(4 * nb, tn)
+
+
+def _nibble_planes(w):
+    """u32[r, tn] words of whole 128-dim groups -> f32[r / 16, 8, 16, tn],
+    (q - 8) * 2^28 exactly, a group's eight nibble planes side by side. No
+    byte is widened: one xor a word turns every nibble into q - 8 in two's
+    complement, a shift and a mask put it at the top of the word, where an
+    int32 convert reads it. The planes are one array's leading axis, so the
+    kernel's traced size does not grow with them (a kernel is lowered again
+    at every call site of every program)."""
+    r, tn = w.shape
+    w = (w ^ jnp.uint32(0x88888888)).reshape(r // 16, 1, 16, tn)
+    shift = 28 - 4 * jax.lax.broadcasted_iota(jnp.uint32, (r // 16, 8, 16, tn), 1)
+    v = (w << shift) & jnp.uint32(0xF0000000)
+    return jax.lax.bitcast_convert_type(v, jnp.int32).astype(jnp.float32)
+
+
+def _dequant_words(w, sb, dtype):
+    """u32[r, tn] words and f32[r, tn] scale rows (`_scale_rows`, times
+    2^-28) -> dtype[8 r, tn] dequantised weights (q - 8) * s, a group's rows
+    in `_deq_position` order: the f32 product of q - 8 and s (a power of two
+    apart), rounded once to `dtype`: four vector ops and the rounding a vreg
+    of weights."""
+    r, tn = w.shape
+    planes = _nibble_planes(w) * sb.reshape(r // 16, 1, 16, tn)
+    return planes.astype(dtype).reshape(8 * r, tn)
+
+
+def _deq_dot(xa, w):
+    """[m, rows] x [rows, tn] -> f32[m, tn]: m rows through the MXU a pass."""
+    return jnp.dot(xa, w, preferred_element_type=jnp.float32)
+
+
+def _deq_kernel(layer_ref, x_ref, packed_ref, scales_ref, out_ref, xa_ref, s_ref,
+                *, rows):
+    """Grid step (i, j, kb): rows tile i of x against tile (kb, j) of one
+    layer's weight, dequantised `rows` k rows at a time as whole-array ops
+    and fed to one plain dot a pass (m rows through the MXU, not 4 m)."""
     del layer_ref  # consumed by the index maps
-    kb = pl.program_id(2)
+    j, kb = pl.program_id(1), pl.program_id(2)
+    tm, k = x_ref.shape
+    tk = 2 * packed_ref.shape[0]
+    nb = tk // Q_BLOCK
+    # a float32 x keeps its bits through the 0/1 matrix only at full precision
+    exact = jax.lax.Precision.HIGHEST if x_ref.dtype == jnp.float32 else None
+
+    @pl.when((j == 0) & (kb == 0))
+    def _():
+        # Once an m tile, for every grid step to read: x with each 128-dim
+        # group in the order the planes dequantise to, through the MXU
+        # (exact: one 1 a column), a few groups stacked along the rows a dot.
+        src = jax.lax.broadcasted_iota(jnp.int32, (_DEQ_GROUP, _DEQ_GROUP), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (_DEQ_GROUP, _DEQ_GROUP), 1)
+        place = jnp.where(col == _deq_position(src), 1.0, 0.0).astype(x_ref.dtype)
+
+        def lay(base, groups):
+            at = lambda g: pl.ds(base + g * _DEQ_GROUP, _DEQ_GROUP)
+            xc = jnp.concatenate([x_ref[:, at(g)] for g in range(groups)], axis=0)
+            z = jnp.dot(xc, place, preferred_element_type=jnp.float32,
+                        precision=exact).astype(xa_ref.dtype)
+            for g in range(groups):
+                xa_ref[:, at(g)] = z[g * tm:(g + 1) * tm]
+
+        span = _DEQ_LAY * _DEQ_GROUP
+
+        def chunk(c, carry):
+            lay(pl.multiple_of(c * span, span), _DEQ_LAY)
+            return carry
+
+        jax.lax.fori_loop(0, k // span, chunk, 0)
+        if k % span:
+            lay(k // span * span, k % span // _DEQ_GROUP)
 
     @pl.when(kb == 0)
     def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        out_ref[:] = jnp.zeros_like(out_ref)
 
-    c = _unpack_codes(packed_ref[:], tk, tn)  # [nb, 32, tn] exact q - 8
-    s = _scales_f32(scales_ref[:])[:, None, :]
-    w = (c * s).reshape(tk, tn).astype(x_ref.dtype)
-    acc_ref[:] += jnp.dot(x_ref[:], w, preferred_element_type=jnp.float32)
+    s_ref[0:nb, :] = _scales_f32(scales_ref[:]) * _TOP_NIBBLE
 
-    @pl.when(kb == pl.num_programs(2) - 1)
-    def _():
-        out_ref[:] = acc_ref[:]
+    def sweep(at, rows):
+        """`rows` k rows from row `at(1)` of the tile; `at(d)` is that row
+        over d: the offsets of the packed rows (2) and the scales (32)."""
+        w = pltpu.bitcast(packed_ref[pl.ds(at(2), rows // 2), :], jnp.uint32)
+        sb = _scale_rows(s_ref[pl.ds(at(Q_BLOCK), rows // Q_BLOCK), :])
+        xa = xa_ref[:, pl.ds(pl.multiple_of(kb * tk, _DEQ_GROUP) + at(1), rows)]
+        out_ref[:] += _deq_dot(xa, _dequant_words(w, sb, xa_ref.dtype))
+
+    if rows == tk:
+        sweep(lambda d: 0, tk)
+    else:
+        def one(i, carry):
+            sweep(lambda d: pl.multiple_of(i * (rows // d), _SUB_K // d), rows)
+            return carry
+
+        jax.lax.fori_loop(0, tk // rows, one, 0)
 
 
 #: what the kernel walks k by: eight Q40 blocks, one f32 tile of their scales
@@ -337,49 +421,6 @@ def _blockdot_body(j, kb, x_ref, packed_ref, scales_ref, out_ref, xa_ref,
     jax.lax.fori_loop(0, tn // lanes, lane_step, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _deq_call(layer, x, packed, scales, *, interpret: bool = False):
-    """x[m, k] @ dequant(packed[layer], scales[layer]) -> f32[m, n]."""
-    m, k = x.shape
-    n = packed.shape[-1]
-    # every m tile streams and dequantises the WHOLE weight again, so a batch
-    # up to 512 rows is one tile whatever it divides by: m = 48 (48 serving
-    # slots) split as 3 x 16 cost three passes, 124 us a 2048 x 8192 call
-    # where m = 64 cost 59 (my chip run, PR 29, experiments/q40_decode_bench.py)
-    tm = m if m <= 512 else _pick_tile(m, (512, 256, 128, 64, 32, 16, 8))
-    tn = _pick_tile(n, (512, 256, 128))
-    tk = _pick_tile(k, (512, 256, 128, 64, 32))
-    grid = (m // tm, n // tn, k // tk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tm, tk), lambda i, j, kb, L: (i, kb)),
-            pl.BlockSpec((None, tk // 2, tn), lambda i, j, kb, L: (L[0], kb, j)),
-            pl.BlockSpec((None, tk // Q_BLOCK, tn), lambda i, j, kb, L: (L[0], kb, j)),
-        ],
-        out_specs=pl.BlockSpec((tm, tn), lambda i, j, kb, L: (i, j)),
-        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_deq_kernel, tk=tk, tn=tn),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * m * n * k,
-            bytes_accessed=m * k * x.dtype.itemsize
-            + k * n // 2
-            + (k // Q_BLOCK) * n * scales.dtype.itemsize
-            + m * n * 4,
-            transcendentals=0,
-        ),
-        interpret=interpret,
-    )(layer, x, packed, scales)
-
-
 #: What the chip charges beyond a call's bytes, in KB of HBM time at the 600
 #: GB/s a weight stream sustains (fitted to `experiments/kbench.py q40`'s
 #: tile sweep, my chip runs, PR 32): a grid step 0.17 us, a pass of the
@@ -429,6 +470,120 @@ def _blockdot_tiles(k: int, n: int) -> tuple[int, int]:
             if best is None or (odd, cost, -tk) < best[0]:
                 best = ((odd, cost, -tk), tk, tn)
     return best[1], best[2]
+
+
+#: weights a pass of the dequantising tier's loop covers at most: its f32
+#: planes are whole-array values, about 10 B a weight of VMEM while they live
+_DEQ_PASS_WEIGHTS = 9 << 18
+#: what a step's buffers and a pass's planes may take of VMEM, and what x
+#: (its block's two buffers and the laid-out copy) may take beside them
+_DEQ_VMEM, _DEQ_X_BYTES = 40 * 1024 * 1024, 36 * 1024 * 1024
+
+
+def _deq_pass(tk: int, tn: int) -> int:
+    """k rows a pass of the dequantising tier's loop over a tile: all of
+    them, or the most whole 256-row steps dividing tk that keep the pass
+    within `_DEQ_PASS_WEIGHTS` (0: the tile is too wide for any)."""
+    if tk * tn <= _DEQ_PASS_WEIGHTS:
+        return tk
+    return max((r for r in range(_SUB_K, tk, _SUB_K)
+                if tk % r == 0 and r * tn <= _DEQ_PASS_WEIGHTS), default=0)
+
+
+@functools.lru_cache(maxsize=None)
+def _deq_tiles(m: int, k: int, n: int, itemsize: int = 2) -> tuple[int, int, int, int]:
+    """(tm, tk, tn, rows a pass) for the m > 16 tier from the call's shape
+    alone. tm: the whole batch up to 512 rows (every m tile streams and
+    dequantises the whole weight again: m = 48 split as 3 x 16 cost three
+    passes, PR 29), less where x would not fit beside its laid-out copy.
+    (tk, tn): the tile that costs least by `_blockdot_tiles`' figures (a
+    grid step, a pass of the inner loop, the first tile's copy) and a grid
+    step more a tile of the result, among tn a multiple of 128 dividing n
+    and tk the whole of k or whole 256-row steps dividing it, within the
+    VMEM budget with the m tile's f32 result; no tile under `_STEP_FLOOR`
+    and no weight of two floors or more in one step, where its divisors
+    leave another choice. A pass is the tile's whole width and as many of
+    its rows as `_DEQ_PASS_WEIGHTS` allows."""
+    fits = lambda t: 3 * t * k * itemsize <= _DEQ_X_BYTES
+    tm = m if m <= 512 and fits(m) else next(
+        t for t in (512, 256, 128, 64, 32, 16, 8) if m % t == 0 and (fits(t) or t == 8))
+    best = None
+    depths = [k] + [t for t in range(_SUB_K, k, _SUB_K) if k % t == 0]
+    for tn in (t for t in range(128, n + 1, 128) if n % t == 0):
+        for tk in depths:
+            nb = tk // Q_BLOCK
+            rows = _deq_pass(tk, tn)
+            # two buffers of packed rows, u16 scales and the f32 result, the
+            # scales as f32, a pass's planes
+            vmem = (2 * (tk * tn // 2 + nb * tn * 2 + tm * tn * 4) + nb * tn * 4
+                    + 10 * rows * tn)
+            if not rows or vmem > _DEQ_VMEM:
+                continue
+            steps = (k // tk) * (n // tn)
+            odd = (tk * tn // 2 < min(_STEP_FLOOR, k * n // 4)
+                   or steps == 1 and k * n // 2 >= 2 * _STEP_FLOOR)
+            # a tile of the result costs a grid step more: its zeroing and
+            # write-back (0.14-0.20 us a tile over `kbench.py deq`'s sweep,
+            # my chip run, PR 37: whole-width tiles read 1.2-1.4 us under
+            # whole-depth ones on Granite's 16 M-weight shapes)
+            cost = ((steps + n // tn) * _STEP_KB + steps * (tk // rows) * _PASS_KB
+                    + _FIRST_TILE * tk * tn / 2048)
+            if best is None or (odd, cost, -tk) < best[0]:
+                best = ((odd, cost, -tk), tk, tn, rows)
+    return (tm,) + best[1:]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "tk", "tn", "rows"))
+def _deq_call(layer, x, packed, scales, *, interpret: bool = False,
+              tk: int | None = None, tn: int | None = None, rows: int | None = None):
+    """x[m, k] @ dequant(packed[layer], scales[layer]) -> f32[m, n], k whole
+    128-dim groups. The name, the f32[m, n] result and the packed array as
+    the first u8 operand are what the benchmark's trace reader finds and
+    prices this call by (benchmark/costs/q40_matmul.py). tk / tn / rows are
+    the chip sweep's overrides (`experiments/kbench.py deq`); serving passes
+    none and runs `_deq_tiles`."""
+    m, k = x.shape
+    n = packed.shape[-1]
+    tm, dtk, dtn, _ = _deq_tiles(m, k, n, x.dtype.itemsize)
+    tk, tn = tk or dtk, tn or dtn
+    rows = min(rows or _deq_pass(tk, tn), tk)
+    assert k % _DEQ_GROUP == 0 and tk % rows == 0 and (rows == tk or rows % _SUB_K == 0)
+    nb = tk // Q_BLOCK
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(m // tm, n // tn, k // tk),
+        in_specs=[
+            pl.BlockSpec((tm, k), lambda i, j, kb, L: (i, 0)),
+            pl.BlockSpec((None, tk // 2, tn), lambda i, j, kb, L: (L[0], kb, j)),
+            pl.BlockSpec((None, nb, tn), lambda i, j, kb, L: (L[0], kb, j)),
+        ],
+        out_specs=pl.BlockSpec((tm, tn), lambda i, j, kb, L: (i, j)),
+        scratch_shapes=[pltpu.VMEM((tm, k), x.dtype),
+                        pltpu.VMEM((-(-nb // 8) * 8, tn), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_deq_kernel, rows=rows),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            # an m tile's first grid step lays x out for its later ones
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            # all but 8 MB of VMEM is this call's to claim: as for
+            # `_blockdot_call`, XLA's memory-space assignment then cannot
+            # park a stacked scales array there (`slice-done`, 1.3 ms of a
+            # Granite decode step until PR 37; beside a claim of 96 MB the
+            # 19 MB of out_proj's scales still fitted)
+            vmem_limit_bytes=120 * 1024 * 1024,
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * n * k,
+            bytes_accessed=m * k * x.dtype.itemsize
+            + (m // tm) * (k * n // 2 + (k // Q_BLOCK) * n * scales.dtype.itemsize)
+            + m * n * 4,
+            transcendentals=0,
+        ),
+        interpret=interpret,
+    )(layer, x, packed, scales)
 
 
 def _x_and_scratch(x, tk: int, tn: int, m: int | None = None):
@@ -588,9 +743,11 @@ def q40_expert_matmul(x: jax.Array, w: QTensor, *, layer, tile_expert,
 
 
 def supported(x_shape: tuple[int, ...], w: QTensor) -> bool:
-    """Tileability check used by the ops.matmul dispatcher."""
+    """Tileability check used by the ops.matmul dispatcher: whole 128-lane
+    tiles of n, and k in whole 128-dim groups (what the dequantising tier
+    lays x out by; the block-dot tier asks for 256 on top)."""
     k, n = w.shape[-2], w.shape[-1]
-    return k % Q_BLOCK == 0 and n % 128 == 0 and k >= 128
+    return k % _DEQ_GROUP == 0 and n % 128 == 0
 
 
 def q40_matmul(
@@ -603,7 +760,7 @@ def q40_matmul(
     stacked form is indexed by the DMA engine, never sliced by XLA.
     """
     *lead, k = x.shape
-    assert k % Q_BLOCK == 0 and k >= 128 and w.shape[-1] % 128 == 0, (
+    assert k % _DEQ_GROUP == 0 and w.shape[-1] % 128 == 0, (
         f"untileable Q40 matmul: k={k}, n={w.shape[-1]} (see supported())"
     )
     m = 1
@@ -622,12 +779,11 @@ def q40_matmul(
         scales = jax.lax.bitcast_convert_type(scales, jnp.uint16)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     x2 = x.reshape(m, k)
-    # the block-dot kernel carries its codes in bf16, walks k by eight blocks
-    # and takes 16 rows (a whole bf16 tile): other activations, depths and
-    # batches take the dequantising tier, padded to the f32 sublane (8)
-    blockdot = (STYLE != "deq" and m <= 16 and k % _SUB_K == 0
-                and x2.dtype == jnp.bfloat16)
-    pad = (-m) % (16 if blockdot else 8)
+    # the block-dot kernel carries its codes in bf16 and walks k by eight
+    # blocks: other activations, depths and batches take the dequantising
+    # tier; both take whole tiles of rows (16 of bf16, 8 of f32)
+    blockdot = m <= 16 and k % _SUB_K == 0 and x2.dtype == jnp.bfloat16
+    pad = (-m) % (32 // x2.dtype.itemsize)
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     if blockdot:
@@ -637,13 +793,3 @@ def q40_matmul(
     if pad:
         out = out[:m]
     return out.reshape(*lead, n).astype(x.dtype)
-
-
-def q40_matmul_2d(
-    x: jax.Array, packed: jax.Array, scales: jax.Array, *, interpret: bool = False
-) -> jax.Array:
-    """Back-compat wrapper: x[m, k] @ dequant(packed, scales) -> f32[m, n]."""
-    if scales.dtype == jnp.float16:
-        scales = jax.lax.bitcast_convert_type(scales, jnp.uint16)
-    layer = jnp.zeros((1,), jnp.int32)
-    return _deq_call(layer, x, packed[None], scales[None], interpret=interpret)

@@ -26,6 +26,7 @@ kernels, `--full`'s tile overrides — are flagged but not fatal).
 """
 
 import os
+import re
 import sys
 import time
 
@@ -87,18 +88,14 @@ def cases(full: bool):
     layer = S((1,), jnp.int32)
     out = []
 
-    def style_case(name, style, m, k, n, production, layers=L, **tiles):
+    def q40_case(name, m, k, n, production, layers=L, **tiles):
         packed, scales = (S((layers, k // 2, n), jnp.uint8),
                           S((layers, k // Q_BLOCK, n), jnp.uint16))
 
-        def fn(l, x, p, s, style=style):
+        def fn(l, x, p, s):
             if tiles:  # the chip sweep's overrides, on the jitted call itself
                 return qmod._blockdot_call(l, x, p, s, **tiles)
-            qmod.STYLE = style
-            try:
-                return qmod.q40_matmul(x, QTensor(p, s), l)
-            finally:
-                qmod.STYLE = "auto"
+            return qmod.q40_matmul(x, QTensor(p, s), l)
 
         out.append((name, fn, (layer, S((m, k), jnp.bfloat16), packed, scales), production))
 
@@ -110,14 +107,14 @@ def cases(full: bool):
                      S((k // Q_BLOCK, n), jnp.float16)), True))
 
     # decode rows = serving slots, prefill rows = the 256-token chunk cap;
-    # style auto resolves blockdot (m <= 16) / deq (m > 16)
+    # the dispatcher takes blockdot (m <= 16) / deq (m > 16)
     for m in (SLOTS, 256):
         tier = "decode" if m <= 16 else "prefill"
-        style_case(f"q40 {tier} m={m} w1({DIM}x{HIDDEN})", "auto", m, DIM, HIDDEN, True)
-        style_case(f"q40 {tier} m={m} w2({HIDDEN}x{DIM})", "auto", m, HIDDEN, DIM, True)
+        q40_case(f"q40 {tier} m={m} w1({DIM}x{HIDDEN})", m, DIM, HIDDEN, True)
+        q40_case(f"q40 {tier} m={m} w2({HIDDEN}x{DIM})", m, HIDDEN, DIM, True)
         flat_case(f"q40 {tier} m={m} wcls({DIM}x{VOCAB})", m, DIM, VOCAB)
-    style_case(f"q40 decode m={SLOTS} wk({DIM}x{HKV * HD})", "auto", SLOTS, DIM, HKV * HD, True)
-    style_case(f"q40 spec-verify m={SLOTS * (SPEC_K + 1)} w1", "auto",
+    q40_case(f"q40 decode m={SLOTS} wk({DIM}x{HKV * HD})", SLOTS, DIM, HKV * HD, True)
+    q40_case(f"q40 spec-verify m={SLOTS * (SPEC_K + 1)} w1",
                SLOTS * (SPEC_K + 1), DIM, HIDDEN, True)
     flat_case("q40 decode m=8 wcls8b(4096x128256)", 8, 4096, 128256)
     flat_case("q40 prefill m=256 wcls8b(4096x128256)", 256, 4096, 128256)
@@ -127,11 +124,19 @@ def cases(full: bool):
             ("deepseek wq", 16, 4096, 4096, 30), ("deepseek w1", 16, 4096, 11008, 30),
             ("deepseek w2", 16, 11008, 4096, 30), ("deepseek head", 16, 4096, 102400, 1),
             ("granite head", 8, 2048, 100352, 1), ("granite in_proj", 8, 2048, 8576, 40)):
-        style_case(f"q40 decode m={m} {tag}({k}x{n})", "auto", m, k, n, True,
+        q40_case(f"q40 decode m={m} {tag}({k}x{n})", m, k, n, True,
                    layers=layers)
+    # the dequantising tier (m > 16) at the cells' own shapes: Granite's 48
+    # slots, its head, a SmallThinker slice, a DeepSeek slice over k = 43 x 256
+    for tag, m, k, n, layers in (
+            ("granite in_proj", 48, 2048, 8576, 40), ("granite out_proj", 48, 4096, 2048, 40),
+            ("granite w1", 48, 2048, 8192, 40), ("granite w2", 48, 8192, 2048, 40),
+            ("granite head", 48, 2048, 100352, 1), ("smallthinker wq", 512, 2560, 3584, 24),
+            ("deepseek w2", 128, 11008, 4096, 30)):
+        q40_case(f"q40 m={m} {tag}({k}x{n})", m, k, n, True, layers=layers)
     if full:
         for tn in (128, 256, 512, 1024, 2048):
-            style_case(f"blockdot tiles tk={DIM} tn={tn}", "blockdot",
+            q40_case(f"blockdot tiles tk={DIM} tn={tn}",
                        16, DIM, HIDDEN, False, tk=DIM, tn=tn)
 
     # q80 fused matmuls (packed int8 weights, the Q80-file fast path): the
@@ -674,6 +679,15 @@ def all_cases(topo, full: bool = False):
     return out + serving_cases(topo)
 
 
+def scale_slices(hlo_text: str) -> tuple[int, int]:
+    """(`slice-start` instructions, those of a u16 array) in a compiled
+    program's text: what XLA's memory-space assignment copies into VMEM
+    ahead of its user, and how much of it is a Q40 call's scales."""
+    starts = [line for line in hlo_text.splitlines()
+              if re.search(r"= .*slice-start\(", line)]
+    return len(starts), sum("u16[" in line for line in starts)
+
+
 def main():
     full = "--full" in sys.argv
     md_path = "MOSAIC_AOT.md"
@@ -687,14 +701,16 @@ def main():
     from dllama_tpu.ops import matmul as mmod
 
     mmod.device_platform = lambda: "tpu"  # see module docstring
-    rows, prod_reject = [], []
+    rows, prod_reject, parked = [], [], {}
     topo = topology()
     for cname, thunk, production in (all_cases(topo, full) + hybrid_cases(topo)
                                      + window_moe_cases(topo)):
         t0 = time.time()
         try:
-            thunk()
+            compiled = thunk()
             verdict = "ACCEPT"
+            if cname.startswith("serve "):
+                parked[cname] = scale_slices(compiled.as_text())
         except Exception as e:
             verdict = f"REJECT {repr(e)[:220]}"
             if production:
@@ -730,6 +746,17 @@ def main():
             f.write(f"| {cname} | {hb} | {depth} | {nbytes:,} |\n")
             print(f"paged plan | {cname}: hb={hb} depth={depth} "
                   f"vmem={nbytes:,} B")
+        f.write("\n## Operands XLA copies into VMEM ahead of a kernel\n\n"
+                "`slice-start` instructions of each whole step program (XLA's "
+                "memory-space assignment prefetching an operand in slices; on the "
+                "chip their waits are `slice-done`), and those of a u16 array: a "
+                "Q40 call's stacked scales, which the calls' VMEM claim keeps out "
+                "(PERF.md section 6, PR 32 and PR 37; before PR 37 the hybrid-ssm "
+                "decode program read 48 / 24 and its hybrid step 52 / 24).\n\n"
+                "| case | slice-start | of u16 scales |\n|---|---|---|\n")
+        for cname, (starts, scales) in parked.items():
+            f.write(f"| {cname} | {starts} | {scales} |\n")
+            print(f"slices | {cname}: slice-start {starts}, of u16 scales {scales}")
     print(f"wrote {md_path}")
     print("AOT CHECK " + ("FAIL: production kernels rejected: " + str(prod_reject)
                           if prod_reject else "ALL PRODUCTION KERNELS ACCEPT"))

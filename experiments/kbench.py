@@ -3,6 +3,7 @@
 Usage: python experiments/kbench.py suite
        python experiments/kbench.py paged
        python experiments/kbench.py q40 [--no-tiles]
+       python experiments/kbench.py deq [--no-tiles] [--parent]
        python experiments/kbench.py M SHAPE [variant ...]
 'suite' benches the decode variants (m=8 on w1/wcls) and the prefill tier
 comparison (m=256/512: in-kernel deq vs XLA dequant-dot) in one process.
@@ -13,15 +14,21 @@ against what the HBM would take for the rows it needs.
 shapes at its 16 rows, 300 calls in one scan with the layer cycling: parity,
 the kernel as it is and with each part taken out, the (tk, tn) tile sweep
 (--no-tiles leaves it out) and the inner loops' (lanes, rows a pass) sweep.
-'suite --smoke' (and 'paged --smoke', 'q40 --smoke') runs the same code path on CPU (interpret-mode Pallas, tiny
-shapes, 2 iters) so CI proves the harness cannot crash on the chip; smoke
+'deq' times the dequantising Q40 tier (m > 16) alone at Granite's shapes at
+48 and 64 rows and a prefill slice: parity, the kernel as it is and with each
+part taken out, the block-dot kernel at the same rows, the (tk, tn, rows a
+pass) sweep; --parent adds PR 36's byte-wise body whole, in parts and over its
+tiles (the yardstick PR 37 rebuilt the tier against).
+'suite --smoke' (and 'paged --smoke', 'q40 --smoke', 'deq --smoke') runs the
+same code path on CPU (interpret-mode Pallas, tiny shapes, 2 iters) so CI proves the harness cannot crash on the chip; smoke
 numbers are meaningless, only completion matters.
-  variants: A  production dispatch (q40_matmul auto: blockdot for m<=16, deq above)
-            DQ forced deq-style kernel      BD forced blockdot kernel
+  variants: A  production dispatch (q40_matmul: blockdot for m<=16, deq above)
+            DQ the dequantising tier's call BD the block-dot tier's call
             B  legacy fma-f32 kernel        D  bf16-weights roofline reference
             E  XLA dequantize-then-dot
 Measures achieved HBM GB/s (packed+scales bytes) on 1B-preset shapes.
 """
+import contextlib
 import functools
 import os
 import sys
@@ -152,17 +159,19 @@ def make_inputs(m, label):
 
 
 def dispatch_closure(w, style):
-    """Production-dispatch closure with a forced style; a FRESH closure per
-    row so each re-traces under its own style."""
+    """`q40_matmul` as it serves ('auto') or one tier's jitted call on the
+    same operands ('deq' / 'blockdot', rows padded to its tile); a FRESH
+    closure per row so each traces for itself."""
+    if style == "auto":
+        return lambda x, w=w: qmod.q40_matmul(x, w, interpret=INTERPRET)
+    call = {"deq": qmod._deq_call, "blockdot": qmod._blockdot_call}[style]
+    scales = jax.lax.bitcast_convert_type(w.scales, jnp.uint16)[None]
 
-    def prod(x, w=w, style=style):
-        qmod.STYLE = style
-        try:
-            return qmod.q40_matmul(x, w, interpret=INTERPRET)
-        finally:
-            qmod.STYLE = "auto"
+    def tier(x, packed=w.packed[None], scales=scales):
+        x = jnp.pad(x, ((0, -x.shape[0] % 16), (0, 0)))
+        return call(jnp.zeros((1,), jnp.int32), x, packed, scales, interpret=INTERPRET)
 
-    return prod
+    return tier
 
 
 def run_one(m, label, variants):
@@ -174,8 +183,6 @@ def run_one(m, label, variants):
         # row's other timings in a one-shot TPU window
         try:
             if v in ("A", "DQ", "BD"):
-                # NOTE: a forced blockdot applies only when m <= 16; larger m
-                # silently uses deq (the dispatcher's prefill rule)
                 style = {"A": "auto", "DQ": "deq", "BD": "blockdot"}[v]
                 t = bench(dispatch_closure(w, style), (x,))
                 rows.append((f"{v} {style}", t, qbytes))
@@ -240,7 +247,7 @@ def enable_smoke():
     ITERS = 2
     SHAPES = {
         "wq": (128, 128),
-        "w1": (128, 256),
+        "w1": (256, 256),  # the block-dot tier walks k by 256
         "w2": (256, 128),
         "wcls": (128, 512),
     }
@@ -251,6 +258,10 @@ def enable_smoke():
     ]
     global PAGED_CELLS, PAGED_CALLS, Q40_CELLS, Q40_CALLS
     global Q40_SWEEP_TK, Q40_SWEEP_TN, Q40_SWEEP_LANES, Q40_SWEEP_ROWS
+    global DEQ_SHAPES, DEQ_SWEEP_TK, DEQ_SWEEP_TN, DEQ_SWEEP_BYTES, DEQ_PARENT_TK, DEQ_PARENT_TN
+    DEQ_SHAPES = {"tiny stacked": (512, 384, 2, (48,)), "tiny head": (256, 256, 1, (24,))}
+    DEQ_SWEEP_TK, DEQ_SWEEP_TN, DEQ_SWEEP_BYTES = (256, None), (128, -1), (0, 1 << 30)
+    DEQ_PARENT_TK, DEQ_PARENT_TN = (256, None), (128,)
     Q40_CALLS = 2
     Q40_CELLS = {"tiny": {"stacked": (8192, 256, 2), "head": (256, 384, 1)}}
     Q40_SWEEP_TK, Q40_SWEEP_TN, Q40_SWEEP_LANES = (4096, None), (128, -1), (128,)
@@ -369,7 +380,7 @@ PAGED_CELLS = {
         rows=(100, 1000)),
 }
 PAGED_CALLS = 300
-HBM_GBS = 819.0  # TPU v5e (benchmark/peaks.json)
+HBM_GBS, MXU_TFLOPS = 819.0, 197.0  # TPU v5e (benchmark/peaks.json)
 
 
 def bench_paged_decode(cells=None, calls=None, rows=None):
@@ -542,25 +553,34 @@ def _q40_ablations():
     }
 
 
-def _q40_row(tag, m, k, n, data, patch=None, **tiles):
-    """One timed row; `patch` swaps kernel helpers for the row's trace."""
+@contextlib.contextmanager
+def _swapped(call, patch):
+    """`q40_matmul`'s helpers swapped (name -> stand-in) for one row's trace
+    of the jitted `call`, and put back."""
     saved = {name: getattr(qmod, name) for name in patch or {}}
-    call = lambda layer, x, p, s: qmod._blockdot_call(
-        layer, x, p, s, interpret=INTERPRET, **tiles)
     try:
         for name, fn in (patch or {}).items():
             setattr(qmod, name, fn)
-        qmod._blockdot_call.clear_cache()
-        us = q40_loop_us(call, *data)
+        call.clear_cache()
+        yield
+    finally:
+        for name, fn in saved.items():
+            setattr(qmod, name, fn)
+        call.clear_cache()
+
+
+def _q40_row(tag, m, k, n, data, patch=None, **tiles):
+    """One timed row; `patch` swaps kernel helpers for the row's trace."""
+    call = lambda layer, x, p, s: qmod._blockdot_call(
+        layer, x, p, s, interpret=INTERPRET, **tiles)
+    try:
+        with _swapped(qmod._blockdot_call, patch):
+            us = q40_loop_us(call, *data)
         floor = q40_floor_us(m, k, n)
         print(f"q40 {tag}: {us:.2f} us a call, {100 * floor / us:.1f}% of the "
               f"byte roofline ({floor:.2f} us)")
     except Exception as e:
         print(f"q40 {tag}: FAILED {e!r}"[:300])
-    finally:
-        for name, fn in saved.items():
-            setattr(qmod, name, fn)
-        qmod._blockdot_call.clear_cache()
     sys.stdout.flush()
 
 
@@ -614,6 +634,220 @@ def bench_q40(cells=None, tiles=True):
             del x, packed, scales, data
 
 
+# ------------------------------------------------------------------ deq mode
+#: The Q40 matmul shapes the dequantising tier (m > 16) runs in the
+#: benchmark's cells (PERF.md section 4): name -> (k, n, layers, rows).
+DEQ_SHAPES = {
+    "granite in_proj": (2048, 8576, 40, (48, 64)),
+    "granite out_proj": (4096, 2048, 40, (48, 64)),
+    "granite w1/w3": (2048, 8192, 40, (48, 64)),
+    "granite w2": (8192, 2048, 40, (48, 64)),
+    "granite head": (2048, 100352, 1, (48,)),
+    "smallthinker wq (a slice)": (2560, 3584, 24, (256, 512)),
+}
+DEQ_SWEEP_TK = (256, 512, 1024, 2048, None)  # None = the whole of k
+DEQ_SWEEP_TN = (256, 512, 1024, 2048, -1, -2, -14)  # -d = n / d
+DEQ_SWEEP_ROWS = (256, 1024, None)  # None = what `_deq_pass` gives the tile
+DEQ_SWEEP_BYTES = (256 * 1024, 4 * 1024 * 1024)  # packed bytes a grid step
+DEQ_SWEEP_PASS = 5 << 19  # weights a pass
+DEQ_PARENT_TK, DEQ_PARENT_TN = (512, 2048, None), (512, 2048)
+
+# f32 2^23 + q with the nibble OR-ed into the mantissa; minus (2^23 + 8) = q - 8
+_EXP_BITS, _V_OFFSET = 0x4B000000, 8388608.0 + 8.0
+
+#: the byte-wise body this tier ran until PR 37, whole or with parts taken
+#: out: what feeds the dot instead of the dequantised tile
+DEQ_PARENT_PARTS = ("as it was", "unpack gone", "scale multiply gone",
+                    "convert + dot + DMA", "dot + DMA", "DMA only")
+
+
+def _deq_parent_kernel(layer_ref, x_ref, packed_ref, scales_ref, out_ref, acc_ref,
+                       *, tk, tn, part):
+    """PR 36's `_deq_kernel` (every packed byte widened to int32, mask, or, a
+    concatenate, a subtract, one f32 multiply a weight, a convert), kept here
+    as the yardstick the rebuilt tier is read against, with one part swapped
+    for the cheapest thing of the same shape and dtype."""
+    del layer_ref
+    kb = pl.program_id(1)
+
+    @pl.when(kb == 0)
+    def _():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    nb = tk // Q_BLOCK
+    raw = lambda dt: pltpu.bitcast(packed_ref[:], dt)  # no byte widened
+    if part == "DMA only":
+        acc_ref[0:8, 0:128] += jax.lax.bitcast_convert_type(
+            raw(jnp.uint32)[0:8, 0:128], jnp.float32)
+    else:
+        if part == "dot + DMA":
+            w = jnp.concatenate([raw(jnp.bfloat16)] * 4, axis=0)  # [tk, tn]
+        else:
+            if part in ("unpack gone", "convert + dot + DMA"):
+                c = jnp.concatenate([jax.lax.bitcast_convert_type(
+                    raw(jnp.uint32), jnp.float32)] * 8, axis=0).reshape(nb, Q_BLOCK, tn)
+            else:
+                p = packed_ref[:].astype(jnp.int32)
+                lo = (p & 0x0F) | _EXP_BITS
+                hi = (p >> 4) | _EXP_BITS
+                c = jax.lax.bitcast_convert_type(jnp.concatenate(
+                    [lo.reshape(nb, 16, tn), hi.reshape(nb, 16, tn)], axis=1),
+                    jnp.float32) - _V_OFFSET
+            if part in ("as it was", "unpack gone"):
+                c = c * qmod._scales_f32(scales_ref[:])[:, None, :]
+            w = c.reshape(tk, tn).astype(x_ref.dtype)
+        acc_ref[:] += jnp.dot(x_ref[:], w, preferred_element_type=jnp.float32)
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _():
+        out_ref[:] = acc_ref[:]
+
+
+@functools.partial(jax.jit, static_argnames=("tk", "tn", "part"))
+def _deq_parent_call(layer, x, packed, scales, *, tk=None, tn=None, part="as it was"):
+    """PR 36's `_deq_call` (one m tile, tiles 512 x 512 unless given)."""
+    m, k = x.shape
+    n = packed.shape[-1]
+    tn = tn or _pick_tile(n, (512, 256, 128))
+    tk = tk or _pick_tile(k, (512, 256, 128, 64, 32))
+    return pl.pallas_call(
+        functools.partial(_deq_parent_kernel, tk=tk, tn=tn, part=part),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n // tn, k // tk),
+            in_specs=[
+                pl.BlockSpec((m, tk), lambda j, kb, L: (0, kb)),
+                pl.BlockSpec((None, tk // 2, tn), lambda j, kb, L: (L[0], kb, j)),
+                pl.BlockSpec((None, tk // Q_BLOCK, tn), lambda j, kb, L: (L[0], kb, j)),
+            ],
+            out_specs=pl.BlockSpec((m, tn), lambda j, kb, L: (0, j)),
+            scratch_shapes=[pltpu.VMEM((m, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            # the default tiles need no claim; the sweep's larger ones do
+            **({"vmem_limit_bytes": 96 * 1024 * 1024} if tk * tn > 512 * 512 else {})),
+        interpret=INTERPRET,
+    )(layer, x, packed, scales)
+
+
+def _deq_row(tag, m, k, n, data, call):
+    """One timed row beside what `q40_deq_roofline` prices the call at: the
+    larger of its bytes over 819 GB/s and 2mkn over 197 TFLOP/s."""
+    from benchmark.costs.q40_matmul import cost
+
+    try:
+        us = q40_loop_us(call, *data)
+        flops, nbytes = cost(m, k, n)
+        by_bytes, by_mxu = nbytes / HBM_GBS / 1e3, flops / MXU_TFLOPS / 1e6
+        floor = max(by_bytes, by_mxu)
+        print(f"deq {tag}: {us:.2f} us a call, {100 * floor / us:.1f}% of the "
+              f"roofline ({floor:.2f} us: bytes {by_bytes:.2f}, MXU {by_mxu:.2f})")
+        return us
+    except Exception as e:
+        print(f"deq {tag}: FAILED {e!r}"[:300])
+    finally:
+        sys.stdout.flush()
+
+
+def _deq_parity(call, x, packed, scales):
+    """Largest difference from the float32 dequantise-then-dot, as a share
+    of the largest value, at the middle layer."""
+    from dllama_tpu.ops.quant import QTensor
+
+    li = packed.shape[0] // 2
+    w = QTensor(packed[li], jax.lax.bitcast_convert_type(
+        scales[li], jnp.float16)).dequantize(jnp.float32)
+    want = jnp.dot(x.astype(jnp.float32), w, precision="highest")
+    got = call(jnp.full((1,), li, jnp.int32), x, packed, scales)
+    return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+
+def _deq_ablations():
+    """The tier's kernel with one part taken out, by swapping the helper
+    that does it (`q40_matmul._dequant_words`, `_scale_rows`)."""
+    raw = lambda w, sb, dt: jnp.concatenate(
+        [pltpu.bitcast(w ^ jnp.uint32(1), dt)] * (2 * jnp.dtype(dt).itemsize), axis=0)
+
+    unscaled = lambda w, sb, dt: qmod._nibble_planes(w).astype(dt).reshape(
+        8 * w.shape[0], w.shape[1])
+
+    return {
+        "as it is": {},
+        "no scale rows (one row for all)": {"_scale_rows": lambda s: jnp.broadcast_to(
+            s[0:1], (4 * s.shape[0], s.shape[1]))},
+        "no scale multiply": {"_scale_rows": lambda s: s[0:1], "_dequant_words": unscaled},
+        "no dequantise (the packed bits fed to the dot)": {
+            "_scale_rows": lambda s: s[0:1], "_dequant_words": raw},
+        "DMA only": {"_scale_rows": lambda s: s[0:1], "_dequant_words": raw,
+                     "_deq_dot": lambda xa, w: jnp.zeros(
+                         (xa.shape[0], w.shape[1]), jnp.float32) + w[0:1].astype(jnp.float32)},
+    }
+
+
+def _deq_now(tag, m, k, n, data, patch=None, **tiles):
+    """One timed row of the tier as it is; `patch` swaps kernel helpers."""
+    with _swapped(qmod._deq_call, patch):
+        return _deq_row(tag, m, k, n, data, lambda layer, x, p, s: qmod._deq_call(
+            layer, x, p, s, interpret=INTERPRET, **tiles))
+
+
+def bench_deq(shapes=None, tiles=True, parent=False):
+    """The dequantising Q40 tier (m > 16) alone on the chip at the cells'
+    real shapes and rows: parity against the float32 dequantise-then-dot,
+    the tier as it is and with each part taken out, the block-dot kernel at
+    the same rows, the (tk, tn, rows a pass) sweep, and (--parent) PR 36's
+    byte-wise body whole, with each part taken out and over its tiles; 300
+    calls in one jitted scan over the layer-stacked arrays, the layer
+    cycling. A layer's sum x 40 is what a Granite decode step spends in
+    `_deq_call` (PERF.md section 6, PR 37)."""
+    now = lambda layer, x, p, s: qmod._deq_call(layer, x, p, s, interpret=INTERPRET)
+    for name, (k, n, layers, rows) in (shapes or DEQ_SHAPES).items():
+        for m in rows:
+            data = q40_inputs(m, k, n, layers)
+            tag = f"{name} {k}x{n} m={m}"
+            tm, tk0, tn0, rows0 = qmod._deq_tiles(m, k, n)
+            print(f"deq {tag}: tiles tm={tm} tk={tk0} tn={tn0} rows={rows0}, parity "
+                  f"{_deq_parity(now, *data):.2e} of the largest value"
+                  + (f", PR 36's body {_deq_parity(_deq_parent_call, *data):.2e}"
+                     if parent else ""))
+            for label, patch in _deq_ablations().items():
+                _deq_now(f"{tag} {label}", m, k, n, data, patch)
+            if m % 16 == 0 and k % qmod._SUB_K == 0 and m <= 64:
+                _deq_row(f"{tag} the block-dot kernel", m, k, n, data,
+                         lambda layer, x, p, s: qmod._blockdot_call(
+                             layer, x, p, s, interpret=INTERPRET))
+            first = tiles and m == rows[0]
+            seen = {(tk0, tn0, rows0)}
+            for tk in DEQ_SWEEP_TK if first else ():
+                for tn in DEQ_SWEEP_TN:
+                    for r in DEQ_SWEEP_ROWS:
+                        tk_, tn_ = tk or k, n // -tn if tn < 0 else tn
+                        lo, hi = DEQ_SWEEP_BYTES
+                        if k % tk_ or n % tn_ or tn_ % 128 or not lo <= tk_ * tn_ // 2 <= hi:
+                            continue
+                        r_ = min(r or qmod._deq_pass(tk_, tn_) or tk_, tk_)
+                        if tk_ % r_ or (tk_, tn_, r_) in seen or r_ * tn_ > DEQ_SWEEP_PASS:
+                            continue
+                        seen.add((tk_, tn_, r_))
+                        _deq_now(f"{tag} sweep tk={tk_} tn={tn_} rows={r_}", m, k, n,
+                                 data, tk=tk_, tn=tn_, rows=r_)
+            for part in DEQ_PARENT_PARTS if parent else ():
+                _deq_row(f"{tag} PR 36's body, {part}", m, k, n, data,
+                         functools.partial(_deq_parent_call, part=part))
+            seen = {(512, 512)}
+            for tk in DEQ_PARENT_TK if first and parent else ():
+                for tn in DEQ_PARENT_TN:
+                    tk_ = tk or k
+                    if k % tk_ or n % tn or (tk_, tn) in seen:
+                        continue
+                    seen.add((tk_, tn))
+                    for part in ("as it was", "DMA only"):
+                        _deq_row(f"{tag} PR 36's body, {part}, sweep tk={tk_} tn={tn}",
+                                 m, k, n, data,
+                                 functools.partial(_deq_parent_call, tk=tk_, tn=tn, part=part))
+            del data
+
+
 Q40_SWEEP_TK = (4096, 8192, None)  # None = the whole of k
 Q40_SWEEP_TN = (256, 512, 1024, 2048, -2, -1)  # -d = n / d
 Q40_SWEEP_LANES = (256, 512)
@@ -625,6 +859,7 @@ def main():
     # argv: 'suite [--smoke] [--no-flash]' | 'flash [--smoke]' |
     # 'paged [--smoke]' (the paged decode call of each benchmark cell) |
     # 'q40 [--smoke] [--no-tiles]' (the block-dot kernel at the cells' shapes) |
+    # 'deq [--smoke] [--no-tiles] [--parent]' (the dequantising tier, m > 16) |
     # M SHAPE [variant ...] — suite runs the whole decode + prefill matrix in
     # ONE process (one device init, not six). --no-flash skips the flash
     # section; the q40 rows still land.
@@ -647,6 +882,10 @@ def main():
         return
     if sys.argv[1:2] == ["q40"]:
         bench_q40(tiles="--no-tiles" not in sys.argv)
+        print("KBENCH DONE")
+        return
+    if sys.argv[1:2] == ["deq"]:
+        bench_deq(tiles="--no-tiles" not in sys.argv, parent="--parent" in sys.argv)
         print("KBENCH DONE")
         return
     if sys.argv[1:2] == ["suite"]:

@@ -302,13 +302,17 @@ def test_check_drives_the_engine_end_to_end(tiny):
 # ------------------------------------------- the Q40 kernel's traced call
 
 
-@pytest.mark.parametrize("m,k,n,layers", [(16, 512, 256, 3), (16, 256, 384, 1)])
-def test_blockdot_call_parses_as_the_cost_file_reads_it(m, k, n, layers):
-    """`q40_matmul_roofline` prices every traced `_blockdot_call` from the
-    call's HLO text: a result f32[m, n] and the FIRST u8 operand, the packed
-    array u8[layers, k/2, n] with the same n (`benchmark/costs/q40_matmul.py`;
-    one call that does not parse turns the metric to null). Lowered for the
-    TPU here, no chip and no compile: the custom call's own line."""
+@pytest.mark.parametrize("tier,m,k,n,layers", [
+    ("_blockdot_call", 16, 512, 256, 3), ("_blockdot_call", 16, 256, 384, 1),
+    # `q40_deq_roofline`: 48 slots (an n of 67 x 128, as Granite's in_proj) and a slice
+    ("_deq_call", 48, 256, 8576, 2), ("_deq_call", 256, 1280, 512, 1)])
+def test_q40_call_parses_as_the_cost_file_reads_it(tier, m, k, n, layers):
+    """`q40_matmul_roofline` prices every traced `_blockdot_call`, and
+    `q40_deq_roofline` every `_deq_call`, from the call's HLO text: a result
+    f32[m, n] and the FIRST u8 operand, the packed array u8[layers, k/2, n]
+    with the same n (`benchmark/costs/q40_matmul.py`; one call that does not
+    parse turns the metric to null). Lowered for the TPU here, no chip and no
+    compile: the custom call's own line."""
     import jax
     import jax.numpy as jnp
 
@@ -318,8 +322,9 @@ def test_blockdot_call_parses_as_the_cost_file_reads_it(m, k, n, layers):
     S = jax.ShapeDtypeStruct
     args = (S((1,), jnp.int32), S((m, k), jnp.bfloat16),
             S((layers, k // 2, n), jnp.uint8), S((layers, k // 32, n), jnp.uint16))
-    assert qmod._blockdot_call.__name__ == "_blockdot_call"  # the op's group
-    hlo = jax.jit(lambda *a: qmod._blockdot_call(*a)).trace(*args).lower(
+    call = getattr(qmod, tier)
+    assert call.__name__ == tier  # the op's group
+    hlo = jax.jit(lambda *a: call(*a)).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text(dialect="hlo")
     calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
     assert len(calls) == 1, hlo

@@ -35,6 +35,14 @@ CASES = (
     "q40 prefill m=256 w1(2048x8192)",
     "q40 prefill m=256 w2(8192x2048)",
     "q40 prefill m=256 wcls(2048x128256)",
+    # the dequantising tier (m > 16) at the cells' shapes: another tile each
+    "q40 m=48 granite in_proj(2048x8576)",
+    "q40 m=48 granite out_proj(4096x2048)",
+    "q40 m=48 granite w1(2048x8192)",
+    "q40 m=48 granite w2(8192x2048)",
+    "q40 m=48 granite head(2048x100352)",
+    "q40 m=512 smallthinker wq(2560x3584)",
+    "q40 m=128 deepseek w2(11008x4096)",
     "flash decode t=1 S=2048 hd=64",
     "flash prefill t=256 S=2048 hd=64",
     "paged decode t=1 p=128 hd=64 fused scatter",
@@ -95,14 +103,20 @@ def test_compiles_for_v5e(thunks, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("name,m,k,n", [
-    ("q40 decode m=16 deepseek w2(11008x4096)", 16, 11008, 4096),
-    ("q40 decode m=8 granite head(2048x100352)", 16, 2048, 100352),  # 8 rows ride as 16
+@pytest.mark.parametrize("name,group,m,k,n", [
+    ("q40 decode m=16 deepseek w2(11008x4096)", "_blockdot_call", 16, 11008, 4096),
+    # 8 rows ride as 16
+    ("q40 decode m=8 granite head(2048x100352)", "_blockdot_call", 16, 2048, 100352),
+    # `q40_deq_roofline`: the claimed cell's 48 slots, and a prefill slice
+    ("q40 m=48 granite in_proj(2048x8576)", "_deq_call", 48, 2048, 8576),
+    ("q40 m=48 granite w2(8192x2048)", "_deq_call", 48, 8192, 2048),
+    ("q40 m=512 smallthinker wq(2560x3584)", "_deq_call", 512, 2560, 3584),
 ])
-def test_blockdot_call_is_named_and_shaped_as_the_benchmark_reads_it(thunks, name, m, k, n):
-    """`q40_matmul_roofline` finds the kernel by the device op's group
-    `_blockdot_call` (the compiled instruction's name without its number)
-    and prices it from that instruction's text."""
+def test_q40_call_is_named_and_shaped_as_the_benchmark_reads_it(thunks, name, group, m, k, n):
+    """`q40_matmul_roofline` and `q40_deq_roofline` find their kernel by the
+    device op's group, `_blockdot_call` / `_deq_call` (the compiled
+    instruction's name without its number), and price it from that
+    instruction's text: the real m, k, n, whatever the call lays out inside."""
     import re
 
     from benchmark.costs import q40_matmul as cost
@@ -110,7 +124,7 @@ def test_blockdot_call_is_named_and_shaped_as_the_benchmark_reads_it(thunks, nam
     calls = [line for line in thunks[name]().as_text().splitlines()
              if "tpu_custom_call" in line]
     assert len(calls) == 1
-    assert re.search(r"%(_blockdot_call)(\.\d+)? = ", calls[0]), calls[0][:200]
+    assert re.search(rf"%({group})(\.\d+)? = ", calls[0]), calls[0][:200]
     assert cost.calls({}, {"hlo": calls[0]}) == cost.cost(m, k, n)
 
 

@@ -59,6 +59,22 @@ def test_kbench_q40_smoke():
     assert p.stdout.count("parity") == 2
 
 
+def test_kbench_deq_smoke():
+    """The dequantising tier's own bench (the pricing of every change to it)
+    at a tiny size: parity, the kernel with each part taken out, the
+    block-dot kernel at the same rows, the (tk, tn, rows) sweep, and PR 36's
+    byte-wise body whole, in parts and over its tiles."""
+    p = _run(["experiments/kbench.py", "deq", "--smoke", "--parent"])
+    assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
+    assert "KBENCH DONE" in p.stdout and "FAILED" not in p.stdout, p.stdout
+    for row in ("as it is", "no scale rows", "no scale multiply", "no dequantise",
+                "DMA only", "sweep tk=", "PR 36's body, as it was",
+                "PR 36's body, dot + DMA"):
+        assert p.stdout.count(row) >= 2, (row, p.stdout)  # both weights
+    assert p.stdout.count("the block-dot kernel") == 1  # 48 rows; 24 are no whole tiles
+    assert p.stdout.count("parity") == 2
+
+
 def test_collectives_table_smoke():
     p = _run(["experiments/collectives_table.py", "--smoke"])
     assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"

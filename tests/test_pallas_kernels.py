@@ -167,23 +167,20 @@ def test_rms_norm_pallas_matches_jnp(rng, shape, dtype):
     )
 
 
-@pytest.mark.parametrize("style", ["blockdot", "deq"])
-def test_q40_styles_agree(rng, style):
-    """Both tiers compute the same product at a decode shape (bf16 in, the
-    block-dot kernel's only activation type)."""
+@pytest.mark.parametrize("tier", ["_blockdot_call", "_deq_call"])
+def test_q40_tiers_agree(rng, tier):
+    """Both tiers' jitted calls compute the same product at a decode shape
+    (bf16 in, the block-dot kernel's only activation type; 16 rows, a whole
+    bf16 tile, as the dispatcher pads them)."""
     from dllama_tpu.ops.pallas import q40_matmul as qmod
 
     x = jnp.asarray(rng.standard_normal((3, 512)), jnp.bfloat16)
     w = QTensor.quantize(rng.standard_normal((512, 384)).astype(np.float32) * 0.1)
     want = jnp.dot(x.astype(jnp.float32), w.dequantize(jnp.float32))
-    old = qmod.STYLE
-    try:
-        qmod.STYLE = style
-        got = q40_matmul(x, w, interpret=True)
-    finally:
-        qmod.STYLE = old
-    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
-                               atol=8e-2, rtol=8e-2)
+    got = getattr(qmod, tier)(
+        jnp.zeros((1,), jnp.int32), jnp.pad(x, ((0, 13), (0, 0))), w.packed[None],
+        jax.lax.bitcast_convert_type(w.scales, jnp.uint16)[None], interpret=True)[:3]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=8e-2, rtol=8e-2)
 
 
 # The cells' decode shapes scaled down but keeping what their tiling hangs
@@ -279,6 +276,123 @@ def test_position_is_the_order_the_words_unpack_to(rng):
     np.testing.assert_array_equal(codes[where], dense)
 
 
+# Granite's four stacked shapes (PERF.md section 4) cut to test size with the
+# divisibility their tiling hangs on, and what else the tier must take: a k
+# of whole 128-dim groups that is no whole 256-row step, DeepSeek's 43 x 256.
+_DEQ_SHAPES = {
+    "in_proj: n = 67 x 128": (256, 8576, 2),
+    "out_proj: k = 2 n": (1024, 512, 2),
+    "w1: n = 4 k": (256, 1024, 3),
+    "w2: k = 4 n": (2048, 512, 2),
+    "k = 3 x 128": (384, 256, 1),
+    "k = 43 x 256": (11008, 128, 2),
+}
+
+
+@pytest.fixture(scope="module")
+def deq_weights():
+    """name -> (stacked QTensor, a layer's float32 dequantisation and the same
+    rounded to bf16 a weight, which is what the tier feeds its dot for a bf16
+    x)."""
+    rng = np.random.default_rng(11)
+    out = {}
+    for name, (k, n, layers) in _DEQ_SHAPES.items():
+        packed = rng.integers(0, 256, (layers, k // 2, n), dtype=np.uint8)
+        scales = (rng.random((layers, k // 32, n), np.float32) * 0.02 + 1e-3).astype(np.float16)
+        scales[:, 1::7, 1::3] *= np.float16(-1)
+        w = QTensor(jnp.asarray(packed), jnp.asarray(scales))
+        dense = [QTensor(w.packed[i], w.scales[i]).dequantize(jnp.float32)
+                 for i in range(layers)]
+        out[name] = (w, dense, [d.astype(jnp.bfloat16).astype(jnp.float32) for d in dense])
+    return out
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("m", [24, 48, 64, 256])
+@pytest.mark.parametrize("name", list(_DEQ_SHAPES))
+def test_deq_matches_dequant_dot_at_cell_shapes(deq_weights, name, m, dtype):
+    """The m > 16 tier against the XLA dequantise-then-dot in float32, the
+    layer a TRACED index into the stacked arrays. For a bf16 x it rounds each
+    dequantised weight (q - 8) * s to bf16 once, as the byte-wise body it
+    replaced did (1e-5 of the largest value from that product: the order of
+    the float32 sums; 4e-3 from the unrounded one); a float32 x meets the
+    float32 weights."""
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    if m == 256 and _DEQ_SHAPES[name][0] * _DEQ_SHAPES[name][1] > 1 << 20:
+        m = 128  # the interpreter's minutes, not another path
+    w, dense, rounded = deq_weights[name]
+    k, _, layers = _DEQ_SHAPES[name]
+    x = jnp.asarray(np.random.default_rng(m).standard_normal((m, k)), dtype)
+    pad = jnp.pad(x, ((0, -m % 16), (0, 0)))  # whole tiles of rows, as the dispatcher pads
+    scales = jax.lax.bitcast_convert_type(w.scales, jnp.uint16)
+    # (the weights as arguments, as a step program holds them: closed over,
+    # XLA's CPU constant folder takes the interpreter's byte-to-word bitcast
+    # apart and reads k = 384 wrong)
+    call = jax.jit(lambda layer, packed, scales: qmod._deq_call(
+        layer.reshape(1), pad, packed, scales, interpret=True))
+    for li in {0, layers - 1}:
+        got = np.asarray(call(jnp.int32(li), w.packed, scales))[:m]
+        exact = np.asarray(jnp.dot(x.astype(jnp.float32), dense[li], precision="highest"))
+        top = np.abs(exact).max()
+        if dtype == jnp.bfloat16:
+            same = np.asarray(jnp.dot(x.astype(jnp.float32), rounded[li], precision="highest"))
+            assert np.abs(got - same).max() <= 1e-5 * top
+            assert np.abs(got - exact).max() <= 4e-3 * top
+        else:
+            assert np.abs(got - exact).max() <= 1e-5 * top
+    # and through the public entry, in the activation dtype
+    out = q40_matmul(x, w, jnp.int32(layers - 1), interpret=True)
+    assert out.dtype == dtype and out.shape == exact.shape
+    np.testing.assert_allclose(np.asarray(out, np.float32), exact, atol=1e-2 * top, rtol=0)
+
+
+def test_deq_lays_x_out_again_for_every_m_tile(rng):
+    """Past 512 rows the batch is cut into m tiles; x is laid out at each
+    tile's first grid step (its rows are other rows), over two n tiles and
+    two k tiles here."""
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    m, k, n = 1024, 512, 256
+    w = QTensor.quantize(rng.standard_normal((k, n)).astype(np.float32) * 0.1)
+    x = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    assert qmod._deq_tiles(m, k, n, 4)[0] == 512
+    got = qmod._deq_call(jnp.zeros((1,), jnp.int32), x, w.packed[None],
+                         jax.lax.bitcast_convert_type(w.scales, jnp.uint16)[None],
+                         interpret=True, tk=256, tn=128)
+    want = jnp.dot(x, w.dequantize(jnp.float32), precision="highest")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5)
+
+
+def test_deq_position_is_the_order_the_words_dequantise_to(rng):
+    """`_deq_position` says where an input dim of a 128-dim group lands among
+    the rows `_dequant_words` leaves (the kernel moves x there through the
+    MXU): the dequantised rows, read back through it, are the plain
+    dequantisation, bit for bit in float32."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    k, n = 256, 128
+    packed = jnp.asarray(rng.integers(0, 256, (k // 2, n), dtype=np.uint8))
+    scales = jnp.asarray((rng.random((k // 32, n), np.float32) * 0.02 + 1e-3).astype(np.float16))
+
+    def kern(p_ref, s_ref, o_ref):
+        o_ref[:] = qmod._dequant_words(pltpu.bitcast(p_ref[:], jnp.uint32), s_ref[:],
+                                       jnp.float32)
+
+    # a block's scale under each of its four word rows, as the kernel stores them
+    sb = jnp.repeat(scales.astype(jnp.float32) * qmod._TOP_NIBBLE, 4, axis=0)
+    rows = np.asarray(pl.pallas_call(
+        kern, out_shape=jax.ShapeDtypeStruct((k, n), jnp.float32),
+        interpret=True)(packed, sb))
+    dense = np.asarray(QTensor(packed, scales).dequantize(jnp.float32))
+    src = np.arange(k)
+    where = 128 * (src // 128) + np.asarray(qmod._deq_position(src % 128))
+    assert sorted(where) == list(src)  # a permutation within each group
+    np.testing.assert_array_equal(rows[where], dense)
+
+
 # every Q40 (k, n) the two cells serve at m <= 16 (PERF.md section 4)
 _SERVED = {
     "deepseek wq..wo": (4096, 4096), "deepseek w1/w3": (4096, 11008),
@@ -314,6 +428,92 @@ def test_blockdot_tiles_keep_their_floor(name):
     assert sound(tk, tn) or not could
     if name.startswith("deepseek") or name == "granite head":
         assert sound(tk, tn)  # the shapes the cells' decode steps run
+
+
+# every Q40 (m, k, n) the three cells send the m > 16 tier: Granite's 48
+# slots, prompt slices of 32-512 rows in every cell, the check's prefill
+_DEQ_SERVED = [(m, k, n) for m, shapes in (
+    (48, ["granite in_proj", "granite out_proj", "granite w1/w3", "granite w2",
+          "granite wq/wo", "granite wk/wv", "granite head"]),
+    (64, ["granite in_proj", "granite w2", "deepseek w1/w3", "deepseek w2"]),
+    (128, ["deepseek wq..wo", "deepseek w1/w3", "deepseek w2"]),
+    (512, ["deepseek w2", "granite w1/w3"]),
+) for k, n in (_SERVED[name] for name in shapes)] + [
+    (512, 2560, 3584), (512, 3584, 2560), (256, 2560, 512)]  # SmallThinker's attention
+
+
+@pytest.mark.parametrize("m,k,n", _DEQ_SERVED)
+def test_deq_tiles_keep_their_rules(m, k, n):
+    """Every served shape's tile divides its weight into whole 128-lane
+    columns and whole 256-row steps (or takes k whole), a pass is whole
+    steps of the tile within the pass cap, the step's buffers and the pass's
+    planes fit the budget; and wherever the weight's divisors allow it at
+    all, a grid step moves 256 KB of packed bytes or more and the call is
+    more than one step."""
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    tm, tk, tn, rows = qmod._deq_tiles(m, k, n)
+    assert tm == m  # one m tile up to 512 rows: the weight streams once
+    assert k % tk == 0 and n % tn == 0 and tn % 128 == 0
+    assert tk == k or tk % qmod._SUB_K == 0
+    assert tk % rows == 0 and (rows == tk or rows % qmod._SUB_K == 0)
+    assert rows == qmod._deq_pass(tk, tn) and rows * tn <= qmod._DEQ_PASS_WEIGHTS
+    nb = tk // 32
+    assert (2 * (tk * tn // 2 + nb * tn * 2 + tm * tn * 4) + nb * tn * 4
+            + 10 * rows * tn) <= qmod._DEQ_VMEM
+    sound = lambda tk, tn: (tk * tn // 2 >= qmod._STEP_FLOOR
+                            and (k // tk) * (n // tn) >= 2)
+    could = any(sound(a, b) and qmod._deq_pass(a, b)
+                for a in [k] + list(range(256, k, 256))
+                for b in range(128, n + 1, 128) if k % a == 0 and n % b == 0)
+    assert sound(tk, tn) or not could
+    if m == 48 and k * n >= 1 << 23:
+        assert sound(tk, tn)  # the claimed cell's large shapes
+
+
+def test_deq_tiles_take_the_whole_width_where_a_pass_holds_it():
+    """A tile of the result costs a grid step (its zeroing and write-back):
+    on Granite's 16 M-weight shapes the chooser takes the whole width and a
+    pass's worth of rows, not the whole depth in narrow columns (1.2-1.4 us
+    a call on the chip, PERF.md section 6, PR 37); in_proj's n = 67 x 128
+    has no other tile than whole or 128 lanes."""
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    for k, n in ((2048, 8576), (2048, 8192), (8192, 2048), (4096, 2048)):
+        _, tk, tn, rows = qmod._deq_tiles(48, k, n)
+        assert tn == n and rows == tk and tk * tn <= qmod._DEQ_PASS_WEIGHTS
+        assert 2 * tk * tn > qmod._DEQ_PASS_WEIGHTS or tk == k  # as deep as a pass allows
+
+
+def test_deq_pass_is_whole_steps_within_the_cap():
+    """`_deq_pass`: the whole tile where it is within the cap; else the most
+    whole 256-row steps that divide it; 0 where one step is already over."""
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    cap = qmod._DEQ_PASS_WEIGHTS
+    assert qmod._deq_pass(384, 256) == 384  # a k of 3 x 128: one pass, no steps
+    assert qmod._deq_pass(2048, 1024) == 2048 and 2048 * 1024 <= cap
+    assert qmod._deq_pass(2048, 2048) == 1024
+    assert qmod._deq_pass(11008, 512) == 256 * 1  # 43 x 256: only 256 and 11008 divide
+    assert qmod._deq_pass(1024, 7168) == 256
+    assert qmod._deq_pass(512, 100352) == 0 and 256 * 100352 > cap
+
+
+@pytest.mark.parametrize("m,k,itemsize,want", [
+    (48, 8192, 2, 48), (512, 11008, 2, 512),  # the cells' largest: one tile
+    (1024, 4096, 2, 512), (768, 4096, 2, 256),  # past 512 rows: the largest divisor
+    (512, 28672, 2, 128), (512, 11008, 4, 256),  # x and its copy would not fit
+])
+def test_deq_m_tile_is_the_batch_unless_x_does_not_fit(m, k, itemsize, want):
+    """One m tile streams and dequantises the whole weight once (PR 29 read
+    3 x 16 rows at three times the time of 48); x stays in VMEM for the call,
+    so a tile is cut only where x's block, its second buffer and the laid-out
+    copy would pass their share."""
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    tm = qmod._deq_tiles(m, k, 4096, itemsize)[0]
+    assert tm == want and m % tm == 0
+    assert 3 * tm * k * itemsize <= qmod._DEQ_X_BYTES
 
 
 class TestDispatchKnobs:
