@@ -832,6 +832,8 @@ class BatchEngine:
                          "state cannot be re-entered at a page boundary "
                          "(prefix rows are recomputed)")
                 radix_cache = "off"
+        self._kv_pool = "latent" if cfg.latent else ""  # the launch record's
+        # name for cache rows of a kind of their own (rows READ, by pool)
         self.windowed = cfg.n_window_layers > 0
         self.window = cfg.window if self.windowed else 0  # rows a windowed
         # layer's query sees; 0 = the model has none
@@ -923,7 +925,9 @@ class BatchEngine:
         # release(keep_rows=) when the rows kept end where the state stands,
         # consumed by add_begin(start_pos=)
         self._state_at = np.full(n_slots, -1, np.int64)
-        self._moe_seen = np.zeros(4, np.uint32)  # see _moe_count
+        self._moe_seen = np.zeros(  # see _moe_count
+            0 if self.cache.moe_stats is None else self.cache.moe_stats.shape[0],
+            np.uint32)
         if self.cache.state is not None:
             ins.RECURRENT_STATE_BYTES.set(self.cache.state.nbytes)
         if radix_cache not in ("auto", "on", "off"):
@@ -1209,7 +1213,7 @@ class BatchEngine:
         cannot DMA-walk a narrower pool (pool_lanes has the details)."""
         from dllama_tpu.ops.pallas.paged_attention import pool_lanes
 
-        lanes = (pool_lanes(self.cfg.head_size)
+        lanes = (pool_lanes(self.cfg.cache_row)
                  if self._paged_route.startswith("paged_kernel") else 0)
         return PagedKVCache.create(
             self.cfg, self.n_slots, n_pages, self.page_size,
@@ -2748,7 +2752,7 @@ class BatchEngine:
             kind, self.chunk_seq + 1, n, start_pos, active, advance,
             seq_len=self.seq_len, pool_dry=self._pool_dry(),
             prefill_rows=prefill_rows,
-            window=self.window)
+            window=self.window, kv_pool=self._kv_pool)
 
     def decode_dispatch(self, n: int, spec: bool = False) -> DecodeChunk:
         """Dispatch one fused n-step decode chunk WITHOUT waiting for its
@@ -2884,6 +2888,10 @@ class BatchEngine:
         for fam, d in zip((ins.MOE_ASSIGNMENTS, ins.MOE_EXPERTS_TOUCHED,
                            ins.MOE_LAYER_STEPS, ins.MOE_GROUP_ROWS_MAX), delta):
             fam.inc(int(d))
+        # every routed row, and those this chip's share of the experts
+        # computed (a fifth counter where the model holds a share)
+        ins.MOE_ROWS_ROUTED.inc(int(delta[4] if len(delta) > 4 else delta[0]))
+        ins.MOE_ROWS_HELD.inc(int(delta[0]))
 
     @property
     def supports_hybrid(self) -> bool:
@@ -3189,7 +3197,7 @@ class BatchEngine:
                 chunk.active, total, seq_len=self.seq_len,
                 pool_dry=chunk.launch.pool_dry,
                 frozen=np.where(total == 0, m_cycles, 0),
-                window=self.window).count()
+                window=self.window, kv_pool=self._kv_pool).count()
             if tr.enabled:
                 tr.span_at("decode.spec", chunk.t_disp, tr.now(),
                            cat="decode", track="launches", chunk=chunk.seq,

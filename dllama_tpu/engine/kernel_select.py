@@ -64,9 +64,10 @@ class KernelSelection:
     def route(self) -> str:
         """The mixers' routes: `attn_route` (with '.window' where the model
         has windowed layers and that route clips their walk:
-        'paged_kernel.window'), and behind a '+' each the recurrent state's
+        'paged_kernel.window'; '.latent' where its cache rows are latent),
+        and behind a '+' each the recurrent state's
         decode step and element type where the model has one
-        ('paged_kernel+ssm_step.float32') and the expert layers' route
+        ('paged_kernel+ssm_step.float32', '...+kda_step.float32') and the expert layers' route
         where it has experts ('paged_kernel.window+moe_grouped')."""
         return (self.attn_route
                 + (f"+{self.state_route}" if self.state_route else "")
@@ -100,25 +101,30 @@ class KernelSelection:
 
 def resolve_state_step(cfg: LlamaConfig, batch: int, backend: str,
                        state_dtype=None) -> tuple[Callable | None, str]:
-    """(step, route) of the state-space layers' decode step for a model
-    with recurrent state, (None, '') for any other. The in-place Pallas
-    kernel serves where the quantized matmuls run on Pallas and the kernel
-    takes the state's shape and element type (32-bit); everything else is
-    the jnp step, and the route says so."""
+    """(step, route) of the recurrent layers' decode step for a model with
+    recurrent state (state-space layers: `ssm_step`; delta-rule layers:
+    `kda_step`), (None, '') for any other. The in-place Pallas kernel serves
+    where the quantized matmuls run on Pallas and the kernel takes the
+    state's shape and element type (32-bit); everything else is the jnp
+    step, and the route says so."""
     if not cfg.recurrent:
         return None, ""
     import jax.numpy as jnp
 
     from dllama_tpu.ops.matmul import device_platform, resolve_backend
-    from dllama_tpu.ops.pallas.ssm_step import ssm_step, supported
 
+    if cfg.n_kda_layers:
+        from dllama_tpu.ops.pallas.kda_step import kda_step as step, supported
+        name = "kda"
+    else:
+        from dllama_tpu.ops.pallas.ssm_step import ssm_step as step, supported
+        name = "ssm"
     dtype = jnp.dtype(jnp.float32 if state_dtype is None else state_dtype)
-    shape = (cfg.n_ssm_layers, batch, cfg.ssm_heads, cfg.ssm_head_dim,
-             cfg.ssm_state)
+    shape = (cfg.n_state_layers, batch, *cfg.state_shape)
     if resolve_backend(backend) == "pallas" and supported(shape, dtype):
-        return (partial(ssm_step, interpret=device_platform() != "tpu"),
-                f"ssm_step.{dtype.name}")
-    return None, f"ssm_jnp.{dtype.name}"
+        return (partial(step, interpret=device_platform() != "tpu"),
+                f"{name}_step.{dtype.name}")
+    return None, f"{name}_jnp.{dtype.name}"
 
 
 def resolve_moe_impl(moe_impl: str, shardings, cfg: LlamaConfig, params,
@@ -239,17 +245,18 @@ def resolve_kernels(
         route = "paged_gather"
         fused_cap = None
         if attn_impl != "jnp" and paged_decode_supported(
-            (cfg.n_heads, cfg.head_size), page_size,
+            (cfg.n_heads, cfg.cache_row), page_size,
             kv_dtype=cache_dtype if cache_dtype is not None else jnp.bfloat16,
         ) and (attn_impl == "flash" or on_tpu):
             def attn_fn(q, k_pool, v_pool, tables, pos, new_k, new_v, active,
-                        layer, **window):
+                        layer, **kind):
                 # the pools are the whole layer-stacked arrays: the kernel
                 # indexes `layer` (models/llama.run_layers carries them); a
-                # windowed layer brings window=W and the sweep clips its walk
+                # windowed layer brings window=W and the sweep clips its
+                # walk, a latent layer latent=rank and its score scale
                 return paged_decode_attention(
                     q, k_pool, v_pool, tables, pos, new_k, new_v, active,
-                    layer=layer, interpret=not on_tpu, **window)
+                    layer=layer, interpret=not on_tpu, **kind)
 
             # models/llama._layer hands the new KV rows to the kernel
             # instead of paying a separate scatter dispatch per layer; the
@@ -262,6 +269,8 @@ def resolve_kernels(
             fused_cap = FUSED_SCATTER_MAX_T
         if windowed:
             route += ".window"  # both paged routes take the window
+        if cfg.latent:
+            route += ".latent"  # and the latent row (one pool, read once)
         return KernelSelection(mm=mm, mm_in=mm_in, attn_fn=attn_fn,
                                backend=backend, attn_route=route,
                                interpret=not on_tpu,
@@ -278,6 +287,9 @@ def resolve_kernels(
         # the dense layouts' flash kernel takes no window yet: the jnp
         # attention masks it (the serving path is the paged kernel above)
         route = "jnp.window"
+    elif cfg.latent:
+        # nor a latent row (models/llama._mla_mixer's jnp attention)
+        route = "jnp.latent"
     elif attn_fn is None and attn_impl != "jnp":
         from dllama_tpu.ops.pallas.flash_attention import flash_gqa_attention, supported
 

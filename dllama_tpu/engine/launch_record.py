@@ -74,6 +74,9 @@ class LaunchRecord:
     kv_rows_window: int | None = None  # a model with windowed layers: the
     # rows a windowed layer's decode steps READ, min(position + 1, window) a
     # slot-step (kv_rows is what a layer that sees everything reads)
+    kv_pool: str = ""  # a model whose cache rows are of a kind of their own
+    # ("latent": one shared row a token): kv_rows is also counted as rows
+    # READ in that pool
 
     def args(self) -> dict:
         """The span / annotation arguments (`kind` is in the name too)."""
@@ -101,6 +104,9 @@ class LaunchRecord:
             read = ins.LAUNCH_KV_ROWS_READ
             read.labels(kind=self.kind, pool="global").inc(self.kv_rows)
             read.labels(kind=self.kind, pool="window").inc(self.kv_rows_window)
+        elif self.kv_pool and self.kv_rows:
+            ins.LAUNCH_KV_ROWS_READ.labels(
+                kind=self.kind, pool=self.kv_pool).inc(self.kv_rows)
         return self
 
     def annotation(self):
@@ -116,7 +122,8 @@ class LaunchRecord:
 def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
           active: np.ndarray, advance: np.ndarray, *, seq_len: int,
           pool_dry: bool, prefill_rows: int = 0,
-          frozen: np.ndarray | None = None, window: int = 0) -> LaunchRecord:
+          frozen: np.ndarray | None = None, window: int = 0,
+          kv_pool: str = "") -> LaunchRecord:
     """The record of a launch of `n` steps over slots at `start_pos`, of
     which the `active` ones advance `advance` rows each.
 
@@ -149,4 +156,4 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
         empty=(active.size - n_active) * int(n),
         kv_rows=int((adv * pos + adv * (adv + 1) // 2).sum()),
         prefill_rows=int(prefill_rows), pool_dry=bool(pool_dry),
-        kv_rows_window=kv_rows_window)
+        kv_rows_window=kv_rows_window, kv_pool=kv_pool)

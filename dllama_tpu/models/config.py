@@ -28,6 +28,14 @@ class ArchType(IntEnum):
 class LayerKind(IntEnum):
     ATTENTION = 0
     SSM = 1
+    KDA = 2  # gated delta-rule linear attention, a decay per key channel:
+    # a [key, value] matrix state a head (ops/delta.py)
+    MLA = 3  # latent attention: one shared low-rank row a token in the cache
+
+
+#: the kinds whose layers hold per-sequence recurrent state (at most one of
+#: them in a model) and those that hold cache rows (likewise)
+STATE_KINDS = (LayerKind.SSM, LayerKind.KDA)
 
 
 class HiddenAct(IntEnum):
@@ -88,6 +96,28 @@ class HeaderKey(IntEnum):
     # HEAD_SIZE present in a LLAMA file, n_heads * head size need not be dim)
     WINDOW_SIZE = 120  # rows a windowed layer's query sees, itself included
     ROUTER_INPUT = 121  # 1 = the router reads the ATTENTION norm's output
+    # ---- dllama-tpu extensions for LayerKind.KDA layers
+    KDA_HEADS = 130
+    KDA_HEAD_DIM = 131  # key and value size of a head
+    KDA_CONV = 132  # conv taps over q, k and v
+    KDA_RANK = 133  # inner size of the decay's and the output gate's
+    # two-matrix projections
+    # ---- dllama-tpu extensions for LayerKind.MLA layers (no q-side low
+    # rank; the "rope" dims ride unrotated: ROPE_TYPE must be NONE)
+    MLA_KV_RANK = 140  # the latent c a token leaves in the cache
+    MLA_NOPE_DIM = 141  # a head's key dims that come out of the latent
+    MLA_PE_DIM = 142  # a head's key dims shared by all heads, beside c
+    MLA_V_DIM = 143
+    # ---- dllama-tpu extensions for the expert layers (absent = softmax
+    # over the top k, no shared expert, every expert held, expert width =
+    # HIDDEN_DIM)
+    ROUTER_KIND = 150  # 1 = sigmoid scores, top k of score + bias,
+    # weights renormalised over the chosen scores
+    ROUTED_SCALE_X1E6 = 151  # the routed sum is multiplied by it
+    N_SHARED_EXPERTS = 152  # always-on experts, one SwiGLU of their width
+    EXPERTS_HELD = 153  # this file holds experts [offset, offset + held)
+    EXPERT_OFFSET = 154
+    MOE_HIDDEN_DIM = 155  # an expert's width where dense layers differ
 
 
 #: the kind of layer i is header key LAYER_KIND_BASE + i (one key a layer,
@@ -98,7 +128,11 @@ LAYER_KIND_BASE = 1000
 #: layer rotates as ROPE_TYPE says)
 LAYER_WINDOW_BASE = 2000
 LAYER_ROPE_BASE = 3000
-_LAYER_LISTS_END = 4000
+#: layer i of a file with experts has a DENSE feed-forward block (width
+#: HIDDEN_DIM) iff key LAYER_FFN_BASE + i is 1 (absent list: every layer of
+#: such a file is an expert layer)
+LAYER_FFN_BASE = 4000
+_LAYER_LISTS_END = 5000
 
 #: `LlamaConfig.schedule_kinds` entries: a LayerKind in the low two bits, and
 #: beside it whether the layer is windowed and whether it leaves q and k
@@ -106,6 +140,7 @@ _LAYER_LISTS_END = 4000
 SCHEDULE_KIND_MASK = 3
 SCHEDULE_WINDOWED = 4
 SCHEDULE_UNROTATED = 8
+SCHEDULE_DENSE_FFN = 16  # a dense feed-forward block in a model with experts
 
 
 @dataclasses.dataclass
@@ -151,6 +186,26 @@ class LlamaConfig:
     layer_ropes: tuple = ()  # 0/1 per layer; () = every layer rotates
     router_pre_attention: bool = False  # the router reads the attention
     # block's normed input, not the feed-forward block's
+    # ---- delta-rule linear-attention layers (LayerKind.KDA)
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_rank: int = 0
+    # ---- latent attention layers (LayerKind.MLA)
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_pe_dim: int = 0
+    v_head_dim: int = 0
+    # ---- expert layers (defaults: softmax over the top k, all held)
+    router_sigmoid: bool = False
+    routed_scale: float = 1.0
+    n_shared_experts: int = 0
+    experts_held: int = 0  # 0 = all n_experts; else experts
+    # [expert_offset, expert_offset + experts_held) are in the file: one
+    # chip's share of a layer, routed over all n_experts
+    expert_offset: int = 0
+    moe_hidden_dim: int = 0  # 0 = hidden_dim
+    layer_ffn: tuple = ()  # 0/1 per layer, 1 = dense; () = none is dense
 
     def __post_init__(self):
         if self.orig_seq_len == 0:
@@ -161,16 +216,42 @@ class LlamaConfig:
                 f"{len(self.layer_kinds)} layer kinds for {self.n_layers} layers")
         self.layer_windows = tuple(int(bool(w)) for w in self.layer_windows)
         self.layer_ropes = tuple(int(bool(r)) for r in self.layer_ropes)
+        self.layer_ffn = tuple(int(bool(f)) for f in self.layer_ffn)
         for name, flags in (("window", self.layer_windows),
-                            ("rope", self.layer_ropes)):
+                            ("rope", self.layer_ropes),
+                            ("feed-forward", self.layer_ffn)):
             if flags and len(flags) != self.n_layers:
                 raise ValueError(
                     f"{len(flags)} {name} flags for {self.n_layers} layers")
         if any(self.layer_windows) and self.window <= 0:
             raise ValueError("windowed layers need a window size")
-        if any(w and k == LayerKind.SSM for w, k in
+        if any(w and k != LayerKind.ATTENTION for w, k in
                zip(self.layer_windows, self.layer_kinds)):
-            raise ValueError("a state-space layer cannot be windowed")
+            raise ValueError("only a softmax attention layer can be windowed")
+        kinds = set(self.layer_kinds)
+        if len(kinds & set(STATE_KINDS)) > 1:
+            raise ValueError("state-space and delta-rule layers in one model "
+                             "are not supported (one recurrent state a slot)")
+        if {int(LayerKind.ATTENTION), int(LayerKind.MLA)} <= kinds:
+            raise ValueError("softmax and latent attention layers in one "
+                             "model are not supported (one row width a cache)")
+        if LayerKind.MLA in kinds and (
+                self.rope_type != RopeType.NONE or not self.kv_lora_rank):
+            raise ValueError("latent attention layers need MLA_KV_RANK and "
+                             "ROPE_TYPE none (their shared key dims ride "
+                             "unrotated; rotated latent attention is not "
+                             "supported)")
+        if LayerKind.KDA in kinds and not (self.kda_heads and self.kda_rank):
+            raise ValueError("delta-rule layers need KDA_HEADS and KDA_RANK")
+        if any(self.layer_ffn) and not self.n_experts:
+            raise ValueError("per-layer feed-forward kinds are for a model "
+                             "with experts")
+        if self.experts_held and not (
+                0 <= self.expert_offset
+                and self.expert_offset + self.experts_held <= self.n_experts):
+            raise ValueError(
+                f"experts [{self.expert_offset}, {self.expert_offset} + "
+                f"{self.experts_held}) are not among {self.n_experts}")
         if self.n_ssm_layers and self.ssm_groups != 1:
             raise ValueError("state-space layers with more than one B/C group "
                              "are not supported")
@@ -205,15 +286,17 @@ class LlamaConfig:
     @property
     def schedule_kinds(self) -> tuple:
         """What `models/llama.layer_schedule` groups by: a layer's kind, and
-        beside it SCHEDULE_WINDOWED and SCHEDULE_UNROTATED. () for a
-        homogeneous stack."""
-        if not (self.layer_kinds or self.layer_windows or self.layer_ropes):
+        beside it SCHEDULE_WINDOWED, SCHEDULE_UNROTATED and
+        SCHEDULE_DENSE_FFN. () for a homogeneous stack."""
+        if not (self.layer_kinds or self.layer_windows or self.layer_ropes
+                or self.layer_ffn):
             return ()
         kinds = self.layer_kinds or (int(LayerKind.ATTENTION),) * self.n_layers
         return tuple(
             int(k) + (SCHEDULE_WINDOWED if self.layer_window(i) else 0)
             + (SCHEDULE_UNROTATED if k == LayerKind.ATTENTION
                and self.layer_ropes and not self.layer_ropes[i] else 0)
+            + (SCHEDULE_DENSE_FFN if self.layer_ffn and self.layer_ffn[i] else 0)
             for i, k in enumerate(kinds))
 
     @property
@@ -221,14 +304,83 @@ class LlamaConfig:
         return sum(1 for k in self.layer_kinds if k == LayerKind.SSM)
 
     @property
+    def n_kda_layers(self) -> int:
+        return sum(1 for k in self.layer_kinds if k == LayerKind.KDA)
+
+    @property
+    def n_state_layers(self) -> int:
+        """Layers that hold recurrent state: the state's layer axis."""
+        return self.n_ssm_layers + self.n_kda_layers
+
+    @property
     def n_attn_layers(self) -> int:
         """Layers that hold KV rows: what a cache's layer axis is sized by."""
-        return self.n_layers - self.n_ssm_layers
+        return self.n_layers - self.n_state_layers
 
     @property
     def recurrent(self) -> bool:
         """The model carries per-sequence state that cannot be rewound."""
-        return self.n_ssm_layers > 0
+        return self.n_state_layers > 0
+
+    @property
+    def state_shape(self) -> tuple:
+        """(heads, rows, lanes) of one slot's state in one layer: a
+        state-space head's [P, N], a delta-rule head's [key, value]."""
+        if self.n_kda_layers:
+            return (self.kda_heads, self.kda_head_dim, self.kda_head_dim)
+        return (self.ssm_heads, self.ssm_head_dim, self.ssm_state)
+
+    @property
+    def state_conv(self) -> tuple:
+        """(rows, channels) of one slot's conv window in one layer."""
+        if self.n_kda_layers:
+            return (self.kda_conv - 1, 3 * self.kda_inner)
+        return (self.ssm_conv - 1, self.ssm_conv_dim)
+
+    @property
+    def kda_inner(self) -> int:
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def kda_proj(self) -> int:
+        """Output columns of a delta-rule layer's input projection as the
+        file holds it: q | k | v | decay's inner | gate's inner | beta."""
+        return 3 * self.kda_inner + 2 * self.kda_rank + self.kda_heads
+
+    @property
+    def latent(self) -> bool:
+        """The cache row is one latent shared by all query heads."""
+        return any(k == LayerKind.MLA for k in self.layer_kinds)
+
+    @property
+    def cache_kv_heads(self) -> int:
+        return 1 if self.latent else self.n_kv_heads
+
+    @property
+    def cache_row(self) -> int:
+        """Width of a cache row: a kv head's size, or the latent and the
+        shared key dims side by side."""
+        return self.kv_lora_rank + self.qk_pe_dim if self.latent else self.head_size
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_hidden_dim or self.hidden_dim
+
+    @property
+    def n_held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def n_dense_ffn_layers(self) -> int:
+        return sum(self.layer_ffn) if self.n_experts else self.n_layers
+
+    def ffn_index(self, layer: int) -> int:
+        """Layer `layer`'s index into the stack of ITS feed-forward kind's
+        weights (dense layers and expert layers are stacked apart)."""
+        if not self.layer_ffn:
+            return layer
+        mine = self.layer_ffn[layer]
+        return sum(1 for f in self.layer_ffn[:layer] if f == mine)
 
     @property
     def ssm_inner(self) -> int:
@@ -266,10 +418,18 @@ class LlamaConfig:
             f"act={self.hidden_act.name} rope={self.rope_type.name} "
             f"weights={self.weight_type.name}"
             + (f" experts={self.n_experts}/{self.n_active_experts}" if self.n_experts else "")
+            + (f" held={self.expert_offset}+{self.experts_held}"
+               if self.experts_held else "")
+            + (f" shared={self.n_shared_experts}" if self.n_shared_experts else "")
+            + (f" dense_ffn={sum(self.layer_ffn)}" if any(self.layer_ffn) else "")
             + (f" window={self.window}x{self.n_window_layers}" if self.n_window_layers else "")
             + (f" ssm_layers={self.n_ssm_layers}/{self.n_layers} "
                f"ssm={self.ssm_heads}x{self.ssm_head_dim}x{self.ssm_state}"
-               if self.recurrent else "")
+               if self.n_ssm_layers else "")
+            + (f" kda_layers={self.n_kda_layers}/{self.n_layers} "
+               f"kda={self.kda_heads}x{self.kda_head_dim}x{self.kda_head_dim}"
+               if self.n_kda_layers else "")
+            + (f" latent={self.kv_lora_rank}+{self.qk_pe_dim}" if self.latent else "")
         )
 
     def clamp_seq_len(self, max_seq_len: int | None) -> "LlamaConfig":
@@ -318,6 +478,16 @@ class LlamaConfig:
             kv += [(LAYER_KIND_BASE + i, k) for i, k in enumerate(self.layer_kinds)]
         elif self.head_dim:
             kv.append((HeaderKey.HEAD_SIZE, self.head_size))
+        if self.layer_kinds and self.arch != ArchType.HYBRID_SSM:
+            kv += [(LAYER_KIND_BASE + i, k) for i, k in enumerate(self.layer_kinds)]
+        kv += [(key, getattr(self, name)) for key, name in _EXTRA_INT_KEYS.items()
+               if getattr(self, name) != _FIELD_DEFAULTS[name]]
+        if self.router_sigmoid:
+            kv.append((HeaderKey.ROUTER_KIND, 1))
+        if self.routed_scale != 1.0:
+            kv.append((HeaderKey.ROUTED_SCALE_X1E6,
+                       int(round(self.routed_scale * 1e6))))
+        kv += [(LAYER_FFN_BASE + i, f) for i, f in enumerate(self.layer_ffn)]
         if self.router_pre_attention:
             kv.append((HeaderKey.ROUTER_INPUT, 1))
         if self.window:
@@ -332,9 +502,13 @@ class LlamaConfig:
         kinds: dict = {}
         windows: dict = {}
         ropes: dict = {}
+        ffns: dict = {}
         for key, value in kv:
             if key >= _LAYER_LISTS_END:
                 raise ValueError(f"unknown header key {key}")
+            if key >= LAYER_FFN_BASE:
+                ffns[key - LAYER_FFN_BASE] = value
+                continue
             if key >= LAYER_ROPE_BASE:
                 ropes[key - LAYER_ROPE_BASE] = value
                 continue
@@ -397,7 +571,14 @@ class LlamaConfig:
                 vals["window"] = value
             elif key == HeaderKey.ROUTER_INPUT:
                 vals["router_pre_attention"] = bool(value)
-        for name, flags in (("layer_windows", windows), ("layer_ropes", ropes)):
+            elif key in _EXTRA_INT_KEYS:
+                vals[_EXTRA_INT_KEYS[key]] = value
+            elif key == HeaderKey.ROUTER_KIND:
+                vals["router_sigmoid"] = bool(value)
+            elif key == HeaderKey.ROUTED_SCALE_X1E6:
+                vals["routed_scale"] = value / 1e6
+        for name, flags in (("layer_windows", windows), ("layer_ropes", ropes),
+                            ("layer_ffn", ffns)):
             if flags:
                 n = vals.get("n_layers", 0)
                 if sorted(flags) != list(range(n)):
@@ -417,6 +598,19 @@ _X1E6_KEYS = {HeaderKey.ATTN_SCALE_X1E6: "attn_scale",
               HeaderKey.EMBEDDING_MULT_X1E6: "embedding_multiplier",
               HeaderKey.RESIDUAL_MULT_X1E6: "residual_multiplier",
               HeaderKey.LOGITS_DIV_X1E6: "logits_scaling"}
+_EXTRA_INT_KEYS = {HeaderKey.KDA_HEADS: "kda_heads",
+                   HeaderKey.KDA_HEAD_DIM: "kda_head_dim",
+                   HeaderKey.KDA_CONV: "kda_conv",
+                   HeaderKey.KDA_RANK: "kda_rank",
+                   HeaderKey.MLA_KV_RANK: "kv_lora_rank",
+                   HeaderKey.MLA_NOPE_DIM: "qk_nope_dim",
+                   HeaderKey.MLA_PE_DIM: "qk_pe_dim",
+                   HeaderKey.MLA_V_DIM: "v_head_dim",
+                   HeaderKey.N_SHARED_EXPERTS: "n_shared_experts",
+                   HeaderKey.EXPERTS_HELD: "experts_held",
+                   HeaderKey.EXPERT_OFFSET: "expert_offset",
+                   HeaderKey.MOE_HIDDEN_DIM: "moe_hidden_dim"}
+_FIELD_DEFAULTS = {f.name: f.default for f in dataclasses.fields(LlamaConfig)}
 _SSM_INT_KEYS = {HeaderKey.SSM_HEADS: "ssm_heads",
                  HeaderKey.SSM_HEAD_DIM: "ssm_head_dim",
                  HeaderKey.SSM_STATE: "ssm_state",

@@ -13,7 +13,20 @@ order (llm.cpp:453-468):
              (a state-space layer of an ArchType.HYBRID_SSM file holds, in
              place of q/k/v/wo: in_proj [2*inner + 2*state + heads, dim],
              conv_w f32 [inner + 2*state, taps], conv_b, dt_bias [heads],
-             a_log [heads], d [heads], ssm_norm [inner], out_proj [dim, inner])
+             a_log [heads], d [heads], ssm_norm [inner], out_proj [dim, inner];
+             a delta-rule layer (LayerKind.KDA): kda_proj [3*inner + 2*rank +
+             heads, dim] (q | k | v | decay's inner | gate's inner | beta),
+             kda_conv_w f32 [3*inner, taps], kda_fb / kda_gb [inner, rank],
+             kda_dt_bias f32 [inner], kda_a_log f32 [heads], kda_norm f32
+             [head], kda_o [dim, inner]; a latent attention layer
+             (LayerKind.MLA): mla_q [heads*(nope+pe), dim], mla_kva [rank+pe,
+             dim], mla_kv_norm f32 [rank], mla_kvb [heads*(nope+v), rank],
+             mla_o [dim, heads*v]. An expert layer holds moe_gate f32
+             [experts, dim] (every column, whatever the file holds of the
+             experts), moe_bias f32 [experts] under a sigmoid router, the
+             held experts' stacks at the expert width, and shared_w1/w2/w3
+             where the header counts shared experts; a layer the header
+             marks dense holds w1/w2/w3 at HIDDEN_DIM)
   final_rms_norm f32 [dim]
   wcls [vocab, dim]                                            (weight_type)
 
@@ -117,6 +130,28 @@ def tensor_plan(config: LlamaConfig) -> list[tuple[str, tuple[int, int] | tuple[
                 (f"layers.{layer}.ssm_norm", (config.ssm_inner,), f32),
                 (f"layers.{layer}.out_proj", (config.dim, config.ssm_inner), wt),
             ]
+        elif kind == LayerKind.KDA:
+            f32, p = FloatType.F32, f"layers.{layer}."
+            inner, rank = config.kda_inner, config.kda_rank
+            plan += [
+                (p + "kda_proj", (config.kda_proj, config.dim), wt),
+                (p + "kda_conv_w", (3 * inner, config.kda_conv), f32),
+                (p + "kda_fb", (inner, rank), wt),
+                (p + "kda_gb", (inner, rank), wt),
+                (p + "kda_dt_bias", (inner,), f32),
+                (p + "kda_a_log", (config.kda_heads,), f32),
+                (p + "kda_norm", (config.kda_head_dim,), f32),
+                (p + "kda_o", (config.dim, inner), wt),
+            ]
+        elif kind == LayerKind.MLA:
+            p, h, r = f"layers.{layer}.", config.n_heads, config.kv_lora_rank
+            plan += [
+                (p + "mla_q", (h * (config.qk_nope_dim + config.qk_pe_dim), config.dim), wt),
+                (p + "mla_kva", (r + config.qk_pe_dim, config.dim), wt),
+                (p + "mla_kv_norm", (r,), FloatType.F32),
+                (p + "mla_kvb", (h * (config.qk_nope_dim + config.v_head_dim), r), wt),
+                (p + "mla_o", (config.dim, h * config.v_head_dim), wt),
+            ]
         else:
             plan += [
                 (f"layers.{layer}.wq", (config.attn_dim, config.dim), wt),
@@ -124,21 +159,30 @@ def tensor_plan(config: LlamaConfig) -> list[tuple[str, tuple[int, int] | tuple[
                 (f"layers.{layer}.wv", (config.kv_dim, config.dim), wt),
                 (f"layers.{layer}.wo", (config.dim, config.attn_dim), wt),
             ]
-        if config.n_experts:
+        if config.n_experts and not (config.layer_ffn and config.layer_ffn[layer]):
             # MoE extension: the reference header carries N_EXPERTS
             # (llm.hpp:17-18) and its HF converter emits expert tensors
             # (convert-hf.py:66-73), but its runtime never reads them; this
             # is the layout our converter writes — router gate then
             # expert-stacked w1/w2/w3 blobs.
+            held, width = config.n_held_experts, config.expert_width
+            plan.append((f"layers.{layer}.moe_gate", (config.n_experts, config.dim),
+                         FloatType.F32))
+            if config.router_sigmoid:
+                plan.append((f"layers.{layer}.moe_bias", (config.n_experts,),
+                             FloatType.F32))
             plan += [
-                (f"layers.{layer}.moe_gate", (config.n_experts, config.dim), FloatType.F32),
-                (f"layers.{layer}.moe_w1",
-                 (config.n_experts, config.hidden_dim, config.dim), wt),
-                (f"layers.{layer}.moe_w2",
-                 (config.n_experts, config.dim, config.hidden_dim), wt),
-                (f"layers.{layer}.moe_w3",
-                 (config.n_experts, config.hidden_dim, config.dim), wt),
+                (f"layers.{layer}.moe_w1", (held, width, config.dim), wt),
+                (f"layers.{layer}.moe_w2", (held, config.dim, width), wt),
+                (f"layers.{layer}.moe_w3", (held, width, config.dim), wt),
             ]
+            if config.n_shared_experts:
+                sw = config.n_shared_experts * width
+                plan += [
+                    (f"layers.{layer}.shared_w1", (sw, config.dim), wt),
+                    (f"layers.{layer}.shared_w2", (config.dim, sw), wt),
+                    (f"layers.{layer}.shared_w3", (sw, config.dim), wt),
+                ]
         else:
             plan += [
                 (f"layers.{layer}.w1", (config.hidden_dim, config.dim), wt),
@@ -370,7 +414,12 @@ def _load_matmul(raw: np.ndarray, shape: tuple[int, int], ft: FloatType, dtype, 
 #: a layer's small float32 tensors, loaded as they lie: the norms and the
 #: state-space mixer's conv, step and skip parameters
 _F32_LEAVES = ("rms_att", "rms_ffn", "conv_w", "conv_b", "dt_bias", "a_log",
-               "d", "ssm_norm")
+               "d", "ssm_norm", "kda_conv_w", "kda_dt_bias", "kda_a_log",
+               "kda_norm", "mla_kv_norm", "moe_bias")
+#: matmul weights whose published output width is not whole lane tiles: zero
+#: columns are added on the way to the device, the file keeps the width
+#: (kda_proj to whole 512s so that the wide tiles divide it)
+_PADDED_COLUMNS = {"in_proj": 128, "mla_kva": 128, "kda_proj": 512}
 
 
 def _pad_columns(w, multiple: int):
@@ -444,13 +493,22 @@ def load_params(
             _, _, short = name.split(".")
             if short in _F32_LEAVES:
                 leaf = decode_dense(raw, shape, ft)
-            elif short == "in_proj":
+            elif short in _PADDED_COLUMNS:
                 # 2*inner + 2*state + heads columns are not whole 128-lane
                 # tiles (8,512 at the published widths): zero columns are
-                # added after the dt block HERE, on the way to the device;
+                # added after the last block HERE, on the way to the device;
                 # the file keeps the published width
                 leaf = _pad_columns(_load_matmul(raw, shape, ft, dtype, dequantize,
-                                                 q80_packed=q80_packed), 128)
+                                                 q80_packed=q80_packed),
+                                    _PADDED_COLUMNS[short])
+            elif short == "mla_kvb":
+                # [heads * (nope + v), rank] -> f32 [heads, nope + v, rank]:
+                # the absorbed form multiplies q by the key half's TRANSPOSE
+                # (a contraction over the file's output rows, which the Q40
+                # block layout does not serve), so the 8 M weights a layer
+                # are held as the float32 values the Q40 blocks decode to
+                leaf = decode_dense(raw, shape, ft).reshape(
+                    config.n_heads, -1, shape[1]).astype(np.float32, order="C")
             elif short == "moe_gate":
                 # router stays f32; file [E, dim] -> h@gate operand [dim, E]
                 leaf = decode_dense(raw, shape, ft).T.astype(np.float32, order="C")

@@ -18,19 +18,23 @@ import jax
 import jax.numpy as jnp
 
 from dllama_tpu.models.config import (
+    SCHEDULE_DENSE_FFN,
     SCHEDULE_KIND_MASK,
     SCHEDULE_UNROTATED,
     SCHEDULE_WINDOWED,
+    STATE_KINDS,
     LayerKind,
     LlamaConfig,
     RopeType,
 )
-from dllama_tpu.ops import ssm
+from dllama_tpu.ops import delta, ssm
 from dllama_tpu.ops.layers import (
     activation,
     apply_rope,
     gqa_attention,
+    latent_attention,
     moe_ffn,
+    paged_view,
     rms_norm,
     router_logits,
 )
@@ -40,7 +44,10 @@ from dllama_tpu.ops.matmul import matmul
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class RecurrentState:
-    """The state-space layers' per-sequence state, beside the KV cache:
+    """The recurrent layers' per-sequence state, beside the KV cache (a
+    state-space head's [P, N] running sum, or a delta-rule head's [key,
+    value] matrix, `LlamaConfig.state_shape`; the conv's channels are x|B|C
+    or q|k|v, `state_conv`):
 
       s    [Ls, B, H, P, N]   the recurrence's running sum, float32 unless
                               constructed otherwise (it sums over the whole
@@ -80,10 +87,10 @@ class RecurrentState:
     @classmethod
     def create(cls, cfg: LlamaConfig, batch: int, dtype=jnp.float32,
                conv_dtype=jnp.bfloat16, step=None):
-        ls = cfg.n_ssm_layers
+        ls = cfg.n_state_layers
         return cls(
-            jnp.zeros((ls, batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), dtype),
-            jnp.zeros((ls, batch, cfg.ssm_conv - 1, cfg.ssm_conv_dim), conv_dtype),
+            jnp.zeros((ls, batch, *cfg.state_shape), dtype),
+            jnp.zeros((ls, batch, *cfg.state_conv), conv_dtype),
             step=step)
 
     @property
@@ -120,7 +127,17 @@ class RecurrentState:
 
 
 def _moe_stats0(cfg: LlamaConfig):
-    return jnp.zeros((4,), jnp.uint32) if cfg.n_experts else None
+    """The expert counters (ops/layers.moe_ffn): four, and a fifth (every
+    routed row) where the model holds a share of its experts."""
+    if not cfg.n_experts:
+        return None
+    return jnp.zeros((5 if cfg.experts_held else 4,), jnp.uint32)
+
+
+def _v_placeholder(cfg: LlamaConfig, lead: tuple, dtype):
+    """A latent cache has no v rows (the row is key and value): `v` is a
+    placeholder of the cache's rank that nothing reads or writes."""
+    return jnp.zeros((cfg.n_attn_layers, *lead, 1, 8, 128), dtype)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -154,12 +171,14 @@ class KVCache:
                state_step=None):
         """The layer axis counts the layers that hold KV rows
         (`cfg.n_attn_layers`: all of them, but for a hybrid stack)."""
-        shape = (cfg.n_attn_layers, batch, cfg.n_kv_heads, seq_len or cfg.seq_len, cfg.head_size)
+        shape = (cfg.n_attn_layers, batch, cfg.cache_kv_heads,
+                 seq_len or cfg.seq_len, cfg.cache_row)
         state = (RecurrentState.create(cfg, batch, state_dtype, conv_dtype,
                                        state_step)
                  if cfg.recurrent else None)
-        return cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), state,
-                   _moe_stats0(cfg))
+        v = (_v_placeholder(cfg, (batch,), dtype) if cfg.latent
+             else jnp.zeros(shape, dtype))
+        return cls(jnp.zeros(shape, dtype), v, state, _moe_stats0(cfg))
 
     @property
     def seq_len(self) -> int:
@@ -239,7 +258,7 @@ class PagedKVCache:
         windowed layers, those that are not, and ``window_pages`` sizes the
         windowed layers' own pool."""
         lw = cfg.n_window_layers
-        row = (cfg.n_kv_heads, page_size, lanes or cfg.head_size)
+        row = (cfg.cache_kv_heads, page_size, lanes or cfg.cache_row)
         shape = (cfg.n_attn_layers - lw, n_pages + 1, *row)
         tables = jnp.zeros((n_slots, max_blocks or 1), jnp.int32)
         state = (RecurrentState.create(cfg, n_slots, state_dtype, conv_dtype,
@@ -252,8 +271,10 @@ class PagedKVCache:
             # an entry nothing backs points at the window pool's trash page
             wtables = jnp.full((n_slots, max_blocks or 1), window_pages,
                                jnp.int32)
-        return cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), tables,
-                   state, kw, vw, wtables, _moe_stats0(cfg))
+        v = (_v_placeholder(cfg, (1,), dtype) if cfg.latent
+             else jnp.zeros(shape, dtype))
+        return cls(jnp.zeros(shape, dtype), v, tables, state, kw, vw, wtables,
+                   _moe_stats0(cfg))
 
     def slot_view(self, slot) -> "PagedKVCache":
         """The cache as ONE slot's B = 1 forward sees it: its block-table
@@ -425,18 +446,127 @@ def _ssm_mixer(cfg: LlamaConfig, h, layers, si, state: RecurrentState,
     return colmm(y, layers["out_proj"], si), state
 
 
-def _mlp(cfg: LlamaConfig, h, layers, li, mm, colmm, moe_impl, logits=None,
-         stats=None):
+def _kda_mixer(cfg: LlamaConfig, h, layers, si, state: RecurrentState,
+               pos_base, active, mm, colmm):
+    """The gated delta-rule mixer (ops/delta.py has the equations). `si`
+    indexes the delta-rule layers' weight stacks and the state's layer axis;
+    a row at position 0 starts from ZERO state and a zero conv window, rows
+    with active==False leave both bit-equal, as `_ssm_mixer`'s. Returns
+    (out [B, T, D], state)."""
+    b, t, _ = h.shape
+    heads, dk, rank, inner = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_rank, cfg.kda_inner
+    proj = mm(h, layers["kda_proj"], si)  # q k v | fa | ga | beta (| zero pad)
+    fa = proj[..., 3 * inner : 3 * inner + rank]
+    ga = proj[..., 3 * inner + rank : 3 * inner + 2 * rank]
+    beta = jax.nn.sigmoid(proj[..., 3 * inner + 2 * rank :
+                               3 * inner + 2 * rank + heads].astype(jnp.float32))
+    fresh = jnp.broadcast_to(jnp.asarray(pos_base, jnp.int32) == 0, (b,))
+    window = state.window(si)
+    qkv, new_window = ssm.causal_conv(
+        proj[..., : 3 * inner], window, layers["kda_conv_w"][si],
+        jnp.zeros((), jnp.float32), fresh)
+    split = lambda i: qkv[..., i * inner : (i + 1) * inner].reshape(b, t, heads, dk)
+    q = delta.l2norm(split(0)) * (dk ** -0.5)
+    k, v = delta.l2norm(split(1)), split(2)
+    g = delta.decay(mm(fa, layers["kda_fb"], si), layers["kda_dt_bias"][si],
+                    layers["kda_a_log"][si], heads)  # [B, T, H, K]
+    act = jnp.ones((b,), bool) if active is None else active
+    new_window = jnp.where(act[:, None, None], new_window, window)
+    if t == 1 and state.slot is None and state.step is not None:
+        # the decode step the engine resolved, on the layer-stacked state,
+        # in place
+        mode = jnp.where(act, jnp.where(fresh, 2, 1), 0)
+        o, s_stack = state.step(state.s, si, q[:, 0], k[:, 0], v[:, 0],
+                                jnp.exp(g[:, 0]), beta[:, 0], mode)
+        o = o[:, None]
+        state = state.replace_layer(si, new_window, s_stack=s_stack)
+    else:
+        s_old = state.layer_state(si)
+        s_in = jnp.where(fresh[:, None, None, None], 0.0,
+                         s_old.astype(jnp.float32))
+        if t == 1:
+            o, s_new = delta.kda_step_ref(s_in, q[:, 0], k[:, 0], v[:, 0],
+                                          g[:, 0], beta[:, 0])
+            o = o[:, None]
+        else:
+            # a prefill slice: the step scanned over its rows, under a name
+            # of its own in the profile's op metadata
+            with jax.named_scope("kda_slice"):
+                o, s_new = delta.kda_scan(s_in, q, k, v, g, beta)
+        s_new = jnp.where(act[:, None, None, None], s_new.astype(s_old.dtype), s_old)
+        state = state.replace_layer(si, new_window, s_new=s_new)
+    gate = mm(ga, layers["kda_gb"], si).reshape(b, t, heads, dk)
+    y = delta.gated_head_norm(o, gate, layers["kda_norm"][si], cfg.norm_epsilon)
+    return colmm(y.reshape(b, t, inner).astype(h.dtype), layers["kda_o"], si), state
+
+
+def _mla_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, pos_base,
+               attn_fn, active, mm, colmm, tables, ci=None):
+    """Latent attention without rotation, in its ABSORBED form on every
+    route: the cache row of a token is (c, k_pe) = (rmsnorm(W_kva h)[:r],
+    W_kva h[r:]), one for all heads; a head's query meets it as
+    (W_kvb,k^T q_nope, q_pe), the mix of the rows' latents comes back and
+    W_kvb,v expands it to the head's value. One read of a row serves key and
+    value of every head; the expanded form (k_nope, v = W_kvb c a head) is
+    the benchmark reference's. `v_cache` is the latent cache's placeholder.
+    Returns (out [B, T, D], k_cache, v_cache)."""
+    b, t, _ = h.shape
+    heads, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dp, dv = cfg.qk_nope_dim, cfg.qk_pe_dim, cfg.v_head_dim
+    ci = ai if ci is None else ci
+    hi = jax.lax.Precision.HIGHEST
+    q = mm(h, layers["mla_q"], ai).reshape(b, t, heads, dn + dp)
+    kva = mm(h, layers["mla_kva"], ai)  # c | k_pe (| zero pad)
+    c = rms_norm(kva[..., :r], layers["mla_kv_norm"][ai], cfg.norm_epsilon)
+    row = jnp.concatenate([c, kva[..., r : r + dp]], axis=-1)[:, None]  # [B, 1, T, W]
+    wkvb = layers["mla_kvb"][ai]  # f32 [H, nope + v, r]
+    q_lat = jnp.einsum("bthn,hnr->bthr", q[..., :dn].astype(jnp.float32),
+                       wkvb[:, :dn], precision=hi)
+    q_abs = jnp.concatenate([q_lat.astype(h.dtype), q[..., dn:]], axis=-1)
+    scale = cfg.attn_scale or (dn + dp) ** -0.5
+    if tables is None:
+        k_cache = _cache_update(k_cache, row, pos_base, active)
+        o_lat = latent_attention(q_abs, k_cache[:, 0], pos_base, scale, r)
+    elif getattr(attn_fn, "fused_kv_scatter", False):
+        # the paged kernel's latent sweep, on the whole layer-stacked pool
+        o_lat, k_cache, v_cache = attn_fn(
+            q_abs, k_cache, v_cache, tables, pos_base, row, None, active, ci,
+            latent=r, scale=scale)
+    else:
+        k_cache = _paged_cache_update(k_cache, row, tables, pos_base, active)
+        o_lat = latent_attention(q_abs, paged_view(k_cache, tables)[:, 0],
+                                 pos_base, scale, r)
+    o = jnp.einsum("bthr,hvr->bthv", o_lat.astype(jnp.float32), wkvb[:, dn:],
+                   precision=hi)
+    out = colmm(o.reshape(b, t, heads * dv).astype(h.dtype), layers["mla_o"], ai)
+    return out, k_cache, v_cache
+
+
+def _mlp(cfg: LlamaConfig, h, layers, li, mm, colmm, moe_impl, experts: bool,
+         logits=None, stats=None):
     """The feed-forward block (reference "ff" segment, llm.cpp:314-385);
     sparse-MoE variant when the header carries N_EXPERTS (llm.hpp:17-18 —
     a key the reference parses but never executes): `logits` are the
     router's, computed by `_layer` where the header says the router reads.
     The expert stacks go in whole with the layer index, as the matmuls'
-    weights do. Returns the block's output, and with `stats` (out, stats')."""
-    if "moe_gate" in layers:
-        return moe_ffn(
+    weights do. Returns the block's output, and with `stats` (out, stats').
+    `li` indexes the stacks of the layer's OWN feed-forward kind (`experts`:
+    routed experts, else dense), and a shared expert, one SwiGLU at the
+    shared width, is added to the routed sum once."""
+    if experts:
+        bias = layers["moe_bias"][li] if "moe_bias" in layers else None
+        out = moe_ffn(
             cfg, h, None, layers["moe_w1"], layers["moe_w2"], layers["moe_w3"],
-            impl=moe_impl, logits=logits, layer=li, stats=stats)
+            impl=moe_impl, logits=logits, layer=li, stats=stats, bias=bias)
+        if "shared_w1" not in layers:
+            return out
+        gate = activation(mm(h, layers["shared_w1"], li).astype(jnp.float32),
+                          cfg.hidden_act).astype(h.dtype)
+        shared = colmm(gate * mm(h, layers["shared_w3"], li),
+                       layers["shared_w2"], li)
+        if stats is None:
+            return out + shared
+        return out[0] + shared, out[1]
     if "w13" in layers:  # fused launch (fuse_layer_weights)
         gu = mm(h, layers["w13"], li)
         f = cfg.hidden_dim
@@ -448,7 +578,8 @@ def _mlp(cfg: LlamaConfig, h, layers, li, mm, colmm, moe_impl, logits=None,
 
 
 def _layer(cfg: LlamaConfig, x, layers, li, mix, col_fn=None, mm=None,
-           mm_in=None, moe_impl="auto", moe_stats=None):
+           mm_in=None, moe_impl="auto", moe_stats=None, dense_ffn=False,
+           fi=None):
     """One decoder layer: the ONE skeleton every architecture runs,
 
         x += r * mix(norm(x));  x += r * mlp(norm(x))
@@ -472,7 +603,12 @@ def _layer(cfg: LlamaConfig, x, layers, li, mix, col_fn=None, mm=None,
     reference's col slices with merge-add): under sharded-Pallas it psums
     partials inside shard_map; default is plain `mm` (GSPMD inserts the
     collective itself on the XLA path).
+
+    `dense_ffn`: the layer's feed-forward block is dense though the model
+    has experts; `fi` is the layer's index into its feed-forward kind's
+    stacks where dense and expert layers are stacked apart (None = `li`).
     """
+    fi = li if fi is None else fi
     mm = mm or matmul
     if col_fn is None:
         colmm = mm_in or mm  # `--sync q80` swaps in the Q80-exchange
@@ -484,19 +620,21 @@ def _layer(cfg: LlamaConfig, x, layers, li, mix, col_fn=None, mm=None,
     scaled = (lambda y: y) if r == 1.0 else (lambda y: y * jnp.asarray(r, y.dtype))
     # --- mixer block (reference "att" segment, llm.cpp:198-312)
     h = rms_norm(x, layers["rms_att"][li], cfg.norm_epsilon)
-    experts = "moe_gate" in layers
+    experts = "moe_gate" in layers and not dense_ffn
     logits = None
     if experts and cfg.router_pre_attention:
         # the router reads the attention block's normed input
-        logits = router_logits(h, layers["moe_gate"][li])
+        logits = router_logits(h, layers["moe_gate"][fi])
     out, aux = mix(h, mm, colmm)
     x = x + scaled(out)
     h = rms_norm(x, layers["rms_ffn"][li], cfg.norm_epsilon)
     if experts and logits is None:
-        logits = router_logits(h, layers["moe_gate"][li])
-    y = _mlp(cfg, h, layers, li, mm, colmm, moe_impl, logits, moe_stats)
+        logits = router_logits(h, layers["moe_gate"][fi])
+    y = _mlp(cfg, h, layers, fi, mm, colmm, moe_impl, experts, logits,
+             moe_stats if experts else None)
     if moe_stats is not None:
-        y, moe_stats = y
+        if experts:
+            y, moe_stats = y
         return x + scaled(y), (aux, moe_stats)
     return x + scaled(y), aux
 
@@ -557,6 +695,44 @@ def layer_schedule(kinds: tuple) -> tuple[int, list[tuple[int, int, int]]]:
     return period, [tuple(r) for r in runs]
 
 
+def ragged_schedule(kinds: tuple):
+    """A layer pattern as a PREFIX of runs and a pattern of run KINDS that
+    repeats behind it with run lengths of its own in every period, or None
+    where that holds no fewer layer bodies than `layer_schedule`'s whole
+    periods. `k' k k m  k k k m  k k k m  k k m` has no whole period short of
+    itself (8 runs, 8 bodies), but it is the prefix `k'` and then (k, m)
+    four times with 2, 3, 3, 2 layers of k: three bodies. Returns (prefix,
+    pattern, lengths): prefix a list of (kind, first layer, length), pattern
+    the run kinds of a period, lengths an int array [periods, len(pattern)];
+    among the splits with the fewest bodies, the shortest prefix."""
+    import numpy as np
+
+    rle: list = []
+    for kind in kinds:
+        if rle and rle[-1][0] == kind:
+            rle[-1][1] += 1
+        else:
+            rle.append([kind, 1])
+    best = None
+    for p in range(len(rle)):
+        rest = rle[p:]
+        for m in range(1, len(rest) + 1):
+            if len(rest) % m == 0 and all(
+                    rest[i][0] == rest[i % m][0] for i in range(len(rest))):
+                if best is None or p + m < best[0] + best[1]:
+                    best = (p, m)
+                break
+    p, m = best
+    if p + m >= len(layer_schedule(kinds)[1]):
+        return None
+    prefix, first = [], 0
+    for kind, n in rle[:p]:
+        prefix.append((kind, first, n))
+        first += n
+    lengths = np.asarray([n for _, n in rle[p:]], np.int32).reshape(-1, m)
+    return prefix, [kind for kind, _ in rle[p:p + m]], lengths
+
+
 def run_layers(
     cfg: LlamaConfig,
     layer_params: dict,  # stacked [L, ...] leaves (per kind: a mixer's
@@ -587,8 +763,9 @@ def run_layers(
     `wpool` or `moe_stats` two more: the window pools (kw, vw) and the
     counters.
 
-    A layer's kind is what `cfg.schedule_kinds` gives: attention or
-    state-space, windowed or not, rotated or not. A windowed layer is handed
+    A layer's kind is what `cfg.schedule_kinds` gives: softmax or latent
+    attention, state-space or delta-rule, windowed or not, rotated or not,
+    its feed-forward block experts or dense. A windowed layer is handed
     `window=cfg.window` (its attention masks, and the paged sweep clips its
     walk); a layer the header leaves unrotated gets no rope.
 
@@ -615,6 +792,14 @@ def run_layers(
 
     The recurrent state is always carried whole and updated in place.
 
+    A pattern with no whole period short of itself (a leading layer of a
+    kind of its own, a last period cut short) is scanned as
+    `ragged_schedule` splits it: the prefix's runs once, then a scan over
+    periods of run KINDS in which a run whose length differs by period is a
+    `fori_loop` of that (traced) length, each kind's body once; the layer
+    and per-kind indices a run starts at are looked up by period. The caches
+    ride in the carry there, whatever the route.
+
     `unroll`: passed to lax.scan — trades compile time for cross-layer
     scheduling freedom."""
     if attn_fn is None:
@@ -627,7 +812,19 @@ def run_layers(
     kinds = cfg.schedule_kinds or (int(LayerKind.ATTENTION),) * k_cache.shape[0]
     period, runs = layer_schedule(kinds)
     n_periods = len(kinds) // period
-    is_attn_kind = lambda kind: (kind & SCHEDULE_KIND_MASK) == LayerKind.ATTENTION
+    ragged = ragged_schedule(kinds)
+    # a layer that holds cache rows (softmax or latent attention), as
+    # against one that holds recurrent state
+    is_attn_kind = lambda kind: (kind & SCHEDULE_KIND_MASK) not in STATE_KINDS
+    if cfg.layer_ffn:
+        # dense and expert layers are stacked apart: a layer's index into
+        # its own feed-forward kind's stacks, looked up by the traced layer
+        ffn_ix = jnp.asarray([cfg.ffn_index(i) for i in range(cfg.n_layers)],
+                             jnp.int32)
+        ffn_of = lambda kind, li: dict(
+            dense_ffn=bool(kind & SCHEDULE_DENSE_FFN), fi=ffn_ix[li])
+    else:
+        ffn_of = lambda kind, li: {}
     a_pp = sum(n for kind, _, n in runs if is_attn_kind(kind))
     s_pp = period - a_pp
     two_pools = wpool is not None  # a pool a kind: (kw, vw, wtables)
@@ -637,19 +834,28 @@ def run_layers(
     # the pools ride in the carry where the kernel indexes the layer itself,
     # and wherever there are two of them (a layer of the other routes then
     # cuts its slice out of the carried stack and puts it back: the CPU route)
-    fused = kernel or two_pools
+    # ... and wherever the pattern is ragged (its runs are loops of a length
+    # that is data: no slice of the cache can ride as a scan's xs)
+    fused = kernel or two_pools or ragged is not None
+    if ragged is not None and two_pools:
+        raise NotImplementedError(
+            "a ragged layer pattern beside windowed layers with a page pool "
+            "of their own is not supported")
     kwp, vwp, wtables = wpool if two_pools else (None, None, None)
 
     def one_layer(x, kc, vc, st, kw, vw, ms, li, ai, ci, si, kind):
         """Layer `li` of kind `kind`; kc/vc are the whole pools (fused) or
         this layer's slice; `ci` is the layer's index into ITS pool."""
+        base = kind & SCHEDULE_KIND_MASK
         if not is_attn_kind(kind):
+            mixer = _kda_mixer if base == LayerKind.KDA else _ssm_mixer
+
             def mix(h, mm_, colmm):
-                return _ssm_mixer(cfg, h, layer_params, si, st, pos_base,
-                                  active, mm_, colmm)
+                return mixer(cfg, h, layer_params, si, st, pos_base,
+                             active, mm_, colmm)
 
             x, st = _layer(cfg, x, layer_params, li, mix, col_fn, mm, mm_in,
-                           moe_impl, ms)
+                           moe_impl, ms, **ffn_of(kind, li))
             if ms is not None:
                 st, ms = st
             return x, kc, vc, st, kw, vw, ms
@@ -666,16 +872,21 @@ def run_layers(
             # carried, the scan's xs elsewhere)
             cut = fused and not kernel
             ks, vs = (pk[ci], pv[ci]) if cut else (pk, pv)
-            out, k2, v2 = _attention_mixer(
-                cfg, h, layer_params, ai, ks, vs, lrope, pos_base, attn_fn,
-                active, mm_, colmm, tbl, ci, cfg.window if windowed else 0)
+            if base == LayerKind.MLA:
+                out, k2, v2 = _mla_mixer(
+                    cfg, h, layer_params, ai, ks, vs, pos_base, attn_fn,
+                    active, mm_, colmm, tbl, ci)
+            else:
+                out, k2, v2 = _attention_mixer(
+                    cfg, h, layer_params, ai, ks, vs, lrope, pos_base, attn_fn,
+                    active, mm_, colmm, tbl, ci, cfg.window if windowed else 0)
             if cut:
                 k2 = jax.lax.dynamic_update_index_in_dim(pk, k2, ci, 0)
                 v2 = jax.lax.dynamic_update_index_in_dim(pv, v2, ci, 0)
             return out, (k2, v2)
 
         x, aux = _layer(cfg, x, layer_params, li, mix, col_fn, mm, mm_in,
-                        moe_impl, ms)
+                        moe_impl, ms, **ffn_of(kind, li))
         if ms is not None:
             aux, ms = aux
         if in_wpool:
@@ -752,6 +963,57 @@ def run_layers(
     period_ids = jnp.arange(n_periods, dtype=jnp.int32)
     extra = lambda out: out + (((kwp, vwp) if two_pools else None, moe_stats)
                                if two_pools or moe_stats is not None else ())
+    if ragged is not None:
+        import numpy as np
+
+        prefix, pattern, lengths = ragged
+        attn_run = np.asarray([is_attn_kind(kind) for kind in pattern])
+
+        def run_of(c, kind, n, li0, ai0, si0):
+            """`n` layers of one kind from layer li0 on, the ai0-th of those
+            with cache rows and the si0-th of those with state; `n` a Python
+            int or, where a period's runs differ in length, traced: one body
+            either way."""
+            def body(j, c):
+                ai = ai0 + j
+                return one_layer(*c, li0 + j, ai, ai, si0 + j, kind)
+
+            if isinstance(n, int) and n == 1:
+                return body(0, c)
+            return jax.lax.fori_loop(0, n, body, c)
+
+        c = (x, k_cache, v_cache, state, None, None, moe_stats)
+        li = ai = si = 0
+        for kind, first, n in prefix:
+            c = run_of(c, kind, n, li, ai, si)
+            li += n
+            if is_attn_kind(kind):
+                ai += n
+            else:
+                si += n
+        # where each run of each period starts, by the three counts
+        flat = lengths.reshape(-1)
+        before = np.concatenate([[0], np.cumsum(flat)[:-1]])
+        is_a = np.tile(attn_run, len(lengths))
+        a_before = np.concatenate([[0], np.cumsum(flat * is_a)[:-1]])
+        starts = jnp.asarray(np.stack(
+            [li + before, ai + a_before, si + before - a_before],
+            axis=-1).reshape(*lengths.shape, 3), jnp.int32)
+        fixed = [int(col[0]) if (col == col[0]).all() else None
+                 for col in lengths.T]
+        lens = jnp.asarray(lengths)
+
+        def ragged_period(c, pi):
+            for r, kind in enumerate(pattern):
+                n = lens[pi, r] if fixed[r] is None else fixed[r]
+                li0, ai0, si0 = (starts[pi, r, i] for i in range(3))
+                c = run_of(c, kind, n, li0, ai0, si0)
+            return c, None
+
+        (x, k_new, v_new, state, _, _, moe_stats), _ = jax.lax.scan(
+            ragged_period, c, jnp.arange(len(lengths), dtype=jnp.int32),
+            unroll=unroll)
+        return extra((x, k_new, v_new, state))
     if fused:
         (x, k_new, v_new, state, kwp, vwp, moe_stats), _ = jax.lax.scan(
             period_fn, (x, k_cache, v_cache, state, kwp, vwp, moe_stats),

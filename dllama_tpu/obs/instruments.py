@@ -362,6 +362,16 @@ MOE_GROUP_ROWS_MAX = metrics.counter(
     "dllama_moe_group_rows_max_total",
     "The longest expert group of each layer-step, summed: over layer-steps "
     "and against assignments / experts it is the load skew")
+MOE_ROWS_ROUTED = metrics.counter(
+    "dllama_moe_rows_routed_total",
+    "Token-expert rows the routers chose, over ALL the experts they route "
+    "among (rows x active experts, over layers and steps)")
+MOE_ROWS_HELD = metrics.counter(
+    "dllama_moe_rows_held_total",
+    "Of those, the rows that landed on experts this process holds (all of "
+    "them unless the file holds one chip's share of each expert layer): "
+    "what dllama_moe_assignments_total and the touched / longest-group "
+    "counters are counted over")
 # recurrent (state-space) models: the per-slot state beside the page pool
 RECURRENT_STATE_BYTES = metrics.gauge(
     "dllama_recurrent_state_bytes",

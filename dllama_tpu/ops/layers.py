@@ -188,10 +188,25 @@ def moe_ffn(
     # by the caller (which knows WHERE the router reads)
     layer=None,  # traced layer index into layer-stacked expert weights
     stats: jax.Array | None = None,  # u32[4] running counters, see below
+    bias: jax.Array | None = None,  # f32 [E] selection bias (sigmoid router)
 ):
     """Sparse MoE FFN: softmax over the top-k router logits (= softmax over
     all, top k, renormalised), gated experts act(w1 x) * w3 x -> w2 with the
     header's activation, probability-weighted combine in float32.
+
+    A SIGMOID router (`cfg.router_sigmoid`): scores s = sigmoid(logits), the
+    top k of s + `bias` are chosen (the bias decides WHO is chosen and is
+    left out of the weights), the weights are the chosen scores over their
+    sum (+ 1e-20), and either router's weights are multiplied by
+    `cfg.routed_scale`.
+
+    ONE CHIP'S SHARE (`cfg.experts_held` > 0): the stacks hold experts
+    [expert_offset, expert_offset + held) of the n_experts the router
+    chooses among. Routing and renormalisation are over all of them; only
+    the chosen experts that are held are computed and summed, and what the
+    absent ones would add is left out (the other chips' part of the layer).
+    The jnp route of a share is `dense`. `stats` then has a fifth counter:
+    [0] counts the rows that landed on held experts, [4] every routed row.
 
     The reference *parses* N_EXPERTS from the header and its converter emits
     expert tensors, but the runtime has no MoE graph (SURVEY.md §2.4 — EP row);
@@ -233,8 +248,28 @@ def moe_ffn(
         impl = "sort" if n >= e else "dense"
     if logits is None:
         logits = router_logits(h, gate)
-    topv, topi = jax.lax.top_k(logits.astype(jnp.float32), k)
-    probs = jax.nn.softmax(topv, axis=-1)  # [B, T, k]
+    if cfg.router_sigmoid:
+        score = jax.nn.sigmoid(logits.astype(jnp.float32))
+        _, topi = jax.lax.top_k(
+            score if bias is None else score + bias.astype(jnp.float32), k)
+        topv = jnp.take_along_axis(score, topi, axis=-1)
+        probs = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+    else:
+        topv, topi = jax.lax.top_k(logits.astype(jnp.float32), k)
+        probs = jax.nn.softmax(topv, axis=-1)  # [B, T, k]
+    if cfg.routed_scale != 1.0:
+        probs = probs * cfg.routed_scale
+    mine = None
+    if cfg.experts_held:
+        # this chip's share: a choice outside it becomes the sentinel `e`,
+        # which every scheme below drops, with weight 0
+        e = cfg.experts_held
+        local = topi - cfg.expert_offset
+        mine = (local >= 0) & (local < e)
+        topi = jnp.where(mine, local, e)
+        probs = jnp.where(mine, probs, 0.0)
+        if impl != "grouped":
+            impl = "dense"
     if layer is not None and impl != "grouped":
         from dllama_tpu.ops.quant import slice_leaf
 
@@ -246,9 +281,10 @@ def moe_ffn(
             return out
         if sizes is None:
             sizes = jnp.bincount(topi.reshape(-1), length=e)
+        rows = jnp.asarray(n * k) if mine is None else jnp.count_nonzero(mine)
         return out, stats + jnp.stack(
-            [jnp.asarray(n * k), jnp.count_nonzero(sizes), jnp.asarray(1),
-             sizes.max()]).astype(stats.dtype)
+            [rows, jnp.count_nonzero(sizes), jnp.asarray(1), sizes.max()]
+            + ([] if mine is None else [jnp.asarray(n * k)])).astype(stats.dtype)
 
     if impl == "grouped":
         from dllama_tpu.ops.matmul import device_platform
@@ -266,7 +302,14 @@ def moe_ffn(
         up = mm(xs, w3)
         act = (activation(g, cfg.hidden_act) * up).astype(h.dtype)
         y = mm(act, w2)  # f32 [T*tm, D]
-        out = jnp.sum(y[pos] * probs.reshape(n, k)[..., None], axis=1)
+        if mine is None:
+            out = jnp.sum(y[pos] * probs.reshape(n, k)[..., None], axis=1)
+        else:
+            # a choice this chip does not hold stands nowhere in the padded
+            # order: a select, since a tile no row reached is never written
+            held = mine.reshape(n, k)
+            yk = jnp.where(held[..., None], y[jnp.where(held, pos, 0)], 0.0)
+            out = jnp.sum(yk * probs.reshape(n, k)[..., None], axis=1)
         return done(out, sizes)
 
     if impl == "sort":
@@ -369,6 +412,29 @@ def gqa_attention(
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgts,bhsd->bthgd", probs, vf)
     return out.reshape(b, t, hq, hd).astype(q.dtype)
+
+
+def latent_attention(
+    q: jax.Array,  # [B, T, H, W]: a head's absorbed query, latent | shared dims
+    rows: jax.Array,  # [B, S, W]: the cache rows, one a token for all heads
+    pos_base: jax.Array,  # i32 scalar, or [B] per-sequence positions
+    scale: float,
+    rank: int,  # the leading `rank` dims of a row are also its value
+) -> jax.Array:
+    """Causal attention of every head over ONE shared row a token (latent
+    attention in its absorbed form): scores q . row, output the softmax mix
+    of the rows' first `rank` dims, f32 [B, T, H, rank]. The jnp route and
+    the parity reference of ops/pallas/paged_attention's latent sweep."""
+    b, t, _, _ = q.shape
+    s = rows.shape[1]
+    rf = rows.astype(jnp.float32)
+    scores = jnp.einsum("bthw,bsw->bhts", q.astype(jnp.float32), rf) * scale
+    pos = jnp.broadcast_to(jnp.asarray(pos_base, jnp.int32), (b,))
+    row = pos[:, None, None] + jnp.arange(t, dtype=jnp.int32)[None, :, None]
+    mask = jnp.arange(s, dtype=jnp.int32)[None, None, :] <= row  # [B, T, S]
+    scores = jnp.where(mask[:, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhts,bsr->bthr", probs, rf[..., :rank])
 
 
 def paged_view(pool: jax.Array, tables: jax.Array) -> jax.Array:

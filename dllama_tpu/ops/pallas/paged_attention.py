@@ -206,7 +206,7 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
             kbuf, vbuf, newk32, newv32, acc_ref, m_ref, l_ref, base_ref,
             copy_sems, write_sems,
             *, scale, page, group, t, tq, rows_live, nb, fused, hb, depth,
-            mxu_dtype, window=None):
+            mxu_dtype, window=None, latent=False):
     b, hblk, iq = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nbatch, nhb, nq = pl.num_programs(0), pl.num_programs(1), pl.num_programs(2)
     lanes = kbuf.shape[-1]
@@ -296,7 +296,9 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
 
     def copies(pg, hh, slot, back=False):
         heads = pl.ds(hh * hb, hb)  # [hb, page, lanes]: contiguous in HBM
-        pairs = ((kpool_ref, kbuf), (vpool_ref, vbuf))
+        # a latent row is key AND value: one pool, one copy a page
+        pairs = (((kpool_ref, kbuf),) if latent
+                 else ((kpool_ref, kbuf), (vpool_ref, vbuf)))
         if back:
             return [pltpu.make_async_copy(buf.at[slot], pool.at[pg, heads],
                                           write_sems.at[j])
@@ -332,7 +334,8 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
         # f32 copies of the new rows: the blend below takes one row at a
         # dynamic sublane offset, which Mosaic serves for 32-bit rows only
         newk32[...] = newk_ref[...].astype(jnp.float32)
-        newv32[...] = newv_ref[...].astype(jnp.float32)
+        if not latent:
+            newv32[...] = newv_ref[...].astype(jnp.float32)
     # causal mask against absolute cache positions: query row r of tile iq
     # holds token offset (iq*tq + r) // group (t-major GQA fold)
     qpos = pos_b + (iq * tq + jax.lax.broadcasted_iota(
@@ -361,7 +364,8 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
                     window = (slot, slice(None), pl.ds(r0, win), slice(None))
                     sel = jax.lax.broadcasted_iota(
                         jnp.int32, (win, lanes), 0) == off - r0
-                    for rows, buf in ((newk32, kbuf), (newv32, vbuf)):
+                    for rows, buf in (((newk32, kbuf),) if latent else
+                                      ((newk32, kbuf), (newv32, vbuf))):
                         buf[window] = jnp.where(
                             sel[None], rows[:, pl.ds(tt, 1), :],
                             buf[window].astype(jnp.float32)).astype(buf.dtype)
@@ -374,7 +378,7 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
                     wr.start()
 
         k = kbuf[slot]  # [hb, page, lanes]
-        v = vbuf[slot]
+        v = k if latent else vbuf[slot]  # the landed page read ONCE
         # q and K enter the MXU as the bfloat16 they are stored as where
         # both are (a product of two bfloat16 values is exact in float32);
         # a float32 q or pool keeps float32 operands
@@ -420,7 +424,7 @@ def _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
                 new_v, *, group: int, interpret: bool, rows_live: int,
                 fused: bool, scale: float,
                 vmem_budget: int = _VMEM_BUDGET_BYTES,
-                window: int | None = None):
+                window: int | None = None, latent: bool = False):
     """qf[B, Hkv, rows_pad, hd] x pool[N, Hkv, page, hd] ->
     (out f32 [B, Hkv, rows_pad, hd], k_pool, v_pool).
 
@@ -430,7 +434,13 @@ def _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
     pages, or all L*P of the layer-merged stack with ``tables``/``wpages``
     already offset to the layer (the kernel cannot tell, and need not). The
     name and the 4-D pool in the result are what the benchmark's trace
-    reader finds this call by (benchmark/costs/paged_attention.py)."""
+    reader finds this call by (benchmark/costs/paged_attention.py).
+
+    ``latent``: the k pool's row is key and value at once (one latent a
+    token, Hkv = 1, every query head in the one head block's q rows): the
+    sweep lands each page once and uses it for the scores and the mix; the
+    v pool is a placeholder nothing reads or writes, and the output's lanes
+    are the whole row's (the caller keeps the latent's)."""
     b, hkv, rows, hd = qf.shape
     npool, _, page, _ = k_pool.shape
     nb = tables.shape[1]
@@ -464,7 +474,8 @@ def _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
         ],
         scratch_shapes=[
             pltpu.VMEM((depth, hb, page, hd), k_pool.dtype),  # k landing ring
-            pltpu.VMEM((depth, hb, page, hd), v_pool.dtype),
+            pltpu.VMEM((1, 1, 8, _LANES) if latent else (depth, hb, page, hd),
+                       v_pool.dtype),
             pltpu.VMEM((hb, t, hd), jnp.float32),  # the new k rows, widened
             pltpu.VMEM((hb, t, hd), jnp.float32),
             pltpu.VMEM((hb, tq, hd), jnp.float32),  # acc
@@ -479,7 +490,7 @@ def _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
         functools.partial(_kernel, scale=scale, page=page,
                           group=group, t=t, tq=tq, rows_live=rows_live,
                           nb=nb, fused=fused, hb=hb, depth=depth,
-                          mxu_dtype=mxu_dtype, window=window),
+                          mxu_dtype=mxu_dtype, window=window, latent=latent),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, rows, hd), jnp.float32),
@@ -532,6 +543,16 @@ def _paged_window(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
     paged_attention_window.py)."""
     return _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
                        new_v, window=window, **kw)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _paged_latent(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
+                  new_v, **kw):
+    """The same sweep over a pool of LATENT rows (one a token, key and value
+    of every head), a call of its own name on the device plane
+    (benchmark/costs/paged_attention_latent.py)."""
+    return _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
+                       new_v, latent=True, **kw)
 
 
 def _scatter_rows_by_page(pool, new, nb, pos, wpages, woffs, trash):
@@ -592,6 +613,10 @@ def paged_decode_attention(
     interpret: bool = False,
     window: int | None = None,  # rows a query sees, itself included; None
     # = the whole context (and today's program, to the instruction)
+    latent: int = 0,  # > 0: the k pool holds ONE row a token for all heads
+    # (Hkv = 1), key and value at once; the output is the mix of the rows'
+    # first `latent` dims, [B, T, Hq, latent]; v_pool is a placeholder
+    scale: float | None = None,  # score scale; None = 1/sqrt(hd)
 ) -> jax.Array | tuple[jax.Array, jax.Array, jax.Array]:
     """Block-table paged attention over the HBM page pool, any page size.
 
@@ -619,7 +644,8 @@ def paged_decode_attention(
     stack_shape = k_pool.shape
     if layer is not None:
         k_pool = k_pool.reshape(-1, *stack_shape[2:])
-        v_pool = v_pool.reshape(-1, *stack_shape[2:])
+        if not latent:
+            v_pool = v_pool.reshape(-1, *stack_shape[2:])
     n_pool, hkv, page, lanes = stack_shape[-4:]
     group = hq // hkv
     if lanes != hd:
@@ -658,10 +684,12 @@ def paged_decode_attention(
             # prefill-sized chunk (or one whose folded rows take several q
             # tiles, so several sweeps): scattered by XLA, then a
             # read-only sweep
-            k_pool, v_pool = (
-                _scatter_rows_by_page(pool, new, tables.shape[1], pos,
-                                      wpages, woffs, first_page + n_pool - 1)
-                for pool, new in ((k_pool, new_k), (v_pool, new_v)))
+            scatter = lambda pool, new: _scatter_rows_by_page(
+                pool, new, tables.shape[1], pos, wpages, woffs,
+                first_page + n_pool - 1)
+            k_pool = scatter(k_pool, new_k)
+            if not latent:
+                v_pool = scatter(v_pool, new_v)
             write = False
     if not write:
         # dummy single-row write of what the trash page already gets —
@@ -672,20 +700,23 @@ def paged_decode_attention(
         nv = jnp.zeros((b, hkv, 1, lanes), v_pool.dtype)
     else:
         nk = new_k.astype(k_pool.dtype)
-        nv = new_v.astype(v_pool.dtype)
+        nv = jnp.zeros_like(nk) if latent else new_v.astype(v_pool.dtype)
 
-    call = (_paged_folded if window is None
+    call = (_paged_latent if latent else _paged_folded if window is None
             else functools.partial(_paged_window, window=int(window)))
     out, k_pool, v_pool = call(
         qf, k_pool, v_pool, pos, tables + first_page, wpages, woffs, nk, nv,
         group=group, interpret=interpret, rows_live=rows, fused=write,
-        scale=1.0 / math.sqrt(hd), vmem_budget=_VMEM_BUDGET_BYTES)
+        scale=1.0 / math.sqrt(hd) if scale is None else float(scale),
+        vmem_budget=_VMEM_BUDGET_BYTES)
+    vd = latent or hd
     out = (
-        out[:, :, :rows, :hd].reshape(b, hkv, t, group, hd)
+        out[:, :, :rows, :vd].reshape(b, hkv, t, group, vd)
         .transpose(0, 2, 1, 3, 4)
-        .reshape(b, t, hq, hd)
+        .reshape(b, t, hq, vd)
         .astype(q.dtype)
     )
     if new_k is None:
         return out
-    return out, k_pool.reshape(stack_shape), v_pool.reshape(stack_shape)
+    return (out, k_pool.reshape(stack_shape),
+            v_pool if latent else v_pool.reshape(stack_shape))

@@ -414,6 +414,15 @@ class ApiServer:
         h["kv_cache_bytes"] = self.kv_cache_bytes
         h["recurrent_state_bytes"] = self.recurrent_state_bytes
         eng = self.scheduler.engine if self.scheduler is not None else None
+        if eng is not None and eng.cfg.recurrent:
+            # per-slot recurrent state stands at one row: what re-enters a
+            # sequence at another resolved off at start-up (engine/batch.py)
+            h["recurrent_state"] = {
+                "kind": "kda" if eng.cfg.n_kda_layers else "ssm",
+                "layers": eng.cfg.n_state_layers,
+                "bytes": self.recurrent_state_bytes,
+                "resolved_off": ["radix_cache", "kv_host_pages", "spec_k",
+                                 "cross_slot_prefix_copy", "preempt_to_pages"]}
         if getattr(eng, "wpool", None) is not None:
             # windowed layers keep a page pool of their own: what follows one
             # page list a slot resolved off at start-up (engine/batch.py)

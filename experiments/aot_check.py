@@ -444,22 +444,26 @@ def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
                      spec=spec, max_prefill_chunk=prefill_chunk)
     assert be.kernel_route == (
         "pallas/paged_kernel" + (".window" if cfg.n_window_layers else "")
-        + ("+ssm_step.float32" if cfg.recurrent else "")
+        + (".latent" if cfg.latent else "")
+        + ("+kda_step.float32" if cfg.n_kda_layers else
+           "+ssm_step.float32" if cfg.recurrent else "")
         + ("+moe_grouped" if cfg.n_experts else "")), be.kernel_route
     nb = seq // page
     n_pages = kv_pages or slots * nb
-    row = (cfg.n_kv_heads, page, be.cache.k.shape[-1])
+    row = (cfg.cache_kv_heads, page, be.cache.k.shape[-1])
     pool = A((cfg.n_attn_layers - cfg.n_window_layers, n_pages + 1, *row),
              jnp.bfloat16)
+    # a latent cache's v is the placeholder the engine made
+    vpool = place(be.cache.v) if cfg.latent else pool
     wpool = wtables = None
     if cfg.n_window_layers:
         # the window pool as the engine sizes it beside `kv_pages`
         be._build_pools(n_pages, nb)
         wpool = A((cfg.n_window_layers, be.wpool.n_pages + 1, *row), jnp.bfloat16)
         wtables = i32(slots, nb)
-    cache = PagedKVCache(pool, pool, i32(slots, nb), place(be.cache.state),
+    cache = PagedKVCache(pool, vpool, i32(slots, nb), place(be.cache.state),
                          wpool, wpool, wtables,
-                         A((4,), jnp.uint32) if cfg.n_experts else None)
+                         place(be.cache.moe_stats) if cfg.n_experts else None)
     rope = place(jax.eval_shape(lambda: build_rope_cache(cfg, seq)))
     vecs = (i32(slots), A((slots,), jnp.bool_), A((slots, 2), jnp.uint32),
             f32(slots), f32(slots))  # pos, active, keys, temps, topp
@@ -658,6 +662,86 @@ def window_moe_cases(topo, slots: int = WINDOW_MOE_SLOTS,
                            seq=WINDOW_MOE_SEQ, prefill_chunk=512)
 
 
+#: the delta-rule / latent-attention stack over sigmoid-routed experts at
+#: the published widths of benchmark/configs/kimi-linear-48b-a3b.json: 2,304
+#: stream, 32 KDA heads of 128, latent 512 + 64 under 32 heads of 128 + 64 /
+#: 128, a dense layer of 9,216, then 64 held of 256 experts of 1,024 with 8
+#: active and a shared expert, a 40,960-row head; 48 slots over 456 pages
+DELTA_LATENT_SLOTS, DELTA_LATENT_PAGES, DELTA_LATENT_SEQ = 48, 456, 8192
+#: the published pattern: layer 1 dense, latent attention at 4, 8, ..., 24, 27
+DELTA_LATENT_KINDS = tuple(3 if i in (4, 8, 12, 16, 20, 24, 27) else 2
+                           for i in range(1, 28))
+
+
+def delta_latent_cfg(kinds: tuple = DELTA_LATENT_KINDS):
+    from dllama_tpu.models.config import LlamaConfig, RopeType
+
+    return LlamaConfig(
+        dim=2304, hidden_dim=9216, n_layers=len(kinds), n_heads=32,
+        n_kv_heads=32, vocab_size=40960, seq_len=DELTA_LATENT_SEQ,
+        n_experts=256, n_active_experts=8, rope_type=RopeType.NONE,
+        layer_kinds=kinds, kda_heads=32, kda_head_dim=128, kda_conv=4,
+        kda_rank=128, kv_lora_rank=512, qk_nope_dim=128, qk_pe_dim=64,
+        v_head_dim=128, router_sigmoid=True, routed_scale=2.446,
+        n_shared_experts=1, experts_held=64, expert_offset=0,
+        moe_hidden_dim=1024, layer_ffn=(1,) + (0,) * (len(kinds) - 1))
+
+
+def delta_latent_params(cfg, A):
+    """Abstract params as models/formats.load_params stacks them (per mixer
+    kind and per feed-forward kind; kda_proj and mla_kva zero-padded)."""
+    def qw(lead, k, n):
+        return QTensor(A((*lead, k // 2, n), jnp.uint8),
+                       A((*lead, k // Q_BLOCK, n), jnp.float16))
+
+    f32 = lambda *shape: A(shape, jnp.float32)
+    L, Lk, Lm = cfg.n_layers, cfg.n_kda_layers, cfg.n_attn_layers
+    Ld = cfg.n_dense_ffn_layers
+    Le, d, w, E = L - Ld, cfg.dim, cfg.expert_width, cfg.n_held_experts
+    inner, rank, h = cfg.kda_inner, cfg.kda_rank, cfg.n_heads
+    return {
+        "embedding": A((cfg.vocab_size, d), jnp.bfloat16),
+        "final_norm": f32(d), "wcls": qw((), d, cfg.vocab_size),
+        "layers": {
+            "kda_proj": qw((Lk,), d, -(-cfg.kda_proj // 512) * 512),
+            "kda_conv_w": f32(Lk, 3 * inner, cfg.kda_conv),
+            "kda_fb": qw((Lk,), rank, inner), "kda_gb": qw((Lk,), rank, inner),
+            "kda_dt_bias": f32(Lk, inner), "kda_a_log": f32(Lk, cfg.kda_heads),
+            "kda_norm": f32(Lk, cfg.kda_head_dim), "kda_o": qw((Lk,), inner, d),
+            "mla_q": qw((Lm,), d, h * (cfg.qk_nope_dim + cfg.qk_pe_dim)),
+            "mla_kva": qw((Lm,), d, -(-cfg.cache_row // 128) * 128),
+            "mla_kv_norm": f32(Lm, cfg.kv_lora_rank),
+            "mla_kvb": f32(Lm, h, cfg.qk_nope_dim + cfg.v_head_dim, cfg.kv_lora_rank),
+            "mla_o": qw((Lm,), h * cfg.v_head_dim, d),
+            "w1": qw((Ld,), d, cfg.hidden_dim), "w2": qw((Ld,), cfg.hidden_dim, d),
+            "w3": qw((Ld,), d, cfg.hidden_dim),
+            "moe_gate": f32(Le, d, cfg.n_experts), "moe_bias": f32(Le, cfg.n_experts),
+            "moe_w1": qw((Le, E), d, w), "moe_w2": qw((Le, E), w, d),
+            "moe_w3": qw((Le, E), d, w),
+            "shared_w1": qw((Le,), d, w), "shared_w2": qw((Le,), w, d),
+            "shared_w3": qw((Le,), d, w),
+            "rms_att": f32(L, d), "rms_ffn": f32(L, d),
+        },
+    }
+
+
+def delta_latent_cases(topo, slots: int = DELTA_LATENT_SLOTS,
+                       pages: int = DELTA_LATENT_PAGES,
+                       kinds: tuple = DELTA_LATENT_KINDS):
+    """The step programs of `serve --slots 48 --kv-pages 456` on the
+    delta-rule / latent-attention model at its published widths and depth:
+    decode chunk and the hybrid step (`_kda_step` on the stacked state, the
+    latent paged sweep, the grouped kernel over the held experts). Kept out
+    of all_cases(): the engine allocates the slots' state on the host."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = delta_latent_cfg(kinds)
+    params = delta_latent_params(cfg, lambda shape, dt: S(shape, dt, sharding=one))
+    return engine_programs(topo, f"serve delta-latent {slots}-slot", cfg, params,
+                           slots, 0, kv_pages=pages, seq=DELTA_LATENT_SEQ)
+
+
 def all_cases(topo, full: bool = False):
     """Every case as (name, thunk, production): thunk() compiles for the
     described chip and raises what the chip's compiler would raise."""
@@ -704,7 +788,8 @@ def main():
     rows, prod_reject, parked = [], [], {}
     topo = topology()
     for cname, thunk, production in (all_cases(topo, full) + hybrid_cases(topo)
-                                     + window_moe_cases(topo)):
+                                     + window_moe_cases(topo)
+                                     + delta_latent_cases(topo)):
         t0 = time.time()
         try:
             compiled = thunk()

@@ -238,3 +238,66 @@ def test_window_moe_step_program_compiles_with_its_kernels_named(window_moe_thun
     one_expert_layer = 64 * 2560 * 768 // 2  # a projection's packed stack
     assert not pool_copies.big_movers(text, one_expert_layer)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# ------- delta-rule / latent-attention layers over one chip's share of experts
+
+
+@pytest.fixture(scope="module")
+def delta_latent_thunks(thunks):
+    """The delta-rule / latent-attention model's step programs at its
+    published widths and full depth (aot_check.delta_latent_cases: 2,304
+    stream, 20 KDA layers of 32 x 128 x 128 state, 7 latent layers of 512 +
+    64, 64 held of 256 experts), at 12 slots over 120 pages so the engine
+    built on the host holds 0.5 GB of state and not 2.1 (12, not 8: at 8 a
+    layer's state over the slots is to the byte a latent layer's float32
+    W_kvb, whose slice out of its stack IS copied, 16.8 MB a latent layer
+    and step). Depends on `thunks` for the platform steer and the cache
+    settings."""
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(aot_check.TARGET, platform="tpu")
+    return {name.split("-slot ")[1]: thunk for name, thunk, _ in
+            aot_check.delta_latent_cases(topo, slots=12, pages=120)}
+
+
+@pytest.mark.parametrize("name", ["paged decode chunk n=4",
+                                  "hybrid step p=64 n=4"])
+def test_delta_latent_step_program_compiles_with_its_kernels_named(
+        delta_latent_thunks, name):
+    """The decode and hybrid programs compile for v5e with ONE body a kind
+    of layer (a leading dense-FFN KDA layer, then KDA runs of 2, 3, ..., 2
+    layers as a loop of a length that is data, and a latent layer): the
+    device plane will read `_kda_step`, `_paged_latent` and `_expert_call`
+    beside `_deq_call` / `_blockdot_call`, each custom call's line parses as
+    its cost file reads it, and no instruction writes a layer's state over
+    the slots (`_kda_step` updates the stack in place)."""
+    import re
+
+    from benchmark.costs import kda_step, moe_experts, paged_attention_latent
+    from experiments import pool_copies
+
+    compiled = delta_latent_thunks[name]()
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    groups = {m.group(1) for l in calls
+              for m in [re.search(r"%(_[a-z_]+?)(?:\.\d+)? = ", l)] if m}
+    assert {"_kda_step", "_paged_latent", "_expert_call", "_deq_call"} <= groups, groups
+    count = lambda g: sum(f"%{g}" in l for l in calls)
+    # three bodies a step: the prefix layer's and the period's KDA body, one
+    # latent layer (a hybrid launch's prefill slice holds the latent sweep
+    # once more; its KDA layers scan the jnp step)
+    assert count("_kda_step") == 2, count("_kda_step")
+    assert count("_paged_latent") == (1 if "decode" in name else 2)
+    for line in calls:
+        if "%_kda_step" in line:
+            assert kda_step.shape({"hlo": line}) == (12, 32, 128, 128, "f32")
+        if "%_expert_call" in line:
+            assert moe_experts.shape({"hlo": line}) in ((64, 2304, 1024),
+                                                        (64, 1024, 2304))
+        if "%_paged_latent" in line:
+            batch, rows, dtype = paged_attention_latent.shape({"hlo": line})
+            assert (batch, dtype) in ((12, "bf16"), (1, "bf16")) and rows >= 32
+    layer_state = 12 * 32 * 128 * 128 * 4
+    assert not pool_copies.big_movers(text, layer_state)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
