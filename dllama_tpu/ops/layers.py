@@ -126,9 +126,17 @@ def router_logits(h: jax.Array, gate: jax.Array) -> jax.Array:
 
 def expert_tile_rows(rows: int, e: int) -> int:
     """Rows a tile of the grouped expert kernel holds: a whole bf16 tile (16)
-    while an expert sees a few rows (a decode step), 32 once the mean group
-    passes a tile (a prefill slice), so the MXU's passes are not mostly pad."""
-    return 16 if rows <= 16 * e else 32
+    while an expert sees a few rows (a decode step: the block-dot walk, one
+    grid step a touched expert); once the mean group passes a tile (a prefill
+    slice: the dequantising walk), the power of two from 32 up that holds the
+    mean group and a third more, so that nearly every expert is ONE tile: a
+    second tile of an expert dequantises its weight again (a 512-row slice's
+    48 rows an expert in tiles of 64 read 245 / 212 us on SmallThinker's two
+    projection shapes where tiles of 32 read 418 / 383 and tiles of 128
+    309 / 276; `kbench.py expert`, my chip run, PR 39)."""
+    if rows <= 16 * e:
+        return 16
+    return next(t for t in (32, 64, 128, 256) if 3 * t * e >= 4 * rows or t == 256)
 
 
 def expert_groups(topi: jax.Array, e: int, tm: int):
@@ -290,7 +298,8 @@ def moe_ffn(
         from dllama_tpu.ops.matmul import device_platform
         from dllama_tpu.ops.pallas.q40_matmul import q40_expert_matmul
 
-        tm = expert_tile_rows(n * k, e)
+        # (of a share's routed rows, the held experts' part is expected here)
+        tm = expert_tile_rows(n * k if mine is None else n * k * e // cfg.n_experts, e)
         src, pos, tile_expert, tile_src, n_live, sizes = expert_groups(
             topi.reshape(n, k), e, tm)
         xs = h.reshape(n, d)[src]  # [T*tm, D] rows in padded expert order

@@ -52,14 +52,45 @@ Two TPU-specific design points beyond the reference's scheme:
      under 256 KB of packed bytes costs more than it moves (PR 32 measured
      64 KB steps at 22% of the byte roofline).
 
+3. **The grouped expert call has a tile walk of its own** (`_expert_call`:
+   rows in expert order against the stacked experts ``[L, E, k/2, n]``, the
+   expert of a rows tile scalar-prefetched beside the layer). The arithmetic
+   is the two tiers'; what differs is what a tile pays beyond its expert's
+   bytes, because a decode step's tile holds 1-3 real rows against a 1.1-1.3
+   MB expert where a dense call holds 16 against 9-26 MB (priced part by
+   part on the chip, PERF.md section 6, PR 39: the dense body a tile read
+   3.3-4.3 us a touched expert of which 1.4 were the bytes):
+
+   * the grid is ``(n_live, n tiles)``, its first bound the traced count of
+     tiles that hold a row: a dead tile costs nothing (a frozen grid step
+     cost 0.3-0.5 us, 20-44 of them a call), and its rows are never written;
+   * a grid step holds the WHOLE depth of an expert, the whole expert where
+     4 MB of packed bytes hold it (`_expert_inner`): one step a touched
+     expert, not two or three, the next expert's copy running behind it;
+     two tiles of one expert follow each other and the second finds the
+     weight's block in place;
+   * a 16-row tile (`_expert_kernel`) runs the block-dot arithmetic in one
+     pass of the whole depth and as many columns as `_EXPERT_PASS_WEIGHTS`
+     allows (a 768 x 2560 expert was ten passes of 0.2 M weights, each
+     waiting for its MXU results), writes its result once (nothing zeroed,
+     nothing accumulated across steps), lays its rows out with two dots
+     against 0/1 matrices the call builds ONCE in VMEM (`_expert_constants`;
+     rebuilt a tile they were 0.1-0.3 us of vector work), and zeroes the
+     scales scratch's spare rows once a call;
+   * a taller tile (`_expert_deq_kernel`: a prefill slice, tiles of 32 rows
+     and more by `ops/layers.expert_tile_rows`) runs the dequantising
+     tier's body, m rows through the MXU a pass where the block-dot body
+     streams 4 m. The choice is static (by the tile's height alone).
+
 Layout (see ops/quant.QTensor): ``packed: u8[(L,) k/2, n]`` where packed row
 ``16*b + j`` holds codes for input dims ``32*b + j`` (low nibble) and
 ``32*b + j + 16`` (high nibble); ``scales: f16[(L,) k/32, n]`` (streamed as
 raw u16 bits, widened in-register by ``_scales_f32``).
 
-Grid is ((m_tiles,) n_tiles, k_tiles) with k innermost: the f32 result
-block stays VMEM-resident across the k sweep and is written back once per
-(m, n) tile. Inputs are double-buffered by the Pallas pipeline automatically.
+Grid of the dense calls is ((m_tiles,) n_tiles, k_tiles) with k innermost:
+the f32 result block stays VMEM-resident across the k sweep and is written
+back once per (m, n) tile. Inputs are double-buffered by the Pallas pipeline
+automatically.
 """
 
 from __future__ import annotations
@@ -179,10 +210,31 @@ def _deq_dot(xa, w):
 def _deq_kernel(layer_ref, x_ref, packed_ref, scales_ref, out_ref, xa_ref, s_ref,
                 *, rows):
     """Grid step (i, j, kb): rows tile i of x against tile (kb, j) of one
-    layer's weight, dequantised `rows` k rows at a time as whole-array ops
-    and fed to one plain dot a pass (m rows through the MXU, not 4 m)."""
+    layer's weight."""
     del layer_ref  # consumed by the index maps
-    j, kb = pl.program_id(1), pl.program_id(2)
+    _deq_body(pl.program_id(1), pl.program_id(2), x_ref, packed_ref, scales_ref,
+              out_ref, xa_ref, s_ref, rows=rows)
+
+
+def _expert_deq_kernel(layer_ref, expert_ref, src_ref, live_ref, x_ref, packed_ref,
+                       scales_ref, out_ref, xa_ref, s_ref, *, rows):
+    """Grid step (t, j) of the grouped expert call where a tile is taller
+    than 16 rows (a prefill slice): rows tile t against the whole depth of
+    columns tile j of that tile's expert, through the dequantising tier's
+    body (m rows through the MXU a pass, where the block-dot body streams
+    4 m: from 32 rows a tile on that binds, PR 37). Its needs are the dense
+    call's: x laid out once a rows tile, at the tile's first step."""
+    del layer_ref, expert_ref, src_ref, live_ref  # the index maps' and the grid's
+    _deq_body(pl.program_id(1), 0, x_ref, packed_ref, scales_ref, out_ref, xa_ref,
+              s_ref, rows=rows)
+
+
+def _deq_body(j, kb, x_ref, packed_ref, scales_ref, out_ref, xa_ref, s_ref, *, rows):
+    """Step (j, kb) of a rows tile of x against tile (kb, j) of one Q40
+    weight, dequantised `rows` k rows at a time as whole-array ops and fed
+    to one plain dot a pass (m rows through the MXU, not 4 m): what
+    `_deq_kernel` (one weight a call) and `_expert_deq_kernel` (one expert
+    a tile of rows) both run."""
     tm, k = x_ref.shape
     tk = 2 * packed_ref.shape[0]
     nb = tk // Q_BLOCK
@@ -265,16 +317,25 @@ def _layout_constants(groups: int):
     block's lanes, the four blocks side by side); `sums[g, src, 4*g + b]`
     adds dim src of group g into lane 4*g + b of its chunk's row of block
     sums."""
-    src = jax.lax.broadcasted_iota(jnp.int32, (_GROUP, 4 * _GROUP), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (_GROUP, 4 * _GROUP), 1)
-    place = ((col & 127) == _position(src)) & ((col >> 7) == (src >> 5))
+    place = _place_mask()
     g = jax.lax.broadcasted_iota(jnp.int32, (groups, _GROUP, _GROUP), 0)
     src = jax.lax.broadcasted_iota(jnp.int32, (groups, _GROUP, _GROUP), 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, (groups, _GROUP, _GROUP), 2)
     sums = lane == 4 * g + (src >> 5)
-    # (selected as f32, then narrowed: a mask has the 32-bit layout)
-    as_bf16 = lambda mask: jnp.where(mask, 1.0, 0.0).astype(jnp.bfloat16)
-    return as_bf16(place), as_bf16(sums)
+    return _as_bf16(place), _as_bf16(sums)
+
+
+def _place_mask():
+    """`place` of `_layout_constants` as a mask [128, 512]."""
+    src = jax.lax.broadcasted_iota(jnp.int32, (_GROUP, 4 * _GROUP), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (_GROUP, 4 * _GROUP), 1)
+    return ((col & 127) == _position(src)) & ((col >> 7) == (src >> 5))
+
+
+def _as_bf16(mask):
+    """A mask as 0 / 1 in bf16 (selected as f32, then narrowed: a mask has
+    the 32-bit layout)."""
+    return jnp.where(mask, 1.0, 0.0).astype(jnp.bfloat16)
 
 
 def _group_dot(xa, codes):
@@ -308,37 +369,10 @@ def _blockdot_kernel(
     layer_ref, x_ref, packed_ref, scales_ref, out_ref, xa_ref, xs_ref, s_ref,
     *, tk, tn, lanes, rows
 ):
-    del layer_ref
-    _blockdot_body(pl.program_id(0), pl.program_id(1), x_ref, packed_ref,
-                   scales_ref, out_ref, xa_ref, xs_ref, s_ref,
-                   tk=tk, tn=tn, lanes=lanes, rows=rows)
-
-
-def _expert_kernel(
-    layer_ref, expert_ref, src_ref, live_ref, x_ref, packed_ref, scales_ref,
-    out_ref, xa_ref, xs_ref, s_ref, *, tk, tn, lanes, rows
-):
-    """The block-dot tier with an expert index beside the layer index: grid
-    step (t, j, kb) is tile t of the rows in expert order against tile
-    (kb, j) of that tile's expert. The inner loop is `_blockdot_body`'s; x
-    is laid out again at each tile's first step (its rows are another
-    expert's). Tiles behind the last live one do nothing and, their block
-    indices frozen by the index maps, move nothing."""
-    del layer_ref, expert_ref, src_ref
-    t, j, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when(t < live_ref[0])
-    def _():
-        _blockdot_body(j, kb, x_ref, packed_ref,
-                       scales_ref, out_ref, xa_ref, xs_ref, s_ref,
-                       tk=tk, tn=tn, lanes=lanes, rows=rows)
-
-
-def _blockdot_body(j, kb, x_ref, packed_ref, scales_ref, out_ref, xa_ref,
-                   xs_ref, s_ref, *, tk, tn, lanes, rows):
-    """Grid step (j, kb) of x[m, k] against one Q40 weight: what
-    `_blockdot_kernel` (one weight a call) and `_expert_kernel` (one weight a
-    tile of rows) both run."""
+    """Grid step (j, kb) of x[m, k] against tile (kb, j) of one layer's Q40
+    weight."""
+    del layer_ref  # consumed by the index maps
+    j, kb = pl.program_id(0), pl.program_id(1)
     m = out_ref.shape[0]
     nb = tk // Q_BLOCK
     per_chunk = min(_CHUNK, xa_ref.shape[0])  # groups a chunk
@@ -416,6 +450,87 @@ def _blockdot_body(j, kb, x_ref, packed_ref, scales_ref, out_ref, xa_ref,
                   for c in range(chunks))
         off = off[0:m] + off[m:2 * m] + off[2 * m:3 * m]
         out_ref[:, pl.ds(l0, lanes)] += acc - _W_OFFSET * off
+        return carry
+
+    jax.lax.fori_loop(0, tn // lanes, lane_step, 0)
+
+
+def _expert_constants(k: int, nbp: int):
+    """The 0/1 matrices that lay a tile's rows out, through the MXU (exact:
+    one 1 a column): `place` as `_layout_constants` gives it, and
+    `sums[src, b]`, which adds input dim src into its Q40 block's lane b of
+    the row of block sums (nbp lanes: whole 128-lane tiles)."""
+    src = jax.lax.broadcasted_iota(jnp.int32, (k, nbp), 0)
+    blk = jax.lax.broadcasted_iota(jnp.int32, (k, nbp), 1)
+    return _as_bf16(_place_mask()), _as_bf16(blk == (src >> 5))
+
+
+def _lay_rows(x_ref, place_ref, sums_ref, xa_ref, xs_ref):
+    """A tile's rows for the grouped expert kernel, two dots against the 0/1
+    matrices the call keeps in VMEM: x in the kernel's row order and masked
+    to each Q40 block's lanes, the four blocks stacked along rows (one
+    128-deep pass then gives the four blocks' partial dots), and x's block
+    sums as three bf16 parts (and a zero one, to whole tiles) for the codes'
+    offset."""
+    m, k = x_ref.shape
+    groups = k // _GROUP
+    xc = jnp.concatenate(
+        [x_ref[:, pl.ds(g * _GROUP, _GROUP)] for g in range(groups)], axis=0)
+    z = jnp.dot(xc, place_ref[:], preferred_element_type=jnp.float32)
+    xa_ref[:] = jnp.concatenate(
+        [z[:, _GROUP * b:_GROUP * (b + 1)].reshape(groups, m, _GROUP)
+         for b in range(4)], axis=1).astype(xa_ref.dtype)  # [groups, 4m, 128]
+    xsum = jnp.dot(x_ref[:], sums_ref[:], preferred_element_type=jnp.float32)
+    parts = _bf16_parts(xsum, 3) + [jnp.zeros_like(xsum)]
+    xs_ref[:] = jnp.concatenate(parts, axis=0).astype(xs_ref.dtype)
+
+
+def _expert_kernel(
+    layer_ref, expert_ref, src_ref, live_ref, x_ref, packed_ref, scales_ref,
+    out_ref, place_ref, sums_ref, xa_ref, xs_ref, s_ref, *, lanes
+):
+    """Grid step (t, j) of the grouped expert call at 16 rows a tile: tile t
+    of the rows in expert order against the WHOLE depth of columns tile j of
+    that tile's expert (a 1-1.3 MB expert is one step: j has one value). The
+    arithmetic is the block-dot tier's (`_unpack_words`, `_group_dot`,
+    `_scaled`, the offset's exact dot); the walk is this kernel's own (the
+    module's docstring, point 3, says what a tile of 1-3 real rows paid in
+    the dense call's body): the 0/1 layout matrices and the scales scratch's
+    zero rows are built at the call's first step and kept; a tile lays its
+    rows out at its first step (`_lay_rows`); a pass covers the whole depth
+    and `lanes` columns; the result is written once."""
+    del layer_ref, expert_ref, src_ref, live_ref  # the index maps' and the grid's
+    t, j = pl.program_id(0), pl.program_id(1)
+    m, k = x_ref.shape
+    tn = out_ref.shape[1]
+    groups, nb = k // _GROUP, k // Q_BLOCK
+
+    @pl.when((t == 0) & (j == 0))
+    def _():
+        place_ref[:], sums_ref[:] = _expert_constants(*sums_ref.shape)
+        if s_ref.shape[0] != nb:  # rows past the blocks meet zero sums
+            s_ref[:] = jnp.zeros_like(s_ref)
+
+    @pl.when(j == 0)
+    def _():  # once a tile: its rows are another expert's
+        _lay_rows(x_ref, place_ref, sums_ref, xa_ref, xs_ref)
+
+    s_ref[0:nb, :] = _scales_f32(scales_ref[:])
+
+    def lane_step(c, carry):
+        l0 = pl.multiple_of(c * lanes, 128)
+        w = pltpu.bitcast(packed_ref[:, pl.ds(l0, lanes)], jnp.uint32)
+        codes = jnp.concatenate(
+            [v.reshape(groups, 32, lanes) for v in _unpack_words(w)], axis=1)
+        y = _group_dot(xa_ref[:], codes)
+        acc = _scaled(y.reshape(nb, m, lanes), s_ref[0:nb, pl.ds(l0, lanes)])
+        # the codes read 16 + q: take 24 * sum_b xsum[b] * s[b] off, exactly
+        # (an f16 scale is two bf16 parts, a block sum three; f32 sums)
+        off = sum(jnp.dot(xs_ref[:], part.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+                  for part in _bf16_parts(s_ref[:, pl.ds(l0, lanes)], 2))
+        off = off[0:m] + off[m:2 * m] + off[2 * m:3 * m]
+        out_ref[:, pl.ds(l0, lanes)] = acc - _W_OFFSET * off
         return carry
 
     jax.lax.fori_loop(0, tn // lanes, lane_step, 0)
@@ -586,14 +701,13 @@ def _deq_call(layer, x, packed, scales, *, interpret: bool = False,
     )(layer, x, packed, scales)
 
 
-def _x_and_scratch(x, tk: int, tn: int, m: int | None = None):
-    """What `_blockdot_body` works on besides the weight, for rows of `m`
-    (default: all of x's): x as it goes in (padded to whole chunks of 4096
-    dims where k has a part chunk: the kernel lays it out itself at a
-    tile's first grid step) and the three VMEM scratches: x by group and
-    block, x's block sums, the tile's scales as f32 in whole chunks."""
-    m = m or x.shape[0]
-    k = x.shape[1]
+def _x_and_scratch(x, tk: int, tn: int):
+    """What `_blockdot_kernel` works on besides the weight: x as it goes in
+    (padded to whole chunks of 4096 dims where k has a part chunk: the
+    kernel lays it out itself at its first grid step) and the three VMEM
+    scratches: x by group and block, x's block sums, the tile's scales as
+    f32 in whole chunks."""
+    m, k = x.shape
     groups = k // _GROUP
     per_chunk = min(_CHUNK, groups)
     chunks = -(-groups // per_chunk)
@@ -657,50 +771,95 @@ def _blockdot_call(layer, x, packed, scales, *, interpret: bool = False,
     )(layer, x, packed, scales)
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+#: packed bytes of an expert's columns tile a grid step holds at most (two
+#: buffers of it, the scales and a pass's values stay far inside VMEM)
+_EXPERT_STEP_BYTES = 4 * 1024 * 1024
+#: weights a pass of the expert kernel's loop covers at most: one pass for
+#: Kimi-Linear's 2304 x 1024 read 125 us a call where two read 139
+#: (`kbench.py expert`, my chip run, PR 39)
+_EXPERT_PASS_WEIGHTS = 9 << 18
+
+
+@functools.lru_cache(maxsize=None)
+def _expert_inner(k: int, n: int) -> tuple[int, int]:
+    """(tn, lanes) of the grouped expert kernel from an expert's shape alone:
+    a grid step takes the whole depth and the widest columns tile (a
+    multiple of 128 dividing n) within `_EXPERT_STEP_BYTES`, the whole
+    expert where it fits; a pass of the inner loop the whole depth and the
+    widest part of the tile that keeps it within `_EXPERT_PASS_WEIGHTS`."""
+    widths = [w for w in range(n, 0, -128) if n % w == 0]
+    tn = next((w for w in widths if k * w // 2 <= _EXPERT_STEP_BYTES), 128)
+    lanes = next((w for w in widths if tn % w == 0 and k * w <= _EXPERT_PASS_WEIGHTS), 128)
+    return tn, lanes
+
+
+@functools.lru_cache(maxsize=None)
+def _expert_deq_tn(k: int, n: int) -> int:
+    """The columns tile of the grouped expert kernel's tall tiles: the widest
+    (a multiple of 128 dividing n) whose whole depth one pass of
+    `_DEQ_PASS_WEIGHTS` dequantises, the whole expert where it fits; a depth
+    no pass holds at 128 columns is walked by `_deq_pass`' steps."""
+    return next((w for w in range(n, 0, -128)
+                 if n % w == 0 and k * w <= _DEQ_PASS_WEIGHTS), 128)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret", "tn", "lanes"))
 def _expert_call(layer, tile_expert, tile_src, n_live, x, packed, scales, *,
-                 tm: int, interpret: bool = False):
+                 tm: int, interpret: bool = False, tn: int | None = None,
+                 lanes: int | None = None):
     """bf16 x[T*tm, k], rows in expert order and padded to whole tiles of tm,
     against the stacked experts packed u8[L, E, k/2, n] -> f32[T*tm, n]: tile t
-    meets expert tile_expert[t] of layer `layer`. The name and the 4-D packed
-    operand are what the benchmark's trace reader finds this call by
-    (benchmark/costs/moe_experts.py)."""
+    meets expert tile_expert[t] of layer `layer`, for the n_live tiles that
+    hold a row (the grid's first bound: the rows of the tiles behind them
+    are never written). The name and the 4-D packed operand are what the
+    benchmark's trace reader finds this call by
+    (benchmark/costs/moe_experts.py). tn / lanes are the chip sweep's
+    overrides (`experiments/kbench.py expert`); serving passes none and
+    runs `_expert_inner` / `_expert_deq_tn`."""
     rows_total, k = x.shape
     n = packed.shape[-1]
     tiles = rows_total // tm
-    tk, tn = _blockdot_tiles(k, n)
-    assert tm % 16 == 0 and k % _SUB_K == 0 and (tk == k or tk % (_CHUNK * _GROUP) == 0)
-    nb = tk // Q_BLOCK
-    lanes, rows = _inner(tk, tn)
-    x, scratch = _x_and_scratch(x, tk, tn, m=tm)
-    kp = x.shape[1]
-    nj, nkb = n // tn, k // tk
-
-    def weight_map(t, j, kb, L, E, S, N):
-        # a dead tile keeps the last live step's block: nothing is copied
-        dead = t >= N[0]
-        return (L[0], E[t], jnp.where(dead, nkb - 1, kb),
-                jnp.where(dead, nj - 1, j))
-
+    nb = k // Q_BLOCK
+    assert tm % 16 == 0 and k % _SUB_K == 0
+    if tm > 16:  # a slice's tiles: the dequantising body (static: by tm alone)
+        tn = tn or _expert_deq_tn(k, n)
+        kernel = functools.partial(_expert_deq_kernel, rows=_deq_pass(k, tn))
+        scratch = [pltpu.VMEM((tm, k), x.dtype),  # x in the planes' row order
+                   pltpu.VMEM((-(-nb // 8) * 8, tn), jnp.float32)]  # the tile's scales
+    else:
+        dtn, dlanes = _expert_inner(k, n)
+        tn = tn or dtn
+        lanes = lanes or (dlanes if tn % dlanes == 0 else tn)
+        assert tn % lanes == 0
+        nbp = -(-nb // _GROUP) * _GROUP
+        kernel = functools.partial(_expert_kernel, lanes=lanes)
+        scratch = [
+            pltpu.VMEM((_GROUP, 4 * _GROUP), x.dtype),  # place
+            pltpu.VMEM((k, nbp), x.dtype),  # sums
+            pltpu.VMEM((k // _GROUP, 4 * tm, _GROUP), x.dtype),  # x by group and block
+            pltpu.VMEM((4 * tm, nbp), x.dtype),  # x's block sums, in parts
+            pltpu.VMEM((nbp, tn), jnp.float32),  # the tile's scales
+        ]
+    assert n % tn == 0 and tn % 128 == 0
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # layer[1], tile_expert[T], tile_src[T], n_live[1]
-        grid=(tiles, nj, nkb),
+        grid=(n_live[0], n // tn),
         in_specs=[
-            pl.BlockSpec((tm, kp), lambda t, j, kb, L, E, S, N: (S[t], 0)),
-            pl.BlockSpec((None, None, tk // 2, tn), weight_map),
-            pl.BlockSpec((None, None, nb, tn), weight_map),
+            pl.BlockSpec((tm, k), lambda t, j, L, E, S, N: (S[t], 0)),
+            pl.BlockSpec((None, None, k // 2, tn), lambda t, j, L, E, S, N: (L[0], E[t], 0, j)),
+            pl.BlockSpec((None, None, nb, tn), lambda t, j, L, E, S, N: (L[0], E[t], 0, j)),
         ],
-        out_specs=pl.BlockSpec(
-            (tm, tn), lambda t, j, kb, L, E, S, N: (
-                S[t], jnp.where(t >= N[0], nj - 1, j))),
+        out_specs=pl.BlockSpec((tm, tn), lambda t, j, L, E, S, N: (S[t], j)),
         scratch_shapes=scratch,
     )
     return pl.pallas_call(
-        functools.partial(_expert_kernel, tk=tk, tn=tn, lanes=lanes, rows=rows),
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows_total, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            # the call's first step builds what every later one reads, a
+            # tile's first step what its later ones read
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=96 * 1024 * 1024,
         ),
         cost_estimate=pl.CostEstimate(

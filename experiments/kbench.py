@@ -4,6 +4,7 @@ Usage: python experiments/kbench.py suite
        python experiments/kbench.py paged
        python experiments/kbench.py q40 [--no-tiles]
        python experiments/kbench.py deq [--no-tiles] [--parent]
+       python experiments/kbench.py expert [--no-tiles]
        python experiments/kbench.py M SHAPE [variant ...]
 'suite' benches the decode variants (m=8 on w1/wcls) and the prefill tier
 comparison (m=256/512: in-kernel deq vs XLA dequant-dot) in one process.
@@ -19,7 +20,13 @@ the kernel as it is and with each part taken out, the (tk, tn) tile sweep
 part taken out, the block-dot kernel at the same rows, the (tk, tn, rows a
 pass) sweep; --parent adds PR 36's byte-wise body whole, in parts and over its
 tiles (the yardstick PR 37 rebuilt the tier against).
-'suite --smoke' (and 'paged --smoke', 'q40 --smoke', 'deq --smoke') runs the
+'expert' times the grouped Q40 expert kernel (`_expert_call`) alone at the two
+expert cells' shapes and fills (SmallThinker's decode step and 512-row slice,
+Kimi-Linear's held share of a decode step): parity against dequantise-then-dot
+of each tile's expert, the kernel as it is and with each part taken out, every
+tile live against the cell's dead ones, the (tn, lanes) sweep (--no-tiles
+leaves it out), other tile heights for the slice.
+'suite --smoke' (and 'paged --smoke', 'q40 --smoke', 'deq --smoke', 'expert --smoke') runs the
 same code path on CPU (interpret-mode Pallas, tiny shapes, 2 iters) so CI proves the harness cannot crash on the chip; smoke
 numbers are meaningless, only completion matters.
   variants: A  production dispatch (q40_matmul: blockdot for m<=16, deq above)
@@ -266,6 +273,13 @@ def enable_smoke():
     Q40_CELLS = {"tiny": {"stacked": (8192, 256, 2), "head": (256, 384, 1)}}
     Q40_SWEEP_TK, Q40_SWEEP_TN, Q40_SWEEP_LANES = (4096, None), (128, -1), (128,)
     Q40_SWEEP_ROWS = (256, None)
+    global EXPERT_CELLS, EXPERT_CALLS, EXPERT_SWEEP_LANES
+    EXPERT_CALLS, EXPERT_SWEEP_LANES = 2, (128, -1)
+    EXPERT_CELLS = {
+        "tiny": dict(held=4, routed=4, active=2, layers=2, shapes=((512, 256), (256, 512)),
+                     fills={"decode": (3, (16,)), "slice": (40, (64, 32))}),
+        "tiny share": dict(held=4, routed=8, active=3, layers=2, shapes=((256, 256),),
+                           fills={"decode": (6, (16,))})}
     PAGED_CALLS = 2
     PAGED_CELLS = {
         "tiny mha": dict(slots=2, hq=4, hkv=4, hd=64, page=16, layers=2,
@@ -557,9 +571,11 @@ def _q40_ablations():
 def _swapped(call, patch):
     """`q40_matmul`'s helpers swapped (name -> stand-in) for one row's trace
     of the jitted `call`, and put back."""
-    saved = {name: getattr(qmod, name) for name in patch or {}}
+    # (a helper another form of the kernel has is left alone)
+    patch = {name: fn for name, fn in (patch or {}).items() if hasattr(qmod, name)}
+    saved = {name: getattr(qmod, name) for name in patch}
     try:
-        for name, fn in (patch or {}).items():
+        for name, fn in patch.items():
             setattr(qmod, name, fn)
         call.clear_cache()
         yield
@@ -848,6 +864,203 @@ def bench_deq(shapes=None, tiles=True, parent=False):
             del data
 
 
+# --------------------------------------------------------------- expert mode
+#: The grouped expert calls of the two expert cells (PERF.md section 4):
+#: name -> experts held, experts the router chooses among, choices a token,
+#: layers, the two projection shapes (k, n), and the fills: tag -> (tokens a
+#: call, tile heights; the first is `expert_tile_rows`' choice).
+EXPERT_CELLS = {
+    "smallthinker.long_decode_closed": dict(
+        held=64, routed=64, active=6, layers=24, shapes=((2560, 768), (768, 2560)),
+        fills={"decode": (16, (16,)), "slice": (512, (64, 32, 128))}),
+    "kimilinear.reason_closed": dict(
+        held=64, routed=256, active=8, layers=26, shapes=((2304, 1024), (1024, 2304)),
+        fills={"decode": (48, (16,))}),
+}
+EXPERT_CALLS = 240
+EXPERT_SWEEP_LANES = (128, 256, 512, -2, -1)  # -d = tn / d
+EXPERT_SWEEP_TN = (-2, -1)  # -d = n / d
+
+
+def expert_inputs(cell, k, n, tokens, tm, seed=0):
+    """One projection's stacked experts (random nibbles and f16 scales made
+    ON the device: 1.5-2 GB a stack) and, a layer, the rows in expert order
+    as `ops/layers.expert_groups` lays them out for `tokens` tokens routed
+    uniformly (distinct experts a token; a choice past the held ones is
+    another chip's). Returns (x [T*tm, k], packed, scales, tile maps
+    [layers, T], n_live [layers], touched and rows a call); every layer's
+    call reads the same rows (cutting a layer's x out of a stack inside the
+    scan would be a copy of it a call: 37 MB for a slice)."""
+    from dllama_tpu.ops.layers import expert_groups
+
+    held, routed, active, layers = (cell[key] for key in ("held", "routed", "active", "layers"))
+    kp, ks, kx = jax.random.split(jax.random.PRNGKey(seed), 3)
+    # (32-bit words read as bytes: drawing 1.5 G bytes one by one would pass
+    # through 6 GB of words)
+    packed = jax.lax.bitcast_convert_type(
+        jax.random.bits(kp, (layers, held, k // 2, n // 4), jnp.uint32),
+        jnp.uint8).reshape(layers, held, k // 2, n)
+    scales = jax.lax.bitcast_convert_type(
+        jax.random.uniform(ks, (layers, held, k // Q_BLOCK, n), jnp.float32, 1e-3, 2e-2
+                           ).astype(jnp.float16), jnp.uint16)
+    rng = np.random.default_rng(seed)
+    topi = np.stack([np.stack([rng.permutation(routed)[:active] for _ in range(tokens)])
+                     for _ in range(layers)])
+    topi = jnp.asarray(np.where(topi < held, topi, held), jnp.int32)
+    src, _, tile_expert, tile_src, n_live, sizes = jax.vmap(
+        lambda t: expert_groups(t, held, tm))(topi)
+    x = jax.random.normal(kx, (src.shape[1], k), jnp.float32).astype(jnp.bfloat16)
+    touched = float(jnp.count_nonzero(sizes, axis=1).mean())
+    rows = float(sizes.sum(axis=1).mean())
+    return x, packed, scales, tile_expert, tile_src, n_live, touched, rows
+
+
+def expert_loop_us(call, data, calls=None, live=None):
+    """us a call of `call(layer[1], tile_expert, tile_src, n_live[1], x,
+    packed, scales)` over `calls` calls in ONE jitted scan, the layer (and
+    its routing) cycling; `live` overrides every layer's n_live. The best of
+    two timed runs."""
+    calls = calls or EXPERT_CALLS
+    x, packed, scales, tile_expert, tile_src, n_live = data[:6]
+    layers = packed.shape[0]
+    if live is not None:
+        n_live = jnp.full_like(n_live, live)
+
+    @jax.jit
+    def loop(x, packed, scales):
+        def step(acc, i):
+            li = i % layers
+            out = call(li.reshape(1), tile_expert[li], tile_src[li],
+                       n_live[li].reshape(1), x, packed, scales)
+            return acc + out[0, 0], None
+        return jax.lax.scan(step, jnp.float32(0), jnp.arange(calls, dtype=jnp.int32))[0]
+
+    jax.block_until_ready(loop(x, packed, scales))
+    best = float("inf")
+    for _ in range(2):
+        t0 = time.perf_counter()
+        jax.block_until_ready(loop(x, packed, scales))
+        best = min(best, time.perf_counter() - t0)
+    return best / calls * 1e6
+
+
+def expert_parity(call, data, tm):
+    """Largest difference of a layer's live tiles from the float32
+    dequantise-then-dot of each tile's expert, as a share of the largest
+    value."""
+    from dllama_tpu.ops.quant import QTensor
+
+    x, packed, scales, tile_expert, tile_src, n_live = data[:6]
+    li = packed.shape[0] // 2
+    got = call(jnp.full((1,), li, jnp.int32), tile_expert[li], tile_src[li],
+               n_live[li].reshape(1), x, packed, scales)
+    worst, top = 0.0, 0.0
+    for t in range(int(n_live[li])):
+        e = int(tile_expert[li, t])
+        w = QTensor(packed[li, e], jax.lax.bitcast_convert_type(
+            scales[li, e], jnp.float16)).dequantize(jnp.float32)
+        want = jnp.dot(x[t * tm:(t + 1) * tm].astype(jnp.float32), w, precision="highest")
+        worst = max(worst, float(jnp.abs(got[t * tm:(t + 1) * tm] - want).max()))
+        top = max(top, float(jnp.abs(want).max()))
+    return worst / top
+
+
+def _expert_ablations():
+    """`_q40_ablations`' rows (the arithmetic's helpers are shared) and the
+    tile walk's own: the layout's 0/1 matrices swapped for constants nobody
+    computes, and every tile's rows laid out by dots that move nothing."""
+    rows = dict(_q40_ablations())
+    ones = lambda *shape: jnp.ones(shape, jnp.bfloat16)
+    rows["layout matrices not computed (ones)"] = {
+        "_layout_constants": lambda groups: (ones(128, 512), ones(groups, 128, 128)),
+        "_expert_constants": lambda k, nbp: (ones(128, 512), ones(k, nbp))}
+    rows["rows laid out at the call's first tile only"] = (
+        {"_expert_kernel": _pr38_kernel_laid_once} if hasattr(qmod, "_blockdot_body")
+        else {"_lay_rows": lambda *refs: None})
+    return rows
+
+
+def _pr38_kernel_laid_once(layer_ref, expert_ref, src_ref, live_ref, x_ref, packed_ref,
+                           scales_ref, out_ref, xa_ref, xs_ref, s_ref, **tiles):
+    """PR 38's `_expert_kernel` (every tile runs the dense call's body, which
+    lays x out where j = kb = 0) with the layout left to the first tile: for
+    pricing that form from a checkout that still has it."""
+    t, j, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(t < live_ref[0])
+    def _():
+        qmod._blockdot_body(j + (t > 0).astype(jnp.int32), kb, x_ref, packed_ref,
+                            scales_ref, out_ref, xa_ref, xs_ref, s_ref, **tiles)
+
+
+def _expert_row(tag, data, k, n, patch=None, live=None, **kw):
+    """One timed row beside what `moe_expert_roofline` prices the call at:
+    the touched experts' packed bytes and the rows in and out over 819 GB/s
+    (`benchmark/costs/moe_experts.cost`)."""
+    from benchmark.costs.moe_experts import cost
+
+    touched, rows = data[6:8]
+    call = lambda *a: qmod._expert_call(*a, interpret=INTERPRET, **kw)
+    try:
+        with _swapped(qmod._expert_call, patch):
+            us = expert_loop_us(call, data, live=live)
+        floor = cost(touched, rows, k, n)[1] / HBM_GBS / 1e3
+        print(f"expert {tag}: {us:.2f} us a call, {100 * floor / us:.1f}% of the byte "
+              f"roofline ({floor:.2f} us), {us / touched:.3f} us a touched expert")
+        return us
+    except Exception as e:
+        print(f"expert {tag}: FAILED {e!r}"[:300])
+    finally:
+        sys.stdout.flush()
+
+
+def bench_expert(cells=None, tiles=True):
+    """The grouped Q40 expert kernel alone on the chip at the expert cells'
+    real shapes and fills, EXPERT_CALLS calls in one jitted scan over the
+    layer-stacked expert stacks, the layer and its routing cycling. A
+    layer's three projections x the layers is what a decode step spends in
+    `_expert_call` (PERF.md section 6, PR 39)."""
+    for cell, c in (cells or EXPERT_CELLS).items():
+        for k, n in c["shapes"]:
+            for fill, (tokens, heights) in c["fills"].items():
+                for tm in heights:
+                    data = expert_inputs(c, k, n, tokens, tm)
+                    tag = f"{cell} {k}x{n} {fill} tm={tm}"
+                    t_all = data[3].shape[1]
+                    live = float(data[5].mean())
+                    kw = {"tm": tm}
+                    call = lambda *a: qmod._expert_call(*a, interpret=INTERPRET, **kw)
+                    print(f"expert {tag}: {tokens} tokens, {data[7]:.1f} rows over "
+                          f"{data[6]:.1f} touched of {c['held']} experts, {live:.1f} live of "
+                          f"{t_all} tiles, parity {expert_parity(call, data, tm):.2e} of the "
+                          f"largest value")
+                    _expert_row(f"{tag} as it is", data, k, n, **kw)
+                    if tm != heights[0]:
+                        continue
+                    # a tall tile runs the dequantising tier's body
+                    parts = _deq_ablations() if tm > 16 and hasattr(
+                        qmod, "_expert_deq_kernel") else _expert_ablations()
+                    for label, patch in parts.items():
+                        if patch:
+                            _expert_row(f"{tag} {label}", data, k, n, patch, **kw)
+                    _expert_row(f"{tag} every tile live ({t_all})", data, k, n,
+                                live=t_all, **kw)
+                    _expert_row(f"{tag} one tile live", data, k, n, live=1, **kw)
+                    seen = set()
+                    for tn in EXPERT_SWEEP_TN if tiles and hasattr(qmod, "_expert_inner") else ():
+                        for lanes in EXPERT_SWEEP_LANES:
+                            tn_ = n // -tn if tn < 0 else tn
+                            lanes_ = tn_ // -lanes if lanes < 0 else lanes
+                            if (n % tn_ or tn_ % 128 or tn_ % lanes_ or lanes_ % 128
+                                    or (tn_, lanes_) in seen
+                                    or (tn_, lanes_) == qmod._expert_inner(k, n)):
+                                continue
+                            seen.add((tn_, lanes_))
+                            _expert_row(f"{tag} sweep tn={tn_} lanes={lanes_}", data, k, n,
+                                        tn=tn_, lanes=lanes_, **kw)
+                    del data
+
+
 Q40_SWEEP_TK = (4096, 8192, None)  # None = the whole of k
 Q40_SWEEP_TN = (256, 512, 1024, 2048, -2, -1)  # -d = n / d
 Q40_SWEEP_LANES = (256, 512)
@@ -860,6 +1073,7 @@ def main():
     # 'paged [--smoke]' (the paged decode call of each benchmark cell) |
     # 'q40 [--smoke] [--no-tiles]' (the block-dot kernel at the cells' shapes) |
     # 'deq [--smoke] [--no-tiles] [--parent]' (the dequantising tier, m > 16) |
+    # 'expert [--smoke] [--no-tiles]' (the grouped expert kernel at the cells' fills) |
     # M SHAPE [variant ...] — suite runs the whole decode + prefill matrix in
     # ONE process (one device init, not six). --no-flash skips the flash
     # section; the q40 rows still land.
@@ -882,6 +1096,10 @@ def main():
         return
     if sys.argv[1:2] == ["q40"]:
         bench_q40(tiles="--no-tiles" not in sys.argv)
+        print("KBENCH DONE")
+        return
+    if sys.argv[1:2] == ["expert"]:
+        bench_expert(tiles="--no-tiles" not in sys.argv)
         print("KBENCH DONE")
         return
     if sys.argv[1:2] == ["deq"]:

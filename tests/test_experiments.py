@@ -75,6 +75,23 @@ def test_kbench_deq_smoke():
     assert p.stdout.count("parity") == 2
 
 
+def test_kbench_expert_smoke():
+    """The grouped expert kernel's own bench (the pricing of every change to
+    its tile walk) at a tiny size: parity against each tile's expert, the
+    kernel with each part taken out, every tile live against the fill's dead
+    ones, the (tn, lanes) sweep, a slice at two tile heights, one chip's
+    share."""
+    p = _run(["experiments/kbench.py", "expert", "--smoke"])
+    assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
+    assert "KBENCH DONE" in p.stdout and "FAILED" not in p.stdout, p.stdout
+    for row in ("as it is", "no dot", "no unpack", "DMA only", "layout matrices not computed",
+                "rows laid out at the call's first tile only", "every tile live",
+                "one tile live", "sweep tn="):
+        assert p.stdout.count(row) >= 3, (row, p.stdout)  # every shape
+    assert p.stdout.count("parity") == 3 + 2 * 2  # decode fills, a slice at two heights
+    assert "slice tm=32 as it is" in p.stdout and "tiny share" in p.stdout
+
+
 def test_collectives_table_smoke():
     p = _run(["experiments/collectives_table.py", "--smoke"])
     assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"

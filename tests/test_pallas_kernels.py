@@ -430,6 +430,163 @@ def test_blockdot_tiles_keep_their_floor(name):
         assert sound(tk, tn)  # the shapes the cells' decode steps run
 
 
+# the dense (k, n) the accepted cells send `_blockdot_call` -> (tk, tn, lanes,
+# rows a pass) and the sha256 of the call's traced jaxpr (addresses blanked)
+# at 16 rows over a 2-layer stack, as PR 38's tree gives them under this
+# suite's jax configuration (tests/conftest.py): a change to
+# the grouped expert kernel leaves the dense call the same program
+_DENSE_CALLS = {
+    (4096, 4096): ((4096, 512, 512, 4096), "1daf61a21fb574cb"),
+    (4096, 11008): ((4096, 256, 256, 4096), "dd899f8f50b1e93b"),
+    (11008, 4096): ((11008, 512, 512, 4096), "6543fffc52220216"),
+    (4096, 102400): ((4096, 2560, 512, 4096), "63461a58865053a1"),
+    (2560, 3584): ((2560, 512, 512, 2560), "40574844b4642254"),
+    (3584, 2560): ((3584, 512, 512, 3584), "bae0fdba2b1fde2f"),
+    (2560, 151936): ((2560, 128, 128, 2560), "44966ff708936a48"),
+    (2048, 100352): ((2048, 3584, 512, 2048), "35c05390c4b03e00"),
+}
+
+
+@pytest.mark.parametrize("k,n", list(_DENSE_CALLS))
+def test_dense_blockdot_call_is_the_program_it_was(k, n):
+    """DeepSeek's, Granite's and SmallThinker's dense shapes run the tiles,
+    the inner loop and the traced kernel they ran before the expert kernel
+    got a walk of its own (PR 39): their cells are the controls."""
+    import hashlib
+    import re
+
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    tiles, digest = _DENSE_CALLS[k, n]
+    tk, tn = qmod._blockdot_tiles(k, n)
+    assert (tk, tn) + qmod._inner(tk, tn) == tiles
+    S = jax.ShapeDtypeStruct
+    text = str(jax.make_jaxpr(lambda *a: qmod._blockdot_call(*a))(
+        S((1,), jnp.int32), S((16, k), jnp.bfloat16), S((2, k // 2, n), jnp.uint8),
+        S((2, k // 32, n), jnp.uint16)))
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+# ---------------------------------------------------- the grouped expert kernel
+# the expert projections of the two expert cells (PERF.md section 4) -> the
+# columns tile a grid step holds and the lanes a pass of its loop covers
+_EXPERT_SHAPES = {
+    "smallthinker w1/w3": ((2560, 768), (768, 768)),
+    "smallthinker w2": ((768, 2560), (2560, 2560)),
+    "kimi w1/w3": ((2304, 1024), (1024, 1024)),
+    "kimi w2": ((1024, 2304), (2304, 2304)),
+}
+
+
+def _expert_stack(rng, experts, k, n, layers=2):
+    packed = rng.integers(0, 256, (layers, experts, k // 2, n), dtype=np.uint8)
+    scales = (rng.random((layers, experts, k // 32, n), np.float32) * 0.02
+              + 1e-3).astype(np.float16)
+    scales[:, :, 1::7, 1::3] *= np.float16(-1)
+    return jnp.asarray(packed), jnp.asarray(scales)
+
+
+def _expert_want(x, packed, scales, li, e, tm=16):
+    """A tile against its expert in float32; a tile taller than 16 rows runs
+    the dequantising body, which rounds each weight (q - 8) * s to bf16 once
+    (as `_deq_call` does for every other matmul of a slice)."""
+    w = QTensor(packed[li, e], scales[li, e]).dequantize(jnp.float32)
+    if tm > 16:
+        w = w.astype(jnp.bfloat16).astype(jnp.float32)
+    return np.asarray(jnp.dot(x.astype(jnp.float32), w, precision="highest"))
+
+
+def _run_expert_call(rng, sizes, tm, k, n, li=1, held=None, **kw):
+    """`sizes[e]` rows routed to expert e (one choice a token; `held` keeps
+    the first experts and turns the others' rows into the sentinel) through
+    `expert_groups` and `_expert_call` on layer `li`; returns what the
+    asserts below read."""
+    from dllama_tpu.ops.layers import expert_groups
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    e = held or len(sizes)
+    topi = np.repeat(np.arange(len(sizes)), sizes)
+    topi = jnp.asarray(np.where(topi < e, topi, e)[rng.permutation(len(topi)), None],
+                       jnp.int32)
+    packed, scales = _expert_stack(rng, e, k, n)
+    src, pos, tile_expert, tile_src, n_live, got_sizes = expert_groups(topi, e, tm)
+    assert got_sizes.tolist() == list(sizes[:e])
+    h = jnp.asarray(rng.standard_normal((topi.shape[0], k)), jnp.bfloat16)
+    xs = h[src]
+    live = int(n_live)
+    # what stands behind the last live tile is never read: poison it
+    xs = xs.at[live * tm:].set(jnp.nan)
+    tile_expert = tile_expert.at[live:].set(10 ** 6)
+    out = np.asarray(qmod._expert_call(
+        jnp.full((1,), li, jnp.int32), tile_expert, tile_src, n_live.reshape(1), xs,
+        packed, jax.lax.bitcast_convert_type(scales, jnp.uint16), tm=tm,
+        interpret=True, **kw))
+    assert out.shape == (xs.shape[0], n) and out.dtype == np.float32
+    for t in range(live):
+        rows = slice(t * tm, (t + 1) * tm)
+        want = _expert_want(xs[rows], packed, scales, li, int(tile_expert[t]), tm)
+        assert np.abs(out[rows] - want).max() <= 1e-5 * np.abs(want).max(), t
+    # ... and never written (an interpreted kernel's untouched result is NaN)
+    assert np.isnan(out[live * tm:]).all()
+    return live, len(tile_expert), np.asarray(pos), out, h, packed, scales, topi
+
+
+@pytest.mark.parametrize("fill", ["tm 16: 1, 3 and 16 rows, an expert with none",
+                                  "tm 32: a full tile, a part one, two and three of one expert"])
+@pytest.mark.parametrize("name", list(_EXPERT_SHAPES))
+def test_expert_call_matches_dequant_dot_at_cell_shapes(name, fill):
+    """The grouped kernel at the two expert cells' four projection shapes
+    against the float32 dequantise-then-dot of each live tile's expert
+    (1e-5 of the largest value: the codes' offset cancels in float32; at 32
+    rows a tile the dequantising walk, against the weights it rounds), the
+    layer a traced index; the tiles behind the last live one are neither
+    read nor written."""
+    (k, n), inner = _EXPERT_SHAPES[name]
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    assert qmod._expert_inner(k, n) == inner
+    assert qmod._expert_deq_tn(k, n) == n  # a tall tile: one pass an expert
+    rng = np.random.default_rng(len(name) + len(fill))
+    if fill.startswith("tm 16"):
+        live, tiles, *_ = _run_expert_call(rng, (1, 0, 3, 16), 16, k, n)
+        assert (live, tiles) == (3, 4 + 1)
+    else:
+        live, tiles, *_ = _run_expert_call(rng, (32, 5, 40, 70), 32, k, n)
+        assert (live, tiles) == (1 + 1 + 2 + 3, 4 + 147 // 32)
+
+
+@pytest.mark.parametrize("case", ["no tile live", "one chip's share",
+                                  "columns tiles and lane passes", "every row found again"])
+def test_expert_call_walks_only_the_live_tiles(case):
+    from dllama_tpu.ops.pallas import q40_matmul as qmod
+
+    rng = np.random.default_rng(11)
+    if case == "no tile live":  # every row is another chip's: nothing runs
+        live, tiles, _, out, *_ = _run_expert_call(rng, (0, 0, 5, 9), 16, 256, 256, held=2)
+        assert live == 0 and np.isnan(out).all()
+    elif case == "one chip's share":  # the sentinel rows stand nowhere
+        live, tiles, pos, out, h, packed, scales, topi = _run_expert_call(
+            rng, (2, 17, 5, 9), 16, 512, 256, held=2)
+        assert (live, tiles) == (1 + 2, 2 + 33 // 16)
+    elif case == "columns tiles and lane passes":
+        # an expert past a grid step's bytes is walked by columns tiles, a
+        # tile past a pass's weights by lanes: the sweep's overrides say how
+        _run_expert_call(rng, (3, 20, 1), 16, 512, 768, tn=256, lanes=128)
+        _run_expert_call(rng, (3, 20, 1), 16, 512, 768, tn=768, lanes=384)
+        assert qmod._expert_inner(5120, 1536) == (1536, 384)  # DeepSeek-V2's
+        assert qmod._expert_inner(1536, 5120) == (5120, 1280)
+        assert qmod._expert_inner(4096, 14336) == (2048, 512)  # 4 MB a step
+        _run_expert_call(rng, (40, 3, 70), 32, 512, 768, tn=256)  # tall tiles too
+        assert qmod._expert_deq_tn(5120, 1536) == 384
+    else:  # each (token, choice) reads its own row of its own expert
+        live, tiles, pos, out, h, packed, scales, topi = _run_expert_call(
+            rng, (4, 0, 19, 2), 16, 256, 384)
+        for token in (0, 7, 24):
+            want = _expert_want(h[token:token + 1], packed, scales, 1, int(topi[token, 0]))
+            assert np.abs(out[pos[token, 0]] - want[0]).max() <= 1e-5 * np.abs(want).max()
+
+
 # every Q40 (m, k, n) the three cells send the m > 16 tier: Granite's 48
 # slots, prompt slices of 32-512 rows in every cell, the check's prefill
 _DEQ_SERVED = [(m, k, n) for m, shapes in (

@@ -225,11 +225,12 @@ def _routed_to(rows, e, choices):
 
 
 @pytest.mark.parametrize("case", ["m=1", "decode batch", "every row on one set",
-                                  "a ragged slice"])
+                                  "a ragged slice", "a slice past a tile an expert"])
 def test_grouped_kernel_matches_dense(experts, case):
     cfg, (w1, w2, w3), rng = experts
     b, t = {"m=1": (1, 1), "decode batch": (4, 1),
-            "every row on one set": (1, 24), "a ragged slice": (1, 40)}[case]
+            "every row on one set": (1, 24), "a ragged slice": (1, 40),
+            "a slice past a tile an expert": (1, 64)}[case]
     h = jnp.asarray(rng.standard_normal((b, t, cfg.dim)), jnp.bfloat16)
     if case == "every row on one set":  # five experts receive no row at all
         logits = _routed_to(t, cfg.n_experts, (2, 5, 7))
