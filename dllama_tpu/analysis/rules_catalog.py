@@ -7,9 +7,12 @@ name in ``obs/trace.{SPAN,EVENT}_CATALOG``, every fault point in
 to those catalogs; these rules close the other half of the loop — CODE
 that registers or emits outside the catalog fails at the callsite with a
 real location (the grep gates this replaces could only say "something,
-somewhere"). A ``hook("dllama.x." + y)`` call (the
-``obs/trace.PROFILER_HOOK`` callable, always bound to a local named
-``hook``) is a span emission too: its literal head is its catalog name.
+somewhere"). The profiler's clock has ONE writer,
+``obs/trace.profiler_annotation(prefix, leaf, args)``: a call of it is a
+span emission whose literal prefix (``"dllama.sched."``) is its catalog
+name; so is a call of the phase seam (``obs/perf.PhaseClock``, always
+reached as ``phases(...)`` / ``self.phases(...)`` or a local ``ph``) with
+a literal phase name.
 """
 
 from __future__ import annotations
@@ -40,11 +43,17 @@ def _is_metric_factory(call: ast.Call) -> bool:
     return parts[-2] in ("metrics", "REGISTRY") or parts[0] == "REGISTRY"
 
 
+#: callables that open a span by a literal name: the profiler clock's one
+#: writer and the phase seam
+_SPAN_OPENERS = {"profiler_annotation", "phases", "ph"}
+
+
 def _is_tracer_call(call: ast.Call, src_rel: str) -> str | None:
     """'span' | 'event' when the call is a tracer emission (a call of the
-    profiler hook is a span on the profiler's clock)."""
+    profiler clock's writer or of the phase seam is a span)."""
     f = call.func
-    if isinstance(f, ast.Name) and f.id == "hook":
+    if (f.id if isinstance(f, ast.Name) else
+            f.attr if isinstance(f, ast.Attribute) else None) in _SPAN_OPENERS:
         return "span"
     if not isinstance(f, ast.Attribute):
         return None
@@ -63,8 +72,9 @@ def _is_tracer_call(call: ast.Call, src_rel: str) -> str | None:
 
 
 def _emitted_name(call: ast.Call) -> str | None:
-    """The literal name a tracer call emits; of a ``"prefix." + x`` (how a
-    profiler hook call names its span) the constant head."""
+    """The literal name a tracer call emits (of the profiler clock's
+    writer: the prefix, its first argument); of a ``"prefix." + x`` the
+    constant head."""
     name = call.args[0] if call.args else None
     if isinstance(name, ast.BinOp):
         name = name.left
@@ -77,6 +87,15 @@ def check(project) -> list[Diagnostic]:
     diags: list[Diagnostic] = []
     for src in project.py_sources("dllama_tpu/"):
         for node in ast.walk(src.tree):
+            if (isinstance(node, ast.Attribute)
+                    and node.attr == "PROFILER_HOOK"
+                    and isinstance(node.ctx, ast.Load)
+                    and src.rel != "dllama_tpu/obs/trace.py"):
+                diags.append(Diagnostic(
+                    src.rel, node.lineno, "catalog-span",
+                    "PROFILER_HOOK is read in ONE function, "
+                    "obs/trace.profiler_annotation — open the annotation "
+                    "through it (utils/profiling only installs the hook)"))
             if not isinstance(node, ast.Call):
                 continue
             if _is_metric_factory(node) and src.rel not in METRIC_SITES:

@@ -45,7 +45,7 @@ from dllama_tpu.models.config import LlamaConfig
 from dllama_tpu.models.llama import KVCache, PagedKVCache, forward
 from dllama_tpu.obs import compile as compile_obs
 from dllama_tpu.obs import instruments as ins
-from dllama_tpu.obs import trace
+from dllama_tpu.obs import perf, trace
 from dllama_tpu.utils import faults
 from dllama_tpu.utils import locks
 
@@ -985,6 +985,12 @@ class BatchEngine:
         self._base_key = jax.random.PRNGKey(seed)
         self._admissions = 0
         self.chunk_seq = 0  # decode/spec chunk counter (DecodeChunk.seq)
+        # the phase seam of whoever drives this engine (obs/perf.PhaseClock):
+        # the dispatch.* and consume.* phases are opened here, the
+        # scheduler opens its own on the same clock
+        self.phases = perf.PhaseClock()
+        self._wait_ready = ins.LAUNCH_WAITS.labels(outcome="ready")
+        self._wait_blocked = ins.LAUNCH_WAITS.labels(outcome="blocked")
 
         # ---- device-resident decode state. The JAX arrays below are the
         # authoritative operands of the fused decode step, threaded
@@ -2480,7 +2486,7 @@ class BatchEngine:
             with compile_obs.LEDGER.scope(
                     "prefill_chunk", f"m{c}",
                     sig=lambda: compile_obs.sig_of(ptoks)), \
-                    rec.annotation():
+                    self.phases("dispatch.call", 0, rec):
                 row, self.cache = self._prefill_slot(
                     self.params, self.cache,
                     ptoks,
@@ -2511,7 +2517,7 @@ class BatchEngine:
             with compile_obs.LEDGER.scope(
                     "prefill_chunk", f"m{c}",
                     sig=lambda: compile_obs.sig_of(chunk_dev)), \
-                    rec.annotation():
+                    self.phases("dispatch.call", 0, rec):
                 logits, self.cache = self._prefill_step(
                     self.params, self.cache,
                     chunk_dev,
@@ -2547,18 +2553,19 @@ class BatchEngine:
         launch and AHEAD of its successor, so add_commit's one host read is
         ready when the launch ends and the pipeline never drains for it."""
         assert adm.off >= len(adm.toks) and adm.logits is not None, "admission not pumped"
-        if seed is not None:
-            key = jax.random.PRNGKey(seed)
-        else:
-            key = jax.random.fold_in(self._base_key, self._admissions)
-        self._admissions += 1
-        key, sub = jax.random.split(key)
-        with compile_obs.LEDGER.scope(
-                "commit", "b1",
-                sig=lambda: compile_obs.sig_of(adm.logits)):
-            tok = sample_logits(adm.logits, sub, jnp.float32(temperature),
-                                jnp.float32(topp))
-        adm.sampled = (tok, key)
+        with self.phases("commit.sample", self.chunk_seq + 1):
+            if seed is not None:
+                key = jax.random.PRNGKey(seed)
+            else:
+                key = jax.random.fold_in(self._base_key, self._admissions)
+            self._admissions += 1
+            key, sub = jax.random.split(key)
+            with compile_obs.LEDGER.scope(
+                    "commit", "b1",
+                    sig=lambda: compile_obs.sig_of(adm.logits)):
+                tok = sample_logits(adm.logits, sub, jnp.float32(temperature),
+                                    jnp.float32(topp))
+            adm.sampled = (tok, key)
 
     def add_commit(self, adm: "Admission", temperature: float = 0.8,
                    topp: float = 0.9, seed: int | None = None,
@@ -2787,48 +2794,50 @@ class BatchEngine:
             return self._spec_dispatch(max(1, int(n)))
         if not self.active.any():
             raise ValueError("no active slots")
-        self._alloc_decode_rows(n)
-        limit = self._row_limit()
-        room = limit[self.active] - self.pos[self.active]
-        n = min(n, int(room.max()))
-        if n <= 0:
-            raise ValueError("every active slot is at its row limit "
-                             "(seq_len, or an exhausted page pool); "
-                             "release first")
-        self._sync_vectors()
-        pos_before = self._pos_dev
-        args = (
-            self.params, self.cache,
-            self._last_dev[:, None],
-            self._pos_dev,
-            self._active_dev,
-            self._keys_dev,
-            self._temps_dev,
-            self._topp_dev,
-            n,
-            self.rope_cache,
-            self._limit_dev,
-        )
-        t0 = time.perf_counter()
-        t_disp = time.monotonic()  # trace clock; ~free next to perf_counter
-        # steady-state contract, both halves (ISSUE 13): the compile scope
-        # attributes any trace/compile this launch causes to its shape
-        # bucket, and the transfer guard (strict mode) turns an implicit
-        # host->device upload into an error — every operand below is a
-        # device-resident carry, so a clean engine trips neither.
-        guard = compile_obs.h2d_guard(self.transfer_guard)
-        pen = self._penalized()
-        start_pos = self.pos.copy()
-        active = self.active.copy()
-        advance = np.where(
-            active, np.clip(limit - start_pos, 0, n), 0
-        ).astype(np.int32)
-        rec = self._launch_record("decode_pen" if pen else "decode", n,
-                                  start_pos, active, advance)
+        seq = self.chunk_seq + 1
+        with self.phases("dispatch.build", seq):
+            self._alloc_decode_rows(n)
+            limit = self._row_limit()
+            room = limit[self.active] - self.pos[self.active]
+            n = min(n, int(room.max()))
+            if n <= 0:
+                raise ValueError("every active slot is at its row limit "
+                                 "(seq_len, or an exhausted page pool); "
+                                 "release first")
+            self._sync_vectors()
+            pos_before = self._pos_dev
+            args = (
+                self.params, self.cache,
+                self._last_dev[:, None],
+                self._pos_dev,
+                self._active_dev,
+                self._keys_dev,
+                self._temps_dev,
+                self._topp_dev,
+                n,
+                self.rope_cache,
+                self._limit_dev,
+            )
+            t0 = time.perf_counter()
+            t_disp = time.monotonic()  # trace clock; ~free next to perf_counter
+            # steady-state contract, both halves (ISSUE 13): the compile scope
+            # attributes any trace/compile this launch causes to its shape
+            # bucket, and the transfer guard (strict mode) turns an implicit
+            # host->device upload into an error — every operand below is a
+            # device-resident carry, so a clean engine trips neither.
+            guard = compile_obs.h2d_guard(self.transfer_guard)
+            pen = self._penalized()
+            start_pos = self.pos.copy()
+            active = self.active.copy()
+            advance = np.where(
+                active, np.clip(limit - start_pos, 0, n), 0
+            ).astype(np.int32)
+            rec = self._launch_record("decode_pen" if pen else "decode", n,
+                                      start_pos, active, advance)
         with compile_obs.LEDGER.scope(
                 rec.kind, f"n{n}",
                 sig=lambda: compile_obs.sig_of(*args[2:])), guard, \
-                rec.annotation():
+                self.phases("dispatch.call", seq, rec):
             if pen:
                 (toks, self.cache, self._keys_dev, self._pos_dev,
                  self._last_dev, self._counts, bad) = self._decode_pen(
@@ -2836,39 +2845,40 @@ class BatchEngine:
             else:
                 (toks, self.cache, self._keys_dev, self._pos_dev,
                  self._last_dev, bad) = self._decode(*args)
-        rec.count()
-        moe = self._moe_snapshot()
-        bad_inject = None
-        if faults.flag("decode.nan"):
-            # drill the NaN guard without needing genuinely poisoned
-            # weights: flag the lowest active slot as if its logits went
-            # non-finite — the scheduler's consume path fails that request
-            bad_inject = np.zeros(self.n_slots, bool)
-            bad_inject[int(np.flatnonzero(active)[0])] = True
-        if self.spec_k:
-            # history backfill rides the device stream off the
-            # not-yet-materialized tokens (no host round-trip). Rows whose
-            # full chunk would spill past the history row are skipped: their
-            # slot froze mid-chunk at seq_len, where spec_eligible freezes it
-            # anyway — a draft from slightly stale history is only a
-            # proposal, verify rejects it. The mask is computed ON DEVICE
-            # off the dispatch-time carry (identical values to the old host
-            # mask for every active row — _active_dev/_pos_dev are synced
-            # mirrors here), so spec engines keep steady-state decode at
-            # literally zero host->device uploads (ISSUE 13).
-            fits_dev = self._active_dev & (pos_before + 1 + n
-                                           <= self.seq_len + 1)
-            with compile_obs.LEDGER.scope("boundary", "hist_batch"):
-                self.history = self._hist_write_batch(
-                    self.history, toks.T, pos_before, fits_dev)
-        # the host pos mirror advances arithmetically — exactly what the scan
-        # computes — so it stays current without waiting for the tokens
-        self.pos += advance
-        self.chunk_seq += 1
-        return DecodeChunk(toks=toks, n=n, start_pos=start_pos, active=active,
-                           advance=advance, t0=t0, seq=self.chunk_seq,
-                           t_disp=t_disp, bad=bad, bad_inject=bad_inject,
-                           launch=rec, moe=moe)
+        with self.phases("dispatch.after", seq):
+            rec.count()
+            moe = self._moe_snapshot()
+            bad_inject = None
+            if faults.flag("decode.nan"):
+                # drill the NaN guard without needing genuinely poisoned
+                # weights: flag the lowest active slot as if its logits went
+                # non-finite — the scheduler's consume path fails that request
+                bad_inject = np.zeros(self.n_slots, bool)
+                bad_inject[int(np.flatnonzero(active)[0])] = True
+            if self.spec_k:
+                # history backfill rides the device stream off the
+                # not-yet-materialized tokens (no host round-trip). Rows whose
+                # full chunk would spill past the history row are skipped: their
+                # slot froze mid-chunk at seq_len, where spec_eligible freezes it
+                # anyway — a draft from slightly stale history is only a
+                # proposal, verify rejects it. The mask is computed ON DEVICE
+                # off the dispatch-time carry (identical values to the old host
+                # mask for every active row — _active_dev/_pos_dev are synced
+                # mirrors here), so spec engines keep steady-state decode at
+                # literally zero host->device uploads (ISSUE 13).
+                fits_dev = self._active_dev & (pos_before + 1 + n
+                                               <= self.seq_len + 1)
+                with compile_obs.LEDGER.scope("boundary", "hist_batch"):
+                    self.history = self._hist_write_batch(
+                        self.history, toks.T, pos_before, fits_dev)
+            # the host pos mirror advances arithmetically — exactly what the scan
+            # computes — so it stays current without waiting for the tokens
+            self.pos += advance
+            self.chunk_seq += 1
+            return DecodeChunk(toks=toks, n=n, start_pos=start_pos, active=active,
+                               advance=advance, t0=t0, seq=self.chunk_seq,
+                               t_disp=t_disp, bad=bad, bad_inject=bad_inject,
+                               launch=rec, moe=moe)
 
     def _moe_snapshot(self):
         """The expert counters as the launch just dispatched leaves them: a
@@ -2930,64 +2940,66 @@ class BatchEngine:
             raise ValueError("admission already fully pumped")
         c = pow2_chunk(min(max(1, int(budget)), remaining),
                        self.max_prefill_chunk)
-        self._alloc_decode_rows(n)
-        limit = self._row_limit()
-        room = limit[self.active] - self.pos[self.active]
-        n = min(n, int(room.max()))
-        if n <= 0:
-            raise ValueError("every active slot is at its row limit "
-                             "(seq_len, or an exhausted page pool); "
-                             "release first")
-        ppos = int(self.pos[slot])
-        if self.wpool is not None and self._window_advance(slot, ppos + c):
-            self._vec_dirty = True
-        if self.spec_k:
-            # prompt tokens feed the n-gram proposer exactly like add_step
-            compile_obs.note_transfer("h2d", "history", c * 4)
-            with compile_obs.LEDGER.scope("boundary", "hist"):
-                self.history = self._hist_write(
-                    self.history, jnp.int32(slot), jnp.int32(ppos),
-                    jnp.asarray(adm.toks[adm.off : adm.off + c]),
-                )
-        self._sync_vectors()
-        pos_before = self._pos_dev
-        ptoks = jnp.asarray(adm.toks[adm.off : adm.off + c][None])
-        compile_obs.note_transfer("h2d", "prefill", int(ptoks.nbytes))
-        args = (
-            self.params, self.cache,
-            ptoks,
-            jnp.int32(slot),
-            jnp.int32(ppos),
-            self._last_dev[:, None],
-            self._pos_dev,
-            self._active_dev,
-            self._keys_dev,
-            self._temps_dev,
-            self._topp_dev,
-            n,
-            self.rope_cache,
-            self._limit_dev,
-        )
-        t0 = time.perf_counter()
-        t_disp = time.monotonic()
-        # same steady-state contract as decode_dispatch: the prefill slice
-        # upload happened above (an expected, counted boundary transfer);
-        # the fused launch itself takes only device-resident operands, so
-        # the strict transfer guard holds through hybrid serving too
-        guard = compile_obs.h2d_guard(self.transfer_guard)
-        pen = self._penalized()
-        start_pos = self.pos.copy()
-        active = self.active.copy()
-        advance = np.where(
-            active, np.clip(limit - start_pos, 0, n), 0
-        ).astype(np.int32)
-        rec = self._launch_record("hybrid_pen" if pen else "hybrid", n,
-                                  start_pos, active, advance,
-                                  prefill_rows=c)
+        seq = self.chunk_seq + 1
+        with self.phases("dispatch.build", seq):
+            self._alloc_decode_rows(n)
+            limit = self._row_limit()
+            room = limit[self.active] - self.pos[self.active]
+            n = min(n, int(room.max()))
+            if n <= 0:
+                raise ValueError("every active slot is at its row limit "
+                                 "(seq_len, or an exhausted page pool); "
+                                 "release first")
+            ppos = int(self.pos[slot])
+            if self.wpool is not None and self._window_advance(slot, ppos + c):
+                self._vec_dirty = True
+            if self.spec_k:
+                # prompt tokens feed the n-gram proposer exactly like add_step
+                compile_obs.note_transfer("h2d", "history", c * 4)
+                with compile_obs.LEDGER.scope("boundary", "hist"):
+                    self.history = self._hist_write(
+                        self.history, jnp.int32(slot), jnp.int32(ppos),
+                        jnp.asarray(adm.toks[adm.off : adm.off + c]),
+                    )
+            self._sync_vectors()
+            pos_before = self._pos_dev
+            ptoks = jnp.asarray(adm.toks[adm.off : adm.off + c][None])
+            compile_obs.note_transfer("h2d", "prefill", int(ptoks.nbytes))
+            args = (
+                self.params, self.cache,
+                ptoks,
+                jnp.int32(slot),
+                jnp.int32(ppos),
+                self._last_dev[:, None],
+                self._pos_dev,
+                self._active_dev,
+                self._keys_dev,
+                self._temps_dev,
+                self._topp_dev,
+                n,
+                self.rope_cache,
+                self._limit_dev,
+            )
+            t0 = time.perf_counter()
+            t_disp = time.monotonic()
+            # same steady-state contract as decode_dispatch: the prefill slice
+            # upload happened above (an expected, counted boundary transfer);
+            # the fused launch itself takes only device-resident operands, so
+            # the strict transfer guard holds through hybrid serving too
+            guard = compile_obs.h2d_guard(self.transfer_guard)
+            pen = self._penalized()
+            start_pos = self.pos.copy()
+            active = self.active.copy()
+            advance = np.where(
+                active, np.clip(limit - start_pos, 0, n), 0
+            ).astype(np.int32)
+            rec = self._launch_record("hybrid_pen" if pen else "hybrid", n,
+                                      start_pos, active, advance,
+                                      prefill_rows=c)
         with compile_obs.LEDGER.scope(
                 rec.kind, f"p{c}.n{n}",
                 sig=lambda: compile_obs.sig_of(ptoks, *args[5:])), guard, \
-                rec.annotation():
+                self.phases("dispatch.call", seq, rec):
             if pen:
                 (plog, toks, self.cache, self._keys_dev, self._pos_dev,
                  self._last_dev, self._counts, bad) = self._hybrid_pen(
@@ -2995,33 +3007,34 @@ class BatchEngine:
             else:
                 (plog, toks, self.cache, self._keys_dev, self._pos_dev,
                  self._last_dev, bad) = self._hybrid(*args)
-        rec.count()
-        moe = self._moe_snapshot()
-        adm.logits = plog  # [1, V] — materializes with the chunk
-        adm.off += c
-        # the admitting slot's host pos advances with its slice (the device
-        # pos carry keeps its stale inactive row — add_commit/resume_commit
-        # write it surgically at activation, same contract as add_step)
-        self.pos[slot] += c
-        bad_inject = None
-        if faults.flag("decode.nan"):
-            bad_inject = np.zeros(self.n_slots, bool)
-            bad_inject[int(np.flatnonzero(active)[0])] = True
-        if self.spec_k:
-            # device-side fits mask, same reasoning as decode_dispatch
-            fits_dev = self._active_dev & (pos_before + 1 + n
-                                           <= self.seq_len + 1)
-            with compile_obs.LEDGER.scope("boundary", "hist_batch"):
-                self.history = self._hist_write_batch(
-                    self.history, toks.T, pos_before, fits_dev)
-        self.pos += advance
-        self.chunk_seq += 1
-        ins.PREFILL_TOKENS.inc(c)
-        return DecodeChunk(toks=toks, n=n, start_pos=start_pos, active=active,
-                           advance=advance, t0=t0, seq=self.chunk_seq,
-                           t_disp=t_disp, bad=bad, bad_inject=bad_inject,
-                           hybrid_slot=slot, hybrid_tokens=c, launch=rec,
-                           moe=moe)
+        with self.phases("dispatch.after", seq):
+            rec.count()
+            moe = self._moe_snapshot()
+            adm.logits = plog  # [1, V] — materializes with the chunk
+            adm.off += c
+            # the admitting slot's host pos advances with its slice (the device
+            # pos carry keeps its stale inactive row — add_commit/resume_commit
+            # write it surgically at activation, same contract as add_step)
+            self.pos[slot] += c
+            bad_inject = None
+            if faults.flag("decode.nan"):
+                bad_inject = np.zeros(self.n_slots, bool)
+                bad_inject[int(np.flatnonzero(active)[0])] = True
+            if self.spec_k:
+                # device-side fits mask, same reasoning as decode_dispatch
+                fits_dev = self._active_dev & (pos_before + 1 + n
+                                               <= self.seq_len + 1)
+                with compile_obs.LEDGER.scope("boundary", "hist_batch"):
+                    self.history = self._hist_write_batch(
+                        self.history, toks.T, pos_before, fits_dev)
+            self.pos += advance
+            self.chunk_seq += 1
+            ins.PREFILL_TOKENS.inc(c)
+            return DecodeChunk(toks=toks, n=n, start_pos=start_pos, active=active,
+                               advance=advance, t0=t0, seq=self.chunk_seq,
+                               t_disp=t_disp, bad=bad, bad_inject=bad_inject,
+                               hybrid_slot=slot, hybrid_tokens=c, launch=rec,
+                               moe=moe)
 
     def _spec_dispatch(self, n_cycles: int) -> DecodeChunk:
         """Dispatch one fused spec CHUNK (decode_dispatch's spec=True
@@ -3034,49 +3047,51 @@ class BatchEngine:
         device from the carried position EVERY cycle, so a chunk pipelined
         off an in-flight predecessor stays exact even though the host
         mirrors lag it."""
-        k = self.spec_k
-        # page top-up + shared-page COW for this chunk — doubled ONLY when
-        # a predecessor spec chunk is still unconsumed (then the host pos
-        # mirror lags the device carry by up to its rows; an under-backed
-        # row merely freezes per-row on device, this keeps that the rare
-        # case). Boundary/lockstep dispatches have an exact mirror and
-        # must not double the pool pressure.
-        lag = 2 if self._spec_inflight else 1
-        self._alloc_decode_rows(lag * n_cycles * (k + 1))
-        if not self.spec_eligible().any():
-            raise ValueError(
-                "no active slot is spec-eligible (needs room for K+1 "
-                "rows); use decode() or release the full slots")
-        self._sync_vectors()
-        start_dev = self._pos_dev
-        t0 = time.perf_counter()
-        t_disp = time.monotonic()
-        args = (
-            self.params, self.cache, self.history,
-            self._last_dev,
-            self._pos_dev,
-            self._active_dev,
-            self._speck_dev,
-            self._keys_dev,
-            self._temps_dev,
-            self._topp_dev,
-            self.rope_cache,
-            self._limit_dev,
-        )
-        guard = compile_obs.h2d_guard(self.transfer_guard)
-        pen = self._penalized()
-        active = self.active.copy()
-        # the chunk's rows are data-dependent: this record names the launch
-        # (its annotation carries seq/n/active) and holds the pool's state
-        # it was dispatched under; decode_consume rebuilds it from the
-        # materialised counts and counts it there, once
-        rec = launch_record.LaunchRecord(
-            "spec_pen" if pen else "spec", seq=self.chunk_seq + 1,
-            n=n_cycles, active=int(active.sum()), pool_dry=self._pool_dry())
+        seq = self.chunk_seq + 1
+        with self.phases("dispatch.build", seq):
+            k = self.spec_k
+            # page top-up + shared-page COW for this chunk — doubled ONLY when
+            # a predecessor spec chunk is still unconsumed (then the host pos
+            # mirror lags the device carry by up to its rows; an under-backed
+            # row merely freezes per-row on device, this keeps that the rare
+            # case). Boundary/lockstep dispatches have an exact mirror and
+            # must not double the pool pressure.
+            lag = 2 if self._spec_inflight else 1
+            self._alloc_decode_rows(lag * n_cycles * (k + 1))
+            if not self.spec_eligible().any():
+                raise ValueError(
+                    "no active slot is spec-eligible (needs room for K+1 "
+                    "rows); use decode() or release the full slots")
+            self._sync_vectors()
+            start_dev = self._pos_dev
+            t0 = time.perf_counter()
+            t_disp = time.monotonic()
+            args = (
+                self.params, self.cache, self.history,
+                self._last_dev,
+                self._pos_dev,
+                self._active_dev,
+                self._speck_dev,
+                self._keys_dev,
+                self._temps_dev,
+                self._topp_dev,
+                self.rope_cache,
+                self._limit_dev,
+            )
+            guard = compile_obs.h2d_guard(self.transfer_guard)
+            pen = self._penalized()
+            active = self.active.copy()
+            # the chunk's rows are data-dependent: this record names the launch
+            # (its annotation carries seq/n/active) and holds the pool's state
+            # it was dispatched under; decode_consume rebuilds it from the
+            # materialised counts and counts it there, once
+            rec = launch_record.LaunchRecord(
+                "spec_pen" if pen else "spec", seq=self.chunk_seq + 1,
+                n=n_cycles, active=int(active.sum()), pool_dry=self._pool_dry())
         with compile_obs.LEDGER.scope(
                 rec.kind, f"n{n_cycles}",
                 sig=lambda: compile_obs.sig_of(*args[3:])), guard, \
-                rec.annotation():
+                self.phases("dispatch.call", seq, rec):
             if pen:
                 (emits, advs, nxt, self.cache, self.history, self._keys_dev,
                  self._pos_dev, drafts, bad, self._counts) = \
@@ -3085,24 +3100,25 @@ class BatchEngine:
             else:
                 (emits, advs, nxt, self.cache, self.history, self._keys_dev,
                  self._pos_dev, drafts, bad) = self._spec_step(*args, n_cycles)
-        self._last_dev = nxt
-        self._spec_inflight += 1
-        bad_inject = None
-        if faults.flag("decode.nan"):
-            bad_inject = np.zeros(self.n_slots, bool)
-            bad_inject[int(np.flatnonzero(active)[0])] = True
-        self.chunk_seq += 1
-        # start_pos/advance are host ESTIMATES until consumption (the chunk
-        # in flight below us decides the truth): advance's lower bound — one
-        # bonus token per active row — feeds the scheduler's conservative
-        # budget check, and both are overwritten in decode_consume
-        return DecodeChunk(toks=emits, n=n_cycles,
-                           start_pos=self.pos.copy(), active=active,
-                           advance=np.where(active, 1, 0).astype(np.int32),
-                           t0=t0, seq=self.chunk_seq, t_disp=t_disp, bad=bad,
-                           bad_inject=bad_inject, spec=True, adv_dev=advs,
-                           drafted_dev=drafts, start_dev=start_dev,
-                           launch=rec, moe=self._moe_snapshot())
+        with self.phases("dispatch.after", seq):
+            self._last_dev = nxt
+            self._spec_inflight += 1
+            bad_inject = None
+            if faults.flag("decode.nan"):
+                bad_inject = np.zeros(self.n_slots, bool)
+                bad_inject[int(np.flatnonzero(active)[0])] = True
+            self.chunk_seq += 1
+            # start_pos/advance are host ESTIMATES until consumption (the chunk
+            # in flight below us decides the truth): advance's lower bound — one
+            # bonus token per active row — feeds the scheduler's conservative
+            # budget check, and both are overwritten in decode_consume
+            return DecodeChunk(toks=emits, n=n_cycles,
+                               start_pos=self.pos.copy(), active=active,
+                               advance=np.where(active, 1, 0).astype(np.int32),
+                               t0=t0, seq=self.chunk_seq, t_disp=t_disp, bad=bad,
+                               bad_inject=bad_inject, spec=True, adv_dev=advs,
+                               drafted_dev=drafts, start_dev=start_dev,
+                               launch=rec, moe=self._moe_snapshot())
 
     def decode_consume(self, chunk: DecodeChunk) -> np.ndarray:
         """Block until the chunk's tokens are on host; fold them into the
@@ -3117,105 +3133,113 @@ class BatchEngine:
         was in flight keep their rewound state — their rows here are the
         one-chunk stop overrun), and the acceptance telemetry
         (dllama_spec_* series) is recorded."""
-        toks = np.asarray(chunk.toks)
-        compile_obs.note_transfer("d2h", "decode_tokens", int(toks.nbytes))
-        if chunk.moe is not None:
+        ph = self.phases
+        with ph("consume.wait", chunk.seq):
+            toks = np.asarray(chunk.toks)
+            compile_obs.note_transfer("d2h", "decode_tokens", int(toks.nbytes))
             # four scalars that were ready with the tokens
-            self._moe_count(np.asarray(chunk.moe))
-        # the transfer above is the device sync: observing here (not at
-        # dispatch) keeps DECODE_CHUNK_SECONDS device-real under overlapped
-        # consumption. The clock starts at the later of the chunk's dispatch
-        # and the previous chunk's consumption: an overlapped dispatch lands
-        # while its predecessor still runs, and billing it the predecessor's
-        # tail would read as ~2x chunk time.
-        now = time.perf_counter()
-        start = (chunk.t0 if self._t_last_consume is None
-                 else max(chunk.t0, self._t_last_consume))
-        ins.DECODE_CHUNK_SECONDS.observe(now - start)
-        self._t_last_consume = now
-        tr = trace.TRACER
-        if chunk.spec:
-            # toks here is the stacked per-cycle emit [m, B, k+1]; flatten
-            # each slot's accepted runs (cycle-major) into the same
-            # [rows, B] layout a decode chunk returns, so the scheduler's
-            # emit loop serves both chunk kinds unchanged
-            self._spec_inflight = max(0, self._spec_inflight - 1)
-            emits = toks
-            advs = np.asarray(chunk.adv_dev).astype(np.int32)  # [m, B]
-            drafted = np.asarray(chunk.drafted_dev).astype(np.int32)
-            chunk.start_pos = np.asarray(chunk.start_dev).astype(np.int32)
-            # accounted immediately after the three materializations above
-            # (the transfer-note rule windows the annotation to its site)
-            compile_obs.note_transfer(
-                "d2h", "spec_counts",
-                int(advs.nbytes) + int(drafted.nbytes)
-                + int(chunk.start_pos.nbytes))
-            total = advs.sum(axis=0).astype(np.int32)  # [B]
-            chunk.advance = total
-            chunk.adv_cycles = advs
-            m_cycles, b = advs.shape
-            # flatten each slot's accepted runs (cycle-major) with one
-            # boolean-mask gather per emitting slot — C-speed, not an
-            # O(cycles x slots) Python concat loop on the consume hot path
-            keep = (np.arange(emits.shape[2])[None, None, :]
-                    < advs[:, :, None])  # [m, B, k+1]
-            out = np.zeros((max(1, int(total.max(initial=0))), b), np.int32)
-            for s in np.flatnonzero(total):
-                out[: total[s], s] = emits[:, s, :][keep[:, s, :]]
-            # host mirror fixup: the chunk's advance was data-dependent, so
-            # the mirrors could not move at dispatch. Slots released while
-            # it was in flight (EOS found consuming the predecessor) keep
-            # their rewound pos — their rows here are discarded overrun.
-            upd = chunk.active & self.active
-            self.pos[upd] = chunk.start_pos[upd] + total[upd]
-            emitted = np.flatnonzero(upd & (total > 0))
-            if emitted.size:
-                self.last_token[emitted] = out[total[emitted] - 1, emitted]
-            # acceptance telemetry, single-site: every consumed verify
-            # cycle lands in the dllama_spec_* series AND the engine totals
-            acc = advs - 1
-            msk = drafted > 0
-            n_drafted, n_acc = int(drafted.sum()), int(acc[msk].sum())
-            n_emit = int(total.sum())
-            self._spec_totals["cycles"] += m_cycles
-            self._spec_totals["drafted"] += n_drafted
-            self._spec_totals["accepted"] += n_acc
-            self._spec_totals["emitted"] += n_emit
-            ins.SPEC_CYCLES.inc(m_cycles)
-            ins.SPEC_TOKENS.labels(kind="drafted").inc(n_drafted)
-            ins.SPEC_TOKENS.labels(kind="accepted").inc(n_acc)
-            ins.SPEC_TOKENS.labels(kind="emitted").inc(n_emit)
-            # one bulk histogram update per distinct accepted length, not a
-            # Python observe() per (cycle, row) sample
-            for val, cnt in enumerate(np.bincount(acc[msk])):
-                ins.SPEC_ACCEPTED_LENGTH.observe_n(val, int(cnt))
-            ins.BATCH_OCCUPANCY.observe(int((total > 0).sum()))
-            # the launch's record, now that its rows are known: a slot
-            # that emitted nothing was frozen for every cycle
-            chunk.launch = rec = launch_record.build(
-                chunk.launch.kind, chunk.seq, m_cycles, chunk.start_pos,
-                chunk.active, total, seq_len=self.seq_len,
-                pool_dry=chunk.launch.pool_dry,
-                frozen=np.where(total == 0, m_cycles, 0),
-                window=self.window, kv_pool=self._kv_pool).count()
+            moe = None if chunk.moe is None else np.asarray(chunk.moe)
+        # the device had finished before the host asked, or the host
+        # waited for it: one compare a launch
+        (self._wait_ready if ph.last_s < perf.READY_WAIT_S
+         else self._wait_blocked).inc()
+        with ph("consume.fold", chunk.seq):
+            if moe is not None:
+                self._moe_count(moe)
+            # the transfer above is the device sync: observing here (not at
+            # dispatch) keeps DECODE_CHUNK_SECONDS device-real under overlapped
+            # consumption. The clock starts at the later of the chunk's dispatch
+            # and the previous chunk's consumption: an overlapped dispatch lands
+            # while its predecessor still runs, and billing it the predecessor's
+            # tail would read as ~2x chunk time.
+            now = time.perf_counter()
+            start = (chunk.t0 if self._t_last_consume is None
+                     else max(chunk.t0, self._t_last_consume))
+            ins.DECODE_CHUNK_SECONDS.observe(now - start)
+            self._t_last_consume = now
+            tr = trace.TRACER
+            if chunk.spec:
+                # toks here is the stacked per-cycle emit [m, B, k+1]; flatten
+                # each slot's accepted runs (cycle-major) into the same
+                # [rows, B] layout a decode chunk returns, so the scheduler's
+                # emit loop serves both chunk kinds unchanged
+                self._spec_inflight = max(0, self._spec_inflight - 1)
+                emits = toks
+                advs = np.asarray(chunk.adv_dev).astype(np.int32)  # [m, B]
+                drafted = np.asarray(chunk.drafted_dev).astype(np.int32)
+                chunk.start_pos = np.asarray(chunk.start_dev).astype(np.int32)
+                # accounted immediately after the three materializations above
+                # (the transfer-note rule windows the annotation to its site)
+                compile_obs.note_transfer(
+                    "d2h", "spec_counts",
+                    int(advs.nbytes) + int(drafted.nbytes)
+                    + int(chunk.start_pos.nbytes))
+                total = advs.sum(axis=0).astype(np.int32)  # [B]
+                chunk.advance = total
+                chunk.adv_cycles = advs
+                m_cycles, b = advs.shape
+                # flatten each slot's accepted runs (cycle-major) with one
+                # boolean-mask gather per emitting slot — C-speed, not an
+                # O(cycles x slots) Python concat loop on the consume hot path
+                keep = (np.arange(emits.shape[2])[None, None, :]
+                        < advs[:, :, None])  # [m, B, k+1]
+                out = np.zeros((max(1, int(total.max(initial=0))), b), np.int32)
+                for s in np.flatnonzero(total):
+                    out[: total[s], s] = emits[:, s, :][keep[:, s, :]]
+                # host mirror fixup: the chunk's advance was data-dependent, so
+                # the mirrors could not move at dispatch. Slots released while
+                # it was in flight (EOS found consuming the predecessor) keep
+                # their rewound pos — their rows here are discarded overrun.
+                upd = chunk.active & self.active
+                self.pos[upd] = chunk.start_pos[upd] + total[upd]
+                emitted = np.flatnonzero(upd & (total > 0))
+                if emitted.size:
+                    self.last_token[emitted] = out[total[emitted] - 1, emitted]
+                # acceptance telemetry, single-site: every consumed verify
+                # cycle lands in the dllama_spec_* series AND the engine totals
+                acc = advs - 1
+                msk = drafted > 0
+                n_drafted, n_acc = int(drafted.sum()), int(acc[msk].sum())
+                n_emit = int(total.sum())
+                self._spec_totals["cycles"] += m_cycles
+                self._spec_totals["drafted"] += n_drafted
+                self._spec_totals["accepted"] += n_acc
+                self._spec_totals["emitted"] += n_emit
+                ins.SPEC_CYCLES.inc(m_cycles)
+                ins.SPEC_TOKENS.labels(kind="drafted").inc(n_drafted)
+                ins.SPEC_TOKENS.labels(kind="accepted").inc(n_acc)
+                ins.SPEC_TOKENS.labels(kind="emitted").inc(n_emit)
+                # one bulk histogram update per distinct accepted length, not a
+                # Python observe() per (cycle, row) sample
+                for val, cnt in enumerate(np.bincount(acc[msk])):
+                    ins.SPEC_ACCEPTED_LENGTH.observe_n(val, int(cnt))
+                ins.BATCH_OCCUPANCY.observe(int((total > 0).sum()))
+                # the launch's record, now that its rows are known: a slot
+                # that emitted nothing was frozen for every cycle
+                chunk.launch = rec = launch_record.build(
+                    chunk.launch.kind, chunk.seq, m_cycles, chunk.start_pos,
+                    chunk.active, total, seq_len=self.seq_len,
+                    pool_dry=chunk.launch.pool_dry,
+                    frozen=np.where(total == 0, m_cycles, 0),
+                    window=self.window, kv_pool=self._kv_pool).count()
+                if tr.enabled:
+                    tr.span_at("decode.spec", chunk.t_disp, tr.now(),
+                               cat="decode", track="launches", chunk=chunk.seq,
+                               cycles=m_cycles,
+                               occupancy=int((total > 0).sum()),
+                               emitted=n_emit, accepted=n_acc, **rec.args())
+                return out
+            ins.BATCH_OCCUPANCY.observe(int(chunk.active.sum()))
             if tr.enabled:
-                tr.span_at("decode.spec", chunk.t_disp, tr.now(),
+                # the launch on the host clock: dispatch -> tokens on host.
+                # Under the overlapped pipeline this span brackets the NEXT
+                # chunk's dispatch span — the overlap, visible in Perfetto.
+                tr.span_at("decode.device", chunk.t_disp, tr.now(),
                            cat="decode", track="launches", chunk=chunk.seq,
-                           cycles=m_cycles,
-                           occupancy=int((total > 0).sum()),
-                           emitted=n_emit, accepted=n_acc, **rec.args())
-            return out
-        ins.BATCH_OCCUPANCY.observe(int(chunk.active.sum()))
-        if tr.enabled:
-            # the launch on the host clock: dispatch -> tokens on host.
-            # Under the overlapped pipeline this span brackets the NEXT
-            # chunk's dispatch span — the overlap, visible in Perfetto.
-            tr.span_at("decode.device", chunk.t_disp, tr.now(),
-                       cat="decode", track="launches", chunk=chunk.seq,
-                       occupancy=int(chunk.active.sum()),
-                       **chunk.launch.args())
-        self.last_token[chunk.active] = toks[-1, chunk.active]
-        return toks
+                           occupancy=int(chunk.active.sum()),
+                           **chunk.launch.args())
+            self.last_token[chunk.active] = toks[-1, chunk.active]
+            return toks
 
     def decode(self, n: int) -> np.ndarray:
         """n fused decode steps across all active slots; returns tokens

@@ -13,7 +13,8 @@ exist:
 * the args of the launch's span in the tracer ring (`decode.device` /
   `decode.spec`, track `launches`), behind `tr.enabled`;
 * while a jax.profiler capture runs, a `dllama.launch.<kind>` annotation
-  around the jit call (`obs/trace.PROFILER_HOOK`), on the profiler's clock.
+  around the jit call, on the profiler's clock: the `dispatch.call` phase
+  of the phase seam (`obs/perf.PhaseClock`) opens it over its own stretch.
 
 :data:`PROGRAMS` is the ONE table the program names come from: the word a
 program's `compile_obs.LEDGER.scope(fn, key)` uses (for the small boundary
@@ -109,14 +110,18 @@ class LaunchRecord:
                 kind=self.kind, pool=self.kv_pool).inc(self.kv_rows)
         return self
 
-    def annotation(self):
-        """The launch's profiler annotation, to be entered around the jit
-        call: the shared no-op span unless a capture is running."""
-        hook = trace.PROFILER_HOOK
-        if hook is None:
-            return trace.NULL_SPAN
+    def _annotation_args(self) -> dict:
         a = self.args()
-        return hook("dllama.launch." + a.pop("kind"), **a)
+        del a["kind"]  # it is in the annotation's name
+        return a
+
+    def annotation(self):
+        """The launch's profiler annotation `dllama.launch.<kind>`, entered
+        (obs/trace.profiler_annotation), or None when no capture runs. The
+        phase seam opens it around the jit call: the `dispatch.call` phase
+        given this record (obs/perf.PhaseClock)."""
+        return trace.profiler_annotation("dllama.launch.", self.kind,
+                                         self._annotation_args)
 
 
 def build(kind: str, seq: int, n: int, start_pos: np.ndarray,

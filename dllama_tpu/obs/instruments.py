@@ -408,6 +408,34 @@ SCHEDULER_TIME = metrics.counter(
     "(obs/perf.TimeLedger): the per-state totals partition loop wall time "
     "by construction, so fractions answer 'what is the scheduler doing'",
     ("state",))
+# phases under the states (ISSUE 40, obs/perf.PhaseClock): one named
+# stretch of the worker's host work, always inside one ledger state
+SCHEDULER_PHASE_SECONDS = metrics.counter(
+    "dllama_scheduler_phase_seconds_total",
+    "Scheduler worker host seconds by phase (obs/perf.PHASES): a named "
+    "stretch of work inside ONE ledger state; phases never overlap (one "
+    "that opens inside another suspends it), so a state's seconds minus "
+    "its phases' is the state's self time",
+    ("phase",))
+SCHEDULER_PHASES = metrics.counter(
+    "dllama_scheduler_phase_total",
+    "Times each scheduler phase was opened (a suspended phase that resumes "
+    "is not counted again): seconds over this is the cost of one",
+    ("phase",))
+PIPELINE_DRAINS = metrics.counter(
+    "dllama_pipeline_drains_total",
+    "Launches the overlapped loop consumed with NO successor queued, by the "
+    "first reason that asked for a boundary (obs/perf.DRAIN_REASONS): the "
+    "device then idles through emit, the boundary work and the next "
+    "dispatch. Over dllama_launches_total it is the drained share",
+    ("reason",))
+LAUNCH_WAITS = metrics.counter(
+    "dllama_launch_waits_total",
+    "Consumed launches by what the host found when it asked for the "
+    "tokens: ready (the read returned in under 0.5 ms: the device had "
+    "finished first, the host was the slower of the two that cycle) or "
+    "blocked (the host waited for the device)",
+    ("outcome",))
 SLO_VIOLATIONS = metrics.counter(
     "dllama_slo_violations_total",
     "Terminal requests that missed a configured SLO target, by kind "

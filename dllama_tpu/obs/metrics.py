@@ -267,6 +267,14 @@ class Histogram(_Family):
     def observe_n(self, v: float, n: int) -> None:
         self.labels().observe_n(v, n)
 
+    def series(self) -> dict:
+        """{"sum", "count"} over every child: one consistent read (the
+        profiler-capture snapshots, beside Counter.series)."""
+        with self._lock:
+            kids = list(self._children.values())
+            return {"sum": sum(c.sum for c in kids),
+                    "count": float(sum(sum(c.counts) for c in kids))}
+
     def _render_child(self, out, values, child) -> None:
         cum = 0
         for b, c in zip(self.buckets, child.counts):

@@ -24,6 +24,13 @@ right for; a kernel's share of its roofline is read from a trace by
   totals partition wall time by construction — their sum equals loop wall
   time to the clock's precision, which is the invariant
   tests/test_perf.py drives a real scheduler run through.
+* :class:`PhaseClock` — the phases UNDER the states (ISSUE 40): the one
+  seam that opens a span of the worker's host work (:data:`PHASES`), into
+  three sinks: the always-on counters
+  ``dllama_scheduler_phase_seconds_total{phase}`` / ``_total{phase}``, the
+  ``dllama.phase.<name>`` annotation while a profiler capture runs, the
+  ring span when the ring is on. Phases lie inside one state and never
+  overlap, so a state's seconds minus its phases' is its self time.
 * :class:`SloPolicy` / :class:`PerfAggregator` — configurable TTFT/ITL SLO
   targets (``--slo-ttft-ms`` / ``--slo-itl-ms``), burn counters
   (``dllama_slo_violations_total{kind}``), a windowed attainment gauge,
@@ -55,6 +62,37 @@ from dllama_tpu.utils import locks
 #: its own bucket instead of polluting either attribution.
 LEDGER_STATES = ("idle", "admission", "prefill", "hybrid", "decode_dispatch",
                  "decode_wait", "emit", "commit", "restart_backoff")
+
+#: the phases under the states (ISSUE 40): named stretches of the worker's
+#: host work, each inside ONE state, never overlapping each other: the
+#: label set of dllama_scheduler_phase_seconds_total{phase}, the
+#: `dllama.phase.<name>` annotations and the ring spans of the same names
+#: (obs/trace.SPAN_CATALOG holds one row each; README's phase table too)
+PHASES = ("dispatch.plan", "dispatch.build", "dispatch.call",
+          "dispatch.after", "consume.wait", "consume.fold", "emit.scan",
+          "emit.finish", "commit.sample", "commit.activate", "admit.start",
+          "admit.pump", "boundary.scan")
+
+#: why the overlapped loop consumed a launch with no successor queued: the
+#: label set of dllama_pipeline_drains_total{reason}, in the order
+#: Scheduler._boundary_reason asks (mode_switch is _dispatch_chunk's bail)
+DRAIN_REASONS = ("stop", "empty", "backlog", "recover", "arrival", "commit",
+                 "cancel", "deadline", "row_limit", "mode_switch")
+
+#: a launch's tokens were there when the host asked (the read took less
+#: than this): the device had finished first
+READY_WAIT_S = 0.0005
+
+for _p in PHASES:  # the series exist from the first scrape on
+    ins.SCHEDULER_PHASE_SECONDS.labels(phase=_p)
+    ins.SCHEDULER_PHASES.labels(phase=_p)
+for _r in DRAIN_REASONS:
+    ins.PIPELINE_DRAINS.labels(reason=_r)
+for _o in ("ready", "blocked"):
+    ins.LAUNCH_WAITS.labels(outcome=_o)
+for _s in LEDGER_STATES:
+    ins.SCHEDULER_TIME.labels(state=_s)
+ins.DECODE_HOST_GAP_SECONDS.labels()
 
 
 # ------------------------------------------------------------------ windows
@@ -314,13 +352,9 @@ class TimeLedger:
     def _stamp(self, state: str | None) -> None:
         """Close the open profiler annotation and, while a capture runs,
         open `state`'s (caller holds the lock)."""
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
-        hook = trace.PROFILER_HOOK
-        if hook is not None and state is not None:
-            self._ann = hook("dllama.sched." + state)
-            self._ann.__enter__()
+        trace.end_annotation(self._ann)
+        self._ann = (None if state is None else
+                     trace.profiler_annotation("dllama.sched.", state))
 
     def restamp(self) -> None:
         """Close and reopen the current state's annotation, from any thread
@@ -329,8 +363,12 @@ class TimeLedger:
         when it stops and never sees one opened before it started, so a
         capture calls this as it begins and just before it stops: the state
         it began in and the state still open at its end are then stamped
-        (a commit can hold the worker in one state for a whole launch)."""
+        (a commit can hold the worker in one state for a whole launch).
+        The open span is billed too, so the counter deltas a capture is
+        bracketed with hold the seconds inside it, not a state's whole
+        open stretch."""
         with self._lock:
+            self._bill(self._now())  # the counter is current at both ends
             self._stamp(self._state)
 
     def transition(self, state: str) -> None:
@@ -394,6 +432,149 @@ class TimeLedger:
             "fractions": {s: round(v / wall, 6) if wall > 0 else 0.0
                           for s, v in totals.items()},
         }
+
+
+# ------------------------------------------------------------------ phases
+
+
+class PhaseClock:
+    """The ONE seam that opens a span of the worker's host work (ISSUE 40).
+
+    ``with phases("emit.scan", seq):`` names a stretch of the scheduler
+    worker's (or a direct engine caller's) host work, a :data:`PHASES`
+    word, done for the launch `seq`. One way to open it, three sinks:
+
+    * always: its seconds go to
+      ``dllama_scheduler_phase_seconds_total{phase}`` and one to
+      ``dllama_scheduler_phase_total{phase}`` (two clock reads, a lock, two
+      counter adds; nothing is built);
+    * while a jax.profiler capture runs it is one ``dllama.phase.<name>``
+      annotation on the profiler's clock, under the open
+      ``dllama.sched.<state>``, carrying `seq` and, while the pipeline is
+      drained, the reason (:attr:`drain`). A phase given a launch record
+      (`launch`: the jit call, ``dispatch.call``) is that record's
+      ``dllama.launch.<kind>`` annotation instead, over the same stretch;
+    * when the tracer ring is on it is the ring span of the phase's name
+      on the ``scheduler`` track (arg ``chunk`` = seq).
+
+    Phases never overlap: one that opens inside another SUSPENDS it (its
+    time so far is billed, its annotation and ring span closed) and the
+    outer one resumes when the inner closes, so ``emit.scan`` is the emit
+    loop without the finishes that ``emit.finish`` times inside it, and a
+    state's seconds minus its phases' is the state's self time.
+    :attr:`by_state` keeps the seconds by (ledger state at billing, phase).
+
+    One object serves every call (``phases(...)`` returns itself), owned by
+    the thread that drives the engine; :meth:`restamp` and
+    :meth:`snapshot` may come from other threads, hence the lock."""
+
+    def __init__(self, ledger: "TimeLedger | None" = None,
+                 now_fn=time.monotonic):
+        self.ledger = ledger  # the scheduler's, once one drives the engine
+        self.drain: str | None = None  # the reason the pipeline is drained
+        self.last_s = 0.0  # seconds of the phase closed last (its last run)
+        self.by_state: dict = {}
+        self._now = now_fn
+        self._lock = locks.make_lock("obs.perf")
+        self._series = {p: (ins.SCHEDULER_PHASE_SECONDS.labels(phase=p),
+                            ins.SCHEDULER_PHASES.labels(phase=p))
+                        for p in PHASES}
+        # the open phases, innermost last (parallel lists: nothing is
+        # allocated to open one)
+        self._names: list = []
+        self._seqs: list = []
+        self._launches: list = []
+        self._t = 0.0  # when the innermost phase started or resumed
+        self._ann = None  # its profiler annotation, if a capture runs
+        self._args = self._annotation_args  # bound once: no per-call object
+
+    def current(self) -> str | None:
+        """The phase the owner is in now (None: a state's self time)."""
+        with self._lock:
+            return self._names[-1] if self._names else None
+
+    def __call__(self, name: str, seq: int = 0, launch=None) -> "PhaseClock":
+        series = self._series.get(name)
+        if series is None:
+            raise ValueError(f"unknown phase {name!r} (catalog: {PHASES})")
+        state = self.ledger.state() if self.ledger is not None else None
+        with self._lock:
+            now = self._now()
+            if self._names:
+                self._bill(now, state)  # the outer phase pauses
+            self._names.append(name)
+            self._seqs.append(seq)
+            self._launches.append(launch)
+            series[1].inc()
+            self._start(now)
+        return self
+
+    def __enter__(self) -> "PhaseClock":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        state = self.ledger.state() if self.ledger is not None else None
+        with self._lock:
+            now = self._now()
+            self.last_s = self._bill(now, state)
+            self._names.pop()
+            self._seqs.pop()
+            self._launches.pop()
+            if self._names:
+                self._start(now)  # the outer phase resumes
+        return False
+
+    def _annotation_args(self) -> dict:
+        a = {"seq": self._seqs[-1]}
+        if self.drain is not None:
+            a["drain"] = self.drain
+        return a
+
+    def _start(self, now: float) -> None:
+        """(Re)open the innermost phase's clock and annotation (caller
+        holds the lock)."""
+        self._t = now
+        launch = self._launches[-1]
+        self._ann = (launch.annotation() if launch is not None else
+                     trace.profiler_annotation("dllama.phase.",
+                                               self._names[-1], self._args))
+
+    def _bill(self, now: float, state) -> float:
+        """Close the innermost phase's clock, annotation and ring span
+        (caller holds the lock); the seconds billed."""
+        trace.end_annotation(self._ann)
+        self._ann = None
+        name = self._names[-1]
+        dt = max(now - self._t, 0.0)
+        self._series[name][0].inc(dt)
+        key = (state, name)
+        self.by_state[key] = self.by_state.get(key, 0.0) + dt
+        tr = trace.TRACER
+        if tr.enabled:
+            tr.span_at(name, self._t, now, cat="phase", track="scheduler",
+                       chunk=self._seqs[-1])
+        return dt
+
+    def restamp(self) -> None:
+        """Pause and resume the open phase, from any thread: its seconds so
+        far are billed and its annotation is closed and reopened. A capture
+        calls this as it begins and just before it stops, as it does
+        :meth:`TimeLedger.restamp`, and for the same reasons."""
+        state = self.ledger.state() if self.ledger is not None else None
+        with self._lock:
+            if self._names:
+                now = self._now()
+                self._bill(now, state)
+                self._start(now)
+
+    def snapshot(self) -> dict:
+        """{state: {phase: seconds}} as billed so far (`/debug/perf`)."""
+        with self._lock:
+            items = list(self.by_state.items())
+        out: dict = {}
+        for (state, name), v in items:
+            out.setdefault(state or "none", {})[name] = round(v, 6)
+        return out
 
 
 # -------------------------------------------------------------- SLO policy
@@ -617,9 +798,11 @@ class PerfAggregator:
         ins.THROUGHPUT.set(roof["throughput_tok_s"])
         ins.GOODPUT.set(roof["goodput_tok_s"])
 
-    def snapshot(self, ledger: TimeLedger | None = None) -> dict:
+    def snapshot(self, ledger: TimeLedger | None = None,
+                 phases: PhaseClock | None = None) -> dict:
         """The `/debug/perf` join: windowed quantiles, SLO accounting,
-        ledger attribution, goodput/throughput — one JSON document."""
+        ledger attribution (with the phases' seconds under each state),
+        goodput/throughput — one JSON document."""
         out = {
             "window": self.window_snapshot(),
             "slo": self.slo_snapshot(),
@@ -627,4 +810,6 @@ class PerfAggregator:
         }
         if ledger is not None:
             out["ledger"] = ledger.snapshot()
+            if phases is not None:
+                out["ledger"]["phases"] = phases.snapshot()
         return out

@@ -80,11 +80,11 @@ SPAN_CATALOG = {
     "prefill": "whole admission prefill: popped -> first token committed (track: requests)",
     "prefill.chunk": "one pumped prefill chunk, device-synced whenever decoders would stall (track: scheduler)",
     "request": "whole request lifetime: submit -> terminal state (track: requests)",
-    "decode.dispatch": "host work to dispatch one fused decode chunk (track: scheduler)",
-    "decode.consume": "blocking wait for a dispatched chunk's tokens (track: scheduler)",
+    "decode.dispatch": "host work to dispatch one fused decode chunk, engine call to its return: the phases dispatch.build/.call/.after lie inside it; args chunk/n/occupancy/spec/pipelined/hybrid_tokens/host_gap_ms (track: scheduler)",
+    "decode.consume": "blocking wait for a dispatched chunk's tokens + the fold into the host mirrors: the phases consume.wait/.fold lie inside it (track: scheduler)",
     "decode.device": "one engine launch on the HOST clock, dispatch -> its tokens on the host (a prefill chunk: dispatch -> the call returned; its sync is prefill.chunk's); NOT a device time. Args carry the launch record: kind/seq/n/active/starved/kv_rows/prefill_rows (track: launches)",
     "decode.spec": "one fused speculative propose/verify launch, dispatch -> its counts on the host, with the same launch record plus cycles/emitted/accepted (track: launches)",
-    "emit.scan": "post-consume token emit + EOS/budget stop scan (track: scheduler)",
+    "emit.scan": "phase: the per-slot token emit + EOS/budget stop scan of one consumed launch, without the finishes (track: scheduler)",
     "compile": "one jit trace/lower/compile attributed to a dispatch site (obs/compile ledger); args carry fn/key/classification — visible in Perfetto as compile stealing device time mid-traffic (track: compile)",
     "proxy": "router: one relay leg of a proxied SSE stream — headers to terminal frame or upstream death; args carry replica/verdict (track: router)",
     "connect": "router: connect + request + response headers of one upstream forwarding attempt; args carry replica/hop (track: router)",
@@ -92,10 +92,28 @@ SPAN_CATALOG = {
     "failover.attempt": "router: one mid-stream failover attempt — the jittered exponential backoff + survivor pick before a resume dispatch; args carry attempt (track: router)",
     "resume": "router: connect + resume request to a survivor replica, journal replay included; args carry replica/tokens (track: router)",
     "journal": "router: a proxied stream's failover-journal hold window, acquire to release; args carry valid (False = ring-capped, unresumable) + tokens journaled + retries (track: router)",
+    # the phases of the scheduler worker's host work (obs/perf.PHASES, one
+    # seam: obs/perf.PhaseClock): each is a ring span under its own name
+    # (track: scheduler, arg chunk = the seq of the launch it works for), a
+    # dllama.phase.<name> annotation while a capture runs, and always the
+    # counters dllama_scheduler_phase_seconds_total / _total{phase}
+    "dispatch.plan": "phase: _dispatch_chunk up to the engine call: spec eligibility, the mode switch, the host-gap stamp, the budget controller",
+    "dispatch.build": "phase: the engine's dispatch before the jit call: page top-up, window advance, the vectors' h2d, the host arrays, the launch record",
+    "dispatch.call": "phase: the jit call of one launch (on the profiler's clock it is the dllama.launch.<kind> annotation)",
+    "dispatch.after": "phase: the engine's dispatch after the jit call returned: the record's counters, the expert-counter snapshot, the history backfill, the DecodeChunk",
+    "consume.wait": "phase: the blocking read of a launch's tokens (np.asarray): the host's slack; under 0.5 ms the device had finished first (dllama_launch_waits_total{outcome})",
+    "consume.fold": "phase: the consumed launch folded into the host mirrors: expert counters, the spec counts, chunk timing",
+    "emit.finish": "phase: one request's finish: slot release, radix insert, metrics ring, flight record, the out queue's end mark",
+    "commit.sample": "phase: the eager first-token sampling of a pumped admission (add_sample)",
+    "commit.activate": "phase: a pumped admission's commit: add_commit / resume_commit, slot activation, radix insert, the first emit",
+    "admit.start": "phase: _admit_starts: the queue, sheds, preemption, slot choice, prefix mapping, add_begin",
+    "admit.pump": "phase: one pumped prefill chunk outside the hybrid step: add_step + its device sync",
+    "boundary.scan": "phase: the cancel / deadline / row-limit / page-starved scans of the loop and the boundary decision (_boundary_reason)",
     # written onto the PROFILER's clock while a capture runs (PROFILER_HOOK
     # below), not into the ring; named by prefix
     "dllama.launch.": "profiler annotation dllama.launch.<kind> around one engine launch's jit call, kind one of engine/launch_record.LAUNCH_KINDS; args seq/n/active/starved/kv_rows/prefill_rows (a spec launch's rows are known only when consumed: its annotation carries seq/n/active)",
     "dllama.sched.": "profiler annotation dllama.sched.<state>: the scheduler worker's exclusive time-ledger state (obs/perf.LEDGER_STATES), opened and closed at each transition (and restamped at a capture's two ends, TimeLedger.restamp), so the states tile the host plane",
+    "dllama.phase.": "profiler annotation dllama.phase.<name>: one phase of the worker's host work (obs/perf.PHASES) under the open dllama.sched.<state>; args seq (the launch it works for) and, while the pipeline is drained, drain (the reason, obs/perf.DRAIN_REASONS); dispatch.call is the dllama.launch.<kind> annotation",
 }
 
 #: instant-event names (``ph: "i"`` in the export), same drift contract
@@ -122,9 +140,32 @@ EVENT_CATALOG = {
 #: for the length of a capture (this package stays stdlib-only). A call
 #: ``PROFILER_HOOK(name, **args)`` gives a context manager that stamps one
 #: event onto the host plane of the capture's .xplane.pb, whose device plane
-#: the same profiler stamps: no clock arithmetic joins them. Hot paths load
-#: the attribute once and test it for None before building any argument.
+#: the same profiler stamps: no clock arithmetic joins them. It is read in
+#: ONE function, :func:`profiler_annotation`, which every writer of the
+#: profiler's clock calls (the ledger's states, the launch records, the
+#: phases).
 PROFILER_HOOK = None
+
+
+def profiler_annotation(prefix: str, leaf: str, args=None):
+    """Open one annotation ``prefix + leaf`` on the profiler's clock and
+    hand it back ENTERED (close it with :func:`end_annotation`, from any
+    thread), or None when no capture runs: then nothing is built, not
+    the name and not the arguments. `args` is a zero-argument callable that
+    gives the arguments' dict, called only while a capture runs."""
+    hook = PROFILER_HOOK
+    if hook is None:
+        return None
+    ann = hook(prefix + leaf, **(args() if args is not None else {}))
+    ann.__enter__()
+    return ann
+
+
+def end_annotation(ann) -> None:
+    """Close what :func:`profiler_annotation` handed back (None: nothing
+    was opened), from any thread."""
+    if ann is not None:
+        ann.__exit__(None, None, None)
 
 
 def _clean(v):

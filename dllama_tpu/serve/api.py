@@ -1271,7 +1271,8 @@ class RequestRoutes:
             # read as idle seconds now, not at the next state transition
             sched.perf.refresh_gauges()  # /metrics and this JSON agree
             payload["mode"] = "continuous"
-            payload.update(sched.perf.snapshot(ledger=sched.ledger))
+            payload.update(sched.perf.snapshot(ledger=sched.ledger,
+                                               phases=sched.phases))
             # saved-prefill accounting (radix prefix cache; None when off):
             # hit_tokens are prompt rows that cost zero prefill FLOPs
             payload["radix"] = (sched.engine.radix_stats()
@@ -1488,7 +1489,7 @@ class RequestRoutes:
             info = profiling.start_profile(
                 log_dir=body.get("dir"),
                 duration_s=body.get("duration_s", 2.0),
-                restamp=sched.ledger.restamp if sched is not None else None)
+                restamp=sched.restamp if sched is not None else None)
         except profiling.ProfileBusy as e:
             self._send_json(409, {"error": {"message": str(e)}},
                             {"Retry-After": "2"})

@@ -369,11 +369,11 @@ class Scheduler:
         # inter-chunk host gap: time from one chunk's tokens materializing to
         # the next chunk's dispatch — the device-idle window host scheduling
         # inserts. ~0 under overlap (chunk N+1 dispatches before chunk N is
-        # consumed); the lockstep A/B baseline shows the real gap. Mirrors
-        # the dllama_decode_host_gap_seconds histogram.
-        self._host_gap_ms: list[float] = []
+        # consumed); the lockstep A/B baseline shows the real gap. Kept ONCE,
+        # in the dllama_decode_host_gap_seconds histogram: latency_summary
+        # reads its movement since this mark.
+        self._host_gap_mark = ins.DECODE_HOST_GAP_SECONDS.series()
         self._t_consumed: float | None = None
-        self._last_gap_ms: float | None = None  # latest host gap (trace arg)
         # gated spec/decode alternation: per-slot eligibility (ISSUE 11)
         # lets sampled, penalized, and non-spec traffic ride spec cycles
         # one token at a time, so the only slots a cycle still freezes are
@@ -416,6 +416,11 @@ class Scheduler:
         # burn/attainment accounting (--slo-ttft-ms / --slo-itl-ms), and
         # goodput vs throughput. Both feed GET /debug/perf and /metrics.
         self.ledger = perf.TimeLedger(counter=ins.SCHEDULER_TIME)
+        # phases under the states (obs/perf.PhaseClock): ONE clock for the
+        # worker's host work, the engine's half of a dispatch included (an
+        # engine without one, a test's stand-in, gets the scheduler's)
+        self.phases = getattr(engine, "phases", None) or perf.PhaseClock()
+        self.phases.ledger = self.ledger
         self.perf = perf.PerfAggregator(
             slo=perf.SloPolicy(
                 None if slo_ttft_ms is None else float(slo_ttft_ms),
@@ -749,7 +754,8 @@ class Scheduler:
         with self._metrics_lock:
             done = list(self._completed)
             gaps = list(self._admit_gaps_ms)
-            hgaps = list(self._host_gap_ms)
+        hgap = ins.DECODE_HOST_GAP_SECONDS.series()
+        n_hgaps = int(hgap["count"] - self._host_gap_mark["count"])
         ttfts = [r.ttft_ms for r in done if r.ttft_ms is not None]
         itls = [r.itl_ms for r in done if r.itl_ms is not None]
         mean = lambda xs: sum(xs) / len(xs) if xs else None
@@ -770,9 +776,10 @@ class Scheduler:
             "admission_gaps": len(gaps),
             "admission_stall_ms_max": max(gaps) if gaps else None,
             "admission_stall_ms_mean": mean(gaps),
-            "decode_host_gaps": len(hgaps),
-            "decode_host_gap_ms_max": max(hgaps) if hgaps else None,
-            "decode_host_gap_ms_mean": mean(hgaps),
+            "decode_host_gaps": n_hgaps,
+            "decode_host_gap_ms_mean": (
+                (hgap["sum"] - self._host_gap_mark["sum"]) * 1000.0 / n_hgaps
+                if n_hgaps > 0 else None),
             # paged KV pool occupancy (None on the dense layout) — the same
             # numbers the dllama_kv_pages_{total,used,shared} gauges export
             "kv_pages": self.engine.kv_page_stats()
@@ -813,7 +820,7 @@ class Scheduler:
         with self._metrics_lock:
             self._completed.clear()
             self._admit_gaps_ms.clear()
-            self._host_gap_ms.clear()
+        self._host_gap_mark = ins.DECODE_HOST_GAP_SECONDS.series()
         self._t_dec_end = None
         self._t_consumed = None
         # fresh sliding windows too: warmup-compile latencies must not sit
@@ -885,38 +892,53 @@ class Scheduler:
                     else (req.finished_at - req.submitted_at) * 1000.0),
             tokens=req.produced)
 
+    def restamp(self) -> None:
+        """A profiler capture's two ends (utils/profiling.start_profile):
+        the open state's and the open phase's annotations are closed and
+        reopened, so the capture holds both."""
+        self.ledger.restamp()
+        self.phases.restamp()
+
+    def _next_seq(self) -> int:
+        """The seq of the launch the worker's next dispatch will make: what
+        a phase of boundary, admission or commit work is done for."""
+        return int(getattr(self.engine, "chunk_seq", 0)) + 1
+
     def _finish(self, req: Request, reason: str, keep_rows: int | None = None) -> None:
-        if req.slot >= 0:
-            if self._radix is not None:
-                # the tree is the cache: insert the trustworthy emitted
-                # prefix (full pages adopt a tree reference), then hand the
-                # slot's every page back — idle slots stay empty, and reuse
-                # for future requests comes from the tree, not the slot.
-                # keep_rows=None means the rows are unspecified (error/NaN/
-                # crash paths): nothing enters the tree.
-                if keep_rows:
-                    self.engine.radix_insert(
-                        req.slot, self.slot_tokens.get(req.slot, [])[:keep_rows])
-                self.engine.release(req.slot, None)
-                self.slot_tokens[req.slot] = []
-            else:
-                self.engine.release(req.slot, keep_rows)
-                if keep_rows is not None:
-                    # only the first keep_rows tokens have live KV rows (the
-                    # last emitted token was sampled but never fed back)
-                    self.slot_tokens[req.slot] = self.slot_tokens.get(req.slot, [])[:keep_rows]
+        # one phase wherever a request ends (emit, a boundary scan, an
+        # aborted admission): the phase it ends in pauses meanwhile
+        with self.phases("emit.finish", self._next_seq() - 1):
+            if req.slot >= 0:
+                if self._radix is not None:
+                    # the tree is the cache: insert the trustworthy emitted
+                    # prefix (full pages adopt a tree reference), then hand the
+                    # slot's every page back — idle slots stay empty, and reuse
+                    # for future requests comes from the tree, not the slot.
+                    # keep_rows=None means the rows are unspecified (error/NaN/
+                    # crash paths): nothing enters the tree.
+                    if keep_rows:
+                        self.engine.radix_insert(
+                            req.slot, self.slot_tokens.get(req.slot, [])[:keep_rows])
+                    self.engine.release(req.slot, None)
+                    self.slot_tokens[req.slot] = []
                 else:
-                    self.slot_tokens[req.slot] = []  # unknown state: never reuse
-            self.slots.pop(req.slot, None)
-            req.slot = -1
-        req.finish_reason = req.finish_reason or reason
-        req.finished_at = time.monotonic()
-        with self._metrics_lock:
-            self._completed.append(req)
-            del self._completed[:-256]  # bound the ring
-        self._observe_finish(req)
-        ins.BUSY_SLOTS.set(len(self.slots))
-        req.out.put(_END)
+                    self.engine.release(req.slot, keep_rows)
+                    if keep_rows is not None:
+                        # only the first keep_rows tokens have live KV rows (the
+                        # last emitted token was sampled but never fed back)
+                        self.slot_tokens[req.slot] = self.slot_tokens.get(req.slot, [])[:keep_rows]
+                    else:
+                        self.slot_tokens[req.slot] = []  # unknown state: never reuse
+                self.slots.pop(req.slot, None)
+                req.slot = -1
+            req.finish_reason = req.finish_reason or reason
+            req.finished_at = time.monotonic()
+            with self._metrics_lock:
+                self._completed.append(req)
+                del self._completed[:-256]  # bound the ring
+            self._observe_finish(req)
+            ins.BUSY_SLOTS.set(len(self.slots))
+            req.out.put(_END)
 
     def _emit(self, req: Request, token: int, row_at_emit: int) -> bool:
         """Queue one token; returns True when the request just finished."""
@@ -1464,6 +1486,13 @@ class Scheduler:
         # second pop eating the NEXT admission's entry
         assert self._inflight and self._inflight[0][1] is adm
         self._inflight.pop(0)
+        with self.phases("commit.activate", self._next_seq()):
+            self._activate_admission(req, adm, reuse)
+
+    def _activate_admission(self, req: Request, adm, reuse: int) -> None:
+        """_commit_admission's body under its `commit.activate` phase (the
+        engine times a first token it still has to sample as
+        `commit.sample`, which pauses this one)."""
         if req.resume_tokens is not None:
             # restart/preemption resume: install the last emitted token and
             # the recorded PRNG key as the decode carry — no new token is
@@ -1633,28 +1662,14 @@ class Scheduler:
                 tr = trace.TRACER
                 done = pumped
                 if not pumped:
-                    t_ch = tr.now() if tr.enabled else 0.0
                     self.ledger.transition("prefill")
-                    done = self.engine.add_step(adm)
-                    if self.slots and adm.logits is not None:
-                        # sync whenever decoders could stall: JAX dispatch is
-                        # async, so without this the pacing clock AND the
-                        # admission-gap metric would see host dispatch time
-                        # only (near zero on TPU) while the chunk's device
-                        # time silently serialized into the next decode
-                        # chunk — under-pacing the budget and mis-attributing
-                        # the stall. Applied in every admission mode so the
-                        # sync/strict/paced A/B compares like with like; the
-                        # chunk must finish before the next decode chunk
-                        # anyway (same device stream). With no decoders there
-                        # is no stall to attribute and dispatch stays
-                        # pipelined.
-                        jax.block_until_ready(adm.logits)
+                    sp = tr.span("prefill.chunk", cat="prefill",
+                                 req_id=req.req_id)
+                    with self.phases("admit.pump", self._next_seq()):
+                        done = self._pump_chunk(adm)
                     if tr.enabled:
-                        tr.span_at("prefill.chunk", t_ch, tr.now(),
-                                   cat="prefill", track="scheduler",
-                                   req_id=req.req_id, slot=adm.slot,
-                                   off=int(adm.off), total=len(adm.toks))
+                        sp.end(slot=adm.slot, off=int(adm.off),
+                               total=len(adm.toks))
                     worked = True
                 if done:
                     self._commit_admission(req, adm, reuse)
@@ -1694,6 +1709,26 @@ class Scheduler:
             # stall budget spent: let a decode chunk run now
             return worked
         return worked
+
+    def _pump_chunk(self, adm) -> bool:
+        """One prefill chunk of a pumped admission and its device sync (the
+        `admit.pump` phase); True when the prompt is fully written."""
+        done = self.engine.add_step(adm)
+        if self.slots and adm.logits is not None:
+            # sync whenever decoders could stall: JAX dispatch is
+            # async, so without this the pacing clock AND the
+            # admission-gap metric would see host dispatch time
+            # only (near zero on TPU) while the chunk's device
+            # time silently serialized into the next decode
+            # chunk — under-pacing the budget and mis-attributing
+            # the stall. Applied in every admission mode so the
+            # sync/strict/paced A/B compares like with like; the
+            # chunk must finish before the next decode chunk
+            # anyway (same device stream). With no decoders there
+            # is no stall to attribute and dispatch stays
+            # pipelined.
+            jax.block_until_ready(adm.logits)
+        return done
 
     def _fail_req(self, req: Request, exc: BaseException) -> None:
         """Crash-path finish: mark the request failed and unblock its
@@ -1906,51 +1941,63 @@ class Scheduler:
                     len(recover), self.pending.qsize())
         return True
 
-    def _needs_boundary(self, inflight_chunk=None) -> bool:
-        """True when the next chunk must wait for a fully-consumed pipeline:
-        admission work (a prefill must not race the in-flight chunk's
-        donated cache, and commit/release need settled host mirrors), a
-        pending cancel, a slot at the cache edge, or an emptied batch.
-        Speculative cycles pipeline like plain chunks (their data-dependent
-        counts materialize at consumption; _dispatch_chunk drains the
-        pipeline itself on a spec<->plain mode switch). The overlapped loop
-        then consumes its in-flight chunk WITHOUT dispatching a successor,
-        and the next iteration runs the boundary work on settled state —
-        admission pumps are serialized at chunk consumption points."""
+    def _boundary_reason(self, inflight_chunk=None) -> str | None:
+        """Why the next chunk must wait for a fully-consumed pipeline, as
+        the FIRST clause that asks for it (an obs/perf.DRAIN_REASONS word),
+        or None when a successor may be dispatched off the chunk in flight.
+        Boundary work: admission (a prefill must not race the in-flight
+        chunk's donated cache, and commit/release need settled host
+        mirrors), a pending cancel, a slot at the cache edge, or an emptied
+        batch. Speculative cycles pipeline like plain chunks (their
+        data-dependent counts materialize at consumption; _dispatch_chunk
+        drains the pipeline itself on a spec<->plain mode switch, the
+        reason `mode_switch`). The overlapped loop then consumes its
+        in-flight chunk WITHOUT dispatching a successor, counts the drain
+        under the reason (dllama_pipeline_drains_total), and the next
+        iteration runs the boundary work on settled state — admission
+        pumps are serialized at chunk consumption points."""
         if self._stop.is_set():
-            return True
-        if (not self.slots or self._deferred is not None
-                or self._recover or self._backlog
-                or not self.pending.empty()):
-            return True
+            return "stop"
+        if not self.slots:
+            return "empty"
+        if self._deferred is not None:
+            return "backlog"  # the head, parked until pages free up
+        if self._recover:
+            return "recover"
+        if self._backlog:
+            return "backlog"
+        if not self.pending.empty():
+            return "arrival"
         if self._inflight:
             # hybrid admissions ride the pipelined chunks — no boundary
             # needed while the head is mid-prefill and healthy. Commit,
             # abort (cancel/deadline), and the TTFT-deadline override all
             # need settled state, so those drain the pipeline.
             if not self._hybrid_now():
-                return True
+                return "backlog"  # an admission the boundary's pump feeds
             req, adm, _ = self._inflight[0]
             now0 = time.monotonic()
             # the pipelined commit takes a pumped head after the chunk in
             # flight is consumed: no boundary for it
-            pumped = adm.off >= len(adm.toks) and not (
-                self._pipelined_commit and inflight_chunk is not None)
-            if (pumped or req.cancelled.is_set()
-                    or (req.deadline_at is not None
-                        and now0 >= req.deadline_at)
+            if adm.off >= len(adm.toks) and not (
+                    self._pipelined_commit and inflight_chunk is not None):
+                return "commit"
+            if req.cancelled.is_set():
+                return "cancel"
+            if ((req.deadline_at is not None and now0 >= req.deadline_at)
                     or (self.admit_ttft_deadline_ms is not None
                         and (now0 - req.submitted_at) * 1000.0
                         >= self.admit_ttft_deadline_ms)):
-                return True
+                return "deadline"
         now = time.monotonic()
-        if any(r.cancelled.is_set()
-               or (r.deadline_at is not None and now >= r.deadline_at)
-               for r in self.slots.values()):
+        for r in self.slots.values():
             # a pending cancel OR an expired per-request deadline needs
             # boundary work: "running requests finish with
             # finish_reason='timeout' at the next chunk boundary"
-            return True
+            if r.cancelled.is_set():
+                return "cancel"
+            if r.deadline_at is not None and now >= r.deadline_at:
+                return "deadline"
         # row limit = seq_len on dense; on paged also each slot's allocated
         # pages — a slot AT its limit needs boundary work (finish at the
         # context edge, or page top-up/starvation handling on the pool)
@@ -1959,7 +2006,7 @@ class Scheduler:
         if any(int(self.engine.pos[s]) >= (self.engine.seq_len if limit is None
                                            else int(limit[s]))
                for s in self.slots):
-            return True
+            return "row_limit"
         if inflight_chunk is not None:
             # budget finishes are host-predictable (unlike EOS): when EVERY
             # live request exhausts max_tokens within the chunk already in
@@ -1972,16 +2019,19 @@ class Scheduler:
             # chunk costs a whole wasted device launch.
             if inflight_chunk.spec:
                 bound = inflight_chunk.n * (int(self.engine.spec_k) + 1)
-                return all(req.produced + bound >= req.max_tokens
-                           for req in self.slots.values())
-            return all(
-                req.produced + int(inflight_chunk.advance[slot]) >= req.max_tokens
-                for slot, req in self.slots.items()
-            )
-        return False
+                emptied = all(req.produced + bound >= req.max_tokens
+                              for req in self.slots.values())
+            else:
+                emptied = all(
+                    req.produced + int(inflight_chunk.advance[slot])
+                    >= req.max_tokens
+                    for slot, req in self.slots.items())
+            if emptied:
+                return "empty"  # the chunk in flight ends every request
+        return None
 
     def _observe_host_gap(self, pipeline_empty: bool,
-                          exclude_s: float = 0.0) -> None:
+                          exclude_s: float = 0.0) -> float | None:
         """Inter-chunk host gap, stamped at every chunk dispatch: how long
         the device sat idle on SCHEDULING overhead between chunks. A
         dispatch into an EMPTY pipeline pays the wall time since the
@@ -1989,19 +2039,15 @@ class Scheduler:
         boundary work — that stall is ADMISSION_STALL_SECONDS's story, and
         polluting this series with it would drown the per-chunk signal); a
         dispatch while a chunk is still in flight pays nothing — the device
-        never went idle, which is the overlap win the A/B measures."""
+        never went idle, which is the overlap win the A/B measures. The
+        histogram is the one record; the seconds are handed back for the
+        `decode.dispatch` span's argument (None before the first chunk)."""
         if self._t_consumed is None:
-            self._last_gap_ms = None
-            return
+            return None
         gap_s = (max(0.0, time.monotonic() - self._t_consumed - exclude_s)
                  if pipeline_empty else 0.0)
         ins.DECODE_HOST_GAP_SECONDS.observe(gap_s)
-        # stashed for the decode.dispatch span's host_gap_ms arg — the trace
-        # shows per-chunk what the histogram shows in aggregate
-        self._last_gap_ms = gap_s * 1000.0
-        with self._metrics_lock:
-            self._host_gap_ms.append(gap_s * 1000.0)
-            del self._host_gap_ms[:-256]
+        return gap_s
 
     def _dispatch_chunk(self, pipeline_empty: bool = True,
                         exclude_gap_s: float = 0.0, inflight=None):
@@ -2044,6 +2090,64 @@ class Scheduler:
                 hyb_adm, hyb_req = _adm, _req
         self.ledger.transition("hybrid" if hyb_adm is not None
                                else "decode_dispatch")
+        with self.phases("dispatch.plan", self._next_seq()):
+            plan = self._plan_chunk(hyb_adm, inflight)
+            if plan is None:
+                return None  # mode switch: consume the chunk in flight first
+            use_spec, n_disp = plan
+            gap_s = self._observe_host_gap(pipeline_empty, exclude_gap_s)
+            if hyb_adm is not None and self._budget_ctl is not None:
+                # SLO-driven budget: re-evaluated against the live ITL
+                # window (rate-limited inside the controller)
+                self._budget_now = self._budget_ctl.update(self.perf.itl)
+        # the dispatch span: pure host work, the engine's dispatch.build /
+        # .call / .after phases inside it. Under overlap it lands INSIDE the
+        # previous chunk's decode.device span — the interleaving
+        # scripts/trace_smoke.sh asserts on.
+        tr = trace.TRACER
+        sp = tr.span("decode.dispatch", cat="decode")
+        # the flight recorder's prefill story stays complete under hybrid:
+        # each fused slice is a prefill.chunk span for the ADMITTING request,
+        # bracketing the dispatch
+        sp_slice = (tr.span("prefill.chunk", cat="prefill",
+                            req_id=hyb_req.req_id)
+                    if hyb_adm is not None else trace.NULL_SPAN)
+        if hyb_adm is None:
+            chunk = self.engine.decode_dispatch(n_disp, spec=use_spec)
+        else:
+            try:
+                chunk = self.engine.hybrid_dispatch(n_disp, hyb_adm,
+                                                    self._budget_now)
+            except faults.InjectedFault as e:
+                if e.point != "engine.prefill":
+                    raise  # decode-point drills keep the fatal contract
+                # the per-request admission-failure contract survives
+                # hybrid: the engine.prefill drill fires BEFORE
+                # hybrid_dispatch mutates any state, so the engine is
+                # clean — fail just the joiner and dispatch a plain chunk
+                # for the batch. (A GENUINE failure inside the fused
+                # launch is indistinguishable from a decode failure — the
+                # jit donates the cache — and stays engine-fatal, handled
+                # by warm restart.)
+                self._inflight[:] = [e_ for e_ in self._inflight
+                                     if e_[1] is not hyb_adm]
+                self._abort_admission(hyb_req, hyb_adm, e)
+                chunk = self.engine.decode_dispatch(n_disp, spec=False)
+        self.phases.drain = None  # the pipeline holds a launch again
+        if tr.enabled:
+            sp.end(chunk=chunk.seq, n=chunk.n, occupancy=len(self.slots),
+                   spec=use_spec, pipelined=not pipeline_empty,
+                   hybrid_tokens=(chunk.hybrid_tokens or None),
+                   host_gap_ms=(None if gap_s is None
+                                else round(gap_s * 1000.0, 3)))
+            if chunk.hybrid_tokens:
+                sp_slice.end(slot=chunk.hybrid_slot, off=int(hyb_adm.off),
+                             total=len(hyb_adm.toks), hybrid=True)
+        return chunk, dict(self.slots)
+
+    def _plan_chunk(self, hyb_adm, inflight):
+        """The `dispatch.plan` phase's choice: (use_spec, steps) of the
+        chunk to dispatch, or None when `inflight` is of the other mode."""
         use_spec = False
         alternating = False
         if getattr(self.engine, "spec_k", 0) and hyb_adm is None:
@@ -2081,59 +2185,7 @@ class Scheduler:
             if all(req.max_tokens - req.produced <= k1
                    for req in self.slots.values()):
                 n_disp = 1
-        self._observe_host_gap(pipeline_empty, exclude_gap_s)
-
-        def _launch():
-            if hyb_adm is None:
-                return self.engine.decode_dispatch(n_disp, spec=use_spec)
-            if self._budget_ctl is not None:
-                # SLO-driven budget: re-evaluated against the live ITL
-                # window (rate-limited inside the controller)
-                self._budget_now = self._budget_ctl.update(self.perf.itl)
-            try:
-                return self.engine.hybrid_dispatch(n_disp, hyb_adm,
-                                                   self._budget_now)
-            except faults.InjectedFault as e:
-                if e.point != "engine.prefill":
-                    raise  # decode-point drills keep the fatal contract
-                # the per-request admission-failure contract survives
-                # hybrid: the engine.prefill drill fires BEFORE
-                # hybrid_dispatch mutates any state, so the engine is
-                # clean — fail just the joiner and dispatch a plain chunk
-                # for the batch. (A GENUINE failure inside the fused
-                # launch is indistinguishable from a decode failure — the
-                # jit donates the cache — and stays engine-fatal, handled
-                # by warm restart.)
-                self._inflight[:] = [e_ for e_ in self._inflight
-                                     if e_[1] is not hyb_adm]
-                self._abort_admission(hyb_req, hyb_adm, e)
-                return self.engine.decode_dispatch(n_disp, spec=False)
-
-        tr = trace.TRACER
-        if tr.enabled:
-            t0 = tr.now()
-            chunk = _launch()
-            # the dispatch span: pure host work. Under overlap it lands
-            # INSIDE the previous chunk's decode.device span — the
-            # interleaving scripts/trace_smoke.sh asserts on.
-            tr.span_at("decode.dispatch", t0, tr.now(), cat="decode",
-                       track="scheduler", chunk=chunk.seq, n=chunk.n,
-                       occupancy=len(self.slots), spec=use_spec,
-                       pipelined=not pipeline_empty,
-                       hybrid_tokens=(chunk.hybrid_tokens or None),
-                       host_gap_ms=(None if self._last_gap_ms is None
-                                    else round(self._last_gap_ms, 3)))
-            if chunk.hybrid_tokens:
-                # the flight recorder's prefill story stays complete under
-                # hybrid: each fused slice is a prefill.chunk span for the
-                # ADMITTING request, bracketing the dispatch
-                tr.span_at("prefill.chunk", t0, tr.now(), cat="prefill",
-                           track="scheduler",
-                           req_id=hyb_req.req_id if hyb_req else "",
-                           slot=chunk.hybrid_slot, off=int(hyb_adm.off),
-                           total=len(hyb_adm.toks), hybrid=True)
-            return chunk, dict(self.slots)
-        return _launch(), dict(self.slots)
+        return use_spec, n_disp
 
     def _consume_chunk(self, chunk, snapshot) -> None:
         """Block on a dispatched chunk's tokens and emit them to the
@@ -2144,49 +2196,98 @@ class Scheduler:
         rewound the slot to the truly-emitted prefix, so the prefix cache
         never serves overrun rows."""
         tr = trace.TRACER
-        t0 = tr.now() if tr.enabled else 0.0
+        sp = tr.span("decode.consume", cat="decode")
         self.ledger.transition("decode_wait")
-        toks = self.engine.decode_consume(chunk)  # records decode.device
+        # the engine's consume.wait / consume.fold phases; records
+        # decode.device
+        toks = self.engine.decode_consume(chunk)
         self._t_dec_end = self._t_consumed = time.monotonic()
         self.ledger.transition("emit")
         if tr.enabled:
-            tr.span_at("decode.consume", t0, tr.now(), cat="decode",
-                       track="scheduler", chunk=chunk.seq, n=chunk.n)
-            t_emit = tr.now()
-        bad = chunk.nonfinite()  # NaN guard: rows whose logits went
-        # non-finite (or an armed decode.nan injection) — fail THOSE
-        # requests, not the engine; their chunk tokens are garbage and are
-        # never emitted, their rows are released unreusable
-        for slot, req in snapshot.items():
-            if self.slots.get(slot) is not req:
-                continue  # finished mid-flight: overrun tokens discarded
-            if bad is not None and bad[slot]:
-                log.error("non-finite logits in decode chunk %d (slot %d); "
-                          "failing the request, engine stays up",
-                          chunk.seq, slot, extra=trace.log_extra(req.req_id))
-                self.slot_tokens[slot] = []  # rows are poisoned: never reuse
-                req.finish_reason = "error"  # before the put (client-visible)
-                req.out.put(RuntimeError(
-                    f"non-finite logits in decode chunk {chunk.seq}; "
-                    "request failed (engine healthy)"))
-                self._finish(req, "error")
-                continue
-            if chunk.spec and chunk.advance[slot]:
-                # per-request acceptance record (timings()'s spec object):
-                # cycles this request participated in, and tokens they gave
-                req.spec_cycles += int((chunk.adv_cycles[:, slot] > 0).sum())
-                req.spec_tokens += int(chunk.advance[slot])
-            if tr.enabled and chunk.advance[slot]:
-                # flight-recorder chunk entry BEFORE the tokens reach the
-                # client queue: a response never races its own record
-                tr.req_chunk(req.req_id, chunk.seq, int(chunk.advance[slot]))
-            for i in range(int(chunk.advance[slot])):
-                # row written when sampling token i: start + i (+1 = prefix len)
-                if self._emit(req, toks[i, slot], int(chunk.start_pos[slot]) + i + 1):
-                    break
-        if tr.enabled:
-            tr.span_at("emit.scan", t_emit, tr.now(), cat="decode",
-                       track="scheduler", chunk=chunk.seq)
+            sp.end(chunk=chunk.seq, n=chunk.n)
+        with self.phases("emit.scan", chunk.seq):
+            bad = chunk.nonfinite()  # NaN guard: rows whose logits went
+            # non-finite (or an armed decode.nan injection) — fail THOSE
+            # requests, not the engine; their chunk tokens are garbage and are
+            # never emitted, their rows are released unreusable
+            for slot, req in snapshot.items():
+                if self.slots.get(slot) is not req:
+                    continue  # finished mid-flight: overrun tokens discarded
+                if bad is not None and bad[slot]:
+                    log.error("non-finite logits in decode chunk %d (slot %d); "
+                              "failing the request, engine stays up",
+                              chunk.seq, slot, extra=trace.log_extra(req.req_id))
+                    self.slot_tokens[slot] = []  # rows are poisoned: never reuse
+                    req.finish_reason = "error"  # before the put (client-visible)
+                    req.out.put(RuntimeError(
+                        f"non-finite logits in decode chunk {chunk.seq}; "
+                        "request failed (engine healthy)"))
+                    self._finish(req, "error")
+                    continue
+                if chunk.spec and chunk.advance[slot]:
+                    # per-request acceptance record (timings()'s spec object):
+                    # cycles this request participated in, and tokens they gave
+                    req.spec_cycles += int((chunk.adv_cycles[:, slot] > 0).sum())
+                    req.spec_tokens += int(chunk.advance[slot])
+                if tr.enabled and chunk.advance[slot]:
+                    # flight-recorder chunk entry BEFORE the tokens reach the
+                    # client queue: a response never races its own record
+                    tr.req_chunk(req.req_id, chunk.seq, int(chunk.advance[slot]))
+                for i in range(int(chunk.advance[slot])):
+                    # row written when sampling token i: start + i (+1 = prefix len)
+                    if self._emit(req, toks[i, slot], int(chunk.start_pos[slot]) + i + 1):
+                        break
+
+    def _boundary_scans(self) -> None:
+        """The boundary's scans over the decoding slots (the
+        `boundary.scan` phase): cancels, deadlines, the context edge, and
+        the rescue of a batch whose every slot is page-starved."""
+        for slot, req in list(self.slots.items()):
+            if req.cancelled.is_set():
+                self._finish(req, req.cancel_reason,
+                             keep_rows=int(self.engine.pos[slot]))
+            elif (req.deadline_at is not None
+                  and time.monotonic() >= req.deadline_at):
+                # per-request deadline: the stream ends cleanly at this
+                # chunk boundary with finish_reason="timeout"; the rows
+                # already emitted keep their prefix-cache value
+                trace.TRACER.event("request.timeout", cat="deadline",
+                                   track="requests", req_id=req.req_id,
+                                   where="decoding")
+                self._finish(req, "timeout",
+                             keep_rows=int(self.engine.pos[slot]))
+            elif int(self.engine.pos[slot]) >= self.engine.seq_len:
+                self._finish(req, "length")
+        if self.slots and hasattr(self.engine, "page_starved"):
+            # paged pool exhaustion mid-decode: a starved slot (no page
+            # for its next row, pool dry) waits frozen while batch-mates
+            # run — their releases re-feed it. But when EVERY live slot
+            # is starved nothing will ever free a page: finish the most-
+            # advanced one with 'length' (least budget wasted) so its
+            # pages unfreeze the rest. Admission reserves (+1 decode
+            # page) make this a last resort, not the steady state.
+            # the rescue must run even while an admission is mid-prefill
+            # (_inflight): admissions only ADD page consumers, so waiting
+            # on one can never un-starve the batch — and dispatching a
+            # chunk with every slot at its limit would raise and crash
+            # the worker instead
+            starved = self.engine.page_starved()
+            if starved.any() and all(
+                starved[s] for s in self.slots
+                if self.engine.active[s]
+            ):
+                if self._reclaim_pages(len(self.slots)):
+                    pass  # reclaimed idle caches; next dispatch tops up
+                else:
+                    victim = max(
+                        (s for s in self.slots if starved[s]),
+                        key=lambda s: int(self.engine.pos[s]))
+                    log.warning(
+                        "kv page pool exhausted with every active slot "
+                        "starved; finishing slot %d "
+                        "(finish_reason=length) to free its pages",
+                        victim)
+                    self._finish(self.slots[victim], "length")
 
     def _loop(self) -> None:
         # end of the previous decode chunk (stall metric); instance attribute
@@ -2226,10 +2327,24 @@ class Scheduler:
                             self._commit_ready_inflight()
                     if self._backlog or not self.pending.empty():
                         self.ledger.transition("admission")
-                        self._admit_starts(boundary=False)
-                nxt = (None if self._needs_boundary(pending[0])
-                       else self._dispatch_chunk(pipeline_empty=False,
-                                                 inflight=pending[0]))
+                        with self.phases("admit.start", self._next_seq()):
+                            self._admit_starts(boundary=False)
+                with self.phases("boundary.scan", self._next_seq()):
+                    reason = self._boundary_reason(pending[0])
+                nxt = None
+                if reason is None:
+                    nxt = self._dispatch_chunk(pipeline_empty=False,
+                                               inflight=pending[0])
+                    if nxt is None:
+                        reason = "mode_switch"
+                if nxt is None:
+                    # the pipeline drains: this launch is consumed with no
+                    # successor queued, and the device idles through emit,
+                    # the boundary work and the next dispatch. Counted by
+                    # the reason that asked, which the phases' annotations
+                    # carry until a launch is dispatched again
+                    ins.PIPELINE_DRAINS.labels(reason=reason).inc()
+                    self.phases.drain = reason
                 self._consume_chunk(*pending)
                 if self._pipelined_commit and self._inflight:
                     # second half: the consumed chunk's logits are there and
@@ -2240,57 +2355,14 @@ class Scheduler:
                 continue
             t_boundary = time.monotonic()
             self.ledger.transition("admission")
-            self._admit_starts()
+            with self.phases("admit.start", self._next_seq()):
+                self._admit_starts()
             admitted = self._pump_admissions()
             # boundary scans below (cancels, deadlines, page starvation) are
             # admission-side work; this also bills the pump's open tail
             self.ledger.transition("admission")
-            for slot, req in list(self.slots.items()):
-                if req.cancelled.is_set():
-                    self._finish(req, req.cancel_reason,
-                                 keep_rows=int(self.engine.pos[slot]))
-                elif (req.deadline_at is not None
-                      and time.monotonic() >= req.deadline_at):
-                    # per-request deadline: the stream ends cleanly at this
-                    # chunk boundary with finish_reason="timeout"; the rows
-                    # already emitted keep their prefix-cache value
-                    trace.TRACER.event("request.timeout", cat="deadline",
-                                       track="requests", req_id=req.req_id,
-                                       where="decoding")
-                    self._finish(req, "timeout",
-                                 keep_rows=int(self.engine.pos[slot]))
-                elif int(self.engine.pos[slot]) >= self.engine.seq_len:
-                    self._finish(req, "length")
-            if self.slots and hasattr(self.engine, "page_starved"):
-                # paged pool exhaustion mid-decode: a starved slot (no page
-                # for its next row, pool dry) waits frozen while batch-mates
-                # run — their releases re-feed it. But when EVERY live slot
-                # is starved nothing will ever free a page: finish the most-
-                # advanced one with 'length' (least budget wasted) so its
-                # pages unfreeze the rest. Admission reserves (+1 decode
-                # page) make this a last resort, not the steady state.
-                # the rescue must run even while an admission is mid-prefill
-                # (_inflight): admissions only ADD page consumers, so waiting
-                # on one can never un-starve the batch — and dispatching a
-                # chunk with every slot at its limit would raise and crash
-                # the worker instead
-                starved = self.engine.page_starved()
-                if starved.any() and all(
-                    starved[s] for s in self.slots
-                    if self.engine.active[s]
-                ):
-                    if self._reclaim_pages(len(self.slots)):
-                        pass  # reclaimed idle caches; next dispatch tops up
-                    else:
-                        victim = max(
-                            (s for s in self.slots if starved[s]),
-                            key=lambda s: int(self.engine.pos[s]))
-                        log.warning(
-                            "kv page pool exhausted with every active slot "
-                            "starved; finishing slot %d "
-                            "(finish_reason=length) to free its pages",
-                            victim)
-                        self._finish(self.slots[victim], "length")
+            with self.phases("boundary.scan", self._next_seq()):
+                self._boundary_scans()
             if not self.slots:
                 self._t_dec_end = None
                 if not self._inflight:

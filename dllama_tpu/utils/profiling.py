@@ -47,8 +47,12 @@ _prof_lock = locks.make_lock("utils.profiling")
 _prof_state = {"active": False, "dir": None, "started_at": 0.0,
                "duration_s": None}
 
-#: the launch counters (obs/instruments, fed by engine/launch_record) a
-#: capture is bracketed with, by the key /debug/perf's `capture` block uses
+#: the counters a capture is bracketed with, by the key /debug/perf's
+#: `capture` block uses: the launch counters (obs/instruments, fed by
+#: engine/launch_record) and, since ISSUE 40, the host's seconds by ledger
+#: state and by phase, the drains by reason, the waits by outcome and the
+#: host-gap histogram's sum and count: beside the whole window's (a scrape
+#: before and after) they say what the profiler costs the host, per launch
 _CAPTURE_COUNTERS = {"launches": ins.LAUNCHES, "slot_steps": ins.SLOT_STEPS,
                      "kv_rows": ins.LAUNCH_KV_ROWS,
                      "prefill_rows": ins.LAUNCH_PREFILL_ROWS,
@@ -57,7 +61,13 @@ _CAPTURE_COUNTERS = {"launches": ins.LAUNCHES, "slot_steps": ins.SLOT_STEPS,
                      "moe_experts_touched": ins.MOE_EXPERTS_TOUCHED,
                      "moe_layer_steps": ins.MOE_LAYER_STEPS,
                      "moe_group_rows_max": ins.MOE_GROUP_ROWS_MAX,
-                     "window_pages_released": ins.KV_WINDOW_PAGES_RELEASED}
+                     "window_pages_released": ins.KV_WINDOW_PAGES_RELEASED,
+                     "sched_seconds": ins.SCHEDULER_TIME,
+                     "phase_seconds": ins.SCHEDULER_PHASE_SECONDS,
+                     "phases": ins.SCHEDULER_PHASES,
+                     "drains": ins.PIPELINE_DRAINS,
+                     "launch_waits": ins.LAUNCH_WAITS,
+                     "host_gap": ins.DECODE_HOST_GAP_SECONDS}
 _capture = {"begin": None, "t_begin": 0.0, "last": None}
 
 
@@ -66,11 +76,15 @@ def _launch_counters() -> dict:
 
 
 def last_capture() -> dict | None:
-    """What the engine launched during the last FINISHED capture, as
-    counter deltas: {"launches": {kind: n}, "slot_steps": {state: n},
-    "kv_rows": {kind: n}, "prefill_rows": {kind: n}, "seconds": s}: the
-    exact rows of the launches a reader of that trace is looking at
-    (`/debug/perf`'s `capture` block). None before the first capture."""
+    """What the engine launched and what the host spent during the last
+    FINISHED capture, as counter deltas: {"launches": {kind: n},
+    "slot_steps": {state: n}, "kv_rows": {kind: n}, "prefill_rows":
+    {kind: n}, ..., "sched_seconds": {state: s}, "phase_seconds":
+    {phase: s}, "phases": {phase: n}, "drains": {reason: n},
+    "launch_waits": {outcome: n}, "host_gap": {"sum", "count"},
+    "seconds": s}: the exact rows of the launches a reader of that trace
+    is looking at, and the host's seconds inside it (`/debug/perf`'s
+    `capture` block). None before the first capture."""
     with _prof_lock:
         return _capture["last"]
 
@@ -79,7 +93,8 @@ def last_capture() -> dict | None:
 MAX_PROFILE_SECONDS = 60.0
 
 
-def _profiler_begin(log_dir: str, duration_s: float | None = None) -> None:
+def _profiler_begin(log_dir: str, duration_s: float | None = None,
+                    restamp=None) -> None:
     with _prof_lock:
         if _prof_state["active"]:
             raise ProfileBusy(
@@ -92,13 +107,25 @@ def _profiler_begin(log_dir: str, duration_s: float | None = None) -> None:
         # BEFORE the session starts, so the first state the session can see
         # is stamped; an annotation opened while it spins up is a no-op.
         reqtrace.PROFILER_HOOK = jax.profiler.TraceAnnotation
+        # no Python frames: the program's captures read the device plane
+        # and the dllama.* annotations (TraceMe events, the host tracer's),
+        # and jax's default hooks EVERY Python call of every thread for the
+        # length of the capture, the scheduler's worker among them
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
         try:
-            jax.profiler.start_trace(log_dir)
+            jax.profiler.start_trace(log_dir, profiler_options=options)
         except BaseException:
             reqtrace.PROFILER_HOOK = None
             raise
         _prof_state.update(active=True, dir=log_dir, started_at=time.time(),
                            duration_s=duration_s)
+    if restamp is not None:
+        # outside the lock (the ledger's ranks below it): the open state
+        # and phase are billed and stamped BEFORE the counters are read, so
+        # the block holds the host's seconds inside the capture
+        restamp()
+    with _prof_lock:
         _capture.update(begin=_launch_counters(), t_begin=time.monotonic())
     reqtrace.TRACER.event("profile.start", cat="profile", track="profiler",
                           dir=log_dir)
@@ -135,12 +162,13 @@ def start_profile(log_dir: str | None = None, duration_s: float = 2.0,
     Returns {dir, duration_s}; raises :class:`ProfileBusy` when a capture
     (this one or a CLI ``--trace`` run) is already in flight — the caller
     never blocks behind someone else's capture. `restamp` (the scheduler's
-    ``TimeLedger.restamp``) is called once the capture has begun and again
-    just before it stops, so the states open at its two ends are stamped."""
+    ``Scheduler.restamp``: its ledger's and its phase clock's) is called
+    once the capture has begun and again just before it stops, so the state
+    and the phase open at its two ends are stamped, and billed."""
     duration_s = min(max(float(duration_s), 0.05), MAX_PROFILE_SECONDS)
     if not log_dir:
         log_dir = tempfile.mkdtemp(prefix="dllama_profile_")
-    _profiler_begin(str(log_dir), duration_s)
+    _profiler_begin(str(log_dir), duration_s, restamp)
 
     def stop():
         try:
@@ -149,8 +177,6 @@ def start_profile(log_dir: str | None = None, duration_s: float = 2.0,
         finally:
             _profiler_end()
 
-    if restamp is not None:
-        restamp()
     t = threading.Timer(duration_s, stop)
     t.daemon = True  # a dying process must not hang on the stop timer
     t.start()

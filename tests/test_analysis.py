@@ -230,23 +230,40 @@ def go():
     assert sorted(rules_of(diags)) == ["catalog-event", "catalog-span"]
 
 
-def test_catalog_span_covers_profiler_hook_calls():
-    """A hook("prefix." + x) call (obs/trace.PROFILER_HOOK) is a span on
-    the profiler's clock: its literal head is its SPAN_CATALOG name."""
-    tmpl = '''
-from dllama_tpu.obs import trace
+SEAM_CALLS = {
+    # the profiler clock's one writer: the literal prefix is the name
+    "profiler_annotation": ('trace.profiler_annotation("{head}", kind)',
+                            "dllama.launch.", "mystery.launch."),
+    # the phase seam, as the scheduler and the engine reach it
+    "self.phases": ('self.phases("{head}", 3)', "emit.scan", "emit.mystery"),
+    "ph": ('ph("{head}", 3)', "consume.wait", "consume.mystery"),
+}
 
-def go(kind):
-    hook = trace.PROFILER_HOOK
-    if hook is not None:
-        with hook({name}, seq=1):
-            pass
-'''
-    bad = tmpl.format(name='"mystery.launch." + kind')
-    diags = findings({"dllama_tpu/serve/fake.py": bad})
+
+@pytest.mark.parametrize("how", sorted(SEAM_CALLS))
+def test_catalog_span_covers_the_seam(how):
+    """A call of obs/trace.profiler_annotation or of the phase seam is a
+    span emission: its literal name (the prefix) is a SPAN_CATALOG name."""
+    call, good, bad = SEAM_CALLS[how]
+    tmpl = ("from dllama_tpu.obs import trace\n\n"
+            "def go(self, ph, kind):\n    return {call}\n")
+    diags = findings({"dllama_tpu/serve/fake.py":
+                      tmpl.format(call=call.format(head=bad))})
     assert rules_of(diags) == ["catalog-span"]
-    good = tmpl.format(name='"dllama.launch." + kind')
-    assert findings({"dllama_tpu/serve/fake.py": good}) == []
+    assert findings({"dllama_tpu/serve/fake.py":
+                     tmpl.format(call=call.format(head=good))}) == []
+
+
+def test_profiler_hook_is_read_through_the_one_helper():
+    """Outside obs/trace.py a READ of PROFILER_HOOK is a finding (the
+    install in utils/profiling stores it, and stays green)."""
+    read = ("from dllama_tpu.obs import trace\n\n"
+            "def go():\n    return trace.PROFILER_HOOK is not None\n")
+    diags = findings({"dllama_tpu/serve/fake.py": read})
+    assert rules_of(diags) == ["catalog-span"] and diags[0].line == 4
+    store = ("from dllama_tpu.obs import trace\n\n"
+             "def go(h):\n    trace.PROFILER_HOOK = h\n")
+    assert findings({"dllama_tpu/utils/fake.py": store}) == []
 
 
 def test_catalog_fault_red():

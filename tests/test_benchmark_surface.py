@@ -126,7 +126,7 @@ def served(tiny):
         docs = {"/health/ready": get("/health/ready")}
         with pytest.MonkeyPatch.context() as mp:
             mp.setattr(profiling.jax.profiler, "start_trace",
-                       lambda log_dir: None)
+                       lambda log_dir, **kw: None)
             mp.setattr(profiling.jax.profiler, "stop_trace", lambda: None)
             profile = loadlib.http_json(
                 "127.0.0.1", port, "POST", "/debug/profile",
@@ -234,6 +234,61 @@ def test_metric_family_the_harness_names_is_exported(family, served):
                      served["docs"]["/metrics"][1], re.M)
     assert kind, f"{declared} is not declared by /metrics"
     assert family in served["metrics"] or kind.group(1) == "counter"
+
+
+#: the per-layer metrics that read the host's launch cycle (ISSUE 40):
+#: `ratio_of_deltas` over series named WITH their labels
+HOST_METRICS = ["commit_host_ms_per_commit", "device_wait_ms_per_launch",
+                "emit_us_per_token", "host_gap_ms_per_launch",
+                "host_work_ms_per_launch", "pipeline_drain_share"]
+
+
+@pytest.mark.parametrize("metric", HOST_METRICS)
+def test_host_metric_reads_series_the_program_exports(metric, served):
+    """Every family AND label a new metric file's `params` name is a sample
+    of the program's scrape as the harness's own parser keys it, and the
+    harness's reducer turns the scrape into a number."""
+    from benchmark.reducers import ratio_of_deltas
+
+    spec = read_json(os.path.join(BENCH, "metrics", metric + ".json"))
+    assert spec["reducer"] == "ratio_of_deltas"
+    for key in spec["params"]["num"] + spec["params"]["den"]:
+        assert key in served["metrics"], f"{metric}: no sample {key!r}"
+    value = ratio_of_deltas.reduce(
+        spec["params"], {"before": {"metrics": {}},
+                         "after": {"metrics": served["metrics"]},
+                         "config": {}})
+    assert value is not None and value >= 0.0
+
+
+def test_idle_by_phase_reads_the_capture_block_the_program_writes(served):
+    """`idle_by_phase_ms_per_launch`'s host tables: the capture block's
+    seconds by state and phase, drains, waits and host gap, and the
+    window's from the scrape, under the names the reducer reads."""
+    from benchmark.reducers import trace_idle_by_phase
+    from dllama_tpu.obs import perf
+
+    spec = read_json(os.path.join(BENCH, "metrics",
+                                  "idle_by_phase_ms_per_launch.json"))
+    assert spec["reducer"] == "trace_idle_by_phase"
+    scrape = {"metrics": served["metrics"],
+              "perf": served["docs"]["/debug/perf"][1]}
+    tables = trace_idle_by_phase.host_tables(
+        {"before": {"metrics": {}}, "after": scrape, "t0": 0.0, "t1": 1.0})
+    for where in ("capture", "window"):
+        t = tables[where]
+        assert t["launches"] >= 2
+        assert set(t["state_ms_per_launch"]) <= set(perf.LEDGER_STATES)
+        assert t["state_ms_per_launch"]["decode_wait"] > 0
+        assert set(t["phase_ms_per_launch"]) <= set(perf.PHASES)
+        assert {"dispatch.call", "consume.wait", "emit.scan",
+                "commit.activate"} <= set(t["phase_ms_per_launch"])
+        assert set(t["drains"]) <= set(perf.DRAIN_REASONS)
+        assert sum(t["launch_waits"].values()) >= 1
+        assert t["host_gap_ms_per_launch"] >= 0.0
+    # the phases under each state, beside the ledger's seconds
+    ledger = scrape["perf"]["ledger"]
+    assert set(ledger["phases"]) <= set(ledger["seconds"])
 
 
 # -------------------------------------- the loaded model and the engine

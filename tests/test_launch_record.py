@@ -382,7 +382,7 @@ def test_one_launch_annotation_per_launch_with_the_chunks_seq(hooked_run):
 
 def test_no_hook_no_annotation_nothing_allocated(monkeypatch):
     """With no capture running the launch path makes no TraceAnnotation:
-    the record hands back the one shared no-op span."""
+    the record opens nothing and hands back None."""
     import jax
 
     def boom(*a, **kw):
@@ -391,8 +391,7 @@ def test_no_hook_no_annotation_nothing_allocated(monkeypatch):
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
     assert trace.PROFILER_HOOK is None
     rec = launch_record.LaunchRecord("decode", 1, 4, 2, 8, 0, 4, 100, 0)
-    assert rec.annotation() is trace.NULL_SPAN
-    assert rec.annotation() is rec.annotation()
+    assert rec.annotation() is None
     eng = BatchEngine(CFG, PARAMS, n_slots=2, cache_dtype=jnp.float32)
     eng.add(0, [1, 2, 3], temperature=0.0)
     eng.decode_consume(eng.decode_dispatch(2))
@@ -455,7 +454,7 @@ def test_a_capture_restamps_at_both_ends(monkeypatch, tmp_path):
 
     calls = []
     monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda log_dir: calls.append("start"))
+                        lambda log_dir, **kw: calls.append("start"))
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: calls.append("stop"))
     profiling.start_profile(str(tmp_path), 0.05,
@@ -492,7 +491,8 @@ def test_obs_imports_no_jax():
 def test_capture_block_is_the_counter_deltas(monkeypatch, tmp_path):
     import jax
 
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda log_dir: None)
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda log_dir, **kw: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     launch_record.LaunchRecord("decode", 1, 4, 3, 12, 0, 0, 500, 0).count()
     assert trace.PROFILER_HOOK is None
@@ -520,13 +520,14 @@ def test_capture_block_is_the_counter_deltas(monkeypatch, tmp_path):
                         "kv_rows_read", "moe_assignments",
                         "moe_experts_touched", "moe_layer_steps",
                         "moe_group_rows_max", "window_pages_released",
-                        "seconds"}
+                        "sched_seconds", "phase_seconds", "phases", "drains",
+                        "launch_waits", "host_gap", "seconds"}
 
 
 def test_failed_session_start_leaves_no_hook(monkeypatch, tmp_path):
     import jax
 
-    def refuse(log_dir):
+    def refuse(log_dir, **kw):
         raise RuntimeError("no profiler")
 
     monkeypatch.setattr(jax.profiler, "start_trace", refuse)

@@ -563,7 +563,7 @@ def test_debug_profile_starts_and_conflicts_409(mserver, tmp_path, monkeypatch):
     from dllama_tpu.utils import profiling
 
     monkeypatch.setattr(profiling.jax.profiler, "start_trace",
-                        lambda log_dir: None)
+                        lambda log_dir, **kw: None)
     monkeypatch.setattr(profiling.jax.profiler, "stop_trace", lambda: None)
     port, _api, _ = mserver
     st, data, _ = _post_raw(port, "/debug/profile",
@@ -601,7 +601,7 @@ def test_debug_perf_capture_block_counts_the_captures_launches(
     from dllama_tpu.utils import profiling
 
     monkeypatch.setattr(profiling.jax.profiler, "start_trace",
-                        lambda log_dir: None)
+                        lambda log_dir, **kw: None)
     monkeypatch.setattr(profiling.jax.profiler, "stop_trace", lambda: None)
     port, _api, _ = mserver
     launched = val("dllama_launches_total", {"kind": "decode"}) or 0.0
@@ -625,7 +625,8 @@ def test_debug_perf_capture_block_counts_the_captures_launches(
                         "kv_rows_read", "moe_assignments",
                         "moe_experts_touched", "moe_layer_steps",
                         "moe_group_rows_max", "window_pages_released",
-                        "seconds"}
+                        "sched_seconds", "phase_seconds", "phases", "drains",
+                        "launch_waits", "host_gap", "seconds"}
     assert cap["launches"]["decode"] == during >= 1
     assert cap["slot_steps"]["advanced"] >= 5  # 6 tokens, the first at commit
     assert cap["kv_rows"]["decode"] > 0
