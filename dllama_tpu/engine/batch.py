@@ -991,6 +991,11 @@ class BatchEngine:
         self.phases = perf.PhaseClock()
         self._wait_ready = ins.LAUNCH_WAITS.labels(outcome="ready")
         self._wait_blocked = ins.LAUNCH_WAITS.labels(outcome="blocked")
+        # page top-ups by whether a launch was in flight (dispatched and not
+        # yet consumed) when they were taken
+        self._consumed_seq = 0  # DecodeChunk.seq of the last consumed launch
+        self._topups = {full: ins.KV_PAGE_TOPUPS.labels(
+            pipeline="full" if full else "empty") for full in (False, True)}
 
         # ---- device-resident decode state. The JAX arrays below are the
         # authoritative operands of the fused decode step, threaded
@@ -1094,6 +1099,13 @@ class BatchEngine:
                     mm_in, moe_impl),
             static_argnums=(11,), donate_argnums=(1, 14),
         )
+        # an admission's first token: key derivation, split and the B=1
+        # sampler as ONE program (add_sample), so a commit puts one dispatch
+        # between the launch in flight and its successor
+        self._first_token = named_jit("commit", self._first_token_impl)
+        # the activation's three row writes of the decode carry as one
+        # program (no donation: a chunk in flight may hold the old arrays)
+        self._commit_rows = named_jit("commit_rows", self._commit_rows_impl)
         self._copy_rows = named_jit("copy_rows", self._copy_rows_impl,
                                     donate_argnums=(0,))
         self._copy_page = named_jit("page_copy", self._copy_page_impl,
@@ -1677,6 +1689,28 @@ class BatchEngine:
         return jax.lax.dynamic_update_index_in_dim(history, merged, dst, axis=0)
 
     @staticmethod
+    def _first_token_impl(logits, base_key, admission, seed, seeded,
+                          temperature, topp):
+        """An admission's first token off its [1, V] logits, key arithmetic
+        included: the request's own key (`PRNGKey(seed)`) when `seeded`,
+        else the engine's key folded with the admission counter; one split
+        (the carry the slot decodes on, the sampler's sub-key); the same
+        `sample_logits` every decode step runs. -> (token i32[1], key)."""
+        key = jnp.where(seeded, jax.random.PRNGKey(seed),
+                        jax.random.fold_in(base_key, admission))
+        key, sub = jax.random.split(key)
+        return sample_logits(logits, sub, temperature, topp), key
+
+    @staticmethod
+    def _commit_rows_impl(last, keys, pos, slot, token, key, row):
+        """One slot's rows of the device-authoritative decode carry, written
+        at its activation: the token to feed (`token` i32[1]), the key it
+        decodes on, the row it stands at. Eagerly each `.at[slot].set` is
+        three programs (convert, broadcast, scatter)."""
+        return (last.at[slot].set(token[0]), keys.at[slot].set(key),
+                pos.at[slot].set(row))
+
+    @staticmethod
     def _copy_rows_impl(cache, src, dst, rows):
         """Copy the first `rows` cache rows of slot src into slot dst (both
         k and v, all layers/heads). Static shapes: the whole [S] row axis is
@@ -1741,16 +1775,20 @@ class BatchEngine:
         if self.pool is None:
             return
         changed = False
+        topups = self._topups[self.chunk_seq > self._consumed_seq]
         for s in np.flatnonzero(self.active):
             want = min(self.seq_len, int(self.pos[s]) + n)
             short = (self.pool.blocks_for(want) - int(self.pool.n_blocks[s])
                      - self.pool.free_count)
             if short > 0:
                 self.radix_evict(short)
-            changed |= self.pool.grow(int(s), want, best_effort=True)
+            if self.pool.grow(int(s), want, best_effort=True):
+                changed = True
+                topups.inc()
             changed |= self.pool.cow_writable(int(s), int(self.pos[s]), want,
                                               self._pool_page_copy)
             if self.wpool is not None:
+                # positional tables: it grows at the global pool's edges
                 changed |= self._window_advance(int(s), want,
                                                 best_effort=True)
         if changed:
@@ -1773,6 +1811,22 @@ class BatchEngine:
         chunk or spec verify cycle, so their pages must be exclusive."""
         return [(int(s), int(self.pos[s])) for s in np.flatnonzero(self.active)]
 
+    def row_limited(self) -> np.ndarray:
+        """bool[B]: active slots with no row to decode into even after the
+        page top-up the next dispatch would make, which is taken here as
+        that dispatch takes it (with a launch in flight or without): a slot
+        at the context edge, or at the edge of its pages with no page to be
+        had (the pool dry once the radix tree's LRU leaves went, or the
+        window pool dry). A slot that merely stands on the edge of its
+        pages gets its page and is not among them. Dense layout: the
+        context edge alone. Costs one vector compare while no slot stands
+        at its limit."""
+        at = self.active & (self.pos >= self._row_limit())
+        if self.pool is not None and at.any():
+            self._alloc_decode_rows(1)
+            at = self.active & (self.pos >= self._row_limit())
+        return at
+
     def page_starved(self) -> np.ndarray:
         """bool[B]: active slots whose next decode row has no backing page
         even after a top-up attempt — frozen by pool exhaustion, not by the
@@ -1780,9 +1834,7 @@ class BatchEngine:
         livelock (finish one, its pages feed the rest)."""
         if self.pool is None:
             return np.zeros(self.n_slots, bool)
-        self._alloc_decode_rows(1)
-        limit = self._row_limit()
-        return (self.active & (self.pos >= limit) & (self.pos < self.seq_len)
+        return (self.row_limited() & (self.pos < self.seq_len)
                 & self._pool_dry())
 
     def admission_deficit(self, slot: int, reuse: int, prompt_len: int,
@@ -2087,11 +2139,14 @@ class BatchEngine:
             work.append(("prefill_chunk", f"m{c}", prefill_thunk(c)))
 
         def commit_thunk(lower=False):
-            row = carry.get("logits")
-            if lower or row is None:  # eager ops: nothing to lower ahead
-                return
-            _key, sub = jax.random.split(self._base_key)
-            sample_logits(row, sub, jnp.float32(0.8), jnp.float32(0.9))
+            # add_sample's operands, the logits as every prefill returns
+            # them (the [1, V] row of the thunks above)
+            row = carry.get("logits", jax.ShapeDtypeStruct(
+                (1, self.cfg.vocab_size), jnp.float32))
+            args = self._first_token_args(row, 0.8, 0.9, None)
+            if lower:
+                return self._first_token.lower(*args)
+            self._first_token(*args)
 
         work.append(("commit", "b1", commit_thunk))
 
@@ -2192,16 +2247,18 @@ class BatchEngine:
 
     def _warm_boundary_ops(self) -> None:
         """Precompile the small eager ops the admission/commit/release
-        boundaries dispatch (surgical ``.at[row].set`` carry writes, PRNG
-        key derivation): each is a once-per-process compile XLA would
-        otherwise pay on the FIRST real request — exactly the TTFT the
-        warmup pass exists to protect. Results are discarded; engine
-        state is untouched."""
+        boundaries dispatch (surgical ``.at[row].set`` carry writes, a
+        resumed request's key from its seed; a commit's own key arithmetic
+        is inside the `commit` program): each is a once-per-process compile
+        XLA would otherwise pay on the FIRST real request — exactly the
+        TTFT the warmup pass exists to protect. Results are discarded;
+        engine state is untouched."""
         self._pos_dev.at[0].set(0)
-        self._last_dev.at[0].set(0)
-        self._keys_dev.at[0].set(self._base_key)
-        key = jax.random.PRNGKey(0)
-        jax.random.split(jax.random.fold_in(key, 0))
+        with compile_obs.LEDGER.scope("boundary", "commit_rows"):
+            self._commit_rows(self._last_dev, self._keys_dev, self._pos_dev,
+                              np.int32(0), jnp.zeros(1, jnp.int32),
+                              self._base_key, np.int32(0))
+        jax.random.PRNGKey(0)
         jnp.full((1,), 0, jnp.int32)
         if self._counts is not None:
             self._counts.at[0].set(0)
@@ -2360,6 +2417,7 @@ class BatchEngine:
         self._keys_dev = jnp.asarray(self.keys.copy())
         self._pos_dev = jnp.zeros(self.n_slots, jnp.int32)
         self._spec_inflight = 0  # any unconsumed chunk died with the crash
+        self._consumed_seq = self.chunk_seq
         self._t_last_consume = None
         if self.spec_k:
             self.history = jnp.full((self.n_slots, self.seq_len + 1), -1,
@@ -2548,24 +2606,32 @@ class BatchEngine:
                    topp: float = 0.9, seed: int | None = None) -> None:
         """Dispatch the sampling of a finished admission's first token and
         read nothing back: the token stays on the device in `adm.sampled`
-        until add_commit. Called right after the launch that carried the
-        admission's last prompt rows, the sampling queues behind that
-        launch and AHEAD of its successor, so add_commit's one host read is
-        ready when the launch ends and the pipeline never drains for it."""
+        until add_commit. ONE program (`_first_token`: the key from `seed`
+        or from the engine's key and the admission counter, the split, the
+        sampler), so the call returns as soon as it is enqueued. Called
+        right after the launch that carried the admission's last prompt
+        rows, it queues behind that launch and AHEAD of its successor, so
+        add_commit's one host read is ready when the launch ends and the
+        pipeline never drains for it."""
         assert adm.off >= len(adm.toks) and adm.logits is not None, "admission not pumped"
         with self.phases("commit.sample", self.chunk_seq + 1):
-            if seed is not None:
-                key = jax.random.PRNGKey(seed)
-            else:
-                key = jax.random.fold_in(self._base_key, self._admissions)
+            args = self._first_token_args(adm.logits, temperature, topp, seed)
             self._admissions += 1
-            key, sub = jax.random.split(key)
             with compile_obs.LEDGER.scope(
                     "commit", "b1",
                     sig=lambda: compile_obs.sig_of(adm.logits)):
-                tok = sample_logits(adm.logits, sub, jnp.float32(temperature),
-                                    jnp.float32(topp))
-            adm.sampled = (tok, key)
+                adm.sampled = self._first_token(*args)
+
+    def _first_token_args(self, logits, temperature, topp, seed) -> tuple:
+        """`_first_token`'s operands: host scalars beside the logits and the
+        engine's key, so the call is one enqueue. A seed goes in as
+        `PRNGKey(seed)` takes a Python int: through int64, cut to the
+        default integer width."""
+        word = np.int64(0 if seed is None else seed).astype(
+            jax.dtypes.canonicalize_dtype(np.int64))
+        return (logits, self._base_key, np.uint32(self._admissions), word,
+                np.bool_(seed is not None), np.float32(temperature),
+                np.float32(topp))
 
     def add_commit(self, adm: "Admission", temperature: float = 0.8,
                    topp: float = 0.9, seed: int | None = None,
@@ -2597,9 +2663,7 @@ class BatchEngine:
         # writes just this slot's rows in place — other slots' carries stay
         # intact
         self._vec_dirty = True
-        self._last_dev = self._last_dev.at[slot].set(first)
-        self._keys_dev = self._keys_dev.at[slot].set(key)
-        self._pos_dev = self._pos_dev.at[slot].set(int(self.pos[slot]))
+        self._write_carry_rows(slot, tok, key)
         self.spec_k_slot[slot] = (min(int(spec_k), self.spec_k)
                                   if spec_k is not None else self.spec_k)
         if presence or frequency:
@@ -2619,6 +2683,14 @@ class BatchEngine:
                     jnp.full((1,), first, jnp.int32),
                 )
         return first
+
+    def _write_carry_rows(self, slot: int, token, key) -> None:
+        """Install an activated slot's token, key and position in the device
+        carry (`_commit_rows`: one dispatch, behind whatever is in flight)."""
+        with compile_obs.LEDGER.scope("boundary", "commit_rows"):
+            self._last_dev, self._keys_dev, self._pos_dev = self._commit_rows(
+                self._last_dev, self._keys_dev, self._pos_dev, np.int32(slot),
+                token, key, np.int32(self.pos[slot]))
 
     def resume_commit(self, adm: "Admission", last_token: int, key,
                       temperature: float = 0.8, topp: float = 0.9,
@@ -2642,9 +2714,8 @@ class BatchEngine:
         self.presence[slot] = presence
         self.frequency[slot] = frequency
         self._vec_dirty = True
-        self._last_dev = self._last_dev.at[slot].set(int(last_token))
-        self._keys_dev = self._keys_dev.at[slot].set(jnp.asarray(self.keys[slot]))
-        self._pos_dev = self._pos_dev.at[slot].set(int(self.pos[slot]))
+        self._write_carry_rows(slot, np.array([last_token], np.int32),
+                               self.keys[slot].copy())
         self.spec_k_slot[slot] = (min(int(spec_k), self.spec_k)
                                   if spec_k is not None else self.spec_k)
         if presence or frequency:
@@ -3144,6 +3215,7 @@ class BatchEngine:
         (self._wait_ready if ph.last_s < perf.READY_WAIT_S
          else self._wait_blocked).inc()
         with ph("consume.fold", chunk.seq):
+            self._consumed_seq = chunk.seq  # launches are consumed in order
             if moe is not None:
                 self._moe_count(moe)
             # the transfer above is the device sync: observing here (not at

@@ -40,9 +40,13 @@ LAUNCH_KINDS = ("decode", "decode_pen", "hybrid", "hybrid_pen",
                 "prefill_chunk", "spec", "spec_pen")
 #: the boundary programs, by the key of their ("boundary", key) scope
 BOUNDARY_PROGRAMS = ("copy_rows", "page_copy", "page_spill", "page_restore",
-                     "hist", "hist_batch", "hist_copy")
+                     "hist", "hist_batch", "hist_copy", "commit_rows")
+#: an admission's first-token sampling, by the word of its ("commit", "b1")
+#: scope: one program a commit, no launch of the step (no record)
+COMMIT_PROGRAMS = ("commit",)
 #: fn -> the name the program is jitted under
-PROGRAMS = {fn: f"dllama_{fn}" for fn in LAUNCH_KINDS + BOUNDARY_PROGRAMS}
+PROGRAMS = {fn: f"dllama_{fn}"
+            for fn in LAUNCH_KINDS + BOUNDARY_PROGRAMS + COMMIT_PROGRAMS}
 
 SLOT_STATES = ("advanced", "starved", "empty")
 for _s in SLOT_STATES:  # the series exist from the first scrape on

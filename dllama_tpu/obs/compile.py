@@ -121,8 +121,9 @@ COMPILE_FNS = {
               "p{P}.n{n}: P = pow2 prefill-budget slice, n = decode "
               "steps)",
     "hybrid_pen": "the hybrid launch with penalty counts (keys p{P}.n{n})",
-    "commit": "add_commit's first-token sample off the admission logits "
-              "(key b1 — one [1, V] shape per engine)",
+    "commit": "add_sample's first-token program off the admission logits: "
+              "key derivation, split and the B=1 sampler in one (key b1 — "
+              "one [1, V] shape per engine)",
     "single_sample": "the single-engine Sampler's jitted sample off "
                      "prefill logits (keys b{B}; never contract-declared, "
                      "so it cannot classify unexpected)",

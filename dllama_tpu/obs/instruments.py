@@ -258,6 +258,14 @@ KV_WINDOW_PAGES_RELEASED = metrics.counter(
     "dllama_kv_window_pages_released_total",
     "Window-pool pages handed back while their request ran: blocks that "
     "fell wholly behind a slot's window (PagePool.free_head)")
+KV_PAGE_TOPUPS = metrics.counter(
+    "dllama_kv_page_topups_total",
+    "Decoding slots whose block table a dispatch's page top-up extended "
+    "(BatchEngine._alloc_decode_rows: the slot reached the edge of its "
+    "pages), by whether a launch was in flight when the pages were taken: "
+    "full (the device worked through it) or empty (the pipeline was "
+    "drained)",
+    ("pipeline",))
 KV_HOST_PAGES_TOTAL = metrics.gauge(
     "dllama_kv_host_pages_total",
     "Host-RAM KV spill tier (--kv-host-pages): page slots in the pinned "

@@ -104,7 +104,7 @@ SPAN_CATALOG = {
     "consume.wait": "phase: the blocking read of a launch's tokens (np.asarray): the host's slack; under 0.5 ms the device had finished first (dllama_launch_waits_total{outcome})",
     "consume.fold": "phase: the consumed launch folded into the host mirrors: expert counters, the spec counts, chunk timing",
     "emit.finish": "phase: one request's finish: slot release, radix insert, metrics ring, flight record, the out queue's end mark",
-    "commit.sample": "phase: the eager first-token sampling of a pumped admission (add_sample)",
+    "commit.sample": "phase: the dispatch of a pumped admission's first-token sampling, one program (add_sample)",
     "commit.activate": "phase: a pumped admission's commit: add_commit / resume_commit, slot activation, radix insert, the first emit",
     "admit.start": "phase: _admit_starts: the queue, sheds, preemption, slot choice, prefix mapping, add_begin",
     "admit.pump": "phase: one pumped prefill chunk outside the hybrid step: add_step + its device sync",

@@ -1598,11 +1598,13 @@ class Scheduler:
 
     def _sample_ready_inflight(self) -> None:
         """Pipelined commit, first half: dispatch the first-token sampling
-        of every fully-pumped fresh admission, reading nothing. It queues
-        behind the chunk in flight (which carried the admission's last
-        prompt rows) and ahead of the successor about to be dispatched, so
-        the commit that follows that chunk's consumption finds its token
-        ready: the device never waits for a commit."""
+        of every fully-pumped fresh admission, reading nothing: one small
+        program an admission (`add_sample`), enqueued in well under a
+        millisecond, so the successor still leaves while the chunk in
+        flight runs. It queues behind that chunk (which carried the
+        admission's last prompt rows) and ahead of the successor about to
+        be dispatched, so the commit that follows the chunk's consumption
+        finds its token ready: the device never waits for a commit."""
         for req, adm, _ in self._inflight:
             if adm.off < len(adm.toks):
                 return  # admissions pump head first
@@ -1947,11 +1949,14 @@ class Scheduler:
         or None when a successor may be dispatched off the chunk in flight.
         Boundary work: admission (a prefill must not race the in-flight
         chunk's donated cache, and commit/release need settled host
-        mirrors), a pending cancel, a slot at the cache edge, or an emptied
-        batch. Speculative cycles pipeline like plain chunks (their
-        data-dependent counts materialize at consumption; _dispatch_chunk
-        drains the pipeline itself on a spec<->plain mode switch, the
-        reason `mode_switch`). The overlapped loop then consumes its
+        mirrors), a pending cancel, a slot with no row left to decode into
+        (the context edge, or a page edge on a dry pool: NOT a slot that
+        merely needs its next page, which it gets here under the chunk in
+        flight), or an emptied batch. Speculative cycles pipeline like
+        plain chunks (their data-dependent counts materialize at
+        consumption; _dispatch_chunk drains the pipeline itself on a
+        spec<->plain mode switch, the reason `mode_switch`). The
+        overlapped loop then consumes its
         in-flight chunk WITHOUT dispatching a successor, counts the drain
         under the reason (dllama_pipeline_drains_total), and the next
         iteration runs the boundary work on settled state — admission
@@ -1998,14 +2003,17 @@ class Scheduler:
                 return "cancel"
             if r.deadline_at is not None and now >= r.deadline_at:
                 return "deadline"
-        # row limit = seq_len on dense; on paged also each slot's allocated
-        # pages — a slot AT its limit needs boundary work (finish at the
-        # context edge, or page top-up/starvation handling on the pool)
-        limit = (self.engine._row_limit()
-                 if hasattr(self.engine, "_row_limit") else None)
-        if any(int(self.engine.pos[s]) >= (self.engine.seq_len if limit is None
-                                           else int(limit[s]))
-               for s in self.slots):
+        # a slot with no row to decode into has boundary work: at the
+        # context edge `_boundary_scans` finishes it with `length`, on a dry
+        # page pool the starvation rescue may have to. A slot that merely
+        # stands on the edge of its allocated pages has none: the engine
+        # takes the page here, with the chunk in flight, exactly as the
+        # successor's dispatch would have (`row_limited`'s top-up), and the
+        # successor leaves on time
+        limited = (self.engine.row_limited()
+                   if hasattr(self.engine, "row_limited")
+                   else np.asarray(self.engine.pos) >= self.engine.seq_len)
+        if any(limited[s] for s in self.slots):
             return "row_limit"
         if inflight_chunk is not None:
             # budget finishes are host-predictable (unlike EOS): when EVERY

@@ -62,6 +62,7 @@ _CAPTURE_COUNTERS = {"launches": ins.LAUNCHES, "slot_steps": ins.SLOT_STEPS,
                      "moe_layer_steps": ins.MOE_LAYER_STEPS,
                      "moe_group_rows_max": ins.MOE_GROUP_ROWS_MAX,
                      "window_pages_released": ins.KV_WINDOW_PAGES_RELEASED,
+                     "page_topups": ins.KV_PAGE_TOPUPS,
                      "sched_seconds": ins.SCHEDULER_TIME,
                      "phase_seconds": ins.SCHEDULER_PHASE_SECONDS,
                      "phases": ins.SCHEDULER_PHASES,

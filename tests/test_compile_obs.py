@@ -299,27 +299,73 @@ def test_warmup_compiles_ahead_one_entry_a_program():
 
     eng._precompile = spy
     rep = eng.warmup(chunk=2, hybrid_budget_hi=4)
-    named = [(fn, key) for fn, key, _ in eng._warm_worklist(2, 4)
-             if fn != "commit"]
+    named = [(fn, key) for fn, key, _ in eng._warm_worklist(2, 4)]
+    assert ("commit", "b1") in named  # the first-token sampling is a program
     metered = [m for m in seen if m is not None]
-    assert len(metered) == len(named)  # every jitted program, not `commit`
+    assert len(metered) == len(named)  # every program of the worklist
     assert all(m.n_backend == 1 and m.compile_s > 0 for m in metered)
-    entries = [e for e in cobs.LEDGER.snapshot(entries=256)["entries"]
-               if e["fn"] != "commit"]
+    entries = cobs.LEDGER.snapshot(entries=256)["entries"]
+    # beside the worklist: the activation's row writes, warmed with the
+    # boundary ops (`_warm_boundary_ops`)
+    assert [(e["fn"], e["key"]) for e in entries if e["fn"] == "boundary"] == [
+        ("boundary", "commit_rows")]
+    entries = [e for e in entries if e["fn"] != "boundary"]
     assert sorted((e["fn"], e["key"]) for e in entries) == sorted(named)
     assert all(e["warmup"] and e["compile_s"] > 0 for e in entries)
     # the dispatch pass added no backend compile to a program's entry
     totals = cobs.LEDGER.snapshot()["totals"]
     assert sum(t["compiles"] for fn, t in totals.items()
-               if fn not in ("commit", "untracked")) == len(named)
-    # (`commit` is eager ops an earlier test may have left compiled)
-    assert rep["compiled"] >= len(named) and eng._counts is None
+               if fn not in ("boundary", "untracked")) == len(named)
+    assert rep["compiled"] == len(named) and eng._counts is None
     assert eng._warmed >= set(named)
     # a second warm-up lowers nothing ahead and finds everything cached
     seen.clear()
     rep2 = eng.warmup(chunk=2, hybrid_budget_hi=4)
     assert seen and all(m is None for m in seen)
     assert rep2["compiled"] == 0 and rep2["cached"] == rep2["buckets"]
+
+
+def test_first_token_sampling_is_one_program_and_warm_after_warmup(monkeypatch):
+    """`add_sample` dispatches ONE program under the ("commit", "b1")
+    scope, counted by the backend compiles the ledger saw on a vocabulary
+    no other test compiled at: the key derivation, the split and the
+    sampler were about forty programs, a few of them outside any scope.
+    After warmup() the same call compiles nothing."""
+    cfg = LlamaConfig(dim=32, hidden_dim=64, n_layers=1, n_heads=2,
+                      n_kv_heads=1, vocab_size=104, seq_len=32)
+    params = random_params(cfg, seed=5, dtype=jnp.float32, quantize=False)
+    scopes: list = []
+    real = cobs.LEDGER.scope
+
+    def scope(fn, key="", sig=None):
+        scopes.append(real(fn, key, sig))
+        return scopes[-1]
+
+    def sample_once(eng, seed):
+        adm = eng.add_begin(0, [1, 2, 3])
+        while not eng.add_step(adm):
+            pass
+        jax.block_until_ready(adm.logits)
+        scopes.clear()
+        before = cobs.LEDGER.total_compiles()
+        eng.add_sample(adm, 0.8, 0.9, seed=seed)
+        assert adm.sampled is not None
+        return cobs.LEDGER.total_compiles() - before, adm
+
+    monkeypatch.setattr(cobs.LEDGER, "scope", scope)
+    cold = BatchEngine(cfg, params, n_slots=2, cache_dtype=jnp.float32)
+    assert sample_once(cold, seed=3)[0] == 1  # scoped and untracked together
+    assert [(sc.fn, sc.key, sc.n_backend) for sc in scopes] == [
+        ("commit", "b1", 1)]
+    assert sample_once(cold, seed=None)[0] == 0  # the other key, one program
+    warm = BatchEngine(cfg, params, n_slots=2, cache_dtype=jnp.float32)
+    warm.warmup(chunk=2)
+    compiled, adm = sample_once(warm, seed=None)
+    before = cobs.LEDGER.total_compiles()
+    warm.add_commit(adm, 0.8, 0.9)  # its row writes are one warm program too
+    assert compiled == 0 and cobs.LEDGER.total_compiles() == before
+    assert [(sc.fn, sc.key, sc.n_backend) for sc in scopes] == [
+        ("commit", "b1", 0), ("boundary", "commit_rows", 0)]
 
 
 def test_warmup_rejects_busy_engine():

@@ -29,10 +29,13 @@ so shared PRNG/admission counters cannot leak between runs.
 
 import time
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from dllama_tpu.engine.batch import BatchEngine
+from dllama_tpu.engine.sampling import sample_logits
 from dllama_tpu.models.config import LlamaConfig
 from dllama_tpu.models.llama import random_params
 from dllama_tpu.obs import perf
@@ -507,6 +510,38 @@ def test_commit_is_pipelined_behind_the_successor():
         assert any(n.endswith("_dispatch") for n, _ in events[j + 1:i]), events
         pipelined += 1
     assert pipelined >= 1, events
+
+
+@pytest.mark.parametrize("temperature,topp", [(0.0, 0.9), (0.8, 0.9)],
+                         ids=["greedy", "t0.8-p0.9"])
+@pytest.mark.parametrize("seed,admissions", [
+    (11, 0), (2**31 + 11, 5), (None, 0), (None, 7)],
+    ids=["seed", "seed-past-31-bits", "unseeded-first", "unseeded-eighth"])
+def test_first_token_program_is_the_eager_sampler(seed, admissions,
+                                                  temperature, topp):
+    """`add_sample`'s one program against the arithmetic it folded: the key
+    from the request's seed (as `PRNGKey` takes a Python int) or from the
+    engine's key and the admission counter, one split, `sample_logits`:
+    same token, same carried key."""
+    eng = _engine("paged")
+    adm = eng.add_begin(0, LONG_PROMPT)
+    while not eng.add_step(adm):
+        pass
+    eng._admissions = admissions
+    eng.add_sample(adm, temperature, topp, seed=seed)
+    tok, carried = adm.sampled
+    assert eng._admissions == admissions + 1
+    key = (jax.random.PRNGKey(seed) if seed is not None
+           else jax.random.fold_in(eng._base_key, admissions))
+    key, sub = jax.random.split(key)
+    want = sample_logits(adm.logits, sub, jnp.float32(temperature),
+                         jnp.float32(topp))
+    assert np.asarray(tok).tolist() == np.asarray(want).tolist()
+    assert np.asarray(carried).tolist() == np.asarray(key).tolist()
+    # the commit reads that token and decodes on that key
+    first = eng.add_commit(adm, temperature, topp, seed)
+    assert first == int(want[0]) and eng.keys[0].tolist() == np.asarray(key).tolist()
+    eng.release(0)
 
 
 def test_api_priority_tenant_parsing():

@@ -520,8 +520,9 @@ def test_capture_block_is_the_counter_deltas(monkeypatch, tmp_path):
                         "kv_rows_read", "moe_assignments",
                         "moe_experts_touched", "moe_layer_steps",
                         "moe_group_rows_max", "window_pages_released",
-                        "sched_seconds", "phase_seconds", "phases", "drains",
-                        "launch_waits", "host_gap", "seconds"}
+                        "page_topups", "sched_seconds", "phase_seconds",
+                        "phases", "drains", "launch_waits", "host_gap",
+                        "seconds"}
 
 
 def test_failed_session_start_leaves_no_hook(monkeypatch, tmp_path):

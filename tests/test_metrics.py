@@ -625,8 +625,9 @@ def test_debug_perf_capture_block_counts_the_captures_launches(
                         "kv_rows_read", "moe_assignments",
                         "moe_experts_touched", "moe_layer_steps",
                         "moe_group_rows_max", "window_pages_released",
-                        "sched_seconds", "phase_seconds", "phases", "drains",
-                        "launch_waits", "host_gap", "seconds"}
+                        "page_topups", "sched_seconds", "phase_seconds",
+                        "phases", "drains", "launch_waits", "host_gap",
+                        "seconds"}
     assert cap["launches"]["decode"] == during >= 1
     assert cap["slot_steps"]["advanced"] >= 5  # 6 tokens, the first at commit
     assert cap["kv_rows"]["decode"] > 0

@@ -447,8 +447,9 @@ def test_host_gap_summary_reads_the_histogram():
 def _idle_sched(**kw):
     """A scheduler whose worker never runs: `_boundary_reason` is asked on
     hand-built state."""
-    eng = BatchEngine(CFG, PARAMS, n_slots=3, cache_dtype=jnp.float32,
-                      **kw.pop("engine", {}))
+    engine = dict(kw.pop("engine", {}))
+    eng = BatchEngine(*engine.pop("model", (CFG, PARAMS)), n_slots=3,
+                      cache_dtype=jnp.float32, **engine)
     sched = Scheduler(eng, chunk=2, **kw)
     sched.shutdown()  # the worker is gone; the state is ours
     sched._stop.clear()
@@ -467,6 +468,7 @@ def _adm(slot=1, off=0, n=4):
 
 def _decoding(sched):
     sched.slots.setdefault(0, _req(sched))
+    sched.engine.active[0] = True  # a decoding slot is the engine's too
     return sched
 
 
@@ -533,6 +535,57 @@ def _row_limit(s):
     _decoding(s).engine.pos[0] = s.engine.seq_len
 
 
+# a pool of four 8-row pages; and a model whose second layer sees 16 rows,
+# so the engine keeps a window pool beside the global one
+PAGED = dict(kv_layout="paged", page_size=8, kv_pages=4)
+WCFG = LlamaConfig(dim=64, hidden_dim=128, n_layers=2, n_heads=4, n_kv_heads=2,
+                   vocab_size=96, seq_len=64, window=16, layer_windows=(0, 1))
+WINDOWED = dict(PAGED, kv_pages=0, model=(
+    WCFG, random_params(WCFG, seed=3, dtype=jnp.float32, quantize=False)))
+
+
+def _on_a_page_edge(s, rows=8):
+    """Slot 0 decodes and its next row is the first of a page it has not
+    got: what `pos >= limit` alone used to call a boundary."""
+    eng = _decoding(s).engine
+    for pool in (eng.pool, eng.wpool):
+        if pool is not None:
+            assert pool.grow(0, rows)
+    eng.pos[0] = rows
+    assert eng.pos[0] == eng._row_limit()[0] < eng.seq_len
+    return eng
+
+
+def _page_edge_with_a_dry_pool(s):
+    eng = _on_a_page_edge(s)
+    eng.pool.grow(1, 3 * 8)  # another slot holds the rest, the tree nothing
+    assert eng.pool.free_count == 0
+
+
+def _page_edge_with_a_free_page(s):
+    eng = _on_a_page_edge(s)
+    assert eng.pool.free_count == 3
+
+
+def _page_edge_with_an_evictable_radix_leaf(s):
+    eng = _on_a_page_edge(s)
+    eng.pool.grow(1, 3 * 8)
+    assert eng.radix_insert(1, list(range(1, 25))) == 3
+    eng._free_tail(1, 0)  # the request went, its pages stay in the tree
+    assert eng.pool.free_count == 0
+
+
+def _page_edge_on_a_window_pool(s):
+    eng = _on_a_page_edge(s)
+    assert eng.wpool is not None and eng.wpool.free_count > 0
+
+
+for _build in (_page_edge_with_a_dry_pool, _page_edge_with_a_free_page,
+               _page_edge_with_an_evictable_radix_leaf):
+    _build.engine = PAGED
+_page_edge_on_a_window_pool.engine = WINDOWED
+
+
 def _budget_ends_with_the_chunk_in_flight(s):
     _decoding(s).slots[0].produced = 48
     return types.SimpleNamespace(spec=False, n=2,
@@ -562,6 +615,13 @@ BOUNDARY_CASES = [
     ("row_limit", _row_limit),
     ("empty", _budget_ends_with_the_chunk_in_flight),
     (None, _nothing), (None, _pumped_head_under_the_pipelined_commit),
+    # the row_limit clause asks what the boundary is for (ISSUE 41): the
+    # context edge above and a dry pool have work for it, a page to be had
+    # has none
+    ("row_limit", _page_edge_with_a_dry_pool),
+    (None, _page_edge_with_a_free_page),
+    (None, _page_edge_with_an_evictable_radix_leaf),
+    (None, _page_edge_on_a_window_pool),
 ]
 
 
@@ -569,10 +629,16 @@ BOUNDARY_CASES = [
                          ids=[f"{r}-{b.__name__.strip('_')}"
                               for r, b in BOUNDARY_CASES])
 def test_boundary_reason_is_the_first_clause_that_asks(reason, build):
-    sched = _idle_sched()
+    sched = _idle_sched(engine=getattr(build, "engine", {}))
     inflight = build(sched)
     assert sched._boundary_reason(inflight) == reason
     assert reason is None or reason in perf.DRAIN_REASONS
+    if hasattr(build, "engine"):  # the page-edge cases, on a paged engine
+        eng = sched.engine
+        # the slot got its page exactly where one was to be had, and the
+        # pool's books balance after the top-up
+        assert (eng.pos[0] < eng._row_limit()[0]) == (reason is None)
+        assert eng.pool.audit()["ok"]
 
 
 def test_boundary_reason_keeps_the_clauses_order():
@@ -641,6 +707,50 @@ def test_drains_counter_moves_by_exactly_the_drains_of_a_run():
     assert counted == {k: float(v) for k, v in seen.items()}
     assert "empty" in counted  # the last stream's budget ended the batch
     assert set(counted) & {"arrival", "backlog"}  # the joiner's admission
+
+
+def test_page_edges_with_pages_to_spare_never_drain_the_pipeline():
+    """Three streams on 8-row pages, two steps a launch, prompts of even
+    length: every stream's position lands EXACTLY on the edge of its pages
+    every fourth launch, three edges a stream. The slot's next page is
+    taken with the launch in flight (`dllama_kv_page_topups_total`,
+    pipeline="full") and no launch drains under `row_limit`; until ISSUE 41
+    every such edge drained one."""
+    eng = BatchEngine(CFG, PARAMS, n_slots=3, cache_dtype=jnp.float32,
+                      kv_layout="paged", page_size=8)
+    sched = Scheduler(eng, chunk=2)
+    assert eng.pool.n_pages == 3 * 8  # pages to spare: never dry
+    topups = ins.KV_PAGE_TOPUPS.series()
+    edges = []
+    ask = eng.row_limited
+
+    def row_limited():
+        on_edge = eng.active & (eng.pos >= eng._row_limit())
+        edges.extend(int(p) for p in eng.pos[on_edge])
+        return ask()
+
+    eng.row_limited = row_limited
+
+    def submit(s):
+        r1 = s.submit([1, 2, 3, 4], 0.0, 0.9, 27, frozenset(), seed=1)
+        first = next(iter(r1.tokens()))
+        r2 = s.submit([4, 5, 6, 7, 8, 9], 0.0, 0.9, 27, frozenset(), seed=2)
+        r3 = s.submit([7, 8], 0.8, 0.9, 27, frozenset(), seed=3)
+        assert len([first] + list(r1.tokens())) == 27
+        assert len(list(r2.tokens())) == 27
+        assert len(list(r3.tokens())) == 27
+
+    try:
+        seen, counted = _scripted_drains(sched, submit)
+    finally:
+        del eng.row_limited
+    assert "row_limit" not in seen and not counted.get("row_limit")
+    moved = _delta(ins.KV_PAGE_TOPUPS, topups)
+    # the loop met a slot on the edge of its pages, each stream twice or
+    # more, and every one of those pages was taken under a launch in flight
+    assert len(edges) >= 6 and set(edges) >= {8, 16, 24}
+    assert moved["full"] >= len(edges)
+    assert eng.pool.audit()["ok"]
 
 
 def test_a_mode_switch_is_a_drain_of_its_own():
