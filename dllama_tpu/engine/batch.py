@@ -832,6 +832,9 @@ class BatchEngine:
                          "state cannot be re-entered at a page boundary "
                          "(prefix rows are recomputed)")
                 radix_cache = "off"
+        # the launch record's layer counts by kind: (global, windowed)
+        self._kind_layers = (cfg.n_attn_layers - cfg.n_window_layers,
+                             cfg.n_window_layers)
         self._kv_pool = "latent" if cfg.latent else ""  # the launch record's
         # name for cache rows of a kind of their own (rows READ, by pool)
         self.windowed = cfg.n_window_layers > 0
@@ -1893,6 +1896,22 @@ class BatchEngine:
                 st[k] += w[k]
         return st
 
+    def pool_report(self) -> dict | None:
+        """The page pools as `/health` states them: by pool the layers that
+        keep their rows there, the usable pages and the bytes on the device
+        (k and v, the trash page included); None for a dense cache."""
+        if self.pool is None:
+            return None
+        nbytes = lambda *a: int(sum(x.nbytes for x in a))
+        c = self.cache
+        out = {"global": {"layers": int(c.k.shape[0]), "pages": self.pool.n_pages,
+                          "bytes": nbytes(c.k, c.v)}}
+        if self.wpool is not None:
+            out["window"] = {"layers": int(c.kw.shape[0]),
+                             "pages": self.wpool.n_pages,
+                             "bytes": nbytes(c.kw, c.vw)}
+        return out
+
     # ------------------------------------------------------ radix prefix api
     # (engine/radix.RadixCache over the page pool; the serving scheduler is
     # the only driver — these are no-ops / zeros when the cache is off)
@@ -2830,7 +2849,8 @@ class BatchEngine:
             kind, self.chunk_seq + 1, n, start_pos, active, advance,
             seq_len=self.seq_len, pool_dry=self._pool_dry(),
             prefill_rows=prefill_rows,
-            window=self.window, kv_pool=self._kv_pool)
+            window=self.window, kv_pool=self._kv_pool,
+            kind_layers=self._kind_layers)
 
     def decode_dispatch(self, n: int, spec: bool = False) -> DecodeChunk:
         """Dispatch one fused n-step decode chunk WITHOUT waiting for its
@@ -3293,7 +3313,8 @@ class BatchEngine:
                     chunk.active, total, seq_len=self.seq_len,
                     pool_dry=chunk.launch.pool_dry,
                     frozen=np.where(total == 0, m_cycles, 0),
-                    window=self.window, kv_pool=self._kv_pool).count()
+                    window=self.window, kv_pool=self._kv_pool,
+            kind_layers=self._kind_layers).count()
                 if tr.enabled:
                     tr.span_at("decode.spec", chunk.t_disp, tr.now(),
                                cat="decode", track="launches", chunk=chunk.seq,

@@ -64,7 +64,10 @@ class KernelSelection:
     def route(self) -> str:
         """The mixers' routes: `attn_route` (with '.window' where the model
         has windowed layers and that route clips their walk:
-        'paged_kernel.window'; '.latent' where its cache rows are latent),
+        'paged_kernel.window'; '.latent' where its cache rows are latent;
+        '.heads48g64w' where the global and the windowed layers have a head
+        count each, so the kernel runs at two folds in one program, and
+        '.ropes2' where they have a rope table each),
         and behind a '+' each the recurrent state's
         decode step and element type where the model has one
         ('paged_kernel+ssm_step.float32', '...+kda_step.float32') and the expert layers' route
@@ -271,6 +274,7 @@ def resolve_kernels(
             route += ".window"  # both paged routes take the window
         if cfg.latent:
             route += ".latent"  # and the latent row (one pool, read once)
+        route += kinds_tag(cfg)
         return KernelSelection(mm=mm, mm_in=mm_in, attn_fn=attn_fn,
                                backend=backend, attn_route=route,
                                interpret=not on_tpu,
@@ -309,6 +313,13 @@ def resolve_kernels(
                     s_buckets=os.environ.get("DLLAMA_FLASH_BUCKETS") == "1")
 
     return KernelSelection(mm=mm, mm_in=mm_in, attn_fn=attn_fn,
-                           backend=backend, attn_route=route,
+                           backend=backend, attn_route=route + kinds_tag(cfg),
                            interpret=not on_tpu, state_step=state_step,
                            state_route=state_route, **moe)
+
+
+def kinds_tag(cfg: LlamaConfig) -> str:
+    """What the route says of attention whose shape goes by the layer's
+    kind: the two head counts, and that there are two rope tables."""
+    return ((f".heads{cfg.n_heads}g{cfg.window_heads}w" if cfg.window_heads else "")
+            + (".ropes2" if cfg.global_rope is not None else ""))

@@ -82,6 +82,9 @@ class LaunchRecord:
     kv_pool: str = ""  # a model whose cache rows are of a kind of their own
     # ("latent": one shared row a token): kv_rows is also counted as rows
     # READ in that pool
+    kind_layers: tuple = (0, 0)  # a model with windowed layers: how many
+    # layers see the whole context and how many are windowed (the rows a
+    # KIND walks are a layer's rows times its layers)
 
     def args(self) -> dict:
         """The span / annotation arguments (`kind` is in the name too)."""
@@ -109,6 +112,11 @@ class LaunchRecord:
             read = ins.LAUNCH_KV_ROWS_READ
             read.labels(kind=self.kind, pool="global").inc(self.kv_rows)
             read.labels(kind=self.kind, pool="window").inc(self.kv_rows_window)
+            n_global, n_window = self.kind_layers
+            ins.ATTN_ROWS_WALKED.labels(kind="global").inc(
+                self.kv_rows * n_global)
+            ins.ATTN_ROWS_WALKED.labels(kind="window").inc(
+                self.kv_rows_window * n_window)
         elif self.kv_pool and self.kv_rows:
             ins.LAUNCH_KV_ROWS_READ.labels(
                 kind=self.kind, pool=self.kv_pool).inc(self.kv_rows)
@@ -132,7 +140,7 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
           active: np.ndarray, advance: np.ndarray, *, seq_len: int,
           pool_dry: bool, prefill_rows: int = 0,
           frozen: np.ndarray | None = None, window: int = 0,
-          kv_pool: str = "") -> LaunchRecord:
+          kv_pool: str = "", kind_layers: tuple = (0, 0)) -> LaunchRecord:
     """The record of a launch of `n` steps over slots at `start_pos`, of
     which the `active` ones advance `advance` rows each.
 
@@ -143,7 +151,8 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
     condition and counts its frozen steps as starved. `frozen` gives the
     frozen steps per slot where they are not n - advance (a spec chunk).
     `window` > 0 (a model with windowed layers): also the rows such a layer
-    reads, min(p + 1, window) a step."""
+    reads, min(p + 1, window) a step; `kind_layers` = (layers that see the
+    whole context, windowed layers)."""
     if kind not in LAUNCH_KINDS:
         raise ValueError(f"unknown launch kind {kind!r} "
                          f"(catalog: {LAUNCH_KINDS})")
@@ -165,4 +174,5 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
         empty=(active.size - n_active) * int(n),
         kv_rows=int((adv * pos + adv * (adv + 1) // 2).sum()),
         prefill_rows=int(prefill_rows), pool_dry=bool(pool_dry),
-        kv_rows_window=kv_rows_window, kv_pool=kv_pool)
+        kv_rows_window=kv_rows_window, kv_pool=kv_pool,
+        kind_layers=kind_layers)

@@ -49,6 +49,8 @@ class RopeType(IntEnum):
     FALCON = 1  # present in the reference enum order (nn-core.hpp), unused
     LLAMA3_1 = 2
     NONE = 3  # no positional rotation at all: q and k are used as projected
+    YARN = 4  # YaRN (Peng et al. 2023) as the public `transformers` library's
+    # `yarn` rope type computes it; a `RopeSpec`'s type only
 
 
 class HeaderKey(IntEnum):
@@ -118,6 +120,26 @@ class HeaderKey(IntEnum):
     EXPERTS_HELD = 153  # this file holds experts [offset, offset + held)
     EXPERT_OFFSET = 154
     MOE_HIDDEN_DIM = 155  # an expert's width where dense layers differ
+    # ---- dllama-tpu extensions for attention whose shape goes by the layer's
+    # KIND, windowed or global (absent = one head count, one rope table, no
+    # norm over the head, no gate)
+    WINDOW_HEADS = 160  # query heads of a WINDOWED layer (N_HEADS: the
+    # others'); present = the windowed layers' attention tensors are stacked
+    # apart from the global layers' (`*_win`)
+    QK_NORM = 161  # 1 = q and k are RMS-normed over the head before the
+    # rotation, one gain vector each a layer, shared by its heads
+    ATTN_GATE = 162  # 1 = a head's output is multiplied by softplus(n W_g)
+    # (one gate a head, read from the attention block's normed input)
+    # the GLOBAL layers' own rope (absent = they rotate as ROPE_TYPE says,
+    # like every other layer): a `RopeSpec`, floats int-coded x 1e6
+    GLOBAL_ROPE_TYPE = 170
+    GLOBAL_ROPE_THETA = 171
+    GLOBAL_ROPE_SHARE_X1E6 = 172  # the leading share of a head that rotates
+    GLOBAL_ROPE_FACTOR_X1E6 = 173
+    GLOBAL_ROPE_ORIG_LEN = 174
+    GLOBAL_ROPE_BETA_FAST_X1E6 = 175
+    GLOBAL_ROPE_BETA_SLOW_X1E6 = 176
+    GLOBAL_ROPE_ATTN_FACTOR_X1E6 = 177  # cos and sin are multiplied by it
 
 
 #: the kind of layer i is header key LAYER_KIND_BASE + i (one key a layer,
@@ -141,6 +163,28 @@ SCHEDULE_KIND_MASK = 3
 SCHEDULE_WINDOWED = 4
 SCHEDULE_UNROTATED = 8
 SCHEDULE_DENSE_FFN = 16  # a dense feed-forward block in a model with experts
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """A rope table of its own for one kind of attention layer
+    (ops/layers.rope_table builds it): plain (`RopeType.LLAMA`) or YaRN, over
+    the leading `share` of a head; the dims behind it pass through."""
+
+    type: RopeType = RopeType.LLAMA
+    theta: float = 10000.0
+    share: float = 1.0
+    factor: float = 1.0  # YaRN: positions are interpolated by it
+    orig_len: int = 0  # YaRN: the context the model was trained at
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attn_factor: float = 1.0
+
+    def describe(self) -> str:
+        yarn = (f" x{self.factor:g} from {self.orig_len} beta "
+                f"{self.beta_fast:g}/{self.beta_slow:g} attn "
+                f"{self.attn_factor:.4f}" if self.type == RopeType.YARN else "")
+        return f"{self.type.name} theta={self.theta:g} share={self.share:g}{yarn}"
 
 
 @dataclasses.dataclass
@@ -206,6 +250,13 @@ class LlamaConfig:
     expert_offset: int = 0
     moe_hidden_dim: int = 0  # 0 = hidden_dim
     layer_ffn: tuple = ()  # 0/1 per layer, 1 = dense; () = none is dense
+    # ---- attention by layer kind (defaults: one kind of attention layer)
+    window_heads: int = 0  # query heads of a windowed layer; 0 = n_heads and
+    # one stack of attention tensors for every layer
+    qk_norm: bool = False
+    attn_gate: bool = False
+    global_rope: RopeSpec | None = None  # the global (not windowed) layers'
+    # own rope table; None = they rotate as the others
 
     def __post_init__(self):
         if self.orig_seq_len == 0:
@@ -243,6 +294,19 @@ class LlamaConfig:
                              "supported)")
         if LayerKind.KDA in kinds and not (self.kda_heads and self.kda_rank):
             raise ValueError("delta-rule layers need KDA_HEADS and KDA_RANK")
+        if self.window_heads and (
+                not any(self.layer_windows) or LayerKind.MLA in kinds
+                or self.window_heads % self.n_kv_heads):
+            raise ValueError("WINDOW_HEADS is for softmax attention with "
+                             "windowed layers, a whole number of query heads "
+                             "a kv head")
+        if self.global_rope is not None and (
+                self.rope_type == RopeType.NONE
+                or not 0 < self.global_rope.share <= 1
+                or int(self.head_size * self.global_rope.share) % 2):
+            raise ValueError("a rope table of the global layers' own needs a "
+                             "model that rotates and an even number of "
+                             "rotated dims")
         if any(self.layer_ffn) and not self.n_experts:
             raise ValueError("per-layer feed-forward kinds are for a model "
                              "with experts")
@@ -269,6 +333,33 @@ class LlamaConfig:
         """Columns of wq and rows of wo: heads x head size (the model's dim
         unless the header gives a head size of its own)."""
         return self.n_heads * self.head_size
+
+    def heads_of(self, windowed: bool) -> int:
+        """Query heads of a layer of that kind (`n_heads`, `attn_dim` and
+        `q_per_kv` are the global kind's, and every layer's where the header
+        gives one head count)."""
+        return self.window_heads if windowed and self.window_heads else self.n_heads
+
+    def attn_dim_of(self, windowed: bool) -> int:
+        return self.heads_of(windowed) * self.head_size
+
+    def q_per_kv_of(self, windowed: bool) -> int:
+        return self.heads_of(windowed) // self.n_kv_heads
+
+    def attn_suffix(self, windowed: bool) -> str:
+        """What a windowed layer's attention tensors are named by where the
+        kinds are stacked apart: `wq_win`, ... beside the global `wq`."""
+        return "_win" if windowed and self.window_heads else ""
+
+    def kind_index(self, layer: int) -> int:
+        """Layer `layer`'s index among the cache-holding layers of ITS kind,
+        windowed or global: into a pool a kind, and into the attention
+        stacks where they are stacked apart."""
+        kinds = self.layer_kinds or (int(LayerKind.ATTENTION),) * self.n_layers
+        mine = bool(self.layer_window(layer))
+        return sum(1 for i in range(layer)
+                   if kinds[i] not in STATE_KINDS
+                   and bool(self.layer_window(i)) == mine)
 
     @property
     def n_window_layers(self) -> int:
@@ -413,10 +504,16 @@ class LlamaConfig:
         (llm.cpp:100-123)."""
         return (
             f"{self.arch.name} dim={self.dim} hidden={self.hidden_dim} "
-            f"layers={self.n_layers} heads={self.n_heads}/{self.n_kv_heads} "
+            f"layers={self.n_layers} heads={self.n_heads}"
+            + (f"g,{self.window_heads}w" if self.window_heads else "")
+            + f"/{self.n_kv_heads} "
             f"vocab={self.vocab_size} seq={self.seq_len} "
-            f"act={self.hidden_act.name} rope={self.rope_type.name} "
-            f"weights={self.weight_type.name}"
+            f"act={self.hidden_act.name} rope={self.rope_type.name}"
+            + (f" theta={self.rope_theta:g} (window); global rope "
+               f"{self.global_rope.describe()}" if self.global_rope else "")
+            + f" weights={self.weight_type.name}"
+            + (" qk_norm" if self.qk_norm else "")
+            + (" attn_gate=per_head" if self.attn_gate else "")
             + (f" experts={self.n_experts}/{self.n_active_experts}" if self.n_experts else "")
             + (f" held={self.expert_offset}+{self.experts_held}"
                if self.experts_held else "")
@@ -494,11 +591,21 @@ class LlamaConfig:
             kv.append((HeaderKey.WINDOW_SIZE, self.window))
         kv += [(LAYER_WINDOW_BASE + i, w) for i, w in enumerate(self.layer_windows)]
         kv += [(LAYER_ROPE_BASE + i, r) for i, r in enumerate(self.layer_ropes)]
+        if self.window_heads:
+            kv.append((HeaderKey.WINDOW_HEADS, self.window_heads))
+        if self.qk_norm:
+            kv.append((HeaderKey.QK_NORM, 1))
+        if self.attn_gate:
+            kv.append((HeaderKey.ATTN_GATE, 1))
+        if self.global_rope is not None:
+            kv += [(key, int(round(getattr(self.global_rope, name) * mult)))
+                   for key, (name, mult) in _GLOBAL_ROPE_KEYS.items()]
         return [(int(k), int(v)) for k, v in kv]
 
     @classmethod
     def from_header_kv(cls, kv: list[tuple[int, int]]) -> "LlamaConfig":
         vals: dict = {}
+        grope: dict = {}
         kinds: dict = {}
         windows: dict = {}
         ropes: dict = {}
@@ -577,6 +684,17 @@ class LlamaConfig:
                 vals["router_sigmoid"] = bool(value)
             elif key == HeaderKey.ROUTED_SCALE_X1E6:
                 vals["routed_scale"] = value / 1e6
+            elif key == HeaderKey.WINDOW_HEADS:
+                vals["window_heads"] = value
+            elif key == HeaderKey.QK_NORM:
+                vals["qk_norm"] = bool(value)
+            elif key == HeaderKey.ATTN_GATE:
+                vals["attn_gate"] = bool(value)
+            elif key in _GLOBAL_ROPE_KEYS:
+                name, mult = _GLOBAL_ROPE_KEYS[key]
+                grope[name] = type(_ROPE_DEFAULTS[name])(value / mult)
+        if grope:
+            vals["global_rope"] = RopeSpec(**grope)
         for name, flags in (("layer_windows", windows), ("layer_ropes", ropes),
                             ("layer_ffn", ffns)):
             if flags:
@@ -611,6 +729,17 @@ _EXTRA_INT_KEYS = {HeaderKey.KDA_HEADS: "kda_heads",
                    HeaderKey.EXPERT_OFFSET: "expert_offset",
                    HeaderKey.MOE_HIDDEN_DIM: "moe_hidden_dim"}
 _FIELD_DEFAULTS = {f.name: f.default for f in dataclasses.fields(LlamaConfig)}
+#: header key -> (RopeSpec field, what its value is multiplied by on disk)
+_GLOBAL_ROPE_KEYS = {
+    HeaderKey.GLOBAL_ROPE_TYPE: ("type", 1),
+    HeaderKey.GLOBAL_ROPE_THETA: ("theta", 1),
+    HeaderKey.GLOBAL_ROPE_SHARE_X1E6: ("share", 1e6),
+    HeaderKey.GLOBAL_ROPE_FACTOR_X1E6: ("factor", 1e6),
+    HeaderKey.GLOBAL_ROPE_ORIG_LEN: ("orig_len", 1),
+    HeaderKey.GLOBAL_ROPE_BETA_FAST_X1E6: ("beta_fast", 1e6),
+    HeaderKey.GLOBAL_ROPE_BETA_SLOW_X1E6: ("beta_slow", 1e6),
+    HeaderKey.GLOBAL_ROPE_ATTN_FACTOR_X1E6: ("attn_factor", 1e6)}
+_ROPE_DEFAULTS = {f.name: f.default for f in dataclasses.fields(RopeSpec)}
 _SSM_INT_KEYS = {HeaderKey.SSM_HEADS: "ssm_heads",
                  HeaderKey.SSM_HEAD_DIM: "ssm_head_dim",
                  HeaderKey.SSM_STATE: "ssm_state",

@@ -153,12 +153,24 @@ def tensor_plan(config: LlamaConfig) -> list[tuple[str, tuple[int, int] | tuple[
                 (p + "mla_o", (config.dim, h * config.v_head_dim), wt),
             ]
         else:
+            # a layer's attention tensors go by its kind, windowed or global,
+            # where the header gives the windowed layers heads of their own:
+            # `*_win` names, so the loader stacks the two kinds apart
+            win = bool(config.layer_window(layer))
+            p, sfx = f"layers.{layer}.", config.attn_suffix(win)
+            heads, ad = config.heads_of(win), config.attn_dim_of(win)
             plan += [
-                (f"layers.{layer}.wq", (config.attn_dim, config.dim), wt),
-                (f"layers.{layer}.wk", (config.kv_dim, config.dim), wt),
-                (f"layers.{layer}.wv", (config.kv_dim, config.dim), wt),
-                (f"layers.{layer}.wo", (config.dim, config.attn_dim), wt),
+                (p + "wq" + sfx, (ad, config.dim), wt),
+                (p + "wk" + sfx, (config.kv_dim, config.dim), wt),
+                (p + "wv" + sfx, (config.kv_dim, config.dim), wt),
+                (p + "wo" + sfx, (config.dim, ad), wt),
             ]
+            if config.qk_norm:
+                plan += [(p + "q_norm" + sfx, (config.head_size,), FloatType.F32),
+                         (p + "k_norm" + sfx, (config.head_size,), FloatType.F32)]
+            if config.attn_gate:
+                plan.append((p + "attn_gate" + sfx, (heads, config.dim),
+                             FloatType.F32))
         if config.n_experts and not (config.layer_ffn and config.layer_ffn[layer]):
             # MoE extension: the reference header carries N_EXPERTS
             # (llm.hpp:17-18) and its HF converter emits expert tensors
@@ -415,7 +427,8 @@ def _load_matmul(raw: np.ndarray, shape: tuple[int, int], ft: FloatType, dtype, 
 #: state-space mixer's conv, step and skip parameters
 _F32_LEAVES = ("rms_att", "rms_ffn", "conv_w", "conv_b", "dt_bias", "a_log",
                "d", "ssm_norm", "kda_conv_w", "kda_dt_bias", "kda_a_log",
-               "kda_norm", "mla_kv_norm", "moe_bias")
+               "kda_norm", "mla_kv_norm", "moe_bias", "q_norm", "k_norm",
+               "q_norm_win", "k_norm_win")
 #: matmul weights whose published output width is not whole lane tiles: zero
 #: columns are added on the way to the device, the file keeps the width
 #: (kda_proj to whole 512s so that the wide tiles divide it)
@@ -509,8 +522,10 @@ def load_params(
                 # are held as the float32 values the Q40 blocks decode to
                 leaf = decode_dense(raw, shape, ft).reshape(
                     config.n_heads, -1, shape[1]).astype(np.float32, order="C")
-            elif short == "moe_gate":
+            elif short in ("moe_gate", "attn_gate", "attn_gate_win"):
                 # router stays f32; file [E, dim] -> h@gate operand [dim, E]
+                # (the attention output's gate likewise: [heads, dim] ->
+                # [dim, heads])
                 leaf = decode_dense(raw, shape, ft).T.astype(np.float32, order="C")
             elif short.startswith("moe_"):
                 leaf = _load_expert_matmul(raw, shape, ft, dtype, dequantize)

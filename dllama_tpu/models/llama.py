@@ -341,30 +341,39 @@ from dllama_tpu.ops.quant import slice_leaf as _slice_layer
 def _attention_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, rope,
                      pos_base, attn_fn, active, mm, colmm, tables, ci=None,
                      window: int = 0):
-    """Softmax attention over the cache. `ai` indexes the ATTENTION layers'
-    weight stacks and `ci` the cache's layer axis (= `ai` unless the cache
-    is a pool a kind; a hybrid model has fewer of both than it has layers).
-    `window` > 0: the layer's queries see that many rows. Returns
-    (out [B, T, D], k_cache, v_cache)."""
+    """Softmax attention over the cache. `ai` indexes the layer's weight
+    stacks (the attention layers', or its own KIND's where the windowed
+    layers' tensors are stacked apart as `*_win`) and `ci` the cache's layer
+    axis (= `ai` unless the cache is a pool a kind; a hybrid model has fewer
+    of both than it has layers). `window` > 0: the layer's queries see that
+    many rows, and the layer has the windowed kind's head count. `rope` is
+    the layer's own table's rows. Returns (out [B, T, D], k_cache, v_cache)."""
     b, t, _ = h.shape
-    d = cfg.attn_dim  # heads x head size: the model's dim unless the header
-    # gives the head size
+    windowed = window > 0
+    heads, sfx = cfg.heads_of(windowed), cfg.attn_suffix(windowed)
+    d = heads * cfg.head_size  # heads x head size: the model's dim unless
+    # the header gives the head size
     kvd = cfg.kv_dim
     ci = ai if ci is None else ci
     win = {"window": window} if window else {}
-    if "wqkv" in layers:  # fused launch (fuse_layer_weights)
-        qkv = mm(h, layers["wqkv"], ai)
+    if "wqkv" + sfx in layers:  # fused launch (fuse_layer_weights)
+        qkv = mm(h, layers["wqkv" + sfx], ai)
         q, k, v = qkv[..., :d], qkv[..., d : d + kvd], qkv[..., d + kvd :]
     else:
-        q = mm(h, layers["wq"], ai)
-        k = mm(h, layers["wk"], ai)
-        v = mm(h, layers["wv"], ai)
-    q = q.reshape(b, t, cfg.n_heads, cfg.head_size)
+        q = mm(h, layers["wq" + sfx], ai)
+        k = mm(h, layers["wk" + sfx], ai)
+        v = mm(h, layers["wv" + sfx], ai)
+    q = q.reshape(b, t, heads, cfg.head_size)
     k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_size)
     v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_size)
+    if cfg.qk_norm:  # over the head, before the rotation
+        with jax.named_scope("qk_norm"):
+            q = rms_norm(q, layers["q_norm" + sfx][ai], cfg.norm_epsilon)
+            k = rms_norm(k, layers["k_norm" + sfx][ai], cfg.norm_epsilon)
     if rope is not None:  # RopeType.NONE: q and k as projected
-        q = apply_rope(q, rope)
-        k = apply_rope(k, rope)
+        with jax.named_scope("rope_window" if windowed else "rope_global"):
+            q = apply_rope(q, rope)
+            k = apply_rope(k, rope)
     if cfg.softmax_scale_ratio != 1.0:
         # a configured score scale: every attention path bakes 1/sqrt(hd)
         # in, so q carries the ratio (a power of two where the scale is one:
@@ -392,7 +401,14 @@ def _attention_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, rope,
         v_cache = _paged_cache_update(v_cache, v.transpose(0, 2, 1, 3),
                                       tables, pos_base, active)
         att = attn_fn(q, k_cache, v_cache, tables, pos_base, **win).reshape(b, t, d)
-    return colmm(att, layers["wo"], ai), k_cache, v_cache
+    if cfg.attn_gate:
+        # one gate a head, softplus in float32, read from the same normed
+        # input as q
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.softplus(router_logits(h, layers["attn_gate" + sfx][ai]))
+            att = (att.reshape(b, t, heads, cfg.head_size).astype(jnp.float32)
+                   * gate[..., None]).astype(h.dtype).reshape(b, t, d)
+    return colmm(att, layers["wo" + sfx], ai), k_cache, v_cache
 
 
 def _ssm_mixer(cfg: LlamaConfig, h, layers, si, state: RecurrentState,
@@ -670,8 +686,9 @@ def fuse_layer_weights(layers: dict) -> dict:
         return jnp.concatenate(ws, axis=-1)
 
     out = dict(layers)
-    if all(k in out for k in ("wq", "wk", "wv")):
-        out["wqkv"] = cat(out.pop("wq"), out.pop("wk"), out.pop("wv"))
+    for sfx in ("", "_win"):  # a stack a kind of attention layer
+        if all(k + sfx in out for k in ("wq", "wk", "wv")):
+            out["wqkv" + sfx] = cat(*(out.pop(k + sfx) for k in ("wq", "wk", "wv")))
     if all(k in out for k in ("w1", "w3")):
         out["w13"] = cat(out.pop("w1"), out.pop("w3"))
     return out
@@ -741,8 +758,9 @@ def run_layers(
     pos_base: jax.Array,  # scalar, or [B] per-row positions
     k_cache: jax.Array,  # [La, B, Hkv, S, hd], La = attention layers
     v_cache: jax.Array,
-    rope: jax.Array | None,  # [T, head_size/2, 2] rope rows (or [B, T, ...]
-    # per-row); None = no rotation
+    rope,  # [T, head_size/2, 2] rope rows (or [B, T, ...] per-row); None =
+    # no rotation; a pair (global layers' rows, windowed layers' rows) where
+    # the kinds have a table each (`cfg.global_rope`)
     attn_fn=None,
     active: jax.Array | None = None,  # [B] bool: rows allowed to write cache
     unroll: int | bool = 1,
@@ -828,6 +846,15 @@ def run_layers(
     a_pp = sum(n for kind, _, n in runs if is_attn_kind(kind))
     s_pp = period - a_pp
     two_pools = wpool is not None  # a pool a kind: (kw, vw, wtables)
+    # a layer's index among the cache-holding layers of its kind, windowed
+    # or global, looked up by the traced layer where the period's arithmetic
+    # does not give it: into the attention stacks where they are stacked
+    # apart, and into a pool a kind under a ragged pattern
+    kind_ix = (jnp.asarray([cfg.kind_index(i) for i in range(cfg.n_layers)],
+                           jnp.int32)
+               if cfg.window_heads or (two_pools and ragged is not None)
+               else None)
+    rope_g, rope_w = rope if isinstance(rope, tuple) else (rope, rope)
     w_pp = (sum(n for kind, _, n in runs if kind & SCHEDULE_WINDOWED)
             if two_pools else 0)
     kernel = tables is not None and getattr(attn_fn, "fused_kv_scatter", False)
@@ -837,10 +864,6 @@ def run_layers(
     # ... and wherever the pattern is ragged (its runs are loops of a length
     # that is data: no slice of the cache can ride as a scan's xs)
     fused = kernel or two_pools or ragged is not None
-    if ragged is not None and two_pools:
-        raise NotImplementedError(
-            "a ragged layer pattern beside windowed layers with a page pool "
-            "of their own is not supported")
     kwp, vwp, wtables = wpool if two_pools else (None, None, None)
 
     def one_layer(x, kc, vc, st, kw, vw, ms, li, ai, ci, si, kind):
@@ -864,7 +887,10 @@ def run_layers(
         in_wpool = windowed and two_pools
         pk, pv = (kw, vw) if in_wpool else (kc, vc)
         tbl = wtables if in_wpool else tables
-        lrope = None if kind & SCHEDULE_UNROTATED else rope
+        lrope = (None if kind & SCHEDULE_UNROTATED
+                 else rope_w if windowed else rope_g)
+        if cfg.window_heads:
+            ai = kind_ix[li]  # the stacks of the layer's own kind
 
         def mix(h, mm_, colmm):
             # the kernel indexes the layer in the carried stack; any other
@@ -976,13 +1002,14 @@ def run_layers(
             either way."""
             def body(j, c):
                 ai = ai0 + j
-                return one_layer(*c, li0 + j, ai, ai, si0 + j, kind)
+                ci = kind_ix[li0 + j] if two_pools else ai
+                return one_layer(*c, li0 + j, ai, ci, si0 + j, kind)
 
             if isinstance(n, int) and n == 1:
                 return body(0, c)
             return jax.lax.fori_loop(0, n, body, c)
 
-        c = (x, k_cache, v_cache, state, None, None, moe_stats)
+        c = (x, k_cache, v_cache, state, kwp, vwp, moe_stats)
         li = ai = si = 0
         for kind, first, n in prefix:
             c = run_of(c, kind, n, li, ai, si)
@@ -1010,7 +1037,7 @@ def run_layers(
                 c = run_of(c, kind, n, li0, ai0, si0)
             return c, None
 
-        (x, k_new, v_new, state, _, _, moe_stats), _ = jax.lax.scan(
+        (x, k_new, v_new, state, kwp, vwp, moe_stats), _ = jax.lax.scan(
             ragged_period, c, jnp.arange(len(lengths), dtype=jnp.int32),
             unroll=unroll)
         return extra((x, k_new, v_new, state))
@@ -1033,7 +1060,8 @@ def forward(
     tokens: jax.Array,  # i32 [B, T]
     pos_base: jax.Array,  # scalar i32
     cache: KVCache,
-    rope_cache: jax.Array,  # [seq, head_size/2, 2]
+    rope_cache,  # [seq, head_size/2, 2]; a pair of tables where the global
+    # layers have a rope of their own (ops/layers.build_rope_cache)
     attn_fn=None,  # (q, k_cache, v_cache, pos) -> out; default full-cache GQA.
     # A sequence-parallel mesh passes the shard_map'd LSE-merge attention here
     # (parallel/ring_attention.sp_cache_attention).
@@ -1073,9 +1101,12 @@ def forward(
         rope = None
     elif pos_base.ndim == 1:
         idx = pos_base[:, None] + jnp.arange(t, dtype=jnp.int32)[None]  # [B, T]
-        rope = rope_cache[jnp.clip(idx, 0, rope_cache.shape[0] - 1)]
+        rope = jax.tree.map(
+            lambda c: c[jnp.clip(idx, 0, c.shape[0] - 1)], rope_cache)
     else:
-        rope = jax.lax.dynamic_slice_in_dim(rope_cache, pos_base, t, axis=0)
+        rope = jax.tree.map(
+            lambda c: jax.lax.dynamic_slice_in_dim(c, pos_base, t, axis=0),
+            rope_cache)
     paged = isinstance(cache, PagedKVCache)
     wpool = ((cache.kw, cache.vw, cache.wtables)
              if paged and cache.kw is not None else None)

@@ -353,6 +353,15 @@ LAUNCH_KV_ROWS_READ = metrics.counter(
     "dllama_launch_kv_rows_total), in the window pool min(position + 1, "
     "window) a slot-step; only for a model with windowed layers",
     ("kind", "pool"))
+ATTN_ROWS_WALKED = metrics.counter(
+    "dllama_attn_rows_walked_total",
+    "KV rows the attention layers of a kind walked in the launches' decode "
+    "steps, summed over that kind's LAYERS: global = rows attended x the "
+    "layers that see the whole context, window = min(position + 1, window) "
+    "a slot-step x the windowed layers (a window's worth a slot a layer "
+    "however long the context); only for a model with windowed layers. At "
+    "one row width it is how a step's KV bytes split by kind",
+    ("kind",))
 # routed experts (ops/layers.moe_ffn): summed on the device over layers and
 # steps, fetched with a launch's tokens (BatchEngine.decode_consume)
 MOE_ASSIGNMENTS = metrics.counter(

@@ -68,12 +68,49 @@ def llama31_scale_freqs(freqs: np.ndarray, cfg: LlamaConfig) -> np.ndarray:
     return out.astype(np.float32)
 
 
-def build_rope_cache(cfg: LlamaConfig, seq_len: int | None = None) -> jax.Array:
+def yarn_freqs(spec, rot: int) -> np.ndarray:
+    """YaRN's inverse frequencies over `rot` rotated dims, as the public
+    `transformers` library's `yarn` rope type computes them: the plain
+    f_i = theta^(-2i/rot) where a dim turns more than `beta_fast` times
+    over the original context, f_i / factor where it turns fewer than
+    `beta_slow` times, and a linear ramp over the frequency indices between
+    the two correction dims d(b) = rot ln(orig_len / (2 pi b)) / (2 ln theta)
+    (floor of d(beta_fast), ceil of d(beta_slow))."""
+    half = rot // 2
+    plain = 1.0 / (spec.theta ** (np.arange(half, dtype=np.float64) * 2.0 / rot))
+    dim_of = lambda turns: (rot * math.log(spec.orig_len / (turns * 2 * math.pi))
+                            / (2 * math.log(spec.theta)))
+    low = max(math.floor(dim_of(spec.beta_fast)), 0)
+    high = min(math.ceil(dim_of(spec.beta_slow)), rot - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / ((high if high != low else high + 0.001) - low), 0.0, 1.0)
+    return plain / spec.factor * ramp + plain * (1.0 - ramp)
+
+
+def rope_table(spec, head_size: int, seq_len: int) -> jax.Array:
+    """[seq_len, rot/2, 2] (cos, sin) of a `RopeSpec`, rot = the leading
+    `share` of the head that rotates (`apply_rope` passes the dims behind it
+    through), both multiplied by the spec's attention factor."""
+    rot = int(head_size * spec.share)
+    if spec.type == RopeType.YARN:
+        freqs = yarn_freqs(spec, rot)
+    else:
+        freqs = 1.0 / (spec.theta ** (np.arange(rot // 2, dtype=np.float64) * 2.0 / rot))
+    angles = np.outer(np.arange(seq_len, dtype=np.float32), freqs.astype(np.float32))
+    cache = np.stack([np.cos(angles), np.sin(angles)], axis=-1) * spec.attn_factor
+    return jnp.asarray(cache, dtype=jnp.float32)
+
+
+def build_rope_cache(cfg: LlamaConfig, seq_len: int | None = None):
     """Precomputed [seq_len, head_size/2, 2] (cos, sin) table, f32.
 
     The analog of the reference's per-node rope_cache buffer
     (nn-cpu-ops.cpp:1082-1102), computed for the *interleaved-pair* layout the
     `.m` format stores Q/K in (converter permutation, convert-hf.py:11-14).
+
+    A model whose global layers have a rope of their own (`cfg.global_rope`)
+    gets the pair (global table, this table): `models/llama.forward` cuts the
+    rows of both and a layer is handed its kind's.
     """
     seq_len = seq_len or cfg.seq_len
     half = cfg.head_size // 2
@@ -83,8 +120,11 @@ def build_rope_cache(cfg: LlamaConfig, seq_len: int | None = None) -> jax.Array:
         freqs = llama31_scale_freqs(freqs, cfg)
     t = np.arange(seq_len, dtype=np.float32)
     angles = np.outer(t, freqs)  # [S, half]
-    cache = np.stack([np.cos(angles), np.sin(angles)], axis=-1)
-    return jnp.asarray(cache, dtype=jnp.float32)
+    cache = jnp.asarray(np.stack([np.cos(angles), np.sin(angles)], axis=-1),
+                        dtype=jnp.float32)
+    if cfg.global_rope is not None:
+        return rope_table(cfg.global_rope, cfg.head_size, seq_len), cache
+    return cache
 
 
 def apply_rope(x: jax.Array, rope: jax.Array) -> jax.Array:
@@ -93,8 +133,14 @@ def apply_rope(x: jax.Array, rope: jax.Array) -> jax.Array:
     x: [B, T, H, head_size]; rope: [T, head_size/2, 2] rows already gathered
     for the absolute positions of the T tokens — or [B, T, head_size/2, 2]
     when rows differ per sequence (continuous batching: per-slot positions).
+    A table of fewer pairs than the head holds rotates the LEADING dims of
+    the head; the dims behind them pass through.
     """
     b, t, h, hs = x.shape
+    rot = 2 * rope.shape[-2]
+    if rot < hs:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], rope), x[..., rot:]], axis=-1)
     xf = x.astype(jnp.float32).reshape(b, t, h, hs // 2, 2)
     if rope.ndim == 4:  # per-row rope rows
         cos = rope[:, :, None, :, 0]

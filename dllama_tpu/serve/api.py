@@ -430,6 +430,10 @@ class ApiServer:
                 "window": eng.cfg.window, "pages": eng.wpool.n_pages,
                 "resolved_off": ["radix_cache", "kv_host_pages",
                                  "cross_slot_prefix_copy", "preempt_to_pages"]}
+        pools = eng.pool_report() if eng is not None else None
+        if pools is not None:
+            # the page pools, by pool: layers, usable pages, device bytes
+            h["kv_pools"] = pools
         h["build"] = self.build_info
         # process self-metrics ride every probe (and /metrics as gauges):
         # uptime answers "did it just restart", RSS + threads answer "is it

@@ -23,6 +23,7 @@ equivalents here:
 from __future__ import annotations
 
 import contextlib
+import logging
 import tempfile
 import threading
 import time
@@ -34,6 +35,8 @@ import numpy as np
 from dllama_tpu.obs import instruments as ins
 from dllama_tpu.obs import trace as reqtrace
 from dllama_tpu.utils import locks
+
+log = logging.getLogger(__name__)
 
 
 class ProfileBusy(RuntimeError):
@@ -134,18 +137,27 @@ def _profiler_begin(log_dir: str, duration_s: float | None = None,
 
 def _profiler_end() -> None:
     with _prof_lock:
-        if not _prof_state["active"]:
+        if not _prof_state["active"] or _prof_state.get("stopping"):
             return
+        _prof_state["stopping"] = True
         end, before = _launch_counters(), _capture["begin"]
         _capture["last"] = {
             **{k: {label: v - before[k].get(label, 0.0)
                    for label, v in end[k].items()} for k in end},
             "seconds": time.monotonic() - _capture["t_begin"]}
-        try:
-            jax.profiler.stop_trace()
-        finally:
+    # writing the capture out takes as long as the capture is large (a
+    # 40-layer step of 6,600 device ops: over a minute for 2 s): OUTSIDE the
+    # lock, so that `last_capture` (GET /debug/perf) reads the block above at
+    # once; the session stays `active` until the file is written, so a second
+    # capture is still refused meanwhile
+    t0 = time.monotonic()
+    try:
+        jax.profiler.stop_trace()
+    finally:
+        with _prof_lock:
             reqtrace.PROFILER_HOOK = None
-            _prof_state.update(active=False, duration_s=None)
+            _prof_state.update(active=False, duration_s=None, stopping=False)
+    log.info("device profile capture written in %.1f s", time.monotonic() - t0)
     reqtrace.TRACER.event("profile.stop", cat="profile", track="profiler")
 
 

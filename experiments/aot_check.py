@@ -442,9 +442,11 @@ def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
     be = BatchEngine(cfg, params, n_slots=slots, max_seq_len=seq,
                      kv_layout="paged", page_size=page, kv_pages=2,
                      spec=spec, max_prefill_chunk=prefill_chunk)
+    from dllama_tpu.engine.kernel_select import kinds_tag
+
     assert be.kernel_route == (
         "pallas/paged_kernel" + (".window" if cfg.n_window_layers else "")
-        + (".latent" if cfg.latent else "")
+        + (".latent" if cfg.latent else "") + kinds_tag(cfg)
         + ("+kda_step.float32" if cfg.n_kda_layers else
            "+ssm_step.float32" if cfg.recurrent else "")
         + ("+moe_grouped" if cfg.n_experts else "")), be.kernel_route
@@ -742,6 +744,85 @@ def delta_latent_cases(topo, slots: int = DELTA_LATENT_SLOTS,
                            slots, 0, kv_pages=pages, seq=DELTA_LATENT_SEQ)
 
 
+#: attention by layer kind over sigmoid-routed experts at the published
+#: widths and depth of benchmark/configs/laguna-xs.2.json: 2,048 stream, 48
+#: global / 64 windowed query heads over 8 kv heads of 128, a 512-row window,
+#: a dense layer of 8,192, then 64 held of 256 experts of 512 with 8 active
+#: and a shared expert, a 25,088-row head; 24 slots over 816 pages, 256-row
+#: slices
+ATTN_KINDS_SLOTS, ATTN_KINDS_PAGES, ATTN_KINDS_SEQ, ATTN_KINDS_SLICE = 24, 816, 4224, 256
+
+
+def attn_kinds_cfg(n_layers: int = 40):
+    from dllama_tpu.models.config import LlamaConfig, RopeSpec, RopeType
+
+    return LlamaConfig(
+        dim=2048, hidden_dim=8192, n_layers=n_layers, n_heads=48, n_kv_heads=8,
+        vocab_size=25088, seq_len=ATTN_KINDS_SEQ, head_dim=128,
+        norm_epsilon=1e-6, n_experts=256, n_active_experts=8, window=512,
+        layer_windows=tuple(int(i % 4 != 0) for i in range(n_layers)),
+        window_heads=64, qk_norm=True, attn_gate=True,
+        global_rope=RopeSpec(RopeType.YARN, 500000.0, 0.5, 64.0, 4096, 64.0,
+                             1.0, 1.415888),
+        router_sigmoid=True, routed_scale=2.5, n_shared_experts=1,
+        experts_held=64, expert_offset=0, moe_hidden_dim=512,
+        layer_ffn=(1,) + (0,) * (n_layers - 1))
+
+
+def attn_kinds_params(cfg, A):
+    """Abstract params as models/formats.load_params stacks them: the
+    attention tensors apart by kind (`*_win`), dense and expert feed-forward
+    weights apart."""
+    def qw(lead, k, n):
+        return QTensor(A((*lead, k // 2, n), jnp.uint8),
+                       A((*lead, k // Q_BLOCK, n), jnp.float16))
+
+    f32 = lambda *shape: A(shape, jnp.float32)
+    L, Lw = cfg.n_layers, cfg.n_window_layers
+    Ld = cfg.n_dense_ffn_layers
+    Le, d, w, E = L - Ld, cfg.dim, cfg.expert_width, cfg.n_held_experts
+    layers = {
+        "w1": qw((Ld,), d, cfg.hidden_dim), "w2": qw((Ld,), cfg.hidden_dim, d),
+        "w3": qw((Ld,), d, cfg.hidden_dim),
+        "moe_gate": f32(Le, d, cfg.n_experts), "moe_bias": f32(Le, cfg.n_experts),
+        "moe_w1": qw((Le, E), d, w), "moe_w2": qw((Le, E), w, d),
+        "moe_w3": qw((Le, E), d, w),
+        "shared_w1": qw((Le,), d, w), "shared_w2": qw((Le,), w, d),
+        "shared_w3": qw((Le,), d, w),
+        "rms_att": f32(L, d), "rms_ffn": f32(L, d),
+    }
+    for windowed, n in ((False, L - Lw), (True, Lw)):
+        sfx, ad = cfg.attn_suffix(windowed), cfg.attn_dim_of(windowed)
+        layers.update({
+            "wq" + sfx: qw((n,), d, ad), "wk" + sfx: qw((n,), d, cfg.kv_dim),
+            "wv" + sfx: qw((n,), d, cfg.kv_dim), "wo" + sfx: qw((n,), ad, d),
+            "q_norm" + sfx: f32(n, cfg.head_size),
+            "k_norm" + sfx: f32(n, cfg.head_size),
+            "attn_gate" + sfx: f32(n, d, cfg.heads_of(windowed))})
+    return {"embedding": A((cfg.vocab_size, d), jnp.bfloat16),
+            "final_norm": f32(d), "wcls": qw((), d, cfg.vocab_size),
+            "layers": layers}
+
+
+def attn_kinds_cases(topo, slots: int = ATTN_KINDS_SLOTS,
+                     pages: int = ATTN_KINDS_PAGES, n_layers: int = 40):
+    """The step programs of `serve --slots 24 --kv-pages 816
+    --max-prefill-chunk 256` on the model whose attention goes by layer kind,
+    at its published widths and depth: decode chunk and the hybrid step (the
+    paged sweep at folds 6 and 8 in one program, the window pool, the
+    grouped kernel over the held experts at width 512). Kept out of
+    all_cases(): the engine allocates the window pool on the host."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = attn_kinds_cfg(n_layers)
+    params = attn_kinds_params(cfg, lambda shape, dt: S(shape, dt, sharding=one))
+    return engine_programs(topo, f"serve attn-kinds {slots}-slot", cfg, params,
+                           slots, 0, kv_pages=pages, seq=ATTN_KINDS_SEQ,
+                           hybrid_p=(ATTN_KINDS_SLICE,),
+                           prefill_chunk=ATTN_KINDS_SLICE)
+
+
 def all_cases(topo, full: bool = False):
     """Every case as (name, thunk, production): thunk() compiles for the
     described chip and raises what the chip's compiler would raise."""
@@ -789,7 +870,8 @@ def main():
     topo = topology()
     for cname, thunk, production in (all_cases(topo, full) + hybrid_cases(topo)
                                      + window_moe_cases(topo)
-                                     + delta_latent_cases(topo)):
+                                     + delta_latent_cases(topo)
+                                     + attn_kinds_cases(topo)):
         t0 = time.time()
         try:
             compiled = thunk()

@@ -301,3 +301,58 @@ def test_delta_latent_step_program_compiles_with_its_kernels_named(
     layer_state = 12 * 32 * 128 * 128 * 4
     assert not pool_copies.big_movers(text, layer_state)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# ---------------- attention by layer kind over one chip's share of experts
+
+
+@pytest.fixture(scope="module")
+def attn_kinds_thunks(thunks):
+    """The step programs of the model whose attention goes by layer kind at
+    its published widths (aot_check.attn_kinds_cases: 2,048 stream, 48 global
+    / 64 windowed query heads over 8 kv heads of 128, a 512-row window, 64
+    held of 256 experts of width 512), at 12 of its 40 layers (the same
+    prefix and the same two period bodies, two periods instead of nine) and
+    8 slots over 280 pages so the engine built on the host holds a 0.2 GB
+    window pool. Depends on `thunks` for the platform steer and the cache
+    settings."""
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(aot_check.TARGET, platform="tpu")
+    return {name.split("-slot ")[1]: thunk for name, thunk, _ in
+            aot_check.attn_kinds_cases(topo, slots=8, pages=280, n_layers=12)}
+
+
+def test_attn_kinds_decode_program_compiles_at_two_folds(attn_kinds_thunks):
+    """The decode program compiles for v5e with the paged sweep at BOTH
+    folds in one program (`_paged_folded` at 48 / 8 = 6 query rows a kv
+    head, padded to 8; `_paged_window` at 64 / 8 = 8, a window of four
+    pages) over a pool a kind, the grouped expert kernel at width 512, and
+    each custom call's line parses as its cost file reads it; no instruction
+    moves a pool's layer."""
+    import re
+
+    from benchmark.costs import moe_experts, paged_attention
+    from experiments import pool_copies
+
+    compiled = attn_kinds_thunks["paged decode chunk n=4"]()
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    groups = {m.group(1) for l in calls
+              for m in [re.search(r"%(_[a-z_]+?)(?:\.\d+)? = ", l)] if m}
+    # (at 8 slots the projections are the block-dot tier's)
+    assert {"_paged_folded", "_paged_window", "_expert_call", "_blockdot_call"} <= groups, groups
+    count = lambda g: sum(f"%{g}" in l for l in calls)
+    # a global layer in the prefix and one in the period; the windowed
+    # layers' run in the prefix and in the period
+    assert (count("_paged_folded"), count("_paged_window")) == (2, 2)
+    for line in calls:
+        if "%_paged_" in line:
+            assert paged_attention.shape({"hlo": line}) == (8, 8, "bf16")
+            assert re.search(r"= \(f32\[8,8,8,128\]", line)  # folds 6 (padded) and 8
+        if "%_expert_call" in line:
+            assert moe_experts.shape({"hlo": line}) in ((64, 2048, 512),
+                                                        (64, 512, 2048))
+    window_layer = 8 * 7 * 8 * 128 * 128 * 2  # a layer's slice of the window pool
+    assert not pool_copies.big_movers(text, window_layer)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
