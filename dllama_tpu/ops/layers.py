@@ -186,15 +186,19 @@ def expert_tile_rows(rows: int, e: int) -> int:
 
 
 def expert_groups(topi: jax.Array, e: int, tm: int):
-    """The layout the grouped expert kernel reads, from the router's choices
-    (argsort, cumulative sums and gathers; no scatter). `topi` [N, k] expert
-    ids. The N*k (token, choice) rows are put in expert order and each
-    expert's group is padded to whole tiles of `tm` rows; T = min(E, N*k) +
-    N*k // tm tiles always hold them. Returns
+    """The layout the grouped expert kernel reads, from the router's choices,
+    by COUNTING over the N*k (token, choice) rows: one [N*k, E] one-hot
+    compare gives an expert's rows (its column sums), a row's rank in its
+    group (the rows above it in its own column, `_rank_in_group`: token
+    order is kept), and through the experts' cumulative tile counts where
+    the row stands. No sort, no scatter, no loop and no table looked up over the
+    padded order. `topi` [N, k] expert ids; an id outside [0, E) (a share's
+    sentinel) is a row of zeros in the one-hot and stands nowhere. The rows
+    are put in expert order and each expert's group is padded to whole tiles
+    of `tm` rows; T = min(E, N*k) + N*k // tm tiles always hold them. Returns
 
-      src   i32[T*tm]  the token whose row stands at each padded position
-                       (pad rows repeat a real token: finite, never read back)
-      pos   i32[N, k]  where (token, choice) stands in the padded order
+      pos   i32[N, k]  where (token, choice) stands in the padded order (0
+                       for a choice that stands nowhere)
       tile_expert i32[T], tile_src i32[T]  for the kernel's index maps: the
                        expert a tile reads and the tile's own index, both
                        frozen at the last live tile for the dead ones behind
@@ -204,30 +208,75 @@ def expert_groups(topi: jax.Array, e: int, tm: int):
     n, k = topi.shape
     r = n * k
     t = min(e, r) + r // tm
-    assign = topi.reshape(-1).astype(jnp.int32)
-    order = jnp.argsort(assign)  # stable: a group stays in token order
-    sizes = jnp.bincount(assign, length=e).astype(jnp.int32)
+    experts = jnp.arange(e, dtype=jnp.int32)
+    hot = topi.reshape(r, 1).astype(jnp.int32) == experts  # [r, E]
+    sizes = jnp.sum(hot.astype(jnp.int32), axis=0)
     tiles = (sizes + tm - 1) // tm
-    tile_end = jnp.cumsum(tiles)
-    tile_start = tile_end - tiles
-    group_start = jnp.cumsum(sizes) - sizes
+    # (E is small: a cumulative sum over it is a masked [E, E] sum)
+    tile_end = jnp.sum(jnp.where(experts[:, None] <= experts, tiles[:, None], 0), axis=0)
+    first_row = (tile_end - tiles) * tm  # where an expert's group starts
+    pos = jnp.sum(jnp.where(hot, first_row + _rank_in_group(hot), 0), axis=1)
     n_live = tile_end[-1]
-    last = jnp.maximum(n_live - 1, 0)
-    tile_ids = jnp.minimum(jnp.arange(t, dtype=jnp.int32), last)
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(tile_end, tile_ids, side="right"), e - 1).astype(jnp.int32)
-    # padded position p = tile * tm + lane holds row `rank` of its expert
-    p_tile = jnp.repeat(tile_ids, tm)
-    p_exp = jnp.repeat(tile_expert, tm)
-    lane = jnp.tile(jnp.arange(tm, dtype=jnp.int32), t)
-    rank = (p_tile - tile_start[p_exp]) * tm + lane
-    sorted_ix = jnp.where(rank < sizes[p_exp], group_start[p_exp] + rank, 0)
-    src = order[jnp.clip(sorted_ix, 0, r - 1)] // k
-    # (token, choice) a -> sorted index inv[a] -> padded position
-    rank_in_group = jnp.argsort(order) - group_start[assign]
-    pos = (tile_start[assign] * tm + rank_in_group).reshape(n, k)
-    return (src.astype(jnp.int32), pos.astype(jnp.int32), tile_expert,
-            tile_ids, n_live.astype(jnp.int32), sizes)
+    tile_ids = jnp.minimum(jnp.arange(t, dtype=jnp.int32), jnp.maximum(n_live - 1, 0))
+    # the expert of tile t: how many experts' tiles end at or before it (the
+    # last expert's never do, for a live tile)
+    tile_expert = jnp.sum((tile_end[:-1] <= tile_ids[:, None]).astype(jnp.int32), axis=1)
+    return pos.reshape(n, k), tile_expert, tile_ids, n_live, sizes
+
+
+#: rows a block of `_rank_in_group`'s triangular dot holds (a block's column
+#: sums, at most this, are whole numbers bfloat16 holds exactly)
+_RANK_BLOCK = 256
+
+
+def _rank_in_group(hot: jax.Array) -> jax.Array:
+    """i32[r, E]: for each row of the [r, E] one-hot, the rows above it in
+    each column (the exclusive cumulative sum down the columns), through the
+    MXU: a strictly lower triangular 0/1 matrix against the one-hot, past
+    512 rows in blocks of `_RANK_BLOCK`, the blocks above a block counted by
+    a second such dot. Exact: 0/1 and counts up to 256 in bfloat16, float32
+    sums. (A `cumsum` is a `reduce-window` to XLA: 3 us at 192 rows and 100
+    at 3,072 where this reads under 1 and 5; my chip run, PR 43.)"""
+    r, e = hot.shape
+    blk = r if r <= 2 * _RANK_BLOCK else _RANK_BLOCK  # (one block: one dot)
+    below = lambda m: jnp.tril(jnp.ones((m, m), jnp.bfloat16), -1)
+    c = jnp.pad(hot.astype(jnp.bfloat16), ((0, -r % blk), (0, 0))).reshape(-1, blk, e)
+    before = jnp.einsum("ij,bje->bie", below(blk), c, preferred_element_type=jnp.float32)
+    if c.shape[0] > 1:
+        sums = jnp.sum(c.astype(jnp.float32), axis=1).astype(jnp.bfloat16)
+        before = before + jnp.dot(below(c.shape[0]), sums,
+                                  preferred_element_type=jnp.float32)[:, None]
+    return before.reshape(-1, e)[:r].astype(jnp.int32)
+
+
+def expert_rows(h: jax.Array, pos: jax.Array, held: jax.Array | None, rows: int,
+                by_dot: bool) -> jax.Array:
+    """h [N, D] laid out in the padded order of `expert_groups`: [rows, D]
+    with token i's row at each of pos[i, :] (`held` bool[N, k]: the choices
+    that stand somewhere). Every padded position is compared with the N*k
+    real ones (positions along the lanes), never looked up in a table over
+    the padded order. `by_dot`: the 0/1 matrix [N, rows] places the rows
+    through the MXU (exact: one 1 a position; a pad row is zero); else the
+    matrix gives each position its token and the rows are gathered (a pad
+    row repeats token 0, never read back). Either way a position holds its
+    own token's row and nothing of another's: the dot sums over all N
+    tokens, and 0 x NaN is NaN, so a token whose row is not finite goes in
+    as a row of zeros there (`moe_ffn` gives it back as NaN)."""
+    n, k = pos.shape
+    at = pos if held is None else jnp.where(held, pos, -1)
+    # [N, rows], the padded positions along the lanes: does token i stand at p
+    place = jnp.any(at[:, :, None] == jnp.arange(rows, dtype=jnp.int32), axis=1)
+    if by_dot:
+        return jnp.einsum("np,nd->pd", place.astype(h.dtype),
+                          jnp.where(finite_rows(h)[:, None], h, 0),
+                          preferred_element_type=h.dtype)
+    token = jnp.arange(n, dtype=jnp.int32)[:, None]
+    return h[jnp.sum(jnp.where(place, token, 0), axis=0)]
+
+
+def finite_rows(h: jax.Array) -> jax.Array:
+    """bool[N]: the rows of h [N, D] that hold no NaN and no infinity."""
+    return jnp.all(jnp.isfinite(h), axis=-1)
 
 
 def moe_ffn(
@@ -269,11 +318,15 @@ def moe_ffn(
     Compute schemes:
     * ``grouped`` (what `auto` resolves to where the Q40 Pallas kernels
       serve, engine/kernel_select.resolve_moe_impl): the rows are put in
-      expert order (`expert_groups`) and ONE grouped Q40 kernel a projection
+      expert order by counting (`expert_groups`: a one-hot compare, sums and
+      small dots; no sort, scatter or lookup over the padded order), laid
+      out for the kernel (`expert_rows`), and ONE grouped Q40 kernel a projection
       (ops/pallas/q40_matmul.q40_expert_matmul) reads each touched expert's
       packed tile and scales from the stacked [L, E, ...] weights by
       scalar-prefetched (layer, expert, tile); experts with no row move no
       bytes and no dequantised copy of an expert is ever written to HBM.
+      The way back is a gather of the N*k real rows of the result (a tile no
+      row reached is never written: a select, never a product, drops it).
     * ``sort`` (the jnp default for T*B >= E): MegaBlocks-style grouped GEMM
       — sort the N*k (token, choice) rows by expert id (argsort + gathers, no
       scatters) and run ragged segment matmuls (``lax.ragged_dot``) over the
@@ -346,9 +399,18 @@ def moe_ffn(
 
         # (of a share's routed rows, the held experts' part is expected here)
         tm = expert_tile_rows(n * k if mine is None else n * k * e // cfg.n_experts, e)
-        src, pos, tile_expert, tile_src, n_live, sizes = expert_groups(
+        pos, tile_expert, tile_src, n_live, sizes = expert_groups(
             topi.reshape(n, k), e, tm)
-        xs = h.reshape(n, d)[src]  # [T*tm, D] rows in padded expert order
+        held = None if mine is None else mine.reshape(n, k)
+        # a decode step's rows (one a sequence) are placed by the dot; a
+        # slice's are gathered: the dot is no slower there (392 / 416 us a
+        # layer-step at 256 rows), but the v5e compiler's memory space
+        # assignment aborts on a program that holds BOTH a decode step's
+        # and a slice's placement dot (every hybrid program of 2-256 slice
+        # rows at Laguna's widths; my chip runs, PR 43; the cause is not
+        # known: `experiments/warm_compile.py` is the check)
+        by_dot = t == 1
+        xs = expert_rows(h.reshape(n, d), pos, held, len(tile_src) * tm, by_dot)
         mm = functools.partial(
             q40_expert_matmul, layer=layer, tile_expert=tile_expert,
             tile_src=tile_src, n_live=n_live, tm=tm,
@@ -357,14 +419,17 @@ def moe_ffn(
         up = mm(xs, w3)
         act = (activation(g, cfg.hidden_act) * up).astype(h.dtype)
         y = mm(act, w2)  # f32 [T*tm, D]
-        if mine is None:
-            out = jnp.sum(y[pos] * probs.reshape(n, k)[..., None], axis=1)
-        else:
+        yk = y[pos]
+        if held is not None:
             # a choice this chip does not hold stands nowhere in the padded
             # order: a select, since a tile no row reached is never written
-            held = mine.reshape(n, k)
-            yk = jnp.where(held[..., None], y[jnp.where(held, pos, 0)], 0.0)
-            out = jnp.sum(yk * probs.reshape(n, k)[..., None], axis=1)
+            yk = jnp.where(held[..., None], yk, 0.0)
+        out = jnp.sum(yk * probs.reshape(n, k)[..., None], axis=1)
+        if by_dot:
+            # a sequence whose row went in as zeros (`expert_rows`) comes
+            # out as one that met its experts would: not finite, and alone
+            # in that (the engine's guard fails that request and no other)
+            out = jnp.where(finite_rows(h.reshape(n, d))[:, None], out, jnp.nan)
         return done(out, sizes)
 
     if impl == "sort":

@@ -5,6 +5,7 @@ Usage: python experiments/kbench.py suite
        python experiments/kbench.py q40 [--no-tiles]
        python experiments/kbench.py deq [--no-tiles] [--parent]
        python experiments/kbench.py expert [--no-tiles]
+       python experiments/kbench.py moe_layer [CELL ...] [--no-profile | --aot]
        python experiments/kbench.py M SHAPE [variant ...]
 'suite' benches the decode variants (m=8 on w1/wcls) and the prefill tier
 comparison (m=256/512: in-kernel deq vs XLA dequant-dot) in one process.
@@ -26,7 +27,16 @@ Kimi-Linear's held share of a decode step): parity against dequantise-then-dot
 of each tile's expert, the kernel as it is and with each part taken out, every
 tile live against the cell's dead ones, the (tn, lanes) sweep (--no-tiles
 leaves it out), other tile heights for the slice.
-'suite --smoke' (and 'paged --smoke', 'q40 --smoke', 'deq --smoke', 'expert --smoke') runs the
+'moe_layer' times ONE whole grouped expert layer-step (`ops/layers.moe_ffn`:
+router logits in, [N, D] out) at the three expert cells' decode and slice
+shapes: PR 42's route (its `expert_groups` and `h[src]`, kept here as the
+yardstick) against today's, the three kernel calls alone (so: what the XLA
+ops around them cost), and each route's device ops from a profile of the
+same scan (--no-profile leaves it out). 'moe_layer --aot' needs no chip: it compiles the
+layer-step for `v5e:2x2` and lists the entry computation's scheduled ops by
+kind and result shape, with the seconds tracing and lowering took.
+'suite --smoke' (and 'paged --smoke', 'q40 --smoke', 'deq --smoke', 'expert --smoke',
+'moe_layer --smoke') runs the
 same code path on CPU (interpret-mode Pallas, tiny shapes, 2 iters) so CI proves the harness cannot crash on the chip; smoke
 numbers are meaningless, only completion matters.
   variants: A  production dispatch (q40_matmul: blockdot for m<=16, deq above)
@@ -280,6 +290,13 @@ def enable_smoke():
                      fills={"decode": (3, (16,)), "slice": (40, (64, 32))}),
         "tiny share": dict(held=4, routed=8, active=3, layers=2, shapes=((256, 256),),
                            fills={"decode": (6, (16,))})}
+    global MOE_LAYER_CELLS, MOE_LAYER_CALLS
+    MOE_LAYER_CALLS = 2
+    MOE_LAYER_CELLS = {
+        "tiny": dict(held=4, routed=4, active=2, d=256, f=256, sigmoid=False, scale=1.0,
+                     act="relu", layers=2, rows={"decode": 3, "slice": 160}),
+        "tiny share": dict(held=4, routed=8, active=3, d=256, f=256, sigmoid=True, scale=2.5,
+                           act="silu", layers=2, rows={"decode": 6})}
     PAGED_CALLS = 2
     PAGED_CELLS = {
         "tiny mha": dict(slots=2, hq=4, hkv=4, hd=64, page=16, layers=2,
@@ -510,6 +527,17 @@ def q40_inputs(m, k, n, layers, seed=0):
     return x, packed, scales
 
 
+def _timed(fn, args, calls):
+    """us a call: the best of two timed runs after a warm one."""
+    jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(2):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        best = min(best, time.perf_counter() - t0)
+    return best / calls * 1e6
+
+
 def q40_loop_us(call, x, packed, scales, calls=None):
     """us a call of `call(layer[1], x, packed, scales) -> f32[m, n]` over
     `calls` calls in ONE jitted scan, the layer cycling as the layer scan
@@ -528,13 +556,7 @@ def q40_loop_us(call, x, packed, scales, calls=None):
         return jax.lax.scan(step, jnp.float32(0),
                             jnp.arange(calls, dtype=jnp.int32))[0]
 
-    jax.block_until_ready(loop(x, packed, scales))
-    best = float("inf")
-    for _ in range(2):
-        t0 = time.perf_counter()
-        jax.block_until_ready(loop(x, packed, scales))
-        best = min(best, time.perf_counter() - t0)
-    return best / calls * 1e6
+    return _timed(loop, (x, packed, scales), calls)
 
 
 def q40_floor_us(m, k, n):
@@ -907,9 +929,9 @@ def expert_inputs(cell, k, n, tokens, tm, seed=0):
     topi = np.stack([np.stack([rng.permutation(routed)[:active] for _ in range(tokens)])
                      for _ in range(layers)])
     topi = jnp.asarray(np.where(topi < held, topi, held), jnp.int32)
-    src, _, tile_expert, tile_src, n_live, sizes = jax.vmap(
+    _, tile_expert, tile_src, n_live, sizes = jax.vmap(
         lambda t: expert_groups(t, held, tm))(topi)
-    x = jax.random.normal(kx, (src.shape[1], k), jnp.float32).astype(jnp.bfloat16)
+    x = jax.random.normal(kx, (tile_src.shape[1] * tm, k), jnp.float32).astype(jnp.bfloat16)
     touched = float(jnp.count_nonzero(sizes, axis=1).mean())
     rows = float(sizes.sum(axis=1).mean())
     return x, packed, scales, tile_expert, tile_src, n_live, touched, rows
@@ -935,13 +957,7 @@ def expert_loop_us(call, data, calls=None, live=None):
             return acc + out[0, 0], None
         return jax.lax.scan(step, jnp.float32(0), jnp.arange(calls, dtype=jnp.int32))[0]
 
-    jax.block_until_ready(loop(x, packed, scales))
-    best = float("inf")
-    for _ in range(2):
-        t0 = time.perf_counter()
-        jax.block_until_ready(loop(x, packed, scales))
-        best = min(best, time.perf_counter() - t0)
-    return best / calls * 1e6
+    return _timed(loop, (x, packed, scales), calls)
 
 
 def expert_parity(call, data, tm):
@@ -1061,6 +1077,362 @@ def bench_expert(cells=None, tiles=True):
                     del data
 
 
+# ------------------------------------------------------------ moe_layer mode
+#: ONE grouped expert layer-step (`ops/layers.moe_ffn`, router logits in, [N, D]
+#: out) of the three expert cells (PERF.md section 4): experts held and routed
+#: over, choices a token, the hidden and the expert width, the router's form,
+#: and the rows of a decode step and of a prefill slice. `layers` stacks are
+#: cycled through (8 here: what a call costs does not depend on how many).
+MOE_LAYER_CELLS = {
+    "lagunaxs2.reason_long_closed": dict(
+        held=64, routed=256, active=8, d=2048, f=512, sigmoid=True, scale=2.5,
+        act="silu", layers=8, rows={"decode": 24, "slice": 256}),
+    "kimilinear.reason_closed": dict(
+        held=64, routed=256, active=8, d=2304, f=1024, sigmoid=True, scale=2.446,
+        act="silu", layers=8, rows={"decode": 48, "slice": 64}),
+    "smallthinker.long_decode_closed": dict(
+        held=64, routed=64, active=6, d=2560, f=768, sigmoid=False, scale=1.0,
+        act="relu", layers=8, rows={"decode": 16, "slice": 512}),
+}
+MOE_LAYER_CALLS = 240
+MOE_LAYER_TOP = 28  # rows of the profile's op table
+
+
+def _pr42_expert_groups(topi, e, tm):
+    """PR 42's `ops/layers.expert_groups`, kept as the yardstick: two
+    argsorts, a bincount's scatter, `searchsorted`, and the lookups over the
+    padded order. Returns (src, pos, tile_expert, tile_src, n_live, sizes)."""
+    n, k = topi.shape
+    r = n * k
+    t = min(e, r) + r // tm
+    assign = topi.reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(assign)  # stable: a group stays in token order
+    sizes = jnp.bincount(assign, length=e).astype(jnp.int32)
+    tiles = (sizes + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles)
+    tile_start = tile_end - tiles
+    group_start = jnp.cumsum(sizes) - sizes
+    n_live = tile_end[-1]
+    last = jnp.maximum(n_live - 1, 0)
+    tile_ids = jnp.minimum(jnp.arange(t, dtype=jnp.int32), last)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, tile_ids, side="right"), e - 1).astype(jnp.int32)
+    p_tile = jnp.repeat(tile_ids, tm)
+    p_exp = jnp.repeat(tile_expert, tm)
+    lane = jnp.tile(jnp.arange(tm, dtype=jnp.int32), t)
+    rank = (p_tile - tile_start[p_exp]) * tm + lane
+    sorted_ix = jnp.where(rank < sizes[p_exp], group_start[p_exp] + rank, 0)
+    src = order[jnp.clip(sorted_ix, 0, r - 1)] // k
+    rank_in_group = jnp.argsort(order) - group_start[assign]
+    pos = (tile_start[assign] * tm + rank_in_group).reshape(n, k)
+    return (src.astype(jnp.int32), pos.astype(jnp.int32), tile_expert,
+            tile_ids, n_live.astype(jnp.int32), sizes)
+
+
+def _route(cfg, logits, bias):
+    """`moe_ffn`'s routing, to the letter: (topi with a share's sentinel,
+    probs, mine or None, e)."""
+    k, e = cfg.n_active_experts, cfg.n_experts
+    if cfg.router_sigmoid:
+        score = jax.nn.sigmoid(logits.astype(jnp.float32))
+        _, topi = jax.lax.top_k(score + bias.astype(jnp.float32), k)
+        topv = jnp.take_along_axis(score, topi, axis=-1)
+        probs = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+    else:
+        topv, topi = jax.lax.top_k(logits.astype(jnp.float32), k)
+        probs = jax.nn.softmax(topv, axis=-1)
+    if cfg.routed_scale != 1.0:
+        probs = probs * cfg.routed_scale
+    mine = None
+    if cfg.experts_held:
+        e = cfg.experts_held
+        local = topi - cfg.expert_offset
+        mine = (local >= 0) & (local < e)
+        topi = jnp.where(mine, local, e)
+        probs = jnp.where(mine, probs, 0.0)
+    return topi, probs, mine, e
+
+
+def _pr42_grouped(cfg, h, logits, w1, w2, w3, layer, bias, stats):
+    """PR 42's grouped route of `moe_ffn` whole (the yardstick): its
+    `expert_groups`, `h[src]`, the three calls, `y[pos]` and the combine."""
+    from dllama_tpu.ops.layers import activation, expert_tile_rows
+    from dllama_tpu.ops.pallas.q40_matmul import q40_expert_matmul
+
+    b, t, d = h.shape
+    n, k = b * t, cfg.n_active_experts
+    topi, probs, mine, e = _route(cfg, logits, bias)
+    tm = expert_tile_rows(n * k if mine is None else n * k * e // cfg.n_experts, e)
+    src, pos, tile_expert, tile_src, n_live, sizes = _pr42_expert_groups(
+        topi.reshape(n, k), e, tm)
+    xs = h.reshape(n, d)[src]
+    mm = functools.partial(q40_expert_matmul, layer=layer, tile_expert=tile_expert,
+                           tile_src=tile_src, n_live=n_live, tm=tm, interpret=INTERPRET)
+    g = mm(xs, w1)
+    up = mm(xs, w3)
+    act = (activation(g, cfg.hidden_act) * up).astype(h.dtype)
+    y = mm(act, w2)
+    if mine is None:
+        out = jnp.sum(y[pos] * probs.reshape(n, k)[..., None], axis=1)
+    else:
+        held = mine.reshape(n, k)
+        yk = jnp.where(held[..., None], y[jnp.where(held, pos, 0)], 0.0)
+        out = jnp.sum(yk * probs.reshape(n, k)[..., None], axis=1)
+    rows = jnp.asarray(n * k) if mine is None else jnp.count_nonzero(mine)
+    stats = stats + jnp.stack(
+        [rows, jnp.count_nonzero(sizes), jnp.asarray(1), sizes.max()]
+        + ([] if mine is None else [jnp.asarray(n * k)])).astype(stats.dtype)
+    return out.reshape(b, t, d).astype(h.dtype), stats
+
+
+def _as_rows(logits, h):
+    """A layer's logits [tokens, routed] as [B, T, routed] beside h [B, T, D]."""
+    return logits.reshape(*h.shape[:2], -1)
+
+
+def _now_grouped(cfg, h, logits, w1, w2, w3, layer, bias, stats):
+    from dllama_tpu.ops.layers import moe_ffn
+
+    return moe_ffn(cfg, h, None, w1, w2, w3, impl="grouped", logits=logits,
+                   layer=layer, stats=stats, bias=bias)
+
+
+MOE_LAYER_FORMS = {"PR 42's route": _pr42_grouped, "as it is": _now_grouped}
+
+
+def moe_layer_cfg(c):
+    from dllama_tpu.models.config import HiddenAct, LlamaConfig
+
+    share = c["held"] != c["routed"]
+    return LlamaConfig(
+        dim=c["d"], hidden_dim=c["f"], n_layers=c["layers"], n_heads=2, n_kv_heads=1,
+        vocab_size=64, seq_len=32, n_experts=c["routed"], n_active_experts=c["active"],
+        hidden_act=HiddenAct.RELU if c["act"] == "relu" else HiddenAct.SILU,
+        router_sigmoid=c["sigmoid"], routed_scale=c["scale"],
+        experts_held=c["held"] if share else 0,
+        expert_offset=c["held"] if share else 0)
+
+
+def moe_layer_shapes(c, tokens, fill):
+    """The layer-step's arguments as shapes: (h, logits [layers], bias,
+    w1, w2, w3 as QTensors of shapes, stats). A decode step's rows are one a
+    sequence ([tokens, 1, D]), a slice's one sequence's ([1, tokens, D]); a
+    layer's logits are kept [tokens, routed] and given h's leading shape
+    where they are used (`_as_rows`), as the router's product leaves them in
+    the serving program: a value whose layout the compiler is free to choose.
+    (As an ARGUMENT of shape [tokens, 1, routed] they are tiled with the 1 in
+    the sublanes, and everything from the sigmoid to `top_k` costs 70-140 us
+    a layer-step more, on either route; my chip run, PR 43.)"""
+    S = jax.ShapeDtypeStruct
+    L, E, d, f = c["layers"], c["held"], c["d"], c["f"]
+    qw = lambda k, n: QTensor(S((L, E, k // 2, n), jnp.uint8),
+                              S((L, E, k // Q_BLOCK, n), jnp.float16))
+    bt = (tokens, 1) if fill == "decode" else (1, tokens)
+    return (S((*bt, d), jnp.bfloat16), S((L, tokens, c["routed"]), jnp.float32),
+            S((c["routed"],), jnp.float32), qw(d, f), qw(f, d), qw(d, f),
+            S((5 if E != c["routed"] else 4,), jnp.uint32))
+
+
+def moe_layer_inputs(c, tokens, fill, seed=0):
+    """Those arguments made ON the device: random nibbles and f16 scales,
+    normal rows, and router logits whose top choices are uniform over the
+    routed experts (a layer's own: the routing cycles with the layer)."""
+    shapes = moe_layer_shapes(c, tokens, fill)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+
+    def stack(kp, ks, w):
+        L, E, kh, n = w.packed.shape
+        packed = jax.lax.bitcast_convert_type(
+            jax.random.bits(kp, (L, E, kh, n // 4), jnp.uint32), jnp.uint8).reshape(L, E, kh, n)
+        scales = jax.random.uniform(ks, w.scales.shape, jnp.float32, 1e-3, 2e-2
+                                    ).astype(jnp.float16)
+        return QTensor(packed, scales)
+
+    h = jax.random.normal(keys[0], shapes[0].shape, jnp.float32).astype(jnp.bfloat16)
+    logits = jax.random.normal(keys[1], shapes[1].shape, jnp.float32)
+    return (h, logits, jnp.zeros(shapes[2].shape, jnp.float32),
+            stack(keys[2], keys[3], shapes[3]), stack(keys[4], keys[5], shapes[4]),
+            stack(keys[6], keys[7], shapes[5]), jnp.zeros(shapes[6].shape, jnp.uint32))
+
+
+def _moe_layer_loop(cfg, form, calls):
+    """`calls` layer-steps in ONE jitted scan, the layer and its routing
+    cycling, a step's rows the step before's result (as layers follow one
+    another; squashed so that they stay finite)."""
+    def loop(h, logits, bias, w1, w2, w3, stats):
+        layers = logits.shape[0]
+
+        def step(carry, i):
+            h, stats = carry
+            li = i % layers
+            out, stats = form(cfg, h, _as_rows(logits[li], h), w1, w2, w3, li, bias, stats)
+            return (jnp.tanh(out.astype(jnp.float32)).astype(h.dtype), stats), None
+        return jax.lax.scan(step, (h, stats), jnp.arange(calls, dtype=jnp.int32))[0]
+    return jax.jit(loop)
+
+
+def _moe_kernels_loop(cfg, tm, calls):
+    """The layer-step's three `_expert_call`s ALONE over the same routings
+    (their layouts made before the scan): what `expert` mode times, a layer's
+    three projections at once."""
+    from dllama_tpu.ops.layers import expert_groups
+    from dllama_tpu.ops.pallas.q40_matmul import q40_expert_matmul
+
+    def loop(h, logits, bias, w1, w2, w3, stats):
+        layers, n = logits.shape[0], h.shape[0] * h.shape[1]
+        e = cfg.experts_held or cfg.n_experts
+        topi = jax.vmap(lambda lg: _route(cfg, lg, bias)[0].reshape(n, -1))(logits)
+        _, tile_expert, tile_src, n_live, _ = jax.vmap(
+            lambda t: expert_groups(t, e, tm))(topi)
+        rows = tile_src.shape[1] * tm
+        xs = jnp.tile(h.reshape(n, -1), (-(-rows // n), 1))[:rows]
+        f = w2.shape[-2]
+        act = jnp.tile(xs, (1, -(-f // xs.shape[1])))[:, :f]
+
+        def step(acc, i):
+            li = i % layers
+            mm = functools.partial(
+                q40_expert_matmul, layer=li, tile_expert=tile_expert[li],
+                tile_src=tile_src[li], n_live=n_live[li], tm=tm, interpret=INTERPRET)
+            return acc + mm(xs, w1)[0, 0] + mm(xs, w3)[0, 0] + mm(act, w2)[0, 0], None
+        return jax.lax.scan(step, jnp.float32(0), jnp.arange(calls, dtype=jnp.int32))[0]
+    return jax.jit(loop)
+
+
+def _entry_ops(hlo_text):
+    """[(kind, result shape, name)] of the scheduled instructions of a
+    compiled module's ENTRY computation, parameters and scalar plumbing
+    (get-tuple-element, bitcast, constant, tuple) counted apart."""
+    import re
+
+    entry = hlo_text[hlo_text.index("ENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    ops, plumbing = [], {}
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|[\w\[\],{}:()#*\s]+?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, shape, kind = m.groups()
+        shape = re.sub(r"\{[^}]*\}", "", shape).strip()
+        if kind in ("parameter", "get-tuple-element", "bitcast", "constant", "tuple"):
+            plumbing[kind] = plumbing.get(kind, 0) + 1
+            continue
+        if kind == "fusion":
+            meta = re.search(r'op_name="([^"]*)"', line)
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            kind = f"fusion {'<- ' + meta.group(1).split('/')[-1] if meta else ''}"
+            name = called.group(1) if called else name
+        elif kind == "custom-call":
+            kind = "custom-call " + (re.search(r'custom_call_target="([^"]*)"', line) or [0, ""])[1]
+        ops.append((kind.strip(), shape, name))
+    return ops, plumbing
+
+
+def moe_layer_aot(cells=None):
+    """No chip: each cell's layer-step, both forms, compiled for `v5e:2x2`
+    (one device of it) and the entry computation's scheduled ops listed by
+    kind and result shape; beside it the seconds tracing + lowering took here
+    (a warm program pays them again: set-up time)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from dllama_tpu.ops import matmul as mmod
+
+    mmod.device_platform = lambda: "tpu"  # the kernels compile, not interpret
+    one = SingleDeviceSharding(
+        topologies.get_topology_desc("v5e:2x2", platform="tpu").devices[0])
+    pin = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    for cell, c in (cells or MOE_LAYER_CELLS).items():
+        cfg = moe_layer_cfg(c)
+        for fill, tokens in c["rows"].items():
+            h, logits, bias, w1, w2, w3, stats = pin(moe_layer_shapes(c, tokens, fill))
+            logits = jax.ShapeDtypeStruct(logits.shape[1:], logits.dtype, sharding=one)
+            li = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+            for tag, form in MOE_LAYER_FORMS.items():
+                fn = lambda h, lg, b, w1, w2, w3, li, st: form(
+                    cfg, h, _as_rows(lg, h), w1, w2, w3, li, b, st)
+                t0 = time.perf_counter()
+                lowered = jax.jit(fn).trace(h, logits, bias, w1, w2, w3, li, stats).lower()
+                t_lower = time.perf_counter() - t0
+                ops, plumbing = _entry_ops(lowered.compile().as_text())
+                around = [o for o in ops if "_expert_call" not in o[2] and "tpu_custom_call" not in o[0]]
+                by_kind = {}
+                for kind, shape, _ in around:
+                    by_kind.setdefault(kind.split(" ")[0], []).append(shape)
+                print(f"moe_layer aot {cell} {fill} ({tokens} rows) {tag}: {len(around)} ops "
+                      f"around {len(ops) - len(around)} kernel calls; "
+                      + ", ".join(f"{len(v)} {k}" for k, v in sorted(by_kind.items()))
+                      + f"; plumbing {plumbing}; traced + lowered in {t_lower:.2f} s")
+                for kind, shape, name in around:
+                    print(f"    {kind:<46} {shape:<34} {name}")
+                sys.stdout.flush()
+
+
+def _profile_ops(fn, args, calls, tag):
+    """One profiled run of a scan: the device's ops by self time, us a call,
+    with each op's result shape (from the HLO text the trace carries)."""
+    import glob
+    import tempfile
+
+    from benchmark.trace_reduce import reduce_file
+
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            jax.block_until_ready(fn(*args))
+        found = glob.glob(os.path.join(d, "plugins", "profile", "*", "*.xplane.pb"))
+        if not found:
+            print(f"moe_layer {tag}: no device trace here")
+            return
+        ops = reduce_file(found[0])["ops"]
+    total = sum(o["seconds"] for o in ops)
+    print(f"moe_layer {tag}: device ops by self time, us a layer-step "
+          f"({total / calls * 1e6:.1f} in all, {len(ops)} ops)")
+    for o in ops[:MOE_LAYER_TOP]:
+        shape = o["hlo"].split(" = ", 1)[-1].split(" ", 1)[0][:40]
+        print(f"    {o['seconds'] / calls * 1e6:8.2f}  x{o['count'] // max(calls, 1) or 1:<3} "
+              f"{o['name']:<40} {shape}")
+    sys.stdout.flush()
+
+
+def bench_moe_layer(cells=None, profile=True):
+    """ONE whole grouped layer-step on the chip at the expert cells' decode
+    and slice shapes: PR 42's route against today's, the three kernel calls
+    alone (so: what the XLA ops around them cost), and each form's device
+    ops from a profile of the same scan."""
+    from dllama_tpu.ops.layers import expert_tile_rows
+
+    calls = MOE_LAYER_CALLS
+    for cell, c in (cells or MOE_LAYER_CELLS).items():
+        cfg = moe_layer_cfg(c)
+        e, k = c["held"], c["active"]
+        for fill, tokens in c["rows"].items():
+            data = moe_layer_inputs(c, tokens, fill)
+            r = tokens * k
+            tm = expert_tile_rows(r * e // c["routed"], e)
+            tag = f"{cell} {fill} ({tokens} rows, r={r}, tm={tm}, T*tm={(min(e, r) + r // tm) * tm})"
+            try:
+                want = _moe_layer_loop(cfg, _pr42_grouped, 1)(*data)
+                got = _moe_layer_loop(cfg, _now_grouped, 1)(*data)
+                diff = float(jnp.abs(got[0].astype(jnp.float32) - want[0].astype(jnp.float32)).max())
+                print(f"moe_layer {tag}: today's route against PR 42's: largest difference "
+                      f"{diff:.2e} of tanh(out), counters {'equal' if (got[1] == want[1]).all() else 'DIFFER'}")
+                kernels = _timed(_moe_kernels_loop(cfg, tm, calls), data, calls)
+                print(f"moe_layer {tag} the three kernel calls alone: {kernels:.2f} us")
+                for name, form in MOE_LAYER_FORMS.items():
+                    loop = _moe_layer_loop(cfg, form, calls)
+                    us = _timed(loop, data, calls)
+                    print(f"moe_layer {tag} {name}: {us:.2f} us a layer-step, "
+                          f"{us - kernels:.2f} around the kernels")
+                    if profile:
+                        _profile_ops(loop, data, calls, f"{tag} {name}")
+            except Exception as ex:
+                print(f"moe_layer {tag}: FAILED {ex!r}"[:400])
+            sys.stdout.flush()
+            del data
+
+
 Q40_SWEEP_TK = (4096, 8192, None)  # None = the whole of k
 Q40_SWEEP_TN = (256, 512, 1024, 2048, -2, -1)  # -d = n / d
 Q40_SWEEP_LANES = (256, 512)
@@ -1074,6 +1446,7 @@ def main():
     # 'q40 [--smoke] [--no-tiles]' (the block-dot kernel at the cells' shapes) |
     # 'deq [--smoke] [--no-tiles] [--parent]' (the dequantising tier, m > 16) |
     # 'expert [--smoke] [--no-tiles]' (the grouped expert kernel at the cells' fills) |
+    # 'moe_layer [--smoke] [--no-profile | --aot]' (one whole grouped expert layer-step) |
     # M SHAPE [variant ...] — suite runs the whole decode + prefill matrix in
     # ONE process (one device init, not six). --no-flash skips the flash
     # section; the q40 rows still land.
@@ -1100,6 +1473,16 @@ def main():
         return
     if sys.argv[1:2] == ["expert"]:
         bench_expert(tiles="--no-tiles" not in sys.argv)
+        print("KBENCH DONE")
+        return
+    if sys.argv[1:2] == ["moe_layer"]:
+        # (cells by the start of their names, all of them if none is given)
+        want = tuple(a for a in sys.argv[2:] if not a.startswith("--"))
+        cells = {c: v for c, v in MOE_LAYER_CELLS.items() if c.startswith(want)} if want else None
+        if "--aot" in sys.argv:
+            moe_layer_aot(cells)
+        else:
+            bench_moe_layer(cells, profile="--no-profile" not in sys.argv)
         print("KBENCH DONE")
         return
     if sys.argv[1:2] == ["deq"]:

@@ -92,6 +92,32 @@ def test_kbench_expert_smoke():
     assert "slice tm=32 as it is" in p.stdout and "tiny share" in p.stdout
 
 
+def test_kbench_moe_layer_smoke():
+    """One whole grouped expert layer-step (`moe_ffn`, router logits in, [N,
+    D] out) at a tiny size: today's route against PR 42's (kept in the
+    experiment as the yardstick) to the bit with equal counters, and the
+    three kernel calls alone; a decode step, a slice and one chip's share."""
+    p = _run(["experiments/kbench.py", "moe_layer", "--smoke"])
+    assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
+    assert "KBENCH DONE" in p.stdout and "FAILED" not in p.stdout, p.stdout
+    assert p.stdout.count("largest difference 0.00e+00 of tanh(out), counters equal") == 3
+    for row in ("the three kernel calls alone", "PR 42's route:", "as it is:"):
+        assert p.stdout.count(row) >= 3, (row, p.stdout)
+    assert "tiny slice" in p.stdout and "tiny share" in p.stdout
+
+
+def test_warm_compile_smoke():
+    """A cell's warm worklist compiled one program after another over zero
+    weights (the on-chip check for what the described chip's compiler lets
+    through), at a tiny size: every program is named and accepted."""
+    p = _run(["experiments/warm_compile.py", "--smoke", "prefill_chunk.m4.,decode.n4.,hybrid.p4."])
+    assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
+    assert "WARM DONE 3/3" in p.stdout and "REJECT" not in p.stdout, p.stdout
+    for name in ("prefill_chunk m4 ACCEPT", "decode n4 ACCEPT", "hybrid p4.n4 ACCEPT"):
+        assert name in p.stdout, (name, p.stdout)
+    assert "decode n1" not in p.stdout and "hybrid_pen" not in p.stdout
+
+
 def test_collectives_table_smoke():
     p = _run(["experiments/collectives_table.py", "--smoke"])
     assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"

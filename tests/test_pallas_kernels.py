@@ -502,7 +502,7 @@ def _run_expert_call(rng, sizes, tm, k, n, li=1, held=None, **kw):
     the first experts and turns the others' rows into the sentinel) through
     `expert_groups` and `_expert_call` on layer `li`; returns what the
     asserts below read."""
-    from dllama_tpu.ops.layers import expert_groups
+    from dllama_tpu.ops.layers import expert_groups, expert_rows
     from dllama_tpu.ops.pallas import q40_matmul as qmod
 
     e = held or len(sizes)
@@ -510,10 +510,10 @@ def _run_expert_call(rng, sizes, tm, k, n, li=1, held=None, **kw):
     topi = jnp.asarray(np.where(topi < e, topi, e)[rng.permutation(len(topi)), None],
                        jnp.int32)
     packed, scales = _expert_stack(rng, e, k, n)
-    src, pos, tile_expert, tile_src, n_live, got_sizes = expert_groups(topi, e, tm)
+    pos, tile_expert, tile_src, n_live, got_sizes = expert_groups(topi, e, tm)
     assert got_sizes.tolist() == list(sizes[:e])
     h = jnp.asarray(rng.standard_normal((topi.shape[0], k)), jnp.bfloat16)
-    xs = h[src]
+    xs = expert_rows(h, pos, topi < e, len(tile_src) * tm, by_dot=tm == 16)
     live = int(n_live)
     # what stands behind the last live tile is never read: poison it
     xs = xs.at[live * tm:].set(jnp.nan)

@@ -39,7 +39,7 @@ from dllama_tpu.models import formats
 from dllama_tpu.models.config import HiddenAct, LlamaConfig
 from dllama_tpu.models.llama import KVCache, forward
 from dllama_tpu.obs import instruments as ins
-from dllama_tpu.ops.layers import build_rope_cache, expert_groups, moe_ffn
+from dllama_tpu.ops.layers import build_rope_cache, expert_groups, expert_rows, moe_ffn
 from dllama_tpu.ops.quant import QTensor
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -249,14 +249,18 @@ def test_grouped_kernel_matches_dense(experts, case):
 
 def test_expert_groups_pad_each_group_to_whole_tiles_and_freeze_dead_tiles():
     topi = jnp.asarray([[0, 3], [3, 5], [3, 0], [3, 7]], jnp.int32)  # 4 rows to 3
-    src, pos, tile_expert, tile_src, n_live, sizes = expert_groups(topi, 8, 2)
+    pos, tile_expert, tile_src, n_live, sizes = expert_groups(topi, 8, 2)
     assert sizes.tolist() == [2, 0, 0, 4, 0, 1, 0, 1]
     assert int(n_live) == 1 + 2 + 1 + 1 and len(tile_expert) == 8 + 4
     assert tile_expert.tolist()[:5] == [0, 3, 3, 5, 7]
     assert set(tile_expert.tolist()[5:]) == {7} and set(tile_src.tolist()[5:]) == {4}
-    # every (token, choice) finds its own token at its padded position
-    assert (np.asarray(src)[np.asarray(pos)] == np.arange(4)[:, None]).all()
-    assert len(set(np.asarray(pos).reshape(-1).tolist())) == 8
+    # groups in expert order, token order inside a group, whole tiles of 2
+    assert np.asarray(pos).tolist() == [[0, 2], [3, 6], [4, 1], [5, 8]]
+    # every (token, choice) finds its own token's row at its padded position
+    h = jnp.arange(4, dtype=jnp.bfloat16)[:, None] * jnp.ones((1, 8), jnp.bfloat16) + 1
+    xs = np.asarray(expert_rows(h, pos, None, 12 * 2, by_dot=True), np.float32)
+    assert (xs[np.asarray(pos), 0] == np.arange(4)[:, None] + 1).all()
+    assert (xs[[7, 9] + list(range(10, 24))] == 0).all()  # pad rows and dead tiles
 
 
 # ----------------------------------------------------------- a pool a kind
