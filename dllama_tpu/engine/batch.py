@@ -2341,6 +2341,7 @@ class BatchEngine:
         per_fn: dict[str, int] = {}
         had_counts = self._counts is not None
         work = self._warm_worklist(max(1, int(chunk)), hybrid_budget_hi)
+        memory_before = compile_obs.device_memory_marks()
         with ledger.warmup_phase():
             meters = self._precompile(work)
             for (fn, key, thunk), meter in zip(work, meters):
@@ -2374,6 +2375,10 @@ class BatchEngine:
             "seconds": round(time.perf_counter() - t_start, 3),
             "full_coverage": ledger.snapshot(entries=0)["contract"]["full"],
         }
+        if memory_before:
+            report["device_memory"] = {
+                "before": memory_before,
+                "after": compile_obs.device_memory_marks()}
         ledger.warmup_report = report
         log.info("warmup precompile: %d/%d buckets compiled, %d cached "
                  "(%.2fs; %s)", compiled, len(work), cached,
@@ -2991,8 +2996,12 @@ class BatchEngine:
             fam.inc(int(d))
         # every routed row, and those this chip's share of the experts
         # computed (a fifth counter where the model holds a share)
-        ins.MOE_ROWS_ROUTED.inc(int(delta[4] if len(delta) > 4 else delta[0]))
+        ins.MOE_ROWS_ROUTED.inc(
+            int(delta[4] if self.cfg.experts_held else delta[0]))
         ins.MOE_ROWS_HELD.inc(int(delta[0]))
+        if self.cfg.grouped_routing:
+            ins.MOE_TOKENS_ROUTED.inc(int(delta[-2]))
+            ins.MOE_TOKENS_GROUP_KEPT.inc(int(delta[-1]))
 
     @property
     def supports_hybrid(self) -> bool:

@@ -14,7 +14,7 @@ from dataclasses import dataclass
 from functools import partial
 from typing import Callable
 
-from dllama_tpu.models.config import LlamaConfig
+from dllama_tpu.models.config import LlamaConfig, RopeType
 
 
 #: Paged-layout attention routes (documented in the README "Paged KV cache"
@@ -64,14 +64,16 @@ class KernelSelection:
     def route(self) -> str:
         """The mixers' routes: `attn_route` (with '.window' where the model
         has windowed layers and that route clips their walk:
-        'paged_kernel.window'; '.latent' where its cache rows are latent;
+        'paged_kernel.window'; '.latent' where its cache rows are latent,
+        '.latent.rope_yarn' where they are rotated ones;
         '.heads48g64w' where the global and the windowed layers have a head
         count each, so the kernel runs at two folds in one program, and
         '.ropes2' where they have a rope table each),
         and behind a '+' each the recurrent state's
         decode step and element type where the model has one
         ('paged_kernel+ssm_step.float32', '...+kda_step.float32') and the expert layers' route
-        where it has experts ('paged_kernel.window+moe_grouped')."""
+        where it has experts ('paged_kernel.window+moe_grouped',
+        '...+moe_grouped.groups4of8' where the selection is group-limited)."""
         return (self.attn_route
                 + (f"+{self.state_route}" if self.state_route else "")
                 + (f"+moe_{self.moe_route}" if self.moe_route else ""))
@@ -219,7 +221,10 @@ def resolve_kernels(
                                                  state_dtype)
     moe = dict(moe_impl=moe_impl, moe_route=(
         "" if not cfg.n_experts else
-        "grouped" if moe_impl == "grouped" else "jnp"))
+        ("grouped" if moe_impl == "grouped" else "jnp")
+        # a group-limited selection: groups kept of groups
+        + (f".groups{cfg.expert_groups_kept}of{cfg.n_expert_groups}"
+           if cfg.grouped_routing else "")))
     windowed = cfg.n_window_layers > 0
 
     if paged and shardings is None:
@@ -320,6 +325,13 @@ def resolve_kernels(
 
 def kinds_tag(cfg: LlamaConfig) -> str:
     """What the route says of attention whose shape goes by the layer's
-    kind: the two head counts, and that there are two rope tables."""
+    kind: the two head counts, and that there are two rope tables; of
+    latent attention that rotates, its one table's type ('.rope_llama',
+    '.rope_yarn': the rows in the cache are rotated ones)."""
+    if cfg.latent:
+        if cfg.rope_type == RopeType.NONE:
+            return ""
+        spec = cfg.rope_spec
+        return ".rope_" + (spec.type if spec else cfg.rope_type).name.lower()
     return ((f".heads{cfg.n_heads}g{cfg.window_heads}w" if cfg.window_heads else "")
             + (".ropes2" if cfg.global_rope is not None else ""))

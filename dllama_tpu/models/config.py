@@ -50,7 +50,8 @@ class RopeType(IntEnum):
     LLAMA3_1 = 2
     NONE = 3  # no positional rotation at all: q and k are used as projected
     YARN = 4  # YaRN (Peng et al. 2023) as the public `transformers` library's
-    # `yarn` rope type computes it; a `RopeSpec`'s type only
+    # `yarn` rope type computes it; a `RopeSpec`'s type only (the global
+    # layers' second table, or a latent model's one: `LlamaConfig.rope_spec`)
 
 
 class HeaderKey(IntEnum):
@@ -104,12 +105,18 @@ class HeaderKey(IntEnum):
     KDA_CONV = 132  # conv taps over q, k and v
     KDA_RANK = 133  # inner size of the decay's and the output gate's
     # two-matrix projections
-    # ---- dllama-tpu extensions for LayerKind.MLA layers (no q-side low
-    # rank; the "rope" dims ride unrotated: ROPE_TYPE must be NONE)
+    # ---- dllama-tpu extensions for LayerKind.MLA layers. The shared key
+    # dims ride unrotated where ROPE_TYPE is NONE; else q_pe a head and the
+    # one k_pe a token are rotated over ALL the MLA_PE_DIM dims before the
+    # row is written: by the plain table of ROPE_THETA, or by the GLOBAL_ROPE
+    # keys' table where they are present (the layers all see the whole
+    # context), and ATTN_SCALE_X1E6 carries a score scale such as YaRN's
     MLA_KV_RANK = 140  # the latent c a token leaves in the cache
     MLA_NOPE_DIM = 141  # a head's key dims that come out of the latent
     MLA_PE_DIM = 142  # a head's key dims shared by all heads, beside c
     MLA_V_DIM = 143
+    MLA_Q_RANK = 144  # q = W_qb rmsnorm(W_qa h; g_q) through this inner size
+    # (tensors mla_qa, mla_q_norm, mla_qb); absent = one matrix mla_q
     # ---- dllama-tpu extensions for the expert layers (absent = softmax
     # over the top k, no shared expert, every expert held, expert width =
     # HIDDEN_DIM)
@@ -120,6 +127,11 @@ class HeaderKey(IntEnum):
     EXPERTS_HELD = 153  # this file holds experts [offset, offset + held)
     EXPERT_OFFSET = 154
     MOE_HIDDEN_DIM = 155  # an expert's width where dense layers differ
+    N_EXPERT_GROUPS = 156  # the experts routed among lie in this many
+    # contiguous groups of equal size; a token's experts are the top k among
+    # the EXPERT_GROUPS_KEPT groups whose two best scores sum highest
+    # (absent or 1 = the plain top k)
+    EXPERT_GROUPS_KEPT = 157
     # ---- dllama-tpu extensions for attention whose shape goes by the layer's
     # KIND, windowed or global (absent = one head count, one rope table, no
     # norm over the head, no gate)
@@ -240,6 +252,7 @@ class LlamaConfig:
     qk_nope_dim: int = 0
     qk_pe_dim: int = 0
     v_head_dim: int = 0
+    q_lora_rank: int = 0  # 0 = q is one projection
     # ---- expert layers (defaults: softmax over the top k, all held)
     router_sigmoid: bool = False
     routed_scale: float = 1.0
@@ -249,6 +262,8 @@ class LlamaConfig:
     # chip's share of a layer, routed over all n_experts
     expert_offset: int = 0
     moe_hidden_dim: int = 0  # 0 = hidden_dim
+    n_expert_groups: int = 0  # 0 or 1 = the plain top k over all the experts
+    expert_groups_kept: int = 0
     layer_ffn: tuple = ()  # 0/1 per layer, 1 = dense; () = none is dense
     # ---- attention by layer kind (defaults: one kind of attention layer)
     window_heads: int = 0  # query heads of a windowed layer; 0 = n_heads and
@@ -286,12 +301,20 @@ class LlamaConfig:
         if {int(LayerKind.ATTENTION), int(LayerKind.MLA)} <= kinds:
             raise ValueError("softmax and latent attention layers in one "
                              "model are not supported (one row width a cache)")
-        if LayerKind.MLA in kinds and (
-                self.rope_type != RopeType.NONE or not self.kv_lora_rank):
-            raise ValueError("latent attention layers need MLA_KV_RANK and "
-                             "ROPE_TYPE none (their shared key dims ride "
-                             "unrotated; rotated latent attention is not "
+        if LayerKind.MLA in kinds and not self.kv_lora_rank:
+            raise ValueError("latent attention layers need MLA_KV_RANK")
+        if LayerKind.MLA in kinds and self.rope_type not in (
+                RopeType.NONE, RopeType.LLAMA):
+            raise ValueError("latent attention layers rotate by the plain "
+                             "table or by a GLOBAL_ROPE table of their own "
+                             f"(ROPE_TYPE {self.rope_type.name} is not "
                              "supported)")
+        if LayerKind.MLA in kinds and self.rope_type != RopeType.NONE and (
+                self.qk_pe_dim <= 0 or self.qk_pe_dim % 2):
+            raise ValueError("rotated latent attention needs an even number "
+                             "of shared key dims (MLA_PE_DIM)")
+        if self.q_lora_rank and LayerKind.MLA not in kinds:
+            raise ValueError("MLA_Q_RANK is for latent attention layers")
         if LayerKind.KDA in kinds and not (self.kda_heads and self.kda_rank):
             raise ValueError("delta-rule layers need KDA_HEADS and KDA_RANK")
         if self.window_heads and (
@@ -303,7 +326,7 @@ class LlamaConfig:
         if self.global_rope is not None and (
                 self.rope_type == RopeType.NONE
                 or not 0 < self.global_rope.share <= 1
-                or int(self.head_size * self.global_rope.share) % 2):
+                or int(self.rope_dims * self.global_rope.share) % 2):
             raise ValueError("a rope table of the global layers' own needs a "
                              "model that rotates and an even number of "
                              "rotated dims")
@@ -316,6 +339,18 @@ class LlamaConfig:
             raise ValueError(
                 f"experts [{self.expert_offset}, {self.expert_offset} + "
                 f"{self.experts_held}) are not among {self.n_experts}")
+        if self.n_expert_groups > 1 and not self.router_sigmoid:
+            raise ValueError("expert groups are for the sigmoid router")
+        if self.n_expert_groups > 1 and not (
+                self.n_experts % self.n_expert_groups == 0
+                and 0 < self.expert_groups_kept <= self.n_expert_groups
+                and self.n_active_experts <= self.expert_groups_kept
+                * (self.n_experts // self.n_expert_groups)
+                and self.n_experts // self.n_expert_groups >= 2):
+            raise ValueError(
+                f"{self.n_experts} experts do not lie in {self.n_expert_groups}"
+                f" equal groups of two or more of which "
+                f"{self.expert_groups_kept} hold {self.n_active_experts}")
         if self.n_ssm_layers and self.ssm_groups != 1:
             raise ValueError("state-space layers with more than one B/C group "
                              "are not supported")
@@ -327,6 +362,25 @@ class LlamaConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_size
+
+    @property
+    def rope_dims(self) -> int:
+        """The dims a rope table spans: the head, or a latent model's shared
+        key dims (q_pe a head, the one k_pe a token)."""
+        return self.qk_pe_dim if self.latent else self.head_size
+
+    @property
+    def rope_spec(self) -> RopeSpec | None:
+        """The model's ONE rope table where the header gives it as a
+        `RopeSpec`: a latent model's layers all see the whole context, so the
+        GLOBAL_ROPE keys describe its only table and ROPE_THETA's plain table
+        is not built. None: the table comes from ROPE_TYPE / ROPE_THETA, and
+        `global_rope`, where present, is the global layers' SECOND table."""
+        return self.global_rope if self.latent else None
+
+    @property
+    def grouped_routing(self) -> bool:
+        return self.n_expert_groups > 1
 
     @property
     def attn_dim(self) -> int:
@@ -509,7 +563,8 @@ class LlamaConfig:
             + f"/{self.n_kv_heads} "
             f"vocab={self.vocab_size} seq={self.seq_len} "
             f"act={self.hidden_act.name} rope={self.rope_type.name}"
-            + (f" theta={self.rope_theta:g} (window); global rope "
+            + (f" latent rope {self.rope_spec.describe()}" if self.rope_spec
+               else f" theta={self.rope_theta:g} (window); global rope "
                f"{self.global_rope.describe()}" if self.global_rope else "")
             + f" weights={self.weight_type.name}"
             + (" qk_norm" if self.qk_norm else "")
@@ -527,6 +582,9 @@ class LlamaConfig:
                f"kda={self.kda_heads}x{self.kda_head_dim}x{self.kda_head_dim}"
                if self.n_kda_layers else "")
             + (f" latent={self.kv_lora_rank}+{self.qk_pe_dim}" if self.latent else "")
+            + (f" q_rank={self.q_lora_rank}" if self.q_lora_rank else "")
+            + (f" expert_groups={self.expert_groups_kept}/{self.n_expert_groups}"
+               if self.grouped_routing else "")
         )
 
     def clamp_seq_len(self, max_seq_len: int | None) -> "LlamaConfig":
@@ -573,8 +631,12 @@ class LlamaConfig:
             kv.append((HeaderKey.TIED_HEAD, int(self.tied_head)))
             kv += [(key, getattr(self, name)) for key, name in _SSM_INT_KEYS.items()]
             kv += [(LAYER_KIND_BASE + i, k) for i, k in enumerate(self.layer_kinds)]
-        elif self.head_dim:
-            kv.append((HeaderKey.HEAD_SIZE, self.head_size))
+        else:
+            if self.head_dim:
+                kv.append((HeaderKey.HEAD_SIZE, self.head_size))
+            if self.attn_scale:
+                kv.append((HeaderKey.ATTN_SCALE_X1E6,
+                           int(round(self.attn_scale * 1e6))))
         if self.layer_kinds and self.arch != ArchType.HYBRID_SSM:
             kv += [(LAYER_KIND_BASE + i, k) for i, k in enumerate(self.layer_kinds)]
         kv += [(key, getattr(self, name)) for key, name in _EXTRA_INT_KEYS.items()
@@ -724,10 +786,13 @@ _EXTRA_INT_KEYS = {HeaderKey.KDA_HEADS: "kda_heads",
                    HeaderKey.MLA_NOPE_DIM: "qk_nope_dim",
                    HeaderKey.MLA_PE_DIM: "qk_pe_dim",
                    HeaderKey.MLA_V_DIM: "v_head_dim",
+                   HeaderKey.MLA_Q_RANK: "q_lora_rank",
                    HeaderKey.N_SHARED_EXPERTS: "n_shared_experts",
                    HeaderKey.EXPERTS_HELD: "experts_held",
                    HeaderKey.EXPERT_OFFSET: "expert_offset",
-                   HeaderKey.MOE_HIDDEN_DIM: "moe_hidden_dim"}
+                   HeaderKey.MOE_HIDDEN_DIM: "moe_hidden_dim",
+                   HeaderKey.N_EXPERT_GROUPS: "n_expert_groups",
+                   HeaderKey.EXPERT_GROUPS_KEPT: "expert_groups_kept"}
 _FIELD_DEFAULTS = {f.name: f.default for f in dataclasses.fields(LlamaConfig)}
 #: header key -> (RopeSpec field, what its value is multiplied by on disk)
 _GLOBAL_ROPE_KEYS = {

@@ -145,8 +145,13 @@ def tensor_plan(config: LlamaConfig) -> list[tuple[str, tuple[int, int] | tuple[
             ]
         elif kind == LayerKind.MLA:
             p, h, r = f"layers.{layer}.", config.n_heads, config.kv_lora_rank
+            qd, qr = h * (config.qk_nope_dim + config.qk_pe_dim), config.q_lora_rank
+            # q through its low rank and norm where the header gives one
+            plan += ([(p + "mla_qa", (qr, config.dim), wt),
+                      (p + "mla_q_norm", (qr,), FloatType.F32),
+                      (p + "mla_qb", (qd, qr), wt)] if qr
+                     else [(p + "mla_q", (qd, config.dim), wt)])
             plan += [
-                (p + "mla_q", (h * (config.qk_nope_dim + config.qk_pe_dim), config.dim), wt),
                 (p + "mla_kva", (r + config.qk_pe_dim, config.dim), wt),
                 (p + "mla_kv_norm", (r,), FloatType.F32),
                 (p + "mla_kvb", (h * (config.qk_nope_dim + config.v_head_dim), r), wt),
@@ -427,7 +432,7 @@ def _load_matmul(raw: np.ndarray, shape: tuple[int, int], ft: FloatType, dtype, 
 #: state-space mixer's conv, step and skip parameters
 _F32_LEAVES = ("rms_att", "rms_ffn", "conv_w", "conv_b", "dt_bias", "a_log",
                "d", "ssm_norm", "kda_conv_w", "kda_dt_bias", "kda_a_log",
-               "kda_norm", "mla_kv_norm", "moe_bias", "q_norm", "k_norm",
+               "kda_norm", "mla_kv_norm", "mla_q_norm", "moe_bias", "q_norm", "k_norm",
                "q_norm_win", "k_norm_win")
 #: matmul weights whose published output width is not whole lane tiles: zero
 #: columns are added on the way to the device, the file keeps the width

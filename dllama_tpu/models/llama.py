@@ -127,11 +127,14 @@ class RecurrentState:
 
 
 def _moe_stats0(cfg: LlamaConfig):
-    """The expert counters (ops/layers.moe_ffn): four, and a fifth (every
-    routed row) where the model holds a share of its experts."""
+    """The expert counters (ops/layers.moe_ffn): four, a fifth (every
+    routed row) where the model holds a share of its experts, and two more
+    at the end (tokens routed, tokens whose kept groups reach this chip)
+    where the selection is group-limited."""
     if not cfg.n_experts:
         return None
-    return jnp.zeros((5 if cfg.experts_held else 4,), jnp.uint32)
+    return jnp.zeros((4 + bool(cfg.experts_held) + 2 * cfg.grouped_routing,),
+                     jnp.uint32)
 
 
 def _v_placeholder(cfg: LlamaConfig, lead: tuple, dtype):
@@ -516,29 +519,50 @@ def _kda_mixer(cfg: LlamaConfig, h, layers, si, state: RecurrentState,
     return colmm(y.reshape(b, t, inner).astype(h.dtype), layers["kda_o"], si), state
 
 
-def _mla_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, pos_base,
+def _mla_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, rope, pos_base,
                attn_fn, active, mm, colmm, tables, ci=None):
-    """Latent attention without rotation, in its ABSORBED form on every
-    route: the cache row of a token is (c, k_pe) = (rmsnorm(W_kva h)[:r],
-    W_kva h[r:]), one for all heads; a head's query meets it as
-    (W_kvb,k^T q_nope, q_pe), the mix of the rows' latents comes back and
-    W_kvb,v expands it to the head's value. One read of a row serves key and
-    value of every head; the expanded form (k_nope, v = W_kvb c a head) is
-    the benchmark reference's. `v_cache` is the latent cache's placeholder.
+    """Latent attention in its ABSORBED form on every route: the cache row
+    of a token is (c, k_pe) = (rmsnorm(W_kva h)[:r], W_kva h[r:]), one for
+    all heads; a head's query meets it as (W_kvb,k^T q_nope, q_pe), the mix
+    of the rows' latents comes back and W_kvb,v expands it to the head's
+    value. One read of a row serves key and value of every head; the
+    expanded form (k_nope, v = W_kvb c a head) is the benchmark reference's.
+
+    `rope` (None: the shared dims ride unrotated) rotates q_pe a head and
+    the ONE k_pe a token BEFORE the row is written, so the cache holds
+    rotated rows and every route's sweep is the unrotated model's. With a
+    q-side low rank (`cfg.q_lora_rank`) q = W_qb rmsnorm(W_qa h; g_q).
+    `v_cache` is the latent cache's placeholder.
     Returns (out [B, T, D], k_cache, v_cache)."""
     b, t, _ = h.shape
     heads, r = cfg.n_heads, cfg.kv_lora_rank
     dn, dp, dv = cfg.qk_nope_dim, cfg.qk_pe_dim, cfg.v_head_dim
     ci = ai if ci is None else ci
     hi = jax.lax.Precision.HIGHEST
-    q = mm(h, layers["mla_q"], ai).reshape(b, t, heads, dn + dp)
+    if cfg.q_lora_rank:
+        with jax.named_scope("mla_q_rank"):
+            cq = rms_norm(mm(h, layers["mla_qa"], ai), layers["mla_q_norm"][ai],
+                          cfg.norm_epsilon)
+        q = mm(cq, layers["mla_qb"], ai)
+    else:
+        q = mm(h, layers["mla_q"], ai)
+    q = q.reshape(b, t, heads, dn + dp)
     kva = mm(h, layers["mla_kva"], ai)  # c | k_pe (| zero pad)
     c = rms_norm(kva[..., :r], layers["mla_kv_norm"][ai], cfg.norm_epsilon)
-    row = jnp.concatenate([c, kva[..., r : r + dp]], axis=-1)[:, None]  # [B, 1, T, W]
+    k_pe = kva[..., r : r + dp]
+    if rope is not None:
+        with jax.named_scope("mla_rope"):
+            k_pe = apply_rope(k_pe[:, :, None], rope)[:, :, 0]
+    row = jnp.concatenate([c, k_pe], axis=-1)[:, None]  # [B, 1, T, W]
     wkvb = layers["mla_kvb"][ai]  # f32 [H, nope + v, r]
-    q_lat = jnp.einsum("bthn,hnr->bthr", q[..., :dn].astype(jnp.float32),
-                       wkvb[:, :dn], precision=hi)
-    q_abs = jnp.concatenate([q_lat.astype(h.dtype), q[..., dn:]], axis=-1)
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.einsum("bthn,hnr->bthr", q[..., :dn].astype(jnp.float32),
+                           wkvb[:, :dn], precision=hi).astype(h.dtype)
+    q_pe = q[..., dn:]
+    if rope is not None:
+        with jax.named_scope("mla_rope"):
+            q_pe = apply_rope(q_pe, rope)
+    q_abs = jnp.concatenate([q_lat, q_pe], axis=-1)
     scale = cfg.attn_scale or (dn + dp) ** -0.5
     if tables is None:
         k_cache = _cache_update(k_cache, row, pos_base, active)
@@ -552,8 +576,9 @@ def _mla_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, pos_base,
         k_cache = _paged_cache_update(k_cache, row, tables, pos_base, active)
         o_lat = latent_attention(q_abs, paged_view(k_cache, tables)[:, 0],
                                  pos_base, scale, r)
-    o = jnp.einsum("bthr,hvr->bthv", o_lat.astype(jnp.float32), wkvb[:, dn:],
-                   precision=hi)
+    with jax.named_scope("mla_expand"):
+        o = jnp.einsum("bthr,hvr->bthv", o_lat.astype(jnp.float32), wkvb[:, dn:],
+                       precision=hi)
     out = colmm(o.reshape(b, t, heads * dv).astype(h.dtype), layers["mla_o"], ai)
     return out, k_cache, v_cache
 
@@ -900,7 +925,7 @@ def run_layers(
             ks, vs = (pk[ci], pv[ci]) if cut else (pk, pv)
             if base == LayerKind.MLA:
                 out, k2, v2 = _mla_mixer(
-                    cfg, h, layer_params, ai, ks, vs, pos_base, attn_fn,
+                    cfg, h, layer_params, ai, ks, vs, lrope, pos_base, attn_fn,
                     active, mm_, colmm, tbl, ci)
             else:
                 out, k2, v2 = _attention_mixer(

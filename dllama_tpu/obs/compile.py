@@ -657,6 +657,25 @@ def refresh_device_gauges() -> dict:
             "peak_bytes_in_use": stats.get("peak_bytes_in_use")}
 
 
+def device_memory_marks() -> dict:
+    """The first device's allocator marks where the backend keeps them
+    (`memory_stats()` is None on CPU: {}): bytes in use now, the
+    high-water mark so far, and what live arrays account for. In use minus
+    live arrays is what the runtime holds beside them: loaded programs and
+    the temporaries of launches in flight. The warmup report carries one
+    reading from before its first program and one from after its last, so
+    a boot's high-water mark can be laid at loading and building the
+    engine or at the warm programs."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}
+    if not stats:
+        return {}
+    return {"bytes_in_use": stats.get("bytes_in_use"),
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+            "live_array_bytes": sum(int(a.nbytes) for a in jax.live_arrays())}
+
+
 _UNSET = object()
 
 

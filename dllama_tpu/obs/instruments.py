@@ -389,6 +389,14 @@ MOE_ROWS_HELD = metrics.counter(
     "them unless the file holds one chip's share of each expert layer): "
     "what dllama_moe_assignments_total and the touched / longest-group "
     "counters are counted over")
+MOE_TOKENS_ROUTED = metrics.counter(
+    "dllama_moe_tokens_routed_total",
+    "Tokens the group-limited routers chose experts for (rows, over layers "
+    "and steps); only where the header gives expert groups")
+MOE_TOKENS_GROUP_KEPT = metrics.counter(
+    "dllama_moe_tokens_group_kept_total",
+    "Of those, the tokens whose kept groups include a group with an expert "
+    "this process holds: the tokens a chip that holds one group sees at all")
 # recurrent (state-space) models: the per-slot state beside the page pool
 RECURRENT_STATE_BYTES = metrics.gauge(
     "dllama_recurrent_state_bytes",

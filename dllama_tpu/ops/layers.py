@@ -110,11 +110,15 @@ def build_rope_cache(cfg: LlamaConfig, seq_len: int | None = None):
 
     A model whose global layers have a rope of their own (`cfg.global_rope`)
     gets the pair (global table, this table): `models/llama.forward` cuts the
-    rows of both and a layer is handed its kind's.
+    rows of both and a layer is handed its kind's. A latent model's table
+    spans its shared key dims (`cfg.rope_dims`) and is its only one
+    (`cfg.rope_spec` where the header describes it).
     """
     seq_len = seq_len or cfg.seq_len
-    half = cfg.head_size // 2
-    freqs = 1.0 / (cfg.rope_theta ** (np.arange(half, dtype=np.float64) * 2.0 / cfg.head_size))
+    if cfg.rope_spec is not None:
+        return rope_table(cfg.rope_spec, cfg.rope_dims, seq_len)
+    half = cfg.rope_dims // 2
+    freqs = 1.0 / (cfg.rope_theta ** (np.arange(half, dtype=np.float64) * 2.0 / cfg.rope_dims))
     freqs = freqs.astype(np.float32)
     if cfg.rope_type == RopeType.LLAMA3_1 and cfg.rope_scaling_factor != 1.0:
         freqs = llama31_scale_freqs(freqs, cfg)
@@ -168,6 +172,22 @@ def router_logits(h: jax.Array, gate: jax.Array) -> jax.Array:
     return jnp.einsum("btd,de->bte", h.astype(jnp.float32),
                       gate.astype(jnp.float32),
                       precision=jax.lax.Precision.HIGHEST)
+
+
+def keep_expert_groups(choose: jax.Array, groups: int, kept: int):
+    """Group-limited selection (the DeepSeek-V3 family's): the E columns of
+    `choose` [..., E] (score + selection bias, float32) lie in `groups`
+    contiguous groups of equal size; a group's score is the sum of its two
+    largest entries, and the `kept` groups with the largest score stay.
+    Returns (`choose` with every other group's entries at -inf, so that a
+    top k over it chooses among the kept groups alone; bool [..., groups],
+    which groups were kept)."""
+    by_group = choose.reshape(*choose.shape[:-1], groups, -1)
+    group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+    _, top_groups = jax.lax.top_k(group_score, kept)
+    keep = jnp.any(top_groups[..., None] == jnp.arange(groups), axis=-2)
+    limited = jnp.where(keep[..., None], by_group, -jnp.inf)
+    return limited.reshape(choose.shape), keep
 
 
 def expert_tile_rows(rows: int, e: int) -> int:
@@ -311,6 +331,13 @@ def moe_ffn(
     The jnp route of a share is `dense`. `stats` then has a fifth counter:
     [0] counts the rows that landed on held experts, [4] every routed row.
 
+    GROUP-LIMITED SELECTION (`cfg.n_expert_groups` > 1, sigmoid router):
+    the top k is taken among the experts of the `cfg.expert_groups_kept`
+    groups that `keep_expert_groups` keeps, in float32 like every choice;
+    the weights are as above. `stats` then ends in two more counters: the
+    tokens routed, and those whose kept groups include a group with an
+    expert held here (a share that is one group sees only those tokens).
+
     The reference *parses* N_EXPERTS from the header and its converter emits
     expert tensors, but the runtime has no MoE graph (SURVEY.md §2.4 — EP row);
     this is the capability it never shipped.
@@ -355,10 +382,14 @@ def moe_ffn(
         impl = "sort" if n >= e else "dense"
     if logits is None:
         logits = router_logits(h, gate)
+    kept = None
     if cfg.router_sigmoid:
         score = jax.nn.sigmoid(logits.astype(jnp.float32))
-        _, topi = jax.lax.top_k(
-            score if bias is None else score + bias.astype(jnp.float32), k)
+        choose = score if bias is None else score + bias.astype(jnp.float32)
+        if cfg.grouped_routing:
+            choose, kept = keep_expert_groups(
+                choose, cfg.n_expert_groups, cfg.expert_groups_kept)
+        _, topi = jax.lax.top_k(choose, k)
         topv = jnp.take_along_axis(score, topi, axis=-1)
         probs = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
     else:
@@ -391,7 +422,20 @@ def moe_ffn(
         rows = jnp.asarray(n * k) if mine is None else jnp.count_nonzero(mine)
         return out, stats + jnp.stack(
             [rows, jnp.count_nonzero(sizes), jnp.asarray(1), sizes.max()]
-            + ([] if mine is None else [jnp.asarray(n * k)])).astype(stats.dtype)
+            + ([] if mine is None else [jnp.asarray(n * k)])
+            + group_counts()).astype(stats.dtype)
+
+    def group_counts():
+        """Tokens routed, and those whose kept groups include one with an
+        expert held here (every token where all are held); [] where the
+        selection is not group-limited."""
+        if kept is None:
+            return []
+        per = cfg.n_experts // cfg.n_expert_groups
+        first = cfg.expert_offset // per
+        last = (cfg.expert_offset + cfg.n_held_experts - 1) // per
+        here = jnp.any(kept[..., first:last + 1], axis=-1)
+        return [jnp.asarray(n), jnp.count_nonzero(here)]
 
     if impl == "grouped":
         from dllama_tpu.ops.matmul import device_platform

@@ -9,7 +9,12 @@ Checkpoints: `LlamaForCausalLM` / Mistral / Mixtral (the `.m` LLAMA arch)
 and `GraniteMoeHybridForCausalLM` (Mamba-2 mixers `mamba.in_proj` / `conv1d`
 / `dt_bias` / `A_log` / `D` / `norm` / `out_proj` beside attention layers,
 `shared_mlp.input_linear` / `output_linear`, the four scalars and
-`layer_types` into the header: `converter_core.HYBRID_NAME_MAP`).
+`layer_types` into the header: `converter_core.HYBRID_NAME_MAP`), and the
+DeepSeek-V3 family's config keys (latent attention with `q_lora_rank`, a
+`yarn` block with its mscales, `n_group` / `topk_group`,
+`first_k_dense_replace`; `converter_core.LATENT_NAME_MAP`; the header mapping
+is unit-tested on a hand-written config, no checkpoint of the family has
+been converted).
 
 Usage:
     python -m dllama_tpu.tools.convert_hf <model_dir> <weight_type> [--output out.m] [--max-seq-len N]

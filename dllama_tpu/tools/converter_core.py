@@ -18,6 +18,7 @@ from dllama_tpu.models.config import (
     HiddenAct,
     LayerKind,
     LlamaConfig,
+    RopeSpec,
     RopeType,
 )
 from dllama_tpu.ops.quant import FloatType, parse_float_type
@@ -44,6 +45,8 @@ def hf_config_to_llama(config: Mapping, weight_type: FloatType) -> LlamaConfig:
         return _hybrid_ssm_config(config, weight_type)
     if "sliding_window_layout" in config:
         return _window_moe_config(config, weight_type)
+    if config.get("kv_lora_rank") and config.get("n_group"):
+        return _latent_groups_config(config, weight_type)
     arch = {
         "llama": ArchType.LLAMA,
         "mistral": ArchType.LLAMA,
@@ -170,6 +173,101 @@ def _hybrid_ssm_config(config: Mapping, weight_type: FloatType) -> LlamaConfig:
     )
 
 
+def yarn_mscale(factor: float, m: float) -> float:
+    """YaRN's magnitude correction as the DeepSeek-V3 family computes it."""
+    return 0.1 * m * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _latent_groups_config(config: Mapping, weight_type: FloatType) -> LlamaConfig:
+    """A config.json of the DeepSeek-V3 family's shape (latent attention with
+    a q-side low rank in every layer, `first_k_dense_replace` leading dense
+    layers, then sigmoid-routed experts under group-limited selection beside
+    shared experts; source of the key names: huggingface.co/skt/A.X-K1
+    config.json) -> a LLAMA header: every layer LayerKind.MLA, `q_lora_rank`
+    -> MLA_Q_RANK, `n_group` / `topk_group` -> N_EXPERT_GROUPS /
+    EXPERT_GROUPS_KEPT, a `yarn` block -> the layers' own rope table over the
+    shared key dims (cos and sin times mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)) and the score scale (nope + pe)^-1/2 x mscale(factor,
+    mscale_all_dim)^2. Refused by mechanism: a softmax router, unnormalised
+    weights, no q-side low rank, expert layers at a stride, projection
+    biases, another rope scaling."""
+    refused = {
+        "a router that is not sigmoid": config.get("scoring_func") != "sigmoid",
+        "top-k weights that are not renormalised": not config.get("norm_topk_prob"),
+        "q without a low rank (q_lora_rank null)": not config.get("q_lora_rank"),
+        "expert layers at a stride (moe_layer_freq != 1)":
+            config.get("moe_layer_freq", 1) != 1,
+        "projection or attention biases": config.get("attention_bias"),
+        "a tied output head": config.get("tie_word_embeddings"),
+    }
+    for what, present in refused.items():
+        if present:
+            raise ValueError(f"unsupported in a latent-attention model: {what}")
+    if config["hidden_act"] != "silu":
+        raise ValueError(f"unsupported hidden act: {config['hidden_act']}")
+    n = config["num_hidden_layers"]
+    nope, pe = config["qk_nope_head_dim"], config["qk_rope_head_dim"]
+    rope, scale = None, 0.0
+    yarn = config.get("rope_scaling")
+    if yarn is not None:
+        if yarn.get("rope_type", yarn.get("type")) != "yarn":
+            raise ValueError(f"unsupported rope scaling: {yarn}")
+        f = float(yarn["factor"])
+        all_dim = yarn_mscale(f, float(yarn.get("mscale_all_dim", 0.0)))
+        # (to the header's six decimals, so that the config round-trips)
+        scale = round(float((nope + pe) ** -0.5 * all_dim * all_dim), 6)
+        rope = RopeSpec(
+            RopeType.YARN, float(config.get("rope_theta", 10000.0)), 1.0, f,
+            int(yarn["original_max_position_embeddings"]),
+            float(yarn.get("beta_fast", 32.0)), float(yarn.get("beta_slow", 1.0)),
+            float(yarn_mscale(f, float(yarn.get("mscale", 1.0))) / all_dim))
+    return LlamaConfig(
+        arch=ArchType.LLAMA, hidden_act=HiddenAct.SILU,
+        dim=config["hidden_size"], hidden_dim=config["intermediate_size"],
+        n_layers=n, n_heads=config["num_attention_heads"],
+        n_kv_heads=config["num_key_value_heads"], weight_type=weight_type,
+        seq_len=config["max_position_embeddings"],
+        vocab_size=config["vocab_size"],
+        norm_epsilon=float(config["rms_norm_eps"]),
+        rope_theta=float(config.get("rope_theta", 10000.0)),
+        global_rope=rope, attn_scale=scale,
+        layer_kinds=(LayerKind.MLA,) * n, kv_lora_rank=config["kv_lora_rank"],
+        qk_nope_dim=nope, qk_pe_dim=pe, v_head_dim=config["v_head_dim"],
+        q_lora_rank=config["q_lora_rank"],
+        n_experts=config["n_routed_experts"],
+        n_active_experts=config["num_experts_per_tok"], router_sigmoid=True,
+        routed_scale=float(config.get("routed_scaling_factor", 1.0)),
+        n_shared_experts=int(config.get("n_shared_experts") or 0),
+        moe_hidden_dim=config["moe_intermediate_size"],
+        n_expert_groups=config["n_group"], expert_groups_kept=config["topk_group"],
+        layer_ffn=tuple(int(i < config.get("first_k_dense_replace", 0))
+                        for i in range(n)))
+
+
+# the DeepSeek-V3 family's names for `_latent_groups_config`'s tensors. The
+# family's checkpoints keep q_pe and k_pe as INTERLEAVED pairs (its public
+# implementation de-interleaves before its rotate-half), which is the `.m`
+# pairing: no row permutation. `moe_bias` is absent from a checkpoint with
+# `topk_method` "none": zeros are written
+LATENT_NAME_MAP = {
+    "mla_qa": "model.layers.{l}.self_attn.q_a_proj.weight",
+    "mla_q_norm": "model.layers.{l}.self_attn.q_a_layernorm.weight",
+    "mla_qb": "model.layers.{l}.self_attn.q_b_proj.weight",
+    "mla_kva": "model.layers.{l}.self_attn.kv_a_proj_with_mqa.weight",
+    "mla_kv_norm": "model.layers.{l}.self_attn.kv_a_layernorm.weight",
+    "mla_kvb": "model.layers.{l}.self_attn.kv_b_proj.weight",
+    "mla_o": "model.layers.{l}.self_attn.o_proj.weight",
+    "moe_gate": "model.layers.{l}.mlp.gate.weight",
+    "moe_bias": "model.layers.{l}.mlp.gate.e_score_correction_bias",
+    "moe_w1": "model.layers.{l}.mlp.experts.{e}.gate_proj.weight",
+    "moe_w2": "model.layers.{l}.mlp.experts.{e}.down_proj.weight",
+    "moe_w3": "model.layers.{l}.mlp.experts.{e}.up_proj.weight",
+    "shared_w1": "model.layers.{l}.mlp.shared_experts.gate_proj.weight",
+    "shared_w2": "model.layers.{l}.mlp.shared_experts.down_proj.weight",
+    "shared_w3": "model.layers.{l}.mlp.shared_experts.up_proj.weight",
+}
+
+
 # `.m` plan name -> HF tensor name template (convert-hf.py:51-89 order)
 HF_NAME_MAP = {
     "embedding": "model.embed_tokens.weight",
@@ -218,6 +316,17 @@ def hf_tensor_for(name: str, cfg: LlamaConfig, get) -> np.ndarray:
     parts = name.split(".")
     if len(parts) == 3:
         _, layer, short = parts
+        if cfg.latent and cfg.q_lora_rank and short in LATENT_NAME_MAP:
+            at = LATENT_NAME_MAP[short]
+            if short == "moe_bias":
+                try:
+                    return get(at.format(l=layer))
+                except KeyError:
+                    return np.zeros((cfg.n_experts,), np.float32)
+            if short in ("moe_w1", "moe_w2", "moe_w3"):
+                return np.stack([get(at.format(l=layer, e=e))
+                                 for e in range(cfg.n_experts)], axis=0)
+            return get(at.format(l=layer))
         if short.startswith("moe_"):
             # by what the checkpoint holds: Mixtral's names, else the
             # window-and-global family's

@@ -449,7 +449,9 @@ def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
         + (".latent" if cfg.latent else "") + kinds_tag(cfg)
         + ("+kda_step.float32" if cfg.n_kda_layers else
            "+ssm_step.float32" if cfg.recurrent else "")
-        + ("+moe_grouped" if cfg.n_experts else "")), be.kernel_route
+        + ("+moe_grouped" if cfg.n_experts else "")
+        + (f".groups{cfg.expert_groups_kept}of{cfg.n_expert_groups}"
+           if cfg.grouped_routing else "")), be.kernel_route
     nb = seq // page
     n_pages = kv_pages or slots * nb
     row = (cfg.cache_kv_heads, page, be.cache.k.shape[-1])
@@ -472,7 +474,7 @@ def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
     dec = lambda n: (params, cache, i32(slots, 1), *vecs, n, rope, i32(slots))
     out = [(f"{tag} paged decode chunk n=4",
             lambda: be._decode.lower(*dec(4)).compile(), True)]
-    if spec or cfg.recurrent or cfg.n_window_layers:
+    if spec or cfg.recurrent or cfg.n_window_layers or cfg.q_lora_rank:
         out += [(f"{tag} hybrid step p={p} n=4", lambda p=p: be._hybrid.lower(
             params, cache, i32(1, p), i32(), i32(), i32(slots, 1),
             *vecs, 4, rope, i32(slots)).compile(), True) for p in hybrid_p]
@@ -823,6 +825,83 @@ def attn_kinds_cases(topo, slots: int = ATTN_KINDS_SLOTS,
                            prefill_chunk=ATTN_KINDS_SLICE)
 
 
+#: rotated latent attention with a q-side low rank over group-limited
+#: sigmoid-routed experts at the published widths of
+#: benchmark/configs/a.x-k1.json: 7,168 stream, 64 heads over a 512 + 64
+#: latent row, q through 1,536, YaRN x32 over the 64 shared dims, a dense
+#: layer of 18,432, then 24 held of 192 experts (8 groups, 4 kept, 8 a token)
+#: of width 2,048 and a shared expert, a 20,480-row head; 9 layers, 32 slots
+#: over 2,368 pages, 512-row slices
+ROT_LATENT_SLOTS, ROT_LATENT_PAGES, ROT_LATENT_SEQ, ROT_LATENT_SLICE = 32, 2368, 16384, 512
+
+
+def rot_latent_cfg(n_layers: int = 9):
+    from dllama_tpu.models.config import LlamaConfig, RopeSpec, RopeType
+
+    return LlamaConfig(
+        dim=7168, hidden_dim=18432, n_layers=n_layers, n_heads=64, n_kv_heads=64,
+        vocab_size=20480, seq_len=ROT_LATENT_SEQ, norm_epsilon=1e-6,
+        attn_scale=0.130861, layer_kinds=(3,) * n_layers, kv_lora_rank=512,
+        qk_nope_dim=128, qk_pe_dim=64, v_head_dim=128, q_lora_rank=1536,
+        global_rope=RopeSpec(RopeType.YARN, 10000.0, 1.0, 32.0, 4096, 32.0,
+                             1.0, 1.0),
+        n_experts=192, n_active_experts=8, router_sigmoid=True,
+        routed_scale=2.5, n_shared_experts=1, experts_held=24, expert_offset=0,
+        moe_hidden_dim=2048, n_expert_groups=8, expert_groups_kept=4,
+        layer_ffn=(1,) + (0,) * (n_layers - 1))
+
+
+def rot_latent_params(cfg, A):
+    """Abstract params as models/formats.load_params stacks them (mla_kva
+    zero-padded to whole lane tiles, W_kvb float32 by head)."""
+    def qw(lead, k, n):
+        return QTensor(A((*lead, k // 2, n), jnp.uint8),
+                       A((*lead, k // Q_BLOCK, n), jnp.float16))
+
+    f32 = lambda *shape: A(shape, jnp.float32)
+    L, Ld = cfg.n_layers, cfg.n_dense_ffn_layers
+    Le, d, w, E = L - Ld, cfg.dim, cfg.expert_width, cfg.n_held_experts
+    h, qr = cfg.n_heads, cfg.q_lora_rank
+    return {
+        "embedding": A((cfg.vocab_size, d), jnp.bfloat16),
+        "final_norm": f32(d), "wcls": qw((), d, cfg.vocab_size),
+        "layers": {
+            "mla_qa": qw((L,), d, qr), "mla_q_norm": f32(L, qr),
+            "mla_qb": qw((L,), qr, h * (cfg.qk_nope_dim + cfg.qk_pe_dim)),
+            "mla_kva": qw((L,), d, -(-cfg.cache_row // 128) * 128),
+            "mla_kv_norm": f32(L, cfg.kv_lora_rank),
+            "mla_kvb": f32(L, h, cfg.qk_nope_dim + cfg.v_head_dim, cfg.kv_lora_rank),
+            "mla_o": qw((L,), h * cfg.v_head_dim, d),
+            "w1": qw((Ld,), d, cfg.hidden_dim), "w2": qw((Ld,), cfg.hidden_dim, d),
+            "w3": qw((Ld,), d, cfg.hidden_dim),
+            "moe_gate": f32(Le, d, cfg.n_experts), "moe_bias": f32(Le, cfg.n_experts),
+            "moe_w1": qw((Le, E), d, w), "moe_w2": qw((Le, E), w, d),
+            "moe_w3": qw((Le, E), d, w),
+            "shared_w1": qw((Le,), d, w), "shared_w2": qw((Le,), w, d),
+            "shared_w3": qw((Le,), d, w),
+            "rms_att": f32(L, d), "rms_ffn": f32(L, d),
+        },
+    }
+
+
+def rot_latent_cases(topo, slots: int = ROT_LATENT_SLOTS,
+                     pages: int = ROT_LATENT_PAGES, n_layers: int = 9):
+    """The step programs of `serve --slots 32 --kv-pages 2368
+    --max-prefill-chunk 512` on the rotated-latent model at its published
+    widths: decode chunk and the hybrid step (the latent paged sweep at 64
+    heads over rotated rows, the grouped kernel over the held group at
+    width 2,048 on 7,168, the q-side low rank)."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = rot_latent_cfg(n_layers)
+    params = rot_latent_params(cfg, lambda shape, dt: S(shape, dt, sharding=one))
+    return engine_programs(topo, f"serve rot-latent {slots}-slot", cfg, params,
+                           slots, 0, kv_pages=pages, seq=ROT_LATENT_SEQ,
+                           hybrid_p=(ROT_LATENT_SLICE,),
+                           prefill_chunk=ROT_LATENT_SLICE)
+
+
 def all_cases(topo, full: bool = False):
     """Every case as (name, thunk, production): thunk() compiles for the
     described chip and raises what the chip's compiler would raise."""
@@ -871,7 +950,8 @@ def main():
     for cname, thunk, production in (all_cases(topo, full) + hybrid_cases(topo)
                                      + window_moe_cases(topo)
                                      + delta_latent_cases(topo)
-                                     + attn_kinds_cases(topo)):
+                                     + attn_kinds_cases(topo)
+                                     + rot_latent_cases(topo)):
         t0 = time.time()
         try:
             compiled = thunk()

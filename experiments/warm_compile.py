@@ -47,6 +47,9 @@ CELLS = {
     "kimilinear.reason_closed": dict(
         cfg="delta_latent_cfg", params="delta_latent_params", slots=48, pages=456,
         seq=8192, chunk=256, hybrid=64),
+    "axk1.long_reason_closed": dict(
+        cfg="rot_latent_cfg", params="rot_latent_params", slots=32, pages=2368,
+        seq=16384, chunk=512, hybrid=512),
     "smallthinker.long_decode_closed": dict(
         cfg="window_moe_cfg", params="window_moe_params", slots=16, pages=1232,
         seq=16384, chunk=512, hybrid=512),

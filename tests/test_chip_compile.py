@@ -356,3 +356,60 @@ def test_attn_kinds_decode_program_compiles_at_two_folds(attn_kinds_thunks):
     window_layer = 8 * 7 * 8 * 128 * 128 * 2  # a layer's slice of the window pool
     assert not pool_copies.big_movers(text, window_layer)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# ------- rotated latent attention over one routing group of wide experts
+
+
+@pytest.fixture(scope="module")
+def rot_latent_thunks(thunks):
+    """The step programs of the rotated-latent model at its published widths
+    (aot_check.rot_latent_cases: 7,168 stream, 64 heads over a 512 + 64
+    latent row, q through 1,536, 24 held of 192 experts of width 2,048 in 8
+    groups), at 3 of its 9 layers (the dense layer's body and the expert
+    layers' body, two periods instead of eight) and the cell's 32 slots over
+    160 pages. Depends on `thunks` for the platform steer and the cache
+    settings."""
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(aot_check.TARGET, platform="tpu")
+    return {name.split("-slot ")[1]: thunk for name, thunk, _ in
+            aot_check.rot_latent_cases(topo, pages=160, n_layers=3)}
+
+
+def test_rot_latent_decode_program_compiles_with_its_kernels_named(rot_latent_thunks):
+    """The decode program compiles for v5e: the latent sweep at 64 query
+    heads (`_paged_latent`, one a layer body), the grouped expert kernel at
+    7,168 x 2,048 and back over the held group of 24, the projections through
+    the q-side rank (`_deq_call` at k = 1,536 and n = 1,536), and each custom
+    call's line parses as its cost file reads it; no instruction moves a
+    layer of the latent pool (W_kvb's float32 layer slice is moved, and is
+    the only thing of that size that is)."""
+    import re
+
+    from benchmark.costs import moe_experts, paged_attention_latent
+    from experiments import pool_copies
+
+    compiled = rot_latent_thunks["paged decode chunk n=4"]()
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    groups = {m.group(1) for l in calls
+              for m in [re.search(r"%(_[a-z_]+?)(?:\.\d+)? = ", l)] if m}
+    assert {"_paged_latent", "_expert_call", "_deq_call"} <= groups, groups
+    count = lambda g: sum(f"%{g}" in l for l in calls)
+    assert count("_paged_latent") == 2  # the dense layer's body, the expert layers'
+    assert any(re.search(r"%_deq_call(\.\d+)? = f32\[32,1536\]", l) for l in calls)
+    assert any(re.search(r"%_deq_call(\.\d+)? = f32\[32,12288\]", l) for l in calls)
+    for line in calls:
+        if "%_expert_call" in line:
+            assert moe_experts.shape({"hlo": line}) in ((24, 7168, 2048),
+                                                        (24, 2048, 7168))
+        if "%_paged_latent" in line:
+            assert paged_attention_latent.shape({"hlo": line}) == (32, 64, "bf16")
+    pool_layer = 161 * 128 * 640 * 2  # a layer's slice of the latent pool
+    # the one thing of that size a layer moves is W_kvb's float32 slice, cut
+    # out of its stack for the absorb and expand products (33.6 MB a layer and
+    # step, what `mla_proj_small_ops_busy_share` reads; ROADMAP Reach 2)
+    w_kvb = 64 * (128 + 128) * 512 * 4
+    assert {m[-1] for m in pool_copies.big_movers(text, pool_layer)} <= {w_kvb}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

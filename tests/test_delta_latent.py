@@ -114,7 +114,7 @@ def test_a_header_without_the_new_keys_means_what_it_meant():
         LlamaConfig(dim=64, hidden_dim=128, n_layers=2, n_heads=4, n_kv_heads=2,
                     vocab_size=100, seq_len=32, layer_kinds=(1, 2), kda_heads=2,
                     kda_rank=8)
-    with pytest.raises(ValueError):  # latent attention that would rotate
+    with pytest.raises(ValueError):  # rotated latent attention with no shared dims
         LlamaConfig(dim=64, hidden_dim=128, n_layers=2, n_heads=4, n_kv_heads=2,
                     vocab_size=100, seq_len=32, layer_kinds=(3, 3), kv_lora_rank=8)
     with pytest.raises(ValueError):  # a share outside the experts

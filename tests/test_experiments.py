@@ -118,6 +118,16 @@ def test_warm_compile_smoke():
     assert "decode n1" not in p.stdout and "hybrid_pen" not in p.stdout
 
 
+def test_walk_check_smoke():
+    """The reference's look at a layout's greedy stream (which token stands
+    out after each), at the tiny size: the file is written, the reference
+    runs and the successor's logit is reported by position."""
+    p = _run(["experiments/walk_check.py", "benchmark/tests/tiny-axk1.json",
+              "--tokens", "40"])
+    assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
+    assert "successor is the argmax at" in p.stdout and "by position:" in p.stdout
+
+
 def test_collectives_table_smoke():
     p = _run(["experiments/collectives_table.py", "--smoke"])
     assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
