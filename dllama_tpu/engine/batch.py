@@ -900,6 +900,16 @@ class BatchEngine:
         self.attn_route = sel.route
         self._paged_route = sel.attn_route
         self._state_step = sel.state_step
+        # the latent sweep's pass as `_plan` sizes it from this engine's
+        # shapes (`/debug/perf` names it; None off the kernel's latent route)
+        self.latent_plan = None
+        if cfg.latent and sel.attn_route.startswith("paged_kernel"):
+            from dllama_tpu.ops.pallas.paged_attention import latent_plan
+
+            self.latent_plan = latent_plan(
+                cfg.n_heads, cfg.cache_row, self.page_size,
+                jnp.dtype(cache_dtype if cache_dtype is not None
+                          else jnp.bfloat16).itemsize, max_prefill_chunk)
         self.pool: PagePool | None = None
         self.wpool: PagePool | None = None  # the windowed layers' pool
         if kv_layout == "paged":

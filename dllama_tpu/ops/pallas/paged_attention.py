@@ -58,7 +58,18 @@ SNIPPETS.md [1]/[2]'s `pltpu.PrefetchScalarGridSpec` scalar-prefetch idiom):
   layer scan carries that one buffer through every layer's aliased call, so
   no layer's slice is cut out of the stack before its call or written back
   after it — the same reason the matmuls DMA-index the weight stacks. A
-  per-layer pool (``layer=None``) is the same call with first page 0.
+  per-layer pool (``layer=None``) is the same call with first page 0;
+* a LATENT pool (one row a token, key and value of every head, ``Hkv = 1``)
+  is swept several pages a PASS (PR 48): a page's pass there is a
+  [tq, page] score tile against 160 KB of rows, and its fixed cost (the
+  wait, two products, the lane reductions, the rescale: a serial chain that
+  does not overlap the next page's) took 0.63 us a page against 0.20 us of
+  bytes. The copies stay a page each (a slot's pages are scattered), ``pp``
+  of them land side by side in one ring slot, and the pass is ONE score
+  product, ONE softmax update and ONE value product over ``pp * page``
+  keys; dead pages of a slot's last pass are not copied and are masked.
+  ``pp`` comes from :func:`_plan`; every other call takes a page a pass and
+  is the program it was.
 
 Numerics are the same online-softmax (flash) formulation as
 ``flash_attention._kernel``: f32 accumulation, large-finite mask fill, one
@@ -106,6 +117,19 @@ _VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 #: hundred KB in flight the HBM is covered; every slot of the ring costs an
 #: unrolled conditional DMA start in the prologue.
 _MAX_DEPTH = 4
+#: Pages one pass of a LATENT sweep takes where the budget holds them
+#: (`_plan`): the smallest within 5% of the best row of the chip's sweep at
+#: A.X-K1's decode shape (32 slots x 64 heads, 24-72 pages a slot; ms a
+#: call at 1 / 2 / 3 / 4 / 6 / 8 / 10 / 12 / 16 pages: 1.004 / 0.680 /
+#: 0.555 / 0.506 / 0.463 / 0.436 / 0.432 / 0.426 / 0.434), which is also
+#: the best row at Kimi-Linear's (48 x 32 heads, 1-9 pages: 0.168 / 0.140 /
+#: 0.125 / 0.119 / 0.122 / 0.116 / 0.116 / 0.123 / 0.139) and within 4% of
+#: the best of a 512-row slice's q tiles of 128 (4.85 / 3.58 / 3.16 / 2.80
+#: / 2.81 / 2.64 / 2.69 / 2.54 / 3.01), so tq and the table's width need no
+#: say in it (`experiments/kbench.py paged --latent`, PERF.md section 6,
+#: PR 48). The pass's pages are walked by loops, so the kernel's traced size
+#: (warm-up time) does not grow with this number.
+_LATENT_PASS_PAGES = 8
 _Q_TILE_MAX = 128  # folded q rows one grid step takes (and one MXU pass)
 
 _LANES = 128  # TPU vector lane count: the minor-dim tile of every memref
@@ -175,28 +199,63 @@ def _fuses(t: int, rows: int) -> bool:
 
 
 def _plan(hkv: int, page: int, lanes: int, itemsize: int, tq: int, t: int,
-          budget: int) -> tuple[int, int, int]:
-    """(hb, depth, bytes): the kv heads one grid step serves, the landing
-    ring's depth in (k, v) pairs, and the VMEM that plan needs — functions
-    of the call's shapes and dtype alone.
+          budget: int, latent: bool = False) -> tuple[int, int, int, int]:
+    """(hb, depth, pp, bytes): the kv heads one grid step serves, the landing
+    ring's depth in passes, the pages one pass of the sweep consumes, and
+    the VMEM that plan needs — functions of the call's shapes and dtype
+    alone.
 
-    A head costs ``depth`` (k, v) page pairs of ring, its f32 accumulator
-    row block with m and l, the f32 copies of its ``t`` new rows, and the
-    sweep's per-page temporaries. ``hb`` is the largest divisor of ``hkv``
-    whose ring of three pairs fits the budget (two in flight behind the one
-    in the MXU); the depth then grows into what is left, up to
-    ``_MAX_DEPTH``. A page too large for any of it gets ``hb = 1`` at depth
-    2, which is what `paged_decode_supported` admits."""
+    A head costs ``depth`` passes of ring, its f32 accumulator row block
+    with m and l, the f32 copies of its ``t`` new rows, and the sweep's
+    per-pass temporaries. ``hb`` is the largest divisor of ``hkv`` whose
+    ring of three passes fits the budget (two in flight behind the one in
+    the MXU); the depth then grows into what is left, up to ``_MAX_DEPTH``.
+    A page too large for any of it gets ``hb = 1`` at depth 2, which is
+    what `paged_decode_supported` admits.
+
+    A pass is one page of (k, v) pairs, except for a LATENT sweep (one
+    pool, ``hkv = 1``): its page is a [tq, page] score tile against 160 KB
+    of rows, so the pass's fixed cost (a wait, two products, the lane
+    reductions and the rescale, none of which overlaps the next pass's)
+    is paid for every 128 rows; there a pass takes
+    ``_LATENT_PASS_PAGES`` pages (fewer if the budget holds no ring of two
+    such passes), and the ring is two passes deep: one pass's copies in
+    flight behind the one in the MXU, which is ``pp`` pages where a page a
+    pass keeps ``depth - 1``."""
+    state = tq * (lanes + 2 * _LANES) + 2 * t * lanes  # acc, m, l, new rows
+    if latent:
+        one = page * lanes * itemsize  # a latent row lands once, k and v
+        need = lambda pp: 2 * pp * one + (  # the ring; s, p, widened rows
+            state + 2 * tq * pp * page + pp * page * lanes) * 4
+        pp = max((p for p in range(2, _LATENT_PASS_PAGES + 1)
+                  if need(p) <= budget), default=1)
+        if pp > 1:
+            return 1, 2, pp, need(pp)
     pair = 2 * page * lanes * itemsize
-    fixed = (tq * (lanes + 2 * _LANES) + 2 * t * lanes  # acc, m, l, new rows
-             + 2 * tq * page + 2 * page * lanes) * 4  # s, p, widened k / v
+    fixed = (state + 2 * tq * page + 2 * page * lanes) * 4  # s, p, widened k / v
     need = lambda hb, depth: hb * (depth * pair + fixed)
     hb = max((d for d in range(1, hkv + 1)
               if hkv % d == 0 and need(d, 3) <= budget),
              default=1)
     depth = max((d for d in range(2, _MAX_DEPTH + 1) if need(hb, d) <= budget),
                 default=2)
-    return hb, depth, need(hb, depth)
+    return hb, depth, 1, need(hb, depth)
+
+
+def latent_plan(heads: int, row: int, page: int, itemsize: int,
+                chunk: int) -> dict:
+    """What :func:`_plan` gives an engine's two latent calls, for the
+    program's own report (`/debug/perf`): the decode step's (t = 1, the
+    heads one q tile) and a prefill slice's of `chunk` rows (q tiles of
+    `_q_tile`, scattered by XLA first)."""
+    def one(t):
+        rows = -(-t * heads // 8) * 8
+        _, depth, pp, nbytes = _plan(1, page, pool_lanes(row), itemsize,
+                                     _q_tile(rows), t if _fuses(t, rows) else 1,
+                                     _VMEM_BUDGET_BYTES, True)
+        return {"pages_per_pass": pp, "ring_passes": depth, "vmem_bytes": nbytes}
+
+    return {"decode": one(1), "slice": one(chunk)}
 
 
 def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
@@ -206,10 +265,19 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
             kbuf, vbuf, newk32, newv32, acc_ref, m_ref, l_ref, base_ref,
             copy_sems, write_sems,
             *, scale, page, group, t, tq, rows_live, nb, fused, hb, depth,
-            mxu_dtype, window=None, latent=False):
+            mxu_dtype, window=None, latent=False, pp=1):
     b, hblk, iq = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nbatch, nhb, nq = pl.num_programs(0), pl.num_programs(1), pl.num_programs(2)
     lanes = kbuf.shape[-1]
+    # A PASS of the sweep is `pp` consecutive pages of the run: one wait,
+    # one score product against `span` keys, one softmax update, one value
+    # product. The ring, the run indices and `base` count passes; a pass's
+    # page k lands in rows k*page.. of its ring slot. pp = 1 (every call
+    # but a latent one, `_plan`) is a page a pass and the program it always
+    # was: each `pp == 1` below keeps that program's equations as they were.
+    assert pp == 1 or (latent and window is None), (pp, latent, window)
+    span = pp * page
+    passes = lambda pages: pages if pp == 1 else (pages + (pp - 1)) // pp
 
     def sweep_pages(bb, qq):
         # live-page horizon of q tile qq of slot bb (mirrors
@@ -241,12 +309,15 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
     else:
         lo = sweep_first(b, iq, n_sweep)
         n_sweep = n_sweep - lo  # pages of the run; run page i is block lo + i
+    n_pass = passes(n_sweep)  # the sweep's passes; the last may be part dead
     if fused:
         live = wpages_ref[b, 0] == tables_ref[b, blk(0)]
-        n = n_sweep + jnp.where(live, 0, 1)
-        # the block a row lands in, as an index of this step's page run
+        n = n_pass + jnp.where(live, 0, 1)
+        # the block a row lands in, as an index of this step's page run;
+        # the trash page is page 0 of a pass of its own behind the sweep
         run_of = (lambda blk_: blk_) if window is None else (lambda blk_: blk_ - lo)
-        target = lambda tt: jnp.where(live, run_of(blk(tt)), n_sweep)
+        trash = n_sweep if pp == 1 else n_pass * pp
+        target = lambda tt: jnp.where(live, run_of(blk(tt)), trash)
         # Mosaic cannot DMA a dynamically-offset single sublane row, so a
         # row is blended in VMEM: an f32 `where` (sub-32-bit sublane
         # broadcasts don't lower; bf16<->f32 round-trips exactly) over the
@@ -255,20 +326,25 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
         win = 32 // jnp.dtype(kbuf.dtype).itemsize
         win = page if page % win else win
     else:
-        n = n_sweep
+        n = n_pass
 
-    # ---- the page run this step consumes, and the successor's: the ring
-    # does not drain between grid steps. Run index v < n is this step's
-    # page v; n <= v < n + n2 is sweep page v - n of the NEXT grid step
+    # ---- the run of passes this step consumes, and the successor's: the
+    # ring does not drain between grid steps. Run index v < n is this step's
+    # pass v; n <= v < n + n2 is sweep pass v - n of the NEXT grid step
     # (next q tile, head block or slot: its pos and block table are scalar
     # prefetch, so they are known; never its trash page, which this step
-    # may still be writing). Run page v lands in ring slot
+    # may still be writing). Run pass v lands in ring slot
     # (base + v) % depth; `base` is carried across steps in SMEM.
     first = (b == 0) & (hblk == 0) & (iq == 0)
 
     @pl.when(first)
     def _():
         base_ref[0] = 0
+        if pp > 1:
+            # a dead page of a part-filled pass is never copied: what its
+            # rows of the ring hold meets p = 0 in the value product, and
+            # must be finite (afterwards: an older pass's rows)
+            kbuf[...] = jnp.zeros_like(kbuf)
 
     base = base_ref[0]
     wrap_q = iq == nq - 1
@@ -277,13 +353,13 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
     hblk2 = jnp.where(wrap_h, 0, jnp.where(wrap_q, hblk + 1, hblk))
     b2 = jnp.minimum(jnp.where(wrap_h, b + 1, b), nbatch - 1)
     last_step = wrap_h & (b == nbatch - 1)  # no successor to fetch for
-    n2 = sweep_pages(b2, iq2)
+    n2_sweep = sweep_pages(b2, iq2)
     if window is None:
         lo2 = 0
     else:
-        lo2 = sweep_first(b2, iq2, n2)
-        n2 = n2 - lo2
-    n2 = jnp.where(last_step, 0, n2)
+        lo2 = sweep_first(b2, iq2, n2_sweep)
+        n2_sweep = n2_sweep - lo2
+    n2 = jnp.where(last_step, 0, passes(n2_sweep))
 
     def page_id(bb, i, own):
         # defensive clamp like _paged_cache_update: a horizon past the
@@ -294,31 +370,58 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
             pg = jnp.where(own & (i >= n_sweep), wpages_ref[b, 0], pg)
         return pg
 
-    def copies(pg, hh, slot, back=False):
+    def copies(pg, hh, slot, k=0, back=False):
         heads = pl.ds(hh * hb, hb)  # [hb, page, lanes]: contiguous in HBM
         # a latent row is key AND value: one pool, one copy a page
         pairs = (((kpool_ref, kbuf),) if latent
                  else ((kpool_ref, kbuf), (vpool_ref, vbuf)))
+        # where page k of a pass lands, and the semaphores of its copies
+        land = lambda buf: buf.at[slot] if pp == 1 else buf.at[
+            slot, :, pl.ds(pl.multiple_of(k * page, page), page), :]
         if back:
-            return [pltpu.make_async_copy(buf.at[slot], pool.at[pg, heads],
-                                          write_sems.at[j])
+            return [pltpu.make_async_copy(land(buf), pool.at[pg, heads],
+                                          write_sems.at[k * 2 + j])
                     for j, (pool, buf) in enumerate(pairs)]
-        return [pltpu.make_async_copy(pool.at[pg, heads], buf.at[slot],
-                                      copy_sems.at[slot, j])
+        sem = slot if pp == 1 else slot * pp + k
+        return [pltpu.make_async_copy(pool.at[pg, heads], land(buf),
+                                      copy_sems.at[sem, j])
                 for j, (pool, buf) in enumerate(pairs)]
+
+    def each_page(i, pages, fn, first_page=lambda: 0):
+        """fn(k, page index) for the pages of pass i of a sweep of `pages()`
+        pages, from `first_page()` on: page 0 of a pass always is (a sweep's
+        pass holds a page; the trash pass is its page 0), a later one where
+        the sweep reaches it: a dead page costs no copy. A loop, not an
+        unrolled run of conditionals: the traced size is warm-up time and
+        must not grow with `pp`. (The bounds are thunks: a page a pass has
+        the one page and traces neither.)"""
+        if pp == 1:
+            return fn(0, i)
+
+        def one(k, _):
+            fn(k, i * pp + k)
+            return 0
+
+        jax.lax.fori_loop(first_page(), jnp.clip(pages() - i * pp, 1, pp),
+                          one, 0)
 
     def start(v):
         mine = v < n
 
         @pl.when(v < n + n2)
         def _():
-            pg = page_id(jnp.where(mine, b, b2), jnp.where(mine, v, v - n), mine)
-            for cp in copies(pg, jnp.where(mine, hblk, hblk2),
-                             jax.lax.rem(base + v, depth)):
-                cp.start()
+            bb, i = jnp.where(mine, b, b2), jnp.where(mine, v, v - n)
+
+            def go(k, ix):
+                pg = page_id(bb, ix, mine)
+                for cp in copies(pg, jnp.where(mine, hblk, hblk2),
+                                 jax.lax.rem(base + v, depth), k):
+                    cp.start()
+
+            each_page(i, lambda: jnp.where(mine, n_sweep, n2_sweep), go)
 
     def prologue(v, _):  # what no predecessor started for this step
-        @pl.when(first | (v >= n_sweep))
+        @pl.when(first | (v >= n_pass))
         def _():
             start(v)
         return 0
@@ -339,27 +442,51 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
     # causal mask against absolute cache positions: query row r of tile iq
     # holds token offset (iq*tq + r) // group (t-major GQA fold)
     qpos = pos_b + (iq * tq + jax.lax.broadcasted_iota(
-        jnp.int32, (tq, page), 0)) // group
-    col = jax.lax.broadcasted_iota(jnp.int32, (tq, page), 1)
+        jnp.int32, (tq, span), 0)) // group
+    col = jax.lax.broadcasted_iota(jnp.int32, (tq, span), 1)
 
     def body(i, _):
         slot = jax.lax.rem(base + i, depth)
-        start(i + depth - 1)  # into the slot page i-1 just left
-        here = page_id(b, i, True)
-        for cp in copies(here, hblk, slot):
-            cp.wait()
+        start(i + depth - 1)  # into the slot pass i-1 just left
+
+        if pp == 1:
+            here = page_id(b, i, True)
+        pid = lambda ix: here if pp == 1 else page_id(b, ix, True)
+
+        def landed(k, ix):
+            for cp in copies(pid(ix), hblk, slot, k):
+                cp.wait()
+
+        each_page(i, lambda: n_sweep, landed)
 
         if fused:
             # rows are blended in order, so a duplicate (page, offset)
             # target — a clipped table, or trash collisions when
             # t > page_size — resolves last-row-wins
-            wrote = i >= target(0)
-            backs = copies(here, hblk, slot, back=True)
+            first_target = target(0)
+            if pp == 1:
+                wrote = i >= first_target
+                back0 = copies(here, hblk, slot, back=True)
+            else:  # the pass holds a page at or behind the first target
+                wrote = i >= first_target // pp
+
+            def each_back(do):
+                # ONE write a page that received rows: every page of the
+                # run from the first target on (the sweep ends on the last)
+                def go(k, ix):
+                    for wr in back0 if pp == 1 else copies(
+                            pid(ix), hblk, slot, k, back=True):
+                        do(wr)
+
+                each_page(i, lambda: n_sweep, go,
+                          lambda: jnp.clip(first_target - i * pp, 0, pp))
 
             def blend(tt, _):
-                @pl.when(target(tt) == i)
+                @pl.when((target(tt) if pp == 1 else target(tt) // pp) == i)
                 def _():
                     off = woffs_ref[b, tt]
+                    if pp > 1:  # the target page's rows of the pass
+                        off = off + jax.lax.rem(target(tt), pp) * page
                     r0 = pl.multiple_of(off // win * win, win)
                     window = (slot, slice(None), pl.ds(r0, win), slice(None))
                     sel = jax.lax.broadcasted_iota(
@@ -374,23 +501,28 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
             @pl.when(wrote)
             def _():
                 jax.lax.fori_loop(0, t, blend, 0)
-                for wr in backs:  # ONE write a pool, under this page's dots
-                    wr.start()
+                # under this pass's dots
+                each_back(lambda wr: wr.start())
 
-        k = kbuf[slot]  # [hb, page, lanes]
+        k = kbuf[slot]  # [hb, span, lanes]
         v = k if latent else vbuf[slot]  # the landed page read ONCE
         # q and K enter the MXU as the bfloat16 they are stored as where
         # both are (a product of two bfloat16 values is exact in float32);
         # a float32 q or pool keeps float32 operands
         s = jax.lax.dot_general(q, mxu(k), (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32)
-        s = s * scale  # [hb, tq, page]
-        if window is None:
+        s = s * scale  # [hb, tq, span]
+        if pp > 1:
+            # the sweep's live pages alone: a part-filled pass's dead pages
+            # and the trash pass lie at or past n_sweep * page
+            key = i * span + col
+            mask = (key <= qpos) & (key < n_sweep * page)
+        elif window is None:
             mask = i * page + col <= qpos
         else:
             key = (lo + i) * page + col
             mask = (key <= qpos) & (key > qpos - window)
-        if fused:  # the trash page behind an inactive slot's sweep
+        if fused and pp == 1:  # the trash page behind an inactive slot's sweep
             mask = mask & (i < n_sweep)
         mask = mask[None]
         s = jnp.where(mask, s, _NEG_INF)
@@ -410,8 +542,7 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
         if fused:
             @pl.when(wrote)  # before the ring hands this slot out again
             def _():
-                for wr in backs:
-                    wr.wait()
+                each_back(lambda wr: wr.wait())
         return 0
 
     jax.lax.fori_loop(0, n, body, 0)
@@ -447,8 +578,8 @@ def _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
     t = new_k.shape[2]
     tq = _q_tile(rows)
     assert not fused or _fuses(t, rows), (t, group, rows)
-    hb, depth, _ = _plan(hkv, page, hd, k_pool.dtype.itemsize, tq, t,
-                         vmem_budget)
+    hb, depth, pp, _ = _plan(hkv, page, hd, k_pool.dtype.itemsize, tq, t,
+                             vmem_budget, latent)
     grid = (b, hkv // hb, rows // tq)
     bf16 = jnp.dtype(jnp.bfloat16)
     mxu_dtype = bf16 if qf.dtype == bf16 == k_pool.dtype else jnp.dtype(
@@ -473,7 +604,7 @@ def _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
             any_spec,
         ],
         scratch_shapes=[
-            pltpu.VMEM((depth, hb, page, hd), k_pool.dtype),  # k landing ring
+            pltpu.VMEM((depth, hb, pp * page, hd), k_pool.dtype),  # k landing ring
             pltpu.VMEM((1, 1, 8, _LANES) if latent else (depth, hb, page, hd),
                        v_pool.dtype),
             pltpu.VMEM((hb, t, hd), jnp.float32),  # the new k rows, widened
@@ -482,15 +613,16 @@ def _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
             pltpu.VMEM((hb, tq, _LANES), jnp.float32),  # m
             pltpu.VMEM((hb, tq, _LANES), jnp.float32),  # l
             pltpu.SMEM((1,), jnp.int32),  # ring slot of this step's page 0
-            pltpu.SemaphoreType.DMA((depth, 2)),  # (ring slot, k/v) copies
-            pltpu.SemaphoreType.DMA((2,)),  # k/v scatter write-backs
+            pltpu.SemaphoreType.DMA((depth * pp, 2)),  # (landed page, k/v) copies
+            pltpu.SemaphoreType.DMA((2 * pp,)),  # k/v scatter write-backs a page
         ],
     )
     out, k_pool, v_pool = pl.pallas_call(
         functools.partial(_kernel, scale=scale, page=page,
                           group=group, t=t, tq=tq, rows_live=rows_live,
                           nb=nb, fused=fused, hb=hb, depth=depth,
-                          mxu_dtype=mxu_dtype, window=window, latent=latent),
+                          mxu_dtype=mxu_dtype, window=window, latent=latent,
+                          pp=pp),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, rows, hd), jnp.float32),

@@ -1287,6 +1287,11 @@ class RequestRoutes:
             payload["spec"] = (sched.engine.spec_stats()
                                if hasattr(sched.engine, "spec_stats")
                                else None)
+            # the latent sweep's plan (pages a pass, the ring's passes, VMEM
+            # bytes; decode call and slice), fixed when the engine was built
+            # from its shapes; None where no latent call runs on the kernel
+            payload["paged_latent_plan"] = getattr(sched.engine, "latent_plan",
+                                                   None)
             # hybrid chunked-prefill + preemption state (ISSUE 12): the
             # live budget and the lifetime preempt/resume record
             payload["hybrid"] = {

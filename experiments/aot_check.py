@@ -55,7 +55,7 @@ S = jax.ShapeDtypeStruct
 
 TARGET = "v5e:2x2"
 #: paged-attention case name -> (hb, depth, VMEM bytes) its shapes were given
-PAGED_PLANS: dict[str, tuple[int, int, int]] = {}
+PAGED_PLANS: dict[str, tuple[int, int, int, int]] = {}
 
 
 def topology():
@@ -234,6 +234,33 @@ def cases(full: bool):
     paged("paged decode t=1 p=128 b=48 Hkv=8 hd=64 layer-indexed stack "
           "(granite4h.reason_closed)", 128, b=48, hq=32, hkv=8, hd=64,
           pages=456, stacked=True)
+
+    # the latent sweep (one pool, Hkv = 1, the row is key and value, several
+    # pages a pass: `_plan`) at the two latent cells' shapes: A.X-K1's decode
+    # call and its 512-row slice (256 q tiles, scattered by XLA first) and
+    # Kimi-Linear's decode call, each on the layer-stacked pool
+    def latent(name, b, t, hq, layers, pages, nb, rank=512, pe=64, page=128):
+        w, lanes = rank + pe, pool_lanes(rank + pe)
+        args = (S((b, t, hq, w), jnp.bfloat16),
+                S((layers, pages + 1, 1, page, lanes), jnp.bfloat16),
+                S((layers, 1, 1, 8, 128), jnp.bfloat16), S((b, nb), jnp.int32),
+                S((b,), jnp.int32), S((b, 1, t, w), jnp.bfloat16),
+                S((b,), jnp.bool_), S((), jnp.int32))
+        fn = lambda q, pool, ph, tb, pos, new, act, li: paged_decode_attention(
+            q, pool, ph, tb, pos, new, None, act, layer=li, interpret=False,
+            latent=rank, scale=w ** -0.5)
+        rows = -(-t * hq // 8) * 8
+        PAGED_PLANS[name] = pa._plan(1, page, lanes, 2, pa._q_tile(rows),
+                                     t if pa._fuses(t, rows) else 1,
+                                     pa._VMEM_BUDGET_BYTES, True)
+        out.append((name, fn, args, True))
+
+    latent("paged latent decode t=1 p=128 b=32 64 heads x 576 layer-indexed "
+           "stack (axk1.long_reason_closed)", 32, 1, 64, 9, 2368, 128)
+    latent("paged latent slice t=512 p=128 64 heads x 576 layer-indexed stack "
+           "(axk1.long_reason_closed, XLA pre-scatter)", 1, 512, 64, 9, 2368, 128)
+    latent("paged latent decode t=1 p=128 b=48 32 heads x 576 layer-indexed "
+           "stack (kimilinear.reason_closed)", 48, 1, 32, 7, 456, 64)
 
     from dllama_tpu.ops.pallas.rms_norm import rms_norm as prms
 
@@ -985,13 +1012,15 @@ def main():
                     f"{verdict.split(chr(10))[0][:120]} |\n")
         f.write("\n## The paged kernel's plan for each case\n\n"
                 "`ops/pallas/paged_attention._plan`: kv heads a grid step "
-                "serves, (k, v) pairs in the landing ring, and the VMEM the "
+                "serves, passes in the landing ring, the pages a pass takes "
+                "(1 but for a latent sweep), and the VMEM the "
                 "plan counts (ring + f32 accumulator, m, l + per-page "
                 "temporaries) — functions of the shapes and dtype alone.\n\n"
-                "| case | hb | depth | VMEM bytes |\n|---|---|---|---|\n")
-        for cname, (hb, depth, nbytes) in PAGED_PLANS.items():
-            f.write(f"| {cname} | {hb} | {depth} | {nbytes:,} |\n")
-            print(f"paged plan | {cname}: hb={hb} depth={depth} "
+                "| case | hb | depth (passes) | pages a pass | VMEM bytes |\n"
+                "|---|---|---|---|---|\n")
+        for cname, (hb, depth, pp, nbytes) in PAGED_PLANS.items():
+            f.write(f"| {cname} | {hb} | {depth} | {pp} | {nbytes:,} |\n")
+            print(f"paged plan | {cname}: hb={hb} depth={depth} pp={pp} "
                   f"vmem={nbytes:,} B")
         f.write("\n## Operands XLA copies into VMEM ahead of a kernel\n\n"
                 "`slice-start` instructions of each whole step program (XLA's "

@@ -297,6 +297,11 @@ def enable_smoke():
                      act="relu", layers=2, rows={"decode": 3, "slice": 160}),
         "tiny share": dict(held=4, routed=8, active=3, d=256, f=256, sigmoid=True, scale=2.5,
                            act="silu", layers=2, rows={"decode": 6})}
+    global PAGED_LATENT_CELLS, PAGED_LATENT_PP, PAGED_SLICE_CALLS
+    PAGED_LATENT_PP, PAGED_SLICE_CALLS = (2,), 2
+    PAGED_LATENT_CELLS = {
+        "tiny latent": dict(slots=3, hq=8, rank=64, pe=32, page=8, layers=2,
+                            kv_pages=16, rows=(3, 70), slice=(24, 20))}
     PAGED_CALLS = 2
     PAGED_CELLS = {
         "tiny mha": dict(slots=2, hq=4, hkv=4, hd=64, page=16, layers=2,
@@ -500,6 +505,160 @@ def bench_paged_decode(cells=None, calls=None, rows=None):
                 print(f"paged decode {name} {label}: FAILED {e!r}"[:300])
             sys.stdout.flush()
 
+
+
+#: The latent cells' sweep (one pool, Hkv = 1, the row is key and value):
+#: each cell's decode call and A.X-K1's hybrid slice (rows, at context).
+PAGED_LATENT_CELLS = {
+    "kimilinear.reason_closed": dict(
+        slots=48, hq=32, rank=512, pe=64, page=128, layers=7, kv_pages=456,
+        rows=(100, 1100)),
+    "axk1.long_reason_closed": dict(
+        slots=32, hq=64, rank=512, pe=64, page=128, layers=9, kv_pages=2368,
+        rows=(3000, 9200), slice=(512, 2300)),
+}
+PAGED_LATENT_PP = (2, 3, 4, 6, 8)  # pages a pass, each on a ring of two passes
+PAGED_SLICE_CALLS = 40
+
+
+def bench_paged_latent(cells=None, calls=None, slice_calls=None, sweep=None):
+    """`_paged_latent` alone, as a decode step calls it (t = 1, fused
+    scatter, the layer cycling over the stacked pool, the pool threaded
+    through one jitted scan) and as a hybrid launch's slice does: ms a call
+    beside the rows' bytes (`benchmark/costs/paged_attention_latent.py`),
+    parity against float64, and the pass fill share (live pages over
+    pp x passes). Rows: the plan as `_plan` gives it, the parent's body (a
+    page a pass, ring of 4: `_plan`'s answer for any call that is not a
+    latent one) and the pages-a-pass sweep. What each part of the page-a-pass
+    body costs is in PERF.md section 7 (PR 45's ablations), not here."""
+    from dllama_tpu.ops.pallas import paged_attention as pa
+
+    real_plan = pa._plan
+
+    @contextlib.contextmanager
+    def planned(pp):
+        """`_plan` answering `pp` pages a pass for the rows traced inside
+        (None: as it is; 1: the page-a-pass plan of every other call)."""
+        def plan(*a):
+            if pp == 1:
+                return real_plan(*a[:7])
+            hb, depth, _, nbytes = real_plan(*a)
+            return hb, depth, pp, nbytes
+        try:
+            if pp is not None:
+                pa._plan = plan
+            pa._paged_latent.clear_cache()
+            yield
+        finally:
+            pa._plan = real_plan
+            pa._paged_latent.clear_cache()
+
+    for name, c in (cells or PAGED_LATENT_CELLS).items():
+        b, hq, rank, page = c["slots"], c["hq"], c["rank"], c["page"]
+        w = rank + c["pe"]
+        lanes, scale = pa.pool_lanes(w), w ** -0.5
+        lo, hi = c["rows"]
+        nb = -(-(hi + 1) // page)
+        n_pool = max(c["kv_pages"], b * nb) + 1  # + the trash page
+        rng = np.random.default_rng(0)
+        one = rng.standard_normal((n_pool, page, lanes), np.float32)
+        pool0 = jnp.asarray(one[None, :, None], jnp.bfloat16) * jnp.ones(
+            (c["layers"], 1, 1, 1, 1), jnp.bfloat16)
+        placeholder = jnp.zeros((c["layers"], 1, 1, 8, 128), jnp.bfloat16)
+        tables = rng.permutation(n_pool - 1)[: b * nb].reshape(b, nb)
+        pos = np.linspace(lo, hi, b).astype(np.int32)
+        q = jnp.asarray(rng.standard_normal((b, 1, hq, w)), jnp.bfloat16)
+        new = jnp.asarray(rng.standard_normal((b, 1, 1, w)), jnp.bfloat16)
+        plan = real_plan(1, page, lanes, 2, pa._q_tile(-(-hq // 8) * 8), 1,
+                         pa._VMEM_BUDGET_BYTES, True)
+        print(f"paged latent {name}: slots {b} x {hq} q heads x {w} "
+              f"({lanes} lanes), rows {lo}-{hi}; _plan: {plan[2]} pages a "
+              f"pass, ring of {plan[1]} passes, {plan[3]:,} B of VMEM")
+
+        def sweep_of(q, pool, tables, pos, new, li):
+            return pa.paged_decode_attention(
+                q, pool, placeholder, tables, pos, new, None, None, layer=li,
+                interpret=INTERPRET, latent=rank, scale=scale)
+
+        def float64(q, pos, new, tables):
+            """The float64 form of layer 0's call: [b, t, hq, rank]."""
+            f64 = lambda x: np.asarray(x.astype(jnp.float32), np.float64)
+            q, new = f64(q), f64(new)
+            rows64 = f64(jnp.asarray(one, jnp.bfloat16))
+            out = np.zeros(q.shape[:3] + (rank,))
+            for bi in range(q.shape[0]):
+                t = q.shape[1]
+                n = int(pos[bi]) + t
+                rows = rows64[tables[bi]].reshape(-1, lanes)[:n, :w].copy()
+                rows[n - t:] = new[bi, 0]
+                sc = np.einsum("thw,sw->ths", q[bi], rows) * scale
+                keep = np.arange(n)[None, :] <= int(pos[bi]) + np.arange(t)[:, None]
+                sc = np.where(keep[:, None], sc, -np.inf)
+                pr = np.exp(sc - sc.max(-1, keepdims=True))
+                out[bi] = np.einsum("ths,sr->thr", pr / pr.sum(-1, keepdims=True),
+                                    rows[:, :rank])
+            return out
+
+        def timed(label, forced, n_calls, q, tables, pos, new, needed, want):
+            pp = forced or plan[2]
+            live = -(-(np.asarray(pos) + 1) // page)  # pages a decode step sweeps
+            fill = "" if q.shape[1] > 1 else (
+                f"; pass fill {live.sum() / (pp * (-(-live // pp)).sum()):.1%}")
+            tables_d, pos_d = jnp.asarray(tables, jnp.int32), jnp.asarray(pos)
+
+            @jax.jit
+            def loop(q, pool):
+                def step(carry, i):
+                    pool, acc = carry
+                    out, pool, _ = sweep_of(q, pool, tables_d, pos_d, new,
+                                            i % c["layers"])
+                    return (pool, acc + out.astype(jnp.float32).sum()), None
+                return jax.lax.scan(step, (pool, jnp.float32(0)),
+                                    jnp.arange(n_calls, dtype=jnp.int32))[0]
+
+            try:
+                with planned(forced):
+                    got = sweep_of(q.astype(jnp.float32), pool0, tables_d,
+                                   pos_d, new, jnp.int32(0))[0]
+                    err = np.abs(np.asarray(got, np.float64) - want).max()
+                    pool, acc = loop(q, pool0)  # compiles; the pool stays threaded
+                    jax.block_until_ready(acc)
+                    t0 = time.perf_counter()
+                    pool, acc = loop(q, pool)
+                    jax.block_until_ready(acc)
+                ms = (time.perf_counter() - t0) / n_calls * 1e3
+                print(f"paged latent {name} {label}: {ms:.4f} ms a call over "
+                      f"{n_calls} calls; rows' bytes {needed / 1e6:.1f} MB = "
+                      f"{needed / HBM_GBS / 1e3:.1f} us at {HBM_GBS:.0f} GB/s "
+                      f"({needed / HBM_GBS / 1e4 / ms:.1f}% of the call); max "
+                      f"|diff| against float64 {err:.3g} of max |out| "
+                      f"{np.abs(want).max():.3g}{fill}")
+            except Exception as e:
+                print(f"paged latent {name} {label}: FAILED {e!r}"[:300])
+            sys.stdout.flush()
+
+        shapes = [("decode", calls or PAGED_CALLS, q, tables, pos, new)]
+        if "slice" in c:  # one slot's slice: scattered by XLA, many q tiles
+            t, at = c["slice"]
+            shapes.append((
+                f"slice of {t} rows at {at} ({t * hq // pa._q_tile(t * hq)} q tiles)",
+                slice_calls or PAGED_SLICE_CALLS,
+                jnp.asarray(rng.standard_normal((1, t, hq, w)), jnp.bfloat16),
+                tables[:1], np.asarray([at], np.int32),
+                jnp.asarray(rng.standard_normal((1, 1, t, w)), jnp.bfloat16)))
+        for shape, n_calls, q_, tables_, pos_, new_ in shapes:
+            t = q_.shape[1]
+            # the rows the call needs ONCE (a slice's q tiles each read the
+            # context's pages again: that is the kernel's cost, not the floor)
+            needed = (float((pos_ + t).sum()) * w * 2
+                      + q_.shape[0] * t * hq * (w * 2 + rank * 4))
+            want = float64(q_, pos_, new_, tables_)
+            rows = [(f"{shape}, as it is ({plan[2]} pages a pass)", None),
+                    (f"{shape}, the parent's body (a page a pass, ring of 4)", 1)]
+            rows += [(f"{shape}, {pp} pages a pass", pp)
+                     for pp in (PAGED_LATENT_PP if sweep is None else sweep)]
+            for label, pp in rows:
+                timed(label, pp, n_calls, q_, tables_, pos_, new_, needed, want)
 
 
 # ------------------------------------------------------------------ q40 mode
@@ -1464,7 +1623,9 @@ def main():
         print("KBENCH DONE")
         return
     if sys.argv[1:2] == ["paged"]:
-        bench_paged_decode()
+        if "--latent" not in sys.argv:  # (--latent: the latent cells alone)
+            bench_paged_decode()
+        bench_paged_latent()
         print("KBENCH DONE")
         return
     if sys.argv[1:2] == ["q40"]:

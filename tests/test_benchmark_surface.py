@@ -182,6 +182,8 @@ def _perf(s, tiny):
     assert set(doc["roofline"]) == {"throughput_tok_s", "goodput_tok_s"}
     assert doc["roofline"]["goodput_tok_s"] > 0
     assert {"window", "slo", "ledger"} <= set(doc)
+    # the latent sweep's plan is named where an engine has one (PR 48)
+    assert doc["paged_latent_plan"] is None
 
 
 def _compile(s, tiny):

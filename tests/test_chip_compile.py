@@ -56,6 +56,13 @@ CASES = (
     "(deepseek7b.decode_closed)",
     "paged decode t=1 p=128 b=48 Hkv=8 hd=64 layer-indexed stack "
     "(granite4h.reason_closed)",
+    # the latent sweep's several pages a pass at the two latent cells' shapes
+    "paged latent decode t=1 p=128 b=32 64 heads x 576 layer-indexed stack "
+    "(axk1.long_reason_closed)",
+    "paged latent slice t=512 p=128 64 heads x 576 layer-indexed stack "
+    "(axk1.long_reason_closed, XLA pre-scatter)",
+    "paged latent decode t=1 p=128 b=48 32 heads x 576 layer-indexed stack "
+    "(kimilinear.reason_closed)",
     "tp=4 shard_map mm in-shard+psum (w2)",
     "serve 1b paged decode chunk n=4",
     "serve 1b hybrid step p=64 n=4",

@@ -329,29 +329,34 @@ def test_the_pallas_step_is_the_step_in_place_on_the_stack(kda_rows):
 # ------------------------------------------------------- the latent page
 
 
+@pytest.mark.parametrize("pos", [(0, 5, 17), (63, 71, 160)],
+                         ids=["1-3-pages", "8-21-pages"])
 @pytest.mark.parametrize("t", [1, 24])
-def test_the_latent_sweep_reads_one_row_for_score_and_value(t):
+def test_the_latent_sweep_reads_one_row_for_score_and_value(t, pos):
     """The paged kernel's latent mode (interpret) against the jnp form: a
     decode step with the fused scatter (t = 1) and a prefill slice
-    scattered by XLA first (t = 24), slots at different lengths."""
+    scattered by XLA first (t = 24), slots at different lengths: runs of
+    1 to 3 pages (less than one pass of the sweep) and of 8 to 21 (a whole
+    pass, one page past it, two passes and a part of the third: PR 48)."""
     rng = np.random.default_rng(2)
-    b, h, rank, pe, page, nb, layers = 3, 8, 64, 32, 8, 8, 2
-    w, lanes = rank + pe, 128
+    b, h, rank, pe, page, nb, layers = 3, 8, 64, 32, 8, 24, 2
+    w, lanes, span = rank + pe, 128, 24 * 8
     f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
     pool = jnp.zeros((layers, b * nb + 1, 1, page, lanes), jnp.float32)
     placeholder = jnp.zeros((layers, 1, 1, 8, 128), jnp.float32)
     tables = jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
-    pos = jnp.asarray([0, 5, 17], jnp.int32)
-    rows = f(b, 64, w)  # the history, written row by row into layer 1
+    pos = jnp.asarray(pos, jnp.int32)
+    rows = f(b, span, w)  # the history, written row by row into layer 1
     for bi in range(b):
-        for r in range(int(pos[bi])):
-            pool = pool.at[1, tables[bi, r // page], 0, r % page, :w].set(rows[bi, r])
+        n = int(pos[bi])
+        paged = jnp.zeros((nb * page, lanes)).at[:n, :w].set(rows[bi, :n])
+        pool = pool.at[1, tables[bi], 0].set(paged.reshape(nb, page, lanes))
     q, new = f(b, t, h, w), f(b, 1, t, w)
     out, pool2, ph2 = paged_decode_attention(
         q, pool, placeholder, tables, pos, new, None, None, layer=1,
         interpret=True, latent=rank, scale=0.125)
     hist = jnp.stack([jnp.concatenate(
-        [rows[bi, :int(pos[bi])], new[bi, 0], jnp.zeros((64 - int(pos[bi]), w))])[:64 + t]
+        [rows[bi, :int(pos[bi])], new[bi, 0], jnp.zeros((span - int(pos[bi]), w))])[:span + t]
         for bi in range(b)])
     want = latent_attention(q, hist, pos, 0.125, rank)
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)

@@ -44,6 +44,10 @@ def test_kbench_paged_smoke():
     assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
     assert "KBENCH DONE" in p.stdout and "FAILED" not in p.stdout, p.stdout
     assert p.stdout.count("fused scatter:") == p.stdout.count("read-only:") == 2
+    # the latent cells' sweep (PR 48): decode and slice, each as it is, as
+    # the parent's page-a-pass body and over the pages-a-pass sweep
+    assert p.stdout.count("the parent's body (a page a pass") == 2
+    assert p.stdout.count("pass fill") == 3 and "against float64" in p.stdout
 
 
 def test_kbench_q40_smoke():

@@ -649,6 +649,26 @@ def test_the_engine_counts_tokens_and_kept_groups(tiny):
     assert read - read0 == sum((p + i + 1) for p in (20, 33) for i in range(8))
 
 
+@pytest.mark.parametrize("kernels,attn,named", [
+    ("xla", "jnp", False), ("pallas", "flash", True)])
+def test_the_engine_names_the_latent_sweeps_plan(tiny, kernels, attn, named):
+    """What `/debug/perf` reports as `paged_latent_plan`: the pass `_plan`
+    sizes from the engine's shapes, for the decode call and the slice, on
+    the kernel's latent route; None where the rows are gathered by XLA."""
+    from dllama_tpu.ops.pallas import paged_attention as pa
+
+    be = BatchEngine(tiny.config, tiny.params, cache_dtype=jnp.float32,
+                     max_seq_len=256, kernels=kernels, attn_impl=attn, **ENGINE)
+    if not named:
+        assert be.latent_plan is None
+        return
+    assert set(be.latent_plan) == {"decode", "slice"}
+    for plan in be.latent_plan.values():
+        assert plan["pages_per_pass"] == pa._LATENT_PASS_PAGES
+        assert plan["ring_passes"] == 2 and 0 < plan["vmem_bytes"] <= pa._VMEM_BUDGET_BYTES
+    assert be.latent_plan["slice"]["vmem_bytes"] > be.latent_plan["decode"]["vmem_bytes"]
+
+
 def test_a_shared_prefix_of_rotated_rows_is_the_cold_run(tiny):
     """The radix cache over the latent pool (the first model that has both:
     a recurrent state resolves it off): a prefix's pages hold rows rotated

@@ -389,18 +389,200 @@ def test_bfloat16_pool_takes_bfloat16_qk_operands(rng):
     ("deepseek-llm-7b decode", 32, 128, 2, 8, (8, 32)),
     ("granite-4.0-h-micro decode", 8, 128, 2, 8, (8, 8)),
     ("deepseek-llm-7b prefill chunk", 32, 128, 2, 128, (4, 32)),
+    ("smallthinker-21b-a3b decode, global and window", 4, 128, 2, 8, (4, 4)),
+    ("smallthinker-21b-a3b 512-row slice", 4, 128, 2, 128, (4, 4)),
+    ("laguna-xs.2 decode, global fold 6 and window fold 8", 8, 128, 2, 8, (8, 8)),
+    ("laguna-xs.2 256-row slice", 8, 128, 2, 128, (8, 8)),
     ("a page only one head of fits", 8, 4096, 2, 8, (1, 1)),
 ])
 def test_plan_is_a_function_of_shapes(name, hkv, page, itemsize, tq, hb_range):
-    """The two cells' decode calls get head blocks of 8-32 heads (256 KB to
+    """The cells' decode calls get head blocks of 4-32 heads (256 KB to
     1 MB a copy); a prefill chunk's accumulator takes its share; a page too
-    large for anything else gets hb = 1 at depth 2."""
-    hb, depth, nbytes = pa._plan(hkv, page, 128, itemsize, tq, 1,
-                                 pa._VMEM_BUDGET_BYTES)
+    large for anything else gets hb = 1 at depth 2. Every call that is not
+    a latent one takes a page a pass."""
+    hb, depth, pp, nbytes = pa._plan(hkv, page, 128, itemsize, tq, 1,
+                                     pa._VMEM_BUDGET_BYTES)
     assert hkv % hb == 0 and hb_range[0] <= hb <= hb_range[1], (name, hb)
-    assert 2 <= depth <= pa._MAX_DEPTH
+    assert 2 <= depth <= pa._MAX_DEPTH and pp == 1
     assert nbytes <= pa._VMEM_BUDGET_BYTES or (hb, depth) == (1, 2)
     assert paged_decode_supported((hkv, 128), page)
+
+
+@pytest.mark.parametrize("name,tq,t", [
+    ("a.x-k1 decode: 64 heads", 64, 1),
+    ("a.x-k1 512-row slice: q tiles of 128", 128, 1),
+    ("kimi-linear decode: 32 heads", 32, 1),
+    ("kimi-linear 64-row slice", 128, 1),
+    ("a.x-k1 verify chunk of 2 (one q tile)", 128, 2),
+])
+def test_plan_of_the_latent_sweep(name, tq, t):
+    """A latent call (one pool, Hkv = 1, 128-row pages of 640 lanes) takes
+    `_LATENT_PASS_PAGES` pages a pass on a ring two passes deep at both
+    cells' shapes; a budget too small for that ring gives fewer pages a
+    pass, and one that holds no pass of two pages the page-a-pass plan of
+    every other call."""
+    plan = lambda budget, latent=True: pa._plan(1, 128, 640, 2, tq, t, budget,
+                                                latent)
+    hb, depth, pp, nbytes = plan(pa._VMEM_BUDGET_BYTES)
+    assert (hb, depth, pp) == (1, 2, pa._LATENT_PASS_PAGES), name
+    assert nbytes <= pa._VMEM_BUDGET_BYTES
+    # the pass's temporaries are in the count: s and p [tq, pp * page], the
+    # widened rows [pp * page, lanes] f32, and the ring's 2 * pp pages
+    assert nbytes >= (2 * tq * pp * 128 + pp * 128 * 640) * 4 + 2 * pp * 128 * 640 * 2
+    pps = [plan(b)[2] for b in range(0, pa._VMEM_BUDGET_BYTES, 100_000)]
+    assert pps == sorted(pps) and pps[0] == 1 and set(pps) >= {1, 2, pa._LATENT_PASS_PAGES}
+    assert plan(1) == plan(1, latent=False)  # hb = 1, depth 2, a page a pass
+    assert plan(pa._VMEM_BUDGET_BYTES, latent=False)[2] == 1
+
+
+# ------------------------------------------------- the latent sweep's passes
+# One pool whose row is key and value (Hkv = 1), `pp` pages a pass (PR 48).
+# Reference: the jnp form over the gathered rows, and the pool as the
+# row-by-row scatter leaves it (last row wins, inactive slots to the trash).
+
+PP = pa._LATENT_PASS_PAGES
+L_PAGE, L_NB, L_RANK, L_W, L_LANES = 8, 2 * PP + 4, 64, 96, 128
+
+
+def _latent_case(rng, pos, t=1, h=8, active=None, dtype=jnp.float32,
+                 fused=True):
+    b = len(pos)
+    npool = b * L_NB + 1
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), dtype)
+    pool = f(npool, 1, L_PAGE, L_LANES).at[..., L_W:].set(0)
+    tables = jnp.asarray(
+        rng.permutation(npool - 1)[: b * L_NB].reshape(b, L_NB), jnp.int32)
+    q, new = f(b, t, h, L_W), f(b, 1, t, L_W)
+    return (q, pool, tables, jnp.asarray(pos, jnp.int32), new,
+            None if active is None else jnp.asarray(active))
+
+
+def _latent_reference(q, pool, tables, pos, new, active):
+    from dllama_tpu.ops.layers import (latent_attention, paged_view,
+                                       paged_write_targets)
+
+    b, t = q.shape[:2]
+    wpages, woffs = paged_write_targets(tables, pos, t, L_PAGE, pool.shape[0],
+                                        active)
+    want_pool = np.array(pool)
+    for bi in range(b):
+        for tt in range(t):  # in order: a duplicate target keeps the last row
+            want_pool[wpages[bi, tt], 0, woffs[bi, tt], :L_W] = new[bi, 0, tt]
+    rows = paged_view(jnp.asarray(want_pool), tables)[:, 0, :, :L_W]
+    return latent_attention(q, rows, pos, 0.125, L_RANK), want_pool
+
+
+def _assert_latent_matches(case, atol=2e-5, **kw):
+    q, pool, tables, pos, new, active = case
+    want, want_pool = _latent_reference(*case)
+    placeholder = jnp.zeros((1, 1, 8, 128), pool.dtype)
+    out, pool2, ph2 = paged_decode_attention(
+        q, pool, placeholder, tables, pos, new, None, active, interpret=True,
+        latent=L_RANK, scale=0.125, **kw)
+    np.testing.assert_array_equal(np.asarray(pool2, np.float32),
+                                  np.asarray(want_pool, np.float32))
+    live = slice(None) if active is None else np.asarray(active)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(want, np.float32)[live],
+                               atol=atol, rtol=atol)
+    assert out.shape == q.shape[:3] + (L_RANK,) and ph2.shape == placeholder.shape
+    return out, pool2
+
+
+def _rows_at(pages, row):
+    """The position whose decode step sweeps `pages` pages and writes its
+    new row at `row` of the last."""
+    return (pages - 1) * L_PAGE + row
+
+
+@pytest.mark.parametrize("pages", [1, PP - 1, PP, PP + 1, 2 * PP, 2 * PP + 1],
+                         ids=lambda n: f"{n}-pages")
+@pytest.mark.parametrize("row", [0, L_PAGE - 1], ids=["first-row", "last-row"])
+def test_latent_runs_of_whole_and_part_filled_passes(rng, pages, row):
+    """A decode step (t = 1, fused scatter) over a run one page short of a
+    pass, a whole pass, one page into the next, two passes and one page
+    past them, and of one page, the new row on its page's first and last
+    row: a part-filled pass's dead pages are out of the softmax. The other
+    slots stand at lengths of their own, so passes of every fill follow one
+    another through the ring."""
+    assert pa._plan(1, L_PAGE, L_LANES, 4, 8, 1, pa._VMEM_BUDGET_BYTES,
+                    True)[1:3] == (2, PP)
+    pos = [_rows_at(pages, row), _rows_at(1, 3), _rows_at(PP + 2, row)]
+    _assert_latent_matches(_latent_case(rng, pos))
+
+
+@pytest.mark.parametrize("t", [1, 5, 16])
+@pytest.mark.parametrize("at", ["page", "pass"])
+def test_latent_rows_cross_a_page_and_a_pass_boundary(rng, t, at):
+    """The fused scatter inside a pass: a verify chunk's rows (t = 5: two
+    pages; t = 16 = 2 pages' rows: three pages) start three rows before a
+    page's end that is inside a pass, and before one that ends a pass, so
+    the written pages sit in one pass or in two; each is written back once
+    and the sweep reads the blended copy."""
+    edge = (PP if at == "pass" else PP - 1) * L_PAGE
+    pos = [edge - 3, edge - 3 + PP * L_PAGE, edge - 1]
+    _assert_latent_matches(_latent_case(rng, pos, t=t))
+
+
+@pytest.mark.parametrize("t", [1, 5])
+def test_latent_trash_pass_next_to_live_slots(rng, t):
+    """An inactive slot's rows go to the trash page, a pass of its own
+    behind the slot's sweep (masked), next to live slots; the last slot's
+    chunk is clipped at the table's end."""
+    pos = [_rows_at(PP, 2), _rows_at(PP + 1, 5), _rows_at(2, 0),
+           L_NB * L_PAGE - 2]
+    case = _latent_case(rng, pos, t=t, active=[False, True, False, True])
+    _, pool2 = _assert_latent_matches(case)
+    np.testing.assert_array_equal(  # rows pos % page .. of the trash page
+        np.asarray(pool2[-1, 0, 0:t, :L_W]), np.asarray(case[4][2, 0]))
+
+
+def test_latent_bfloat16_pool_and_q(rng):
+    """bfloat16 q against a bfloat16 pool: both enter the score product as
+    stored; p, the softmax state and the accumulator stay float32 (the
+    result differs from the float32 form by q's and the output's rounding
+    alone)."""
+    pos = [_rows_at(2 * PP + 1, 4), _rows_at(PP, 7), 0]
+    _assert_latent_matches(_latent_case(rng, pos, t=2, dtype=jnp.bfloat16),
+                           atol=2e-2)
+
+
+@pytest.mark.parametrize("pp", [1, 2, PP],
+                         ids=["a-page-a-pass", "two-pages", "whole-pass"])
+@pytest.mark.parametrize("t", [1, 24], ids=["decode", "slice"])
+def test_latent_ring_carries_over_slots_and_q_tiles(rng, monkeypatch, pp, t):
+    """The ring of passes does not drain between grid steps: decode steps
+    over slots of 1 to 2 pp + 2 pages, and a slice (t = 24 x 8 heads: three
+    q tiles of 64 rows, scattered by XLA first) whose q tiles each sweep the
+    run again; with the budget forced small the same call takes two pages a
+    pass, and a page a pass at depth 2 (every other call's plan)."""
+    rows = 8 if t == 1 else pa._q_tile(t * 8)
+    assert t == 1 or t * 8 // rows == 3
+    plan = lambda budget: pa._plan(1, L_PAGE, L_LANES, 4, rows, 1, budget, True)
+    budget = next(b for b in (1, *range(10_000, 1_000_000, 10_000),
+                              pa._VMEM_BUDGET_BYTES) if plan(b)[2] == pp)
+    monkeypatch.setattr(pa, "_VMEM_BUDGET_BYTES", budget)
+    assert plan(budget)[:3] == (1, 2, pp)
+    pos = [0, _rows_at(2 * PP + 2, 1) - t, 3, _rows_at(PP, 7) - t + 1,
+           _rows_at(PP + 1, 0)]
+    _assert_latent_matches(_latent_case(rng, pos, t=t))
+
+
+def test_latent_layer_indexed_stack(rng):
+    """The same passes on the layer-stacked pool, the layer as data: the
+    layer's pages are read and written IN the stack and the other layers'
+    are left as they were."""
+    q, pool, tables, pos, new, _ = case = _latent_case(
+        rng, [_rows_at(PP + 1, 7), _rows_at(2 * PP, 0)], t=3)
+    _, want_pool = _latent_reference(*case)
+    stack = jnp.stack([pool * 2, pool, pool * 3])
+    out, stack2, _ = paged_decode_attention(
+        q, stack, jnp.zeros((3, 1, 1, 8, 128)), tables, pos, new, None, None,
+        layer=jnp.int32(1), interpret=True, latent=L_RANK, scale=0.125)
+    want, _ = _assert_latent_matches(case)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(stack2[1]), want_pool)
+    np.testing.assert_array_equal(np.asarray(stack2[::2]), np.asarray(stack[::2]))
 
 
 def test_capability_check():
