@@ -1,34 +1,34 @@
-"""AOT compile check for the chip's compiler — no TPU attached.
+"""The case table of the offline compile check — no TPU attached.
 
 libtpu is installed locally, and a PJRT topology description lets XLA:TPU
 compile a lowered module for a chip that is described, not attached
-(`jax.experimental.topologies.get_topology_desc`). This script offers every
-Pallas kernel (the Q40 block-dot kernel at the benchmark cells' own decode
-shapes too), the shard_map'd tensor-parallel paths,
-the whole InferenceEngine step and the whole BatchEngine serving programs
-(paged decode chunk, hybrid step, prefill chunk, spec-verify chunk) to the
-v5e compiler at Llama-3.2-1B width and records ACCEPT or REJECT per case.
-v5e is the one chip there is to run what gets accepted, so it is the one
-target.
+(`jax.experimental.topologies.get_topology_desc`). This module builds the
+cases: every Pallas kernel (the Q40 kernels and the paged sweeps at the
+benchmark cells' own shapes too), the shard_map'd tensor-parallel paths, the
+whole InferenceEngine step, the whole BatchEngine serving programs (paged
+decode chunk, hybrid step, prefill chunk, spec-verify chunk) at Llama-3.2-1B
+width, and the step programs of the five served architectures at their
+published widths. v5e is the one chip there is to run what gets accepted,
+so it is the one target.
 
-ACCEPT means Mosaic/XLA:TPU compiled the program to machine code for the
-chip — interpret mode cannot show a misaligned slice, a VMEM overflow or a
-program that does not fit HBM; this can. Nothing runs, so it says nothing
-about results or times: `python chip_smoke.py` on the chip does.
+`tests/test_chip_compile.py` compiles every one of them (`pytest
+tests/test_chip_compile.py -k '<case>'` is the command line): a case that
+compiles was turned into machine code for the chip by Mosaic/XLA:TPU —
+interpret mode cannot show a misaligned slice, a VMEM overflow or a program
+that does not fit HBM; this can. Nothing runs, so it says nothing about
+results or times: `python chip_smoke.py` on the chip does, and
+`experiments/warm_compile.py` offers a cell's programs to the chip's own
+compiler. Every case is something the program can be told to run, and a
+refusal fails the test.
 
 Code that asks the platform (kernels=auto, interpret=) sees the CPU here, so
-main() points `ops.matmul.device_platform` at "tpu" for the whole run;
-tests/test_chip_compile.py compiles a subset of `all_cases()` the same way.
-
-Usage: python experiments/aot_check.py [--full] [--md MOSAIC_AOT.md]
-Exit 0 when every production-default case accepts (the others — reserve
-kernels, `--full`'s tile overrides — are flagged but not fatal).
+whoever compiles these cases points `ops.matmul.device_platform` at "tpu"
+first (the test's fixture; `experiments/pool_copies.py`'s main()).
 """
 
+import dataclasses
 import os
-import re
 import sys
-import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # topology-AOT needs no TPU attached, and off GCP the instance-metadata
@@ -36,10 +36,9 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
 # libtpu lets one process at a time load it (/tmp/libtpu_lockfile) — right
-# for a process that drives a chip, wrong for compile-only use: pytest holds
-# it for its lifetime once tests/test_chip_compile.py has described the
-# topology, and the aot_check.py subprocess of tests/test_experiments.py
-# must still start
+# for a process that drives a chip, wrong for compile-only use: a pytest
+# worker holds it for its lifetime once it has described the topology, and
+# the next worker (or experiments/pool_copies.py) must still start
 os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 
 import jax
@@ -54,8 +53,6 @@ S = jax.ShapeDtypeStruct
 
 
 TARGET = "v5e:2x2"
-#: paged-attention case name -> (hb, depth, VMEM bytes) its shapes were given
-PAGED_PLANS: dict[str, tuple[int, int, int, int]] = {}
 
 
 def topology():
@@ -80,42 +77,114 @@ HD_8B = 128  # Llama-3.1-8B / OLMoE head size: whole 128-lane rows
 SLOTS, SEQ, SPEC_K = 8, 2048, 4  # chip_smoke.py's serve flags
 
 
-def cases(full: bool):
-    """Kernel-level (name, fn, abstract args, production) tuples at the
-    widths above; `production` marks kernels whose rejection fails the
-    check (the shipped defaults), vs fallback insurance."""
+#: every case all_cases() builds, by name and in its order: what
+#: tests/test_chip_compile.py parametrises over (building the cases needs the
+#: described topology and two engines, so the names stand here, and a test
+#: holds them equal to what is built)
+CASES = (
+    "q40 decode m=8 w1(2048x8192)",
+    "q40 decode m=8 w2(8192x2048)",
+    "q40 decode m=8 wcls(2048x128256)",
+    "q40 prefill m=256 w1(2048x8192)",
+    "q40 prefill m=256 w2(8192x2048)",
+    "q40 prefill m=256 wcls(2048x128256)",
+    "q40 decode m=8 wk(2048x512)",
+    "q40 spec-verify m=40 w1",
+    "q40 decode m=8 wcls8b(4096x128256)",
+    "q40 prefill m=256 wcls8b(4096x128256)",
+    "q40 decode m=16 deepseek wq(4096x4096)",
+    "q40 decode m=16 deepseek w1(4096x11008)",
+    "q40 decode m=16 deepseek w2(11008x4096)",
+    "q40 decode m=16 deepseek head(4096x102400)",
+    "q40 decode m=8 granite head(2048x100352)",
+    "q40 decode m=8 granite in_proj(2048x8576)",
+    "q40 m=48 granite in_proj(2048x8576)",
+    "q40 m=48 granite out_proj(4096x2048)",
+    "q40 m=48 granite w1(2048x8192)",
+    "q40 m=48 granite w2(8192x2048)",
+    "q40 m=48 granite head(2048x100352)",
+    "q40 m=512 smallthinker wq(2560x3584)",
+    "q40 m=128 deepseek w2(11008x4096)",
+    "q80 decode m=8 w1(2048x8192)",
+    "q80 prefill m=256 w1(2048x8192)",
+    "q80 decode m=8 wcls8b(4096x128256)",
+    "flash decode t=1 S=2048 hd=64",
+    "flash prefill t=256 S=2048 hd=64",
+    "flash decode t=1 S=1024 hd=128",
+    "flash prefill t=256 S=1024 hd=128",
+    "flash decode t=1 S=8192 hd=128",
+    "flash decode f8 KV cache hd=128",
+    "flash decode bucketed S=8192 hd=128",
+    "paged decode t=1 p=128 hd=64 fused scatter",
+    "paged spec verify t=5 p=128 hd=64 fused scatter",
+    "paged decode t=1 p=16 hd=64 fused scatter",
+    "paged decode t=1 p=8 hd=64 fused scatter",
+    "paged decode t=1 p=24 (odd page) hd=64 fused scatter",
+    "paged prefill t=256 p=128 hd=64 (XLA pre-scatter)",
+    "paged decode t=1 p=128 hd=64 read-only sweep",
+    "paged decode t=1 p=128 hd=128 fused scatter",
+    "paged decode t=1 p=128 hd=64 layer-indexed stack",
+    "paged prefill t=256 p=128 hd=64 layer-indexed stack (XLA pre-scatter)",
+    "paged spec verify t=9 p=128 hd=128 fused scatter",
+    "paged decode t=1 p=128 b=12 Hkv=32 hd=128 layer-indexed stack "
+    "(deepseek7b.decode_closed)",
+    "paged decode t=1 p=128 b=48 Hkv=8 hd=64 layer-indexed stack "
+    "(granite4h.reason_closed)",
+    "paged latent decode t=1 p=128 b=32 64 heads x 576 layer-indexed stack "
+    "(axk1.long_reason_closed)",
+    "paged latent slice t=512 p=128 64 heads x 576 layer-indexed stack "
+    "(axk1.long_reason_closed, XLA pre-scatter)",
+    "paged latent decode t=1 p=128 b=48 32 heads x 576 layer-indexed stack "
+    "(kimilinear.reason_closed)",
+    "moe sort (8 experts, 64 tokens)",
+    "moe dispatch (8 experts, 64 tokens)",
+    "moe dense (8 experts, 64 tokens)",
+    "tp=4 shard_map mm out-shard (w1)",
+    "tp=4 shard_map mm in-shard+psum (w2)",
+    "tp=4 shard_map head-sharded flash",
+    "FULL 1b tp=4 engine step t=256",
+    "FULL 1b tp=4 engine step t=1",
+    "FULL 1b decode step (scan+flash+blockdot)",
+    "FULL 1b speculative decode (k=8 while_loop)",
+    "serve 1b paged decode chunk n=4",
+    "serve 1b hybrid step p=64 n=4",
+    "serve 1b paged prefill chunk m=256",
+    "serve 1b paged prefill chunk m=1",
+    "serve 1b spec-verify chunk K=4 m=4",
+    "serve 1b paged penalized decode chunk n=4",
+    "serve olmoe-width 64-slot paged decode chunk n=4",
+)
+
+
+def cases():
+    """Kernel-level (name, fn, abstract args) tuples at the widths above."""
     L = 2
     layer = S((1,), jnp.int32)
     out = []
 
-    def q40_case(name, m, k, n, production, layers=L, **tiles):
+    def q40_case(name, m, k, n, layers=L):
         packed, scales = (S((layers, k // 2, n), jnp.uint8),
                           S((layers, k // Q_BLOCK, n), jnp.uint16))
-
-        def fn(l, x, p, s):
-            if tiles:  # the chip sweep's overrides, on the jitted call itself
-                return qmod._blockdot_call(l, x, p, s, **tiles)
-            return qmod.q40_matmul(x, QTensor(p, s), l)
-
-        out.append((name, fn, (layer, S((m, k), jnp.bfloat16), packed, scales), production))
+        out.append((name, lambda l, x, p, s: qmod.q40_matmul(x, QTensor(p, s), l),
+                    (layer, S((m, k), jnp.bfloat16), packed, scales)))
 
     # UNSTACKED 2-D weights with f16 scales and no layer index: byte-for-byte
     # the operands a loaded .m file's wcls runs with
     def flat_case(name, m, k, n):
         out.append((name, lambda x, p, s: qmod.q40_matmul(x, QTensor(p, s)),
                     (S((m, k), jnp.bfloat16), S((k // 2, n), jnp.uint8),
-                     S((k // Q_BLOCK, n), jnp.float16)), True))
+                     S((k // Q_BLOCK, n), jnp.float16))))
 
     # decode rows = serving slots, prefill rows = the 256-token chunk cap;
     # the dispatcher takes blockdot (m <= 16) / deq (m > 16)
     for m in (SLOTS, 256):
         tier = "decode" if m <= 16 else "prefill"
-        q40_case(f"q40 {tier} m={m} w1({DIM}x{HIDDEN})", m, DIM, HIDDEN, True)
-        q40_case(f"q40 {tier} m={m} w2({HIDDEN}x{DIM})", m, HIDDEN, DIM, True)
+        q40_case(f"q40 {tier} m={m} w1({DIM}x{HIDDEN})", m, DIM, HIDDEN)
+        q40_case(f"q40 {tier} m={m} w2({HIDDEN}x{DIM})", m, HIDDEN, DIM)
         flat_case(f"q40 {tier} m={m} wcls({DIM}x{VOCAB})", m, DIM, VOCAB)
-    q40_case(f"q40 decode m={SLOTS} wk({DIM}x{HKV * HD})", SLOTS, DIM, HKV * HD, True)
+    q40_case(f"q40 decode m={SLOTS} wk({DIM}x{HKV * HD})", SLOTS, DIM, HKV * HD)
     q40_case(f"q40 spec-verify m={SLOTS * (SPEC_K + 1)} w1",
-               SLOTS * (SPEC_K + 1), DIM, HIDDEN, True)
+             SLOTS * (SPEC_K + 1), DIM, HIDDEN)
     flat_case("q40 decode m=8 wcls8b(4096x128256)", 8, 4096, 128256)
     flat_case("q40 prefill m=256 wcls8b(4096x128256)", 256, 4096, 128256)
     # the m <= 16 kernel at the benchmark cells' own decode shapes (PERF.md
@@ -124,8 +193,7 @@ def cases(full: bool):
             ("deepseek wq", 16, 4096, 4096, 30), ("deepseek w1", 16, 4096, 11008, 30),
             ("deepseek w2", 16, 11008, 4096, 30), ("deepseek head", 16, 4096, 102400, 1),
             ("granite head", 8, 2048, 100352, 1), ("granite in_proj", 8, 2048, 8576, 40)):
-        q40_case(f"q40 decode m={m} {tag}({k}x{n})", m, k, n, True,
-                   layers=layers)
+        q40_case(f"q40 decode m={m} {tag}({k}x{n})", m, k, n, layers=layers)
     # the dequantising tier (m > 16) at the cells' own shapes: Granite's 48
     # slots, its head, a SmallThinker slice, a DeepSeek slice over k = 43 x 256
     for tag, m, k, n, layers in (
@@ -133,11 +201,7 @@ def cases(full: bool):
             ("granite w1", 48, 2048, 8192, 40), ("granite w2", 48, 8192, 2048, 40),
             ("granite head", 48, 2048, 100352, 1), ("smallthinker wq", 512, 2560, 3584, 24),
             ("deepseek w2", 128, 11008, 4096, 30)):
-        q40_case(f"q40 m={m} {tag}({k}x{n})", m, k, n, True, layers=layers)
-    if full:
-        for tn in (128, 256, 512, 1024, 2048):
-            q40_case(f"blockdot tiles tk={DIM} tn={tn}",
-                       16, DIM, HIDDEN, False, tk=DIM, tn=tn)
+        q40_case(f"q40 m={m} {tag}({k}x{n})", m, k, n, layers=layers)
 
     # q80 fused matmuls (packed int8 weights, the Q80-file fast path): the
     # same decode/prefill split as q40, production on unsharded engines
@@ -149,11 +213,11 @@ def cases(full: bool):
         out.append((f"q80 {'decode' if q8m <= 16 else 'prefill'} m={q8m} w1({DIM}x{HIDDEN})",
                     lambda x, l, c, s: q80_matmul(x, Q8Tensor(c, s), l),
                     (S((q8m, DIM), jnp.bfloat16), S((), jnp.int32),
-                     q8w.codes, q8w.scales), True))
+                     q8w.codes, q8w.scales)))
     out.append(("q80 decode m=8 wcls8b(4096x128256)",
                 lambda x, c, s: q80_matmul(x, Q8Tensor(c, s)),
                 (S((8, 4096), jnp.bfloat16), S((4096, 128256), jnp.int8),
-                 S((4096 // Q_BLOCK, 128256), jnp.float16)), True))
+                 S((4096 // Q_BLOCK, 128256), jnp.float16))))
 
     # flash attention over the dense cache: decode (t=1, group=4 folded+pad)
     # and prefill shapes, at the 1b head size and at whole-lane heads
@@ -163,7 +227,7 @@ def cases(full: bool):
         kv = S((1, HKV, s_len, hd), kv_dtype)
         out.append((name,
                     lambda q, k, v: flash_gqa_attention(q, k, v, jnp.int32(7), **kw),
-                    (S((1, t, HQ, hd), jnp.bfloat16), kv, kv), True))
+                    (S((1, t, HQ, hd), jnp.bfloat16), kv, kv)))
 
     flash(f"flash decode t=1 S={SEQ} hd={HD}", 1, SEQ, HD)
     flash(f"flash prefill t=256 S={SEQ} hd={HD}", 256, SEQ, HD)
@@ -190,8 +254,7 @@ def cases(full: bool):
     # stack" is the call as the decoder's layer scan makes it (the whole
     # [L, P, ...] pool and the layer as data). The last two are the decode
     # calls of the benchmark's two cells at their own shapes (PERF.md
-    # section 4). PAGED_PLANS keeps what `_plan` gave each case.
-    from dllama_tpu.ops.pallas import paged_attention as pa
+    # section 4).
     from dllama_tpu.ops.pallas.paged_attention import paged_decode_attention, pool_lanes
 
     def paged(name, page, t=1, b=SLOTS, hd=HD, read_only=False, stacked=False,
@@ -209,12 +272,7 @@ def cases(full: bool):
             args.append(S((), jnp.int32))
             fn = lambda *a: paged_decode_attention(*a[:-1], layer=a[-1],
                                                    interpret=False)
-        rows = -(-t * (hq // hkv) // 8) * 8
-        fused = not read_only and pa._fuses(t, rows)  # else the kernel sees no row
-        PAGED_PLANS[name] = pa._plan(hkv, page, pool_lanes(hd), 2,
-                                     pa._q_tile(rows), t if fused else 1,
-                                     pa._VMEM_BUDGET_BYTES)
-        out.append((name, fn, tuple(args), True))
+        out.append((name, fn, tuple(args)))
 
     paged(f"paged decode t=1 p=128 hd={HD} fused scatter", 128)
     paged(f"paged spec verify t={SPEC_K + 1} p=128 hd={HD} fused scatter", 128, t=SPEC_K + 1)
@@ -249,11 +307,7 @@ def cases(full: bool):
         fn = lambda q, pool, ph, tb, pos, new, act, li: paged_decode_attention(
             q, pool, ph, tb, pos, new, None, act, layer=li, interpret=False,
             latent=rank, scale=w ** -0.5)
-        rows = -(-t * hq // 8) * 8
-        PAGED_PLANS[name] = pa._plan(1, page, lanes, 2, pa._q_tile(rows),
-                                     t if pa._fuses(t, rows) else 1,
-                                     pa._VMEM_BUDGET_BYTES, True)
-        out.append((name, fn, args, True))
+        out.append((name, fn, args))
 
     latent("paged latent decode t=1 p=128 b=32 64 heads x 576 layer-indexed "
            "stack (axk1.long_reason_closed)", 32, 1, 64, 9, 2368, 128)
@@ -261,11 +315,6 @@ def cases(full: bool):
            "(axk1.long_reason_closed, XLA pre-scatter)", 1, 512, 64, 9, 2368, 128)
     latent("paged latent decode t=1 p=128 b=48 32 heads x 576 layer-indexed "
            "stack (kimilinear.reason_closed)", 48, 1, 32, 7, 456, 64)
-
-    from dllama_tpu.ops.pallas.rms_norm import rms_norm as prms
-
-    out.append(("rms_norm (reserve)", lambda x, w: prms(x, w, 1e-5),
-                (S((8, DIM), jnp.bfloat16), S((DIM,), jnp.bfloat16)), False))
 
     # MoE compute schemes: no Pallas inside, but `sort` leans on
     # lax.ragged_dot and `dispatch` on .at[].add scatters — both exotic
@@ -280,42 +329,99 @@ def cases(full: bool):
                 S((8, 1024, 2048), jnp.bfloat16),
                 S((8, 2048, 1024), jnp.bfloat16),
                 S((8, 1024, 2048), jnp.bfloat16))
-    # production flags follow the auto resolution: sort (n >= E) and dense
-    # (n < E, e.g. B=1 decode) are the shipped paths; dispatch is insurance
+    # the auto resolution takes sort (n >= E) and dense (n < E, e.g. B=1
+    # decode); `--moe dispatch` asks for the third
     for impl in ("sort", "dispatch", "dense"):
         out.append((f"moe {impl} (8 experts, 64 tokens)",
                     lambda h, g, w1, w2, w3, impl=impl: moe_ffn(
                         mcfg, h, g, w1, w2, w3, impl=impl),
-                    moe_args, impl != "dispatch"))
+                    moe_args))
     return out
 
 
-def _abstract_params(cfg, sharding_of):
-    """The dense-FFN param tree as shapes only, each leaf placed by the
-    sharding tree ``sharding_of(shapes)`` — what load_params hands an
-    engine: stacked layers, packed u8 nibbles + f16 scales as the .m file
-    stores them, bf16 embedding, f32 norms."""
+def abstract_params(cfg, A):
+    """The param tree of any served configuration as shapes only, each leaf
+    `A(shape, dtype)`: what models/formats.load_params hands an engine.
+    Stacked by layer, the mixers apart by kind (attention tensors by
+    `attn_suffix` where the windowed layers have heads of their own), dense
+    and expert feed-forward weights apart; packed u8 nibbles + f16 scales as
+    the .m file stores them, bf16 embedding, f32 norms; in_proj, kda_proj
+    and mla_kva zero-padded to whole lane tiles, W_kvb float32 by head."""
     def qw(lead, k, n):
-        return QTensor(S((*lead, k // 2, n), jnp.uint8),
-                       S((*lead, k // Q_BLOCK, n), jnp.float16))
+        return QTensor(A((*lead, k // 2, n), jnp.uint8),
+                       A((*lead, k // Q_BLOCK, n), jnp.float16))
 
-    L = (cfg.n_layers,)
-    shapes = {
-        "embedding": S((cfg.vocab_size, cfg.dim), jnp.bfloat16),
-        "final_norm": S((cfg.dim,), jnp.float32),
-        "wcls": qw((), cfg.dim, cfg.vocab_size),
-        "layers": {
-            "wq": qw(L, cfg.dim, cfg.dim), "wk": qw(L, cfg.dim, cfg.kv_dim),
-            "wv": qw(L, cfg.dim, cfg.kv_dim), "wo": qw(L, cfg.dim, cfg.dim),
-            "w1": qw(L, cfg.dim, cfg.hidden_dim),
-            "w2": qw(L, cfg.hidden_dim, cfg.dim),
-            "w3": qw(L, cfg.dim, cfg.hidden_dim),
-            "rms_att": S((*L, cfg.dim), jnp.float32),
-            "rms_ffn": S((*L, cfg.dim), jnp.float32),
-        },
-    }
-    return jax.tree.map(lambda a, sh: S(a.shape, a.dtype, sharding=sh),
-                        shapes, sharding_of(shapes))
+    f32 = lambda *shape: A(shape, jnp.float32)
+    pad = lambda n, to: -(-n // to) * to
+    L, d, h = cfg.n_layers, cfg.dim, cfg.n_heads
+    layers = {"rms_att": f32(L, d), "rms_ffn": f32(L, d)}
+    if cfg.latent:
+        Lm, qd = cfg.n_attn_layers, h * (cfg.qk_nope_dim + cfg.qk_pe_dim)
+        if cfg.q_lora_rank:
+            layers.update(mla_qa=qw((Lm,), d, cfg.q_lora_rank),
+                          mla_q_norm=f32(Lm, cfg.q_lora_rank),
+                          mla_qb=qw((Lm,), cfg.q_lora_rank, qd))
+        else:
+            layers["mla_q"] = qw((Lm,), d, qd)
+        layers.update(
+            mla_kva=qw((Lm,), d, pad(cfg.cache_row, 128)),
+            mla_kv_norm=f32(Lm, cfg.kv_lora_rank),
+            mla_kvb=f32(Lm, h, cfg.qk_nope_dim + cfg.v_head_dim, cfg.kv_lora_rank),
+            mla_o=qw((Lm,), h * cfg.v_head_dim, d))
+    else:
+        # stacked apart by kind where the windowed layers have heads of their own
+        La, Lw = cfg.n_attn_layers, cfg.n_window_layers
+        for windowed, n in ([(False, La - Lw), (True, Lw)] if cfg.window_heads
+                            else [(False, La)]):
+            sfx, ad = cfg.attn_suffix(windowed), cfg.attn_dim_of(windowed)
+            layers.update({
+                "wq" + sfx: qw((n,), d, ad), "wk" + sfx: qw((n,), d, cfg.kv_dim),
+                "wv" + sfx: qw((n,), d, cfg.kv_dim), "wo" + sfx: qw((n,), ad, d)})
+            if cfg.qk_norm:
+                layers.update({"q_norm" + sfx: f32(n, cfg.head_size),
+                               "k_norm" + sfx: f32(n, cfg.head_size)})
+            if cfg.attn_gate:
+                layers["attn_gate" + sfx] = f32(n, d, cfg.heads_of(windowed))
+    if cfg.n_ssm_layers:
+        Ls, cd = cfg.n_ssm_layers, cfg.ssm_conv_dim
+        layers.update(
+            in_proj=qw((Ls,), d, pad(cfg.ssm_in_proj, 128)),
+            conv_w=f32(Ls, cd, cfg.ssm_conv), conv_b=f32(Ls, cd),
+            dt_bias=f32(Ls, cfg.ssm_heads), a_log=f32(Ls, cfg.ssm_heads),
+            d=f32(Ls, cfg.ssm_heads), ssm_norm=f32(Ls, cfg.ssm_inner),
+            out_proj=qw((Ls,), cfg.ssm_inner, d))
+    if cfg.n_kda_layers:
+        Lk, inner, rank = cfg.n_kda_layers, cfg.kda_inner, cfg.kda_rank
+        layers.update(
+            kda_proj=qw((Lk,), d, pad(cfg.kda_proj, 512)),
+            kda_conv_w=f32(Lk, 3 * inner, cfg.kda_conv),
+            kda_fb=qw((Lk,), rank, inner), kda_gb=qw((Lk,), rank, inner),
+            kda_dt_bias=f32(Lk, inner), kda_a_log=f32(Lk, cfg.kda_heads),
+            kda_norm=f32(Lk, cfg.kda_head_dim), kda_o=qw((Lk,), inner, d))
+    Ld = cfg.n_dense_ffn_layers
+    if Ld:
+        layers.update(w1=qw((Ld,), d, cfg.hidden_dim), w2=qw((Ld,), cfg.hidden_dim, d),
+                      w3=qw((Ld,), d, cfg.hidden_dim))
+    if cfg.n_experts:
+        Le, w, E = L - Ld, cfg.expert_width, cfg.n_held_experts
+        layers.update(moe_gate=f32(Le, d, cfg.n_experts), moe_w1=qw((Le, E), d, w),
+                      moe_w2=qw((Le, E), w, d), moe_w3=qw((Le, E), d, w))
+        if cfg.router_sigmoid:
+            layers["moe_bias"] = f32(Le, cfg.n_experts)
+        if cfg.n_shared_experts:
+            layers.update(shared_w1=qw((Le,), d, w), shared_w2=qw((Le,), w, d),
+                          shared_w3=qw((Le,), d, w))
+    return {"embedding": A((cfg.vocab_size, d), jnp.bfloat16), "final_norm": f32(d),
+            "wcls": qw((), d, cfg.vocab_size), "layers": layers}
+
+
+def on_one_chip(topo):
+    """`A(shape, dtype)` for arguments pinned to one device of the described
+    topology, so that XLA:TPU (not Host) compiles the module."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dt: S(shape, dt, sharding=one)
 
 
 def _cfg_1b(seq_len=SEQ):
@@ -332,15 +438,12 @@ def full_step_case(topo):
     speculative decoder over it. Kernel-level acceptance can miss
     interactions (Mosaic custom calls inside lax.scan, donated buffers);
     this is the whole graph."""
-    from jax.sharding import SingleDeviceSharding
-
     from dllama_tpu.engine.kernel_select import resolve_kernels
     from dllama_tpu.models.llama import KVCache, forward
 
     cfg = _cfg_1b(1024)
-    one = SingleDeviceSharding(topo.devices[0])
-    A = lambda shape, dt: S(shape, dt, sharding=one)
-    params = _abstract_params(cfg, lambda t: jax.tree.map(lambda _: one, t))
+    A = on_one_chip(topo)
+    params = abstract_params(cfg, A)
     cshape = (cfg.n_layers, 1, cfg.n_kv_heads, cfg.seq_len, cfg.head_size)
     cache = KVCache(A(cshape, jnp.bfloat16), A(cshape, jnp.bfloat16))
     rope = A((cfg.seq_len, cfg.head_size // 2, 2), jnp.float32)
@@ -370,9 +473,9 @@ def full_step_case(topo):
 
     return [
         ("FULL 1b decode step (scan+flash+blockdot)", step,
-         (params, cache, tokens, pos, rope), True),
+         (params, cache, tokens, pos, rope)),
         ("FULL 1b speculative decode (k=8 while_loop)", spec_step,
-         (params, cache, h, cur, pos, rope), True),
+         (params, cache, h, cur, pos, rope)),
     ]
 
 
@@ -397,33 +500,33 @@ def sharded_cases(topo):
     assert sel.bucket_tag() == "pallas/sharded_flash" and not sel.interpret, sel
     L = 2
 
-    def qw(k, n, spec):
+    def operands(k, n, spec):
         return (S((L, k // 2, n), jnp.uint8, sharding=ns(spec)),
                 S((L, k // Q_BLOCK, n), jnp.uint16, sharding=ns(spec)))
 
     x = S((1, 1, cfg.dim), jnp.bfloat16, sharding=ns(P()))
     li = S((), jnp.int32, sharding=ns(P()))
     out = []
-    p1, s1 = qw(cfg.dim, cfg.hidden_dim, P(None, None, "tp"))
+    p1, s1 = operands(cfg.dim, cfg.hidden_dim, P(None, None, "tp"))
     out.append(("tp=4 shard_map mm out-shard (w1)",
                 lambda x, p, s, l: sel.mm(x, QTensor(p, s), l),
-                (x, p1, s1, li), True))
-    p2, s2 = qw(cfg.hidden_dim, cfg.dim, P(None, "tp", None))
+                (x, p1, s1, li)))
+    p2, s2 = operands(cfg.hidden_dim, cfg.dim, P(None, "tp", None))
     xh = S((1, 1, cfg.hidden_dim), jnp.bfloat16, sharding=ns(P(None, None, "tp")))
     out.append(("tp=4 shard_map mm in-shard+psum (w2)",
                 lambda x, p, s, l: sel.mm_in(x, QTensor(p, s), l),
-                (xh, p2, s2, li), True))
+                (xh, p2, s2, li)))
     q = S((1, 1, cfg.n_heads, cfg.head_size), jnp.bfloat16,
           sharding=ns(P(None, None, "tp", None)))
     kc = S((1, cfg.n_kv_heads, cfg.seq_len, cfg.head_size), jnp.bfloat16,
            sharding=ns(P(None, "tp", None, None)))
     pos = S((), jnp.int32, sharding=ns(P()))
     out.append(("tp=4 shard_map head-sharded flash", sel.attn_fn,
-                (q, kc, kc, pos), True))
+                (q, kc, kc, pos)))
 
-    params = _abstract_params(
-        cfg, lambda t: jax.tree.map(ns, sh.param_spec_tree(t),
-                                    is_leaf=lambda s: isinstance(s, P)))
+    shapes = abstract_params(cfg, S)
+    params = jax.tree.map(lambda a, spec: S(a.shape, a.dtype, sharding=ns(spec)),
+                          shapes, sh.param_spec_tree(shapes))
     cshape = (cfg.n_layers, 1, cfg.n_kv_heads, cfg.seq_len, cfg.head_size)
     cspec = ns(sh.cache_spec(batch=1))
     cache = KVCache(S(cshape, jnp.bfloat16, sharding=cspec),
@@ -439,27 +542,24 @@ def sharded_cases(topo):
     for t in (256, 1):
         out.append((f"FULL 1b tp=4 engine step t={t}", step,
                     (params, cache, S((1, t), jnp.int32, sharding=ns(P())),
-                     pos, rope), True))
+                     pos, rope)))
     return out
 
 
 def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
                     hybrid_p=(64,), seq: int = SEQ, prefill_chunk: int = 256):
-    """(name, thunk, production) for the step programs of a paged
+    """(name, thunk) for the step programs of a paged
     BatchEngine (`serve --slots N --max-seq-len 2048 [--spec-k K]`): the
     engine is built here on the CPU over shapes, and each thunk lowers one
     of its programs for the described chip and returns the compiled
     executable. `kv_pages` 0 = full coverage; `hybrid_p` = the prefill
     slices to offer the hybrid step at. serving_cases() and
     experiments/pool_copies.py (the 7B cell's sizes) both build on this."""
-    from jax.sharding import SingleDeviceSharding
-
     from dllama_tpu.engine.batch import BatchEngine
     from dllama_tpu.models.llama import PagedKVCache
     from dllama_tpu.ops.layers import build_rope_cache
 
-    one = SingleDeviceSharding(topo.devices[0])
-    A = lambda shape, dt: S(shape, dt, sharding=one)
+    A = on_one_chip(topo)
     place = lambda tree: jax.tree.map(lambda a: A(a.shape, a.dtype), tree)
     i32 = lambda *shape: A(shape, jnp.int32)
     f32 = lambda *shape: A(shape, jnp.float32)
@@ -500,23 +600,23 @@ def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
             f32(slots), f32(slots))  # pos, active, keys, temps, topp
     dec = lambda n: (params, cache, i32(slots, 1), *vecs, n, rope, i32(slots))
     out = [(f"{tag} paged decode chunk n=4",
-            lambda: be._decode.lower(*dec(4)).compile(), True)]
+            lambda: be._decode.lower(*dec(4)).compile())]
     if spec or cfg.recurrent or cfg.n_window_layers or cfg.q_lora_rank:
         out += [(f"{tag} hybrid step p={p} n=4", lambda p=p: be._hybrid.lower(
             params, cache, i32(1, p), i32(), i32(), i32(slots, 1),
-            *vecs, 4, rope, i32(slots)).compile(), True) for p in hybrid_p]
+            *vecs, 4, rope, i32(slots)).compile()) for p in hybrid_p]
     if spec:
         out += [
             (f"{tag} paged prefill chunk m=256", lambda: be._prefill_slot.lower(
-                params, cache, i32(1, 256), i32(), i32(), rope).compile(), True),
+                params, cache, i32(1, 256), i32(), i32(), rope).compile()),
             (f"{tag} paged prefill chunk m=1", lambda: be._prefill_slot.lower(
-                params, cache, i32(1, 1), i32(), i32(), rope).compile(), True),
+                params, cache, i32(1, 1), i32(), i32(), rope).compile()),
             (f"{tag} spec-verify chunk K={spec} m=4", lambda: be._spec_step.lower(
                 params, cache, i32(slots, seq + 1), i32(slots), vecs[0],
-                vecs[1], i32(slots), *vecs[2:], rope, i32(slots), 4).compile(), True),
+                vecs[1], i32(slots), *vecs[2:], rope, i32(slots), 4).compile()),
             (f"{tag} paged penalized decode chunk n=4", lambda: be._decode_pen.lower(
                 *dec(4), i32(slots, cfg.vocab_size), f32(slots),
-                f32(slots)).compile(), True),
+                f32(slots)).compile()),
         ]
     return out
 
@@ -527,61 +627,33 @@ def serving_cases(topo):
     (static args, donation, pool aliasing inside the layer scan): paged
     decode chunk, one hybrid prefill-slice + decode step, prefill chunks,
     the K+1-wide spec-verify chunk, the penalized scan — plus a decode
-    chunk of an OLMoE-width sparse-expert block (`moe_ffn`'s ragged_dot
-    inside the scan; not on the 1b path). Returns (name, thunk, production)
-    as engine_programs() builds them."""
-    from jax.sharding import SingleDeviceSharding
-
+    chunk of an OLMoE-width sparse-expert block (the grouped expert kernel
+    inside a homogeneous layer scan; not on the 1b path)."""
     from dllama_tpu.models.config import LlamaConfig
 
-    one = SingleDeviceSharding(topo.devices[0])
-    A = lambda shape, dt: S(shape, dt, sharding=one)
-    f32 = lambda *shape: A(shape, jnp.float32)
-
+    A = on_one_chip(topo)
     cfg = _cfg_1b()
-    out = engine_programs(topo, "serve 1b", cfg, _abstract_params(
-        cfg, lambda t: jax.tree.map(lambda _: one, t)), SLOTS, SPEC_K)
-
+    out = engine_programs(topo, "serve 1b", cfg, abstract_params(cfg, A), SLOTS, SPEC_K)
     # OLMoE-1B-7B width (ROADMAP Reach #1): 64 experts top-8, expert hidden
-    # 1024, MHA 16/16 hd 128, 64 slots so moe_ffn resolves `sort`, over a
-    # 256-page (32k-token, 4.3 GB) pool: full coverage of 64 x 2048 rows of
-    # this MHA cache is 17 GB, more than the chip holds
+    # 1024, MHA 16/16 hd 128, 64 slots, over a 256-page (32k-token, 4.3 GB)
+    # pool: full coverage of 64 x 2048 rows of this MHA cache is 17 GB, more
+    # than the chip holds
     mcfg = LlamaConfig(dim=2048, hidden_dim=1024, n_layers=16, n_heads=16,
                        n_kv_heads=16, vocab_size=50304, seq_len=SEQ,
                        n_experts=64, n_active_experts=8)
-    L, E = mcfg.n_layers, mcfg.n_experts
-
-    def qw(lead, k, n):
-        return QTensor(A((*lead, k // 2, n), jnp.uint8),
-                       A((*lead, k // Q_BLOCK, n), jnp.float16))
-
-    mparams = {
-        "embedding": A((mcfg.vocab_size, mcfg.dim), jnp.bfloat16),
-        "final_norm": f32(mcfg.dim),
-        "wcls": qw((), mcfg.dim, mcfg.vocab_size),
-        "layers": {
-            **{w: qw((L,), mcfg.dim, mcfg.dim) for w in ("wq", "wk", "wv", "wo")},
-            "moe_gate": f32(L, mcfg.dim, E),
-            "moe_w1": qw((L, E), mcfg.dim, mcfg.hidden_dim),
-            "moe_w2": qw((L, E), mcfg.hidden_dim, mcfg.dim),
-            "moe_w3": qw((L, E), mcfg.dim, mcfg.hidden_dim),
-            "rms_att": f32(L, mcfg.dim), "rms_ffn": f32(L, mcfg.dim),
-        },
-    }
-    # not a shipped default yet: the configuration lands with Reach #1
-    out += [(n, t, False) for n, t, _ in engine_programs(
-        topo, "serve olmoe-width 64-slot", mcfg, mparams, 64, 0, kv_pages=256)]
-    return out
+    return out + engine_programs(topo, "serve olmoe-width 64-slot", mcfg,
+                                 abstract_params(mcfg, A), 64, 0, kv_pages=256)
 
 
-#: the hybrid state-space / attention stack at the published widths of the
-#: benchmark's configuration (benchmark/configs/granite-4.0-h-micro.json):
-#: 40 layers of period `m m m m m a m m m m`, Mamba-2 64 x 64 x 128, GQA 32/8
-#: heads of 64, MLP 8192, a 100,352-row head; 48 slots over 456 pages
-HYBRID_SLOTS, HYBRID_PAGES = 48, 456
+# ---- the served architectures at their published widths: a configuration,
+# ---- and what its benchmark cell's `serve` line gives
 
 
 def hybrid_cfg(n_layers: int = 40):
+    """The hybrid state-space / attention stack of
+    benchmark/configs/granite-4.0-h-micro.json: 40 layers of period
+    `m m m m m a m m m m`, Mamba-2 64 x 64 x 128, GQA 32/8 heads of 64, MLP
+    8192, a 100,352-row head."""
     from dllama_tpu.models.config import ArchType, LlamaConfig, RopeType
 
     period = (1, 1, 1, 1, 1, 0, 1, 1, 1, 1)
@@ -594,122 +666,37 @@ def hybrid_cfg(n_layers: int = 40):
         ssm_state=128, ssm_conv=4, ssm_chunk=256)
 
 
-def hybrid_params(cfg, A):
-    """Abstract params of a HYBRID_SSM model as models/formats.load_params
-    stacks them: per kind, in_proj padded to whole 128-lane tiles."""
-    def qw(lead, k, n):
-        return QTensor(A((*lead, k // 2, n), jnp.uint8),
-                       A((*lead, k // Q_BLOCK, n), jnp.float16))
-
-    f32 = lambda *shape: A(shape, jnp.float32)
-    L, La, Ls = cfg.n_layers, cfg.n_attn_layers, cfg.n_ssm_layers
-    d, h, inner, cd = cfg.dim, cfg.hidden_dim, cfg.ssm_inner, cfg.ssm_conv_dim
-    return {
-        "embedding": A((cfg.vocab_size, d), jnp.bfloat16),
-        "final_norm": f32(d), "wcls": qw((), d, cfg.vocab_size),
-        "layers": {
-            "wq": qw((La,), d, d), "wk": qw((La,), d, cfg.kv_dim),
-            "wv": qw((La,), d, cfg.kv_dim), "wo": qw((La,), d, d),
-            "in_proj": qw((Ls,), d, -(-cfg.ssm_in_proj // 128) * 128),
-            "conv_w": f32(Ls, cd, cfg.ssm_conv), "conv_b": f32(Ls, cd),
-            "dt_bias": f32(Ls, cfg.ssm_heads), "a_log": f32(Ls, cfg.ssm_heads),
-            "d": f32(Ls, cfg.ssm_heads), "ssm_norm": f32(Ls, inner),
-            "out_proj": qw((Ls,), inner, d),
-            "w1": qw((L,), d, h), "w2": qw((L,), h, d), "w3": qw((L,), d, h),
-            "rms_att": f32(L, d), "rms_ffn": f32(L, d),
-        },
-    }
-
-
-def hybrid_cases(topo, slots: int = HYBRID_SLOTS, pages: int = HYBRID_PAGES):
-    """The step programs of `serve --slots 48 --kv-pages 456` on the hybrid
-    state-space model at its published widths: decode chunk and hybrid
-    step (tests/test_chip_compile.py holds them, at fewer slots, to no
-    state-stack-sized copy). Kept out of all_cases(): building the engine
-    allocates the slots' real state on the host (3.7 GB at 48)."""
-    from jax.sharding import SingleDeviceSharding
-
-    one = SingleDeviceSharding(topo.devices[0])
-    cfg = hybrid_cfg()
-    params = hybrid_params(cfg, lambda shape, dt: S(shape, dt, sharding=one))
-    return engine_programs(topo, f"serve hybrid-ssm {slots}-slot", cfg, params,
-                           slots, 0, kv_pages=pages)
-
-
-#: the window-and-global, routed-expert stack at the published widths of the
-#: benchmark's configuration (benchmark/configs/smallthinker-21b-a3b.json),
-#: cut to 24 layers as there: period `g w w w`, 28/4 heads of 128 over a
-#: 2,560 stream, 64 experts of width 768 with 6 active, a 151,936-row head;
-#: 16 slots over 1,232 global pages and the window pool the engine sizes
-WINDOW_MOE_SLOTS, WINDOW_MOE_PAGES, WINDOW_MOE_SEQ = 16, 1232, 16384
-
-
 def window_moe_cfg(n_layers: int = 24):
+    """The window-and-global, routed-expert stack of
+    benchmark/configs/smallthinker-21b-a3b.json, cut to 24 layers as there:
+    period `g w w w`, 28/4 heads of 128 over a 2,560 stream, 64 experts of
+    width 768 with 6 active, a 151,936-row head."""
     from dllama_tpu.models.config import HiddenAct, LlamaConfig
 
     return LlamaConfig(
         dim=2560, hidden_dim=768, n_layers=n_layers, n_heads=28, n_kv_heads=4,
-        head_dim=128, vocab_size=151936, seq_len=WINDOW_MOE_SEQ, n_experts=64,
+        head_dim=128, vocab_size=151936, seq_len=16384, n_experts=64,
         n_active_experts=6, hidden_act=HiddenAct.RELU, rope_theta=1.5e6,
         norm_epsilon=1e-6, window=4096, layer_windows=(0, 1, 1, 1) * (n_layers // 4),
         layer_ropes=(0, 1, 1, 1) * (n_layers // 4), router_pre_attention=True)
 
 
-def window_moe_params(cfg, A):
-    """Abstract params as models/formats.load_params stacks them."""
-    def qw(lead, k, n):
-        return QTensor(A((*lead, k // 2, n), jnp.uint8),
-                       A((*lead, k // Q_BLOCK, n), jnp.float16))
-
-    f32 = lambda *shape: A(shape, jnp.float32)
-    L, E, d, w = cfg.n_layers, cfg.n_experts, cfg.dim, cfg.hidden_dim
-    return {
-        "embedding": A((cfg.vocab_size, d), jnp.bfloat16),
-        "final_norm": f32(d), "wcls": qw((), d, cfg.vocab_size),
-        "layers": {
-            "wq": qw((L,), d, cfg.attn_dim), "wk": qw((L,), d, cfg.kv_dim),
-            "wv": qw((L,), d, cfg.kv_dim), "wo": qw((L,), cfg.attn_dim, d),
-            "moe_gate": f32(L, d, E), "moe_w1": qw((L, E), d, w),
-            "moe_w2": qw((L, E), w, d), "moe_w3": qw((L, E), d, w),
-            "rms_att": f32(L, d), "rms_ffn": f32(L, d),
-        },
-    }
-
-
-def window_moe_cases(topo, slots: int = WINDOW_MOE_SLOTS,
-                     pages: int = WINDOW_MOE_PAGES, n_layers: int = 24):
-    """The step programs of `serve --slots 16 --kv-pages 1232` on the
-    window-and-global routed-expert model at its published widths, with the
-    512-row slices its benchmark cell asks for (`--max-prefill-chunk 512`):
-    decode chunk and the hybrid step (the grouped expert kernel at 1-3 and
-    at 48 rows an expert, the clipped paged sweep)."""
-    from jax.sharding import SingleDeviceSharding
-
-    one = SingleDeviceSharding(topo.devices[0])
-    cfg = window_moe_cfg(n_layers)
-    params = window_moe_params(cfg, lambda shape, dt: S(shape, dt, sharding=one))
-    return engine_programs(topo, f"serve window-moe {slots}-slot", cfg, params,
-                           slots, 0, kv_pages=pages, hybrid_p=(512,),
-                           seq=WINDOW_MOE_SEQ, prefill_chunk=512)
-
-
-#: the delta-rule / latent-attention stack over sigmoid-routed experts at
-#: the published widths of benchmark/configs/kimi-linear-48b-a3b.json: 2,304
-#: stream, 32 KDA heads of 128, latent 512 + 64 under 32 heads of 128 + 64 /
-#: 128, a dense layer of 9,216, then 64 held of 256 experts of 1,024 with 8
-#: active and a shared expert, a 40,960-row head; 48 slots over 456 pages
-DELTA_LATENT_SLOTS, DELTA_LATENT_PAGES, DELTA_LATENT_SEQ = 48, 456, 8192
 #: the published pattern: layer 1 dense, latent attention at 4, 8, ..., 24, 27
 DELTA_LATENT_KINDS = tuple(3 if i in (4, 8, 12, 16, 20, 24, 27) else 2
                            for i in range(1, 28))
 
 
 def delta_latent_cfg(kinds: tuple = DELTA_LATENT_KINDS):
+    """The delta-rule / latent-attention stack over sigmoid-routed experts
+    of benchmark/configs/kimi-linear-48b-a3b.json: 2,304 stream, 32 KDA
+    heads of 128, latent 512 + 64 under 32 heads of 128 + 64 / 128, a dense
+    layer of 9,216, then 64 held of 256 experts of 1,024 with 8 active and a
+    shared expert, a 40,960-row head."""
     from dllama_tpu.models.config import LlamaConfig, RopeType
 
     return LlamaConfig(
         dim=2304, hidden_dim=9216, n_layers=len(kinds), n_heads=32,
-        n_kv_heads=32, vocab_size=40960, seq_len=DELTA_LATENT_SEQ,
+        n_kv_heads=32, vocab_size=40960, seq_len=8192,
         n_experts=256, n_active_experts=8, rope_type=RopeType.NONE,
         layer_kinds=kinds, kda_heads=32, kda_head_dim=128, kda_conv=4,
         kda_rank=128, kv_lora_rank=512, qk_nope_dim=128, qk_pe_dim=64,
@@ -718,76 +705,17 @@ def delta_latent_cfg(kinds: tuple = DELTA_LATENT_KINDS):
         moe_hidden_dim=1024, layer_ffn=(1,) + (0,) * (len(kinds) - 1))
 
 
-def delta_latent_params(cfg, A):
-    """Abstract params as models/formats.load_params stacks them (per mixer
-    kind and per feed-forward kind; kda_proj and mla_kva zero-padded)."""
-    def qw(lead, k, n):
-        return QTensor(A((*lead, k // 2, n), jnp.uint8),
-                       A((*lead, k // Q_BLOCK, n), jnp.float16))
-
-    f32 = lambda *shape: A(shape, jnp.float32)
-    L, Lk, Lm = cfg.n_layers, cfg.n_kda_layers, cfg.n_attn_layers
-    Ld = cfg.n_dense_ffn_layers
-    Le, d, w, E = L - Ld, cfg.dim, cfg.expert_width, cfg.n_held_experts
-    inner, rank, h = cfg.kda_inner, cfg.kda_rank, cfg.n_heads
-    return {
-        "embedding": A((cfg.vocab_size, d), jnp.bfloat16),
-        "final_norm": f32(d), "wcls": qw((), d, cfg.vocab_size),
-        "layers": {
-            "kda_proj": qw((Lk,), d, -(-cfg.kda_proj // 512) * 512),
-            "kda_conv_w": f32(Lk, 3 * inner, cfg.kda_conv),
-            "kda_fb": qw((Lk,), rank, inner), "kda_gb": qw((Lk,), rank, inner),
-            "kda_dt_bias": f32(Lk, inner), "kda_a_log": f32(Lk, cfg.kda_heads),
-            "kda_norm": f32(Lk, cfg.kda_head_dim), "kda_o": qw((Lk,), inner, d),
-            "mla_q": qw((Lm,), d, h * (cfg.qk_nope_dim + cfg.qk_pe_dim)),
-            "mla_kva": qw((Lm,), d, -(-cfg.cache_row // 128) * 128),
-            "mla_kv_norm": f32(Lm, cfg.kv_lora_rank),
-            "mla_kvb": f32(Lm, h, cfg.qk_nope_dim + cfg.v_head_dim, cfg.kv_lora_rank),
-            "mla_o": qw((Lm,), h * cfg.v_head_dim, d),
-            "w1": qw((Ld,), d, cfg.hidden_dim), "w2": qw((Ld,), cfg.hidden_dim, d),
-            "w3": qw((Ld,), d, cfg.hidden_dim),
-            "moe_gate": f32(Le, d, cfg.n_experts), "moe_bias": f32(Le, cfg.n_experts),
-            "moe_w1": qw((Le, E), d, w), "moe_w2": qw((Le, E), w, d),
-            "moe_w3": qw((Le, E), d, w),
-            "shared_w1": qw((Le,), d, w), "shared_w2": qw((Le,), w, d),
-            "shared_w3": qw((Le,), d, w),
-            "rms_att": f32(L, d), "rms_ffn": f32(L, d),
-        },
-    }
-
-
-def delta_latent_cases(topo, slots: int = DELTA_LATENT_SLOTS,
-                       pages: int = DELTA_LATENT_PAGES,
-                       kinds: tuple = DELTA_LATENT_KINDS):
-    """The step programs of `serve --slots 48 --kv-pages 456` on the
-    delta-rule / latent-attention model at its published widths and depth:
-    decode chunk and the hybrid step (`_kda_step` on the stacked state, the
-    latent paged sweep, the grouped kernel over the held experts). Kept out
-    of all_cases(): the engine allocates the slots' state on the host."""
-    from jax.sharding import SingleDeviceSharding
-
-    one = SingleDeviceSharding(topo.devices[0])
-    cfg = delta_latent_cfg(kinds)
-    params = delta_latent_params(cfg, lambda shape, dt: S(shape, dt, sharding=one))
-    return engine_programs(topo, f"serve delta-latent {slots}-slot", cfg, params,
-                           slots, 0, kv_pages=pages, seq=DELTA_LATENT_SEQ)
-
-
-#: attention by layer kind over sigmoid-routed experts at the published
-#: widths and depth of benchmark/configs/laguna-xs.2.json: 2,048 stream, 48
-#: global / 64 windowed query heads over 8 kv heads of 128, a 512-row window,
-#: a dense layer of 8,192, then 64 held of 256 experts of 512 with 8 active
-#: and a shared expert, a 25,088-row head; 24 slots over 816 pages, 256-row
-#: slices
-ATTN_KINDS_SLOTS, ATTN_KINDS_PAGES, ATTN_KINDS_SEQ, ATTN_KINDS_SLICE = 24, 816, 4224, 256
-
-
 def attn_kinds_cfg(n_layers: int = 40):
+    """Attention by layer kind over sigmoid-routed experts, the widths and
+    depth of benchmark/configs/laguna-xs.2.json: 2,048 stream, 48 global /
+    64 windowed query heads over 8 kv heads of 128, a 512-row window, a dense
+    layer of 8,192, then 64 held of 256 experts of 512 with 8 active and a
+    shared expert, a 25,088-row head."""
     from dllama_tpu.models.config import LlamaConfig, RopeSpec, RopeType
 
     return LlamaConfig(
         dim=2048, hidden_dim=8192, n_layers=n_layers, n_heads=48, n_kv_heads=8,
-        vocab_size=25088, seq_len=ATTN_KINDS_SEQ, head_dim=128,
+        vocab_size=25088, seq_len=4224, head_dim=128,
         norm_epsilon=1e-6, n_experts=256, n_active_experts=8, window=512,
         layer_windows=tuple(int(i % 4 != 0) for i in range(n_layers)),
         window_heads=64, qk_norm=True, attn_gate=True,
@@ -798,76 +726,18 @@ def attn_kinds_cfg(n_layers: int = 40):
         layer_ffn=(1,) + (0,) * (n_layers - 1))
 
 
-def attn_kinds_params(cfg, A):
-    """Abstract params as models/formats.load_params stacks them: the
-    attention tensors apart by kind (`*_win`), dense and expert feed-forward
-    weights apart."""
-    def qw(lead, k, n):
-        return QTensor(A((*lead, k // 2, n), jnp.uint8),
-                       A((*lead, k // Q_BLOCK, n), jnp.float16))
-
-    f32 = lambda *shape: A(shape, jnp.float32)
-    L, Lw = cfg.n_layers, cfg.n_window_layers
-    Ld = cfg.n_dense_ffn_layers
-    Le, d, w, E = L - Ld, cfg.dim, cfg.expert_width, cfg.n_held_experts
-    layers = {
-        "w1": qw((Ld,), d, cfg.hidden_dim), "w2": qw((Ld,), cfg.hidden_dim, d),
-        "w3": qw((Ld,), d, cfg.hidden_dim),
-        "moe_gate": f32(Le, d, cfg.n_experts), "moe_bias": f32(Le, cfg.n_experts),
-        "moe_w1": qw((Le, E), d, w), "moe_w2": qw((Le, E), w, d),
-        "moe_w3": qw((Le, E), d, w),
-        "shared_w1": qw((Le,), d, w), "shared_w2": qw((Le,), w, d),
-        "shared_w3": qw((Le,), d, w),
-        "rms_att": f32(L, d), "rms_ffn": f32(L, d),
-    }
-    for windowed, n in ((False, L - Lw), (True, Lw)):
-        sfx, ad = cfg.attn_suffix(windowed), cfg.attn_dim_of(windowed)
-        layers.update({
-            "wq" + sfx: qw((n,), d, ad), "wk" + sfx: qw((n,), d, cfg.kv_dim),
-            "wv" + sfx: qw((n,), d, cfg.kv_dim), "wo" + sfx: qw((n,), ad, d),
-            "q_norm" + sfx: f32(n, cfg.head_size),
-            "k_norm" + sfx: f32(n, cfg.head_size),
-            "attn_gate" + sfx: f32(n, d, cfg.heads_of(windowed))})
-    return {"embedding": A((cfg.vocab_size, d), jnp.bfloat16),
-            "final_norm": f32(d), "wcls": qw((), d, cfg.vocab_size),
-            "layers": layers}
-
-
-def attn_kinds_cases(topo, slots: int = ATTN_KINDS_SLOTS,
-                     pages: int = ATTN_KINDS_PAGES, n_layers: int = 40):
-    """The step programs of `serve --slots 24 --kv-pages 816
-    --max-prefill-chunk 256` on the model whose attention goes by layer kind,
-    at its published widths and depth: decode chunk and the hybrid step (the
-    paged sweep at folds 6 and 8 in one program, the window pool, the
-    grouped kernel over the held experts at width 512). Kept out of
-    all_cases(): the engine allocates the window pool on the host."""
-    from jax.sharding import SingleDeviceSharding
-
-    one = SingleDeviceSharding(topo.devices[0])
-    cfg = attn_kinds_cfg(n_layers)
-    params = attn_kinds_params(cfg, lambda shape, dt: S(shape, dt, sharding=one))
-    return engine_programs(topo, f"serve attn-kinds {slots}-slot", cfg, params,
-                           slots, 0, kv_pages=pages, seq=ATTN_KINDS_SEQ,
-                           hybrid_p=(ATTN_KINDS_SLICE,),
-                           prefill_chunk=ATTN_KINDS_SLICE)
-
-
-#: rotated latent attention with a q-side low rank over group-limited
-#: sigmoid-routed experts at the published widths of
-#: benchmark/configs/a.x-k1.json: 7,168 stream, 64 heads over a 512 + 64
-#: latent row, q through 1,536, YaRN x32 over the 64 shared dims, a dense
-#: layer of 18,432, then 24 held of 192 experts (8 groups, 4 kept, 8 a token)
-#: of width 2,048 and a shared expert, a 20,480-row head; 9 layers, 32 slots
-#: over 2,368 pages, 512-row slices
-ROT_LATENT_SLOTS, ROT_LATENT_PAGES, ROT_LATENT_SEQ, ROT_LATENT_SLICE = 32, 2368, 16384, 512
-
-
 def rot_latent_cfg(n_layers: int = 9):
+    """Rotated latent attention with a q-side low rank over group-limited
+    sigmoid-routed experts, the widths of benchmark/configs/a.x-k1.json:
+    7,168 stream, 64 heads over a 512 + 64 latent row, q through 1,536, YaRN
+    x32 over the 64 shared dims, a dense layer of 18,432, then 24 held of 192
+    experts (8 groups, 4 kept, 8 a token) of width 2,048 and a shared expert,
+    a 20,480-row head; 9 layers."""
     from dllama_tpu.models.config import LlamaConfig, RopeSpec, RopeType
 
     return LlamaConfig(
         dim=7168, hidden_dim=18432, n_layers=n_layers, n_heads=64, n_kv_heads=64,
-        vocab_size=20480, seq_len=ROT_LATENT_SEQ, norm_epsilon=1e-6,
+        vocab_size=20480, seq_len=16384, norm_epsilon=1e-6,
         attn_scale=0.130861, layer_kinds=(3,) * n_layers, kv_lora_rank=512,
         qk_nope_dim=128, qk_pe_dim=64, v_head_dim=128, q_lora_rank=1536,
         global_rope=RopeSpec(RopeType.YARN, 10000.0, 1.0, 32.0, 4096, 32.0,
@@ -878,166 +748,58 @@ def rot_latent_cfg(n_layers: int = 9):
         layer_ffn=(1,) + (0,) * (n_layers - 1))
 
 
-def rot_latent_params(cfg, A):
-    """Abstract params as models/formats.load_params stacks them (mla_kva
-    zero-padded to whole lane tiles, W_kvb float32 by head)."""
-    def qw(lead, k, n):
-        return QTensor(A((*lead, k // 2, n), jnp.uint8),
-                       A((*lead, k // Q_BLOCK, n), jnp.float16))
-
-    f32 = lambda *shape: A(shape, jnp.float32)
-    L, Ld = cfg.n_layers, cfg.n_dense_ffn_layers
-    Le, d, w, E = L - Ld, cfg.dim, cfg.expert_width, cfg.n_held_experts
-    h, qr = cfg.n_heads, cfg.q_lora_rank
-    return {
-        "embedding": A((cfg.vocab_size, d), jnp.bfloat16),
-        "final_norm": f32(d), "wcls": qw((), d, cfg.vocab_size),
-        "layers": {
-            "mla_qa": qw((L,), d, qr), "mla_q_norm": f32(L, qr),
-            "mla_qb": qw((L,), qr, h * (cfg.qk_nope_dim + cfg.qk_pe_dim)),
-            "mla_kva": qw((L,), d, -(-cfg.cache_row // 128) * 128),
-            "mla_kv_norm": f32(L, cfg.kv_lora_rank),
-            "mla_kvb": f32(L, h, cfg.qk_nope_dim + cfg.v_head_dim, cfg.kv_lora_rank),
-            "mla_o": qw((L,), h * cfg.v_head_dim, d),
-            "w1": qw((Ld,), d, cfg.hidden_dim), "w2": qw((Ld,), cfg.hidden_dim, d),
-            "w3": qw((Ld,), d, cfg.hidden_dim),
-            "moe_gate": f32(Le, d, cfg.n_experts), "moe_bias": f32(Le, cfg.n_experts),
-            "moe_w1": qw((Le, E), d, w), "moe_w2": qw((Le, E), w, d),
-            "moe_w3": qw((Le, E), d, w),
-            "shared_w1": qw((Le,), d, w), "shared_w2": qw((Le,), w, d),
-            "shared_w3": qw((Le,), d, w),
-            "rms_att": f32(L, d), "rms_ffn": f32(L, d),
-        },
-    }
+# experiments/warm_compile.py asks for each configuration's parameters by name
+hybrid_params = window_moe_params = delta_latent_params = abstract_params
+attn_kinds_params = rot_latent_params = abstract_params
 
 
-def rot_latent_cases(topo, slots: int = ROT_LATENT_SLOTS,
-                     pages: int = ROT_LATENT_PAGES, n_layers: int = 9):
-    """The step programs of `serve --slots 32 --kv-pages 2368
-    --max-prefill-chunk 512` on the rotated-latent model at its published
-    widths: decode chunk and the hybrid step (the latent paged sweep at 64
-    heads over rotated rows, the grouped kernel over the held group at
-    width 2,048 on 7,168, the q-side low rank)."""
-    from jax.sharding import SingleDeviceSharding
-
-    one = SingleDeviceSharding(topo.devices[0])
-    cfg = rot_latent_cfg(n_layers)
-    params = rot_latent_params(cfg, lambda shape, dt: S(shape, dt, sharding=one))
-    return engine_programs(topo, f"serve rot-latent {slots}-slot", cfg, params,
-                           slots, 0, kv_pages=pages, seq=ROT_LATENT_SEQ,
-                           hybrid_p=(ROT_LATENT_SLICE,),
-                           prefill_chunk=ROT_LATENT_SLICE)
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """A configuration and its cell's `serve --slots --kv-pages
+    [--max-prefill-chunk]`: `slice` is the prefill rows a hybrid launch
+    carries there."""
+    cfg: callable
+    slots: int
+    pages: int
+    slice: int = 64
+    chunk: int = 256
 
 
-def all_cases(topo, full: bool = False):
-    """Every case as (name, thunk, production): thunk() compiles for the
+FAMILIES = {
+    "hybrid-ssm": Family(hybrid_cfg, 48, 456),
+    "window-moe": Family(window_moe_cfg, 16, 1232, 512, 512),
+    "delta-latent": Family(delta_latent_cfg, 48, 456),
+    "attn-kinds": Family(attn_kinds_cfg, 24, 816, 256, 256),
+    "rot-latent": Family(rot_latent_cfg, 32, 2368, 512, 512),
+}
+
+
+def family_cases(topo, name, slots=None, pages=None, **depth):
+    """The decode chunk and the hybrid step of FAMILIES[name] at its
+    published widths, named `serve <name> <slots>-slot ...`. `slots`,
+    `pages` and `depth` (the configuration's own depth argument) cut it to
+    what a test compiles in seconds: building the engine allocates the
+    slots' state and the window pool on the host (3.7 GB for hybrid-ssm's
+    48 slots), which is why these stay out of all_cases()."""
+    fam = FAMILIES[name]
+    cfg = fam.cfg(**depth)
+    slots = slots or fam.slots
+    return engine_programs(
+        topo, f"serve {name} {slots}-slot", cfg, abstract_params(cfg, on_one_chip(topo)),
+        slots, 0, kv_pages=pages or fam.pages, hybrid_p=(fam.slice,),
+        seq=cfg.seq_len, prefill_chunk=fam.chunk)
+
+
+def all_cases(topo):
+    """Every case of CASES as (name, thunk): thunk() compiles for the
     described chip and raises what the chip's compiler would raise."""
-    from jax.sharding import SingleDeviceSharding
-
-    one = SingleDeviceSharding(topo.devices[0])
+    A = on_one_chip(topo)
 
     def thunk(fn, args):
         return lambda: jax.jit(fn).trace(*args).lower().compile()
 
-    out = [
-        # pin abstract args to one device of the target topology so
-        # XLA:TPU (not Host) compiles the module — Mosaic runs inside
-        (name, thunk(fn, tuple(S(a.shape, a.dtype, sharding=one) for a in args)), prod)
-        for name, fn, args, prod in cases(full)
-    ]
-    out += [(name, thunk(fn, args), prod)
-            for name, fn, args, prod in sharded_cases(topo) + full_step_case(topo)]
+    out = [(name, thunk(fn, tuple(A(a.shape, a.dtype) for a in args)))
+           for name, fn, args in cases()]
+    out += [(name, thunk(fn, args))
+            for name, fn, args in sharded_cases(topo) + full_step_case(topo)]
     return out + serving_cases(topo)
-
-
-def scale_slices(hlo_text: str) -> tuple[int, int]:
-    """(`slice-start` instructions, those of a u16 array) in a compiled
-    program's text: what XLA's memory-space assignment copies into VMEM
-    ahead of its user, and how much of it is a Q40 call's scales."""
-    starts = [line for line in hlo_text.splitlines()
-              if re.search(r"= .*slice-start\(", line)]
-    return len(starts), sum("u16[" in line for line in starts)
-
-
-def main():
-    full = "--full" in sys.argv
-    md_path = "MOSAIC_AOT.md"
-    if "--md" in sys.argv:
-        i = sys.argv.index("--md") + 1
-        if i >= len(sys.argv):
-            raise SystemExit("usage: aot_check.py [--full] [--md OUTPUT.md]")
-        md_path = sys.argv[i]
-    from importlib.metadata import version
-
-    from dllama_tpu.ops import matmul as mmod
-
-    mmod.device_platform = lambda: "tpu"  # see module docstring
-    rows, prod_reject, parked = [], [], {}
-    topo = topology()
-    for cname, thunk, production in (all_cases(topo, full) + hybrid_cases(topo)
-                                     + window_moe_cases(topo)
-                                     + delta_latent_cases(topo)
-                                     + attn_kinds_cases(topo)
-                                     + rot_latent_cases(topo)):
-        t0 = time.time()
-        try:
-            compiled = thunk()
-            verdict = "ACCEPT"
-            if cname.startswith("serve "):
-                parked[cname] = scale_slices(compiled.as_text())
-        except Exception as e:
-            verdict = f"REJECT {repr(e)[:220]}"
-            if production:
-                prod_reject.append(cname)
-        rows.append((cname, production, verdict))
-        print(f"{TARGET} | {cname}: {verdict} ({time.time() - t0:.0f}s)", flush=True)
-
-    with open(md_path, "w") as f:
-        f.write(
-            "# Compile verdicts of the v5e compiler (no chip attached)\n\n"
-            f"Regenerated by `python experiments/aot_check.py` under jax "
-            f"{jax.__version__} / libtpu {version('libtpu')}\nfor the described target "
-            f"`{TARGET}`: every Pallas kernel, the shard_map'd tp=4 path,\n"
-            "the whole InferenceEngine step and the whole BatchEngine serving "
-            "programs,\nat Llama-3.2-1B width (head size 64) unless a row "
-            "says otherwise. ACCEPT = the\nchip's compiler produced machine "
-            "code; nothing ran, so no row says anything about\nresults or "
-            "speed (`python chip_smoke.py` does, on the chip). 'prod' rows "
-            "are\nshipped defaults and fail the check when rejected; the "
-            "others are insurance.\n\n"
-            "| case | prod | verdict |\n|---|---|---|\n"
-        )
-        for cname, production, verdict in rows:
-            f.write(f"| {cname} | {'yes' if production else ''} | "
-                    f"{verdict.split(chr(10))[0][:120]} |\n")
-        f.write("\n## The paged kernel's plan for each case\n\n"
-                "`ops/pallas/paged_attention._plan`: kv heads a grid step "
-                "serves, passes in the landing ring, the pages a pass takes "
-                "(1 but for a latent sweep), and the VMEM the "
-                "plan counts (ring + f32 accumulator, m, l + per-page "
-                "temporaries) — functions of the shapes and dtype alone.\n\n"
-                "| case | hb | depth (passes) | pages a pass | VMEM bytes |\n"
-                "|---|---|---|---|---|\n")
-        for cname, (hb, depth, pp, nbytes) in PAGED_PLANS.items():
-            f.write(f"| {cname} | {hb} | {depth} | {pp} | {nbytes:,} |\n")
-            print(f"paged plan | {cname}: hb={hb} depth={depth} pp={pp} "
-                  f"vmem={nbytes:,} B")
-        f.write("\n## Operands XLA copies into VMEM ahead of a kernel\n\n"
-                "`slice-start` instructions of each whole step program (XLA's "
-                "memory-space assignment prefetching an operand in slices; on the "
-                "chip their waits are `slice-done`), and those of a u16 array: a "
-                "Q40 call's stacked scales, which the calls' VMEM claim keeps out "
-                "(PERF.md section 6, PR 32 and PR 37; before PR 37 the hybrid-ssm "
-                "decode program read 48 / 24 and its hybrid step 52 / 24).\n\n"
-                "| case | slice-start | of u16 scales |\n|---|---|---|\n")
-        for cname, (starts, scales) in parked.items():
-            f.write(f"| {cname} | {starts} | {scales} |\n")
-            print(f"slices | {cname}: slice-start {starts}, of u16 scales {scales}")
-    print(f"wrote {md_path}")
-    print("AOT CHECK " + ("FAIL: production kernels rejected: " + str(prod_reject)
-                          if prod_reject else "ALL PRODUCTION KERNELS ACCEPT"))
-    return 1 if prod_reject else 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

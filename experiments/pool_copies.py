@@ -31,8 +31,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from experiments import aot_check  # sets the CPU/libtpu environment before jax loads
 
-import jax
-
 _BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "u8": 1, "s8": 1,
           "pred": 1, "u16": 2, "f8e4m3fn": 1, "f8e5m2": 1}
 _SHAPE = re.compile(r"(\w+)\[([\d,]*)\]")
@@ -128,21 +126,17 @@ def programs(layers: int, pages: int, slots: int):
     programs aot_check.engine_programs() builds, at the cell's sizes, with
     the hybrid step at a fused-scatter slice (p=16) and an XLA pre-scatter
     one (p=64). Spec-verify only exists on a spec engine, hence K=4."""
-    from jax.sharding import SingleDeviceSharding
-
     from dllama_tpu.models.config import LlamaConfig
     from dllama_tpu.ops.pallas.paged_attention import pool_lanes
 
     topo = aot_check.topology()
-    one = SingleDeviceSharding(topo.devices[0])
     cfg = LlamaConfig(dim=4096, hidden_dim=11008, n_layers=layers, n_heads=32,
                       n_kv_heads=32, vocab_size=102400, seq_len=aot_check.SEQ)
-    params = aot_check._abstract_params(
-        cfg, lambda t: jax.tree.map(lambda _: one, t))
+    params = aot_check.abstract_params(cfg, aot_check.on_one_chip(topo))
     pool_bytes = (layers * (pages + 1) * cfg.n_kv_heads * 128
                   * pool_lanes(cfg.head_size) * 2)
-    return pool_bytes, [(name, thunk) for name, thunk, _ in aot_check.engine_programs(
-        topo, "7b", cfg, params, slots, 4, kv_pages=pages, hybrid_p=(16, 64))]
+    return pool_bytes, aot_check.engine_programs(
+        topo, "7b", cfg, params, slots, 4, kv_pages=pages, hybrid_p=(16, 64))
 
 
 def main() -> int:

@@ -19,53 +19,36 @@ import hashlib
 import importlib
 import json
 import os
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import check, files
+from benchmark import files
 from benchmark.layouts import laguna as layout
 from dllama_tpu.engine.batch import BatchEngine
-from dllama_tpu.engine.engine import InferenceEngine
 from dllama_tpu.models import formats
 from dllama_tpu.models import llama as model
 from dllama_tpu.models.config import HeaderKey, LlamaConfig, RopeSpec, RopeType
-from dllama_tpu.models.llama import KVCache, forward, layer_schedule, ragged_schedule
+from dllama_tpu.models.llama import layer_schedule, ragged_schedule
 from dllama_tpu.obs import instruments as ins
 from dllama_tpu.ops import layers as ops
 from dllama_tpu.ops.layers import apply_rope, build_rope_cache, rope_table, yarn_freqs
 from dllama_tpu.ops.matmul import matmul
+from tests import arch
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(ROOT, "benchmark", "tests", "tiny-laguna.json")) as f:
     TINY = json.load(f)
-#: CPU readings, float32 weights and activations, seed 5: sound 4e-7 to 2e-6
-#: on both routes; the controls 0.02 to 1.3
-TOL = {"rel_l2_mean": 1e-4, "deficit_sigma_mean": 1e-3}
-CHECK = {"prompt_lengths": [9, 40, 100], "decode_steps": 64, "tail_tokens": 7}
-ENGINE = dict(n_slots=4, kv_layout="paged", page_size=8, kv_pages=120,
-              radix_cache="auto", max_prefill_chunk=16)
-
-
-def _loaded(path, dtype):
-    cfg, header = formats.read_header(path, 256)
-    params = formats.load_params(path, cfg, header, dtype=dtype)
-    eng = InferenceEngine(cfg, params, cache_dtype=dtype, max_seq_len=256)
-    return types.SimpleNamespace(path=path, config=cfg, params=params, engine=eng)
+#: CPU readings against arch.TOL, seed 5: sound 4e-7 to 2e-6 on both routes;
+#: the controls 0.02 to 1.3
+TOL, ENGINE, _tokens = arch.TOL, arch.ENGINE, arch.tokens
 
 
 @pytest.fixture(scope="module")
 def tiny(tmp_path_factory):
-    path = str(tmp_path_factory.mktemp("laguna") / "tiny.m")
-    files.write_model(path, TINY, 5)
-    return _loaded(path, jnp.float32)
-
-
-def _tokens(n, seed=0, hi=250):
-    return np.random.default_rng(seed).integers(1, hi, n).tolist()
+    return arch.tiny_file(tmp_path_factory, "laguna", TINY)
 
 
 # ------------------------------------------------- files, header, plan
@@ -214,11 +197,9 @@ def test_a_partial_table_rotates_the_leading_dims_and_passes_the_rest():
     ("pallas", "flash", "pallas/paged_kernel.window.heads6g8w.ropes2+moe_jnp"),
 ])
 def test_prefill_decode_and_tail_match_the_reference(tiny, kernels, attn, route):
-    """Prefill in 16-row slices, 64 decode steps through both page pools past
+    """Prefill in 16-row slices, decode steps through both page pools past
     the 16-row window (pages handed back), a tail on the kept rows."""
-    cfg = dict(TINY, engine=dict(ENGINE, kernels=kernels, attn_impl=attn),
-               check=CHECK, tolerances=TOL)
-    out = check.run(tiny, cfg, tiny.path, 5)
+    out = arch.run_check(tiny, TINY, kernels, attn)
     assert out["route"] == route
     assert out["correct"], {k: out[k] for k in ("rel_l2_mean", "deficit_sigma_mean")}
     assert out["rel_l2_max"] < 2e-5
@@ -227,11 +208,8 @@ def test_prefill_decode_and_tail_match_the_reference(tiny, kernels, attn, route)
 def test_stated_precision_runs_the_kernels_at_two_folds(tiny):
     """bfloat16 activations, every kernel in interpret mode (the paged sweep
     at folds 3 and 4, the grouped expert kernel): bf16's own rounding."""
-    loaded = _loaded(tiny.path, jnp.bfloat16)
-    cfg = dict(TINY, engine=dict(ENGINE, kernels="pallas", attn_impl="flash"),
-               check=dict(CHECK, prompt_lengths=[40, 100], decode_steps=32),
-               tolerances={"rel_l2_mean": 0.05, "deficit_sigma_mean": 0.03})
-    out = check.run(loaded, cfg, tiny.path, 5)
+    out = arch.run_check(arch.loaded(tiny.path, jnp.bfloat16), TINY, "pallas", "flash",
+                         tolerances={"rel_l2_mean": 0.05, "deficit_sigma_mean": 0.03})
     assert out["route"] == "pallas/paged_kernel.window.heads6g8w.ropes2+moe_grouped"
     assert out["correct"], {k: out[k] for k in ("rel_l2_mean", "deficit_sigma_mean")}
 
@@ -239,19 +217,9 @@ def test_stated_precision_runs_the_kernels_at_two_folds(tiny):
 # ------------------------------------------------------------ the controls
 
 
-def _logits_rel_l2(params, cfg, seq, want, rope=None):
-    cache = KVCache.create(cfg, 1, jnp.float32, 128)
-    got, _ = forward(cfg, params, jnp.asarray(seq[None]), 0, cache,
-                     build_rope_cache(cfg, 128) if rope is None else rope)
-    return check.rel_l2(np.asarray(got[0, -1]), want)
-
-
 @pytest.fixture(scope="module")
 def sixty(tiny):
-    """60 tokens and the reference's logits at the last."""
-    ref = importlib.import_module(TINY["reference"])
-    seq = np.asarray(_tokens(60, seed=3), np.int32)
-    return seq, ref.logits_at(tiny.path, [seq], [[59]])[0][0]
+    return arch.sixty(tiny, TINY)
 
 
 #: name -> (config fields replaced, what else is done)
@@ -275,7 +243,7 @@ def test_each_control_fails_the_tolerance_the_sound_model_holds(
     departure from the equations is refused by 100 x the limit."""
     seq, want = sixty
     if control is None:
-        assert _logits_rel_l2(tiny.params, tiny.config, seq, want) < TOL["rel_l2_mean"]
+        assert arch.logits_rel_l2(tiny.params, tiny.config, seq, want) < TOL["rel_l2_mean"]
         return
     fields, what = CONTROLS[control]
     params, rope = tiny.params, None
@@ -290,7 +258,7 @@ def test_each_control_fails_the_tolerance_the_sound_model_holds(
         g, w = build_rope_cache(tiny.config, 128)
         rope = (w, g)
     cfg = dataclasses.replace(tiny.config, **fields)
-    err = _logits_rel_l2(params, cfg, seq, want, rope)
+    err = arch.logits_rel_l2(params, cfg, seq, want, rope)
     assert err > 100 * TOL["rel_l2_mean"], err
 
 

@@ -20,55 +20,37 @@ import importlib
 import json
 import os
 import struct
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import check, files
+from benchmark import files
 from benchmark.layouts import axk1 as layout
 from dllama_tpu.engine.batch import BatchEngine
-from dllama_tpu.engine.engine import InferenceEngine
 from dllama_tpu.models import formats
 from dllama_tpu.models import llama as model
 from dllama_tpu.models.config import (HeaderKey, LayerKind, LlamaConfig, RopeSpec,
                                       RopeType)
-from dllama_tpu.models.llama import KVCache, forward
 from dllama_tpu.obs import instruments as ins
 from dllama_tpu.ops import layers as ops_layers
 from dllama_tpu.ops.layers import (apply_rope, build_rope_cache, keep_expert_groups,
                                    moe_ffn, yarn_freqs)
 from dllama_tpu.ops.quant import FloatType, QTensor
+from tests import arch
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(ROOT, "benchmark", "tests", "tiny-axk1.json")) as f:
     TINY = json.load(f)
-#: CPU readings, float32 weights and activations, seed 5: sound 1.1e-6 on
-#: both routes; the controls 0.01 to 1
-TOL = {"rel_l2_mean": 1e-4, "deficit_sigma_mean": 1e-3}
-CHECK = {"prompt_lengths": [9, 40, 100], "decode_steps": 64, "tail_tokens": 7}
-ENGINE = dict(n_slots=4, kv_layout="paged", page_size=8, kv_pages=120,
-              radix_cache="auto", max_prefill_chunk=16)
-
-
-def _loaded(path, dtype):
-    cfg, header = formats.read_header(path, 256)
-    params = formats.load_params(path, cfg, header, dtype=dtype)
-    eng = InferenceEngine(cfg, params, cache_dtype=dtype, max_seq_len=256)
-    return types.SimpleNamespace(path=path, config=cfg, params=params, engine=eng)
+#: CPU readings against arch.TOL, seed 5: sound 1.1e-6 on both routes; the
+#: controls 0.01 to 1
+TOL, ENGINE, _tokens = arch.TOL, arch.ENGINE, arch.tokens
 
 
 @pytest.fixture(scope="module")
 def tiny(tmp_path_factory):
-    path = str(tmp_path_factory.mktemp("rot_latent") / "tiny.m")
-    files.write_model(path, TINY, 5)
-    return _loaded(path, jnp.float32)
-
-
-def _tokens(n, seed=0, hi=250):
-    return np.random.default_rng(seed).integers(1, hi, n).tolist()
+    return arch.tiny_file(tmp_path_factory, "rot_latent", TINY)
 
 
 # ------------------------------------------------- files, header, plan
@@ -368,9 +350,7 @@ def test_absorbed_attention_with_the_rotation_is_the_expanded_form():
     ("pallas", "flash", "pallas/paged_kernel.latent.rope_yarn+moe_jnp.groups2of4"),
 ])
 def test_prefill_decode_and_tail_match_the_reference(tiny, kernels, attn, route):
-    cfg = dict(TINY, engine=dict(ENGINE, kernels=kernels, attn_impl=attn),
-               check=CHECK, tolerances=TOL)
-    out = check.run(tiny, cfg, tiny.path, 5)
+    out = arch.run_check(tiny, TINY, kernels, attn)
     assert out["route"] == route
     assert out["correct"], {k: out[k] for k in ("rel_l2_mean", "deficit_sigma_mean")}
     assert out["rel_l2_max"] < 2e-5
@@ -380,11 +360,8 @@ def test_stated_precision_runs_the_kernels(tiny):
     """bfloat16 activations, every kernel in interpret mode (the latent
     sweep over rotated rows, the grouped kernel over the held group): bf16's
     own rounding."""
-    loaded = _loaded(tiny.path, jnp.bfloat16)
-    cfg = dict(TINY, engine=dict(ENGINE, kernels="pallas", attn_impl="flash"),
-               check=dict(CHECK, prompt_lengths=[40, 100], decode_steps=32),
-               tolerances={"rel_l2_mean": 0.04, "deficit_sigma_mean": 0.02})
-    out = check.run(loaded, cfg, tiny.path, 5)
+    out = arch.run_check(arch.loaded(tiny.path, jnp.bfloat16), TINY, "pallas", "flash",
+                         tolerances={"rel_l2_mean": 0.04, "deficit_sigma_mean": 0.02})
     assert out["route"] == "pallas/paged_kernel.latent.rope_yarn+moe_grouped.groups2of4"
     assert out["correct"], {k: out[k] for k in ("rel_l2_mean", "deficit_sigma_mean")}
 
@@ -392,20 +369,10 @@ def test_stated_precision_runs_the_kernels(tiny):
 # ------------------------------------------------------------ the controls
 
 
-def _logits_rel_l2(tiny, cfg, seq, want):
-    cache = KVCache.create(cfg, 1, jnp.float32, 128)
-    got, _ = forward(cfg, tiny.params, jnp.asarray(seq[None]), 0, cache,
-                     build_rope_cache(cfg, 128))
-    return check.rel_l2(np.asarray(got[0, -1]), want)
-
-
 @pytest.fixture(scope="module")
 def sixty(tiny):
-    """60 tokens (past the tiny model's original 32 positions) and the
-    reference's logits at the last."""
-    ref = importlib.import_module(TINY["reference"])
-    seq = np.asarray(_tokens(60, seed=3), np.int32)
-    return seq, ref.logits_at(tiny.path, [seq], [[59]])[0][0]
+    """(past the tiny model's original 32 positions)"""
+    return arch.sixty(tiny, TINY)
 
 
 def _k_pe_unrotated(x, rope):
@@ -451,7 +418,7 @@ def test_each_control_fails_the_tolerance_the_sound_model_holds(
     few of the 60 tokens only: it read 55 x on this file's draw)."""
     seq, want = sixty
     if control is None:
-        assert _logits_rel_l2(tiny, tiny.config, seq, want) < TOL["rel_l2_mean"]
+        assert arch.logits_rel_l2(tiny.params, tiny.config, seq, want) < TOL["rel_l2_mean"]
         return
     fields, patch = CONTROLS[control]
     params = tiny.params
@@ -460,9 +427,8 @@ def test_each_control_fails_the_tolerance_the_sound_model_holds(
                                       if not k.startswith("shared_")})
     elif patch is not None:
         monkeypatch.setattr(*patch)
-    wrong = types.SimpleNamespace(params=params)
     cfg = dataclasses.replace(tiny.config, **fields)
-    err = _logits_rel_l2(wrong, cfg, seq, want)
+    err = arch.logits_rel_l2(params, cfg, seq, want)
     assert err > 30 * TOL["rel_l2_mean"], err
 
 

@@ -5,8 +5,7 @@ machine AND through a real scheduler run with faults off), SLO policy
 verdicts, and the perf aggregator's goodput accounting.
 
 Everything except the one real-scheduler run is pure host (no engine, no
-compile) — this file sits in conftest's _RUN_FIRST band of the
-time-budgeted tier-1 window."""
+compile): seconds of the tier-1 run."""
 
 import math
 import random

@@ -20,7 +20,6 @@ reuse; speculation is refused; files, header and converter.
 """
 
 import hashlib
-import types
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +35,7 @@ from dllama_tpu.models.config import ArchType, LayerKind, LlamaConfig, RopeType
 from dllama_tpu.models.llama import KVCache, forward, layer_schedule
 from dllama_tpu.obs import instruments as ins
 from dllama_tpu.ops import ssm
+from tests import arch
 
 TINY = {
     "name": "tiny-hybrid", "model_type": "granitemoehybrid",
@@ -56,11 +56,13 @@ TINY = {
     "reference": "benchmark.reference.granite_hybrid",
     "weights": {"attention_sharpness": 1.5},
 }
-#: CPU readings, float32 weights and activations, seeds 1-3 (PERF.md section
-#: 4): sound 1.0e-6, S held in bfloat16 1.8e-3 to 3.1e-3. 1e-4 is 100 x the
-#: worst sound reading and 1/18 of the best control reading.
-TOL = {"rel_l2_mean": 1e-4, "deficit_sigma_mean": 1e-3}
-CHECK = {"prompt_lengths": [9, 40, 100], "decode_steps": 32, "tail_tokens": 7}
+#: CPU readings against arch.TOL, float32 weights and activations, seeds 1-3
+#: (PERF.md section 4): sound 1.0e-6, S held in bfloat16 1.8e-3 to 3.1e-3.
+#: 1e-4 is 100 x the worst sound reading and 1/18 of the best control reading.
+TOL, _tokens = arch.TOL, arch.tokens
+#: pages of 16 rows, slices up to the engine's own cap: this file's check
+#: runs 32 decode steps (two pages a slot) where arch.CHECK's long one runs 64
+CHECK = dict(arch.CHECK["xla"], decode_steps=32)
 ENGINE = dict(n_slots=4, kv_layout="paged", page_size=16, kv_pages=40,
               radix_cache="auto")
 #: sha256 of the tiny file by seed: adding a layout or editing the writer
@@ -78,14 +80,7 @@ def sha256(path):
 
 @pytest.fixture(scope="module")
 def tiny(tmp_path_factory):
-    """(model path, program config, float32 params, a `loaded` stand-in as
-    benchmark/check.py wants it)."""
-    path = str(tmp_path_factory.mktemp("hybrid") / "tiny.m")
-    files.write_model(path, TINY, 5)
-    cfg, header = formats.read_header(path, 256)
-    params = formats.load_params(path, cfg, header, dtype=jnp.float32)
-    eng = InferenceEngine(cfg, params, cache_dtype=jnp.float32, max_seq_len=256)
-    return types.SimpleNamespace(path=path, config=cfg, params=params, engine=eng)
+    return arch.tiny_file(tmp_path_factory, "hybrid", TINY)
 
 
 _ENGINES: dict = {}
@@ -107,10 +102,6 @@ def _prefill(be, slot, toks, start_pos=0):
     while not be.add_step(adm):
         pass
     return adm
-
-
-def _tokens(n, seed=0, hi=250):
-    return np.random.default_rng(seed).integers(1, hi, n).tolist()
 
 
 # ----------------------------------------------------- files, header, plan

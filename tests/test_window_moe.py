@@ -24,48 +24,34 @@ import dataclasses
 import hashlib
 import json
 import os
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import check, files
+from benchmark import files
 from benchmark.layouts import smallthinker as layout
 from dllama_tpu.engine.batch import BatchEngine, StateNotResumable
-from dllama_tpu.engine.engine import InferenceEngine
 from dllama_tpu.models import formats
 from dllama_tpu.models.config import HiddenAct, LlamaConfig
-from dllama_tpu.models.llama import KVCache, forward
 from dllama_tpu.obs import instruments as ins
-from dllama_tpu.ops.layers import build_rope_cache, expert_groups, expert_rows, moe_ffn
+from dllama_tpu.ops.layers import expert_groups, expert_rows, moe_ffn
 from dllama_tpu.ops.quant import QTensor
+from tests import arch
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(ROOT, "benchmark", "tests", "tiny-smallthinker.json")) as f:
     TINY = json.load(f)
-#: CPU readings, float32 weights and activations, seed 5: sound 8e-7 to 1e-6
-#: on both routes; the four controls 0.03 to 1.1 (PERF.md section 4)
-TOL = {"rel_l2_mean": 1e-4, "deficit_sigma_mean": 1e-3}
-CHECK = {"prompt_lengths": [9, 40, 100], "decode_steps": 64, "tail_tokens": 7}
-ENGINE = dict(n_slots=4, kv_layout="paged", page_size=8, kv_pages=120,
-              radix_cache="auto", max_prefill_chunk=16)
+#: CPU readings against arch.TOL, seed 5: sound 8e-7 to 1e-6 on both routes;
+#: the four controls 0.03 to 1.1 (PERF.md section 4)
+TOL, ENGINE, _tokens = arch.TOL, arch.ENGINE, arch.tokens
 WINDOW, PAGE = 16, 8
-
-
-def _loaded(path, dtype):
-    cfg, header = formats.read_header(path, 256)
-    params = formats.load_params(path, cfg, header, dtype=dtype)
-    eng = InferenceEngine(cfg, params, cache_dtype=dtype, max_seq_len=256)
-    return types.SimpleNamespace(path=path, config=cfg, params=params, engine=eng)
 
 
 @pytest.fixture(scope="module")
 def tiny(tmp_path_factory):
-    path = str(tmp_path_factory.mktemp("window_moe") / "tiny.m")
-    files.write_model(path, TINY, 5)
-    return _loaded(path, jnp.float32)
+    return arch.tiny_file(tmp_path_factory, "window_moe", TINY)
 
 
 def _prefill(be, slot, toks, start_pos=0):
@@ -73,10 +59,6 @@ def _prefill(be, slot, toks, start_pos=0):
     while not be.add_step(adm):
         pass
     return adm
-
-
-def _tokens(n, seed=0, hi=250):
-    return np.random.default_rng(seed).integers(1, hi, n).tolist()
 
 
 # ------------------------------------------------- files, header, plan
@@ -137,9 +119,7 @@ def test_the_accepted_layouts_write_the_bytes_they_wrote(name, sha, tmp_path):
 ])
 def test_prefill_decode_past_the_window_and_tail_match_the_reference(
         tiny, kernels, attn, route):
-    cfg = dict(TINY, engine=dict(ENGINE, kernels=kernels, attn_impl=attn),
-               check=CHECK, tolerances=TOL)
-    out = check.run(tiny, cfg, tiny.path, 5)
+    out = arch.run_check(tiny, TINY, kernels, attn)
     assert out["route"] == route
     assert out["correct"], {k: out[k] for k in ("rel_l2_mean", "deficit_sigma_mean")}
     assert out["rel_l2_max"] < 1e-5
@@ -148,11 +128,8 @@ def test_prefill_decode_past_the_window_and_tail_match_the_reference(
 def test_stated_precision_runs_the_grouped_kernel_and_the_windowed_sweep(tiny):
     """bfloat16 activations, both kernels in interpret mode: bf16's own
     rounding (CPU reading 0.011; the jnp route in bf16 reads 0.016)."""
-    loaded = _loaded(tiny.path, jnp.bfloat16)
-    cfg = dict(TINY, engine=dict(ENGINE, kernels="pallas", attn_impl="flash"),
-               check=dict(CHECK, prompt_lengths=[40, 100], decode_steps=32),
-               tolerances={"rel_l2_mean": 0.04, "deficit_sigma_mean": 0.02})
-    out = check.run(loaded, cfg, tiny.path, 5)
+    out = arch.run_check(arch.loaded(tiny.path, jnp.bfloat16), TINY, "pallas", "flash",
+                         tolerances={"rel_l2_mean": 0.04, "deficit_sigma_mean": 0.02})
     assert out["route"] == "pallas/paged_kernel.window+moe_grouped"
     assert out["correct"], {k: out[k] for k in ("rel_l2_mean", "deficit_sigma_mean")}
 
@@ -165,21 +142,9 @@ CONTROLS = {
 }
 
 
-def _logits_rel_l2(tiny, cfg, seq, want):
-    cache = KVCache.create(cfg, 1, jnp.float32, 128)
-    got, _ = forward(cfg, tiny.params, jnp.asarray(seq[None]), 0, cache,
-                     build_rope_cache(cfg, 128))
-    return check.rel_l2(np.asarray(got[0, -1]), want)
-
-
 @pytest.fixture(scope="module")
 def past_the_window(tiny):
-    """60 tokens (past the window) and the reference's logits at the last."""
-    import importlib
-
-    ref = importlib.import_module(TINY["reference"])
-    seq = np.asarray(_tokens(60, seed=3), np.int32)
-    return seq, ref.logits_at(tiny.path, [seq], [[59]])[0][0]
+    return arch.sixty(tiny, TINY)
 
 
 @pytest.mark.parametrize("control", [None, *CONTROLS])
@@ -190,10 +155,10 @@ def test_each_control_fails_the_tolerance_the_sound_model_holds(
     departure from the equations is refused by the limit."""
     seq, want = past_the_window
     if control is None:
-        assert _logits_rel_l2(tiny, tiny.config, seq, want) < TOL["rel_l2_mean"]
+        assert arch.logits_rel_l2(tiny.params, tiny.config, seq, want) < TOL["rel_l2_mean"]
         return
     wrong = dataclasses.replace(tiny.config, **CONTROLS[control])
-    assert _logits_rel_l2(tiny, wrong, seq, want) > 100 * TOL["rel_l2_mean"]
+    assert arch.logits_rel_l2(tiny.params, wrong, seq, want) > 100 * TOL["rel_l2_mean"]
 
 
 # ------------------------------------------------ the grouped expert kernel
@@ -310,11 +275,14 @@ def test_window_pages_go_back_and_no_slot_holds_more_than_a_window(tiny):
 def test_the_trash_entry_is_never_read(tiny):
     """The windowed sweep starts at the first block that holds a visible
     row: NaN in the window pool's trash page (where every handed-back
-    entry points) changes no token of a slot that decodes."""
+    entry points) changes no token of a slot that decodes. One engine (one
+    set of interpret-mode compiles), rebuilt between the two runs."""
+    be = BatchEngine(tiny.config, tiny.params, cache_dtype=jnp.float32,
+                     max_seq_len=256, **dict(ENGINE, n_slots=2),
+                     kernels="pallas", attn_impl="flash")
+
     def run(poison):
-        be = BatchEngine(tiny.config, tiny.params, cache_dtype=jnp.float32,
-                         max_seq_len=256, **dict(ENGINE, n_slots=2),
-                         kernels="pallas", attn_impl="flash")
+        be.warm_restart()
         for slot in (0, 1):
             be.add_commit(_prefill(be, slot, _tokens(40, seed=slot)), temperature=0.0)
         assert int(be.wpool.head[0]) > 0
