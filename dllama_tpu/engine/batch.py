@@ -941,8 +941,12 @@ class BatchEngine:
         self._moe_seen = np.zeros(  # see _moe_count
             0 if self.cache.moe_stats is None else self.cache.moe_stats.shape[0],
             np.uint32)
+        # what a launch's B = 1 prefill slice cuts out of the layer-stacked
+        # state and puts back (the launch record counts it)
+        self._state_slice_bytes = 0
         if self.cache.state is not None:
             ins.RECURRENT_STATE_BYTES.set(self.cache.state.nbytes)
+            self._state_slice_bytes = 2 * self.cache.state.slot_bytes
         if radix_cache not in ("auto", "on", "off"):
             raise ValueError(
                 f"radix_cache must be auto|on|off, got {radix_cache!r}")
@@ -2554,7 +2558,9 @@ class BatchEngine:
         n, off, slot = len(adm.toks), adm.off, adm.slot
         c = pow2_chunk(n - off, self.max_prefill_chunk)
         # a prefill chunk runs no decode step: its record is its rows
-        rec = launch_record.LaunchRecord("prefill_chunk", prefill_rows=c)
+        rec = launch_record.LaunchRecord(
+            "prefill_chunk", prefill_rows=c,
+            state_slice_bytes=self._state_slice_bytes)
         t_disp = time.monotonic()
         if self.spec_k:
             # the n-gram proposer drafts from the prompt too — that's the
@@ -2865,7 +2871,8 @@ class BatchEngine:
             seq_len=self.seq_len, pool_dry=self._pool_dry(),
             prefill_rows=prefill_rows,
             window=self.window, kv_pool=self._kv_pool,
-            kind_layers=self._kind_layers)
+            kind_layers=self._kind_layers,
+            state_slice_bytes=self._state_slice_bytes)
 
     def decode_dispatch(self, n: int, spec: bool = False) -> DecodeChunk:
         """Dispatch one fused n-step decode chunk WITHOUT waiting for its

@@ -47,8 +47,9 @@ class KernelSelection:
     backend: str  # 'pallas' | 'xla' (what the quantized matmuls run on)
     attn_route: str = "jnp"  # which attention path attn_fn resolves to:
     # 'jnp' | 'flash' | 'sharded_flash' | 'ring' | 'paged_kernel' |
-    # 'paged_gather' — the single string obs/README/the benchmark quote
-    # for "what actually runs"
+    # 'paged_gather' | 'no_cache_rows' (a model none of whose layers holds
+    # cache rows) — the single string obs/README/the benchmark quote for
+    # "what actually runs"
     interpret: bool = False  # Pallas interpret mode baked into attn_fn (and
     # what ops.matmul derives for the matmuls): true only off-TPU
 
@@ -71,7 +72,8 @@ class KernelSelection:
         '.ropes2' where they have a rope table each),
         and behind a '+' each the recurrent state's
         decode step and element type where the model has one
-        ('paged_kernel+ssm_step.float32', '...+kda_step.float32') and the expert layers' route
+        ('paged_kernel+ssm_step.float32', '...+kda_step.float32',
+        '...+retention_step.float32') and the expert layers' route
         where it has experts ('paged_kernel.window+moe_grouped',
         '...+moe_grouped.groups4of8' where the selection is group-limited)."""
         return (self.attn_route
@@ -108,7 +110,8 @@ def resolve_state_step(cfg: LlamaConfig, batch: int, backend: str,
                        state_dtype=None) -> tuple[Callable | None, str]:
     """(step, route) of the recurrent layers' decode step for a model with
     recurrent state (state-space layers: `ssm_step`; delta-rule layers:
-    `kda_step`), (None, '') for any other. The in-place Pallas kernel serves
+    `kda_step`; power-retention layers: `retention_step`), (None, '') for any
+    other. The in-place Pallas kernel serves
     where the quantized matmuls run on Pallas and the kernel takes the
     state's shape and element type (32-bit); everything else is the jnp
     step, and the route says so."""
@@ -118,12 +121,15 @@ def resolve_state_step(cfg: LlamaConfig, batch: int, backend: str,
 
     from dllama_tpu.ops.matmul import device_platform, resolve_backend
 
-    if cfg.n_kda_layers:
+    name = cfg.state_kind
+    if name == "retention":
+        from dllama_tpu.ops.pallas.retention_step import (
+            retention_step as step, supported)
+        supported = partial(supported, head_size=cfg.head_size)
+    elif name == "kda":
         from dllama_tpu.ops.pallas.kda_step import kda_step as step, supported
-        name = "kda"
     else:
         from dllama_tpu.ops.pallas.ssm_step import ssm_step as step, supported
-        name = "ssm"
     dtype = jnp.dtype(jnp.float32 if state_dtype is None else state_dtype)
     shape = (cfg.n_state_layers, batch, *cfg.state_shape)
     if resolve_backend(backend) == "pallas" and supported(shape, dtype):
@@ -227,6 +233,13 @@ def resolve_kernels(
            if cfg.grouped_routing else "")))
     windowed = cfg.n_window_layers > 0
 
+    if not cfg.n_attn_layers:
+        # no layer holds cache rows: there is no attention to route, in
+        # either layout (the caches have a layer axis of 0)
+        return KernelSelection(mm=mm, mm_in=mm_in, attn_fn=None,
+                               backend=backend, attn_route="no_cache_rows",
+                               interpret=not on_tpu, state_step=state_step,
+                               state_route=state_route, **moe)
     if paged and shardings is None:
         # paged KV cache (BatchEngine --kv-layout paged; unsharded only — the
         # page pool has no slot axis for a dp mesh to shard, and BatchEngine

@@ -9,7 +9,8 @@ exist:
 
 * the counters `dllama_launches_total{kind}`,
   `dllama_slot_steps_total{state}`, `dllama_launch_kv_rows_total{kind}`,
-  `dllama_launch_prefill_rows_total{kind}` (always on, O(1) a launch);
+  `dllama_launch_prefill_rows_total{kind}`, `dllama_state_slice_bytes_total`
+  (always on, O(1) a launch);
 * the args of the launch's span in the tracer ring (`decode.device` /
   `decode.spec`, track `launches`), behind `tr.enabled`;
 * while a jax.profiler capture runs, a `dllama.launch.<kind>` annotation
@@ -85,6 +86,8 @@ class LaunchRecord:
     kind_layers: tuple = (0, 0)  # a model with windowed layers: how many
     # layers see the whole context and how many are windowed (the rows a
     # KIND walks are a layer's rows times its layers)
+    state_slice_bytes: int = 0  # a recurrent model: the bytes of state a
+    # launch's B = 1 prefill slice cuts out of the stack and puts back
 
     def args(self) -> dict:
         """The span / annotation arguments (`kind` is in the name too)."""
@@ -108,6 +111,8 @@ class LaunchRecord:
         if self.prefill_rows:
             ins.LAUNCH_PREFILL_ROWS.labels(kind=self.kind).inc(
                 self.prefill_rows)
+            if self.state_slice_bytes:
+                ins.STATE_SLICE_BYTES.inc(self.state_slice_bytes)
         if self.kv_rows_window is not None and self.kv_rows:
             read = ins.LAUNCH_KV_ROWS_READ
             read.labels(kind=self.kind, pool="global").inc(self.kv_rows)
@@ -140,7 +145,8 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
           active: np.ndarray, advance: np.ndarray, *, seq_len: int,
           pool_dry: bool, prefill_rows: int = 0,
           frozen: np.ndarray | None = None, window: int = 0,
-          kv_pool: str = "", kind_layers: tuple = (0, 0)) -> LaunchRecord:
+          kv_pool: str = "", kind_layers: tuple = (0, 0),
+          state_slice_bytes: int = 0) -> LaunchRecord:
     """The record of a launch of `n` steps over slots at `start_pos`, of
     which the `active` ones advance `advance` rows each.
 
@@ -175,4 +181,5 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
         kv_rows=int((adv * pos + adv * (adv + 1) // 2).sum()),
         prefill_rows=int(prefill_rows), pool_dry=bool(pool_dry),
         kv_rows_window=kv_rows_window, kv_pool=kv_pool,
-        kind_layers=kind_layers)
+        kind_layers=kind_layers,
+        state_slice_bytes=state_slice_bytes)

@@ -11,6 +11,7 @@ from __future__ import annotations
 import dataclasses
 from enum import IntEnum
 
+from dllama_tpu.ops.power import state_dims
 from dllama_tpu.ops.quant import FloatType
 
 MODEL_MAGIC = 0x0A00ABCD  # llm.cpp:46-48 (magic 0xA00ABCD)
@@ -31,11 +32,14 @@ class LayerKind(IntEnum):
     KDA = 2  # gated delta-rule linear attention, a decay per key channel:
     # a [key, value] matrix state a head (ops/delta.py)
     MLA = 3  # latent attention: one shared low-rank row a token in the cache
+    RETENTION = 4  # power retention: gated linear attention over the symmetric
+    # square of rotated keys, a [value + 1, expanded key] state a kv head that
+    # its query heads share (ops/power.py)
 
 
 #: the kinds whose layers hold per-sequence recurrent state (at most one of
 #: them in a model) and those that hold cache rows (likewise)
-STATE_KINDS = (LayerKind.SSM, LayerKind.KDA)
+STATE_KINDS = (LayerKind.SSM, LayerKind.KDA, LayerKind.RETENTION)
 
 
 class HiddenAct(IntEnum):
@@ -152,6 +156,12 @@ class HeaderKey(IntEnum):
     GLOBAL_ROPE_BETA_FAST_X1E6 = 175
     GLOBAL_ROPE_BETA_SLOW_X1E6 = 176
     GLOBAL_ROPE_ATTN_FACTOR_X1E6 = 177  # cos and sin are multiplied by it
+    # ---- dllama-tpu extensions for LayerKind.RETENTION layers (their q, k,
+    # v, o and head norms are the attention tensors; N_HEADS query heads read
+    # N_KV_HEADS states)
+    RET_DEGREE = 180  # the power p of (q . k)^p; 2 is what is computed
+    RET_GATE = 181  # 1 = the state decays by sigmoid(x W_g + b_g), one gate
+    # a kv head and row (tensors ret_gate, ret_gate_bias)
 
 
 #: the kind of layer i is header key LAYER_KIND_BASE + i (one key a layer,
@@ -168,13 +178,13 @@ LAYER_ROPE_BASE = 3000
 LAYER_FFN_BASE = 4000
 _LAYER_LISTS_END = 5000
 
-#: `LlamaConfig.schedule_kinds` entries: a LayerKind in the low two bits, and
-#: beside it whether the layer is windowed and whether it leaves q and k
+#: `LlamaConfig.schedule_kinds` entries: a LayerKind in the low three bits,
+#: and beside it whether the layer is windowed and whether it leaves q and k
 #: unrotated where the model rotates
-SCHEDULE_KIND_MASK = 3
-SCHEDULE_WINDOWED = 4
-SCHEDULE_UNROTATED = 8
-SCHEDULE_DENSE_FFN = 16  # a dense feed-forward block in a model with experts
+SCHEDULE_KIND_MASK = 7
+SCHEDULE_WINDOWED = 8
+SCHEDULE_UNROTATED = 16
+SCHEDULE_DENSE_FFN = 32  # a dense feed-forward block in a model with experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -272,6 +282,9 @@ class LlamaConfig:
     attn_gate: bool = False
     global_rope: RopeSpec | None = None  # the global (not windowed) layers'
     # own rope table; None = they rotate as the others
+    # ---- power retention layers (LayerKind.RETENTION)
+    ret_degree: int = 0
+    ret_gate: bool = False
 
     def __post_init__(self):
         if self.orig_seq_len == 0:
@@ -296,8 +309,20 @@ class LlamaConfig:
             raise ValueError("only a softmax attention layer can be windowed")
         kinds = set(self.layer_kinds)
         if len(kinds & set(STATE_KINDS)) > 1:
-            raise ValueError("state-space and delta-rule layers in one model "
+            raise ValueError("recurrent layers of two kinds in one model "
                              "are not supported (one recurrent state a slot)")
+        if LayerKind.RETENTION in kinds and not (
+                self.ret_degree == 2 and self.ret_gate
+                and self.head_size % 2 == 0
+                and self.n_heads % self.n_kv_heads == 0):
+            raise ValueError("power retention layers need RET_DEGREE 2 (the "
+                             "symmetric square is what is computed), RET_GATE "
+                             "1, an even head size and a whole number of "
+                             "query heads a kv head")
+        if {int(LayerKind.RETENTION), int(LayerKind.ATTENTION)} <= kinds:
+            raise ValueError("power retention and softmax attention layers in "
+                             "one model are not supported (they hold the same "
+                             "tensors, stacked by name)")
         if {int(LayerKind.ATTENTION), int(LayerKind.MLA)} <= kinds:
             raise ValueError("softmax and latent attention layers in one "
                              "model are not supported (one row width a cache)")
@@ -453,9 +478,22 @@ class LlamaConfig:
         return sum(1 for k in self.layer_kinds if k == LayerKind.KDA)
 
     @property
+    def n_retention_layers(self) -> int:
+        return sum(1 for k in self.layer_kinds if k == LayerKind.RETENTION)
+
+    @property
     def n_state_layers(self) -> int:
         """Layers that hold recurrent state: the state's layer axis."""
-        return self.n_ssm_layers + self.n_kda_layers
+        return (self.n_ssm_layers + self.n_kda_layers
+                + self.n_retention_layers)
+
+    @property
+    def state_kind(self) -> str:
+        """What the routes, `/health` and the launch record call the
+        recurrent layers' kind ('' where the model has none)."""
+        return ("retention" if self.n_retention_layers else
+                "kda" if self.n_kda_layers else
+                "ssm" if self.n_ssm_layers else "")
 
     @property
     def n_attn_layers(self) -> int:
@@ -470,14 +508,26 @@ class LlamaConfig:
     @property
     def state_shape(self) -> tuple:
         """(heads, rows, lanes) of one slot's state in one layer: a
-        state-space head's [P, N], a delta-rule head's [key, value]."""
+        state-space head's [P, N], a delta-rule head's [key, value], a
+        retention kv head's [value + 1, expanded key]: the head's values
+        and, as one more row, the normaliser, over the products of two key
+        dims, rows and lanes rounded up to whole tiles (ops/power.state_dims
+        has why): 8 x 136 x 8,320 float32 = 36.2 MB held for the 34.1 MB of
+        8 x 129 x 8,256 at 8 kv heads of 128, where the other two kinds
+        hold about 2 MB."""
+        if self.n_retention_layers:
+            return (self.n_kv_heads, *state_dims(self.head_size))
         if self.n_kda_layers:
             return (self.kda_heads, self.kda_head_dim, self.kda_head_dim)
         return (self.ssm_heads, self.ssm_head_dim, self.ssm_state)
 
     @property
     def state_conv(self) -> tuple:
-        """(rows, channels) of one slot's conv window in one layer."""
+        """(rows, channels) of one slot's conv window in one layer: the
+        last taps - 1 rows of x|B|C (state-space) or q|k|v (delta rule);
+        EMPTY for retention layers, which have no conv."""
+        if self.n_retention_layers:
+            return (0, 0)
         if self.n_kda_layers:
             return (self.kda_conv - 1, 3 * self.kda_inner)
         return (self.ssm_conv - 1, self.ssm_conv_dim)
@@ -581,6 +631,9 @@ class LlamaConfig:
             + (f" kda_layers={self.n_kda_layers}/{self.n_layers} "
                f"kda={self.kda_heads}x{self.kda_head_dim}x{self.kda_head_dim}"
                if self.n_kda_layers else "")
+            + (f" retention_layers={self.n_retention_layers}/{self.n_layers} "
+               f"p={self.ret_degree} state={'x'.join(map(str, self.state_shape))}"
+               if self.n_retention_layers else "")
             + (f" latent={self.kv_lora_rank}+{self.qk_pe_dim}" if self.latent else "")
             + (f" q_rank={self.q_lora_rank}" if self.q_lora_rank else "")
             + (f" expert_groups={self.expert_groups_kept}/{self.n_expert_groups}"
@@ -659,6 +712,10 @@ class LlamaConfig:
             kv.append((HeaderKey.QK_NORM, 1))
         if self.attn_gate:
             kv.append((HeaderKey.ATTN_GATE, 1))
+        if self.ret_degree:
+            kv.append((HeaderKey.RET_DEGREE, self.ret_degree))
+        if self.ret_gate:
+            kv.append((HeaderKey.RET_GATE, 1))
         if self.global_rope is not None:
             kv += [(key, int(round(getattr(self.global_rope, name) * mult)))
                    for key, (name, mult) in _GLOBAL_ROPE_KEYS.items()]
@@ -752,6 +809,10 @@ class LlamaConfig:
                 vals["qk_norm"] = bool(value)
             elif key == HeaderKey.ATTN_GATE:
                 vals["attn_gate"] = bool(value)
+            elif key == HeaderKey.RET_DEGREE:
+                vals["ret_degree"] = value
+            elif key == HeaderKey.RET_GATE:
+                vals["ret_gate"] = bool(value)
             elif key in _GLOBAL_ROPE_KEYS:
                 name, mult = _GLOBAL_ROPE_KEYS[key]
                 grope[name] = type(_ROPE_DEFAULTS[name])(value / mult)

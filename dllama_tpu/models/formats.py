@@ -21,7 +21,10 @@ order (llm.cpp:453-468):
              [head], kda_o [dim, inner]; a latent attention layer
              (LayerKind.MLA): mla_q [heads*(nope+pe), dim], mla_kva [rank+pe,
              dim], mla_kv_norm f32 [rank], mla_kvb [heads*(nope+v), rank],
-             mla_o [dim, heads*v]. An expert layer holds moe_gate f32
+             mla_o [dim, heads*v]; a power-retention layer
+             (LayerKind.RETENTION): q/k/v/wo and the head norms as an
+             attention layer's, then ret_gate f32 [kv_heads, dim] and
+             ret_gate_bias f32 [kv_heads]. An expert layer holds moe_gate f32
              [experts, dim] (every column, whatever the file holds of the
              experts), moe_bias f32 [experts] under a sigmoid router, the
              held experts' stacks at the expert width, and shared_w1/w2/w3
@@ -158,7 +161,8 @@ def tensor_plan(config: LlamaConfig) -> list[tuple[str, tuple[int, int] | tuple[
                 (p + "mla_o", (config.dim, h * config.v_head_dim), wt),
             ]
         else:
-            # a layer's attention tensors go by its kind, windowed or global,
+            # softmax attention, or power retention over the same tensors. A
+            # layer's attention tensors go by its kind, windowed or global,
             # where the header gives the windowed layers heads of their own:
             # `*_win` names, so the loader stacks the two kinds apart
             win = bool(config.layer_window(layer))
@@ -176,6 +180,12 @@ def tensor_plan(config: LlamaConfig) -> list[tuple[str, tuple[int, int] | tuple[
             if config.attn_gate:
                 plan.append((p + "attn_gate" + sfx, (heads, config.dim),
                              FloatType.F32))
+            if kind == LayerKind.RETENTION:
+                # the decay's gate, one a kv head: projection and bias
+                plan += [(p + "ret_gate", (config.n_kv_heads, config.dim),
+                          FloatType.F32),
+                         (p + "ret_gate_bias", (config.n_kv_heads,),
+                          FloatType.F32)]
         if config.n_experts and not (config.layer_ffn and config.layer_ffn[layer]):
             # MoE extension: the reference header carries N_EXPERTS
             # (llm.hpp:17-18) and its HF converter emits expert tensors
@@ -433,7 +443,7 @@ def _load_matmul(raw: np.ndarray, shape: tuple[int, int], ft: FloatType, dtype, 
 _F32_LEAVES = ("rms_att", "rms_ffn", "conv_w", "conv_b", "dt_bias", "a_log",
                "d", "ssm_norm", "kda_conv_w", "kda_dt_bias", "kda_a_log",
                "kda_norm", "mla_kv_norm", "mla_q_norm", "moe_bias", "q_norm", "k_norm",
-               "q_norm_win", "k_norm_win")
+               "q_norm_win", "k_norm_win", "ret_gate_bias")
 #: matmul weights whose published output width is not whole lane tiles: zero
 #: columns are added on the way to the device, the file keeps the width
 #: (kda_proj to whole 512s so that the wide tiles divide it)
@@ -527,10 +537,10 @@ def load_params(
                 # are held as the float32 values the Q40 blocks decode to
                 leaf = decode_dense(raw, shape, ft).reshape(
                     config.n_heads, -1, shape[1]).astype(np.float32, order="C")
-            elif short in ("moe_gate", "attn_gate", "attn_gate_win"):
+            elif short in ("moe_gate", "attn_gate", "attn_gate_win", "ret_gate"):
                 # router stays f32; file [E, dim] -> h@gate operand [dim, E]
-                # (the attention output's gate likewise: [heads, dim] ->
-                # [dim, heads])
+                # (the attention output's gate and the retention decay's
+                # likewise: [heads, dim] -> [dim, heads])
                 leaf = decode_dense(raw, shape, ft).T.astype(np.float32, order="C")
             elif short.startswith("moe_"):
                 leaf = _load_expert_matmul(raw, shape, ft, dtype, dequantize)

@@ -12,6 +12,7 @@ inserted by XLA from the weight/cache shardings (parallel/sharding.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable
 
 import jax
@@ -27,7 +28,7 @@ from dllama_tpu.models.config import (
     LlamaConfig,
     RopeType,
 )
-from dllama_tpu.ops import delta, ssm
+from dllama_tpu.ops import delta, power, ssm
 from dllama_tpu.ops.layers import (
     activation,
     apply_rope,
@@ -44,33 +45,41 @@ from dllama_tpu.ops.matmul import matmul
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class RecurrentState:
-    """The recurrent layers' per-sequence state, beside the KV cache (a
-    state-space head's [P, N] running sum, or a delta-rule head's [key,
-    value] matrix, `LlamaConfig.state_shape`; the conv's channels are x|B|C
-    or q|k|v, `state_conv`):
+    """The recurrent layers' per-sequence state, beside the KV cache
+    (`LlamaConfig.state_shape` and `state_conv` say what a slot holds in a
+    layer for each of the three kinds):
 
       s    [Ls, B, H, P, N]   the recurrence's running sum, float32 unless
                               constructed otherwise (it sums over the whole
                               context; a narrower `dtype` is the control the
-                              tests hold against the tolerance)
+                              tests hold against the tolerance): a
+                              state-space head's [P, N], a delta-rule head's
+                              [key, value], a retention kv head's [value + 1,
+                              expanded key] (the row behind its values the
+                              normaliser), padded to whole tiles
       conv [Ls, B, K-1, C]    the causal conv's window: the last K-1 rows
-                              of x|B|C before activation, in the
-                              activations' own type (bf16 as served: the
-                              rows ARE bf16 activations, nothing is lost)
+                              of x|B|C (state-space) or q|k|v (delta rule)
+                              before activation, in the activations' own
+                              type (bf16 as served: the rows ARE bf16
+                              activations, nothing is lost); EMPTY
+                              ([Ls, B, 0, 0]) for retention layers, which
+                              have no conv
 
-    Ls = state-space layers, B = batch rows or serving slots. Fixed-size a
+    Ls = recurrent layers, B = batch rows or serving slots. Fixed-size a
     slot, not pageable, and it CANNOT be rewound: it stands at one row of
     its sequence, and the engines keep which (engine/batch.BatchEngine).
     Carried whole through the layer scan and the step scan and updated in
     place, as the page pool is. `slot` (a traced scalar) narrows every read
     and write to that one slot at B = 1 — an admission's prefill slice cuts
-    2 MB a layer out of the stack and puts it back, never the stack.
+    a slot's layer out of the stack and puts it back (2 MB for a
+    state-space or delta-rule layer, 36.2 MB for a retention layer of 8 kv
+    heads of 128: `slot_bytes`), never the stack.
 
     `step` (static, not a leaf) is the whole-batch decode step on the
     layer-stacked `s` that the engine's kernel selection resolved
     (engine/kernel_select.resolve_state_step, named in the route tag); None
-    = the jnp step on a layer's slice (ops/ssm.ssm_step_ref). The model
-    asks nothing else about kernels."""
+    = the jnp step on a layer's slice (ops/ssm.ssm_step_ref and its
+    siblings). The model asks nothing else about kernels."""
 
     s: jax.Array
     conv: jax.Array
@@ -97,6 +106,12 @@ class RecurrentState:
     def nbytes(self) -> int:
         return int(self.s.nbytes + self.conv.nbytes)
 
+    @property
+    def slot_bytes(self) -> int:
+        """One slot's state over every layer: what a B = 1 slice cuts out
+        of the stack, and what it puts back."""
+        return self.nbytes // self.s.shape[1]
+
     def at_slot(self, slot) -> "RecurrentState":
         return RecurrentState(self.s, self.conv, slot, self.step)
 
@@ -119,11 +134,12 @@ class RecurrentState:
         return self._cut(self.s, si)
 
     def replace_layer(self, si, window, s_new=None, s_stack=None) -> "RecurrentState":
-        """Layer si's window put back, and its S (`s_new`, [B, H, P, N]) —
-        or the whole stack where a kernel already updated it in place."""
+        """Layer si's window put back (None: the kind has no conv), and its
+        S (`s_new`, [B, H, P, N]) — or the whole stack where a kernel
+        already updated it in place."""
         s = s_stack if s_stack is not None else self._put(self.s, si, s_new)
-        return RecurrentState(s, self._put(self.conv, si, window), self.slot,
-                              self.step)
+        conv = self.conv if window is None else self._put(self.conv, si, window)
+        return RecurrentState(s, conv, self.slot, self.step)
 
 
 def _moe_stats0(cfg: LlamaConfig):
@@ -341,6 +357,30 @@ def _paged_cache_update(pool, new, tables, pos_base, active):
 from dllama_tpu.ops.quant import slice_leaf as _slice_layer
 
 
+def _qkv_heads(cfg: LlamaConfig, h, layers, ai, mm, heads: int, sfx: str = ""):
+    """q [B, T, heads, hd], k and v [B, T, kv heads, hd] of layer `ai` of the
+    `wq` / `wk` / `wv` stacks named by `sfx`, RMS-normed over the head where
+    the header says so (before any rotation): what softmax attention and
+    retention layers both start from."""
+    b, t, _ = h.shape
+    d, kvd = heads * cfg.head_size, cfg.kv_dim
+    if "wqkv" + sfx in layers:  # fused launch (fuse_layer_weights)
+        qkv = mm(h, layers["wqkv" + sfx], ai)
+        q, k, v = qkv[..., :d], qkv[..., d : d + kvd], qkv[..., d + kvd :]
+    else:
+        q = mm(h, layers["wq" + sfx], ai)
+        k = mm(h, layers["wk" + sfx], ai)
+        v = mm(h, layers["wv" + sfx], ai)
+    q = q.reshape(b, t, heads, cfg.head_size)
+    k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_size)
+    v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_size)
+    if cfg.qk_norm:  # over the head, before the rotation
+        with jax.named_scope("qk_norm"):
+            q = rms_norm(q, layers["q_norm" + sfx][ai], cfg.norm_epsilon)
+            k = rms_norm(k, layers["k_norm" + sfx][ai], cfg.norm_epsilon)
+    return q, k, v
+
+
 def _attention_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, rope,
                      pos_base, attn_fn, active, mm, colmm, tables, ci=None,
                      window: int = 0):
@@ -356,23 +396,9 @@ def _attention_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, rope,
     heads, sfx = cfg.heads_of(windowed), cfg.attn_suffix(windowed)
     d = heads * cfg.head_size  # heads x head size: the model's dim unless
     # the header gives the head size
-    kvd = cfg.kv_dim
     ci = ai if ci is None else ci
     win = {"window": window} if window else {}
-    if "wqkv" + sfx in layers:  # fused launch (fuse_layer_weights)
-        qkv = mm(h, layers["wqkv" + sfx], ai)
-        q, k, v = qkv[..., :d], qkv[..., d : d + kvd], qkv[..., d + kvd :]
-    else:
-        q = mm(h, layers["wq" + sfx], ai)
-        k = mm(h, layers["wk" + sfx], ai)
-        v = mm(h, layers["wv" + sfx], ai)
-    q = q.reshape(b, t, heads, cfg.head_size)
-    k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_size)
-    v = v.reshape(b, t, cfg.n_kv_heads, cfg.head_size)
-    if cfg.qk_norm:  # over the head, before the rotation
-        with jax.named_scope("qk_norm"):
-            q = rms_norm(q, layers["q_norm" + sfx][ai], cfg.norm_epsilon)
-            k = rms_norm(k, layers["k_norm" + sfx][ai], cfg.norm_epsilon)
+    q, k, v = _qkv_heads(cfg, h, layers, ai, mm, heads, sfx)
     if rope is not None:  # RopeType.NONE: q and k as projected
         with jax.named_scope("rope_window" if windowed else "rope_global"):
             q = apply_rope(q, rope)
@@ -517,6 +543,56 @@ def _kda_mixer(cfg: LlamaConfig, h, layers, si, state: RecurrentState,
     gate = mm(ga, layers["kda_gb"], si).reshape(b, t, heads, dk)
     y = delta.gated_head_norm(o, gate, layers["kda_norm"][si], cfg.norm_epsilon)
     return colmm(y.reshape(b, t, inner).astype(h.dtype), layers["kda_o"], si), state
+
+
+def _retention_mixer(cfg: LlamaConfig, h, layers, si, state: RecurrentState,
+                     pos_base, active, mm, colmm, rope=None):
+    """The power-retention mixer (ops/power.py has the equations): the
+    attention tensors' q, k and v, normed over the head and rotated (`rope`:
+    the rows' table rows; a recurrent slot needs its row's position for
+    nothing else), a gate a kv head, and a state that `cfg.q_per_kv` query
+    heads share. `si` indexes the layers' weight stacks and the state's layer
+    axis; a row at position 0 starts from ZERO state and rows with
+    active==False leave it bit-equal, as `_ssm_mixer`'s. Returns
+    (out [B, T, D], state)."""
+    b, t, _ = h.shape
+    q, k, v = _qkv_heads(cfg, h, layers, si, mm, cfg.n_heads)
+    if rope is not None:
+        with jax.named_scope("rope_retention"):
+            q = apply_rope(q, rope)
+            k = apply_rope(k, rope)
+    with jax.named_scope("retention_gate"):
+        # log of the decay, float32: log sigmoid(x W_g + b_g) [B, T, G]
+        gamma = jax.nn.log_sigmoid(router_logits(h, layers["ret_gate"][si])
+                                   + layers["ret_gate_bias"][si])
+    fresh = jnp.broadcast_to(jnp.asarray(pos_base, jnp.int32) == 0, (b,))
+    act = jnp.ones((b,), bool) if active is None else active
+    if t == 1 and state.slot is None and state.step is not None:
+        # the decode step the engine resolved, on the layer-stacked state,
+        # in place
+        mode = jnp.where(act, jnp.where(fresh, 2, 1), 0)
+        y, s_stack = state.step(state.s, si, q[:, 0], k[:, 0], v[:, 0],
+                                jnp.exp(gamma[:, 0]), mode)
+        y = y[:, None]
+        state = state.replace_layer(si, None, s_stack=s_stack)
+    else:
+        s_old = state.layer_state(si)
+        s_in = jnp.where(fresh[:, None, None, None], 0.0,
+                         s_old.astype(jnp.float32))
+        if t == 1:
+            y, s_new = power.retention_step_ref(s_in, q[:, 0], k[:, 0], v[:, 0],
+                                                gamma[:, 0])
+            y = y[:, None]
+        else:
+            # a prefill slice: matrix products over its rows, under a name
+            # of its own in the profile's op metadata
+            with jax.named_scope("retention_slice"):
+                y, s_new = power.retention_slice(s_in, q, k, v, gamma)
+        s_new = jnp.where(act[:, None, None, None], s_new.astype(s_old.dtype), s_old)
+        state = state.replace_layer(si, None, s_new=s_new)
+    with jax.named_scope("retention_norm"):
+        y = power.normalise(y, cfg.head_size).astype(h.dtype)
+    return colmm(y.reshape(b, t, cfg.attn_dim), layers["wo"], si), state
 
 
 def _mla_mixer(cfg: LlamaConfig, h, layers, ai, k_cache, v_cache, rope, pos_base,
@@ -888,7 +964,8 @@ def run_layers(
     # cuts its slice out of the carried stack and puts it back: the CPU route)
     # ... and wherever the pattern is ragged (its runs are loops of a length
     # that is data: no slice of the cache can ride as a scan's xs)
-    fused = kernel or two_pools or ragged is not None
+    # ... and where no layer holds cache rows at all (the caches are empty)
+    fused = kernel or two_pools or ragged is not None or not a_pp
     kwp, vwp, wtables = wpool if two_pools else (None, None, None)
 
     def one_layer(x, kc, vc, st, kw, vw, ms, li, ai, ci, si, kind):
@@ -896,7 +973,11 @@ def run_layers(
         this layer's slice; `ci` is the layer's index into ITS pool."""
         base = kind & SCHEDULE_KIND_MASK
         if not is_attn_kind(kind):
-            mixer = _kda_mixer if base == LayerKind.KDA else _ssm_mixer
+            mixer = (_kda_mixer if base == LayerKind.KDA
+                     else _ssm_mixer if base == LayerKind.SSM
+                     # the one recurrent kind that rotates (every layer by
+                     # the model's one table)
+                     else functools.partial(_retention_mixer, rope=rope_g))
 
             def mix(h, mm_, colmm):
                 return mixer(cfg, h, layer_params, si, st, pos_base,

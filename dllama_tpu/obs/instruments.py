@@ -400,8 +400,14 @@ MOE_TOKENS_GROUP_KEPT = metrics.counter(
 # recurrent (state-space) models: the per-slot state beside the page pool
 RECURRENT_STATE_BYTES = metrics.gauge(
     "dllama_recurrent_state_bytes",
-    "Bytes of per-slot recurrent state (state-space layers' S and conv "
-    "window) resident in HBM beside the KV cache; 0 for a KV-only model")
+    "Bytes of per-slot recurrent state (state-space, delta-rule or "
+    "retention layers' S and conv window) resident in HBM beside the KV "
+    "cache; 0 for a KV-only model")
+STATE_SLICE_BYTES = metrics.counter(
+    "dllama_state_slice_bytes_total",
+    "Bytes of recurrent state that launches with a B = 1 prefill slice cut "
+    "out of the layer-stacked state and put back: 2 x one slot's state over "
+    "every recurrent layer a launch that carries prompt rows")
 STATE_RESETS = metrics.counter(
     "dllama_state_resets_total",
     "Admissions that started a slot's recurrent state from zero "

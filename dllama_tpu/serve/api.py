@@ -417,12 +417,18 @@ class ApiServer:
         if eng is not None and eng.cfg.recurrent:
             # per-slot recurrent state stands at one row: what re-enters a
             # sequence at another resolved off at start-up (engine/batch.py)
+            off = ["radix_cache", "kv_host_pages", "spec_k",
+                   "cross_slot_prefix_copy", "preempt_to_pages"]
             h["recurrent_state"] = {
-                "kind": "kda" if eng.cfg.n_kda_layers else "ssm",
+                "kind": eng.cfg.state_kind,
                 "layers": eng.cfg.n_state_layers,
                 "bytes": self.recurrent_state_bytes,
-                "resolved_off": ["radix_cache", "kv_host_pages", "spec_k",
-                                 "cross_slot_prefix_copy", "preempt_to_pages"]}
+                "resolved_off": off}
+            if not eng.cfg.n_attn_layers:
+                # no layer holds cache rows: the page pool has a layer axis
+                # of 0, a page costs nothing and block tables stand for
+                # positions only; the same prefix features are off
+                h["cache_rows"] = {"layers": 0, "resolved_off": off}
         if getattr(eng, "wpool", None) is not None:
             # windowed layers keep a page pool of their own: what follows one
             # page list a slot resolved off at start-up (engine/batch.py)

@@ -369,8 +369,9 @@ def abstract_params(cfg, A):
             mla_kvb=f32(Lm, h, cfg.qk_nope_dim + cfg.v_head_dim, cfg.kv_lora_rank),
             mla_o=qw((Lm,), h * cfg.v_head_dim, d))
     else:
-        # stacked apart by kind where the windowed layers have heads of their own
-        La, Lw = cfg.n_attn_layers, cfg.n_window_layers
+        # stacked apart by kind where the windowed layers have heads of their
+        # own; retention layers hold the attention tensors and their gate
+        La, Lw = cfg.n_attn_layers or cfg.n_retention_layers, cfg.n_window_layers
         for windowed, n in ([(False, La - Lw), (True, Lw)] if cfg.window_heads
                             else [(False, La)]):
             sfx, ad = cfg.attn_suffix(windowed), cfg.attn_dim_of(windowed)
@@ -382,6 +383,9 @@ def abstract_params(cfg, A):
                                "k_norm" + sfx: f32(n, cfg.head_size)})
             if cfg.attn_gate:
                 layers["attn_gate" + sfx] = f32(n, d, cfg.heads_of(windowed))
+        if cfg.n_retention_layers:
+            layers.update(ret_gate=f32(La, d, cfg.n_kv_heads),
+                          ret_gate_bias=f32(La, cfg.n_kv_heads))
     if cfg.n_ssm_layers:
         Ls, cd = cfg.n_ssm_layers, cfg.ssm_conv_dim
         layers.update(
@@ -547,13 +551,15 @@ def sharded_cases(topo):
 
 
 def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
-                    hybrid_p=(64,), seq: int = SEQ, prefill_chunk: int = 256):
+                    hybrid_p=(64,), seq: int = SEQ, prefill_chunk: int = 256,
+                    prefill_m=()):
     """(name, thunk) for the step programs of a paged
     BatchEngine (`serve --slots N --max-seq-len 2048 [--spec-k K]`): the
     engine is built here on the CPU over shapes, and each thunk lowers one
     of its programs for the described chip and returns the compiled
     executable. `kv_pages` 0 = full coverage; `hybrid_p` = the prefill
-    slices to offer the hybrid step at. serving_cases() and
+    slices to offer the hybrid step at, `prefill_m` the chunks to offer the
+    slot prefill at beside a speculating engine's. serving_cases() and
     experiments/pool_copies.py (the 7B cell's sizes) both build on this."""
     from dllama_tpu.engine.batch import BatchEngine
     from dllama_tpu.models.llama import PagedKVCache
@@ -572,10 +578,10 @@ def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
     from dllama_tpu.engine.kernel_select import kinds_tag
 
     assert be.kernel_route == (
-        "pallas/paged_kernel" + (".window" if cfg.n_window_layers else "")
-        + (".latent" if cfg.latent else "") + kinds_tag(cfg)
-        + ("+kda_step.float32" if cfg.n_kda_layers else
-           "+ssm_step.float32" if cfg.recurrent else "")
+        ("pallas/paged_kernel" + (".window" if cfg.n_window_layers else "")
+         + (".latent" if cfg.latent else "") + kinds_tag(cfg)
+         if cfg.n_attn_layers else "pallas/no_cache_rows")
+        + (f"+{cfg.state_kind}_step.float32" if cfg.recurrent else "")
         + ("+moe_grouped" if cfg.n_experts else "")
         + (f".groups{cfg.expert_groups_kept}of{cfg.n_expert_groups}"
            if cfg.grouped_routing else "")), be.kernel_route
@@ -605,12 +611,11 @@ def engine_programs(topo, tag, cfg, params, slots, spec, kv_pages=0,
         out += [(f"{tag} hybrid step p={p} n=4", lambda p=p: be._hybrid.lower(
             params, cache, i32(1, p), i32(), i32(), i32(slots, 1),
             *vecs, 4, rope, i32(slots)).compile()) for p in hybrid_p]
+    out += [(f"{tag} paged prefill chunk m={m}", lambda m=m: be._prefill_slot.lower(
+        params, cache, i32(1, m), i32(), i32(), rope).compile())
+            for m in ((256, 1) if spec else prefill_m)]
     if spec:
         out += [
-            (f"{tag} paged prefill chunk m=256", lambda: be._prefill_slot.lower(
-                params, cache, i32(1, 256), i32(), i32(), rope).compile()),
-            (f"{tag} paged prefill chunk m=1", lambda: be._prefill_slot.lower(
-                params, cache, i32(1, 1), i32(), i32(), rope).compile()),
             (f"{tag} spec-verify chunk K={spec} m=4", lambda: be._spec_step.lower(
                 params, cache, i32(slots, seq + 1), i32(slots), vecs[0],
                 vecs[1], i32(slots), *vecs[2:], rope, i32(slots), 4).compile()),
@@ -748,9 +753,23 @@ def rot_latent_cfg(n_layers: int = 9):
         layer_ffn=(1,) + (0,) * (n_layers - 1))
 
 
+def retention_cfg(n_layers: int = 10):
+    """Power retention in every layer on a Qwen3-14B skeleton, the widths of
+    benchmark/configs/brumby-14b-base.json: 5,120 stream, 40 query heads on 8
+    states of 129 x 8,256, QK-norm, rope theta 1e6, SwiGLU 17,408, a
+    151,936-row head; 10 of 40 layers (the first of four pipeline stages)."""
+    from dllama_tpu.models.config import LlamaConfig
+
+    return LlamaConfig(
+        dim=5120, hidden_dim=17408, n_layers=n_layers, n_heads=40, n_kv_heads=8,
+        vocab_size=151936, seq_len=32768, norm_epsilon=1e-6, rope_theta=1e6,
+        head_dim=128, qk_norm=True, layer_kinds=(4,) * n_layers, ret_degree=2,
+        ret_gate=True)
+
+
 # experiments/warm_compile.py asks for each configuration's parameters by name
 hybrid_params = window_moe_params = delta_latent_params = abstract_params
-attn_kinds_params = rot_latent_params = abstract_params
+attn_kinds_params = rot_latent_params = retention_params = abstract_params
 
 
 @dataclasses.dataclass(frozen=True)
@@ -763,6 +782,8 @@ class Family:
     pages: int
     slice: int = 64
     chunk: int = 256
+    more_slices: tuple = ()  # further hybrid slices to compile
+    prefill: tuple = ()  # slot-prefill chunks to compile
 
 
 FAMILIES = {
@@ -771,6 +792,8 @@ FAMILIES = {
     "delta-latent": Family(delta_latent_cfg, 48, 456),
     "attn-kinds": Family(attn_kinds_cfg, 24, 816, 256, 256),
     "rot-latent": Family(rot_latent_cfg, 32, 2368, 512, 512),
+    # a page costs nothing where no layer holds rows: full coverage
+    "retention": Family(retention_cfg, 24, 0, 64, 256, (16,), (256,)),
 }
 
 
@@ -786,8 +809,9 @@ def family_cases(topo, name, slots=None, pages=None, **depth):
     slots = slots or fam.slots
     return engine_programs(
         topo, f"serve {name} {slots}-slot", cfg, abstract_params(cfg, on_one_chip(topo)),
-        slots, 0, kv_pages=pages or fam.pages, hybrid_p=(fam.slice,),
-        seq=cfg.seq_len, prefill_chunk=fam.chunk)
+        slots, 0, kv_pages=pages or fam.pages,
+        hybrid_p=(fam.slice, *fam.more_slices), seq=cfg.seq_len,
+        prefill_chunk=fam.chunk, prefill_m=fam.prefill)
 
 
 def all_cases(topo):

@@ -15,7 +15,7 @@ Run it before a cell run whenever the XLA ops of a layer body changed.
 
 Usage: chiprun -- python experiments/warm_compile.py CELL [ONLY[,ONLY...]]
        python experiments/warm_compile.py --smoke [ONLY[,ONLY...]]
-CELL is one of the benchmark's expert cells (or its configuration's name
+CELL is one of the benchmark's cells listed in CELLS (or its configuration's name
 before the dot); ONLY keeps the programs whose `fn.key.` holds one of the
 strings (`hybrid.`, `decode.n4.`, `p256`). 3-5 minutes a cell on one chip.
 `--smoke` walks the same code on the CPU over a tiny stack (tier-1).
@@ -53,6 +53,10 @@ CELLS = {
     "smallthinker.long_decode_closed": dict(
         cfg="window_moe_cfg", params="window_moe_params", slots=16, pages=1232,
         seq=16384, chunk=512, hybrid=512),
+    # no cache rows: `pages` 0 = full coverage, which costs nothing
+    "brumby14b.reason_closed": dict(
+        cfg="retention_cfg", params="retention_params", slots=24, pages=0,
+        seq=32768, chunk=256, hybrid=64),
 }
 
 

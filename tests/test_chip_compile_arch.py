@@ -1,4 +1,5 @@
-"""The step programs of the five served architectures, compiled for TPU v5e
+"""The step programs of five of the six served architectures (the sixth:
+`tests/test_chip_compile_retention.py`), compiled for TPU v5e
 with no chip attached: the decode chunk and the hybrid step of each, at the
 published widths and the cell's slice, cut in slots, pages and depth to what
 the host builds in seconds (`experiments/aot_check.family_cases`). Beside
@@ -224,3 +225,4 @@ def test_hybrid_step_compiles_at_the_cells_slice(step_program, family, name, ker
     _, groups = _custom_calls(compiled.as_text())
     assert kernels <= groups, groups
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+

@@ -34,7 +34,12 @@ from benchmark import files
 from benchmark.layouts import smallthinker as layout
 from dllama_tpu.engine.batch import BatchEngine, StateNotResumable
 from dllama_tpu.models import formats
-from dllama_tpu.models.config import HiddenAct, LlamaConfig
+from dllama_tpu.models.config import (
+    SCHEDULE_UNROTATED,
+    SCHEDULE_WINDOWED,
+    HiddenAct,
+    LlamaConfig,
+)
 from dllama_tpu.obs import instruments as ins
 from dllama_tpu.ops.layers import expert_groups, expert_rows, moe_ffn
 from dllama_tpu.ops.quant import QTensor
@@ -70,7 +75,7 @@ def test_header_round_trip_and_plan(tiny):
     assert (cfg.window, cfg.head_size, cfg.attn_dim, cfg.dim) == (16, 128, 384, 256)
     assert cfg.hidden_act == HiddenAct.RELU and cfg.router_pre_attention
     assert (cfg.n_experts, cfg.n_active_experts, cfg.n_window_layers) == (8, 3, 6)
-    assert cfg.schedule_kinds == (8, 4, 4, 4) * 2
+    assert cfg.schedule_kinds == (SCHEDULE_UNROTATED, *(SCHEDULE_WINDOWED,) * 3) * 2
     assert LlamaConfig.from_header_kv(cfg.to_header_kv()) == cfg
     mine, header = layout.read_header(tiny.path)
     assert header == formats.read_header(tiny.path)[1]
