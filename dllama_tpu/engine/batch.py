@@ -662,13 +662,6 @@ class HostKVPool:
             return problems
 
 
-def _sample_rows(logits, keys, temps, topps):
-    """Per-row sampling with per-row keys: [B, V] x [B, 2] -> [B]."""
-    return jax.vmap(lambda lg, k, t, p: sample_logits(lg[None], k, t, p)[0])(
-        logits, keys, temps, topps
-    )
-
-
 @dataclass
 class Admission:
     """In-flight incremental prefill of one slot (add_begin/add_step/add_commit)."""
@@ -1394,7 +1387,7 @@ class BatchEngine:
             splits = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
             nkeys, subs = splits[:, 0], splits[:, 1]
             keys = jnp.where(act[:, None], nkeys, keys)
-            nxt = _sample_rows(logits[:, -1], subs, temps, topps)[:, None]
+            nxt = sample_logits(logits[:, -1], subs, temps, topps, act)[:, None]
             nxt = jnp.where(act[:, None], nxt, tok)  # frozen slots keep token
             return (nxt, cache, p + act.astype(jnp.int32), keys, bad), nxt[:, 0]
 
@@ -1437,7 +1430,7 @@ class BatchEngine:
             nkeys, subs = splits[:, 0], splits[:, 1]
             keys = jnp.where(act[:, None], nkeys, keys)
             pen = apply_penalties(logits[:, -1], counts, presence, frequency)
-            nxt = _sample_rows(pen, subs, temps, topps)[:, None]
+            nxt = sample_logits(pen, subs, temps, topps, act)[:, None]
             nxt = jnp.where(act[:, None], nxt, tok)
             return (nxt, cache, p + act.astype(jnp.int32), keys, counts,
                     bad), nxt[:, 0]
@@ -1610,7 +1603,7 @@ class BatchEngine:
             cache, history, cur, pos, keys, bad = carry
 
             def sample_fn(logits, subs, cur, eff):
-                return _sample_rows(logits[:, 0], subs, temps, topps), None
+                return sample_logits(logits[:, 0], subs, temps, topps, eff), None
 
             (emit, adv, nxt, cache, history, keys, pos2, drafted, bad1,
              _extras) = cls._spec_cycle_core(
@@ -1658,7 +1651,7 @@ class BatchEngine:
                 cnt = counts.at[jnp.arange(b), cur].add(eff.astype(jnp.int32))
                 penalized = apply_penalties(logits[:, 0], cnt, presence,
                                             frequency)
-                return _sample_rows(penalized, subs, temps, topps), cnt
+                return sample_logits(penalized, subs, temps, topps, eff), cnt
 
             (emit, adv, nxt, cache, history, keys, pos2, drafted, bad1,
              cnt) = cls._spec_cycle_core(
@@ -2872,7 +2865,9 @@ class BatchEngine:
             prefill_rows=prefill_rows,
             window=self.window, kv_pool=self._kv_pool,
             kind_layers=self._kind_layers,
-            state_slice_bytes=self._state_slice_bytes)
+            state_slice_bytes=self._state_slice_bytes,
+            sampler=launch_record.sampler_path(self.active, self.temperature,
+                                               self.topp))
 
     def decode_dispatch(self, n: int, spec: bool = False) -> DecodeChunk:
         """Dispatch one fused n-step decode chunk WITHOUT waiting for its
@@ -3204,7 +3199,9 @@ class BatchEngine:
             # materialised counts and counts it there, once
             rec = launch_record.LaunchRecord(
                 "spec_pen" if pen else "spec", seq=self.chunk_seq + 1,
-                n=n_cycles, active=int(active.sum()), pool_dry=self._pool_dry())
+                n=n_cycles, active=int(active.sum()), pool_dry=self._pool_dry(),
+                sampler=launch_record.sampler_path(active, self.temperature,
+                                                   self.topp))
         with compile_obs.LEDGER.scope(
                 rec.kind, f"n{n_cycles}",
                 sig=lambda: compile_obs.sig_of(*args[3:])), guard, \
@@ -3340,7 +3337,8 @@ class BatchEngine:
                     pool_dry=chunk.launch.pool_dry,
                     frozen=np.where(total == 0, m_cycles, 0),
                     window=self.window, kv_pool=self._kv_pool,
-            kind_layers=self._kind_layers).count()
+                    kind_layers=self._kind_layers,
+                    sampler=chunk.launch.sampler).count()
                 if tr.enabled:
                     tr.span_at("decode.spec", chunk.t_disp, tr.now(),
                                cat="decode", track="launches", chunk=chunk.seq,

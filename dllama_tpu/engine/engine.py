@@ -235,7 +235,7 @@ class InferenceEngine:
     def _decode_sample_n_impl(fwd, params, cache, token, pos, rope_cache,
                               key, n, temperature, topp):
         """n *sampled* decode steps fused on device — the sampler runs inside
-        the scan (branchless in temperature/topp, sampling.sample_logits), so
+        the scan (temperature/topp traced, sampling.sample_logits), so
         non-greedy generation also avoids the per-token host roundtrip the
         reference's decode loop pays (dllama.cpp:69-88)."""
         from dllama_tpu.engine.sampling import sample_logits

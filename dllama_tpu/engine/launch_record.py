@@ -8,7 +8,8 @@ where the launch is built, from the host arrays the dispatch already holds
 exist:
 
 * the counters `dllama_launches_total{kind}`,
-  `dllama_slot_steps_total{state}`, `dllama_launch_kv_rows_total{kind}`,
+  `dllama_sampler_launches_total{path}`, `dllama_slot_steps_total{state}`,
+  `dllama_launch_kv_rows_total{kind}`,
   `dllama_launch_prefill_rows_total{kind}`, `dllama_state_slice_bytes_total`
   (always on, O(1) a launch);
 * the args of the launch's span in the tracer ring (`decode.device` /
@@ -52,6 +53,21 @@ PROGRAMS = {fn: f"dllama_{fn}"
 SLOT_STATES = ("advanced", "starved", "empty")
 for _s in SLOT_STATES:  # the series exist from the first scrape on
     ins.SLOT_STEPS.labels(state=_s)
+#: the sampler's bodies, shortest first (engine/sampling.sample_logits)
+SAMPLER_PATHS = ("greedy", "temperature", "nucleus")
+for _s in SAMPLER_PATHS:
+    ins.SAMPLER_LAUNCHES.labels(path=_s)
+
+
+def sampler_path(active: np.ndarray, temperature: np.ndarray,
+                 topp: np.ndarray) -> str:
+    """The longest sampler body a launch over these slots can run: the
+    predicate `sample_logits` evaluates on the device, on the host's own
+    vectors (a released slot keeps its stale temperature: `active` masks
+    it there as here)."""
+    samples = active & (temperature != 0.0)
+    nucleus = samples & (topp > 0.0) & (topp < 1.0)
+    return SAMPLER_PATHS[int(samples.any()) + int(nucleus.any())]
 
 
 def named_jit(fn: str, impl, **jit_kw):
@@ -88,6 +104,8 @@ class LaunchRecord:
     # KIND walks are a layer's rows times its layers)
     state_slice_bytes: int = 0  # a recurrent model: the bytes of state a
     # launch's B = 1 prefill slice cuts out of the stack and puts back
+    sampler: str = ""  # a SAMPLER_PATHS word: the sampler body the launch's
+    # slots ask for ("" for a prefill chunk, which samples nothing)
 
     def args(self) -> dict:
         """The span / annotation arguments (`kind` is in the name too)."""
@@ -102,6 +120,8 @@ class LaunchRecord:
         """Into the counters, once per launch, after its call returned (a
         launch that raised was not made)."""
         ins.LAUNCHES.labels(kind=self.kind).inc()
+        if self.sampler:
+            ins.SAMPLER_LAUNCHES.labels(path=self.sampler).inc()
         ins.SLOT_STEPS.labels(state="advanced").inc(self.advanced)
         ins.SLOT_STEPS.labels(state="empty").inc(self.empty)
         if self.starved:
@@ -146,7 +166,7 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
           pool_dry: bool, prefill_rows: int = 0,
           frozen: np.ndarray | None = None, window: int = 0,
           kv_pool: str = "", kind_layers: tuple = (0, 0),
-          state_slice_bytes: int = 0) -> LaunchRecord:
+          state_slice_bytes: int = 0, sampler: str = "") -> LaunchRecord:
     """The record of a launch of `n` steps over slots at `start_pos`, of
     which the `active` ones advance `advance` rows each.
 
@@ -182,4 +202,4 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
         prefill_rows=int(prefill_rows), pool_dry=bool(pool_dry),
         kv_rows_window=kv_rows_window, kv_pool=kv_pool,
         kind_layers=kind_layers,
-        state_slice_bytes=state_slice_bytes)
+        state_slice_bytes=state_slice_bytes, sampler=sampler)

@@ -327,6 +327,17 @@ LAUNCHES = metrics.counter(
     "Engine program launches by kind (decode, hybrid, prefill_chunk, spec "
     "and their _pen variants): the denominator of every per-launch cost",
     ("kind",))
+SAMPLER_LAUNCHES = metrics.counter(
+    "dllama_sampler_launches_total",
+    "Launches that sample (decode, hybrid, spec and their _pen variants) by "
+    "the longest sampler body the launch's own slots ask for, from the "
+    "host's active / temperature / topp vectors at dispatch (the predicate "
+    "engine/sampling.sample_logits evaluates on the device every step): "
+    "greedy (every active slot at temperature 0: the argmax alone), "
+    "temperature (some slot samples, none with 0 < topp < 1: one "
+    "full-vocabulary draw more) or nucleus (the candidates' top-k, the "
+    "logsumexp and their draw too)",
+    ("path",))
 SLOT_STEPS = metrics.counter(
     "dllama_slot_steps_total",
     "Decode steps x slots of every launch, by what the slot did: advanced "

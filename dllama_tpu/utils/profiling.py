@@ -56,7 +56,9 @@ _prof_state = {"active": False, "dir": None, "started_at": 0.0,
 #: state and by phase, the drains by reason, the waits by outcome and the
 #: host-gap histogram's sum and count: beside the whole window's (a scrape
 #: before and after) they say what the profiler costs the host, per launch
-_CAPTURE_COUNTERS = {"launches": ins.LAUNCHES, "slot_steps": ins.SLOT_STEPS,
+_CAPTURE_COUNTERS = {"launches": ins.LAUNCHES,
+                     "sampler_launches": ins.SAMPLER_LAUNCHES,
+                     "slot_steps": ins.SLOT_STEPS,
                      "kv_rows": ins.LAUNCH_KV_ROWS,
                      "prefill_rows": ins.LAUNCH_PREFILL_ROWS,
                      "kv_rows_read": ins.LAUNCH_KV_ROWS_READ,

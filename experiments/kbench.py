@@ -6,6 +6,7 @@ Usage: python experiments/kbench.py suite
        python experiments/kbench.py deq [--no-tiles] [--parent]
        python experiments/kbench.py expert [--no-tiles]
        python experiments/kbench.py moe_layer [CELL ...] [--no-profile | --aot]
+       python experiments/kbench.py sampler
        python experiments/kbench.py M SHAPE [variant ...]
 'suite' benches the decode variants (m=8 on w1/wcls) and the prefill tier
 comparison (m=256/512: in-kernel deq vs XLA dequant-dot) in one process.
@@ -35,7 +36,13 @@ ops around them cost), and each route's device ops from a profile of the
 same scan (--no-profile leaves it out). 'moe_layer --aot' needs no chip: it compiles the
 layer-step for `v5e:2x2` and lists the entry computation's scheduled ops by
 kind and result shape, with the seconds tracing and lowering took.
-'suite --smoke' (and 'paged --smoke', 'q40 --smoke', 'deq --smoke', 'expert --smoke',
+'sampler' times the sampler that closes every decode step
+(`engine/sampling.sample_logits`, per-row keys) ALONE at four cells' slots x
+vocabulary: PR 51's one straight-line body mapped over rows (kept here as the
+yardstick) against today's conditional bodies on a greedy, a temperature and a
+nucleus batch, and on a greedy batch with one nucleus row; what a sampled
+batch pays for the conditional shows as nucleus against PR 51.
+'suite --smoke' (and 'sampler --smoke', 'paged --smoke', 'q40 --smoke', 'deq --smoke', 'expert --smoke',
 'moe_layer --smoke') runs the
 same code path on CPU (interpret-mode Pallas, tiny shapes, 2 iters) so CI proves the harness cannot crash on the chip; smoke
 numbers are meaningless, only completion matters.
@@ -297,6 +304,8 @@ def enable_smoke():
                      act="relu", layers=2, rows={"decode": 3, "slice": 160}),
         "tiny share": dict(held=4, routed=8, active=3, d=256, f=256, sigmoid=True, scale=2.5,
                            act="silu", layers=2, rows={"decode": 6})}
+    global SAMPLER_CELLS, SAMPLER_CALLS
+    SAMPLER_CELLS, SAMPLER_CALLS = {"tiny": (3, 512)}, 2
     global PAGED_LATENT_CELLS, PAGED_LATENT_PP, PAGED_SLICE_CALLS
     PAGED_LATENT_PP, PAGED_SLICE_CALLS = (2,), 2
     PAGED_LATENT_CELLS = {
@@ -1599,6 +1608,89 @@ Q40_SWEEP_LANES = (256, 512)
 Q40_SWEEP_ROWS = (1024, 2048, 4096, 8192, None)
 Q40_SWEEP_BYTES = 6 * 1024 * 1024
 
+# ------------------------------------------------ the sampler alone (PR 52)
+
+#: cell -> (slots, vocabulary): the logits a decode step hands the sampler
+SAMPLER_CELLS = {"granite4h": (48, 100352), "kimilinear": (48, 40960),
+                 "brumby14b": (24, 151936), "deepseek7b": (12, 102400)}
+SAMPLER_CALLS = 200
+
+
+def _pr51_sample_rows(logits, keys, temps, topps, active=None):
+    """PR 51's sampler, the yardstick: ONE straight-line body (the
+    candidates' top-k, the logsumexp and both draws for every row, thrown
+    away with a `where`), mapped over rows with per-row keys."""
+    from dllama_tpu.engine.sampling import NUCLEUS_K
+
+    def one(lg, key, t, p):
+        scaled = lg[None] / jnp.maximum(t, 1e-6)
+        key_p, key_t = jax.random.split(key)
+        vals, idx = jax.lax.approx_max_k(scaled, min(NUCLEUS_K, lg.shape[-1]),
+                                         recall_target=0.99, aggregate_to_topk=True)
+        lse = jax.scipy.special.logsumexp(scaled, axis=-1, keepdims=True)
+        pk = jnp.exp(vals - lse)
+        cum = jnp.cumsum(pk, axis=-1)
+        masked = jnp.where((cum - pk) < p, vals, -jnp.inf)
+        choice = jax.random.categorical(key_p, masked, axis=-1)
+        tok_topp = jnp.take_along_axis(idx, choice[:, None], axis=-1)[:, 0]
+        tok_temp = jax.random.categorical(key_t, scaled, axis=-1)
+        use_topp = (p > 0.0) & (p < 1.0) & (cum[:, -1] >= p)
+        sampled = jnp.where(use_topp, tok_topp, tok_temp)
+        return jnp.where(t == 0.0, jnp.argmax(lg[None], axis=-1), sampled)[0]
+
+    return jax.vmap(one)(logits, keys, temps, topps).astype(jnp.int32)
+
+
+def sampler_loop_us(fn, logits, temps, topps, calls=None):
+    """us a call of `fn(logits, keys, temps, topps, active) -> i32[B]` over
+    `calls` calls in ONE jitted scan that does what a decode step does
+    around it (the per-row key split, the frozen-row `where`). The logits
+    hang on the carry by one add their own size, or the argmax would leave
+    the loop: the first row of a cell prices that add with the argmax."""
+    calls = calls or SAMPLER_CALLS
+    b = logits.shape[0]
+    active = jnp.ones(b, bool)
+
+    @jax.jit
+    def loop(logits, keys, temps, topps):
+        def step(carry, _):
+            tok, keys = carry
+            splits = jax.vmap(jax.random.split)(keys)
+            lg = logits + (tok[:, None] * 1e-30).astype(logits.dtype)
+            nxt = fn(lg, splits[:, 1], temps, topps, active)
+            return (jnp.where(active, nxt, tok), splits[:, 0]), None
+        return jax.lax.scan(step, (jnp.zeros(b, jnp.int32), keys), None,
+                            length=calls)[0][0]
+
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(b))
+    return _timed(loop, (logits, keys, temps, topps), calls)
+
+
+def bench_sampler(cells=None):
+    """The sampler's bodies at the cells' slots x vocabulary, us a call."""
+    from dllama_tpu.engine.sampling import sample_logits
+
+    print("--- the sampler alone: us a call (a decode step calls it once)")
+    for cell, (b, v) in (cells or SAMPLER_CELLS).items():
+        logits = jax.random.normal(jax.random.PRNGKey(52), (b, v), jnp.float32) * 3
+        full = lambda x: jnp.full(b, x, jnp.float32)
+        one_nucleus = (full(0.0).at[0].set(0.8), full(0.9))
+        rows = (
+            ("the carry's add + the argmax alone",
+             lambda lg, *_: jnp.argmax(lg, axis=-1).astype(jnp.int32), full(0.0), full(0.9)),
+            ("PR 51, a greedy batch", _pr51_sample_rows, full(0.0), full(0.9)),
+            ("today, a greedy batch", sample_logits, full(0.0), full(0.9)),
+            ("today, a temperature batch", sample_logits, full(0.8), full(1.0)),
+            ("PR 51, a nucleus batch", _pr51_sample_rows, full(0.8), full(0.9)),
+            ("today, a nucleus batch", sample_logits, full(0.8), full(0.9)),
+            ("today, greedy with ONE nucleus row", sample_logits, *one_nucleus),
+        )
+        for tag, fn, temps, topps in rows:
+            us = sampler_loop_us(fn, logits, temps, topps)
+            print(f"sampler {cell} {b} x {v}: {tag}: {us:.1f} us")
+            sys.stdout.flush()
+
+
 def main():
     # argv: 'suite [--smoke] [--no-flash]' | 'flash [--smoke]' |
     # 'paged [--smoke]' (the paged decode call of each benchmark cell) |
@@ -1606,6 +1698,7 @@ def main():
     # 'deq [--smoke] [--no-tiles] [--parent]' (the dequantising tier, m > 16) |
     # 'expert [--smoke] [--no-tiles]' (the grouped expert kernel at the cells' fills) |
     # 'moe_layer [--smoke] [--no-profile | --aot]' (one whole grouped expert layer-step) |
+    # 'sampler [--smoke]' (the sampler's bodies at the cells' slots x vocabulary) |
     # M SHAPE [variant ...] — suite runs the whole decode + prefill matrix in
     # ONE process (one device init, not six). --no-flash skips the flash
     # section; the q40 rows still land.
@@ -1644,6 +1737,10 @@ def main():
             moe_layer_aot(cells)
         else:
             bench_moe_layer(cells, profile="--no-profile" not in sys.argv)
+        print("KBENCH DONE")
+        return
+    if sys.argv[1:2] == ["sampler"]:
+        bench_sampler()
         print("KBENCH DONE")
         return
     if sys.argv[1:2] == ["deq"]:

@@ -41,6 +41,9 @@ import jax.numpy as jnp
 #: gives: slots, global pages, rows a sequence, rows a slice; a hybrid launch
 #: carries at most `hybrid` slice rows)
 CELLS = {
+    "granite4h.reason_closed": dict(
+        cfg="hybrid_cfg", params="hybrid_params", slots=48, pages=456,
+        seq=2048, chunk=256, hybrid=64),
     "lagunaxs2.reason_long_closed": dict(
         cfg="attn_kinds_cfg", params="attn_kinds_params", slots=24, pages=816,
         seq=4224, chunk=256, hybrid=256),

@@ -419,3 +419,122 @@ def test_batched_penalized_sampled_reproducible():
     a, b = run(0.9), run(0.9)
     assert a == b  # reproducible under penalties
     assert run(0.0) != a  # and the penalty actually reshapes sampling
+
+
+# ------------------------- the sampler's conditional bodies (ISSUE 52)
+
+
+def _eqns(jaxpr, under=()):
+    """Every equation of a jaxpr and of the jaxprs in its parameters, with
+    the primitives it stands under (outermost first)."""
+    for eqn in jaxpr.eqns:
+        yield eqn, under
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub, under + (eqn.primitive.name,))
+
+
+class _Traced:
+    """Stands in for a jitted program in the warm worklist: `lower(...)`
+    gives the program's jaxpr for the operands the worklist built."""
+
+    def __init__(self, prog):
+        self.prog = prog
+
+    def lower(self, *args):
+        return self.prog.trace(*args).jaxpr
+
+
+@pytest.fixture(scope="module")
+def sampling_programs():
+    """fn -> the jaxpr of that step program, for every program of a
+    speculating engine's warm worklist that samples."""
+    be = BatchEngine(CFG, PARAMS, n_slots=3, cache_dtype=jnp.float32, spec=2)
+    for attr in ("_decode", "_decode_pen", "_hybrid", "_hybrid_pen",
+                 "_spec_step", "_spec_step_pen", "_first_token"):
+        setattr(be, attr, _Traced(getattr(be, attr)))
+    return {fn: thunk(lower=True) for fn, key, thunk in be._warm_worklist(2, 4)
+            if fn != "prefill_chunk" and key in ("n2", "b1", "p4.n2")}
+
+
+SAMPLING_PROGRAMS = ("commit", "decode", "decode_pen", "hybrid", "hybrid_pen",
+                     "spec", "spec_pen")
+
+
+@pytest.mark.parametrize("fn", SAMPLING_PROGRAMS)
+def test_step_program_holds_the_sampler_under_a_conditional(fn, sampling_programs):
+    """In every program that samples, the candidates' top-k and every draw
+    (`random_bits`: the gumbels of both categoricals) stand inside ONE
+    `cond` of three branches whose index is a scalar of the whole batch. A
+    per-row `vmap` of the sampler would have batched the index, and a `cond`
+    with a batched index lowers to a select that runs every branch: no
+    `cond` at all."""
+    eqns = list(_eqns(sampling_programs[fn].jaxpr))
+    heavy = [(e, under) for e, under in eqns
+             if e.primitive.name in ("approx_top_k", "top_k", "random_bits")]
+    assert {e.primitive.name for e, _ in heavy} >= {"approx_top_k", "random_bits"}
+    for e, under in heavy:
+        assert under.count("cond") == 1, f"{fn}: {e.primitive.name} under {under}"
+    (cond, under), = [(e, u) for e, u in eqns if e.primitive.name == "cond"]
+    assert cond.invars[0].aval.shape == (), cond.invars[0].aval
+    # the argmax alone / + the temperature draw / + the nucleus: the first
+    # branch holds no equation, only the last the candidates' top-k
+    branches = [[e.primitive.name for e, _ in _eqns(b.jaxpr)]
+                for b in cond.params["branches"]]
+    assert branches[0] == []
+    assert "random_bits" in branches[1] and "approx_top_k" not in branches[1]
+    assert "approx_top_k" in branches[2]
+    # the step programs sample inside their scan over the steps
+    assert ("scan" in under) == (fn != "commit")
+
+
+def _sampler_series():
+    from dllama_tpu.obs import instruments as ins
+
+    return dict(ins.SAMPLER_LAUNCHES.series())
+
+
+@pytest.mark.parametrize("path,params", [
+    ("greedy", dict(temperature=0.0, topp=0.9)),
+    ("temperature", dict(temperature=0.8, topp=1.0)),
+    ("nucleus", dict(temperature=0.8, topp=0.9)),
+])
+def test_sampler_launch_counter_moves_its_own_path_once_a_launch(path, params):
+    """`dllama_sampler_launches_total{path}`: one count a launch, under the
+    longest body the launch's ACTIVE slots ask for; a released slot's stale
+    temperature counts for nothing, a prefill chunk moves no series."""
+    from dllama_tpu.engine import launch_record
+
+    be = BatchEngine(CFG, PARAMS, n_slots=3, cache_dtype=jnp.float32)
+    be.add(1, [7, 8, 9], temperature=1.2, topp=0.5)  # then released: stale
+    be.release(1)
+    before = _sampler_series()
+    be.add(0, [1, 2, 3], **params)
+    assert _sampler_series() == before  # prefill chunks and the commit
+    be.decode(3)
+    be.decode(2)
+    moved = {k: v - before.get(k, 0) for k, v in _sampler_series().items()}
+    assert moved == {p: (2 if p == path else 0)
+                     for p in launch_record.SAMPLER_PATHS}
+    # a sampled batch-mate moves the whole launch off the greedy body
+    be.add(2, [4, 5], temperature=0.7, topp=0.8)
+    mid = _sampler_series()
+    be.decode(1)
+    moved = {k: v - mid[k] for k, v in _sampler_series().items() if v != mid[k]}
+    assert moved == {"nucleus": 1}
+
+
+def test_spec_chunk_counts_its_sampler_path_once_at_consume():
+    """A spec chunk's record is rebuilt when its rows are known: the path
+    it was dispatched under rides along and is counted there, once."""
+    be = BatchEngine(CFG, PARAMS, n_slots=2, cache_dtype=jnp.float32, spec=2)
+    be.add(0, [1, 2, 3, 1, 2, 3], temperature=0.0)
+    before = _sampler_series()
+    chunk = be.decode_dispatch(2, spec=True)
+    assert chunk.launch.sampler == "greedy"
+    assert _sampler_series() == before
+    be.decode_consume(chunk)
+    moved = {k: v - before[k] for k, v in _sampler_series().items()}
+    assert moved == {"greedy": 1, "temperature": 0, "nucleus": 0}

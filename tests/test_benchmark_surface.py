@@ -175,6 +175,10 @@ def _perf(s, tiny):
             "kv_rows_read", "moe_experts_touched", "moe_layer_steps",
             "moe_assignments"} <= set(cap)
     assert cap["seconds"] > 0 and cap["slot_steps"]["advanced"] >= 5
+    # the harness's streams are greedy: the capture's launches ran the
+    # sampler's argmax body alone (ISSUE 52)
+    assert cap["sampler_launches"]["greedy"] >= 1
+    assert not cap["sampler_launches"].get("nucleus")
     # the reader of the block: rows a decode step swept, for its cost file
     assert paged_attention.rows_per_step(cap, tiny["args"].slots) > 0
     # what host clocks are right for stays beside it, under its names (the
@@ -242,7 +246,9 @@ def test_metric_family_the_harness_names_is_exported(family, served):
 #: `ratio_of_deltas` over series named WITH their labels
 HOST_METRICS = ["commit_host_ms_per_commit", "device_wait_ms_per_launch",
                 "emit_us_per_token", "host_gap_ms_per_launch",
-                "host_work_ms_per_launch", "pipeline_drain_share"]
+                "host_work_ms_per_launch", "pipeline_drain_share",
+                # ISSUE 52: the launches by the sampler body they ask for
+                "sampler_greedy_launch_share"]
 
 
 @pytest.mark.parametrize("metric", HOST_METRICS)

@@ -391,6 +391,126 @@ def test_exact_topp_escape_hatch_no_fallback():
 
 
 
+# ------------------------------ the sampler's conditional bodies (ISSUE 52)
+
+
+def _parent_sample_logits(logits, key, temperature, topp, nucleus_k):
+    """PR 51's `sample_logits`, frozen: one straight-line body that ran the
+    candidates' top-k, the logsumexp and both draws for every row and threw
+    them away with a `where`. The yardstick of token identity."""
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    temperature = jnp.asarray(temperature, jnp.float32)
+    topp = jnp.asarray(topp, jnp.float32)
+    if temperature.ndim == 1:
+        temperature = temperature[:, None]
+    if topp.ndim == 1:
+        topp = topp[:, None]
+    scaled = logits / jnp.maximum(temperature, 1e-6)
+    key_p, key_t = jax.random.split(key)
+    if nucleus_k is None:
+        vals, idx = jax.lax.top_k(scaled, scaled.shape[-1])
+    else:
+        k = min(nucleus_k, logits.shape[-1])
+        vals, idx = jax.lax.approx_max_k(scaled, k, recall_target=0.99,
+                                         aggregate_to_topk=True)
+    lse = jax.scipy.special.logsumexp(scaled, axis=-1, keepdims=True)
+    pk = jnp.exp(vals - lse)
+    cum = jnp.cumsum(pk, axis=-1)
+    keep = (cum - pk) < topp
+    masked = jnp.where(keep, vals, -jnp.inf)
+    choice = jax.random.categorical(key_p, masked, axis=-1)
+    tok_topp = jnp.take_along_axis(idx, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
+    tok_temp = jax.random.categorical(key_t, scaled, axis=-1).astype(jnp.int32)
+    covered = cum[:, -1:] >= topp
+    use_topp = (topp > 0.0) & (topp < 1.0) & covered
+    if use_topp.ndim == 2:
+        use_topp = use_topp[:, 0]
+    sampled = jnp.where(use_topp, tok_topp, tok_temp)
+    t_is_zero = temperature == 0.0
+    if t_is_zero.ndim == 2:
+        t_is_zero = t_is_zero[:, 0]
+    return jnp.where(t_is_zero, greedy, sampled)
+
+
+def _parent_sample_rows(logits, keys, temps, topps, nucleus_k):
+    """PR 51's `engine/batch._sample_rows`: the per-row map of the above."""
+    return jax.vmap(lambda lg, k, t, p: _parent_sample_logits(
+        lg[None], k, t, p, nucleus_k)[0])(logits, keys, temps, topps)
+
+
+#: case -> (temperatures, topps, active or None, NUCLEUS_K, which body the
+#: batch asks for); six rows over a vocabulary of 512
+_F = lambda *v: np.asarray(v, np.float32)
+SAMPLER_MIXES = {
+    "all_greedy": (_F(0, 0, 0, 0, 0, 0), _F(.9, .9, .5, 0, 1, .9), None, 256, 0),
+    "all_temperature": (_F(.8, 1, 1.3, .5, 2, .8), _F(1, 1, 1, 1, 1, 1), None, 256, 1),
+    "topp_at_0_and_1": (_F(.8, 1, 1.3, .5, 2, 0), _F(0, 1, 0, 1, 0, 1), None, 256, 1),
+    "all_nucleus": (_F(.8, 1, 1.3, .5, 2, .8), _F(.9, .5, .99, .1, .7, .9), None, 256, 2),
+    "mixed_rows": (_F(0, .8, 1, 0, 2, .5), _F(.9, .9, 1, 0, .5, 0), None, 256, 2),
+    "nucleus_wider_than_k": (_F(50, 50, 1, 0, 50, 50), _F(.99, .9, .9, .9, 1, .95), None, 4, 2),
+    "exact_topp": (_F(0, .8, 1, 5, 2, .5), _F(.9, .9, 1, .99, .5, 0), None, None, 2),
+    # a released slot keeps its temperature (BatchEngine.release does not
+    # reset it): it must not pull its greedy batch-mates off the argmax body
+    "stale_inactive_row": (_F(0, .8, 0, 0, 1.5, 0), _F(.9, .9, .9, .9, 1, .9),
+                           np.array([1, 0, 1, 1, 0, 1], bool), 256, 0),
+    "stale_inactive_nucleus_row": (_F(.7, .8, 0, 0, 1.5, 0), _F(1, .9, .9, .9, 1, .9),
+                                   np.array([1, 0, 1, 1, 0, 1], bool), 256, 1),
+}
+
+
+@pytest.mark.parametrize("keys", ["one_key", "row_keys"])
+@pytest.mark.parametrize("mix", sorted(SAMPLER_MIXES))
+def test_sampler_returns_the_parents_token_for_every_row(mix, keys, monkeypatch):
+    """Whichever of its three bodies the batch's own vectors select, each
+    row that counts (active, where the caller says) gets the token PR 51's
+    straight-line sampler gave it on the same key: with one key for the
+    batch (the batch-1 engine, the commit's first token) and with per-row
+    keys (the step programs, which mapped the one-key call over rows)."""
+    from dllama_tpu.engine import sampling
+
+    temps, topps, active, nucleus_k, body = SAMPLER_MIXES[mix]
+    monkeypatch.setattr(sampling, "NUCLEUS_K", nucleus_k)
+    rng = np.random.default_rng(52)
+    logits = jnp.asarray(rng.normal(size=(6, 512)) * 3, jnp.float32)
+    # a fresh function a case: NUCLEUS_K is read when the body is traced
+    sampler = jax.jit(lambda *a: sampling.sample_logits(*a))
+    for seed in range(4):
+        if keys == "one_key":
+            key = jax.random.PRNGKey(seed)
+            want = _parent_sample_logits(logits, key, temps, topps, nucleus_k)
+        else:
+            key = jax.vmap(jax.random.PRNGKey)(jnp.arange(6) + 10 * seed)
+            want = _parent_sample_rows(logits, key, temps, topps, nucleus_k)
+        got = sampler(logits, key, temps, topps, active)
+        rows = slice(None) if active is None else active
+        np.testing.assert_array_equal(np.asarray(got)[rows],
+                                      np.asarray(want)[rows])
+    # and the case is the case its name says: the host's predicate (the
+    # counter's) picks the body the mix was written for
+    from dllama_tpu.engine import launch_record
+
+    act = np.ones(6, bool) if active is None else active
+    assert launch_record.sampler_path(act, temps, topps) == \
+        launch_record.SAMPLER_PATHS[body]
+
+
+def test_sampler_scalar_params_take_the_same_bodies():
+    """Scalars (the batch-1 engine's traced temperature / topp) go through
+    the same conditionals as the per-slot vectors."""
+    from dllama_tpu.engine import sampling
+
+    logits = jnp.asarray(np.random.default_rng(5).normal(size=(2, 96)) * 2,
+                         jnp.float32)
+    for t, p in ((0.0, 0.9), (0.8, 1.0), (0.8, 0.9)):
+        for seed in range(3):
+            key = jax.random.PRNGKey(seed)
+            np.testing.assert_array_equal(
+                np.asarray(sample(logits, key, t, p)),
+                np.asarray(_parent_sample_logits(logits, key, t, p,
+                                                 sampling.NUCLEUS_K)))
+
+
 
 # ------------------------------------------------- repetition penalties
 

@@ -36,6 +36,19 @@ def test_kbench_suite_smoke():
         assert row in p.stdout, p.stdout
 
 
+def test_kbench_sampler_smoke():
+    """The sampler alone (`kbench.py sampler`, what priced ISSUE 52's bodies
+    on the chip) at a tiny size: PR 51's one straight-line body and today's
+    three, each on the batch that asks for it."""
+    p = _run(["experiments/kbench.py", "sampler", "--smoke"])
+    assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
+    assert "KBENCH DONE" in p.stdout and "FAILED" not in p.stdout, p.stdout
+    for row in ("the argmax alone", "PR 51, a greedy batch", "today, a greedy batch",
+                "today, a temperature batch", "PR 51, a nucleus batch",
+                "today, a nucleus batch", "greedy with ONE nucleus row"):
+        assert p.stdout.count(row) == 1, (row, p.stdout)
+
+
 def test_kbench_paged_smoke():
     """The paged-decode loop of the benchmark's cells (the pricing of every
     paged-kernel PR) at a tiny size: fused and read-only, pools threaded."""

@@ -499,8 +499,10 @@ def test_capture_block_is_the_counter_deltas(monkeypatch, tmp_path):
     profiling._profiler_begin(str(tmp_path))
     try:
         assert trace.PROFILER_HOOK is jax.profiler.TraceAnnotation
-        launch_record.LaunchRecord("decode", 2, 4, 3, 10, 2, 0, 640, 0).count()
-        launch_record.LaunchRecord("hybrid", 3, 4, 2, 8, 0, 4, 300, 16).count()
+        launch_record.LaunchRecord("decode", 2, 4, 3, 10, 2, 0, 640, 0,
+                                   sampler="greedy").count()
+        launch_record.LaunchRecord("hybrid", 3, 4, 2, 8, 0, 4, 300, 16,
+                                   sampler="nucleus").count()
         launch_record.LaunchRecord("prefill_chunk", 0, 0, 0, 0, 0, 0, 0,
                                    8).count()
     finally:
@@ -511,12 +513,15 @@ def test_capture_block_is_the_counter_deltas(monkeypatch, tmp_path):
     pick = lambda d: {k: v for k, v in d.items() if v}
     assert pick(cap["launches"]) == {"decode": 1, "hybrid": 1,
                                      "prefill_chunk": 1}
+    # a prefill chunk samples nothing: it moves no sampler series
+    assert pick(cap["sampler_launches"]) == {"greedy": 1, "nucleus": 1}
     assert pick(cap["slot_steps"]) == {"advanced": 18, "starved": 2,
                                        "empty": 4}
     assert pick(cap["kv_rows"]) == {"decode": 640, "hybrid": 300}
     assert pick(cap["prefill_rows"]) == {"hybrid": 16, "prefill_chunk": 8}
     assert cap["seconds"] >= 0.0
-    assert set(cap) == {"launches", "slot_steps", "kv_rows", "prefill_rows",
+    assert set(cap) == {"launches", "sampler_launches", "slot_steps",
+                        "kv_rows", "prefill_rows",
                         "kv_rows_read", "moe_assignments",
                         "moe_experts_touched", "moe_layer_steps",
                         "moe_group_rows_max", "window_pages_released",

@@ -621,7 +621,8 @@ def test_debug_perf_capture_block_counts_the_captures_launches(
     assert st == 200
     cap = json.loads(data)["capture"]
     assert cap == profiling.last_capture()
-    assert set(cap) == {"launches", "slot_steps", "kv_rows", "prefill_rows",
+    assert set(cap) == {"launches", "sampler_launches", "slot_steps",
+                        "kv_rows", "prefill_rows",
                         "kv_rows_read", "moe_assignments",
                         "moe_experts_touched", "moe_layer_steps",
                         "moe_group_rows_max", "window_pages_released",
@@ -629,6 +630,9 @@ def test_debug_perf_capture_block_counts_the_captures_launches(
                         "phases", "drains", "launch_waits", "host_gap",
                         "seconds"}
     assert cap["launches"]["decode"] == during >= 1
+    # every launch that samples is counted under the body its slots ask for
+    assert sum(cap["sampler_launches"].values()) == sum(
+        n for kind, n in cap["launches"].items() if kind != "prefill_chunk")
     assert cap["slot_steps"]["advanced"] >= 5  # 6 tokens, the first at commit
     assert cap["kv_rows"]["decode"] > 0
     assert sum(cap["prefill_rows"].values()) > 0
