@@ -905,6 +905,11 @@ class BatchEngine:
                           else jnp.bfloat16).itemsize, max_prefill_chunk)
         self.pool: PagePool | None = None
         self.wpool: PagePool | None = None  # the windowed layers' pool
+        # the paged kernel's route over cache rows a head: (page rows, table
+        # width, the rows of a written tile and of an end page's copy), what
+        # the launch record counts the rows the kernel's copies MOVE from
+        # (paged_attention.rows_moved)
+        self._paged_rows: tuple | None = None
         if kv_layout == "paged":
             if shardings is not None:
                 raise ValueError(
@@ -921,6 +926,16 @@ class BatchEngine:
             n_pages = int(kv_pages) or max_blocks * n_slots
             self._build_pools(n_pages, max_blocks)
             self.cache = self._new_paged_cache(n_pages, max_blocks)
+            if (sel.attn_route.startswith("paged_kernel") and not cfg.latent
+                    and cfg.n_attn_layers):
+                from dllama_tpu.ops.pallas.paged_attention import (
+                    decode_tiles, pool_lanes)
+
+                self._paged_rows = (self.page_size, max_blocks, *decode_tiles(
+                    cfg.n_heads, cfg.n_kv_heads, self.page_size,
+                    pool_lanes(cfg.cache_row),
+                    jnp.dtype(cache_dtype if cache_dtype is not None
+                              else jnp.bfloat16).itemsize))
         else:
             self.cache = KVCache.create(cfg, n_slots, cache_dtype, self.seq_len,
                                         state_dtype=state_dtype,
@@ -2867,7 +2882,8 @@ class BatchEngine:
             kind_layers=self._kind_layers,
             state_slice_bytes=self._state_slice_bytes,
             sampler=launch_record.sampler_path(self.active, self.temperature,
-                                               self.topp))
+                                               self.topp),
+            paged=self._paged_rows)
 
     def decode_dispatch(self, n: int, spec: bool = False) -> DecodeChunk:
         """Dispatch one fused n-step decode chunk WITHOUT waiting for its
@@ -3338,7 +3354,8 @@ class BatchEngine:
                     frozen=np.where(total == 0, m_cycles, 0),
                     window=self.window, kv_pool=self._kv_pool,
                     kind_layers=self._kind_layers,
-                    sampler=chunk.launch.sampler).count()
+                    sampler=chunk.launch.sampler,
+                    paged=self._paged_rows).count()
                 if tr.enabled:
                     tr.span_at("decode.spec", chunk.t_disp, tr.now(),
                                cat="decode", track="launches", chunk=chunk.seq,

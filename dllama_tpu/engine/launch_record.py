@@ -10,6 +10,7 @@ exist:
 * the counters `dllama_launches_total{kind}`,
   `dllama_sampler_launches_total{path}`, `dllama_slot_steps_total{state}`,
   `dllama_launch_kv_rows_total{kind}`,
+  `dllama_launch_kv_rows_moved_total{kind}`,
   `dllama_launch_prefill_rows_total{kind}`, `dllama_state_slice_bytes_total`
   (always on, O(1) a launch);
 * the args of the launch's span in the tracer ring (`decode.device` /
@@ -106,14 +107,22 @@ class LaunchRecord:
     # launch's B = 1 prefill slice cuts out of the stack and puts back
     sampler: str = ""  # a SAMPLER_PATHS word: the sampler body the launch's
     # slots ask for ("" for a prefill chunk, which samples nothing)
+    kv_rows_moved: int = 0  # the paged kernel's route: KV rows the copies of
+    # a layer that sees everything MOVE for those steps, in and back
+    # (paged_attention.rows_moved; kv_rows is what they need)
+    kv_rows_moved_window: int = 0  # and those of a windowed layer's copies
 
     def args(self) -> dict:
         """The span / annotation arguments (`kind` is in the name too)."""
         a = {"kind": self.kind, "seq": self.seq, "n": self.n,
              "active": self.active, "starved": self.starved,
              "kv_rows": self.kv_rows, "prefill_rows": self.prefill_rows}
+        if self.kv_rows_moved:
+            a["kv_rows_moved"] = self.kv_rows_moved
         if self.kv_rows_window is not None:
             a["kv_rows_window"] = self.kv_rows_window
+            if self.kv_rows_moved_window:
+                a["kv_rows_moved_window"] = self.kv_rows_moved_window
         return a
 
     def count(self) -> "LaunchRecord":
@@ -128,6 +137,9 @@ class LaunchRecord:
             ins.SLOT_STEPS.labels(state="starved").inc(self.starved)
         if self.kv_rows:
             ins.LAUNCH_KV_ROWS.labels(kind=self.kind).inc(self.kv_rows)
+        if self.kv_rows_moved:
+            ins.LAUNCH_KV_ROWS_MOVED.labels(kind=self.kind).inc(
+                self.kv_rows_moved)
         if self.prefill_rows:
             ins.LAUNCH_PREFILL_ROWS.labels(kind=self.kind).inc(
                 self.prefill_rows)
@@ -166,7 +178,8 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
           pool_dry: bool, prefill_rows: int = 0,
           frozen: np.ndarray | None = None, window: int = 0,
           kv_pool: str = "", kind_layers: tuple = (0, 0),
-          state_slice_bytes: int = 0, sampler: str = "") -> LaunchRecord:
+          state_slice_bytes: int = 0, sampler: str = "",
+          paged: tuple | None = None) -> LaunchRecord:
     """The record of a launch of `n` steps over slots at `start_pos`, of
     which the `active` ones advance `advance` rows each.
 
@@ -178,7 +191,11 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
     frozen steps per slot where they are not n - advance (a spec chunk).
     `window` > 0 (a model with windowed layers): also the rows such a layer
     reads, min(p + 1, window) a step; `kind_layers` = (layers that see the
-    whole context, windowed layers)."""
+    whole context, windowed layers). `paged` = (page rows, table width, tile
+    rows, end-copy rows) on the paged kernel's route: also the rows a
+    layer's copies MOVE for those steps, by the kernel's own definition
+    (`paged_attention.rows_moved`; a spec chunk's as its single-row steps,
+    as `kv_rows` counts them)."""
     if kind not in LAUNCH_KINDS:
         raise ValueError(f"unknown launch kind {kind!r} "
                          f"(catalog: {LAUNCH_KINDS})")
@@ -189,16 +206,27 @@ def build(kind: str, seq: int, n: int, start_pos: np.ndarray,
     if pool_dry:
         idle = (n - adv) if frozen is None else frozen[active]
         starved = int(idle[pos + adv < seq_len].sum())
-    kv_rows_window = None
-    if window:
+    kv_rows_window, moved, moved_window = None, 0, 0
+    if window or paged:
+        # a step a column: the s-th step of a slot writes row pos + s - 1
         step = np.arange(1, int(adv.max(initial=0)) + 1, dtype=np.int64)[None]
+        took = step <= adv[:, None]
+    if window:
         seen = np.minimum(pos[:, None] + step, window)
-        kv_rows_window = int(seen[step <= adv[:, None]].sum())
+        kv_rows_window = int(seen[took].sum())
+    if paged:
+        from dllama_tpu.ops.pallas.paged_attention import rows_moved
+
+        at = (pos[:, None] + step - 1)[took]
+        moved = int(rows_moved(at, *paged).sum())
+        if window:
+            moved_window = int(rows_moved(at, *paged, window).sum())
     return LaunchRecord(
         kind=kind, seq=int(seq), n=int(n), active=n_active,
         advanced=int(adv.sum()), starved=starved,
         empty=(active.size - n_active) * int(n),
         kv_rows=int((adv * pos + adv * (adv + 1) // 2).sum()),
+        kv_rows_moved=moved, kv_rows_moved_window=moved_window,
         prefill_rows=int(prefill_rows), pool_dry=bool(pool_dry),
         kv_rows_window=kv_rows_window, kv_pool=kv_pool,
         kind_layers=kind_layers,

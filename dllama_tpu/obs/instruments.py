@@ -352,6 +352,16 @@ LAUNCH_KV_ROWS = metrics.counter(
     "slot, advance*start_pos + advance*(advance+1)/2. Over advanced "
     "slot-steps it is the mean context length on the device",
     ("kind",))
+LAUNCH_KV_ROWS_MOVED = metrics.counter(
+    "dllama_launch_kv_rows_moved_total",
+    "KV rows the paged kernel's copies MOVE per attention layer that sees "
+    "the whole context for the decode steps dllama_launch_kv_rows_total "
+    "counts, by kind: the walk's pages copied in, its last page by live "
+    "units of rows, and the tile of rows written back "
+    "(ops/pallas/paged_attention.rows_moved, the kernel's own definition); "
+    "over dllama_launch_kv_rows_total it is what the kernel moves for a "
+    "row the step needs. Only on the paged kernel's route",
+    ("kind",))
 LAUNCH_PREFILL_ROWS = metrics.counter(
     "dllama_launch_prefill_rows_total",
     "Prompt rows written by the launches, by kind (a hybrid launch's "

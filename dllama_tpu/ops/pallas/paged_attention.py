@@ -21,6 +21,21 @@ SNIPPETS.md [1]/[2]'s `pltpu.PrefetchScalarGridSpec` scalar-prefetch idiom):
   lanes]``, so a page's ``hb`` consecutive heads are ONE contiguous run
   (``pool.at[pg, pl.ds(h0, hb)]``: 1 MB at 32 heads x 128 rows x 128 lanes
   of bf16), not ``hb`` separate 32 KB copies that each pay a round trip;
+* the two ENDS of a walk move the rows the step reads, not the pages it
+  touches (PR 53; the kernel is HBM-bound, and at a few pages a slot the
+  ends are a third of its bytes): the walk's LAST page is copied in by
+  units of ``sub`` rows from row 0 to the unit that holds row
+  ``(pos + t - 1) % page``, a windowed walk's FIRST page from the unit that
+  holds row ``(pos - W + 1) % page`` on (one page that is both: the
+  intersection), every page between them stays ONE copy.
+  :func:`walk_ends` is the one definition of those rows: the kernel sizes
+  its copies from it (the successor's too: its ``pos`` is scalar prefetch)
+  and the host counts them from it (:func:`rows_moved`). ``sub`` is a
+  function of the page, the dtype and the head block's bytes
+  (:func:`_row_tiles`: a page whose copy is under 256 KB comes whole, its
+  pass is bound by its fixed cost and a branch a page buys nothing). Rows of a ring
+  slot that no copy filled hold an older page's rows: the mask gives them
+  p = 0 and 0 x finite = 0, so the value ring is zeroed once a call;
 * the grid is one step per (slot, block of ``hb`` kv heads, q tile); the
   page run is a dynamic ``fori_loop`` bounded by the slot's LIVE page count
   (``pos``-derived), so decode cost scales with the live context exactly
@@ -41,12 +56,15 @@ SNIPPETS.md [1]/[2]'s `pltpu.PrefetchScalarGridSpec` scalar-prefetch idiom):
   nothing back: a row's target page is one of the sweep's own last pages,
   so when that page's head block has landed the rows are blended into the
   landed copy (an f32 ``where`` over the sublane tile that holds the row),
-  the sweep reads the blended copy, and ONE DMA writes the block back under
-  that page's dots. An inactive slot's rows, routed to the trash page no
-  table holds, find that page riding as one more page behind the slot's
-  sweep (masked out of the softmax): the same blend, the same one write a
-  head block. The separate `_paged_cache_update` dispatch decode used to
-  pay per layer is gone;
+  the sweep reads the blended copy, and the TILES that received rows are
+  written back under that page's dots (PR 53: ``hb`` runs of ``win`` rows,
+  2 x 128 KB a slot at 32 heads where the two head blocks were 2 MB; a chunk
+  of t rows writes the tiles from its first row's to its last row's of
+  each page; a page that is not whole tiles of its dtype is one tile). An
+  inactive slot's rows, routed to the trash page no table holds, find that
+  page riding as one more page behind the slot's sweep (masked out of the
+  softmax): the same blend, the same tiles in and out. The separate
+  `_paged_cache_update` dispatch decode used to pay per layer is gone;
 * q and K enter the q.k product as the bfloat16 they are stored as where
   both are (a product of two bfloat16 values is exact in float32, so it is
   the same sum in one MXU pass); the scale, mask, exp, p, l, the
@@ -131,6 +149,27 @@ _MAX_DEPTH = 4
 #: (warm-up time) does not grow with this number.
 _LATENT_PASS_PAGES = 8
 _Q_TILE_MAX = 128  # folded q rows one grid step takes (and one MXU pass)
+#: Rows one copy of a walk's END page carries where the page holds several
+#: (`_row_tiles`): the smallest within 5% of the best row at EVERY shape of
+#: the chip's sweep (`experiments/kbench.py paged --decode --sub`, PR 53,
+#: ms a fused decode call at 16 / 32 / 64 rows and the whole page, two
+#: runs: DeepSeek 12 slots x 32 heads, 2-4 pages: 0.1353-0.1356 /
+#: 0.1360-0.1397 / 0.1414-0.1424 / 0.1482-0.1486; Granite 48 x 8, 1-8:
+#: 0.1763-0.1768 / 0.1774 / 0.1787-0.1810 / 0.1854-0.1874; Laguna's window
+#: 24 x 8, 5 pages of which two are ends: 0.1194-0.1198 / 0.1141-0.1153 /
+#: 0.1125-0.1138 / 0.1202-0.1205, where 16 rows read 6% over the best;
+#: Laguna's global 4-33 pages: 0.3552-0.3559 / 0.3556-0.3564 / 0.3573-0.3575
+#: / 0.3616-0.3622).
+_END_COPY_ROWS = 32
+#: A page's head block (one pool's copy) below this many bytes comes whole:
+#: a pass that small is bound by its fixed cost, not its bytes (PR 48), and
+#: what an end saves (3/8 of a page's copy on average: 0.06 us at 4 heads)
+#: is less than the choice costs, which is a branch for EVERY page of the
+#: walk, at its start and at its wait. SmallThinker's calls (4 kv heads: 128 KB; walks of 33-76 pages)
+#: read 0.2935-0.2960 ms by units and 0.2835-0.2841 whole (the parent
+#: 0.2875); Granite's and Laguna's (8 heads: 256 KB) and DeepSeek's (1 MB)
+#: gain (PR 53, the same sweep).
+_END_MIN_PAGE_BYTES = 256 * 1024
 
 _LANES = 128  # TPU vector lane count: the minor-dim tile of every memref
 
@@ -145,6 +184,95 @@ def pool_lanes(head_size: int) -> int:
     Engines on the kernel route allocate their pool this wide; the pad
     lanes stay zero (zero q lanes score 0, zero v lanes are sliced off)."""
     return -(-head_size // _LANES) * _LANES
+
+
+def _row_tiles(page: int, itemsize: int, block_bytes: int | None = None,
+               end_copy: tuple[int, int] | None = None) -> tuple[int, int]:
+    """(win, sub) of a call, functions of its shapes and dtype alone:
+    ``win`` is the rows of one sublane tile of the dtype (16 of bfloat16, 8
+    of float32), what a new row is blended into and written back as; ``sub``
+    is the rows one copy of a walk's END page carries: the largest whole
+    number of tiles up to ``_END_COPY_ROWS`` that divides the page, or the
+    page where its head block (``block_bytes`` a pool) is under
+    ``_END_MIN_PAGE_BYTES`` (``end_copy`` = those two, read at call time).
+    A page that is not whole tiles is one tile and one unit: every copy of
+    it is the whole page, as before PR 53."""
+    end_rows, min_bytes = end_copy or (_END_COPY_ROWS, _END_MIN_PAGE_BYTES)
+    win = 32 // itemsize
+    if page % win:
+        return page, page
+    if block_bytes is not None and block_bytes < min_bytes:
+        return win, page
+    return win, max(s for s in range(win, max(end_rows, win) + 1, win)
+                    if page % s == 0)
+
+
+def decode_tiles(n_heads: int, n_kv_heads: int, page: int, lanes: int,
+                 itemsize: int) -> tuple[int, int]:
+    """(win, sub) of an engine's fused decode call (t = 1), for the host's
+    count of the rows it moves (`rows_moved`): the head block is `_plan`'s
+    for the call's shapes."""
+    tq = _q_tile(-(-(n_heads // n_kv_heads) // 8) * 8)
+    hb = _plan(n_kv_heads, page, lanes, itemsize, tq, 1, _VMEM_BUDGET_BYTES)[0]
+    return _row_tiles(page, itemsize, hb * page * lanes * itemsize)
+
+
+class _Rows:
+    """Row arithmetic on TRACED scalars, as `numpy` spells it on the host's
+    arrays: `walk_ends` runs on either. Every operand is a row count >= 0,
+    so the truncating division is the floor; the primitives are bound
+    directly because the kernel's lowering time is every warm program's
+    set-up time: `jnp.where` / `minimum` wrap theirs in a nested jit, and
+    `//` and `%` lower through a traced `sign` (3 ms an operator on the CPU
+    where a bound `div` takes 0.3)."""
+
+    _i32 = staticmethod(lambda x: jnp.int32(x) if isinstance(x, int) else x)
+    where = staticmethod(lambda c, a, b: jax.lax.select(
+        c, _Rows._i32(a), _Rows._i32(b)))
+    minimum = staticmethod(lambda a, b: jax.lax.min(a, _Rows._i32(b)))
+    maximum = staticmethod(lambda a, b: jax.lax.max(a, _Rows._i32(b)))
+    floor_divide = staticmethod(lambda a, b: jax.lax.div(a, _Rows._i32(b)))
+    remainder = staticmethod(lambda a, b: jax.lax.rem(a, _Rows._i32(b)))
+
+
+def walk_ends(xp, pos, first_q, last_q, page: int, nb: int,
+              window: int | None):
+    """(lo, hi, r0, r1) of one walk: the blocks [lo, hi) of the slot's table
+    that queries at ``pos + first_q .. pos + last_q`` read, the first live
+    row of block ``lo`` and one past the last live row of block ``hi - 1``.
+    THE definition of which rows a walk needs from its two end pages: the
+    kernel sizes its copies from it and the host counts them from it
+    (``xp`` is :class:`_Rows` there and `numpy` here). A walk clipped at
+    the table's end (a horizon past ``nb`` pages) takes both end pages
+    whole: a clipped chunk's rows wrap around the last page."""
+    last_key = pos + last_q
+    last_blk = xp.floor_divide(last_key, page)
+    hi = xp.minimum(last_blk + 1, nb)
+    inside = last_blk < nb
+    r1 = xp.where(inside, xp.remainder(last_key, page) + 1, page)
+    if window is None:
+        return 0, hi, 0, r1
+    first_key = xp.maximum(pos + first_q - window + 1, 0)
+    first_blk = xp.floor_divide(first_key, page)
+    lo = xp.minimum(first_blk, hi - 1)
+    r0 = xp.where(inside & (first_blk == lo), xp.remainder(first_key, page), 0)
+    return lo, hi, r0, r1
+
+
+def rows_moved(pos, page: int, nb: int, win: int, sub: int,
+               window: int | None = None):
+    """KV rows (of one pool: k and v move the same) the DMAs of ONE layer's
+    fused decode call move for a live slot whose new row lands at ``pos``
+    (numpy, any shape): the walk's pages copied in, its two end pages by
+    live units of ``sub`` rows, and the ``win``-row tile written back
+    (``win``, ``sub``: `decode_tiles`). What the call NEEDS is ``pos + 1``
+    rows (``min(pos + 1, window)``)."""
+    import numpy as np
+
+    lo, hi, r0, r1 = walk_ends(np, np.asarray(pos, np.int64), 0, 0, page, nb,
+                               window)
+    first, last = r0 // sub * sub, ((r1 - 1) // sub + 1) * sub
+    return (hi - lo - 1) * page + last - first + win  # one page is both ends
 
 
 def paged_decode_supported(q_shape: tuple[int, ...], page_size: int,
@@ -265,7 +393,7 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
             kbuf, vbuf, newk32, newv32, acc_ref, m_ref, l_ref, base_ref,
             copy_sems, write_sems,
             *, scale, page, group, t, tq, rows_live, nb, fused, hb, depth,
-            mxu_dtype, window=None, latent=False, pp=1):
+            mxu_dtype, sub, window=None, latent=False, pp=1):
     b, hblk, iq = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nbatch, nhb, nq = pl.num_programs(0), pl.num_programs(1), pl.num_programs(2)
     lanes = kbuf.shape[-1]
@@ -278,6 +406,10 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
     assert pp == 1 or (latent and window is None), (pp, latent, window)
     span = pp * page
     passes = lambda pages: pages if pp == 1 else (pages + (pp - 1)) // pp
+    # A page a pass copies the two END pages of a walk by live units of
+    # `sub` rows (`walk_ends`); `sub = page` (a page that is not whole tiles,
+    # or no larger than a unit) is one copy a page and the program it was.
+    ends = pp == 1 and sub < page
 
     def sweep_pages(bb, qq):
         # live-page horizon of q tile qq of slot bb (mirrors
@@ -292,6 +424,14 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
         # blocks before it may have been handed back to the pool already
         lo = jnp.maximum(pos_ref[bb] + (qq * tq) // group - window + 1, 0) // page
         return jnp.minimum(lo, hi - 1)
+
+    def sweep_rows(bb, qq):
+        # the first live row of the walk's first page, one past the last
+        # live row of its last page (the blocks are sweep_first / _pages')
+        return walk_ends(
+            _Rows, pos_ref[bb], _Rows.floor_divide(qq * tq, group),
+            _Rows.floor_divide(_Rows.minimum(qq * tq + tq - 1, rows_live - 1),
+                               group), page, nb, window)[2:]
 
     # ---- fused KV scatter, addressing. A live slot's rows pos .. pos+t-1
     # land in table pages blk(0) .. blk(t-1), the LAST pages of its sweep
@@ -325,6 +465,12 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
         # a page is not whole tiles of its dtype
         win = 32 // jnp.dtype(kbuf.dtype).itemsize
         win = page if page % win else win
+        if pp == 1:
+            # the rows [lo, hi) a chunk routed to the trash page lands in:
+            # (pos + tt) % page, which wraps where the chunk crosses a page
+            tr_lo = woffs_ref[b, 0]
+            tr_hi = _Rows.where(tr_lo + t <= page, tr_lo + t, page)
+            tr_lo = _Rows.where(tr_lo + t <= page, tr_lo, 0)
     else:
         n = n_pass
 
@@ -345,6 +491,11 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
             # rows of the ring hold meets p = 0 in the value product, and
             # must be finite (afterwards: an older pass's rows)
             kbuf[...] = jnp.zeros_like(kbuf)
+        if ends:
+            # rows of a ring slot that no copy has filled meet p = 0 in the
+            # value product: finite (afterwards: an older page's rows)
+            vals = kbuf if latent else vbuf
+            vals[...] = jnp.zeros_like(vals)
 
     base = base_ref[0]
     wrap_q = iq == nq - 1
@@ -360,6 +511,8 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
         lo2 = sweep_first(b2, iq2, n2_sweep)
         n2_sweep = n2_sweep - lo2
     n2 = jnp.where(last_step, 0, passes(n2_sweep))
+    if ends:
+        (r0, r1), (r0_2, r1_2) = sweep_rows(b, iq), sweep_rows(b2, iq2)
 
     def page_id(bb, i, own):
         # defensive clamp like _paged_cache_update: a horizon past the
@@ -370,11 +523,12 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
             pg = jnp.where(own & (i >= n_sweep), wpages_ref[b, 0], pg)
         return pg
 
+    # a latent row is key AND value: one pool, one copy a page
+    pairs = (((kpool_ref, kbuf),) if latent
+             else ((kpool_ref, kbuf), (vpool_ref, vbuf)))
+
     def copies(pg, hh, slot, k=0, back=False):
         heads = pl.ds(hh * hb, hb)  # [hb, page, lanes]: contiguous in HBM
-        # a latent row is key AND value: one pool, one copy a page
-        pairs = (((kpool_ref, kbuf),) if latent
-                 else ((kpool_ref, kbuf), (vpool_ref, vbuf)))
         # where page k of a pass lands, and the semaphores of its copies
         land = lambda buf: buf.at[slot] if pp == 1 else buf.at[
             slot, :, pl.ds(pl.multiple_of(k * page, page), page), :]
@@ -386,6 +540,55 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
         return [pltpu.make_async_copy(pool.at[pg, heads], land(buf),
                                       copy_sems.at[sem, j])
                 for j, (pool, buf) in enumerate(pairs)]
+
+    def row_block(pg, hh, slot, r, rows, back=False):
+        # rows r .. r + rows of a page's head block: `hb` runs, one copy
+        heads = pl.ds(hh * hb, hb)
+        at = pl.ds(pl.multiple_of(r, rows), rows)
+        if back:
+            return [pltpu.make_async_copy(buf.at[slot, :, at, :],
+                                          pool.at[pg, heads, at, :],
+                                          write_sems.at[j])
+                    for j, (pool, buf) in enumerate(pairs)]
+        return [pltpu.make_async_copy(pool.at[pg, heads, at, :],
+                                      buf.at[slot, :, at, :],
+                                      copy_sems.at[slot, j])
+                for j, (pool, buf) in enumerate(pairs)]
+
+    def end_units(i, mine, pg, hh, slot, do):
+        """do(copy) for the copies that bring run page i of this step's walk
+        (`mine`) or of the successor's into its ring slot: ONE copy of the
+        page, or, where the page is an END of the walk (its first page
+        under a window, its last, the trash page behind it), a copy a unit
+        of `sub` rows from the first live unit to the last. A page between
+        the ends pays one compare and the branch; the units are a loop: the
+        traced size is warm-up time. A wait mirrors its start."""
+        pick = (lambda a, b: a) if mine is True else (
+            lambda a, b: _Rows.where(mine, a, b))
+        last = pick(n_sweep, n2_sweep) - 1
+        at_end = i >= last if window is None else (i >= last) | (i == 0)
+
+        def whole():
+            for cp in copies(pg, hh, slot):
+                do(cp)
+
+        def units():
+            lo_r = 0 if window is None else _Rows.where(
+                i == 0, pick(r0, r0_2), 0)
+            hi_r = _Rows.where(i == last, pick(r1, r1_2), page)
+            if fused:  # (the trash page: the units its rows land in)
+                lo_r = _Rows.where(i > last, tr_lo, lo_r)
+                hi_r = _Rows.where(i > last, tr_hi, hi_r)
+
+            def unit(u, _):
+                for cp in row_block(pg, hh, slot, u * sub, sub):
+                    do(cp)
+                return 0
+
+            jax.lax.fori_loop(_Rows.floor_divide(lo_r, sub),
+                              _Rows.floor_divide(hi_r - 1, sub) + 1, unit, 0)
+
+        jax.lax.cond(at_end, units, whole)
 
     def each_page(i, pages, fn, first_page=lambda: 0):
         """fn(k, page index) for the pages of pass i of a sweep of `pages()`
@@ -414,6 +617,10 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
 
             def go(k, ix):
                 pg = page_id(bb, ix, mine)
+                if ends:
+                    return end_units(ix, mine, pg, jnp.where(mine, hblk, hblk2),
+                                     jax.lax.rem(base + v, depth),
+                                     lambda cp: cp.start())
                 for cp in copies(pg, jnp.where(mine, hblk, hblk2),
                                  jax.lax.rem(base + v, depth), k):
                     cp.start()
@@ -454,6 +661,9 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
         pid = lambda ix: here if pp == 1 else page_id(b, ix, True)
 
         def landed(k, ix):
+            if ends:
+                return end_units(ix, True, pid(ix), hblk, slot,
+                                 lambda cp: cp.wait())
             for cp in copies(pid(ix), hblk, slot, k):
                 cp.wait()
 
@@ -466,16 +676,43 @@ def _kernel(pos_ref, tables_ref, wpages_ref, woffs_ref,  # scalar prefetch
             first_target = target(0)
             if pp == 1:
                 wrote = i >= first_target
-                back0 = copies(here, hblk, slot, back=True)
+                # the rows of this page that received rows, first to last:
+                # the chunk's own (one row: its offset), those of a chunk
+                # clipped at the table's end (they wrap: the page), the
+                # trash page's
+                if t == 1:
+                    w_lo = woffs_ref[b, 0]
+                else:
+                    at = pos_b - (lo + i) * page  # the chunk's first row
+                    clipped = (lo + i == nb - 1) & (pos_b + t > nb * page)
+                    w_lo = _Rows.where(live, _Rows.where(
+                        clipped, 0, _Rows.maximum(at, 0)), tr_lo)
+                    w_hi = _Rows.where(live, _Rows.where(
+                        clipped, page - 1,
+                        _Rows.minimum(at + t - 1, page - 1)), tr_hi - 1)
             else:  # the pass holds a page at or behind the first target
                 wrote = i >= first_target // pp
 
             def each_back(do):
+                if pp == 1:
+                    # the TILES of the page that received rows: `hb` runs
+                    # of `win` rows each, not the head block
+                    def tile(w, _):
+                        for wr in row_block(here, hblk, slot, w * win, win,
+                                            back=True):
+                            do(wr)
+                        return 0
+
+                    tile_of = lambda r: _Rows.floor_divide(r, win)
+                    if t == 1:
+                        return tile(tile_of(w_lo), 0)
+                    return jax.lax.fori_loop(tile_of(w_lo), tile_of(w_hi) + 1,
+                                             tile, 0)
+
                 # ONE write a page that received rows: every page of the
                 # run from the first target on (the sweep ends on the last)
                 def go(k, ix):
-                    for wr in back0 if pp == 1 else copies(
-                            pid(ix), hblk, slot, k, back=True):
+                    for wr in copies(pid(ix), hblk, slot, k, back=True):
                         do(wr)
 
                 each_page(i, lambda: n_sweep, go,
@@ -555,6 +792,7 @@ def _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
                 new_v, *, group: int, interpret: bool, rows_live: int,
                 fused: bool, scale: float,
                 vmem_budget: int = _VMEM_BUDGET_BYTES,
+                end_copy: tuple[int, int] | None = None,
                 window: int | None = None, latent: bool = False):
     """qf[B, Hkv, rows_pad, hd] x pool[N, Hkv, page, hd] ->
     (out f32 [B, Hkv, rows_pad, hd], k_pool, v_pool).
@@ -580,6 +818,8 @@ def _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
     assert not fused or _fuses(t, rows), (t, group, rows)
     hb, depth, pp, _ = _plan(hkv, page, hd, k_pool.dtype.itemsize, tq, t,
                              vmem_budget, latent)
+    sub = _row_tiles(page, k_pool.dtype.itemsize,
+                     hb * page * hd * k_pool.dtype.itemsize, end_copy)[1]
     grid = (b, hkv // hb, rows // tq)
     bf16 = jnp.dtype(jnp.bfloat16)
     mxu_dtype = bf16 if qf.dtype == bf16 == k_pool.dtype else jnp.dtype(
@@ -622,7 +862,7 @@ def _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
                           group=group, t=t, tq=tq, rows_live=rows_live,
                           nb=nb, fused=fused, hb=hb, depth=depth,
                           mxu_dtype=mxu_dtype, window=window, latent=latent,
-                          pp=pp),
+                          pp=pp, sub=sub),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, rows, hd), jnp.float32),
@@ -649,21 +889,23 @@ def _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
     return out, k_pool, v_pool
 
 
-_STATIC = ("group", "interpret", "rows_live", "fused", "scale", "vmem_budget")
+_STATIC = ("group", "interpret", "rows_live", "fused", "scale", "vmem_budget",
+           "end_copy")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _paged_folded(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
                   new_v, *, group: int, interpret: bool, rows_live: int,
                   fused: bool, scale: float,
-                  vmem_budget: int = _VMEM_BUDGET_BYTES):
+                  vmem_budget: int = _VMEM_BUDGET_BYTES,
+                  end_copy: tuple[int, int] | None = None):
     """`_paged_call` for a layer whose queries see the whole context, under
     the name the benchmark's trace reader finds it by (benchmark/costs/
     paged_attention.py)."""
     return _paged_call(qf, k_pool, v_pool, pos, tables, wpages, woffs, new_k,
                        new_v, group=group, interpret=interpret,
                        rows_live=rows_live, fused=fused, scale=scale,
-                       vmem_budget=vmem_budget)
+                       vmem_budget=vmem_budget, end_copy=end_copy)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC + ("window",))
@@ -840,7 +1082,8 @@ def paged_decode_attention(
         qf, k_pool, v_pool, pos, tables + first_page, wpages, woffs, nk, nv,
         group=group, interpret=interpret, rows_live=rows, fused=write,
         scale=1.0 / math.sqrt(hd) if scale is None else float(scale),
-        vmem_budget=_VMEM_BUDGET_BYTES)
+        vmem_budget=_VMEM_BUDGET_BYTES,
+        end_copy=(_END_COPY_ROWS, _END_MIN_PAGE_BYTES))
     vd = latent or hd
     out = (
         out[:, :, :rows, :vd].reshape(b, hkv, t, group, vd)

@@ -60,6 +60,7 @@ _CAPTURE_COUNTERS = {"launches": ins.LAUNCHES,
                      "sampler_launches": ins.SAMPLER_LAUNCHES,
                      "slot_steps": ins.SLOT_STEPS,
                      "kv_rows": ins.LAUNCH_KV_ROWS,
+                     "kv_rows_moved": ins.LAUNCH_KV_ROWS_MOVED,
                      "prefill_rows": ins.LAUNCH_PREFILL_ROWS,
                      "kv_rows_read": ins.LAUNCH_KV_ROWS_READ,
                      "moe_assignments": ins.MOE_ASSIGNMENTS,
@@ -84,7 +85,8 @@ def _launch_counters() -> dict:
 def last_capture() -> dict | None:
     """What the engine launched and what the host spent during the last
     FINISHED capture, as counter deltas: {"launches": {kind: n},
-    "slot_steps": {state: n}, "kv_rows": {kind: n}, "prefill_rows":
+    "slot_steps": {state: n}, "kv_rows": {kind: n}, "kv_rows_moved":
+    {kind: n}, "prefill_rows":
     {kind: n}, ..., "sched_seconds": {state: s}, "phase_seconds":
     {phase: s}, "phases": {phase: n}, "drains": {reason: n},
     "launch_waits": {outcome: n}, "host_gap": {"sum", "count"},

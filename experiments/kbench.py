@@ -317,7 +317,11 @@ def enable_smoke():
                          kv_pages=8, rows=(10, 40)),
         "tiny gqa": dict(slots=3, hq=8, hkv=2, hd=64, page=8, layers=2,
                          kv_pages=16, rows=(3, 30)),
+        "tiny window": dict(slots=2, hq=8, hkv=2, hd=128, page=64, layers=2,
+                            kv_pages=8, rows=(70, 200), window=80),
     }
+    global PAGED_SUBS
+    PAGED_SUBS = (16, 0)
 
 
 def bench_flash_decode():
@@ -415,7 +419,8 @@ def bench_flash_decode():
 
 #: The decode-shaped paged-attention call of each benchmark cell (PERF.md
 #: section 4): slots, heads, the pool as the engine allocates it, and the
-#: span of rows the slots stand at in a window.
+#: span of rows the slots stand at in a window; `window`: a windowed
+#: layer's call (`_paged_window`).
 PAGED_CELLS = {
     "deepseek7b.decode_closed": dict(
         slots=12, hq=32, hkv=32, hd=128, page=128, layers=30, kv_pages=66,
@@ -423,37 +428,64 @@ PAGED_CELLS = {
     "granite4h.reason_closed": dict(
         slots=48, hq=32, hkv=8, hd=64, page=128, layers=4, kv_pages=456,
         rows=(100, 1000)),
+    "lagunaxs2.reason_long_closed window": dict(
+        slots=24, hq=64, hkv=8, hd=128, page=128, layers=30, kv_pages=168,
+        rows=(512, 4096), window=512),
+    "lagunaxs2.reason_long_closed global": dict(
+        slots=24, hq=48, hkv=8, hd=128, page=128, layers=10, kv_pages=816,
+        rows=(512, 4096)),
+    "smallthinker.long_decode_closed window": dict(
+        slots=16, hq=28, hkv=4, hd=128, page=128, layers=18, kv_pages=592,
+        rows=(5120, 9700), window=4096),
+    "smallthinker.long_decode_closed global": dict(
+        slots=16, hq=28, hkv=4, hd=128, page=128, layers=6, kv_pages=1232,
+        rows=(5120, 9700)),
 }
 PAGED_CALLS = 300
+PAGED_SUBS = (16, 32, 64, 0)  # rows a copy of an end page (0: the page, PR 52's kernel)
 HBM_GBS, MXU_TFLOPS = 819.0, 197.0  # TPU v5e (benchmark/peaks.json)
 
 
-def bench_paged_decode(cells=None, calls=None, rows=None):
+def bench_paged_decode(cells=None, calls=None, rows=None, subs=()):
     """The paged flash-decode kernel alone on the chip, as a decode step
     calls it: `paged_decode_attention` with t = 1 on the layer-stacked pool,
     the layer cycling, the pools threaded through PAGED_CALLS calls of one
     jitted scan. Prints ms a call beside what the HBM would take for the
-    rows the call NEEDS (`benchmark/costs/paged_attention.py`'s bytes) and
-    for the whole pages it touches, with the new row's scatter fused into
-    the kernel and without it (the same kernel over pools nobody writes)."""
+    rows the call NEEDS (`benchmark/costs/paged_attention.py`'s bytes), for
+    the whole pages it touches and for the bytes its copies MOVE (in and
+    back: `pa.rows_moved`; a tree without it moved the pages touched and a
+    page's head block back), with the new row's scatter fused into the
+    kernel and without it (the same kernel over pools nobody writes).
+    `subs`: the fused call again at each size of an end page's copies
+    (`pa._END_COPY_ROWS`; 0 = the whole page), whatever the head block's
+    bytes (`pa._END_MIN_PAGE_BYTES` = 0)."""
     from dllama_tpu.ops.pallas import paged_attention as pa
 
     calls = calls or PAGED_CALLS
     for name, c in (cells or PAGED_CELLS).items():
         b, hq, hkv, hd, page = c["slots"], c["hq"], c["hkv"], c["hd"], c["page"]
+        window = c.get("window")
         lo, hi = rows or c["rows"]
         lanes = pa.pool_lanes(hd)
         nb = -(-(hi + 1) // page)
-        n_pool = max(c["kv_pages"], b * nb) + 1  # + the trash page
+        # a windowed layer's table holds the window's pages alone
+        held = nb if window is None else min(nb, -(-window // page) + 1)
+        n_pool = max(c["kv_pages"], b * held) + 1  # + the trash page
         rng = np.random.default_rng(0)
         pool = lambda: jnp.asarray(
             rng.standard_normal((1, n_pool, hkv, page, lanes), np.float32),
             jnp.bfloat16) * jnp.ones((c["layers"], 1, 1, 1, 1), jnp.bfloat16)
         kp, vp = pool(), pool()
+        pos_h = np.linspace(lo, hi, b).astype(np.int32)
+        pos = jnp.asarray(pos_h)
         # distinct pages a slot, shuffled: the physical order must not help
-        tables = jnp.asarray(
-            rng.permutation(n_pool - 1)[: b * nb].reshape(b, nb), jnp.int32)
-        pos = jnp.asarray(np.linspace(lo, hi, b).astype(np.int32))
+        pages = rng.permutation(n_pool - 1)[: b * held].reshape(b, held)
+        tables = np.zeros((b, nb), np.int32)
+        for i, p in enumerate(pos_h):  # the blocks the walk reads
+            first = 0 if window is None else max(p - window + 1, 0) // page
+            blocks = np.arange(first, p // page + 1)
+            tables[i, blocks] = pages[i, : len(blocks)]
+        tables = jnp.asarray(tables)
         q = jnp.asarray(rng.standard_normal((b, 1, hq, hd)), jnp.bfloat16)
         nk = jnp.asarray(rng.standard_normal((b, hkv, 1, hd)), jnp.bfloat16)
         nv = jnp.asarray(rng.standard_normal((b, hkv, 1, hd)), jnp.bfloat16)
@@ -462,7 +494,7 @@ def bench_paged_decode(cells=None, calls=None, rows=None):
         def fused(q, kp, vp, li):
             return pa.paged_decode_attention(
                 q, kp, vp, tables, pos, nk, nv, None, layer=li,
-                interpret=INTERPRET)
+                interpret=INTERPRET, window=window)
 
         def read_only(q, kp, vp, li):
             # the wrapper's fold, then the kernel with fused=False: the
@@ -474,17 +506,30 @@ def bench_paged_decode(cells=None, calls=None, rows=None):
             qf = jnp.pad(qf, ((0, 0), (0, 0), (0, (-group) % 8), (0, 0)))
             zero = jnp.zeros((b, 1), jnp.int32)
             row = jnp.zeros((b, hkv, 1, lanes), kp.dtype)
-            out, k2, v2 = pa._paged_folded(
+            call = pa._paged_folded if window is None else functools.partial(
+                pa._paged_window, window=window)
+            out, k2, v2 = call(
                 qf, kp.reshape(-1, *kp.shape[2:]), vp.reshape(-1, *vp.shape[2:]),
                 pos, tables + li * n_pool, zero, zero, row, row, group=group,
                 interpret=INTERPRET, rows_live=group, fused=False,
                 scale=hd ** -0.5)
             return out, k2.reshape(kp.shape), v2.reshape(vp.shape)
 
-        seen = np.asarray(pos) + 1
+        row_bytes = 2 * hkv * lanes * 2  # k and v, every head, bf16
+        seen = pos_h + 1 if window is None else np.minimum(pos_h + 1, window)
+        first = 0 if window is None else np.maximum(pos_h - window + 1, 0) // page
+        touched_rows = (pos_h // page + 1 - first) * page
         needed = float(seen.sum()) * 2 * hkv * hd * 2 + b * hq * hd * (2 + 4)
-        touched = float((-(-seen // page)).sum()) * 2 * hkv * page * lanes * 2
-        for label, call in (("fused scatter", fused), ("read-only", read_only)):
+        touched = float(touched_rows.sum()) * row_bytes
+
+        def moved_bytes(is_fused):
+            if not hasattr(pa, "rows_moved"):  # whole pages, a page back
+                return float((touched_rows + (page if is_fused else 0)).sum()) * row_bytes
+            win, sub = pa.decode_tiles(hq, hkv, page, lanes, 2)
+            return float((pa.rows_moved(pos_h, page, nb, win, sub, window)
+                          - (0 if is_fused else win)).sum()) * row_bytes
+
+        def timed(label, call, is_fused):
             @jax.jit
             def loop(q, kp, vp):
                 def step(carry, i):
@@ -495,6 +540,7 @@ def bench_paged_decode(cells=None, calls=None, rows=None):
                     step, (kp, vp, jnp.float32(0)),
                     jnp.arange(calls, dtype=jnp.int32))[0]
 
+            nonlocal kp, vp
             try:
                 kp, vp, acc = loop(q, kp, vp)  # compiles; pools stay threaded
                 jax.block_until_ready(acc)
@@ -502,18 +548,29 @@ def bench_paged_decode(cells=None, calls=None, rows=None):
                 kp, vp, acc = loop(q, kp, vp)
                 jax.block_until_ready(acc)
                 ms = (time.perf_counter() - t0) / calls * 1e3
+                share = lambda nbytes: (
+                    f"{nbytes / 1e6:.1f} MB = {nbytes / HBM_GBS / 1e3:.1f} us "
+                    f"({nbytes / HBM_GBS / 1e4 / ms:.1f}% of the call)")
                 print(f"paged decode {name} {label}: {ms:.4f} ms a call over "
                       f"{calls} calls; slots {b} x {hkv} kv heads x {hd}, rows "
-                      f"{lo}-{hi}; needed {needed / 1e6:.1f} MB = "
-                      f"{needed / HBM_GBS / 1e3:.1f} us at {HBM_GBS:.0f} GB/s "
-                      f"({needed / HBM_GBS / 1e4 / ms:.1f}% of the call); pages "
-                      f"touched {touched / 1e6:.1f} MB = "
-                      f"{touched / HBM_GBS / 1e3:.1f} us "
-                      f"({touched / HBM_GBS / 1e4 / ms:.1f}%)")
+                      f"{lo}-{hi}{f', window {window}' if window else ''}; "
+                      f"needed {share(needed)} at {HBM_GBS:.0f} GB/s; pages "
+                      f"touched {share(touched)}; moved "
+                      f"{share(moved_bytes(is_fused))}")
             except Exception as e:
                 print(f"paged decode {name} {label}: FAILED {e!r}"[:300])
             sys.stdout.flush()
 
+        timed("fused scatter", fused, True)
+        timed("read-only", read_only, False)
+        keep = (getattr(pa, "_END_COPY_ROWS", None),
+                getattr(pa, "_END_MIN_PAGE_BYTES", None))
+        for sub in subs if keep[0] else ():
+            # (whatever the head block's bytes: the sweep prices that gate too)
+            pa._END_COPY_ROWS, pa._END_MIN_PAGE_BYTES = sub or page, 0
+            timed(f"fused scatter, end copies of {sub or page} rows", fused, True)
+        if keep[0]:
+            pa._END_COPY_ROWS, pa._END_MIN_PAGE_BYTES = keep
 
 
 #: The latent cells' sweep (one pool, Hkv = 1, the row is key and value):
@@ -1716,9 +1773,12 @@ def main():
         print("KBENCH DONE")
         return
     if sys.argv[1:2] == ["paged"]:
-        if "--latent" not in sys.argv:  # (--latent: the latent cells alone)
-            bench_paged_decode()
-        bench_paged_latent()
+        # (--latent: the latent cells alone; --decode: the other cells alone;
+        # --sub: the fused call again at each size of an end page's copies)
+        if "--latent" not in sys.argv:
+            bench_paged_decode(subs=PAGED_SUBS if "--sub" in sys.argv else ())
+        if "--decode" not in sys.argv:
+            bench_paged_latent()
         print("KBENCH DONE")
         return
     if sys.argv[1:2] == ["q40"]:

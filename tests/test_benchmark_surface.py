@@ -269,6 +269,51 @@ def test_host_metric_reads_series_the_program_exports(metric, served):
     assert value is not None and value >= 0.0
 
 
+def test_kv_rows_moved_metric_reads_what_the_paged_kernels_engine_counts():
+    """`kv_rows_moved_per_needed` (ISSUE 53): on the paged kernel's route a
+    decode launch moves `dllama_launch_kv_rows_moved_total` beside
+    `dllama_launch_kv_rows_total`, the harness's parser keys both as the
+    metric file names them, and the harness's reducer gives the ratio the
+    kernel's own definition gives for the launch's positions; a scrape
+    without the counter (the gather route of `served`) reads 0, which the
+    metric file says is no measurement."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.reducers import ratio_of_deltas
+    from dllama_tpu.engine.batch import BatchEngine
+    from dllama_tpu.models.config import LlamaConfig
+    from dllama_tpu.models.llama import random_params
+    from dllama_tpu.obs import metrics
+    from dllama_tpu.ops.pallas import paged_attention as pa
+
+    spec = read_json(os.path.join(BENCH, "metrics",
+                                  "kv_rows_moved_per_needed.json"))
+    assert spec["reducer"] == "ratio_of_deltas"
+    cfg = LlamaConfig(dim=64, hidden_dim=128, n_layers=2, n_heads=4,
+                      n_kv_heads=2, vocab_size=96, seq_len=256)
+    eng = BatchEngine(cfg, random_params(cfg, seed=3, dtype=jnp.float32,
+                                         quantize=False),
+                      n_slots=2, cache_dtype=jnp.float32, kv_layout="paged",
+                      page_size=64, attn_impl="flash")
+    # (float32 tiles of 8 rows; 2 heads x 64 rows are far under the bytes
+    # that pay an end's units, so every copy in is the page)
+    assert eng.attn_route == "paged_kernel" and eng._paged_rows == (64, 4, 8, 64)
+    eng.add(0, list(range(1, 40)), temperature=0.0, seed=0)
+    eng.add(1, [9, 8, 7], temperature=0.0, seed=1)
+    before = {"metrics": loadlib.prometheus(metrics.render())}
+    start = eng.pos.copy()
+    eng.decode(4)
+    after = {"metrics": loadlib.prometheus(metrics.render())}
+    for key in spec["params"]["num"] + spec["params"]["den"]:
+        assert key in after["metrics"], f"no sample {key!r}"
+    value = ratio_of_deltas.reduce(spec["params"], {
+        "before": before, "after": after, "config": {}})
+    at = (start[:, None] + np.arange(4)[None]).ravel()
+    want = 100.0 * pa.rows_moved(at, 64, 4, 8, 64).sum() / (at + 1).sum()
+    assert value == pytest.approx(want) and 100.0 < value < 400.0
+
+
 def test_idle_by_phase_reads_the_capture_block_the_program_writes(served):
     """`idle_by_phase_ms_per_launch`'s host tables: the capture block's
     seconds by state and phase, drains, waits and host gap, and the

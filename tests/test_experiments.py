@@ -52,10 +52,15 @@ def test_kbench_sampler_smoke():
 def test_kbench_paged_smoke():
     """The paged-decode loop of the benchmark's cells (the pricing of every
     paged-kernel PR) at a tiny size: fused and read-only, pools threaded."""
-    p = _run(["experiments/kbench.py", "paged", "--smoke"])
+    p = _run(["experiments/kbench.py", "paged", "--smoke", "--sub"])
     assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr[-2000:]}"
     assert "KBENCH DONE" in p.stdout and "FAILED" not in p.stdout, p.stdout
-    assert p.stdout.count("fused scatter:") == p.stdout.count("read-only:") == 2
+    # two global calls and a windowed one (PR 53), each fused and read-only
+    # beside the bytes needed, touched and MOVED, then the fused call again
+    # at each size of an end page's copies (`--sub`: 16 rows, the page)
+    assert p.stdout.count("fused scatter:") == p.stdout.count("read-only:") == 3
+    assert p.stdout.count("; moved ") == 12 and p.stdout.count(", window 80;") == 4
+    assert p.stdout.count("fused scatter, end copies of") == 6
     # the latent cells' sweep (PR 48): decode and slice, each as it is, as
     # the parent's page-a-pass body and over the pages-a-pass sweep
     assert p.stdout.count("the parent's body (a page a pass") == 2

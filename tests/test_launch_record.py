@@ -145,6 +145,54 @@ def test_record_matches_brute_force(name):
         assert rec.starved == 3 + 2 + 0
 
 
+@pytest.mark.parametrize("window", [0, 5, 24], ids=["global", "w5", "w24"])
+@pytest.mark.parametrize("itemsize", [2, 4], ids=["bfloat16", "float32"])
+def test_rows_moved_match_a_brute_count_over_a_random_batch(window, itemsize):
+    """`kv_rows_moved` (and a windowed layer's) against a walk row by row:
+    a step at position p needs rows first .. p (first = 0, or p - W + 1);
+    the kernel copies whole pages between the walk's two end pages, those
+    by the units of `sub` rows that hold a needed row, and writes back the
+    `win`-row tile of row p. Over `kv_rows` it is the new metric's ratio."""
+    from dllama_tpu.ops.pallas import paged_attention as pa
+
+    page, nb, n = 64, 8, 4
+    win, sub = pa._row_tiles(page, itemsize)
+    assert sub < page
+    rng = np.random.default_rng(53)
+    start = rng.integers(0, page * nb - n, size=16).astype(np.int32)
+    active = rng.random(16) < 0.8
+    advance = np.where(active, rng.integers(0, n + 1, size=16), 0)
+
+    def moved(p, w):
+        first = max(p - w + 1, 0) if w else 0
+        units = {r // sub for r in range(first, p + 1)}  # units with a needed row
+        for blk in range(first // page + 1, p // page):  # pages between: whole
+            units |= {blk * (page // sub) + u for u in range(page // sub)}
+        return len(units) * sub + win
+
+    rec = launch_record.build("decode", 1, n, start, active, advance,
+                              seq_len=page * nb, pool_dry=False, window=window,
+                              paged=(page, nb, win, sub))
+    steps = [(int(p) + s) for p, a, on in zip(start, advance, active) if on
+             for s in range(int(a))]
+    assert rec.kv_rows == sum(p + 1 for p in steps)
+    assert rec.kv_rows_moved == sum(moved(p, 0) for p in steps)
+    assert rec.kv_rows_moved_window == (
+        sum(moved(p, window) for p in steps) if window else 0)
+    assert rec.kv_rows < rec.kv_rows_moved <= rec.kv_rows + len(steps) * (
+        sub + win)  # the last unit's dead rows and the tile, no page's
+    # off the paged kernel's route nothing is counted
+    assert launch_record.build("decode", 1, n, start, active, advance,
+                               seq_len=page * nb, pool_dry=False
+                               ).kv_rows_moved == 0
+    if not window:
+        before = ins.LAUNCH_KV_ROWS_MOVED.series()
+        rec.count()
+        assert _delta(ins.LAUNCH_KV_ROWS_MOVED, before) == {
+            "decode": rec.kv_rows_moved}
+        assert rec.args()["kv_rows_moved"] == rec.kv_rows_moved
+
+
 def test_spec_record_counts_slots_that_emitted_nothing_as_frozen():
     start = np.array([10, 20, 5], np.int32)
     active = np.array([True, True, False])
@@ -500,7 +548,7 @@ def test_capture_block_is_the_counter_deltas(monkeypatch, tmp_path):
     try:
         assert trace.PROFILER_HOOK is jax.profiler.TraceAnnotation
         launch_record.LaunchRecord("decode", 2, 4, 3, 10, 2, 0, 640, 0,
-                                   sampler="greedy").count()
+                                   sampler="greedy", kv_rows_moved=704).count()
         launch_record.LaunchRecord("hybrid", 3, 4, 2, 8, 0, 4, 300, 16,
                                    sampler="nucleus").count()
         launch_record.LaunchRecord("prefill_chunk", 0, 0, 0, 0, 0, 0, 0,
@@ -518,10 +566,11 @@ def test_capture_block_is_the_counter_deltas(monkeypatch, tmp_path):
     assert pick(cap["slot_steps"]) == {"advanced": 18, "starved": 2,
                                        "empty": 4}
     assert pick(cap["kv_rows"]) == {"decode": 640, "hybrid": 300}
+    assert pick(cap["kv_rows_moved"]) == {"decode": 704}
     assert pick(cap["prefill_rows"]) == {"hybrid": 16, "prefill_chunk": 8}
     assert cap["seconds"] >= 0.0
     assert set(cap) == {"launches", "sampler_launches", "slot_steps",
-                        "kv_rows", "prefill_rows",
+                        "kv_rows", "kv_rows_moved", "prefill_rows",
                         "kv_rows_read", "moe_assignments",
                         "moe_experts_touched", "moe_layer_steps",
                         "moe_group_rows_max", "window_pages_released",

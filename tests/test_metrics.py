@@ -622,7 +622,7 @@ def test_debug_perf_capture_block_counts_the_captures_launches(
     cap = json.loads(data)["capture"]
     assert cap == profiling.last_capture()
     assert set(cap) == {"launches", "sampler_launches", "slot_steps",
-                        "kv_rows", "prefill_rows",
+                        "kv_rows", "kv_rows_moved", "prefill_rows",
                         "kv_rows_read", "moe_assignments",
                         "moe_experts_touched", "moe_layer_steps",
                         "moe_group_rows_max", "window_pages_released",
